@@ -243,8 +243,10 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 
 def main() -> None:
+    from ..utils.jaxenv import init_compile_cache
     from ..utils.logging_ext import init_logging
     init_logging()
+    init_compile_cache()
     args = parse_args()
     # Worker shell: SIGINT/SIGTERM drain gracefully — stop pulling the
     # queue, finish/ship the in-flight job, revoke the lease, exit

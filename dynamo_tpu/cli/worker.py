@@ -585,11 +585,13 @@ def parse_args(argv=None) -> argparse.Namespace:
 
 def main() -> None:
     from ..utils.logging_ext import init_logging
-    from ..utils.hostmesh import honor_jax_platforms_env
 
     init_logging()
-    honor_jax_platforms_env()
     args = parse_args()
+    if args.engine == "jax":
+        from ..utils.jaxenv import init_compile_cache
+
+        init_compile_cache()
     if args.num_nodes > 1 and args.node_rank > 0:
         run_follower(args)
         return

@@ -195,10 +195,16 @@ class DraftModelProposer:
     """
 
     def __init__(self, sc: SpecConfig, cfg, s_buckets: List[int],
-                 c_buckets: List[int]):
+                 c_buckets: List[int], device=None):
+        """``device``: where the draft model and its pool live — the main
+        engine's first device (default: this process's first device), so a
+        replica built on another chip keeps its draft beside it."""
         import jax
 
         from ..models import llama
+
+        if device is None:
+            device = jax.devices()[0]
 
         if jax.process_count() > 1:
             raise ValueError(
@@ -229,7 +235,7 @@ class DraftModelProposer:
             from ..parallel.mesh import serving_mesh, sharding as mk_sharding
             from jax.sharding import PartitionSpec as P
 
-            mesh = serving_mesh(1, 1, 1, 1, [jax.devices()[0]])
+            mesh = serving_mesh(1, 1, 1, 1, [device])
             specs = llama.param_specs(mcfg, 1, 1)
             shardings = jax.tree.map(
                 lambda s: mk_sharding(mesh, *s), specs,
@@ -237,13 +243,16 @@ class DraftModelProposer:
             from .loader import load_llama_params
             self.params = load_llama_params(src, mcfg, shardings)
         else:
-            self.params = llama.init_params(
-                mcfg, jax.random.PRNGKey(cfg.seed + 101))
+            with jax.default_device(device):
+                self.params = llama.init_params(
+                    mcfg, jax.random.PRNGKey(cfg.seed + 101))
         import jax.numpy as jnp
 
         pool_shape = (mcfg.num_layers, mcfg.num_kv_heads,
                       self.pool.num_pages, self.page, mcfg.head_dim)
-        zeros = jax.jit(lambda: jnp.zeros(pool_shape, mcfg.dtype))
+        zeros = jax.jit(lambda: jnp.zeros(pool_shape, mcfg.dtype),
+                        out_shardings=jax.sharding.SingleDeviceSharding(
+                            device))
         self.k_pool = zeros()
         self.v_pool = zeros()
         self._sync_fns: Dict[Tuple[int, int], Any] = {}
@@ -400,7 +409,7 @@ class DraftModelProposer:
 
 
 def build_proposer(sc: SpecConfig, cfg, s_buckets: List[int],
-                   c_buckets: List[int]):
+                   c_buckets: List[int], device=None):
     if sc.mode == "draft":
-        return DraftModelProposer(sc, cfg, s_buckets, c_buckets)
+        return DraftModelProposer(sc, cfg, s_buckets, c_buckets, device)
     return NgramProposer(sc)

@@ -12,8 +12,11 @@ Two kernels cover the two hot paths:
   gather-into-contiguous-context copy entirely, which is the dominant HBM
   traffic of decode.
 
-Both kernels run in interpreter mode off-TPU so the CPU test suite exercises
-the exact same code path the TPU runs compiled.
+On a TPU both kernels always compile (unless a caller passes
+``interpret=True``); off-TPU ``interpret=None`` selects the Pallas
+interpreter so the CPU test suite exercises the same kernel bodies. The
+platform test is :func:`dynamo_tpu.utils.jaxenv.on_tpu`, keyed on the
+device: callers that know their mesh pass ``interpret=not on_tpu(device)``.
 
 Reference capability: the CUDA paged/flash attention vLLM supplies behind the
 reference's engine adapters (SURVEY §2.1 engine rows; §7 "Pallas paged
@@ -33,24 +36,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.jaxenv import on_tpu
+
 NEG_INF = -1e30
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def _pick_block(n: int, align: int, cap: int = 128) -> int:
+    """Block size for an axis of length n under Mosaic's tiling rule: a
+    block's last two dims must be multiples of (8, 128) or span the whole
+    axis. Returns the largest power-of-two block <= cap that divides n and
+    is a multiple of ``align`` (8 for a second-minor axis, 128 for a minor
+    one); when none does, n itself — one block over the whole axis.
 
-
-def _pick_block(n: int, cap: int = 128) -> int:
-    """Largest power-of-two block <= cap that divides n.
-
-    The engine only calls flash_attention with power-of-two bucketed T/S,
-    so this returns >= 8 on every real path; a degenerate block of 1 can
-    only happen for odd ad-hoc shapes (tests), where interpret mode does
-    not care about TPU tiling."""
+    The engine keeps the whole-axis case small: prefill chunks are powers of
+    two, context buckets above 128 are multiples of 128 (engine.py), so only
+    short axes (a spec-verify chunk of k+1 tokens, sub-128 contexts) are
+    taken whole."""
     b = cap
-    while b > 1 and n % b:
+    while b >= align:
+        if n % b == 0:
+            return b
         b //= 2
-    return b
+    return n
 
 
 # ---------------------------------------------------------------------------
@@ -142,9 +149,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     S, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
     if interpret is None:
-        interpret = _interpret_default()
-    BT = _pick_block(T)
-    BS = _pick_block(S)
+        interpret = not on_tpu()
+    BT = _pick_block(T, 8)
+    BS = _pick_block(S, 128)
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
 
@@ -463,6 +470,22 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = o.astype(o_ref.dtype)
 
 
+def paged_kernel_variant(interpret: bool) -> str:
+    """Which paged-attention kernel :func:`paged_attention` runs:
+    ``dma`` (multi-page double-buffered, compiled — the default on a TPU),
+    ``simple`` (one page per grid step, compiled —
+    ``DYNAMO_TPU_PAGED_KERNEL=simple``) or ``simple[interpret]`` (off-TPU).
+    The engine reports this same value, so what a run says it ran is what
+    the kernel entry point selected."""
+    variant = os.environ.get("DYNAMO_TPU_PAGED_KERNEL", "dma")
+    if variant not in ("dma", "simple"):
+        # repo convention: a typo'd env flag must not silently select the
+        # slow path (cf. DYNAMO_TPU_DATAPLANE / DYNAMO_TPU_STORE)
+        raise ValueError(f"DYNAMO_TPU_PAGED_KERNEL={variant!r} "
+                         f"(expected dma|simple)")
+    return "simple[interpret]" if interpret else variant
+
+
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     page_tables: jax.Array, lengths: jax.Array,
                     interpret: Optional[bool] = None,
@@ -483,12 +506,12 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     every page. ``softcap`` tanh-caps scores pre-softmax (Gemma2);
     ``scale`` overrides rsqrt(Dh) (query_pre_attn_scalar).
 
-    On a real TPU this runs the multi-page double-buffered DMA kernel
-    above (``DYNAMO_TPU_PAGED_KERNEL=simple`` falls back to the
-    BlockSpec-pipelined one-page-per-step kernel below, compiled — the
-    variant proven on-chip before the DMA rewrite); off-TPU (and under
+    On a TPU this runs the multi-page double-buffered DMA kernel above
+    (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
+    one-page-per-step kernel below, compiled); off-TPU (and under
     ``interpret=True``) the simple kernel runs in interpreter mode so the
-    CPU test suite exercises the same contract.
+    CPU test suite exercises the same contract. See
+    :func:`paged_kernel_variant`.
     """
     B, Hq, Dh = q.shape
     Hkv, n_pages, page, _ = k_pages.shape
@@ -500,14 +523,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # callers to pad lengths.
     lengths = jnp.maximum(lengths, 1)
     if interpret is None:
-        interpret = _interpret_default()
-    variant = os.environ.get("DYNAMO_TPU_PAGED_KERNEL", "dma")
-    if variant not in ("dma", "simple"):
-        # repo convention: a typo'd env flag must not silently select the
-        # slow path (cf. DYNAMO_TPU_DATAPLANE / DYNAMO_TPU_STORE)
-        raise ValueError(f"DYNAMO_TPU_PAGED_KERNEL={variant!r} "
-                         f"(expected dma|simple)")
-    if not interpret and variant == "dma":
+        interpret = not on_tpu()
+    if paged_kernel_variant(interpret) == "dma":
         q4 = q.reshape(B, Hkv, G, Dh)
         # DMA depth knob for on-chip tuning sweeps (perf_probe) — larger
         # blocks amortize DMA issue latency, smaller ones cut the tail

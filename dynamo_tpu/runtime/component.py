@@ -275,12 +275,14 @@ class DistributedRuntime:
 
                 self._native_dp = NativeDataPlane(self)
                 self.dp_port = self._native_dp.start("0.0.0.0", 0)
-            except Exception:
+            except Exception as e:
                 self._native_dp = None   # half-started plane must not
                 if mode == "native":     # block the asyncio fallback
                     raise
-                log.info("native data plane unavailable; using asyncio",
-                         exc_info=True)
+                log.warning("native data plane unavailable (%s: %s); "
+                            "serving on the asyncio data plane — set "
+                            "DYNAMO_TPU_DATAPLANE=native to make this an "
+                            "error", type(e).__name__, e)
         if self._native_dp is None:
             self._dp_server = await asyncio.start_server(
                 self._serve_conn, "0.0.0.0", 0)

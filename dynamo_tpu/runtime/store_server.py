@@ -671,12 +671,22 @@ if _os.environ.get("DYNAMO_TPU_STORE") == "native":
 
 
 async def main(host: str = "0.0.0.0", port: int = 4222) -> None:
+    import signal
+
     srv = StoreServer(host, port)
     p = await srv.start()
     log.info("dynstore listening on %s:%s", host, p)
     print(f"dynstore listening on {host}:{p}", flush=True)
-    while True:
-        await asyncio.sleep(3600)
+    # SIGTERM/SIGINT stop the server first: the native implementation is a
+    # child process, and a wrapper that just dies leaves it running
+    done = asyncio.Event()
+    loop = asyncio.get_running_loop()
+    for sig in (signal.SIGTERM, signal.SIGINT):
+        loop.add_signal_handler(sig, done.set)
+    try:
+        await done.wait()
+    finally:
+        await srv.stop()
 
 
 if __name__ == "__main__":
@@ -703,8 +713,10 @@ if __name__ == "__main__":
         try:
             build_native("build/dynstore")
             StoreServer = NativeStoreServer  # type: ignore[misc]
-        except RuntimeError:
-            log.info("native dynstore unavailable; using asyncio server")
+        except RuntimeError as e:
+            log.warning("native dynstore unavailable (%s); running the "
+                        "asyncio store — pass --impl native to make this "
+                        "an error", e)
     elif a.impl == "python":
         StoreServer = PyStoreServer  # type: ignore[misc]
     asyncio.run(main(host=a.host, port=a.port))

@@ -5,8 +5,11 @@ reference's GPU allocator assigns CUDA_VISIBLE_DEVICES ranges,
 deploy/dynamo/sdk/cli/allocator.py:35-101). Contiguity matters on TPU:
 neighboring chips share ICI links, so a slice split across the board pays
 DCN-class latency for what should be ICI collectives. On TPU VMs chip
-visibility is controlled with ``TPU_VISIBLE_DEVICES``; for hermetic CPU
-runs the same request becomes a virtual device count
+visibility is controlled with ``TPU_VISIBLE_DEVICES``; a process that takes
+only part of the host's chips must also be told the shape of its sub-slice
+and that it is the only process in it (:func:`tpu_process_env`), or its TPU
+runtime tries to bring up the whole host and collides with its neighbours.
+For hermetic CPU runs the same request becomes a virtual device count
 (``--xla_force_host_platform_device_count``).
 
 Beyond the round-4 bump allocator: per-allocation release (a restarted
@@ -24,6 +27,37 @@ from typing import Dict, List, Optional
 
 class AllocationError(RuntimeError):
     pass
+
+
+# chips granted -> the sub-slice shape the TPU runtime is told (x,y,z)
+_SLICE_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
+_TPU_PROCESS_PORT0 = 8476
+
+
+def tpu_process_env(chips: List[int]) -> Dict[str, str]:
+    """Environment that confines one process to ``chips`` of a TPU host
+    shared with other processes: visibility, the sub-slice's shape, a
+    single-process "slice" of its own, and a runtime port no neighbour uses
+    (derived from the first chip, so disjoint grants get disjoint ports).
+    A count that is no rectangular sub-slice (3, 5, ...) gets visibility
+    only and is left to the runtime."""
+    visible = {"TPU_VISIBLE_DEVICES": ",".join(map(str, chips))}
+    bounds = _SLICE_BOUNDS.get(len(chips))
+    if bounds is None:
+        return visible
+    port = _TPU_PROCESS_PORT0 + chips[0]
+    return {
+        **visible,
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": bounds,
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        # the same two under their older names, which a host image may
+        # already export for the whole board (v5e hosts do: 2,2,1 / 1,1,1)
+        "TPU_CHIPS_PER_HOST_BOUNDS": bounds,
+        "TPU_HOST_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "CLOUD_TPU_TASK_ID": "0",
+    }
 
 
 @dataclass
@@ -88,8 +122,7 @@ class TpuAllocator:
         run = min(candidates, key=len)
         chips = run[:n_chips]
         self._free.difference_update(chips)
-        alloc = Allocation(service, chips, {
-            "TPU_VISIBLE_DEVICES": ",".join(map(str, chips))})
+        alloc = Allocation(service, chips, tpu_process_env(chips))
         self._allocs.append(alloc)
         return alloc
 

@@ -19,6 +19,7 @@ watchers per service + GPU allocator + env-injected config).
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import subprocess
@@ -30,6 +31,14 @@ from typing import Any, Dict, List, Optional, Type
 from .allocator import TpuAllocator
 from .service import SERVICE_CONFIG_ENV, collect_graph
 from .serve_child import READY_MARKER, load_class
+
+
+def host_has_tpu() -> bool:
+    """Does this machine expose TPU chips? Decided from the accelerator
+    device nodes (``/dev/accel*`` up to v4, ``/dev/vfio/<n>`` from v5e on) —
+    the orchestrator must not touch JAX itself: a parent that initialises
+    the TPU runtime holds the chips its workers need."""
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
 
 
 class LocalServe:
@@ -78,7 +87,7 @@ class LocalServe:
         store = self._ensure_store()
         platform = self.platform
         if platform == "auto":
-            platform = "tpu" if os.environ.get("TPU_NAME") else "cpu"
+            platform = "tpu" if host_has_tpu() else "cpu"
         alloc = TpuAllocator(self.total_chips, platform)
         services = collect_graph(self.entry)
 

@@ -73,15 +73,28 @@ def main(argv=None) -> None:
     ap.add_argument("service", help="pkg.module:ServiceClass")
     ap.add_argument("--store", default="127.0.0.1:4222")
     args = ap.parse_args(argv)
-    from ..utils.hostmesh import honor_jax_platforms_env
+    import os
 
     init_logging()
-    honor_jax_platforms_env()
+    if os.environ.get("TPU_VISIBLE_DEVICES"):
+        # the orchestrator granted this worker chips (sdk/allocator.py): it
+        # builds an engine, and it must come up on them — a granted worker
+        # that lands on the CPU is an error here, not a slow deployment
+        from ..utils.jaxenv import init_compile_cache, on_tpu
+
+        import jax
+
+        init_compile_cache()
+        chips = os.environ["TPU_VISIBLE_DEVICES"]
+        if not on_tpu():
+            raise SystemExit(
+                f"{args.service}: granted TPU chips {chips} but jax came up "
+                f"on {jax.devices()[0].platform!r}")
+        print(f"{args.service}: granted TPU chips {chips}; jax sees "
+              f"{jax.devices()}", flush=True)
     sys.path.insert(0, ".")
     # artifact-deployed graphs: the operator extracts the bundle and hands
     # its path down (deploy/artifacts.py)
-    import os
-
     apath = os.environ.get("DYNAMO_ARTIFACT_PATH")
     if apath:
         # appended, matching load_entry: bundles must not shadow framework

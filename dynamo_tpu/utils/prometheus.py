@@ -456,6 +456,22 @@ class StageMetrics:
         self.hbm_gbps = r.gauge(
             "dyn_hbm_gbps", "Achieved main-memory GB/s over the recent "
             "dispatch window", ("worker",))
+        # what this engine actually runs, read from the live engine object
+        # (value is always 1; the labels are the report): attention paths,
+        # paged-kernel variant, the devices it was given and where its
+        # roofline peaks came from
+        self.engine_info = r.gauge(
+            "dyn_engine_info",
+            "Engine build facts as labels (value 1): selected attention "
+            "paths, paged-kernel variant, device platform/kind/count, peak "
+            "source",
+            ("worker", "attn_impl", "decode_attn_impl", "paged_kernel",
+             "platform", "device_kind", "devices", "peak_source"))
+        self.device_peak_bytes = r.gauge(
+            "dyn_device_peak_bytes_in_use",
+            "Peak device memory in use per engine device "
+            "(device.memory_stats(); absent where the backend reports none)",
+            ("worker", "device"))
         # compile plane: warmup cost and bucket-explosion regressions are
         # invisible in latency histograms until they hit a request — count
         # every XLA program build (first call of a fresh bucket program)
@@ -745,7 +761,8 @@ class StageMetrics:
         into engine shutdown/deregistration so a process that outlives its
         engine (shared-runtime tests, model remove/re-add) stops exporting
         ghost occupancy/MFU for an engine that no longer exists."""
-        for g in (self.batch_occupancy, self.mfu, self.mbu, self.hbm_gbps):
+        for g in (self.batch_occupancy, self.mfu, self.mbu, self.hbm_gbps,
+                  self.engine_info, self.device_peak_bytes):
             g.clear_label(0, worker)
         self.kv_tier_blocks.clear_label(1, worker)   # (tier, worker)
         self.kvpage_resident_bytes.clear_label(1, worker)

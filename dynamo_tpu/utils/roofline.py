@@ -110,7 +110,13 @@ def detect_peaks(device_kind: Optional[str] = None,
                  platform: Optional[str] = None) -> Peaks:
     """Peaks for the attached accelerator. ``device_kind``/``platform``
     default to jax's first device; passing them explicitly keeps this
-    importable (and testable) without touching a backend."""
+    importable (and testable) without touching a backend.
+
+    An accelerator whose ``device_kind`` is not in
+    :data:`PEAKS_BY_DEVICE_KIND` raises ``ValueError`` — a device missing
+    from the table is an error, never a default (set ``DYN_PEAK_FLOPS`` /
+    ``DYN_PEAK_GBPS`` or add the row). Only ``platform == "cpu"`` takes the
+    calibrated host measurement."""
     env = _env_peaks()
     if env is not None:
         return env
@@ -119,11 +125,16 @@ def detect_peaks(device_kind: Optional[str] = None,
 
         d = jax.devices()[0]
         device_kind, platform = d.device_kind, d.platform
-    if platform not in ("cpu",):
+    if platform != "cpu":
         k = device_kind.lower()
         for sub, pf, pb in PEAKS_BY_DEVICE_KIND:
             if sub in k:
                 return Peaks(pf, pb, f"table:{sub}")
+        raise ValueError(
+            f"no peak FLOP/s / bandwidth known for device_kind "
+            f"{device_kind!r} (platform {platform!r}): add it to "
+            f"roofline.PEAKS_BY_DEVICE_KIND or set DYN_PEAK_FLOPS and "
+            f"DYN_PEAK_GBPS")
     if "cpu" not in _CAL_CACHE:
         _CAL_CACHE["cpu"] = _calibrate_cpu()
     return _CAL_CACHE["cpu"]
@@ -376,7 +387,9 @@ def instrument_compile(kind: str, fn: Callable,
     reported via ``on_compile(kind, seconds)``. Later calls pass through
     untouched. This is how ``dyn_compile_seconds_total`` /
     ``dyn_compiled_programs`` see warmup AND mid-serving bucket compiles
-    without instrumenting every dispatch site."""
+    without instrumenting every dispatch site. The jitted program itself
+    stays reachable as ``wrapper.jitted`` (``.lower(...).compile()`` for
+    ``memory_analysis()`` / compiled text)."""
     state = {"first": True}
 
     def wrapper(*args, **kwargs):
@@ -388,4 +401,5 @@ def instrument_compile(kind: str, fn: Callable,
             return out
         return fn(*args, **kwargs)
 
+    wrapper.jitted = fn
     return wrapper

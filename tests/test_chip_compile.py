@@ -1,0 +1,377 @@
+"""Compile-only guards for the chip path, run on the CPU.
+
+The TPU compiler is installed with jax and compiles for a chip that is
+described and not attached (`jax.experimental.topologies`). Nothing executes
+here: these tests say "the v5e compiler accepts this kernel / this decode
+step at real widths", which interpret mode cannot (tiling and VMEM limits
+only exist in the real lowering). They are skipped where the topology cannot
+be described. The file sorts early on purpose, ahead of the slow
+multi-device suites.
+
+Also here: unit tests for the places where a device decision used to fall
+back silently (unknown accelerator peaks, a failing kernel probe under
+``attn_impl="auto"``, where the compile cache goes).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from dynamo_tpu.models import llama
+from dynamo_tpu.ops import attention as A
+from dynamo_tpu.utils import jaxenv, roofline
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+PAGE = 64
+# (Hq, Hkv, Dh): llama-3.2-1b, a Dh=128 GQA model, a Dh=256 (Gemma-class)
+GEOMETRIES = [(32, 8, 64), (32, 8, 128), (16, 8, 256)]
+VARIANTS = {
+    "plain": {},
+    "window": {"window": 1024},
+    "softcap": {"softcap": 50.0, "scale": 0.0625},
+}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e device, with the persistent compile cache off: a
+    compile for a described device is written to the cache but cannot be
+    read back without a chip, so a warm cache would only add warnings."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler in this image
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices[0]
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(device, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(device))
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_text(v5e, geom, B, T, S, **kw) -> str:
+    Hq, Hkv, Dh = geom
+    return _compiled_text(
+        lambda q, k, v, qp, kp, kv: A.flash_attention(
+            q, k, v, qp, kp, kv, interpret=False, **kw),
+        _sds(v5e, (B, T, Hq, Dh), jnp.bfloat16),
+        _sds(v5e, (B, S, Hkv, Dh), jnp.bfloat16),
+        _sds(v5e, (B, S, Hkv, Dh), jnp.bfloat16),
+        _sds(v5e, (B, T), jnp.int32), _sds(v5e, (B, S), jnp.int32),
+        _sds(v5e, (B, S), jnp.bool_))
+
+
+@pytest.mark.parametrize("variant", list(VARIANTS))
+@pytest.mark.parametrize("geom", GEOMETRIES, ids=lambda g: "x".join(map(str, g)))
+def test_flash_compiles_for_v5e(v5e, geom, variant):
+    assert "tpu_custom_call" in _flash_text(v5e, geom, 8, 128, 1024,
+                                            **VARIANTS[variant])
+
+
+@pytest.mark.parametrize("T,S", [(5, 2176), (32, 80), (512, 2176)])
+def test_flash_compiles_at_engine_edge_shapes(v5e, T, S):
+    """Shapes the engine really produces besides power-of-two buckets: a
+    spec-verify chunk of k+1 tokens, a sub-128 context, and the last context
+    bucket (max_context + pad, rounded to 128)."""
+    assert "tpu_custom_call" in _flash_text(v5e, GEOMETRIES[0], 4, T, S)
+
+
+@pytest.mark.parametrize("variant", list(VARIANTS))
+@pytest.mark.parametrize("kernel", ["dma", "simple"])
+@pytest.mark.parametrize("geom", GEOMETRIES, ids=lambda g: "x".join(map(str, g)))
+def test_paged_compiles_for_v5e(v5e, monkeypatch, geom, kernel, variant):
+    Hq, Hkv, Dh = geom
+    B, P = 32, 34                       # 34 pages: max_context 2048 + pad
+    n_pages = B * P + 1
+    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", kernel)
+    txt = _compiled_text(
+        lambda q, k, v, pt, ln: A.paged_attention(
+            q, k, v, pt, ln, interpret=False, **VARIANTS[variant]),
+        _sds(v5e, (B, Hq, Dh), jnp.bfloat16),
+        _sds(v5e, (Hkv, n_pages, PAGE, Dh), jnp.bfloat16),
+        _sds(v5e, (Hkv, n_pages, PAGE, Dh), jnp.bfloat16),
+        _sds(v5e, (B, P), jnp.int32), _sds(v5e, (B,), jnp.int32))
+    assert "tpu_custom_call" in txt
+
+
+def test_decode_step_compiles_with_kernels_for_v5e(v5e):
+    """One whole decode step at llama-3.2-1b widths (depth cut to two layers
+    to stay within seconds), placed on the described device through a mesh:
+    the model picks compiled kernels from the mesh's device, so the program
+    must hold one ``tpu_custom_call`` per layer — in a CPU-backend process
+    keyed on the default backend it would hold none."""
+    from dynamo_tpu.parallel.mesh import serving_mesh
+
+    cfg = llama.preset("llama-3.2-1b", num_layers=2)
+    mesh = serving_mesh(1, devices=[v5e])
+    B, P = 32, 34
+    n_pages = B * P + 1
+    pshapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), pshapes)
+    pool = _sds(v5e, (cfg.num_layers, cfg.num_kv_heads, n_pages, PAGE,
+                      cfg.head_dim), cfg.dtype)
+    txt = _compiled_text(
+        lambda p, t, k, v, pt, ln: llama.forward_decode(
+            p, cfg, t, k, v, pt, ln, attn_impl="pallas", mesh=mesh),
+        params, _sds(v5e, (B,), jnp.int32), pool, pool,
+        _sds(v5e, (B, P), jnp.int32), _sds(v5e, (B,), jnp.int32))
+    assert txt.count("tpu_custom_call") >= cfg.num_layers
+
+
+@pytest.mark.parametrize("full_tracebacks", [False, True])
+def test_kernel_program_text_vs_call_stack(v5e, full_tracebacks):
+    """A Pallas kernel is serialised into its program with its locations.
+    With jax's default (full tracebacks) those hold the tracing call stack,
+    so the same program traced from two entry points hashes to two compile
+    cache keys; with what init_compile_cache sets, it is one program."""
+    args = (_sds(v5e, (8, 32, 64), jnp.bfloat16),
+            _sds(v5e, (8, 65, PAGE, 64), jnp.bfloat16),
+            _sds(v5e, (8, 65, PAGE, 64), jnp.bfloat16),
+            _sds(v5e, (8, 8), jnp.int32), _sds(v5e, (8,), jnp.int32))
+
+    def lowered_text(depth):
+        if depth:
+            return lowered_text(depth - 1)
+
+        def fresh(q, k, v, pt, ln):        # a new function: a new trace
+            return A.paged_attention(q, k, v, pt, ln, interpret=False)
+
+        return jax.jit(fresh).lower(*args).compiler_ir().operation.get_asm(
+            enable_debug_info=False)
+
+    was = jax.config.jax_include_full_tracebacks_in_locations
+    jax.config.update("jax_include_full_tracebacks_in_locations",
+                      full_tracebacks)
+    try:
+        same = lowered_text(0) == lowered_text(3)
+    finally:
+        jax.config.update("jax_include_full_tracebacks_in_locations", was)
+    assert same is (not full_tracebacks)
+
+
+# ---------------------------------------------------------------------------
+# device decisions that used to fall back silently
+# ---------------------------------------------------------------------------
+
+def _tiny_engine_cfg(**kw):
+    from dynamo_tpu.engine.engine import JaxEngineConfig
+
+    return JaxEngineConfig(**{"model": llama.preset("tiny-byte"),
+                              "max_batch": 2, "max_context": 64,
+                              "page_size": 8, "prefill_chunk": 32, **kw})
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("platform,kind,expect", [
+    ("tpu", "TPU v5 lite", True),
+    ("renamed-backend", "TPU v5 lite", True),   # keyed on the device kind
+    ("cpu", "cpu", False),
+    ("gpu", "NVIDIA H100", False),
+])
+def test_on_tpu_is_keyed_on_the_device(platform, kind, expect):
+    assert jaxenv.on_tpu(_FakeDevice(platform, kind)) is expect
+
+
+def test_on_tpu_defaults_to_this_process_device():
+    assert jaxenv.on_tpu() is False        # the suite runs on the CPU
+
+
+@pytest.mark.parametrize("kind,platform", [
+    ("TPU vNext", "tpu"), ("NVIDIA H100", "gpu"), ("TPU vNext", "renamed")])
+def test_detect_peaks_raises_for_unknown_accelerator(monkeypatch, kind,
+                                                     platform):
+    monkeypatch.delenv("DYN_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("DYN_PEAK_GBPS", raising=False)
+    with pytest.raises(ValueError, match=kind):
+        roofline.detect_peaks(kind, platform)
+
+
+def test_detect_peaks_known_kinds_and_cpu(monkeypatch):
+    monkeypatch.delenv("DYN_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("DYN_PEAK_GBPS", raising=False)
+    assert roofline.detect_peaks("TPU v5 lite", "tpu").source == \
+        "table:v5 lite"
+    assert roofline.detect_peaks("cpu", "cpu").source == "calibrated-cpu"
+
+
+def test_auto_on_tpu_with_failing_probe_raises(monkeypatch):
+    """``attn_impl="auto"`` on a TPU resolves to pallas or raises the
+    compiler's message; it never degrades to dense attention. The TPU is
+    mocked; the probe is real and cannot compile on the CPU backend."""
+    from dynamo_tpu.engine import engine as E
+
+    monkeypatch.delenv("DYNAMO_TPU_ATTN", raising=False)
+    monkeypatch.setattr(E, "on_tpu", lambda device=None: True)
+    # the mocked platform would otherwise also fail the peak-table lookup
+    monkeypatch.setenv("DYN_PEAK_FLOPS", "1e12")
+    monkeypatch.setenv("DYN_PEAK_GBPS", "100")
+    with pytest.raises(ValueError, match="interpret mode"):
+        E.EngineCore(_tiny_engine_cfg())
+
+
+def test_auto_off_tpu_is_dense_and_reported():
+    from dynamo_tpu.engine.engine import EngineCore
+
+    core = EngineCore(_tiny_engine_cfg())
+    assert (core.attn_impl, core.decode_attn_impl, core.paged_kernel) == \
+        ("xla", "xla", None)
+
+
+def test_explicit_pallas_off_tpu_reports_interpreted_kernel():
+    from dynamo_tpu.engine.engine import EngineCore
+
+    core = EngineCore(_tiny_engine_cfg(attn_impl="pallas"))
+    assert core.paged_kernel == "simple[interpret]"
+
+
+@pytest.mark.parametrize("env,interpret,expect", [
+    (None, False, "dma"), ("simple", False, "simple"),
+    ("dma", True, "simple[interpret]"), (None, True, "simple[interpret]")])
+def test_paged_kernel_variant(monkeypatch, env, interpret, expect):
+    if env is None:
+        monkeypatch.delenv("DYNAMO_TPU_PAGED_KERNEL", raising=False)
+    else:
+        monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", env)
+    assert A.paged_kernel_variant(interpret) == expect
+
+
+def test_paged_kernel_variant_rejects_typo(monkeypatch):
+    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", "dmaa")
+    with pytest.raises(ValueError, match="DYNAMO_TPU_PAGED_KERNEL"):
+        A.paged_kernel_variant(False)
+
+
+@pytest.mark.parametrize("n,align,expect", [
+    (512, 8, 128), (32, 8, 32), (5, 8, 5), (3, 8, 3),      # query axis
+    (2176, 128, 128), (256, 128, 128), (80, 128, 80),      # context axis
+    (2112, 128, 2112)])
+def test_pick_block_obeys_mosaic_tiling(n, align, expect):
+    """A block is a multiple of the tile (8 sublanes / 128 lanes) that
+    divides the axis, or the whole axis — the two shapes Mosaic accepts."""
+    assert A._pick_block(n, align) == expect
+
+
+@pytest.mark.parametrize("max_context,page", [(2048, 64), (1024, 16),
+                                              (256, 16), (64, 8)])
+def test_context_buckets_tile_for_flash(max_context, page):
+    from dynamo_tpu.engine.engine import EngineCore
+
+    core = EngineCore(_tiny_engine_cfg(max_context=max_context,
+                                       page_size=page))
+    assert core.s_buckets[-1] >= max_context + core._spec_pad
+    for s in core.s_buckets:
+        assert s % page == 0
+        assert s <= 128 or s % 128 == 0
+
+
+def test_compile_cache_dir_honours_environment(monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert jaxenv.compile_cache_dir() == str(tmp_path)
+
+
+def test_compile_cache_dir_default_is_fixed_in_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert jaxenv.compile_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert jaxenv.compile_cache_dir() == jaxenv.compile_cache_dir()
+
+
+@pytest.mark.parametrize("env_set,platforms", [
+    (True, "cpu"), (True, None), (False, None), (False, "tpu,cpu"),
+    (False, "cpu")])
+def test_init_compile_cache_sets_a_path_only_without_the_env(
+        monkeypatch, tmp_path, env_set, platforms):
+    """With JAX_COMPILATION_CACHE_DIR set the program sets no cache path in
+    code (jax reads the variable itself); unset, it sets the in-checkout
+    path — except in a process told to run on the CPU, which keeps no
+    persistent cache. No backend is touched either way."""
+    updates = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.__setitem__(k, v))
+    monkeypatch.setattr(type(jax.config), "jax_platforms", platforms,
+                        raising=False)
+    monkeypatch.setattr(jax, "devices", lambda *a: pytest.fail(
+        "init_compile_cache must not initialise a backend"))
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = jaxenv.init_compile_cache()
+    if env_set:
+        assert path == str(tmp_path)
+        assert "jax_compilation_cache_dir" not in updates
+    elif platforms == "cpu":
+        assert path is None and updates == {}
+    else:
+        assert path.endswith(".jax_cache")
+        assert updates["jax_compilation_cache_dir"] == path
+    if path is not None:
+        assert updates["jax_persistent_cache_min_compile_time_secs"] == 0.0
+        assert updates["jax_include_full_tracebacks_in_locations"] is False
+
+
+def test_cpu_env_roundtrip(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    monkeypatch.setenv("XLA_FLAGS", "--foo=1")
+    assert not jaxenv.cpu_env_ready(4)
+    env = jaxenv.cpu_env(4)
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert env["XLA_FLAGS"] == \
+        "--foo=1 --xla_force_host_platform_device_count=4"
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    assert jaxenv.cpu_env_ready(4) and jaxenv.cpu_env_ready(2)
+    assert not jaxenv.cpu_env_ready(8)
+
+
+def test_serve_auto_platform_reads_device_nodes_not_jax(monkeypatch):
+    from dynamo_tpu.sdk import serve
+
+    seen = []
+
+    def fake_glob(pattern):
+        seen.append(pattern)
+        return ["/dev/vfio/0"] if "vfio" in pattern else []
+
+    monkeypatch.setattr(serve.glob, "glob", fake_glob)
+    monkeypatch.setenv("TPU_NAME", "")          # no longer consulted
+    assert serve.host_has_tpu() is True
+    monkeypatch.setattr(serve.glob, "glob", lambda pattern: [])
+    monkeypatch.setenv("TPU_NAME", "local")
+    assert serve.host_has_tpu() is False
+    assert any("accel" in p for p in seen)
+
+
+def test_allocator_confines_a_one_chip_worker():
+    from dynamo_tpu.sdk.allocator import TpuAllocator
+
+    a = TpuAllocator(total_chips=4, platform="tpu")
+    envs = [a.allocate(1, service="Worker") for _ in range(4)]
+    assert [e["TPU_VISIBLE_DEVICES"] for e in envs] == ["0", "1", "2", "3"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+    for e in envs:
+        assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        assert e["TPU_PROCESS_ADDRESSES"].endswith(e["TPU_PROCESS_PORT"])
