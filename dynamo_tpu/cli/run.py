@@ -280,6 +280,9 @@ async def amain(argv: Optional[List[str]] = None) -> None:
 
 
 def main() -> None:
+    from ..utils.logging_ext import init_logging
+
+    init_logging()
     try:
         asyncio.run(amain())
     except KeyboardInterrupt:

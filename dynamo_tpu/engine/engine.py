@@ -44,6 +44,7 @@ from ..ops.attention import (flash_attention, paged_attention,
                              paged_kernel_variant)
 from ..parallel.mesh import AXIS_TP, serving_mesh
 from ..runtime.engine import AsyncEngine, Context
+from ..utils import tracing as _tracing
 from ..utils.jaxenv import on_tpu
 from .cache import OutOfPages, PagePool
 from .sampling import (STATIC_K, SamplingState, apply_penalties,
@@ -55,6 +56,75 @@ log = logging.getLogger("dynamo_tpu.engine")
 # named ``jax.profiler`` scope around each device dispatch: lines the XLA
 # timeline up with the host-side request spans in captured profiles
 _trace_annotation = jax.profiler.TraceAnnotation
+
+# What the engine thread can be doing. Exactly one phase is open at a time:
+# siblings, back to back, never nested, so a reader of a capture that takes
+# "the last dynamo.* span begun before an idle gap" names what the host was
+# doing in it. ``prefill`` / ``decode`` / ``verify`` are the enqueue of a
+# dispatch (their annotations carry the bucket in brackets); the ``*_fetch``
+# phases are the engine thread blocked on the device.
+PHASES = ("inbox", "admit", "prefill_build", "prefill", "prefill_fetch",
+          "decode_build", "decode", "verify", "decode_fetch", "emit",
+          "deliver", "housekeeping", "paged", "idle")
+_PHASE_SCOPE = {p: "dynamo." + p for p in PHASES}
+
+
+class _Phases:
+    """The engine thread's phase switch: ``to(name)`` closes the open phase
+    and opens the next. One switch, two outputs: a ``TraceAnnotation``
+    ``dynamo.<phase>`` on the profiler's clock (free when no capture runs),
+    and the phase's ``perf_counter`` seconds in
+    ``dyn_engine_phase_seconds_total{phase}``."""
+
+    __slots__ = ("_seconds", "_name", "_scope", "_t0")
+
+    def __init__(self, seconds):
+        self._seconds = seconds
+        self._name: Optional[str] = None
+        self._scope = None
+        self._t0 = 0.0
+
+    def to(self, name: Optional[str], scope: Optional[str] = None) -> None:
+        """``scope`` names the annotation where it carries a dispatch's
+        bucket (``dynamo.decode[S512]``); otherwise ``dynamo.<name>``."""
+        now = time.perf_counter()
+        if self._name is not None:
+            self._scope.__exit__(None, None, None)
+            self._seconds.inc(self._name, amount=now - self._t0)
+        self._name, self._t0 = name, now
+        if name is not None:
+            self._scope = _trace_annotation(scope or _PHASE_SCOPE[name])
+            self._scope.__enter__()
+
+    def close(self) -> None:
+        self.to(None)
+
+
+class _LaneClock:
+    """Seconds, so far, during which a slot was free and every prefill lane
+    was taken by a prompt mid-prefill: what a request waiting then waited
+    for is a lane, not a slot. ``step()`` tells it the condition once an
+    iteration and ``_prefill_round`` once it has admitted; a request's share
+    is the difference of two ``read``s."""
+
+    __slots__ = ("_total", "_since")
+
+    def __init__(self) -> None:
+        self._total = 0.0
+        self._since = 0.0       # time.monotonic() the lanes filled; 0 = free
+
+    def set(self, full: bool) -> None:
+        if full != bool(self._since):
+            now = time.monotonic()
+            if full:
+                self._since = now
+            else:
+                self._total += now - self._since
+                self._since = 0.0
+
+    def read(self, at: float) -> float:
+        since = self._since
+        return self._total + (max(0.0, at - since) if since else 0.0)
 
 
 def global_put(host_array, sharding) -> jax.Array:
@@ -195,6 +265,14 @@ class _Slot:
     # [n_images, mm_tokens, D] (None for text-only requests)
     mm_spans: Optional[np.ndarray] = None
     mm_soft: Optional[np.ndarray] = None
+    # the request's way to its first token, ``time.monotonic()`` stamps
+    # taken on the engine thread (0.0 = not reached), and what its spans in
+    # the request trace hang under and say
+    t_admitted: float = 0.0
+    t_first_token: float = 0.0      # on the host
+    trace_parent: Any = None        # tracing.SpanContext of the submitter
+    prefix_hit: int = 0
+    chunks: int = 0
 
 
 @dataclass
@@ -219,6 +297,10 @@ class StepOutput:
     # output only (None elsewhere) — rides to EngineOutput.
     # kv_prefix_hit_tokens
     prefix_hit: Optional[int] = None
+    # ``time.monotonic()`` when the sequence's first token reached the host,
+    # on its FIRST output only: the frontend's ``post_engine`` stage starts
+    # here
+    first_token_at: Optional[float] = None
 
 
 class EngineCore:
@@ -245,6 +327,11 @@ class EngineCore:
         from ..utils.prometheus import stage_metrics
 
         self.stage = stage_metrics()   # cached: observe() runs per harvest
+        self.phase = _Phases(self.stage.engine_phase_seconds)
+        self.lane_clock = _LaneClock()
+        from ..utils.roofline import count_xla_compiles
+
+        count_xla_compiles()
         self.page_size = cfg.page_size
         # speculative decoding: resolved up front because the page-pad and
         # bucket sizing below must cover the verify program's k+1 positions
@@ -462,7 +549,10 @@ class EngineCore:
         # --- slots / scheduler ---------------------------------------
         self.slots: List[Optional[_Slot]] = [None] * cfg.max_batch
         self.by_seq: Dict[str, _Slot] = {}
-        self.waiting: Deque[Tuple[str, BackendInput]] = collections.deque()
+        # (seq_id, request, time.monotonic() at submit, the lane clock
+        # then, submitter's span)
+        self.waiting: Deque[Tuple[str, BackendInput, float, float, Any]] = \
+            collections.deque()
         self.sampling = SamplingState.host_init(cfg.max_batch)
         # commit to a canonical replicated sharding: program cache keys
         # include argument shardings, so an uncommitted key would recompile
@@ -912,8 +1002,15 @@ class EngineCore:
         if self.tiered is not None:
             self.tiered.close()
 
-    def submit(self, seq_id: str, request: BackendInput) -> None:
-        self.waiting.append((seq_id, request))
+    def submit(self, seq_id: str, request: BackendInput,
+               submitted: Optional[float] = None, parent: Any = None) -> None:
+        """``submitted`` is ``time.monotonic()`` where the request was handed
+        over (now, when the caller is the engine thread itself) and
+        ``parent`` the submitter's span: the queue stage starts there."""
+        if submitted is None:
+            submitted = time.monotonic()
+        self.waiting.append((seq_id, request, submitted,
+                             self.lane_clock.read(submitted), parent))
 
     def cancel(self, seq_id: str) -> None:
         slot = self.by_seq.get(seq_id)
@@ -921,7 +1018,7 @@ class EngineCore:
             slot.cancelled = True
         else:
             self.waiting = collections.deque(
-                (s, r) for s, r in self.waiting if s != seq_id)
+                w for w in self.waiting if w[0] != seq_id)
             if self.kvpager is not None:
                 self.kvpager.cancel(seq_id)
             if seq_id in self._stream_injects:
@@ -1053,6 +1150,7 @@ class EngineCore:
             min_tokens=None, ignore_eos=True))
         slot_idx = self.slots.index(None)
         slot = _Slot(seq_id, req, prompt)
+        slot.t_admitted = time.monotonic()   # no queue here: prefill only
         self.slots[slot_idx] = slot
         self.by_seq[seq_id] = slot
         self.pool.create(seq_id, lora_id=getattr(req, "lora_id", 0))
@@ -1264,16 +1362,23 @@ class EngineCore:
         fresh first tokens are flushed to callers immediately rather than
         held through a decode dispatch (TTFT)."""
         out: List[StepOutput] = []
+        phase = self.phase
+        phase.to("housekeeping")
         self._advance_writethrough()
         out.extend(self._reap_cancelled())
         n_reaped = len(out)     # paged outputs below don't change slots
         if self.kvpager is not None and self.kvpager.has_work:
             # one unit of paged long-context work (a prefill chunk or a
             # decode token) interleaves with every normal engine step
+            phase.to("paged")
             out.extend(self.kvpager.advance())
 
-        prefill_work = any(s is not None and s.prefill_done < len(s.prompt)
-                           for s in self.slots)
+        phase.to("admit")
+        prefilling = sum(s is not None and s.prefill_done < len(s.prompt)
+                         for s in self.slots)
+        prefill_work = prefilling > 0
+        self.lane_clock.set(prefilling >= self.b_buckets[-1]
+                            and None in self.slots)
         admit_possible = bool(self.waiting) and None in self.slots
         sync_needed = prefill_work or admit_possible or n_reaped > 0
 
@@ -1289,6 +1394,7 @@ class EngineCore:
             return out
 
         if self._inflight:
+            phase.to("decode_build")
             if not sync_needed and self._can_chain():
                 self._dispatch_decode()
             out.extend(self._process_oldest_inflight())
@@ -1314,6 +1420,27 @@ class EngineCore:
         return out
 
     # ------------------------------------------------------------------
+    def _request_span(self, slot: _Slot, name: str, start: float,
+                      end: float, **attrs: Any) -> None:
+        """One interval of a request (``time.monotonic()`` stamps) into its
+        trace as ``engine.<name>``: trace id = request id, parent = the span
+        that was current where the request was submitted (the engine thread
+        has no context of its own). The tracer decides whether it records
+        (``DYN_TRACING=0``) and its sinks whether they keep (sampling)."""
+        tracer = _tracing.get_tracer()
+        if tracer.enabled:
+            epoch = time.time() - time.monotonic()
+            tracer.record("engine." + name, start + epoch, end + epoch,
+                          parent=slot.trace_parent, trace_id=slot.seq_id,
+                          **attrs)
+
+    def _request_stage(self, slot: _Slot, stage: str, start: float,
+                       end: float, **attrs: Any) -> None:
+        """A stage of a request's way to its first token: always into
+        ``llm_request_stage_seconds{stage}``, and into its trace."""
+        self.stage.request_stage.observe(stage, value=end - start)
+        self._request_span(slot, stage, start, end, **attrs)
+
     def _reap_cancelled(self) -> List[StepOutput]:
         outs = []
         for i, slot in enumerate(self.slots):
@@ -1327,6 +1454,10 @@ class EngineCore:
         slot = self.slots[i]
         if slot is None:
             return
+        if slot.t_first_token:
+            self._request_span(slot, "decode", slot.t_first_token,
+                               time.monotonic(),
+                               output_tokens=slot.generated)
         # a queued-but-unapplied seed for this slot must die with it, or a
         # later occupant of the slot could get two key writes at one index
         # (implementation-defined winner)
@@ -1348,6 +1479,7 @@ class EngineCore:
 
     def _apply_deferred_release(self) -> None:
         if self._deferred_release and not self._inflight:
+            self.phase.to("housekeeping")
             for seq_id in self._deferred_release:
                 self.pool.release(seq_id)
             self._deferred_release.clear()
@@ -1576,7 +1708,7 @@ class EngineCore:
         """Admit the head-of-line request into a free slot (no prefill yet).
         Returns (slot_idx, slot), "rejected" (popped with an error emitted),
         or "blocked" (no KV capacity right now)."""
-        seq_id, req = self.waiting[0]
+        seq_id, req, submitted, lane_clock, parent = self.waiting[0]
         prompt = list(req.token_ids)
         over_ctx = len(prompt) >= self.cfg.max_context
         over_pool = (self.pool.pages_needed(len(prompt) + 1)
@@ -1629,6 +1761,7 @@ class EngineCore:
         slot_idx = self.slots.index(None)
         slot = _Slot(seq_id, req, prompt)
         slot.mm_spans, slot.mm_soft = mm_spans, mm_soft
+        slot.trace_parent = parent
         self.slots[slot_idx] = slot
         self.by_seq[seq_id] = slot
         self.pool.create(seq_id, lora_id=chain_salt)
@@ -1636,6 +1769,19 @@ class EngineCore:
         if self.cfg.enable_prefix_reuse:
             matched = self._restore_prefix(seq_id, prompt)
             slot.prefill_done = matched
+        slot.prefix_hit = matched
+        # stamped after the restore: it is part of what admission costs
+        slot.t_admitted = now = time.monotonic()
+        # the wait, split by what it was for: the seconds with a slot free
+        # and the prefill lanes taken (``lane_wait``), and the rest (the
+        # inbox, the drain of the in-flight window, a slot, KV capacity:
+        # ``queue``). Not two intervals: the span is the whole wait
+        lane = min(self.lane_clock.read(now) - lane_clock, now - submitted)
+        self.stage.request_stage.observe("lane_wait", value=lane)
+        self.stage.request_stage.observe(
+            "queue", value=now - submitted - lane)
+        self._request_span(slot, "queue", submitted, now,
+                           lane_wait_ms=round(1e3 * lane, 3))
         self.last_prefix_hit = matched
         self.prefix_hit_tokens += matched
         # surfaced on this sequence's FIRST StepOutput (step()'s tagging
@@ -1659,6 +1805,7 @@ class EngineCore:
         """Advance every mid-prefill slot by one chunk and admit as many
         waiting requests as fit, all in ONE batched dispatch (up to the
         prefill lane budget). Returns True if a dispatch ran."""
+        self.phase.to("admit")
         max_lanes = self.b_buckets[-1]
         chunks = [(i, s) for i, s in enumerate(self.slots)
                   if s is not None and s.prefill_done < len(s.prompt)]
@@ -1672,6 +1819,8 @@ class EngineCore:
             # fully satisfied by prefix reuse still needs its last token
             # computed, so every admission lands in the chunk list
             chunks.append(admitted)
+        # whoever still waits with a slot free now waits for a lane
+        self.lane_clock.set(len(chunks) >= max_lanes and None in self.slots)
         chunks = chunks[:max_lanes]
         if not chunks:
             return False
@@ -1717,19 +1866,20 @@ class EngineCore:
         s = self.sampling
         keys = s.key[jnp.asarray(idxs)]
         fn = self._prefill_fn(Bp, C, S, mm=mm_arrays is not None)
-        with _trace_annotation(f"dynamo.prefill[B{Bp},C{C},S{S}]"):
-            if mm_arrays is not None:
-                packed, _tok, new_keys, self.k_pool, self.v_pool = fn(
-                    self.params, tokens, positions, self.k_pool, self.v_pool,
-                    write_idx, read_idx, read_pos, read_valid, last_i,
-                    temp, top_p, top_k, keys, mm_arrays["ov_vals"],
-                    mm_arrays["ov_mask"], mm_arrays["q_span"],
-                    mm_arrays["read_span"])
-            else:
-                packed, _tok, new_keys, self.k_pool, self.v_pool = fn(
-                    self.params, tokens, positions, self.k_pool, self.v_pool,
-                    write_idx, read_idx, read_pos, read_valid, last_i,
-                    temp, top_p, top_k, keys)
+        self.phase.to("prefill", f"dynamo.prefill[B{Bp},C{C},S{S}]")
+        if mm_arrays is not None:
+            packed, _tok, new_keys, self.k_pool, self.v_pool = fn(
+                self.params, tokens, positions, self.k_pool, self.v_pool,
+                write_idx, read_idx, read_pos, read_valid, last_i,
+                temp, top_p, top_k, keys, mm_arrays["ov_vals"],
+                mm_arrays["ov_mask"], mm_arrays["q_span"],
+                mm_arrays["read_span"])
+        else:
+            packed, _tok, new_keys, self.k_pool, self.v_pool = fn(
+                self.params, tokens, positions, self.k_pool, self.v_pool,
+                write_idx, read_idx, read_pos, read_valid, last_i,
+                temp, top_p, top_k, keys)
+        self.phase.to("prefill_build")
         # persist advanced PRNG keys only for lanes that really sampled
         if last_lanes:
             la = jnp.asarray([int(idxs[l]) for l in last_lanes])
@@ -1743,6 +1893,7 @@ class EngineCore:
         round-trip and keep results only for lanes whose prompt completed.
         Returns True if a dispatch ran."""
         cfg = self.cfg
+        self.phase.to("prefill_build")
         work = []  # (slot_idx, slot, start, count, is_last)
         for i, slot in chunks:
             prompt = slot.prompt
@@ -1835,14 +1986,22 @@ class EngineCore:
                 "last_lanes": last_lanes, "mm": bool(mm_arrays),
             }, arrays)
         t_disp = time.perf_counter()
+        for _, slot, _, _, _ in work:
+            slot.chunks += 1
         packed = self._run_prefill_program(
             Bp, C, S, tokens, positions, write_idx, read_idx, read_pos,
             read_valid, last_i, temp, top_p, top_k, idxs, last_lanes,
             mm_arrays=mm_arrays)
+        self.stage.engine_dispatches.inc("prefill")
+        self.stage.engine_dispatch_tokens.inc(
+            "prefill", amount=float(sum(w[3] for w in work)))
 
+        self.phase.to("prefill_fetch")
         # dynalint: ok(host-sync) THE designed prefill fetch: one packed
         # [Bp,2] (token,logprob) array per dispatch, batched across lanes
         packed_np = np.asarray(packed)            # ONE host fetch
+        self.phase.to("emit")
+        now = time.monotonic()
         if not self._take_compiled_flag():
             from ..utils.roofline import prefill_cost
 
@@ -1865,10 +2024,15 @@ class EngineCore:
                 self._free_slot(i)
                 continue
             slot.cum_logprob += lp
+            slot.t_first_token = now
+            self._request_stage(
+                slot, "prefill", slot.t_admitted, now,
+                prompt_tokens=len(slot.prompt),
+                prefix_hit_tokens=slot.prefix_hit, chunks=slot.chunks)
             fin = self._finish_reason(slot, t)
             out.append(StepOutput(slot.seq_id, t, slot.cum_logprob, fin,
                                   prompt_tokens=len(slot.prompt),
-                                  token_logprob=lp))
+                                  token_logprob=lp, first_token_at=now))
             if fin is not None:
                 self._free_slot(i)
         return True
@@ -1959,6 +2123,7 @@ class EngineCore:
         """Enqueue one multi-step decode dispatch WITHOUT fetching results.
         If a dispatch is already in flight, chain off its on-device token
         and key arrays (no host data dependency)."""
+        self.phase.to("decode_build")
         B = self.cfg.max_batch
         N = self.cfg.decode_steps
         chain = bool(self._inflight)
@@ -2010,6 +2175,9 @@ class EngineCore:
             self.dispatch_hook("decode", {"S": S, "chain": chain}, payload)
         packed, final_tok = self._run_decode_program(
             S, tokens, page_tables, lengths, fresh, active_mask)
+        self.stage.engine_dispatches.inc("decode")
+        self.stage.engine_dispatch_tokens.inc(
+            "decode", amount=float(len(active) * N))
         self._inflight.append({"packed": packed, "final_tok": final_tok,
                                "active": active,
                                "lengths": [phys for _, _, phys in active],
@@ -2039,12 +2207,13 @@ class EngineCore:
             tokens = global_put(tokens, self._rep_sharding)
         s = self.sampling
         fn = self._decode_fn(S)
-        with _trace_annotation(f"dynamo.decode[S{S}]"):
-            (packed, final_tok, new_key, self.k_pool, self.v_pool,
-             self.gen_counts) = fn(
-                self.params, tokens, self.k_pool, self.v_pool,
-                page_tables, lengths, s.temperature, s.top_p, s.top_k, s.key,
-                self.gen_counts, fresh, active_mask, s.freq_pen, s.pres_pen)
+        self.phase.to("decode", f"dynamo.decode[S{S}]")
+        (packed, final_tok, new_key, self.k_pool, self.v_pool,
+         self.gen_counts) = fn(
+            self.params, tokens, self.k_pool, self.v_pool,
+            page_tables, lengths, s.temperature, s.top_p, s.top_k, s.key,
+            self.gen_counts, fresh, active_mask, s.freq_pen, s.pres_pen)
+        self.phase.to("decode_build")
         s.key = new_key
         self._last_final_tok = final_tok
         return packed, final_tok
@@ -2058,13 +2227,14 @@ class EngineCore:
         leader and on follower mirrors (multi-host lockstep)."""
         s = self.sampling
         fn = self._verify_fn(S, K)
-        with _trace_annotation(f"dynamo.verify[S{S},K{K}]"):
-            (packed, new_key, self.k_pool, self.v_pool,
-             self.gen_counts) = fn(
-                self.params, tokens, self.k_pool, self.v_pool, page_tables,
-                lengths, s.temperature, s.top_p, s.top_k, s.key,
-                self.gen_counts, fresh, active_mask, s.freq_pen, s.pres_pen,
-                upd_tok, upd_mask)
+        self.phase.to("verify", f"dynamo.verify[S{S},K{K}]")
+        (packed, new_key, self.k_pool, self.v_pool,
+         self.gen_counts) = fn(
+            self.params, tokens, self.k_pool, self.v_pool, page_tables,
+            lengths, s.temperature, s.top_p, s.top_k, s.key,
+            self.gen_counts, fresh, active_mask, s.freq_pen, s.pres_pen,
+            upd_tok, upd_mask)
+        self.phase.to("decode_build")
         s.key = new_key
         return packed
 
@@ -2103,6 +2273,7 @@ class EngineCore:
         from .sampling import spec_accept, spec_unpack
 
         cfg, sp = self.cfg, self.spec
+        self.phase.to("decode_build")
         B = cfg.max_batch
         # reserve the whole verify window (k drafts + bonus) up front:
         # rollback is then pure bookkeeping, never data movement
@@ -2165,9 +2336,15 @@ class EngineCore:
         packed = self._run_verify_program(
             S, K, tokens, page_tables, lengths, fresh, active_mask,
             upd_tok, upd_mask)
+        self.stage.engine_dispatches.inc("verify")
+        self.stage.engine_dispatch_tokens.inc(
+            "verify", amount=float(sum(len(d) + 1 for d in drafts.values())))
+        self.phase.to("decode_fetch")
         # dynalint: ok(host-sync) THE designed verify fetch: one packed
         # array per verify dispatch covers k+1 positions for every lane
-        r = spec_unpack(np.asarray(packed), K)      # ONE host fetch
+        packed_np = np.asarray(packed)              # ONE host fetch
+        self.phase.to("emit")
+        r = spec_unpack(packed_np, K)
         if not self._take_compiled_flag():
             from ..utils.roofline import verify_cost
 
@@ -2250,14 +2427,19 @@ class EngineCore:
                 arrs["upd_tok"], arrs["upd_mask"])
         else:
             raise ValueError(f"unknown dispatch kind {kind!r}")
+        # a follower has no loop of phases: what it does between two
+        # replayed dispatches (waiting for the leader) is not a phase
+        self.phase.close()
 
     def _process_oldest_inflight(self) -> List[StepOutput]:
         """Fetch (blocking) and account the oldest in-flight dispatch."""
         rec = self._inflight.popleft()
+        self.phase.to("decode_fetch")
         # dynalint: ok(host-sync) THE designed decode fetch: one [N,B,2]
         # array per N-step dispatch — 1/N host round-trips per token, and
         # the pipelined next dispatch is already running when we block here
         packed_np = np.asarray(rec["packed"])     # [N, B, 2] — ONE fetch
+        self.phase.to("emit")
         N = packed_np.shape[0]
         if N and "dispatched_at" in rec:
             # effective per-token decode latency: dispatch -> results on
@@ -2370,6 +2552,95 @@ def _gguf_file(path: str) -> Optional[str]:
 # Async facade
 # ---------------------------------------------------------------------------
 
+class _ProfileCapture:
+    """``DYN_PROFILE_DIR``: an XLA profile of the first ``DYN_PROFILE_STEPS``
+    (default 32) working engine iterations, driven from the engine thread.
+    The phase annotations name the host side of the device timeline. The
+    Python tracer is off (it slows the host it measures), and the capture
+    is stopped and written by a thread of its own, which takes about a
+    second a megabyte, so the engine goes on dispatching meanwhile. At
+    start and at stop the engine thread emits
+    ``dyn.clock[epoch_ns=..,mono_ns=..]``, which pins ``time.time()``
+    (request-trace spans) and ``time.perf_counter()`` to the capture's
+    clock."""
+
+    def __init__(self) -> None:
+        self.dir = os.environ.get("DYN_PROFILE_DIR")
+        try:
+            self.steps = int(os.environ.get("DYN_PROFILE_STEPS", "32"))
+        except ValueError:
+            # a typo'd env var must not kill the engine thread
+            log.warning("invalid DYN_PROFILE_STEPS=%r; using 32",
+                        os.environ.get("DYN_PROFILE_STEPS"))
+            self.steps = 32
+        self.active = False
+        # stop() is the engine thread's and, at shutdown, the closing
+        # thread's too: one of them ends the capture
+        self._lock = threading.Lock()
+        self._writer: Optional[threading.Thread] = None
+
+    @staticmethod
+    def _clock_anchor() -> None:
+        with _trace_annotation(f"dyn.clock[epoch_ns={time.time_ns()},"
+                               f"mono_ns={time.perf_counter_ns()}]"):
+            pass
+
+    def before_step(self) -> None:
+        if not self.dir or self.active or self.steps <= 0:
+            return
+        with self._lock:
+            try:
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(self.dir, profiler_options=options)
+            except Exception:
+                log.exception("DYN_PROFILE_DIR capture failed to start")
+                self.dir = None
+                return
+            self.active = True
+            self._clock_anchor()
+        log.info("XLA profile capture started -> %s", self.dir)
+
+    def after_step(self) -> None:
+        if self.active:
+            self.steps -= 1
+            if self.steps <= 0:
+                self.stop()
+
+    def stop(self) -> None:
+        """End the capture; a writer thread does the slow part."""
+        with self._lock:
+            if not self.active:
+                return
+            self.active = False
+            self._clock_anchor()
+            self._writer = threading.Thread(
+                target=self._write, args=(self.dir,),
+                name="jax-profile-writer", daemon=True)
+            self.dir = None
+            self._writer.start()
+
+    @staticmethod
+    def _write(directory: str) -> None:
+        t0 = time.perf_counter()
+        try:
+            jax.profiler.stop_trace()
+            log.info("XLA profile capture written to %s in %.2fs",
+                     directory, time.perf_counter() - t0)
+        except Exception:
+            log.exception("stopping XLA profile failed")
+
+    def close(self, timeout: float = 120.0) -> None:
+        """Shutdown: JAX only writes trace files on ``stop_trace``, so a
+        capture cut short is still finalized, and a writer is waited for."""
+        self.stop()
+        if self._writer is not None:
+            self._writer.join(timeout)
+            if self._writer.is_alive():
+                log.warning("XLA profile capture still being written "
+                            "after %.0fs", timeout)
+
+
 class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
     """AsyncEngine facade: one background engine thread runs EngineCore."""
 
@@ -2386,6 +2657,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
         self._inbox: thread_queue.Queue = thread_queue.Queue()
         self._wake = threading.Event()
         self._running = True
+        self._capture = _ProfileCapture()
         self._thread = threading.Thread(target=self._run, name="jax-engine",
                                         daemon=True)
         self._thread.start()
@@ -2395,31 +2667,21 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
         from ..utils.prometheus import stage_metrics
 
         stage = stage_metrics()
-        # DYN_PROFILE_DIR: capture an XLA profile of the first
-        # DYN_PROFILE_STEPS (default 32) working engine iterations — the
-        # TraceAnnotation scopes around prefill/decode dispatches name the
-        # device timeline so it lines up with host-side request spans.
-        profile_dir = os.environ.get("DYN_PROFILE_DIR")
-        try:
-            profile_steps = int(os.environ.get("DYN_PROFILE_STEPS", "32"))
-        except ValueError:
-            # a typo'd env var must not kill the engine thread
-            log.warning("invalid DYN_PROFILE_STEPS=%r; using 32",
-                        os.environ.get("DYN_PROFILE_STEPS"))
-            profile_steps = 32
-        profiling = False
+        capture = self._capture
+        # every moment of this loop belongs to one phase (see PHASES); the
+        # core switches between its own inside step()
+        phase = self.core.phase
         last_gauges = 0.0
         last_disp = 0
         while self._running:
-            moved = False
+            phase.to("inbox")
             while True:
                 try:
                     kind, seq_id, payload = self._inbox.get_nowait()
                 except thread_queue.Empty:
                     break
-                moved = True
                 if kind == "submit":
-                    self.core.submit(seq_id, payload)
+                    self.core.submit(seq_id, *payload)
                 elif kind == "cancel":
                     self.core.cancel(seq_id)
                 elif kind == "inject":
@@ -2463,6 +2725,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                     except Exception as e:
                         log.exception("prefill_extract failed")
                         loop.call_soon_threadsafe(_set_exception, fut, e)
+                    phase.to("inbox")   # the prefill moved through its own
                 elif kind == "swap":
                     # model-mobility hot-swap: runs on the engine thread
                     # (single-threaded core contract) post-drain; typed
@@ -2481,19 +2744,13 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                 now = time.monotonic()
                 if now - last_gauges >= 5.0:
                     last_gauges = now
+                    phase.to("housekeeping")
                     self._set_goodput_gauges(stage)
+                phase.to("idle")
                 self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 continue
-            if profile_dir and not profiling and profile_steps > 0:
-                try:
-                    jax.profiler.start_trace(profile_dir)
-                    profiling = True
-                    log.info("XLA profile capture started -> %s",
-                             profile_dir)
-                except Exception:
-                    log.exception("DYN_PROFILE_DIR capture failed to start")
-                    profile_dir = None
+            capture.before_step()
             try:
                 outs = self.core.step()
             except Exception as e:  # engine must never die silently
@@ -2504,6 +2761,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                 for sid in list(self.core.by_seq):
                     self.core.cancel(sid)
                 self.core._reap_cancelled()
+            phase.to("housekeeping")
             stage.batch_occupancy.set(str(os.getpid()),
                                       value=self.core.active)
             # goodput gauges: refresh once dispatches have actually been
@@ -2516,17 +2774,8 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                                       or not self.core.has_work):
                 last_gauges, last_disp = now, disp
                 self._set_goodput_gauges(stage)
-            if profiling:
-                profile_steps -= 1
-                if profile_steps <= 0:
-                    try:
-                        jax.profiler.stop_trace()
-                        log.info("XLA profile capture written to %s",
-                                 profile_dir)
-                    except Exception:
-                        log.exception("stopping XLA profile failed")
-                    profiling = False
-                    profile_dir = None
+            capture.after_step()
+            phase.to("deliver")
             for so in outs:
                 try:
                     self._deliver(so)
@@ -2534,16 +2783,11 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                     log.exception("failed to deliver step output")
             if not outs and not self.core.by_seq:
                 # waiting requests that can't be admitted yet: don't busy-spin
+                phase.to("idle")
                 self._wake.wait(timeout=0.02)
                 self._wake.clear()
-        if profiling:
-            # shutdown before DYN_PROFILE_STEPS working iterations: JAX only
-            # writes trace files on stop_trace, so finalize the short capture
-            try:
-                jax.profiler.stop_trace()
-                log.info("XLA profile capture written to %s", profile_dir)
-            except Exception:
-                log.exception("stopping XLA profile failed")
+        phase.close()
+        capture.stop()      # cut short by shutdown(), which waits for it
 
     def _set_goodput_gauges(self, stage) -> None:
         pid = str(os.getpid())
@@ -2645,6 +2889,15 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
         self._loop = asyncio.get_running_loop()
         seq_id = context.id
         self._queues[seq_id] = asyncio.Queue()
+        submitted = time.monotonic()
+        received = context.stamps.get("received")
+        if received is not None:
+            self.core.stage.request_stage.observe(
+                "pre_engine", value=submitted - received)
+        if kind == "submit":
+            # the engine thread has no context: it hangs the request's
+            # engine spans under the span that is current here
+            payload = (payload, submitted, _tracing.current_span_var.get())
         self._inbox.put((kind, seq_id, payload))
         self._wake.set()
         async for out in self._consume(seq_id, context):
@@ -2680,6 +2933,8 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                                        error_reason=so.error_reason)
                     return
                 ingest_fallback = False   # tokens flowed: no fallback
+                if so.first_token_at is not None:
+                    context.stamps["first_token"] = so.first_token_at
                 yield EngineOutput(
                     token_ids=[so.token],
                     cum_log_prob=so.logprob,
@@ -2739,6 +2994,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
         self._running = False
         self._wake.set()
         self._thread.join(timeout=5)
+        self._capture.close()
         # disk-tier spill files are scratch state: flush + unlink them
         # with the engine (next to the metrics-key cleanup) instead of
         # leaking two pool-sized memmaps per engine lifetime

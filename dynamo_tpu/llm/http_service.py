@@ -127,9 +127,6 @@ class HttpService:
         self.m_duration = m.histogram(
             "dyn_http_request_duration_seconds", "Request duration",
             ("model", "endpoint"))
-        self.m_ttft = m.histogram(
-            "dyn_http_time_to_first_token_seconds", "Time to first streamed token",
-            ("model",))
         self.m_tokens = m.counter(
             "dyn_http_output_tokens_total", "Completion tokens produced", ("model",))
         self._runner: Optional[web.AppRunner] = None
@@ -485,6 +482,7 @@ class HttpService:
         # envelope; expiry anywhere surfaces as a 504 naming the stage.
         # The priority class rides the same envelope.
         ctx = Context(deadline=dl.from_timeout(timeout), priority=priority)
+        ctx.stamps["received"] = started
         # request-id span: every log line in this async call chain (and in
         # remote workers via the wire context_id) carries ctx.id
         from ..utils.logging_ext import request_id_var
@@ -503,7 +501,7 @@ class HttpService:
             if oai_req.stream:
                 try:
                     resp = await self._stream(req, engine, oai_req, ctx,
-                                              model_name, endpoint, started)
+                                              model_name, endpoint)
                 except (ConnectionResetError, asyncio.CancelledError):
                     status = "499"   # client closed mid-stream
                     raise
@@ -528,8 +526,7 @@ class HttpService:
                         status = "400"
                         return _err(400, ch["error"]["message"], ctx.id)
                     if first:
-                        self.stage.ttft.observe(
-                            model_name, value=time.monotonic() - started)
+                        self._first_chunk(model_name, ctx, time.monotonic())
                         first = False
                     chunks.append(ch)
                     u = ch.get("usage")
@@ -554,6 +551,16 @@ class HttpService:
             self._count(model_name, endpoint, status, tenant)
             self.m_duration.observe(model_name, endpoint,
                                     value=time.monotonic() - started)
+
+    def _first_chunk(self, model: str, ctx: Context, now: float) -> None:
+        """The first chunk is about to be written: time to first token, and
+        its last stage, from the first token on the engine's host side to
+        here (a remote engine's clock is not ours: no stamp, no stage)."""
+        self.stage.ttft.observe(model, value=now - ctx.stamps["received"])
+        first_token = ctx.stamps.get("first_token")
+        if first_token is not None:
+            self.stage.request_stage.observe("post_engine",
+                                             value=now - first_token)
 
     def _engine_for(self, model_name: str,
                     endpoint: str) -> Optional[AsyncEngine]:
@@ -615,8 +622,8 @@ class HttpService:
             retry_after=2.0)
 
     async def _stream(self, req: web.Request, engine: AsyncEngine, oai_req,
-                      ctx: Context, model: str, endpoint: str,
-                      started: float) -> web.StreamResponse:
+                      ctx: Context, model: str,
+                      endpoint: str) -> web.StreamResponse:
         agen = engine.generate(oai_req, ctx)
         # Pull the first item BEFORE committing the 200/SSE response so that
         # preprocessing failures (context overflow, bad template) still map to
@@ -674,9 +681,7 @@ class HttpService:
                     continue
                 now = time.monotonic()
                 if first:
-                    ttft = now - started
-                    self.m_ttft.observe(model, value=ttft)
-                    stage.ttft.observe(model, value=ttft)
+                    self._first_chunk(model, ctx, now)
                     first = False
                 elif last_chunk_at is not None:
                     stage.inter_token.observe(model,

@@ -526,9 +526,10 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         interpret = not on_tpu()
     if paged_kernel_variant(interpret) == "dma":
         q4 = q.reshape(B, Hkv, G, Dh)
-        # DMA depth knob for on-chip tuning sweeps (perf_probe) — larger
-        # blocks amortize DMA issue latency, smaller ones cut the tail
-        # wasted on the final partial block. Validated like the sibling
+        # DMA depth knob for on-chip tuning sweeps (read the kernel's time
+        # in a traced benchmark run's ops_by_module) — larger blocks
+        # amortize DMA issue latency, smaller ones cut the tail wasted on
+        # the final partial block. Validated like the sibling
         # DYNAMO_TPU_PAGED_KERNEL knob: a typo must fail loudly, not
         # surface as a ZeroDivisionError deep in the grid math.
         raw_ppb = os.environ.get("DYNAMO_TPU_PAGED_PPB", "8")

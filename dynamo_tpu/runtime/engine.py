@@ -16,7 +16,8 @@ from __future__ import annotations
 import asyncio
 import contextlib
 import uuid
-from typing import Any, AsyncIterator, Awaitable, Callable, Generic, Optional, TypeVar
+from typing import (Any, AsyncIterator, Awaitable, Callable, Dict, Generic,
+                    Optional, TypeVar)
 
 Req = TypeVar("Req")
 Resp = TypeVar("Resp")
@@ -34,8 +35,8 @@ class Context:
     Reference capability: ``AsyncEngineContext`` (lib/runtime/src/engine.rs:71-109).
     """
 
-    __slots__ = ("id", "deadline", "priority", "resume_no", "_stopped",
-                 "_killed", "_children")
+    __slots__ = ("id", "deadline", "priority", "resume_no", "stamps",
+                 "_stopped", "_killed", "_children")
 
     def __init__(self, id: Optional[str] = None,
                  deadline: Optional[float] = None,
@@ -53,6 +54,12 @@ class Context:
         # rides the wire envelope too — shedding and queue ordering at
         # every stage strictly prefer interactive
         self.priority: str = priority
+        # where the request was when, on this process's time.monotonic():
+        # "received" (frontend) and "first_token" (engine, on the host).
+        # The stages of llm_request_stage_seconds on either side of the
+        # engine are measured from them; shared with child contexts, never
+        # sent over the wire (another process has another clock)
+        self.stamps: Dict[str, float] = {}
         self._stopped = asyncio.Event()
         self._killed = asyncio.Event()
         self._children: list["Context"] = []
@@ -89,6 +96,7 @@ class Context:
         deadline is inherited — a sub-call cannot outlive its request)."""
         c = Context(id or self.id, deadline=self.deadline,
                     priority=self.priority)
+        c.stamps = self.stamps
         if self.is_killed:
             c.kill()
         elif self.is_stopped:

@@ -384,6 +384,16 @@ class StageMetrics:
         self.inter_token = r.histogram(
             "llm_inter_token_seconds", "Gap between streamed tokens",
             ("model",), buckets=LATENCY_BUCKETS_FAST)
+        # llm_ttft_seconds split where the request changes hands:
+        # pre_engine (received -> submitted to the engine), queue +
+        # lane_wait (-> admitted to a slot; lane_wait is the part of that
+        # wait with a slot free and every prefill lane taken), prefill (->
+        # first token on the host), post_engine (-> first chunk written).
+        # One monotonic clock, so the five add up to the same request's TTFT
+        self.request_stage = r.histogram(
+            "llm_request_stage_seconds",
+            "A request's stages on the way to its first token", ("stage",),
+            buckets=LATENCY_BUCKETS_WIDE)
         self.queue_wait = r.histogram(
             "llm_prefill_queue_wait_seconds",
             "Remote prefill job wait in the shared queue", (),
@@ -481,6 +491,29 @@ class StageMetrics:
         self.compiled_programs = r.counter(
             "dyn_compiled_programs",
             "Bucket programs compiled", ("kind",))   # prefill|decode|verify|draft
+        # ...and every XLA compile of the process, the lazily built helper
+        # programs included, as jax.monitoring reports them; a load from
+        # the persistent cache is not a compile (roofline.count_xla_compiles)
+        self.xla_compiles = r.counter(
+            "dyn_xla_compiles_total",
+            "XLA backend compiles in this process (persistent-cache loads "
+            "not counted)", ())
+        self.xla_compile_seconds = r.counter(
+            "dyn_xla_compile_seconds_total",
+            "Wall seconds in those compiles", ())
+        # engine loop: where the engine thread's time goes, and what it
+        # dispatched (engine.PHASES; the *_fetch phases are the thread
+        # blocked on the device, idle is the thread with nothing to do)
+        self.engine_phase_seconds = r.counter(
+            "dyn_engine_phase_seconds_total",
+            "Engine-thread wall seconds by loop phase", ("phase",))
+        self.engine_dispatches = r.counter(
+            "dyn_engine_dispatches_total",
+            "Device dispatches enqueued by the engine", ("kind",))
+        self.engine_dispatch_tokens = r.counter(
+            "dyn_engine_dispatch_tokens_total",
+            "Token positions computed by those dispatches (prompt tokens "
+            "of the chunks; active lanes x steps)", ("kind",))
         # model-mobility plane (fleet/mobility/): weight prefetch + hot
         # swap — a swap that recompiles or silently reloads cold defeats
         # the seconds-scale wake contract, so both are first-class series

@@ -380,6 +380,52 @@ def record_compile(kind: str, seconds: float) -> None:
     sm.compiled_programs.inc(kind)
 
 
+_xla_compiles_counted = False
+
+
+def count_xla_compiles() -> None:
+    """Count every XLA compile of this process, not only the bucket
+    programs ``record_compile`` sees: ``jax.monitoring`` listeners feed
+    ``dyn_xla_compiles_total`` / ``dyn_xla_compile_seconds_total``. JAX
+    reports ``backend_compile_duration`` around "compile or load from the
+    persistent cache", with a ``cache_hits`` event first on the same thread
+    when it was a load: a load is not counted. Registered once per process,
+    where an engine is built; listeners cannot be taken back singly."""
+    global _xla_compiles_counted
+    if _xla_compiles_counted:
+        return
+    _xla_compiles_counted = True
+    import threading
+
+    import jax.monitoring
+
+    from .prometheus import stage_metrics
+
+    served_from_cache = threading.local()
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            served_from_cache.hit = True
+
+    def on_duration(event: str, seconds: float, **_kw) -> None:
+        if event != "/jax/core/compile/backend_compile_duration":
+            return
+        if getattr(served_from_cache, "hit", False):
+            served_from_cache.hit = False
+            return
+        sm = stage_metrics()
+        sm.xla_compiles.inc()
+        sm.xla_compile_seconds.inc(amount=seconds)
+
+    # a process that has compiled nothing says 0, not nothing: a reader of
+    # two scrapes has to tell "no compile" from "no such counter"
+    for counter in (stage_metrics().xla_compiles,
+                    stage_metrics().xla_compile_seconds):
+        counter.inc(amount=0.0)
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
 def instrument_compile(kind: str, fn: Callable,
                        on_compile: Callable[[str, float], None]) -> Callable:
     """Wrap a freshly-built jitted program so its FIRST call — the one that

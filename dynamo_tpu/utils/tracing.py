@@ -39,6 +39,7 @@ import hashlib
 import json
 import logging
 import os
+import random
 import threading
 import time
 import uuid
@@ -114,7 +115,11 @@ class Span:
 
 
 def _new_span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    # not uuid4: os.urandom lets go of the GIL, and a thread that records a
+    # span while another wants the GIL (the engine thread, beside an event
+    # loop streaming tokens) then waits up to a switch interval for it, on
+    # every span. Ids have to be unique, not secret
+    return "%016x" % random.getrandbits(64)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,7 @@ from dynamo_tpu.llm.protocols.common import (
     StopConditions,
 )
 from dynamo_tpu.models import llama
+from dynamo_tpu.runtime.engine import Context
 
 
 def make_cfg(**kw):
@@ -45,6 +46,15 @@ def drain(core, want_seqs):
 @pytest.fixture(scope="module")
 def core():
     return EngineCore(make_cfg())
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """One ``JaxEngine`` (engine thread and all) for every test that needs
+    the async facade: its programs compile once."""
+    eng = JaxEngine(make_cfg(max_batch=2))
+    yield eng
+    eng.shutdown()
 
 
 def test_greedy_generate_and_finish(core):
@@ -155,18 +165,13 @@ def test_tp2_matches_tp1():
     assert t1 == t2
 
 
-async def test_async_facade():
-    eng = JaxEngine(make_cfg(max_batch=2))
-    try:
-        outs = []
-        async for o in eng.generate(req([70, 71, 72], max_tokens=4),
-                                    __import__("dynamo_tpu.runtime.engine",
-                                               fromlist=["Context"]).Context()):
-            outs.append(o)
-        assert sum(len(o.token_ids) for o in outs) == 4
-        assert outs[-1].finish_reason == FinishReason.LENGTH
-    finally:
-        eng.shutdown()
+async def test_async_facade(engine):
+    outs = []
+    async for o in engine.generate(req([70, 71, 72], max_tokens=4),
+                                   Context()):
+        outs.append(o)
+    assert sum(len(o.token_ids) for o in outs) == 4
+    assert outs[-1].finish_reason == FinishReason.LENGTH
 
 
 def test_unservable_prompt_rejected_not_starved():
@@ -372,7 +377,7 @@ def test_long_context_over_8k():
     # contexts run at all (pages, chunk loop, position handling)
 
 
-async def test_logprobs_flow_to_openai_responses():
+async def test_logprobs_flow_to_openai_responses(engine):
     """Sampled-token logprobs must reach both OpenAI response shapes:
     completions (tokens/token_logprobs arrays) and chat (content entries)."""
     from dynamo_tpu.llm.model_card import ModelDeploymentCard
@@ -384,34 +389,30 @@ async def test_logprobs_flow_to_openai_responses():
         aggregate_chat_chunks,
         aggregate_completion_chunks,
     )
-    from dynamo_tpu.runtime.engine import Context, collect
+    from dynamo_tpu.runtime.engine import collect
 
-    eng = JaxEngine(make_cfg(max_batch=2))
-    try:
-        card = ModelDeploymentCard(name="m")
-        comp = build_completion_engine(card, "core", eng)
-        req = CompletionRequest.from_dict({
-            "model": "m", "prompt": "abcd", "max_tokens": 4, "logprobs": 1})
-        chunks = await collect(comp.generate(req, Context()))
-        agg = aggregate_completion_chunks([c for c in chunks
-                                           if "event" not in c])
-        lp = agg["choices"][0]["logprobs"]
-        assert lp is not None
-        assert len(lp["tokens"]) == len(lp["token_logprobs"]) == 4
-        assert all(v <= 0.0 for v in lp["token_logprobs"])
+    card = ModelDeploymentCard(name="m")
+    comp = build_completion_engine(card, "core", engine)
+    req = CompletionRequest.from_dict({
+        "model": "m", "prompt": "abcd", "max_tokens": 4, "logprobs": 1})
+    chunks = await collect(comp.generate(req, Context()))
+    agg = aggregate_completion_chunks([c for c in chunks
+                                       if "event" not in c])
+    lp = agg["choices"][0]["logprobs"]
+    assert lp is not None
+    assert len(lp["tokens"]) == len(lp["token_logprobs"]) == 4
+    assert all(v <= 0.0 for v in lp["token_logprobs"])
 
-        chat = build_chat_engine(card, "core", eng)
-        creq = ChatCompletionRequest.from_dict({
-            "model": "m", "messages": [{"role": "user", "content": "hi"}],
-            "max_tokens": 4, "logprobs": True})
-        cchunks = await collect(chat.generate(creq, Context()))
-        cagg = aggregate_chat_chunks([c for c in cchunks
-                                      if "event" not in c])
-        content = cagg["choices"][0]["logprobs"]["content"]
-        assert len(content) > 0
-        assert all("token" in e and e["logprob"] <= 0.0 for e in content)
-    finally:
-        eng.shutdown()
+    chat = build_chat_engine(card, "core", engine)
+    creq = ChatCompletionRequest.from_dict({
+        "model": "m", "messages": [{"role": "user", "content": "hi"}],
+        "max_tokens": 4, "logprobs": True})
+    cchunks = await collect(chat.generate(creq, Context()))
+    cagg = aggregate_chat_chunks([c for c in cchunks
+                                  if "event" not in c])
+    content = cagg["choices"][0]["logprobs"]["content"]
+    assert len(content) > 0
+    assert all("token" in e and e["logprob"] <= 0.0 for e in content)
 
 
 def test_tp2_vocab_sharded_head_matches_tp1():
@@ -524,3 +525,296 @@ def test_penalties_zero_is_noop():
                                  presence_penalty=0.0)))
     b = [g.token for g in drain(core, ["b"])["b"]]
     assert a == b
+
+
+# ----------------------------------------------------------------------
+# what the engine says about itself: loop phases, dispatch counters, a
+# request's stages and spans, compiles, the profiler hook. All on the
+# module's ``core`` / ``engine``: nothing here compiles a bucket program.
+# ----------------------------------------------------------------------
+def _counter_values(counter, labels):
+    return {l: counter.get(l) for l in labels}
+
+
+def test_engine_phases_never_nest_and_cover_the_loop(core, monkeypatch):
+    import time
+
+    from dynamo_tpu.engine import engine as eng_mod
+
+    import threading
+
+    open_scopes, seen, me = [], [], threading.get_ident()
+
+    class Scope:
+        """Stands in for TraceAnnotation; only this thread's scopes count
+        (the module's ``engine`` idles through its own phases beside us)."""
+
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            if threading.get_ident() == me:
+                assert not open_scopes, (self.name, "inside", open_scopes)
+                open_scopes.append(self.name)
+                seen.append(self.name)
+
+        def __exit__(self, *exc):
+            if threading.get_ident() == me:
+                assert open_scopes.pop() == self.name
+
+    monkeypatch.setattr(eng_mod, "_trace_annotation", Scope)
+    seconds = core.stage.engine_phase_seconds
+    core.phase.close()          # whatever an earlier test left open
+    before = _counter_values(seconds, eng_mod.PHASES)
+    t0 = time.perf_counter()
+    core.submit("ph1", req(list(range(1, 71)), max_tokens=9))   # 3 chunks
+    core.submit("ph2", req([3, 4, 5], max_tokens=5))
+    drain(core, ["ph1", "ph2"])
+    core.phase.close()
+    wall = time.perf_counter() - t0
+    assert not open_scopes
+    # the counter is the process's: leave out the phases that only a
+    # JaxEngine's own loop enters (the module's ``engine`` idles beside us)
+    spent = {p: seconds.get(p) - before[p] for p in eng_mod.PHASES
+             if p not in ("inbox", "deliver", "idle")}
+    assert sum(spent.values()) == pytest.approx(wall, rel=0.05)
+    # the core's own phases all ran; the enqueue scopes kept their names
+    for p in ("admit", "prefill_build", "prefill", "prefill_fetch",
+              "decode_build", "decode", "decode_fetch", "emit"):
+        assert spent[p] > 0, p
+    assert {n.split("[")[0] for n in seen} <= {
+        "dynamo." + p for p in eng_mod.PHASES}
+    assert any(n.startswith("dynamo.prefill[B") for n in seen)
+    assert any(n.startswith("dynamo.decode[S") for n in seen)
+
+
+def test_engine_dispatch_and_token_counters(core, monkeypatch):
+    n, tokens = core.stage.engine_dispatches, core.stage.engine_dispatch_tokens
+    kinds = ("prefill", "decode", "verify")
+    n0, t0 = _counter_values(n, kinds), _counter_values(tokens, kinds)
+    decodes = []
+    run = core._run_decode_program
+    monkeypatch.setattr(core, "_run_decode_program",
+                        lambda *a: decodes.append(1) or run(*a))
+    # a prompt no earlier test left in the prefix cache
+    core.submit("cnt", req(list(range(240, 170, -1)), max_tokens=9))
+    drain(core, ["cnt"])
+    while core.has_work:        # the overshoot dispatch behind the finish
+        core.step()
+    # 70 prompt tokens in chunks of 32: three dispatches, one lane each
+    assert n.get("prefill") - n0["prefill"] == 3
+    assert tokens.get("prefill") - t0["prefill"] == 70
+    assert n.get("decode") - n0["decode"] == len(decodes) > 0
+    assert tokens.get("decode") - t0["decode"] == (
+        len(decodes) * core.cfg.decode_steps)     # one active lane
+    assert n.get("verify") == n0["verify"]
+
+
+async def test_request_stages_add_up_to_ttft(engine):
+    """Through the real HTTP frontend: every stage counts once a request,
+    and the five sums are the server-side TTFT of the same requests."""
+    import aiohttp
+
+    from dynamo_tpu.llm.http_service import (HttpService, ModelManager,
+                                             ServedModel)
+    from dynamo_tpu.llm.model_card import ModelDeploymentCard
+    from dynamo_tpu.llm.pipeline import (build_chat_engine,
+                                         build_completion_engine)
+
+    card = ModelDeploymentCard(name="m")
+    manager = ModelManager()
+    manager.add(ServedModel(card, build_chat_engine(card, "core", engine),
+                            build_completion_engine(card, "core", engine)))
+    svc = HttpService(manager, host="127.0.0.1", port=0)
+    port = await svc.start()
+    hist = svc.stage.request_stage
+    stages = ("pre_engine", "queue", "lane_wait", "prefill", "post_engine")
+
+    def sums():
+        series = hist.state()["series"]
+        return ({s: series.get(s, {}).get("sum", 0.0) for s in stages},
+                {s: series.get(s, {}).get("total", 0) for s in stages})
+
+    def ttft():
+        series = svc.stage.ttft.state()["series"].get("m", {})
+        return series.get("sum", 0.0), series.get("total", 0)
+
+    try:
+        (sum0, cnt0), (ttft0, n0) = sums(), ttft()
+        async with aiohttp.ClientSession() as s:
+            for stream in (True, False):
+                body = {"model": "m", "prompt": "abcdefgh", "max_tokens": 4,
+                        "stream": stream}
+                async with s.post(f"http://127.0.0.1:{port}/v1/completions",
+                                  json=body) as r:
+                    assert r.status == 200
+                    await r.read()
+        (sum1, cnt1), (ttft1, n1) = sums(), ttft()
+        assert n1 - n0 == 2
+        assert {s: cnt1[s] - cnt0[s] for s in stages} == dict.fromkeys(
+            stages, 2)
+        assert sum(sum1[s] - sum0[s] for s in stages) == pytest.approx(
+            ttft1 - ttft0, abs=0.005)
+    finally:
+        await svc.stop()
+
+
+async def test_engine_spans_hang_under_the_submitting_span(engine,
+                                                           monkeypatch):
+    from dynamo_tpu.utils import tracing
+
+    tracer = tracing.get_tracer()
+    monkeypatch.setattr(tracer, "enabled", True)
+    queue_count = engine.core.stage.request_stage.get_count
+
+    async def run_one(rid):
+        with tracer.span("caller", trace_id=rid) as caller:
+            async for _ in engine.generate(
+                    req(list(range(1, 41)), max_tokens=5), Context(rid)):
+                pass
+        for _ in range(100):    # the decode span closes with the slot
+            if rid not in engine.core.by_seq:
+                break
+            await asyncio.sleep(0.01)
+        return caller
+
+    caller = await run_one("spans-on")
+    spans = {s.name: s for s in tracer.spans_for("spans-on")}
+    assert {"engine.queue", "engine.prefill", "engine.decode"} <= set(spans)
+    for name in ("engine.queue", "engine.prefill", "engine.decode"):
+        assert spans[name].trace_id == "spans-on"
+        assert spans[name].parent_id == caller.span_id
+    # nothing else was in prefill: none of the wait was for a lane
+    assert spans["engine.queue"].attrs == {"lane_wait_ms": 0.0}
+    assert spans["engine.prefill"].attrs == {
+        "prompt_tokens": 40, "prefix_hit_tokens": 0, "chunks": 2}
+    assert spans["engine.decode"].attrs["output_tokens"] >= 5
+    # back to back on one clock, inside the caller's span
+    assert spans["engine.queue"].end == pytest.approx(
+        spans["engine.prefill"].start, abs=1e-3)
+    assert caller.start <= spans["engine.queue"].start + 1e-3
+    assert spans["engine.prefill"].end <= caller.end + 1e-3
+
+    # DYN_TRACING=0 (the tracer's switch): no span, the histogram counts on
+    monkeypatch.setattr(tracer, "enabled", False)
+    n0 = queue_count("queue")
+    await run_one("spans-off")
+    assert queue_count("queue") == n0 + 1
+    monkeypatch.setattr(tracer, "enabled", True)
+    assert tracer.spans_for("spans-off") == []
+
+
+def test_lane_wait_is_the_wait_behind_another_prompt(core, monkeypatch):
+    """``lane_wait`` is the part of the wait for admission during which a
+    slot was free and the prefill lanes were taken: nothing for a request
+    that finds a lane, nearly all of it for one behind a three-chunk
+    prompt on a single lane."""
+    import time
+
+    from dynamo_tpu.engine.engine import _LaneClock
+    from dynamo_tpu.utils import tracing
+
+    clock = _LaneClock()
+    t = time.monotonic()
+    assert clock.read(t) == 0.0
+    clock.set(True)
+    clock.set(True)                 # told as often as it is evaluated
+    assert clock.read(t - 1.0) == 0.0      # before the lanes filled
+    assert clock.read(time.monotonic() + 2.0) >= 2.0
+    clock.set(False)
+    assert clock.read(time.monotonic() + 5.0) == clock.read(t) < 1.0
+
+    tracer = tracing.get_tracer()
+    monkeypatch.setattr(tracer, "enabled", True)
+    monkeypatch.setattr(core, "b_buckets", [1])     # one prefill lane
+    hist = core.stage.request_stage
+    n0 = {s: hist.get_count(s) for s in ("queue", "lane_wait")}
+    core.submit("lw1", req(list(range(200, 130, -1)), max_tokens=3))
+    core.submit("lw2", req([9, 8, 7], max_tokens=3))
+    drain(core, ["lw1", "lw2"])
+    while core.has_work:
+        core.step()
+    assert {s: hist.get_count(s) - n0[s] for s in n0} == {
+        "queue": 2, "lane_wait": 2}
+    first, second = (
+        next(s for s in tracer.spans_for(rid) if s.name == "engine.queue")
+        for rid in ("lw1", "lw2"))
+    assert first.attrs["lane_wait_ms"] == 0.0
+    waited_ms = 1e3 * (second.end - second.start)
+    # all but the iteration that admitted lw1, which saw the lane free
+    assert 0.5 * waited_ms < second.attrs["lane_wait_ms"] <= waited_ms + 1e-3
+    # lw2 got in only when lw1's prompt was through
+    lw1_prefill = next(s for s in tracer.spans_for("lw1")
+                       if s.name == "engine.prefill")
+    assert second.end >= lw1_prefill.end - 1e-3
+
+
+def test_xla_compile_listener_counts_a_program_once(core):
+    import jax
+    import jax.numpy as jnp
+
+    compiles = core.stage.xla_compiles     # EngineCore registered it
+    seconds = core.stage.xla_compile_seconds
+    x = jnp.arange(7.0)
+    fresh = jax.jit(lambda v: v * 3.0 + 11.0)   # no other test builds this
+    n0, s0 = compiles.get(), seconds.get()
+    fresh(x).block_until_ready()
+    assert compiles.get() == n0 + 1
+    assert seconds.get() > s0
+    fresh(x).block_until_ready()
+    assert compiles.get() == n0 + 1
+
+
+def test_profile_capture_python_tracer_off_and_stop_off_thread(
+        monkeypatch, tmp_path):
+    import threading
+
+    import jax
+
+    from dynamo_tpu.engine.engine import _ProfileCapture
+
+    calls = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, **kw: calls.append(("start", d, kw["profiler_options"])))
+    monkeypatch.setattr(
+        jax.profiler, "stop_trace",
+        lambda: calls.append(("stop", threading.current_thread().name)))
+    monkeypatch.setenv("DYN_PROFILE_DIR", str(tmp_path))
+    monkeypatch.setenv("DYN_PROFILE_STEPS", "2")
+    capture = _ProfileCapture()
+
+    def engine_thread():
+        for _ in range(3):          # the third iteration is past the capture
+            capture.before_step()
+            capture.after_step()
+
+    t = threading.Thread(target=engine_thread, name="jax-engine")
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    capture.close(timeout=10)
+    assert [c[0] for c in calls] == ["start", "stop"]
+    assert calls[0][1] == str(tmp_path)
+    assert calls[0][2].python_tracer_level == 0
+    assert calls[1][1] != "jax-engine"
+
+    # cut short by shutdown: close() still stops and waits for the writer
+    monkeypatch.setenv("DYN_PROFILE_STEPS", "50")
+    short = _ProfileCapture()
+    short.before_step()
+    short.after_step()
+    short.close(timeout=10)
+    assert [c[0] for c in calls[2:]] == ["start", "stop"]
+
+    # the engine thread's last stop() and shutdown's close() at once: one
+    # of them ends the capture, the other finds it ended
+    raced = _ProfileCapture()
+    raced.before_step()
+    both = [threading.Thread(target=raced.stop) for _ in range(2)]
+    for t in both:
+        t.start()
+    for t in both:
+        t.join(timeout=10)
+    raced.close(timeout=10)
+    assert [c[0] for c in calls[4:]] == ["start", "stop"]
