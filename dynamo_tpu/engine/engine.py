@@ -488,8 +488,12 @@ class EngineCore:
                  self.attn_impl, self.decode_attn_impl, self.paged_kernel,
                  dev0.platform, dev0.device_kind)
 
-        # --- KV pools (head-major: [L, Hkv, n_pages, page, Dh] so that
-        # pool[l] is directly the TPU paged-attention kernel layout) ----
+        # --- KV pools: [L, Hkv, n_pages, page, Dh], head-major, stored
+        # once in XLA's default tiled layout. The paged kernel reads the
+        # whole pool in place by layer index; every program writes and
+        # gathers it row-wise (head index spelt out), which keeps that
+        # layout — a window over [Hkv, ·, ·, Dh] makes XLA re-lay the whole
+        # pool at a program's entry and exit (PERF.md §6, PR 26) ----------
         kv_spec = llama.kv_cache_spec(m, cfg.tp, cfg.pp)
         self.kv_sharding = NamedSharding(self.mesh, kv_spec)
         pool_shape = (m.num_layers, m.num_kv_heads, num_pages,
@@ -879,6 +883,7 @@ class EngineCore:
 
             # pp microbatching: shared rule with forward_decode_pp
             M = llama.pp_microbatches(Bp, cfg.pp)
+            page = self.page_size
 
             @partial(jax.jit, donate_argnums=(3, 4),
                      out_shardings=(rep, rep, rep, kv, kv))
@@ -906,7 +911,11 @@ class EngineCore:
                         attn_impl="xla" if mm else impl, mesh=mesh,
                         logits_idx=last_i,
                         embed_override=((ov_vals, ov_mask) if mm else None),
-                        attn_spans=((q_span, read_span) if mm else None))
+                        attn_spans=((q_span, read_span) if mm else None),
+                        # read slots come from PagePool.read_slots: whole
+                        # pages in order (S is a page multiple), so the
+                        # context is gathered by page
+                        read_pages=read_idx[:, ::page] // page)
                 tok, logp, new_keys = sample(
                     logits[:, 0], temp, top_p, top_k, keys)
                 packed = jnp.stack([tok.astype(jnp.float32), logp], -1)
@@ -958,18 +967,16 @@ class EngineCore:
                 write_idx = (jnp.take_along_axis(page_tables, pos // page,
                                                  axis=1) * page + pos % page)
                 t = jnp.arange(S, dtype=jnp.int32)
-                rp = jnp.take_along_axis(
-                    page_tables,
-                    jnp.broadcast_to((t // page)[None], (B, S)), axis=1)
-                read_idx = rp * page + (t % page)[None]
                 read_pos = jnp.broadcast_to(t[None], (B, S))
                 # causality (read_pos <= position) masks the not-yet-written
                 # tail per query; validity only needs the max coverage
                 read_valid = t[None] < (lengths[:, None] + K)
+                # the context is the page table's pages, in order
                 logits, k_pool, v_pool = llama.forward(
                     params, cfg.model, tokens, pos, k_pool, v_pool,
-                    write_idx, read_idx, read_pos, read_valid,
-                    attn_impl=impl, mesh=mesh)          # [B, T, V]
+                    write_idx, None, read_pos, read_valid,
+                    attn_impl=impl, mesh=mesh,
+                    read_pages=page_tables)             # [B, T, V]
                 cf = counts.astype(jnp.float32)[:, None, :]
                 lg = (logits - freq_pen[:, None, None] * cf
                       - pres_pen[:, None, None]
@@ -2515,7 +2522,7 @@ def _pallas_probe(m, cfg, device) -> None:
                else [None])
     with jax.default_device(device):
         q = jnp.zeros((2, Hq, Dh), m.dtype)
-        kp = jnp.zeros((Hkv, 3, page, Dh), m.dtype)
+        kp = jnp.zeros((2, Hkv, 3, page, Dh), m.dtype)   # a pool of 2 layers
         pt = jnp.zeros((2, 1), jnp.int32)
         ln = jnp.ones((2,), jnp.int32)
         T = max(8, min(128, cfg.prefill_chunk))
@@ -2523,7 +2530,7 @@ def _pallas_probe(m, cfg, device) -> None:
         kf = jnp.zeros((2, T, Hkv, Dh), m.dtype)
         pos = jnp.zeros((2, T), jnp.int32)
         for w in windows:
-            paged_attention(q, kp, kp, pt, ln, interpret=False,
+            paged_attention(q, kp, kp, pt, ln, 1, interpret=False,
                             window=w, **kw).block_until_ready()
             flash_attention(qf, kf, kf, pos, pos, pos < 1, interpret=False,
                             window=w, **kw).block_until_ready()

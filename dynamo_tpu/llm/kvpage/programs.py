@@ -154,10 +154,10 @@ class PagedPrograms:
                 B, T = positions.shape
                 flat_w = write_idx.reshape(-1)
                 wp, wo = flat_w // page, flat_w % page
-                k_pool = k_pool.at[l, :, wp, wo].set(
-                    k.reshape(B * T, *k.shape[2:]))
-                v_pool = v_pool.at[l, :, wp, wo].set(
-                    v.reshape(B * T, *v.shape[2:]))
+                k_pool = llama.kv_write(k_pool, l, wp, wo,
+                                        k.reshape(B * T, *k.shape[2:]))
+                v_pool = llama.kv_write(v_pool, l, wp, wo,
+                                        v.reshape(B * T, *v.shape[2:]))
                 return q, k_pool, v_pool
 
             return jax.jit(qkv, donate_argnums=(4, 5),
@@ -167,10 +167,9 @@ class PagedPrograms:
             def attn_hot(q, l, k_pool, v_pool, read_idx, read_pos,
                          read_valid, positions):
                 rp, ro = read_idx // page, read_idx % page
-                # advanced indices split by the Hkv slice: batch dims in
-                # front -> [B, S, Hkv, Dh], each lane reading its own slots
-                k_ctx = k_pool[l, :, rp, ro]
-                v_ctx = v_pool[l, :, rp, ro]
+                # [B, S, Hkv, Dh], each lane reading its own slots
+                k_ctx = llama.kv_rows(k_pool, l, rp, ro)
+                v_ctx = llama.kv_rows(v_pool, l, rp, ro)
                 mask = (read_valid[:, None, :]
                         & (read_pos[:, None, :] <= positions[:, :, None]))
                 if window is not None:

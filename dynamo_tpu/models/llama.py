@@ -744,6 +744,48 @@ def _require_xla_attn(cfg: LlamaConfig, attn_impl: str) -> None:
 
 
 # ---------------------------------------------------------------------------
+# KV pool access
+#
+# The pool is stored once, as [L, Hkv, n_pages, page, Dh] in XLA's default
+# tiled layout, and every program reads and writes it AS STORED. That holds
+# only while each scatter's and gather's window is contiguous in that layout:
+# the natural ``pool[l, :, page, off]`` has the window [Hkv, ·, ·, Dh], for
+# which XLA's layout assignment re-lays the WHOLE pool to [L, pages, page,
+# Hkv, Dh] at the program's entry and back at its exit, and once more per
+# layer for whatever wants the stored order (the paged kernel). With the
+# head index spelt out the window is one [Dh] row (or one [page, Dh] page)
+# and the donated pool is updated in place. Measured on the chip: PERF.md §6,
+# PR 26. Every program that touches a pool goes through these three.
+# ---------------------------------------------------------------------------
+
+def kv_write(pool: jax.Array, layer, w_page: jax.Array, w_off: jax.Array,
+             rows: jax.Array, mode: Optional[str] = None) -> jax.Array:
+    """Write ``rows`` [n, Hkv, Dh] into layer ``layer`` at token slots
+    (``w_page``, ``w_off``), both [n]."""
+    heads = jnp.arange(pool.shape[1])[None, :]
+    return pool.at[layer, heads, w_page[:, None], w_off[:, None]].set(
+        rows, mode=mode)
+
+
+def kv_rows(pool: jax.Array, layer, r_page: jax.Array,
+            r_off: jax.Array) -> jax.Array:
+    """The rows at token slots (``r_page``, ``r_off``), both [...]:
+    [..., Hkv, Dh]."""
+    heads = jnp.arange(pool.shape[1])
+    return pool[layer, heads, r_page[..., None], r_off[..., None]]
+
+
+def kv_pages(pool: jax.Array, layer, pages: jax.Array) -> jax.Array:
+    """Whole pages in order — ``pages`` [B, P] page ids — as a context
+    [B, P * page, Hkv, Dh]: one [page, Dh] window per (head, page) instead
+    of ``page`` rows."""
+    Hkv, page, Dh = pool.shape[1], pool.shape[3], pool.shape[4]
+    B, P = pages.shape
+    ctx = pool[layer, jnp.arange(Hkv)[None, :, None], pages[:, None, :]]
+    return ctx.reshape(B, Hkv, P * page, Dh).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -753,7 +795,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             k_pool: jax.Array,           # [L, Hkv, n_pages, page, Dh] KV pool
             v_pool: jax.Array,
             write_idx: jax.Array,        # [B, T] int32 pool token-slot per new token
-            read_idx: jax.Array,         # [B, S] int32 pool token-slots to attend over
+            read_idx: Optional[jax.Array],  # [B, S] int32 pool token-slots to attend over
             read_pos: jax.Array,         # [B, S] int32 position of each read slot
             read_valid: jax.Array,       # [B, S] bool slot holds a real token
             attn_impl: str = "xla",      # "xla" | "flash" Pallas | "ring" sp
@@ -761,17 +803,24 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             logits_idx: Optional[jax.Array] = None,  # [B] per-lane position
             embed_override: Optional[Tuple[jax.Array, jax.Array]] = None,
             attn_spans: Optional[Tuple[jax.Array, jax.Array]] = None,
+            read_pages: Optional[jax.Array] = None,  # [B, S // page] int32
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One forward pass over a token chunk against the paged KV pool.
 
-    The pool is head-major ([L, Hkv, n_pages, page, Dh] — so ``pool[l]`` is
-    directly the layout TPU paged-attention kernels consume); token-slot
-    indices (page_id * page_size + offset) address it. The new chunk's K/V
-    are scattered into the pool at ``write_idx`` first; attention then
-    gathers ``read_idx`` (which must cover the chunk itself) and masks
-    causally by position: token at position p attends to slots with
-    ``read_pos <= p``. Works for prefill chunks and single-token decode
-    alike.
+    The pool is head-major ([L, Hkv, n_pages, page, Dh], read and written
+    as stored: see "KV pool access" above); token-slot indices (page_id *
+    page_size + offset) address it. The new chunk's K/V are scattered into
+    the pool at ``write_idx`` first; attention then gathers ``read_idx``
+    (which must cover the chunk itself) and masks causally by position:
+    token at position p attends to slots with ``read_pos <= p``. Works for
+    prefill chunks and single-token decode alike.
+
+    ``read_pages``: a caller whose read slots are whole pages in order —
+    ``read_idx[b, t] == read_pages[b, t // page] * page + t % page``
+    wherever ``read_valid`` — passes the page ids, and the context is
+    gathered a page at a time instead of a row at a time (``read_idx`` is
+    then not read and may be None; slots that are not valid may hold
+    anything finite).
 
     Returns (logits [B, T, vocab] fp32, k_pool, v_pool). With ``logits_idx``
     ([B] int32), the LM head runs only on each lane's hidden state at that
@@ -801,7 +850,8 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
         cos_l, sin_l = rope_tables(cfg, positions, local=True)
     flat_w = write_idx.reshape(-1)
     wp, wo = flat_w // page, flat_w % page
-    rp, ro = read_idx // page, read_idx % page
+    if read_pages is None:
+        rp, ro = read_idx // page, read_idx % page
     if attn_impl == "ring":
         from ..parallel.mesh import AXIS_TP as _TP
         from ..parallel.ring_attention import ring_attention
@@ -887,14 +937,16 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
         else:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-        # scatter chunk KV into the pool (write-then-gather). The scalar
-        # layer index is itself an "advanced" index, so the batched dims of
-        # [l, :, wp, wo] land in FRONT of the Hkv slice: shape [n, Hkv, Dh]
-        k_pool = k_pool.at[l, :, wp, wo].set(k.reshape(B * T, *k.shape[2:]))
-        v_pool = v_pool.at[l, :, wp, wo].set(v.reshape(B * T, *v.shape[2:]))
-        # gather this sequence's context (same rule): [B, S, Hkv, Dh]
-        k_ctx = k_pool[l, :, rp, ro]
-        v_ctx = v_pool[l, :, rp, ro]
+        # scatter chunk KV into the pool (write-then-gather)
+        k_pool = kv_write(k_pool, l, wp, wo, k.reshape(B * T, *k.shape[2:]))
+        v_pool = kv_write(v_pool, l, wp, wo, v.reshape(B * T, *v.shape[2:]))
+        # gather this sequence's context: [B, S, Hkv, Dh]
+        if read_pages is not None:
+            k_ctx = kv_pages(k_pool, l, read_pages)
+            v_ctx = kv_pages(v_pool, l, read_pages)
+        else:
+            k_ctx = kv_rows(k_pool, l, rp, ro)
+            v_ctx = kv_rows(v_pool, l, rp, ro)
         if attn_impl == "flash":
             attn = flash_for(l)(q, k_ctx, v_ctx, positions, read_pos,
                                 read_valid)
@@ -1066,12 +1118,12 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
                     c_sel, s_sel = c_m, s_m
                 q = apply_rope(q, c_sel, s_sel)
                 k = apply_rope(k, c_sel, s_sel)
-                kp = kp.at[l, :, wp, wo].set(
-                    k.reshape(-1, *k.shape[2:]), mode="drop")
-                vp = vp.at[l, :, wp, wo].set(
-                    v.reshape(-1, *v.shape[2:]), mode="drop")
-                k_ctx = kp[l, :, rp, ro]
-                v_ctx = vp[l, :, rp, ro]
+                kp = kv_write(kp, l, wp, wo, k.reshape(-1, *k.shape[2:]),
+                              mode="drop")
+                vp = kv_write(vp, l, wp, wo, v.reshape(-1, *v.shape[2:]),
+                              mode="drop")
+                k_ctx = kv_rows(kp, l, rp, ro)
+                v_ctx = kv_rows(vp, l, rp, ro)
                 if attn_impl == "flash":
                     # in-stage Pallas flash: we're already inside manual
                     # SPMD (pp x tp shard_map), so the kernel runs on this
@@ -1338,13 +1390,13 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                              softcap=cfg.attn_logit_softcap, window=w,
                              interpret=_kernel_interpret(mesh))
                 if tp_sz > 1:
-                    kv_spec = (P(AXIS_TP, None, None, None)
+                    kv_spec = (P(None, AXIS_TP, None, None, None)
                                if cfg.num_kv_heads % tp_sz == 0
-                               else P(None, None, None, None))
+                               else P(None, None, None, None, None))
                     fn = jax.shard_map(
                         fn, mesh=mesh,
                         in_specs=(P(None, AXIS_TP, None), kv_spec, kv_spec,
-                                  P(None, None), P(None)),
+                                  P(None, None), P(None), P()),
                         out_specs=P(None, AXIS_TP, None),
                         check_vma=False)   # pallas_call can't declare vma
                 _paged_cache[w] = fn
@@ -1353,9 +1405,6 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     if attn_impl != "pallas":
         S = page_tables.shape[1] * page
         t = jnp.arange(S, dtype=jnp.int32)
-        rp = jnp.take_along_axis(
-            page_tables, jnp.broadcast_to((t // page)[None], (B, S)), axis=1)
-        ro = jnp.broadcast_to((t % page)[None], (B, S))
         # causal == validity here: the query is the last token
         mask = (t[None] < lengths[:, None])[:, None, :]  # [B,1,S]
         if cfg.sliding_window is not None:
@@ -1381,16 +1430,15 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         else:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-        # [l, :, w_page, w_off] batches over the scalar l too, so the
-        # indexed shape is [B, Hkv, Dh] — exactly k[:, 0]
-        k_pool = k_pool.at[l, :, w_page, w_off].set(k[:, 0])
-        v_pool = v_pool.at[l, :, w_page, w_off].set(v[:, 0])
+        k_pool = kv_write(k_pool, l, w_page, w_off, k[:, 0])
+        v_pool = kv_write(v_pool, l, w_page, w_off, v[:, 0])
         if attn_impl == "pallas":
-            attn = paged_for(l)(q[:, 0], k_pool[l], v_pool[l],
-                                page_tables, lengths)[:, None]
+            # the kernel reads the whole pool in place, by layer index
+            attn = paged_for(l)(q[:, 0], k_pool, v_pool, page_tables,
+                                lengths, jnp.int32(l))[:, None]
         else:
-            k_ctx = k_pool[l, :, rp, ro]               # [B,S,Hkv,Dh]
-            v_ctx = v_pool[l, :, rp, ro]
+            k_ctx = kv_pages(k_pool, l, page_tables)   # [B,S,Hkv,Dh]
+            v_ctx = kv_pages(v_pool, l, page_tables)
             attn = attend(q, k_ctx, v_ctx,
                           sliding_mask if cfg.layer_sliding(l) else mask,
                           scale=cfg.attn_scale,

@@ -216,16 +216,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def _paged_dma_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+def _lane_block(b, j, *prefetched):
+    """Index map of the per-lane q / output block of both paged kernels."""
+    return (b, 0, 0, 0)
+
+
+def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
                       k_buf, v_buf, sem, m_scr, l_scr, acc_scr, state,
                       *, scale: float, page: int, ppb: int, hkv: int,
                       fold: int, dh: int, softcap: Optional[float],
                       window: Optional[int]):
-    """Pools arrive pre-folded to [Hkv, n_pages, page//fold, fold*Dh] so DMA
-    rows are 128-lane aligned even for Dh=64; a folded row holds ``fold``
-    consecutive tokens, handled as ``fold`` score slices. Buffers are
-    head-major ([2, Hkv, ppb, rows, fold*Dh]) so the per-page all-head DMA
-    lands as a contiguous per-head reshape for the batched matmul.
+    """Pools are the WHOLE stored pool, [L, Hkv, n_pages, page//fold,
+    fold*Dh], left in HBM; ``layer_ref[0]`` picks the layer inside the copy
+    descriptor, so no per-layer slice of the pool is ever materialised and
+    one Mosaic kernel serves every layer of a (window, softcap) class. With
+    ``fold`` > 1 (Dh < 128) a row holds ``fold`` consecutive tokens,
+    handled as ``fold`` score slices. Buffers are head-major ([2, Hkv, ppb,
+    rows, fold*Dh]) so the per-page all-head DMA lands as a contiguous
+    per-head reshape for the batched matmul.
 
     With ``window``, each lane's active block range is clamped at BOTH ends:
     blocks wholly below ``length - window`` are never DMA'd nor computed
@@ -247,15 +255,19 @@ def _paged_dma_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             return 0
         return jnp.maximum(len_ref[bb] - window, 0) // L2
 
+    layer = layer_ref[0]
+
     def copy_descs(bb, jj, slot):
         descs = []
         for i in range(ppb):
             pidx = pt_ref[bb, jj * ppb + i]
             # one strided DMA per page covering every kv head
             descs.append(pltpu.make_async_copy(
-                k_hbm.at[:, pidx], k_buf.at[slot, :, i], sem.at[slot, 0]))
+                k_hbm.at[layer, :, pidx], k_buf.at[slot, :, i],
+                sem.at[slot, 0]))
             descs.append(pltpu.make_async_copy(
-                v_hbm.at[:, pidx], v_buf.at[slot, :, i], sem.at[slot, 1]))
+                v_hbm.at[layer, :, pidx], v_buf.at[slot, :, i],
+                sem.at[slot, 1]))
         return descs
 
     def start(bb, jj, slot):
@@ -357,17 +369,18 @@ def _paged_dma_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                         ).astype(o_ref.dtype)
 
 
-def _paged_attention_tpu(q4, k_pages, v_pages, page_tables, lengths,
+def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
                          *, pages_per_block: int = 8,
                          scale: Optional[float] = None,
                          softcap: Optional[float] = None,
                          window: Optional[int] = None,
                          interpret: bool = False) -> jax.Array:
-    """q4: [B, Hkv, G, Dh]; pools [Hkv, n_pages, page, Dh]. Returns q4-shaped.
-    ``interpret`` exists for the CPU test suite only — the serving path
-    always compiles this variant (paged_attention gates it to real TPUs)."""
+    """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh]; layer: [1]
+    int32. Returns q4-shaped. ``interpret`` exists for the CPU test suite
+    only — the serving path always compiles this variant (paged_attention
+    gates it to real TPUs)."""
     B, Hkv, G, Dh = q4.shape
-    _, n_pages, page, _ = k_pages.shape
+    L, _, n_pages, page, _ = k_pool.shape
     P = page_tables.shape[1]
     ppb = min(pages_per_block, P)
     if P % ppb:
@@ -377,26 +390,28 @@ def _paged_attention_tpu(q4, k_pages, v_pages, page_tables, lengths,
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
 
-    # fold tokens so DMA rows are 128-lane aligned (free bitcast view)
+    # Dh < 128: fold tokens so DMA rows are 128-lane aligned. At Dh >= 128
+    # nothing is folded and the kernel reads the pool as stored; below, the
+    # fold is a relayout in tiled HBM, which is why paged_attention hands
+    # this function one layer's slice there and never the pool.
     fold = max(1, 128 // Dh)
     if page % fold:
         raise ValueError(f"page size {page} not divisible by fold {fold}")
-    kf = k_pages.reshape(Hkv, n_pages, page // fold, fold * Dh)
-    vf = v_pages.reshape(Hkv, n_pages, page // fold, fold * Dh)
+    k_pool = k_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
+    v_pool = v_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, NB),
         in_specs=[
-            pl.BlockSpec((1, Hkv, G, Dh), lambda b, j, pt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
         ],
-        out_specs=pl.BlockSpec((1, Hkv, G, Dh),
-                               lambda b, j, pt, ln: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
         scratch_shapes=[
-            pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dh), k_pages.dtype),
-            pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dh), v_pages.dtype),
+            pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dh), k_pool.dtype),
+            pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dh), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),                 # [slot, k/v]
             pltpu.VMEM((Hkv, G, 1), jnp.float32),            # m
             pltpu.VMEM((Hkv, G, 1), jnp.float32),            # l
@@ -413,9 +428,10 @@ def _paged_attention_tpu(q4, k_pages, v_pages, page_tables, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(page_tables, lengths, q4, kf, vf)
+    )(page_tables, lengths, layer, q4, k_pool, v_pool)
 
-def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+
+def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_scr, l_scr, acc_scr, *, scale: float, page: int,
                   softcap: Optional[float], window: Optional[int]):
     b = pl.program_id(0)
@@ -440,8 +456,8 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(in_range)
     def _():
         q = q_ref[0]                                       # [Hkv, G, Dh]
-        k = k_ref[:, 0]                                    # [Hkv, page, Dh]
-        v = v_ref[:, 0]
+        k = k_ref[0, :, 0]                                 # [Hkv, page, Dh]
+        v = v_ref[0, :, 0]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale    # [Hkv, G, page]
@@ -486,8 +502,9 @@ def paged_kernel_variant(interpret: bool) -> str:
     return "simple[interpret]" if interpret else variant
 
 
-def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     page_tables: jax.Array, lengths: jax.Array,
+                    layer=None,
                     interpret: Optional[bool] = None,
                     scale: Optional[float] = None,
                     softcap: Optional[float] = None,
@@ -495,7 +512,11 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     """Decode attention straight over the paged KV pool.
 
     q: [B, Hq, Dh] (one new token per sequence, already rope'd)
-    k_pages, v_pages: [Hkv, n_pages, page, Dh] — the layer's HBM pool
+    k_pool, v_pool: [L, Hkv, n_pages, page, Dh] — the WHOLE stored pool,
+      read in place; ``layer`` (an int or a traced int32 scalar) picks the
+      layer inside the kernel's page copies, so no caller slices the pool.
+      A single layer's [Hkv, n_pages, page, Dh] is taken as a pool of one
+      layer (``layer`` must then be left out).
     page_tables: [B, P] int32 page ids (rows padded with page 0)
     lengths: [B] int32 — tokens to attend per sequence (including current)
     Returns [B, Hq, Dh]. Sequences attend to tokens [0, length); with
@@ -504,7 +525,9 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     copies nor compute (sliding decode reads O(window) bytes); the simple
     kernel skips only their compute — its BlockSpec pipeline still copies
     every page. ``softcap`` tanh-caps scores pre-softmax (Gemma2);
-    ``scale`` overrides rsqrt(Dh) (query_pre_attn_scalar).
+    ``scale`` overrides rsqrt(Dh) (query_pre_attn_scalar). ``window`` and
+    ``softcap`` are static (one Mosaic kernel per class); ``layer`` is
+    dynamic, so all layers of a class share that kernel.
 
     On a TPU this runs the multi-page double-buffered DMA kernel above
     (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
@@ -513,10 +536,23 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     CPU test suite exercises the same contract. See
     :func:`paged_kernel_variant`.
     """
+    if k_pool.ndim == 4:
+        if layer is not None:
+            raise ValueError("a layer index needs the whole 5-D pool")
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, Hq, Dh = q.shape
-    Hkv, n_pages, page, _ = k_pages.shape
+    _, Hkv, n_pages, page, _ = k_pool.shape
     G = Hq // Hkv
     P = page_tables.shape[1]
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    if Dh < 128 and k_pool.shape[0] > 1:
+        # rows narrower than a lane tile: XLA's programs keep such a pool in
+        # another order than the kernels' operands take (pages minor; seen
+        # on a v5e), so every call re-lays what it is given. Give it one
+        # layer's slice to re-lay, never the pool (PERF.md §7).
+        k_pool, v_pool = (jax.lax.dynamic_index_in_dim(p, layer[0], 0)
+                          for p in (k_pool, v_pool))
+        layer = jnp.zeros_like(layer)
     # The TPU kernel's prefetch chain assumes every lane covers >=1 block
     # (nblocks==0 would leave a DMA slot un-consumed and stall the next
     # active lane). Enforce the invariant here rather than relying on
@@ -540,7 +576,7 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         if not 1 <= ppb <= 64:
             raise ValueError(f"DYNAMO_TPU_PAGED_PPB={raw_ppb!r} "
                              f"(expected an integer in [1, 64])")
-        out = _paged_attention_tpu(q4, k_pages, v_pages, page_tables,
+        out = _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables,
                                    lengths, pages_per_block=ppb,
                                    scale=scale, softcap=softcap,
                                    window=window)
@@ -549,18 +585,19 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         scale = 1.0 / math.sqrt(Dh)
 
     q4 = q.reshape(B, Hkv, G, Dh)
+
+    def page_map(b, p, pt, ln, ly):
+        return (ly[0], 0, pt[b, p], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, Hkv, G, Dh), lambda b, p, pt, ln: (b, 0, 0, 0)),
-            pl.BlockSpec((Hkv, 1, page, Dh),
-                         lambda b, p, pt, ln: (0, pt[b, p], 0, 0)),
-            pl.BlockSpec((Hkv, 1, page, Dh),
-                         lambda b, p, pt, ln: (0, pt[b, p], 0, 0)),
+            pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
+            pl.BlockSpec((1, Hkv, 1, page, Dh), page_map),
+            pl.BlockSpec((1, Hkv, 1, page, Dh), page_map),
         ],
-        out_specs=pl.BlockSpec((1, Hkv, G, Dh),
-                               lambda b, p, pt, ln: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
         scratch_shapes=[
             pltpu.VMEM((Hkv, G, 1), jnp.float32),    # m
             pltpu.VMEM((Hkv, G, 1), jnp.float32),    # l
@@ -573,5 +610,5 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
         interpret=interpret,
-    )(page_tables, lengths, q4, k_pages, v_pages)
+    )(page_tables, lengths, layer, q4, k_pool, v_pool)
     return out.reshape(B, Hq, Dh)

@@ -4,6 +4,7 @@ Runs in interpreter mode on CPU — the same kernel code the TPU compiles.
 """
 
 import math
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,32 @@ from dynamo_tpu.ops.attention import flash_attention, paged_attention
 def _dense_ref(q, k, v, q_pos, k_pos, k_valid):
     mask = k_valid[:, None, :] & (k_pos[:, None, :] <= q_pos[:, :, None])
     return attend(q, k, v, mask)
+
+
+def _dense_ref_full(q, k, v, q_pos, k_pos, k_valid, scale=None,
+                    softcap=None, window=None):
+    mask = k_valid[:, None, :] & (k_pos[:, None, :] <= q_pos[:, :, None])
+    if window is not None:
+        mask = mask & (k_pos[:, None, :] > q_pos[:, :, None] - window)
+    return attend(q, k, v, mask, scale=scale, softcap=softcap)
+
+
+def _dense_paged_ref(q, k_pages, v_pages, page_tables, lengths, **kw):
+    """Dense reference over ONE layer's [Hkv, n_pages, page, Dh] pool."""
+    Hkv, _, page, Dh = k_pages.shape
+    B, S = q.shape[0], page_tables.shape[1] * page
+    rows = []
+    for b in range(B):
+        ctx_k = (k_pages[:, page_tables[b]].transpose(1, 2, 0, 3)
+                 .reshape(S, Hkv, Dh))
+        ctx_v = (v_pages[:, page_tables[b]].transpose(1, 2, 0, 3)
+                 .reshape(S, Hkv, Dh))
+        k_pos = jnp.arange(S, dtype=jnp.int32)[None]
+        q_pos = jnp.full((1, 1), lengths[b] - 1, jnp.int32)
+        rows.append(_dense_ref_full(
+            q[b][None, None], ctx_k[None], ctx_v[None], q_pos, k_pos,
+            k_pos < lengths[b], **kw)[0, 0])
+    return jnp.stack(rows)
 
 
 @pytest.mark.parametrize("B,T,S,Hq,Hkv,Dh", [
@@ -76,52 +103,61 @@ def test_paged_matches_dense(B, Hq, Hkv, Dh, page, P):
     got = paged_attention(q, k_pages, v_pages, page_tables, lengths,
                           interpret=True)
 
-    # dense reference: gather each sequence's context and mask by length
-    S = P * page
-    for b in range(B):
-        ctx_k = (k_pages[:, page_tables[b]].transpose(1, 2, 0, 3)
-                 .reshape(S, Hkv, Dh))
-        ctx_v = (v_pages[:, page_tables[b]].transpose(1, 2, 0, 3)
-                 .reshape(S, Hkv, Dh))
-        qb = q[b][None, None]                       # [1, 1, Hq, Dh]
-        k_pos = jnp.arange(S, dtype=jnp.int32)[None]
-        valid = k_pos < lengths[b]
-        q_pos = jnp.full((1, 1), lengths[b] - 1, jnp.int32)
-        want = _dense_ref(qb, ctx_k[None], ctx_v[None], q_pos, k_pos, valid)
-        np.testing.assert_allclose(
-            np.asarray(got[b], np.float32),
-            np.asarray(want[0, 0], np.float32), atol=3e-2, rtol=3e-2)
+    want = _dense_paged_ref(q, k_pages, v_pages, page_tables, lengths)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=3e-2, rtol=3e-2)
 
 
-def test_paged_inside_scan_with_donated_pool():
-    """The decode loop shape: kernel invoked inside lax.scan, pool donated."""
+@pytest.mark.parametrize("L", [None, 3])
+def test_paged_inside_scan_with_donated_pool(L):
+    """The decode loop shape: pools carried through lax.scan and donated,
+    every layer's row written in place (head index spelt out, as
+    forward_decode does) and read by the kernel from the whole pool by
+    layer index. ``L=None`` is the single-layer [Hkv, n_pages, page, Dh]
+    form."""
     B, Hq, Hkv, Dh, page, P = 2, 4, 2, 16, 8, 2
     n_pages = 8
+    lead = () if L is None else (L,)
     q = jnp.ones((B, Hq, Dh), jnp.bfloat16)
-    k_pages = jnp.ones((Hkv, n_pages, page, Dh), jnp.bfloat16)
-    v_pages = jnp.ones((Hkv, n_pages, page, Dh), jnp.bfloat16)
+    k_pool = jnp.ones(lead + (Hkv, n_pages, page, Dh), jnp.bfloat16)
+    v_pool = jnp.ones(lead + (Hkv, n_pages, page, Dh), jnp.bfloat16)
     pt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
     lengths = jnp.asarray([5, 9], jnp.int32)
+    hh = jnp.arange(Hkv)[None, :]
 
-    @jax.jit
-    def run(q, k_pages, v_pages, pt, lengths):
+    @partial(jax.jit, donate_argnums=(1, 2))
+    def run(q, k_pool, v_pool, pt, lengths):
         def body(carry, _):
-            out = paged_attention(q, k_pages, v_pages, pt, carry,
-                                  interpret=True)
-            return carry + 1, out
-        return jax.lax.scan(body, lengths, None, length=3)
+            ln, kp, vp = carry
+            outs = []
+            for l in ([None] if L is None else range(L)):
+                pos = ln - 1
+                wp = jnp.take_along_axis(pt, (pos // page)[:, None], 1)
+                at = ((hh, wp, (pos % page)[:, None]) if l is None
+                      else (l, hh, wp, (pos % page)[:, None]))
+                new = jnp.full((B, Hkv, Dh), 2.0, kp.dtype)
+                kp, vp = kp.at[at].set(new), vp.at[at].set(new)
+                outs.append(paged_attention(
+                    q, kp, vp, pt, ln, None if l is None else jnp.int32(l),
+                    interpret=True))
+            return (ln + 1, kp, vp), jnp.stack(outs)
+        (_, kp, vp), outs = jax.lax.scan(
+            body, (lengths, k_pool, v_pool), None, length=3)
+        return outs, kp, vp
 
-    _, outs = run(q, k_pages, v_pages, pt, lengths)
-    assert outs.shape == (3, B, Hq, Dh)
-    assert np.isfinite(np.asarray(outs, np.float32)).all()
-
-
-def _dense_ref_full(q, k, v, q_pos, k_pos, k_valid, scale=None,
-                    softcap=None, window=None):
-    mask = k_valid[:, None, :] & (k_pos[:, None, :] <= q_pos[:, :, None])
-    if window is not None:
-        mask = mask & (k_pos[:, None, :] > q_pos[:, :, None] - window)
-    return attend(q, k, v, mask, scale=scale, softcap=softcap)
+    lowered = run.lower(q, k_pool, v_pool, pt, lengths)
+    outs, kp, vp = run(q, k_pool, v_pool, pt, lengths)
+    assert k_pool.is_deleted() and v_pool.is_deleted()
+    assert "tf.aliasing_output" in lowered.as_text()
+    assert outs.shape == (3, 1 if L is None else L, B, Hq, Dh)
+    out = np.asarray(outs, np.float32)
+    assert np.isfinite(out).all()
+    # the rows written inside the scan are read back by the kernel: V holds
+    # 1s and (t+1) 2s per lane at step t, attention weights are uniform
+    # among equal keys, so the output lies strictly between 1 and 2
+    assert (out > 1.0).all() and (out < 2.0).all()
+    assert float(np.asarray(kp, np.float32).max()) == 2.0
 
 
 @pytest.mark.parametrize("window,softcap,scale", [
@@ -174,22 +210,11 @@ def test_paged_window_softcap_scale(window, softcap, scale):
     got = paged_attention(q, k_pages, v_pages, page_tables, lengths,
                           interpret=True, scale=scale, softcap=softcap,
                           window=window)
-    S = P * page
-    for b in range(B):
-        ctx_k = (k_pages[:, page_tables[b]].transpose(1, 2, 0, 3)
-                 .reshape(S, Hkv, Dh))
-        ctx_v = (v_pages[:, page_tables[b]].transpose(1, 2, 0, 3)
-                 .reshape(S, Hkv, Dh))
-        qb = q[b][None, None]
-        k_pos = jnp.arange(S, dtype=jnp.int32)[None]
-        valid = k_pos < lengths[b]
-        q_pos = jnp.full((1, 1), lengths[b] - 1, jnp.int32)
-        want = _dense_ref_full(qb, ctx_k[None], ctx_v[None], q_pos, k_pos,
-                               valid, scale=scale, softcap=softcap,
-                               window=window)
-        np.testing.assert_allclose(
-            np.asarray(got[b], np.float32),
-            np.asarray(want[0, 0], np.float32), atol=3e-2, rtol=3e-2)
+    want = _dense_paged_ref(q, k_pages, v_pages, page_tables, lengths,
+                            scale=scale, softcap=softcap, window=window)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        atol=3e-2, rtol=3e-2)
 
 
 @pytest.mark.parametrize("window,softcap,ppb", [
@@ -217,11 +242,74 @@ def test_paged_dma_variant_window_softcap(window, softcap, ppb):
     lengths = jnp.asarray([5, 12, page * P], jnp.int32)
 
     got = _paged_attention_tpu(
-        q.reshape(B, Hkv, Hq // Hkv, Dh), k_pages, v_pages, page_tables,
-        lengths, pages_per_block=ppb, softcap=softcap, window=window,
+        q.reshape(B, Hkv, Hq // Hkv, Dh), k_pages[None], v_pages[None],
+        jnp.zeros((1,), jnp.int32), page_tables, lengths,
+        pages_per_block=ppb, softcap=softcap, window=window,
         interpret=True).reshape(B, Hq, Dh)
     want = paged_attention(q, k_pages, v_pages, page_tables, lengths,
                            interpret=True, softcap=softcap, window=window)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32),
         atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("variant", ["simple", "dma"])
+@pytest.mark.parametrize("Dh,G,window,softcap,scale,ppb", [
+    (128, 4, None, None, None, 2),     # the cells' head geometry class
+    (64, 4, None, None, None, 2),      # llama-3.2-1b: fold 2
+    (128, 6, 12, None, None, 3),       # qwen2's group of 6, sliding, padded
+    (64, 6, None, 50.0, None, 8),      # ppb wider than the table
+    (64, 4, 12, 30.0, 1.0 / math.sqrt(24.0), 1),
+    (16, 4, None, None, None, 2),      # fold 8 == page: one folded row
+])
+def test_paged_whole_pool_by_layer(variant, Dh, G, window, softcap, scale,
+                                   ppb):
+    """The kernel contract forward_decode relies on: the WHOLE 5-D pool and
+    a (traced) layer index give, for every layer, what the dense reference
+    and the single-layer form give for that layer's slice."""
+    from dynamo_tpu.ops.attention import _paged_attention_tpu
+
+    L, B, Hkv, page, P = 3, 3, 2, 8, 4
+    Hq = Hkv * G
+    n_pages = B * P + 1
+    ks = jax.random.split(jax.random.PRNGKey(17), 3)
+    q = jax.random.normal(ks[0], (B, Hq, Dh), jnp.float32).astype(jnp.bfloat16)
+    k_pool = jax.random.normal(
+        ks[1], (L, Hkv, n_pages, page, Dh), jnp.float32).astype(jnp.bfloat16)
+    v_pool = jax.random.normal(
+        ks[2], (L, Hkv, n_pages, page, Dh), jnp.float32).astype(jnp.bfloat16)
+    page_tables = (jnp.arange(P, dtype=jnp.int32)[None]
+                   + jnp.arange(B, dtype=jnp.int32)[:, None] * P + 1)
+    lengths = jnp.asarray([5, 12, page * P], jnp.int32)
+    kw = dict(scale=scale, softcap=softcap, window=window)
+
+    if variant == "simple":
+        def whole(layer):
+            return paged_attention(q, k_pool, v_pool, page_tables, lengths,
+                                   layer, interpret=True, **kw)
+    else:
+        def whole(layer):
+            return _paged_attention_tpu(
+                q.reshape(B, Hkv, G, Dh), k_pool, v_pool, layer.reshape(1),
+                page_tables, lengths, pages_per_block=ppb, interpret=True,
+                **kw).reshape(B, Hq, Dh)
+    whole = jax.jit(whole)          # the layer index is traced: one program
+    for l in range(L):
+        got = np.asarray(whole(jnp.int32(l)), np.float32)
+        want = _dense_paged_ref(q, k_pool[l], v_pool[l], page_tables,
+                                lengths, **kw)
+        np.testing.assert_allclose(got, np.asarray(want, np.float32),
+                                   atol=3e-2, rtol=3e-2)
+        per_layer = paged_attention(q, k_pool[l], v_pool[l], page_tables,
+                                    lengths, interpret=True, **kw)
+        np.testing.assert_allclose(got, np.asarray(per_layer, np.float32),
+                                   atol=3e-2, rtol=3e-2)
+    assert whole._cache_size() == 1
+
+
+def test_paged_layer_needs_whole_pool():
+    z = jnp.zeros((2, 3, 8, 16), jnp.bfloat16)
+    with pytest.raises(ValueError, match="whole 5-D pool"):
+        paged_attention(jnp.zeros((1, 4, 16), jnp.bfloat16), z, z,
+                        jnp.zeros((1, 1), jnp.int32),
+                        jnp.ones((1,), jnp.int32), layer=1, interpret=True)

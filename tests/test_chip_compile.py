@@ -136,6 +136,105 @@ def test_decode_step_compiles_with_kernels_for_v5e(v5e):
     assert txt.count("tpu_custom_call") >= cfg.num_layers
 
 
+def _pool_relayouts(txt: str, pool_shape) -> dict:
+    """Lines of a compiled program that materialise a pool-sized or a
+    layer-of-the-pool-sized array by ``copy`` or ``slice``: what the chip
+    showed as 46-62 % of device time before PR 26 (PERF.md §6)."""
+    import re
+
+    whole = ",".join(map(str, pool_shape))
+    layer = ",".join(map(str, pool_shape[1:]))
+    lines = [ln for ln in txt.splitlines()
+             if re.search(r" (copy|slice)\(", ln)]
+    return {"pool": [ln for ln in lines
+                     if re.search(r"= \w+\[%s\]" % whole, ln)],
+            "layer": [ln for ln in lines
+                      if re.search(r"= \w+\[(1,)?%s\]" % layer, ln)]}
+
+
+@pytest.mark.parametrize("kernel", ["dma", "simple"])
+@pytest.mark.parametrize("geom", GEOMETRIES[:2],
+                         ids=lambda g: "x".join(map(str, g)))
+def test_paged_whole_pool_by_layer_compiles_for_v5e(v5e, monkeypatch, geom,
+                                                    kernel):
+    """The form forward_decode calls: the whole [L, Hkv, n_pages, page, Dh]
+    pool and a traced layer index. At Dh = 128 the custom call takes the
+    pool itself (no pool-shaped operand is produced by a copy); at Dh = 64
+    the fold re-lays one layer's slice, never the pool."""
+    Hq, Hkv, Dh = geom
+    L, B, P = 4, 32, 34
+    n_pages = B * P + 1
+    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", kernel)
+    txt = _compiled_text(
+        lambda q, k, v, pt, ln, l: A.paged_attention(
+            q, k, v, pt, ln, l, interpret=False),
+        _sds(v5e, (B, Hq, Dh), jnp.bfloat16),
+        _sds(v5e, (L, Hkv, n_pages, PAGE, Dh), jnp.bfloat16),
+        _sds(v5e, (L, Hkv, n_pages, PAGE, Dh), jnp.bfloat16),
+        _sds(v5e, (B, P), jnp.int32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, (), jnp.int32))
+    assert "tpu_custom_call" in txt
+    found = _pool_relayouts(txt, (L, Hkv, n_pages, PAGE, Dh))
+    assert not found["pool"] and (Dh < 128 or not found["layer"]), found
+
+
+@pytest.mark.parametrize("program", ["decode_scan", "prefill_chunk",
+                                     "prefill_chunk_rows"])
+def test_bucket_program_bodies_keep_the_pool_as_stored(v5e, program):
+    """The decode scan and the prefill chunk at Dh = 128 widths (qwen2-1.5b,
+    depth cut to two layers): the donated pools are updated in place — no
+    whole-pool copy at entry or exit, no per-layer slice re-laid for the
+    kernel — and come back aliased to their inputs."""
+    from dynamo_tpu.parallel.mesh import serving_mesh
+
+    cfg = llama.preset("qwen2-1.5b", num_layers=2)
+    mesh = serving_mesh(1, devices=[v5e])
+    B, S, C, N = 32, 1152, 128, 4
+    P = S // PAGE
+    pshape = (cfg.num_layers, cfg.num_kv_heads, 1089, PAGE, cfg.head_dim)
+    pshapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), pshapes)
+    pool = _sds(v5e, pshape, cfg.dtype)
+
+    def decode_scan(p, t, k, v, pt, ln):
+        def one(carry, _):
+            t, ln, k, v = carry
+            lg, k, v = llama.forward_decode(p, cfg, t, k, v, pt, ln,
+                                            attn_impl="pallas", mesh=mesh)
+            return (jnp.argmax(lg[:, 0], -1).astype(jnp.int32), ln + 1,
+                    k, v), None
+        (t, ln, k, v), _ = jax.lax.scan(one, (t, ln, k, v), None, length=N)
+        return t, k, v
+
+    def prefill_chunk(p, t, pos, k, v, wi, ri, rp, rv, li):
+        pages = None if program.endswith("rows") else ri[:, ::PAGE] // PAGE
+        return llama.forward(p, cfg, t, pos, k, v, wi, ri, rp, rv,
+                             attn_impl="flash", mesh=mesh, logits_idx=li,
+                             read_pages=pages)
+
+    i32 = jnp.int32
+    if program == "decode_scan":
+        fn, donate = decode_scan, (2, 3)
+        args = (params, _sds(v5e, (B,), i32), pool, pool,
+                _sds(v5e, (B, P), i32), _sds(v5e, (B,), i32))
+    else:
+        fn, donate = prefill_chunk, (3, 4)
+        args = (params, _sds(v5e, (1, C), i32), _sds(v5e, (1, C), i32),
+                pool, pool, _sds(v5e, (1, C), i32), _sds(v5e, (1, S), i32),
+                _sds(v5e, (1, S), i32), _sds(v5e, (1, S), jnp.bool_),
+                _sds(v5e, (1,), i32))
+    compiled = jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+    found = _pool_relayouts(compiled.as_text(), pshape)
+    assert not found["pool"] and not found["layer"], found
+    ma = compiled.memory_analysis()
+    pool_bytes = 2 * jnp.dtype(cfg.dtype).itemsize
+    for d in pshape:
+        pool_bytes *= d
+    assert ma.alias_size_in_bytes >= pool_bytes
+    assert ma.temp_size_in_bytes < pool_bytes // 8
+
+
 @pytest.mark.parametrize("full_tracebacks", [False, True])
 def test_kernel_program_text_vs_call_stack(v5e, full_tracebacks):
     """A Pallas kernel is serialised into its program with its locations.

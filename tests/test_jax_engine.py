@@ -818,3 +818,119 @@ def test_profile_capture_python_tracer_off_and_stop_off_thread(
         t.join(timeout=10)
     raced.close(timeout=10)
     assert [c[0] for c in calls[4:]] == ["start", "stop"]
+
+
+# ---------------------------------------------------------------------------
+# the pools are stored once and updated in place (PR 26)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def spec_core():
+    return EngineCore(make_cfg(max_batch=2, spec="ngram", spec_k=2))
+
+
+def _bucket_program(core, kind):
+    """(jitted bucket program, its arguments) for the first bucket of
+    ``kind``, with the arguments warm-up gives it."""
+    import jax.numpy as jnp
+
+    B, s, S = core.cfg.max_batch, core.sampling, core.s_buckets[0]
+    pt = np.zeros((B, S // core.page_size), np.int32)
+    ones, flags = np.ones(B, np.int32), np.zeros(B, bool)
+    tail = (pt, ones, s.temperature, s.top_p, s.top_k, s.key,
+            core.gen_counts, flags, flags, s.freq_pen, s.pres_pen)
+    if kind == "decode":
+        return core._decode_fn(S), (
+            core.params, np.zeros(B, np.int32), core.k_pool, core.v_pool,
+            *tail)
+    if kind == "verify":
+        K, U = core.spec.k_buckets[0], core.spec.k_max + 1
+        return core._verify_fn(S, K), (
+            core.params, np.zeros((B, K + 1), np.int32), core.k_pool,
+            core.v_pool, *tail, np.zeros((B, U), np.int32),
+            np.zeros((B, U), bool))
+    C = core.c_buckets[0]
+    zt = np.zeros((1, C), np.int32)
+    zs = np.zeros((1, S), np.int32)
+    return core._prefill_fn(1, C, S), (
+        core.params, zt, zt, core.k_pool, core.v_pool, zt, zs, zs,
+        np.zeros((1, S), bool), np.zeros(1, np.int32),
+        np.zeros(1, np.float32), np.ones(1, np.float32),
+        np.zeros(1, np.int32), s.key[jnp.asarray(np.zeros(1, np.int32))])
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill", "verify"])
+def test_bucket_programs_alias_both_pools(core, spec_core, kind):
+    """Every bucket program takes the pools donated and returns them
+    aliased to their inputs: the compiled program's ``input_output_alias``
+    names both pool parameters, and one call deletes the arrays passed in
+    and hands back pools that carry ``kv_sharding``."""
+    import re
+
+    c = spec_core if kind == "verify" else core
+    fn, args = _bucket_program(c, kind)
+    text = fn.jitted.lower(*args).compile().as_text()
+    aliased = {int(n) for n in re.findall(
+        r"\((\d+), \{\}, (?:may|must)-alias\)",
+        re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
+    shape = ",".join(map(str, c.k_pool.shape))
+    pool_params = {int(n) for n in re.findall(
+        r"\w+\[%s\]\S* parameter\((\d+)\)" % shape,
+        text[text.index("ENTRY"):])}
+    assert len(pool_params) == 2 and pool_params <= aliased
+
+    k0, v0 = c.k_pool, c.v_pool
+    out = fn(*args)
+    pools = [a for a in out if getattr(a, "shape", None) == k0.shape]
+    assert k0.is_deleted() and v0.is_deleted() and len(pools) == 2
+    assert all(p.sharding == c.kv_sharding for p in pools)
+    c.k_pool, c.v_pool = pools
+    if kind != "prefill":
+        c.gen_counts = out[-1]
+
+
+def test_serving_dispatches_donate_the_pools(core, spec_core):
+    """The same, seen from the serving loop: whenever a step replaces the
+    engine's pools (a prefill, decode or verify dispatch went out), the
+    arrays it replaced are gone."""
+    for c, kinds in ((core, ("prefill", "decode")),
+                     (spec_core, ("prefill", "verify"))):
+        n0 = _counter_values(c.stage.engine_dispatches, kinds)
+        c.submit("don", req(list(range(100, 140)), max_tokens=7))
+        done, swaps = False, 0
+        for _ in range(200):
+            k0, v0 = c.k_pool, c.v_pool
+            done |= any(so.finish is not None for so in c.step())
+            if c.k_pool is not k0:
+                swaps += 1
+                assert k0.is_deleted() and v0.is_deleted()
+                assert c.k_pool.sharding == c.kv_sharding
+                assert c.v_pool.sharding == c.kv_sharding
+            if done and not c.has_work:
+                break
+        n1 = _counter_values(c.stage.engine_dispatches, kinds)
+        assert all(n1[k] > n0[k] for k in kinds) and swaps >= 3
+
+
+def test_pallas_decode_path_serves_what_the_xla_path_serves():
+    """A seeded greedy run through ``EngineCore`` with the paged kernel
+    reading the whole pool by layer index (interpret mode) gives the tokens
+    of the same run on the dense gather path, computed here, in this
+    process, and log-probabilities within float32 round-off. The model is
+    float32 so that the comparison sees the paths and not bf16 ties."""
+    import jax.numpy as jnp
+
+    model = llama.preset("tiny-byte", dtype=jnp.float32)
+    runs = {}
+    for impl in ("xla", "pallas"):
+        c = EngineCore(make_cfg(model=model, max_batch=2, attn_impl=impl,
+                                seed=3))
+        assert c.decode_attn_impl == impl
+        c.submit("p", req([7, 3, 9, 250, 14, 15, 92, 65, 35], max_tokens=12))
+        c.submit("q", req(list(range(40, 85)), max_tokens=12))
+        runs[impl] = drain(c, ["p", "q"])
+    for seq in ("p", "q"):
+        x, p = runs["xla"][seq], runs["pallas"][seq]
+        assert [g.token for g in x] == [g.token for g in p]
+        np.testing.assert_allclose([g.token_logprob for g in p],
+                                   [g.token_logprob for g in x], atol=2e-5)
