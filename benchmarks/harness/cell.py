@@ -161,6 +161,13 @@ def prepare(cat: Catalog, workload: str, seed: int, trace: bool,
     mix = cat.data("traffic", cell["traffic"])
     gen = cat.module("generators", mix["generator"])
     topo = cat.module("topologies", mix.get("topology", "single"))
+    reference = config["benchmark"].get("reference")
+    if not reference:
+        # no default: a model scored by another model's mathematics would
+        # read as incorrect, or worse, as correct
+        raise BenchError(f"configs/{cell['config']}.json names no "
+                         f"benchmark.reference (references/<name>.py)")
+    cat.find("references", reference, ".py")
     if not os.path.isdir(os.path.join(ROOT, "dynamo_tpu")):
         raise BenchError(f"the system under test is not in {ROOT}")
     held_to = os.environ.get("JAX_PLATFORMS", "tpu").split(",")[0]
@@ -183,8 +190,9 @@ def prepare(cat: Catalog, workload: str, seed: int, trace: bool,
         env["DYN_PROFILE_DIR"] = profile_dir
         env["DYN_PROFILE_STEPS"] = str(int(mix.get("trace_steps", 128)))
     return Setup(cell=cell, config=config, mix=mix, gen=gen, topo=topo,
-                 scratch=scratch, model_dir=model_dir, engine=engine,
-                 engine_seed=engine_seed, env=env, profile_dir=profile_dir)
+                 reference=reference, scratch=scratch, model_dir=model_dir,
+                 engine=engine, engine_seed=engine_seed, env=env,
+                 profile_dir=profile_dir)
 
 
 def bring_up(su: Setup, rehearsal: bool):
@@ -256,11 +264,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     finally:
         handle.stop()
 
-    # ---- (b) the sample against the float32 reference, chip now free ----
+    # ---- (b) the sample against the configuration's float32 reference
+    # (references/<name>.py, run by harness/reference.py), chip now free ----
     bad = [r for r in served if not r.ok()]
     if bad:
         raise BenchError(f"correctness sample failed to serve: {bad[0]}")
     job = {"config": {k: v for k, v in config.items() if k != "benchmark"},
+           "reference": su.reference,
+           "catalog": {"manifest": cat.manifest_path, "roots": cat.roots[:-1]},
            "seed": engine_seed, "probe": bool(probe),
            "samples": [{"prompt": rq.prompt,
                         "served": tokens_of("".join(rs.text))}
@@ -271,7 +282,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         json.dump(job, f)
     t_ref = time.monotonic()
     _run_child("benchmarks.harness.reference", [job_path, ref_path], env,
-               os.path.join(scratch, "reference.log"), 600.0)
+               os.path.join(scratch, "reference.log"),
+               float(config["benchmark"].get("reference_timeout_s", 600.0)))
     with open(ref_path) as f:
         ref = json.load(f)
     served_cmp = [{"tokens": s["served"], "logprobs": rs.logprobs}

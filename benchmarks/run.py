@@ -29,6 +29,24 @@ from benchmarks.harness.catalog import BenchError  # noqa: E402
 from benchmarks.harness.cell import run_cell  # noqa: E402
 
 
+def compared(line) -> str:
+    """Every number ``correct`` was decided from, beside its limit: the last
+    lines of standard error in every run, so that the record of a run that
+    is not correct says which comparison it failed."""
+    sample, checks = line["checks"]["sample"], line["checks"]
+    tol = sample["tolerances"]
+    rows = [("failed requests", line["failed"], 0),
+            ("rel_rms_diff", sample["rel_rms_diff"], tol["rel_rms"]),
+            ("rel_max_diff", sample["rel_max_diff"], tol["rel_max"]),
+            ("rel_tie_gap", sample["rel_tie_gap"], tol["rel_tie"]),
+            ("compiled_in_window", checks["compiled_in_window"], 0),
+            ("compile_seconds_in_window",
+             checks["compile_seconds_in_window"], 0)]
+    return "\n".join(f"compared {name}: {value:.6g} (limit {limit:g})"
+                     for name, value, limit in rows) \
+        + f"\ncorrect: {str(line['correct']).lower()}"
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--workload", required=True)
@@ -45,6 +63,7 @@ def main(argv=None) -> int:
     except BenchError as e:
         print(f"benchmark run failed: {e}", file=sys.stderr)
         return 1
+    print(compared(line), file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return code
 

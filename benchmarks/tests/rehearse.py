@@ -3,9 +3,10 @@
 
     python benchmarks/tests/rehearse.py [--seconds 4]
 
-Runs the throw-away cells of ``tests/rehearsal/`` (a configuration, a mix, a
-generator and a metric of each kind that exist ONLY as new files there, found
-by name through a manifest of their own) with ``--trace 0`` and ``--trace 1``,
+Runs the throw-away cells of ``tests/rehearsal/`` (configurations, mixes, a
+generator, a topology, a float32 reference of another architecture and a
+metric of each kind that exist ONLY as new files there, found by name through
+a manifest of their own) with ``--trace 0`` and ``--trace 1``,
 children on the CPU. It can never print a passing line: every result says
 ``"correct": false, "rehearsal": true`` and the script exits 2 when all it
 rehearsed went well, 1 otherwise. Times it prints are CPU times and mean
@@ -38,7 +39,7 @@ def main() -> int:
     root = os.path.join(HERE, "rehearsal")
     cat = Catalog(os.path.join(root, "BENCHMARK.json"), roots=[root])
     ok = True
-    for workload in ("tiny-qwen.drip", "tiny-qwen.loop"):
+    for workload in ("tiny-qwen.drip", "tiny-qwen.loop", "tiny-moe.drip"):
         for trace in (False, True):
             try:
                 code, line = run_cell(workload, a.seed, a.seconds, trace,

@@ -60,6 +60,39 @@ def test_the_command_fails_fast_where_jax_is_held_off_the_tpu():
     assert "no TPU" in r.stderr
 
 
+@pytest.mark.parametrize("reference", [None, "no-such-reference"])
+def test_the_command_fails_before_any_server_without_a_reference(
+        tmp_path, reference):
+    """A checkout of its own (the manifest, ``benchmarks/`` and a program
+    directory that holds nothing to start) whose configuration names no
+    reference, or one that is not there."""
+    import json
+    import shutil
+
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copytree(os.path.join(ROOT, "benchmarks"), tmp_path / "benchmarks",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    os.makedirs(tmp_path / "dynamo_tpu")
+    path = tmp_path / "benchmarks" / "configs" / "qwen2-1.5b.json"
+    with open(path) as f:
+        config = json.load(f)
+    assert config["benchmark"].pop("reference") == "llama"
+    if reference:
+        config["benchmark"]["reference"] = reference
+    with open(path, "w") as f:
+        json.dump(config, f)
+    env = {**os.environ, "JAX_PLATFORMS": "tpu"}   # the parent imports no jax
+    r = subprocess.run(
+        [sys.executable, str(tmp_path / "benchmarks" / "run.py"),
+         "--workload", "qwen2-1.5b.chat", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], capture_output=True, text=True, timeout=120,
+        env=env, cwd=tmp_path)
+    assert r.returncode == 1 and r.stdout.strip() == ""
+    assert r.stderr.startswith("benchmark run failed: ")
+    assert "reference" in r.stderr
+    assert not os.path.exists(tmp_path / ".bench_scratch")   # nothing began
+
+
 def test_warm_rounds_go_on_only_while_the_compile_cache_grows(monkeypatch):
     import numpy as np
 
@@ -82,3 +115,23 @@ def test_warm_rounds_go_on_only_while_the_compile_cache_grows(monkeypatch):
     sent.clear()
     monkeypatch.setattr(cell, "_cache_entries", lambda: None)    # no cache
     assert cell._warm_set("http://x", Src(), 1, {"max_batch": 4}) == (7, 1)
+
+
+def test_every_number_compared_is_printed_beside_its_limit():
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "bench_run", os.path.join(ROOT, "benchmarks", "run.py"))
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    line = {"correct": False, "failed": 2, "checks": {
+        "compiled_in_window": 0.0, "compile_seconds_in_window": 0.0,
+        "sample": {"rel_rms_diff": 0.31, "rel_max_diff": 1.2,
+                   "rel_tie_gap": 3.4,
+                   "tolerances": {"rel_rms": 0.275, "rel_max": 3.0,
+                                  "rel_tie": 3.0}}}}
+    out = run.compared(line).splitlines()
+    assert out[-1] == "correct: false"
+    assert "compared failed requests: 2 (limit 0)" in out
+    assert "compared rel_rms_diff: 0.31 (limit 0.275)" in out
+    assert "compared rel_tie_gap: 3.4 (limit 3)" in out
+    assert len(out) == 7

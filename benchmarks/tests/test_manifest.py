@@ -106,6 +106,34 @@ def test_every_name_in_the_manifest_finds_its_files(cat):
         assert callable(cat.module("layer_metrics", x["name"]).reduce)
 
 
+def test_every_configuration_names_a_reference_that_is_there(cat):
+    pytest.importorskip("jax")      # a reference file may import the program
+    for c in cat.manifest["configs"]:
+        name = cat.data("configs", c["name"])["benchmark"]["reference"]
+        ref = cat.module("references", name)
+        assert callable(ref.build) and callable(ref.tail_logprobs)
+
+
+@pytest.mark.parametrize("reference", [None, "no-such-reference"])
+def test_no_reference_or_an_unknown_one_is_an_error_not_a_default(
+        tmp_path, reference):
+    from benchmarks.harness import cell
+
+    rehearsal = os.path.join(BENCH, "tests", "rehearsal")
+    with open(os.path.join(rehearsal, "configs", "tiny-qwen.json")) as f:
+        config = json.load(f)
+    del config["benchmark"]["reference"]
+    if reference:
+        config["benchmark"]["reference"] = reference
+    os.makedirs(tmp_path / "configs")
+    with open(tmp_path / "configs" / "tiny-qwen.json", "w") as f:
+        json.dump(config, f)
+    cat = Catalog(os.path.join(rehearsal, "BENCHMARK.json"),
+                  roots=[str(tmp_path), rehearsal])
+    with pytest.raises(BenchError, match="reference"):
+        cell.prepare(cat, "tiny-qwen.drip", 1, False, rehearsal=True)
+
+
 def test_files_under_paths_are_named_from_the_allowed_characters():
     bad = []
     for base, dirs, files in os.walk(BENCH):

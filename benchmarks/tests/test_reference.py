@@ -1,6 +1,6 @@
-"""The float32 reference against a two-layer case computed by hand: plain
-numpy, explicit loops over heads and positions, written from the published
-equations and sharing no line with ``reference.py``."""
+"""The float32 reference ``references/llama.py`` against a two-layer case
+computed by hand: plain numpy, explicit loops over heads and positions,
+written from the published equations and sharing no line with it."""
 
 import math
 
@@ -10,6 +10,9 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from benchmarks.harness import reference  # noqa: E402
+from benchmarks.harness.catalog import Catalog  # noqa: E402
+
+llama_ref = Catalog().module("references", "llama")
 
 DIMS = {"L": 2, "D": 8, "Hq": 4, "Hkv": 2, "Dh": 4, "F": 12, "V": 11,
         "theta": 10000.0, "eps": 1e-6}
@@ -94,21 +97,21 @@ def test_reference_matches_the_hand_computed_case(bias, tied):
     p = _params(rng, bias, tied)
     tokens = np.array([3, 1, 4, 1, 5, 9, 2, 6], np.int32)
     want = _by_hand(p, tokens)
-    got = np.asarray(reference.tail_logprobs(
-        jax.tree.map(jax.numpy.asarray, p), DIMS, jax.numpy.asarray(tokens),
-        first=2, n_tail=5, layers_on=jax.numpy.ones(2)))
+    got = np.asarray(llama_ref.tail_logprobs(
+        {"params": jax.tree.map(jax.numpy.asarray, p), "dims": DIMS}, tokens,
+        first=2, n_tail=5, variant="full"))
     assert got.shape == (5, DIMS["V"])
     assert np.abs(got - want[2:7]).max() < 2e-5
 
 
 def test_padding_after_the_scored_positions_is_inert():
     rng = np.random.default_rng(5)
-    p = jax.tree.map(jax.numpy.asarray, _params(rng, True, True))
+    state = {"params": jax.tree.map(jax.numpy.asarray,
+                                    _params(rng, True, True)), "dims": DIMS}
     a = np.array([3, 1, 4, 1, 5, 0, 0, 0], np.int32)
     b = np.array([3, 1, 4, 1, 5, 7, 7, 7], np.int32)
-    f = lambda t: np.asarray(reference.tail_logprobs(
-        p, DIMS, jax.numpy.asarray(t), first=1, n_tail=4,
-        layers_on=jax.numpy.ones(2)))
+    f = lambda t: np.asarray(llama_ref.tail_logprobs(
+        state, t, first=1, n_tail=4, variant="full"))
     assert np.array_equal(f(a), f(b))
 
 
@@ -116,10 +119,12 @@ def test_the_probes_break_the_model_and_the_comparison_sees_it():
     from benchmarks.harness import correct
 
     rng = np.random.default_rng(6)
-    p = jax.tree.map(jax.numpy.asarray, _params(rng, True, True))
+    state = {"params": jax.tree.map(jax.numpy.asarray,
+                                    _params(rng, True, True)), "dims": DIMS}
     samples = [{"prompt": [3, 1, 4, 1], "served": [5, 9, 2]}]
-    full = reference.score_samples(p, DIMS, samples)
-    dropped = reference.score_samples(p, DIMS, samples, "dropped_layer")
+    full = reference.score_samples(llama_ref, state, samples)
+    dropped = reference.score_samples(llama_ref, state, samples,
+                                      "dropped_layer")
     served = [{"tokens": samples[0]["served"],
                "logprobs": full[0]["served_logprob"]}]
     assert correct.compare(served, full)["rel_max_diff"] == 0.0
