@@ -504,6 +504,31 @@ class EngineCore:
                         out_shardings=self.kv_sharding)
         self.k_pool = zeros()
         self.v_pool = zeros()
+        # a model with an indexer (learned top-k attention) keeps its index
+        # keys in a third pool on the SAME pages and page tables: allocated,
+        # donated, written and threaded through the programs with the other
+        # two. Whatever moves blocks off the device pool knows two pools, and
+        # a block that came back without its index keys would select wrongly
+        # without any error: such a model refuses those features by name.
+        self.i_pool = None
+        if m.has_indexer:
+            for on, what in (
+                    (cfg.pp > 1, "pp > 1 (the staged forward)"),
+                    (cfg.sp > 1 or impl == "ring", "sp > 1 / ring prefill"),
+                    (cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0,
+                     "the host / disk KV tiers (host_cache_blocks, "
+                     "disk_cache_blocks), and with them cluster "
+                     "write-through, tier prefetch and the paged "
+                     "long-context lane"),
+                    (cfg.cluster_writethrough, "cluster write-through"),
+                    (self.spec is not None, "speculative decoding (verify)"),
+                    (jax.process_count() > 1, "multi-host serving")):
+                if on:
+                    raise ValueError(self._indexer_refusal(what))
+            self.idx_sharding = NamedSharding(self.mesh, P())
+            i_shape = llama.index_pool_shape(m, num_pages, cfg.page_size)
+            self.i_pool = jax.jit(lambda: jnp.zeros(i_shape, m.dtype),
+                                  out_shardings=self.idx_sharding)()
 
         # --- KV block manager: tiered offload + prefix reuse ----------
         from ..llm.kvbm.transfer import CopyStream
@@ -642,6 +667,9 @@ class EngineCore:
         # straight into the next one, so the host fetch of one dispatch's
         # results overlaps the next dispatch's execution instead of gating it.
         self._inflight: Deque[Dict[str, Any]] = collections.deque()
+        # a DYN_PROFILE_DIR capture is running (set by the engine thread's
+        # loop before each step): see _count_model_work
+        self.capturing = False
         self._deferred_release: List[str] = []
         self._pending_seeds: List[Tuple[int, int]] = []
         # seq_id -> admission's prefix-restore length, consumed by step()'s
@@ -696,6 +724,86 @@ class EngineCore:
         if cfg.warmup:
             self.warmup()
 
+    @staticmethod
+    def _indexer_refusal(what: str) -> str:
+        return (f"a model with an indexer (learned top-k attention) does "
+                f"not run with {what}: it would move KV blocks without "
+                f"their index keys, and a block that comes back without "
+                f"them selects wrongly without any error")
+
+    def _refuse_indexer(self, what: str) -> None:
+        if self.cfg.model.has_indexer:
+            raise ValueError(self._indexer_refusal(what))
+
+    def _idx(self) -> Dict[str, Any]:
+        """The programs' one more operand: the index-key pool of a model
+        with an indexer, nothing for every other model (whose programs
+        therefore compile to what they always did)."""
+        return {} if self.i_pool is None else {"i_pool": self.i_pool}
+
+    def _program_extras(self):
+        """-> (jit options, out_shardings tail, whether ``packed`` carries
+        the experts-hit column) of this model's bucket programs."""
+        m = self.cfg.model
+        if m.has_indexer:
+            return ({"donate_argnames": ("i_pool",)}, (self.idx_sharding,),
+                    bool(m.num_experts))
+        return {}, (), bool(m.num_experts) and self.cfg.pp == 1
+
+    def _take_pools(self, pools) -> None:
+        """(k_pool, v_pool[, i_pool]) as a program returned them."""
+        self.k_pool, self.v_pool, *rest = pools
+        if rest:
+            self.i_pool, = rest
+
+    def _count_model_work(self, kind: str, spans, hit,
+                          captured: bool = False, S: int = 0) -> None:
+        """Host counters of what a dispatch made the experts and the
+        indexer do. ``spans``: (first position, queries) per lane; a query
+        at position p sees p + 1 keys. ``hit``: experts hit, read from the
+        dispatch's packed result (None for a dense model). ``captured``:
+        the dispatch was enqueued while a ``DYN_PROFILE_DIR`` capture ran,
+        so its device time is in the trace: the same amounts also go to
+        ``dyn_profile_captured_work_total``, with the dispatch itself and
+        its tokens, so that a reader of the trace knows the work of the
+        traced dispatches themselves and not a window's mean; ``S``, the
+        dispatch's context bucket, says whether its program scored at all
+        (a bucket no longer than ``index_topk`` selects every visible key
+        by construction and skips the scoring): ``scored_keys``."""
+        m = self.cfg.model
+        if not (m.num_experts or m.has_indexer):
+            return
+        tokens = sum(n for _, n in spans)
+        work = {}
+        if m.num_experts:
+            work[self.stage.moe_assignments] = float(
+                tokens * m.experts_per_token * m.num_layers)
+            if hit is not None:
+                work[self.stage.moe_experts_hit] = float(hit)
+        if m.has_indexer:
+            k = m.index_topk
+            seen = sel = 0
+            for p0, n in spans:
+                seen += n * p0 + n * (n + 1) // 2
+                # min(p + 1, k) summed over p = p0 .. p0 + n - 1
+                below = max(0, min(n, k - p0))
+                sel += (below * p0 + below * (below + 1) // 2
+                        + (n - below) * k)
+            work[self.stage.sparse_attn_context] = float(seen)
+            work[self.stage.sparse_attn_selected] = float(sel)
+        for counter, amount in work.items():
+            counter.inc(kind, amount=amount)
+        if captured:
+            seen_by = self.stage.profile_captured_work
+            for counter, amount in work.items():
+                seen_by.inc(counter.name, kind, amount=amount)
+            seen_by.inc("dispatches", kind)
+            seen_by.inc("tokens", kind, amount=float(tokens))
+            if m.has_indexer and S > m.index_topk:
+                seen_by.inc("scored_keys", kind, amount=float(seen))
+                seen_by.inc("scoring_dispatches", kind)
+                seen_by.inc("scoring_tokens", kind, amount=float(tokens))
+
     # ------------------------------------------------------------------
     def warmup(self) -> None:
         """Compile every bucket program up front on dummy inputs.
@@ -729,10 +837,12 @@ class EngineCore:
             # one program serves chained and unchained dispatches alike
             # (_run_decode_program commits host tokens to the sharding the
             # previous dispatch's on-device tokens carry)
-            (_, _, _, self.k_pool, self.v_pool, self.gen_counts) = fn(
+            _, _, _, kp, vp, self.gen_counts, *ip = fn(
                 self.params, zb, self.k_pool, self.v_pool, pt, ones,
                 s.temperature, s.top_p, s.top_k, s.key,
-                self.gen_counts, fresh, act, s.freq_pen, s.pres_pen)
+                self.gen_counts, fresh, act, s.freq_pen, s.pres_pen,
+                **self._idx())
+            self._take_pools((kp, vp, *ip))
             n += 1
             if self.spec is not None:
                 # spec enabled: also pre-compile every (S, K-bucket) verify
@@ -753,14 +863,15 @@ class EngineCore:
                     fn = self._prefill_fn(Bp, C, S)
                     zt = np.zeros((Bp, C), np.int32)
                     keys = s.key[jnp.asarray(np.zeros(Bp, np.int32))]
-                    _, _, _, self.k_pool, self.v_pool = fn(
+                    _, _, _, *pools = fn(
                         self.params, zt, zt, self.k_pool, self.v_pool,
                         zt, np.zeros((Bp, S), np.int32),
                         np.zeros((Bp, S), np.int32),
                         np.zeros((Bp, S), bool),
                         np.zeros(Bp, np.int32), np.zeros(Bp, np.float32),
                         np.ones(Bp, np.float32), np.zeros(Bp, np.int32),
-                        keys)
+                        keys, **self._idx())
+                    self._take_pools(pools)
                     n += 1
         if self.proposer is not None:
             n += self.proposer.warmup()   # draft model's own bucket set
@@ -813,12 +924,13 @@ class EngineCore:
             # equivalent-but-differently-spec'd sharding and every *other*
             # bucket program compiles a second variant against it
             B = self.cfg.max_batch
+            jit_kw, out_tail, hit_col = self._program_extras()
 
-            @partial(jax.jit, donate_argnums=(2, 3, 10),
-                     out_shardings=(rep, rep, rep, kv, kv, rep))
+            @partial(jax.jit, donate_argnums=(2, 3, 10), **jit_kw,
+                     out_shardings=(rep, rep, rep, kv, kv, rep, *out_tail))
             def step(params, tokens, k_pool, v_pool, page_tables, lengths,
                      temp, top_p, top_k, key, counts, fresh, active,
-                     freq_pen, pres_pen):
+                     freq_pen, pres_pen, i_pool=None):
                 # lanes whose sequence just entered decode restart their
                 # generated-token counts at one-hot(first generated token);
                 # chained dispatches pass fresh all-False
@@ -830,7 +942,8 @@ class EngineCore:
                 act = active.astype(jnp.int32)
 
                 def one(carry, _):
-                    tokens, lengths, k_pool, v_pool, key, counts = carry
+                    tokens, lengths, k_pool, v_pool, key, counts, *ip = carry
+                    stats: Dict[str, Any] = {}
                     if cfg.pp > 1:
                         # in-stage kernels: flash per pp×tp shard (the
                         # paged kernel would need page tables threaded
@@ -841,9 +954,11 @@ class EngineCore:
                             attn_impl=("flash" if impl == "pallas"
                                        else "xla"))
                     else:
-                        logits, k_pool, v_pool = llama.forward_decode(
+                        logits, k_pool, v_pool, *ip = llama.forward_decode(
                             params, cfg.model, tokens, k_pool, v_pool,
-                            page_tables, lengths, attn_impl=impl, mesh=mesh)
+                            page_tables, lengths, attn_impl=impl, mesh=mesh,
+                            stats=stats,
+                            **({"i_pool": ip[0]} if ip else {}))
                     lg = apply_penalties(logits[:, 0], counts, freq_pen,
                                          pres_pen)
                     tok, logp, new_key = sample(lg, temp, top_p, top_k, key)
@@ -851,16 +966,26 @@ class EngineCore:
                     # a deferred (pool-pressure) lane's garbage tokens must
                     # not poison its penalties when it resumes
                     counts = counts.at[lane, tok].add(act)
+                    ys = (tok, logp) + ((stats["experts_hit"],)
+                                        if hit_col else ())
                     return ((tok, lengths + 1, k_pool, v_pool, new_key,
-                             counts), (tok, logp))
+                             counts, *ip), ys)
 
-                carry = (tokens, lengths, k_pool, v_pool, key, counts)
-                (tok, lengths, k_pool, v_pool, key, counts), (toks, logps) \
-                    = jax.lax.scan(one, carry, None, length=N)
+                carry = (tokens, lengths, k_pool, v_pool, key, counts,
+                         *(() if i_pool is None else (i_pool,)))
+                ((tok, lengths, k_pool, v_pool, key, counts, *ip),
+                 (toks, logps, *hit)) = jax.lax.scan(one, carry, None,
+                                                     length=N)
                 # token ids < 2^24 are exact in f32, so one packed array
-                # (one host fetch) carries both streams losslessly
-                packed = jnp.stack([toks.astype(jnp.float32), logps], -1)
-                return packed, tok, key, k_pool, v_pool, counts
+                # (one host fetch) carries both streams losslessly; a
+                # routed model's experts hit in each step ride a third
+                # column (the same number on every lane)
+                cols = [toks.astype(jnp.float32), logps]
+                if hit_col:
+                    cols.append(jnp.broadcast_to(
+                        hit[0].astype(jnp.float32)[:, None], toks.shape))
+                packed = jnp.stack(cols, -1)
+                return (packed, tok, key, k_pool, v_pool, counts, *ip)
 
             from ..utils.roofline import instrument_compile
             self._decode_fns[S] = instrument_compile(
@@ -884,13 +1009,16 @@ class EngineCore:
             # pp microbatching: shared rule with forward_decode_pp
             M = llama.pp_microbatches(Bp, cfg.pp)
             page = self.page_size
+            jit_kw, out_tail, hit_col = self._program_extras()
 
-            @partial(jax.jit, donate_argnums=(3, 4),
-                     out_shardings=(rep, rep, rep, kv, kv))
+            @partial(jax.jit, donate_argnums=(3, 4), **jit_kw,
+                     out_shardings=(rep, rep, rep, kv, kv, *out_tail))
             def fn(params, tokens, positions, k_pool, v_pool, write_idx,
                    read_idx, read_pos, read_valid, last_i, temp, top_p,
                    top_k, keys, ov_vals=None, ov_mask=None, q_span=None,
-                   read_span=None):
+                   read_span=None, i_pool=None):
+                stats: Dict[str, Any] = {}
+                ip = ()
                 if cfg.pp > 1:
                     def mb(a):
                         return a.reshape(M, Bp // M, *a.shape[1:])
@@ -905,11 +1033,12 @@ class EngineCore:
                     # image waves run the xla attention path: the span
                     # or-mask has no Pallas kernel input (text waves keep
                     # the fast path — mm programs compile separately)
-                    logits, k_pool, v_pool = llama.forward(
+                    logits, k_pool, v_pool, *ip = llama.forward(
                         params, cfg.model, tokens, positions, k_pool, v_pool,
                         write_idx, read_idx, read_pos, read_valid,
                         attn_impl="xla" if mm else impl, mesh=mesh,
-                        logits_idx=last_i,
+                        logits_idx=last_i, stats=stats,
+                        **({} if i_pool is None else {"i_pool": i_pool}),
                         embed_override=((ov_vals, ov_mask) if mm else None),
                         attn_spans=((q_span, read_span) if mm else None),
                         # read slots come from PagePool.read_slots: whole
@@ -918,8 +1047,12 @@ class EngineCore:
                         read_pages=read_idx[:, ::page] // page)
                 tok, logp, new_keys = sample(
                     logits[:, 0], temp, top_p, top_k, keys)
-                packed = jnp.stack([tok.astype(jnp.float32), logp], -1)
-                return packed, tok, new_keys, k_pool, v_pool
+                cols = [tok.astype(jnp.float32), logp]
+                if hit_col:
+                    cols.append(jnp.broadcast_to(
+                        stats["experts_hit"].astype(jnp.float32), logp.shape))
+                packed = jnp.stack(cols, -1)
+                return (packed, tok, new_keys, k_pool, v_pool, *ip)
 
             from ..utils.roofline import instrument_compile
             self._prefill_batch_fns[(Bp, C, S, mm)] = instrument_compile(
@@ -1092,6 +1225,7 @@ class EngineCore:
         With ``layer`` set, returns that layer only ([T,Hkv,Dh] k, v) for
         layer-pipelined transfer; otherwise all layers ([L,T,Hkv,Dh]).
         ``count`` limits extraction to the first N tokens (e.g. the prompt)."""
+        self._refuse_indexer("disaggregated KV extract")
         sc = self.pool.seqs[seq_id]
         n = sc.num_tokens if count is None else min(count, sc.num_tokens)
         slots = jnp.asarray(self.pool.write_slots(seq_id, 0, n))
@@ -1133,6 +1267,7 @@ class EngineCore:
         sample its first token, gather the prompt KV to host, release the
         slot. Returns (k [L,T,Hkv,Dh], v, first_token, first_logprob).
         The caller owns queue/transfer; this runs on the engine thread."""
+        self._refuse_indexer("disaggregated prefill (KV extract)")
         from dataclasses import replace
 
         prompt = list(request.token_ids)
@@ -1181,6 +1316,7 @@ class EngineCore:
         """Receive a remotely-prefilled sequence: write its prompt KV into
         this pool and enter it straight into decode (prefill_done=len).
         ``k``/``v``: [L, T, Hkv, Dh] for the prompt tokens."""
+        self._refuse_indexer("disaggregated KV inject")
         if None not in self.slots:
             raise RuntimeError("no free slot for injected sequence")
         prompt = list(request.token_ids)
@@ -1243,6 +1379,7 @@ class EngineCore:
         no stored events, no write-through) until :meth:`
         finish_stream_inject` — a torn stream releases them with nothing
         ever having referenced them."""
+        self._refuse_indexer("layer-streamed KV inject")
         prompt = list(request.token_ids)
         if None not in self.slots:
             raise RuntimeError("no free slot for streamed sequence")
@@ -1568,6 +1705,7 @@ class EngineCore:
         Safe concurrently with the engine thread: the tier is internally
         locked, staged arrays are fresh device buffers nothing else
         references, and the stage dict is lock-guarded."""
+        self._refuse_indexer("tier prefetch staging")
         from ..llm.tokens import compute_seq_hashes
         from ..utils.knobs import env_float
 
@@ -1882,10 +2020,11 @@ class EngineCore:
                 mm_arrays["ov_mask"], mm_arrays["q_span"],
                 mm_arrays["read_span"])
         else:
-            packed, _tok, new_keys, self.k_pool, self.v_pool = fn(
+            packed, _tok, new_keys, *pools = fn(
                 self.params, tokens, positions, self.k_pool, self.v_pool,
                 write_idx, read_idx, read_pos, read_valid, last_i,
-                temp, top_p, top_k, keys)
+                temp, top_p, top_k, keys, **self._idx())
+            self._take_pools(pools)
         self.phase.to("prefill_build")
         # persist advanced PRNG keys only for lanes that really sampled
         if last_lanes:
@@ -1993,6 +2132,7 @@ class EngineCore:
                 "last_lanes": last_lanes, "mm": bool(mm_arrays),
             }, arrays)
         t_disp = time.perf_counter()
+        captured = self.capturing
         for _, slot, _, _, _ in work:
             slot.chunks += 1
         packed = self._run_prefill_program(
@@ -2008,6 +2148,10 @@ class EngineCore:
         # [Bp,2] (token,logprob) array per dispatch, batched across lanes
         packed_np = np.asarray(packed)            # ONE host fetch
         self.phase.to("emit")
+        self._count_model_work(
+            "prefill", [(w[2], w[3]) for w in work],
+            packed_np[0, 2] if packed_np.shape[-1] > 2 else None, captured,
+            S)
         now = time.monotonic()
         if not self._take_compiled_flag():
             from ..utils.roofline import prefill_cost
@@ -2189,6 +2333,7 @@ class EngineCore:
                                "active": active,
                                "lengths": [phys for _, _, phys in active],
                                "compiled": self._take_compiled_flag(),
+                               "captured": self.capturing, "S": S,
                                "dispatched_at": time.perf_counter()})
         # flight recorder: the hang watchdog judges "a dispatch in flight
         # with no fetch completing for N x the EWMA step time" off this
@@ -2215,11 +2360,12 @@ class EngineCore:
         s = self.sampling
         fn = self._decode_fn(S)
         self.phase.to("decode", f"dynamo.decode[S{S}]")
-        (packed, final_tok, new_key, self.k_pool, self.v_pool,
-         self.gen_counts) = fn(
+        packed, final_tok, new_key, kp, vp, self.gen_counts, *ip = fn(
             self.params, tokens, self.k_pool, self.v_pool,
             page_tables, lengths, s.temperature, s.top_p, s.top_k, s.key,
-            self.gen_counts, fresh, active_mask, s.freq_pen, s.pres_pen)
+            self.gen_counts, fresh, active_mask, s.freq_pen, s.pres_pen,
+            **self._idx())
+        self._take_pools((kp, vp, *ip))
         self.phase.to("decode_build")
         s.key = new_key
         self._last_final_tok = final_tok
@@ -2448,6 +2594,10 @@ class EngineCore:
         packed_np = np.asarray(rec["packed"])     # [N, B, 2] — ONE fetch
         self.phase.to("emit")
         N = packed_np.shape[0]
+        self._count_model_work(
+            "decode", [(s0 - 1, N) for s0 in rec["lengths"]],
+            packed_np[:, 0, 2].sum() if packed_np.shape[-1] > 2 else None,
+            rec.get("captured", False), rec.get("S", 0))
         if N and "dispatched_at" in rec:
             # effective per-token decode latency: dispatch -> results on
             # host, amortized over the dispatch's N steps (pipelined
@@ -2758,6 +2908,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                 self._wake.clear()
                 continue
             capture.before_step()
+            self.core.capturing = capture.active
             try:
                 outs = self.core.step()
             except Exception as e:  # engine must never die silently

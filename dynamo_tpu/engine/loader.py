@@ -48,6 +48,15 @@ def load_llama_params_host(path: str, cfg: LlamaConfig) -> Dict[str, Any]:
     WITHOUT any device placement — the weight-mobility cache pins these
     trees in host RAM so a later hot-swap pays only the h2d, and
     :func:`load_llama_params` device_puts the same tree at cold load."""
+    if cfg.has_indexer or cfg.moe_intermediate_size:
+        # no tensor names are published for this family (Keye-VL-2.0: index
+        # projections, experts of their own width): guessing a layout would
+        # load wrong weights without an error. Random init is the supported
+        # path (no params_path).
+        raise ValueError(
+            f"cannot load a checkpoint from {path}: the tensor names of a "
+            f"model with an indexer / experts of their own width are not "
+            f"published; serve it without weights (random init)")
     tensors = _open_all(path)
     L, D, Hq, Hkv, Dh = (cfg.num_layers, cfg.hidden_size, cfg.num_heads,
                          cfg.num_kv_heads, cfg.head_dim)

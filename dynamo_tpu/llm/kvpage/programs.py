@@ -275,6 +275,9 @@ class PagedPrograms:
         m = cfg.model
         if m.num_experts:
             return "MoE models"
+        if m.has_indexer:
+            return ("models with an indexer (learned top-k attention): the "
+                    "segmented forward carries no index keys")
         if m.vision is not None:
             return "VLM deployments (image spans need the dense path)"
         if cfg.pp > 1 or cfg.sp > 1:

@@ -146,6 +146,17 @@ class LlamaConfig:
     # MoE (0 experts = dense FFN). Experts shard over the ep mesh axis.
     num_experts: int = 0
     experts_per_token: int = 2
+    # expert FFN width where it is not the dense width (None: the experts
+    # are ``intermediate_size`` wide, Mixtral's convention)
+    moe_intermediate_size: Optional[int] = None
+    # learned sparse attention (DeepSeek-Sparse-Attention indexer over the
+    # GQA cache; ``index_topk`` 0 = none): ``index_heads`` index query heads
+    # of ``index_head_dim`` score every cached key through ONE index key
+    # head; a query attends to its ``index_topk`` best keys only. The index
+    # keys are cached beside K/V on the same pages (see "KV pool access").
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
     # Gemma3 VLM: a SigLIP vision tower rides alongside the text stack
     # (HF vision_config dict; models/siglip.py builds from it). Image soft
     # tokens replace ``image_token_id`` placeholder embeddings at prefill.
@@ -159,6 +170,14 @@ class LlamaConfig:
         five sliding then one full)."""
         return (self.sliding_window is not None
                 and (layer + 1) % self.sliding_pattern != 0)
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def has_indexer(self) -> bool:
+        return self.index_topk > 0
 
     @property
     def attn_scale(self) -> float:
@@ -231,9 +250,108 @@ class LlamaConfig:
             sliding_pattern=_sliding_pattern(cfg),
             rope_local_theta=(cfg.get("rope_local_base_freq", 10000.0)
                               if _is_gemma3(cfg) else None),
-            qk_norm=_is_gemma3(cfg),
+            qk_norm=_is_gemma3(cfg) or _is_qwen3_family(cfg),
             dtype=dtype,
+            **_map_experts(cfg),
+            **_map_indexer(cfg),
         )
+
+
+# model_type / architecture markers of the Qwen3(-MoE) family: per-head
+# RMSNorm on q and k after projection, before rope
+_QWEN3_MODEL_TYPES = ("qwen3", "qwen3_moe", "KeyeVL2")
+
+# every published expert key from_hf_config honours, and the training-only
+# router keys that say nothing of the forward pass
+_EXPERT_KEYS = ("num_experts", "num_local_experts", "num_experts_per_tok",
+                "moe_intermediate_size", "norm_topk_prob",
+                "decoder_sparse_step", "mlp_only_layers")
+_EXPERT_KEYS_IGNORED = ("router_aux_loss_coef", "output_router_logits",
+                        "router_jitter_noise")
+_INDEXER_KEYS = ("indexer_num_heads", "indexer_head_dim",
+                 "indexer_num_kv_heads", "topk", "q_chunk_size",
+                 "kv_chunk_size")
+
+
+def _is_qwen3_family(cfg: Dict[str, Any]) -> bool:
+    return (cfg.get("model_type") in _QWEN3_MODEL_TYPES
+            or any("Qwen3" in a for a in cfg.get("architectures", []) or []))
+
+
+def _looks_like_expert_key(k: str) -> bool:
+    return ("expert" in k or k.startswith("moe_") or "router" in k
+            or k in ("norm_topk_prob", "decoder_sparse_step",
+                     "mlp_only_layers"))
+
+
+def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """Published routed-expert keys -> ours. A config that carries an
+    expert key this does not honour RAISES: a sparse model must never be
+    served as the dense model of its ``intermediate_size``."""
+    seen = [k for k in cfg if _looks_like_expert_key(k)
+            and k not in _EXPERT_KEYS_IGNORED]
+    if not seen:
+        return {}
+    unknown = [k for k in seen if k not in _EXPERT_KEYS]
+    if unknown:
+        raise ValueError(
+            f"config carries expert keys this engine does not implement: "
+            f"{sorted(unknown)} (known: {', '.join(_EXPERT_KEYS)}); refusing "
+            f"to serve a sparse model as a dense one")
+    E = cfg.get("num_experts", cfg.get("num_local_experts"))
+    if not E:
+        raise ValueError(f"expert keys {sorted(seen)} without num_experts / "
+                         f"num_local_experts")
+    if cfg.get("num_local_experts", E) != E:
+        raise ValueError(f"num_experts {E} != num_local_experts "
+                         f"{cfg['num_local_experts']}")
+    if "num_experts_per_tok" not in cfg:
+        raise ValueError("num_experts without num_experts_per_tok")
+    if "num_experts" in cfg and "norm_topk_prob" not in cfg:
+        # the Qwen-MoE family's class default is False
+        raise ValueError("num_experts without norm_topk_prob: the family's "
+                         "default is false, which this engine does not "
+                         "implement")
+    if not cfg.get("norm_topk_prob", True):
+        raise ValueError("norm_topk_prob false is not implemented: the "
+                         "router renormalises the chosen gates "
+                         "(models/moe.route_topk)")
+    if cfg.get("mlp_only_layers"):
+        raise ValueError(f"mlp_only_layers {cfg['mlp_only_layers']} is not "
+                         f"implemented: every layer is a routed-expert layer")
+    if cfg.get("decoder_sparse_step", 1) != 1:
+        raise ValueError(f"decoder_sparse_step "
+                         f"{cfg['decoder_sparse_step']} is not implemented: "
+                         f"every layer is a routed-expert layer")
+    return {"num_experts": int(E),
+            "experts_per_token": int(cfg["num_experts_per_tok"]),
+            "moe_intermediate_size": (
+                int(cfg["moe_intermediate_size"])
+                if cfg.get("moe_intermediate_size") else None)}
+
+
+def _map_indexer(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``sa_config`` (learned top-k selection of the cache) -> ours."""
+    sa = cfg.get("sa_config")
+    if not sa:
+        return {}
+    unknown = [k for k in sa if k not in _INDEXER_KEYS]
+    if unknown:
+        raise ValueError(f"sa_config carries keys this engine does not "
+                         f"implement: {sorted(unknown)}")
+    if sa.get("indexer_num_kv_heads", 1) != 1:
+        raise ValueError(f"sa_config.indexer_num_kv_heads "
+                         f"{sa['indexer_num_kv_heads']}: one index key head "
+                         f"is what is implemented")
+    rs = cfg.get("rope_scaling") or {}
+    if rs.get("rope_type", rs.get("type", "default")) != "default":
+        raise ValueError("an indexer with scaled rotary is not implemented")
+    # q_chunk_size / kv_chunk_size tile the indexer's computation and select
+    # nothing: accepted, not read. mrope_section: with text-only input the
+    # three position axes are equal and sectioned rotary IS ordinary rotary.
+    return {"index_heads": int(sa["indexer_num_heads"]),
+            "index_head_dim": int(sa["indexer_head_dim"]),
+            "index_topk": int(sa["topk"])}
 
 
 def _sliding_pattern(cfg: Dict[str, Any]) -> int:
@@ -273,6 +391,15 @@ PRESETS: Dict[str, Dict[str, Any]] = {
                      num_kv_heads=2, head_dim=16, intermediate_size=96,
                      rope_theta=10000.0, max_position=1024, num_experts=4,
                      experts_per_token=2),
+    # tiny Keye-VL-2.0-style language model: 8 routed experts of their own
+    # width (2 a token), q/k norm, an indexer (2 index heads, top-8) whose
+    # selection binds at the tests' contexts of 24-48
+    "tiny-keye": dict(vocab_size=259, hidden_size=64, num_layers=2,
+                      num_heads=4, num_kv_heads=2, head_dim=16,
+                      intermediate_size=128, moe_intermediate_size=48,
+                      rope_theta=10000.0, max_position=1024, rms_eps=1e-6,
+                      num_experts=8, experts_per_token=2, qk_norm=True,
+                      index_heads=2, index_head_dim=16, index_topk=8),
     "llama-3.2-1b": dict(vocab_size=128256, hidden_size=2048, num_layers=16,
                          num_heads=32, num_kv_heads=8, head_dim=64,
                          intermediate_size=8192, rope_theta=500000.0,
@@ -411,23 +538,90 @@ def preset(name: str, **overrides) -> LlamaConfig:
 # ---------------------------------------------------------------------------
 
 def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
-    """Random-init params (testing/benching without checkpoint files)."""
+    """Random-init params (testing/benching without checkpoint files).
+
+    Two laws, chosen by the model's geometry. The first: stacked tensors
+    N(0, 1 / L) (the scale is taken from the STACKED tensor's first
+    dimension) and the embedding N(0, 1 / V), which every dense
+    configuration's readings and the Mixtral-style presets' tests (experts
+    as wide as the dense FFN; the benchmark's rehearsal among them, whose
+    files a later PR may not edit) were taken with. The second, for a model
+    of many FINE-GRAINED experts (experts of a width of their own,
+    ``moe_intermediate_size``), makes every activation of unit rms, as a
+    trained model's roughly are: the embedding N(0, 1), EVERY matrix of a
+    layer (attention, indexer, router, experts) N(0, 1 / fan-in), the two
+    projections that write to the residual stream (``wo``, the experts'
+    ``wd``) N(0, 1 / (2 L x fan-in)) (GPT-2's scaled residual init: the 2 L
+    branches add up to the embedding's size, whatever the depth), and the
+    per-head q/k norm weights 1.4. What each is for (my chip and CPU runs,
+    PR 28):
+
+    - router logits of spread 1, where 1 / L gave sqrt(D / L) (18.5 at
+      D 2048, L 6): a softmax that is an argmax, whose slope multiplies
+      every rounding of its input, so that the bfloat16 path and a float32
+      reference of the same weights chose other experts within three layers;
+    - a token's own embedding carries its residual stream (with N(0, 1 / V)
+      it is 0.003 beside layer outputs of 0.03-0.2, every position's stream
+      is then the same average of thousands of values, and its norm turns
+      the few keys by which two selections differ into a 14 % change);
+    - the experts' output and the attention's are of one size, neither a
+      perturbation of the other in the logits, and each a fraction of the
+      stream it is added to: top-k routing is discontinuous, the bfloat16
+      path and a float32 reference part at near-ties with nothing wrong (3 %
+      of tokens a layer), and where a layer's output is as large as the
+      stream itself every such parting moves the next router's input and
+      parts more tokens (six layers read ``rel_rms`` 0.09-0.14 sound and 0.15
+      with every weight in int8: nothing to tell apart; 0.016 and 0.04 with
+      the scaled projections);
+    - attention logits of spread 1.4 x 1.4 put a query's weight on a few
+      dozen keys, not evenly on thousands: with even weights, attention over
+      the selected 2048 keys or over all 14,000 is the same small average and
+      the selection cannot be seen in the logits. Not more, and the same for
+      every dimension: the selection of keys is discontinuous too, the
+      bfloat16 path and the reference part on a few keys in a thousand at
+      the k-th score, and the heavier single keys weigh, the more of the
+      sound runs' noise that is (1.5 with N(0, 1 / L) around it read twice
+      the noise of 1.4 flat)."""
     D, Hq, Hkv, Dh, F, L, V = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                                cfg.head_dim, cfg.intermediate_size,
                                cfg.num_layers, cfg.vocab_size)
     ks = jax.random.split(key, 10)
+    E = cfg.num_experts
+    unit = bool(E) and cfg.expert_width != F       # the second law
     s = lambda *shape: 1.0 / math.sqrt(shape[0])
 
     def norm(k, *shape):
         return (jax.random.normal(k, shape, jnp.float32) * s(*shape)).astype(cfg.dtype)
 
-    E = cfg.num_experts
+    def stack(k, fan_in, *shape, to_residual=False):
+        """A layer's matrix [*shape], stacked on L."""
+        if not unit:
+            return norm(k, L, *shape)
+        return (jax.random.normal(k, (L, *shape), jnp.float32) / math.sqrt(
+            fan_in * (2 * L if to_residual else 1))).astype(cfg.dtype)
+
     if E:
+        Fe = cfg.expert_width
+
+        def experts(k, *shape, to_residual=False):
+            # a layer at a time, cast inside the program: the float32
+            # temporary is one layer's experts (128 x 2048 x 768 x 4 B =
+            # 0.8 GB), never the stacked tensor's (4.8 GB at six layers)
+            if not unit:
+                return norm(k, L, E, *shape)
+            scale = s(*shape) / math.sqrt(2 * L if to_residual else 1)
+
+            def one(kl):
+                return (jax.random.normal(kl, (E, *shape), jnp.float32)
+                        * scale).astype(cfg.dtype)
+            return jax.jit(lambda k: jax.lax.map(one, jax.random.split(k, L))
+                           )(k)
+
         ffn = {
-            "wr": norm(ks[9], L, D, E),
-            "wg": norm(ks[5], L, E, D, F),
-            "wu": norm(ks[6], L, E, D, F),
-            "wd": norm(ks[7], L, E, F, D),
+            "wr": stack(ks[9], D, D, E),
+            "wg": experts(ks[5], D, Fe),
+            "wu": experts(ks[6], D, Fe),
+            "wd": experts(ks[7], Fe, D, to_residual=True),
         }
     else:
         ffn = {
@@ -436,14 +630,16 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             "wd": norm(ks[7], L, F, D),
         }
     params = {
-        "embed": norm(ks[0], V, D),
+        "embed": (norm(ks[0], V, D) if not unit else jax.random.normal(
+            ks[0], (V, D), jnp.float32).astype(cfg.dtype)),
         "layers": {
             "ln1": jnp.ones((L, D), jnp.float32),
             "ln2": jnp.ones((L, D), jnp.float32),
-            "wq": norm(ks[1], L, D, Hq * Dh).reshape(L, D, Hq, Dh),
-            "wk": norm(ks[2], L, D, Hkv * Dh).reshape(L, D, Hkv, Dh),
-            "wv": norm(ks[3], L, D, Hkv * Dh).reshape(L, D, Hkv, Dh),
-            "wo": norm(ks[4], L, Hq * Dh, D).reshape(L, Hq, Dh, D),
+            "wq": stack(ks[1], D, D, Hq * Dh).reshape(L, D, Hq, Dh),
+            "wk": stack(ks[2], D, D, Hkv * Dh).reshape(L, D, Hkv, Dh),
+            "wv": stack(ks[3], D, D, Hkv * Dh).reshape(L, D, Hkv, Dh),
+            "wo": stack(ks[4], Hq * Dh, Hq * Dh, D, to_residual=True
+                        ).reshape(L, Hq, Dh, D),
             **ffn,
         },
         "final_norm": jnp.ones((D,), jnp.float32),
@@ -455,8 +651,23 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         params["layers"]["ln2_post"] = norm(kn[1], L, D).astype(jnp.float32)
     if cfg.qk_norm:
         kq = jax.random.split(ks[6], 2)
-        params["layers"]["ln_q"] = norm(kq[0], L, Dh).astype(jnp.float32)
-        params["layers"]["ln_k"] = norm(kq[1], L, Dh).astype(jnp.float32)
+        for name, k in (("ln_q", kq[0]), ("ln_k", kq[1])):
+            params["layers"][name] = (
+                jnp.full((L, Dh), 1.4, jnp.float32) if unit
+                else norm(k, L, Dh).astype(jnp.float32))
+    if cfg.has_indexer:
+        Hi, Di = cfg.index_heads, cfg.index_head_dim
+        ki = jax.random.split(ks[4], 5)
+        # index queries, the ONE index key head with its LayerNorm (random
+        # weight and bias, so a dropped norm shows), and the per-head score
+        # weights, all read from the layer's normed input
+        params["layers"]["wiq"] = stack(ki[0], D, D, Hi * Di).reshape(
+            L, D, Hi, Di)
+        params["layers"]["wik"] = stack(ki[1], D, D, Di)
+        params["layers"]["wiw"] = stack(ki[2], D, D, Hi)
+        params["layers"]["ln_ik_w"] = (
+            1.0 + norm(ki[3], L, Di).astype(jnp.float32))
+        params["layers"]["ln_ik_b"] = norm(ki[4], L, Di).astype(jnp.float32)
     if cfg.attention_bias:
         kb = jax.random.split(ks[9], 3)
         # non-zero random biases so parity tests would catch a dropped bias
@@ -485,7 +696,7 @@ def param_specs(cfg: LlamaConfig, tp_size: int = 1,
         # experts shard over ep ([L, E, D, F] / [L, E, F, D]); router
         # replicated; the FFN intermediate dim additionally shards over tp
         # when divisible (matching moe_ffn's shard_map specs)
-        ftp = tp if cfg.intermediate_size % max(tp_size, 1) == 0 else None
+        ftp = tp if cfg.expert_width % max(tp_size, 1) == 0 else None
         ffn = {
             "wr": P(st, None, None),
             "wg": P(st, AXIS_EP, None, ftp),
@@ -517,6 +728,12 @@ def param_specs(cfg: LlamaConfig, tp_size: int = 1,
     if cfg.qk_norm:
         specs["layers"]["ln_q"] = P(st, None)
         specs["layers"]["ln_k"] = P(st, None)
+    if cfg.has_indexer:
+        # small next to the experts: replicated (validate_tp holds such a
+        # model to tp == 1 anyway)
+        specs["layers"]["wiq"] = P(st, None, None, None)
+        for k in ("wik", "wiw", "ln_ik_w", "ln_ik_b"):
+            specs["layers"][k] = P(st, None)
     if cfg.attention_bias:
         specs["layers"]["bq"] = P(st, tp, None)
         specs["layers"]["bk"] = P(st, kv, None)
@@ -535,6 +752,11 @@ def validate_tp(cfg: LlamaConfig, tp: int, ep: int = 1) -> None:
         raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp={tp}")
     if not cfg.num_experts and cfg.intermediate_size % tp:
         raise ValueError(f"ffn {cfg.intermediate_size} not divisible by tp={tp}")
+    if cfg.has_indexer and (tp > 1 or ep > 1):
+        raise ValueError(
+            "a model with an indexer (learned top-k attention) runs on one "
+            "chip: the index-key pool and the selection are not sharded "
+            f"(got tp={tp}, ep={ep})")
     if ep > 1:
         if not cfg.num_experts:
             raise ValueError("ep > 1 needs an MoE model (num_experts > 0)")
@@ -557,12 +779,15 @@ def validate_pp(cfg: LlamaConfig, pp: int, tp: int = 1) -> None:
 
 
 def kv_block_bytes(cfg: LlamaConfig, page_size: int) -> int:
-    """Bytes of one KV block (k+v, all layers) at device precision — the
+    """Bytes of one KV block (k+v, and the index keys of a model with an
+    indexer, all layers) at device precision — the
     ONE unit the byte-honest planes price in (engine residency gauges,
     paged-lane admission, router bytes scoring). ml_dtypes registers
     bfloat16 with numpy, so np.dtype resolves every served precision."""
-    return (2 * cfg.num_layers * cfg.num_kv_heads * page_size
-            * cfg.head_dim * np.dtype(cfg.dtype).itemsize)
+    per_token = (2 * cfg.num_kv_heads * cfg.head_dim
+                 + (cfg.index_head_dim if cfg.has_indexer else 0))
+    return (cfg.num_layers * page_size * per_token
+            * np.dtype(cfg.dtype).itemsize)
 
 
 def kv_cache_spec(cfg: LlamaConfig, tp: int, pp: int = 1) -> P:
@@ -701,14 +926,20 @@ def _attn_residual(x: jax.Array, attn_out: jax.Array, lp: Dict[str, Any],
 
 
 def _ffn_block(x: jax.Array, lp: Dict[str, Any], l: int, cfg: LlamaConfig,
-               mesh=None) -> jax.Array:
+               mesh=None, stats: Optional[Dict[str, Any]] = None
+               ) -> jax.Array:
     """Pre-norm FFN (dense or MoE) + residual; Gemma2 adds a post-norm on
-    the branch output (sandwich norms)."""
+    the branch output (sandwich norms). A routed layer adds its experts hit
+    to ``stats["experts_hit"]`` (see :func:`forward`)."""
     h2 = rms_norm(x, lp["ln2"][l], cfg.rms_eps, cfg.norm_offset)
     if cfg.num_experts:
         from .moe import moe_ffn
-        out = moe_ffn(h2, lp["wr"][l], lp["wg"][l], lp["wu"][l],
-                      lp["wd"][l], cfg.experts_per_token, mesh=mesh)
+        out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
+                           cfg.experts_per_token, mesh=mesh, layer=l)
+        if stats is not None:
+            stats["experts_hit"] = stats.get("experts_hit", 0) + hit
+            if "chosen" in stats:
+                stats["chosen"].append(chosen)
     else:
         g = jnp.einsum("btd,df->btf", h2, lp["wg"][l])
         u = jnp.einsum("btd,df->btf", h2, lp["wu"][l])
@@ -785,6 +1016,105 @@ def kv_pages(pool: jax.Array, layer, pages: jax.Array) -> jax.Array:
     return ctx.reshape(B, Hkv, P * page, Dh).transpose(0, 2, 1, 3)
 
 
+# The index keys of a model with an indexer (one head, ``index_head_dim``
+# wide) live in a third pool on the SAME pages: [L, 1, n_pages, page // f,
+# f * Di] with f = 128 // Di tokens folded into one 128-lane row (Di = 64:
+# two tokens a row), because a pool whose rows are narrower than a lane tile
+# is re-laid whole at every program's entry and exit on a v5e (PERF.md §7 b).
+# A token's slot is row ``off // f``, lanes ``(off % f) * Di ..``.
+
+def index_pool_shape(cfg: LlamaConfig, num_pages: int,
+                     page: int) -> Tuple[int, ...]:
+    f = index_fold(cfg)
+    if page % f:
+        raise ValueError(f"page size {page} does not fold by {f} "
+                         f"(index_head_dim {cfg.index_head_dim})")
+    return (cfg.num_layers, 1, num_pages, page // f, f * cfg.index_head_dim)
+
+
+def index_fold(cfg: LlamaConfig) -> int:
+    return max(1, 128 // cfg.index_head_dim)
+
+
+def index_write(pool: jax.Array, layer, w_page: jax.Array, w_off: jax.Array,
+                rows: jax.Array) -> jax.Array:
+    """Write index keys ``rows`` [n, Di] at token slots (``w_page``,
+    ``w_off``), both [n], as WHOLE 128-lane rows: each token's row is read,
+    overlaid with the new key of every token of this call that shares it
+    (its neighbours in the fold, so that rows written twice are written
+    alike), and scattered back like a K/V row. A scatter of [Di]-wide
+    windows at a lane offset runs as a serial loop of a dozen operations a
+    token on a v5e (4.7 us a token, 7 ms of a 256-token chunk's six layers;
+    my chip run, PR 28)."""
+    n, Di = rows.shape
+    f = pool.shape[-1] // Di
+    rows = rows.astype(pool.dtype)
+    if f == 1:
+        return pool.at[layer, 0, w_page, w_off].set(rows)
+    r, slot = w_off // f, w_off % f
+    old = pool[layer, 0, w_page, r].reshape(n, f, Di)
+    shares = (w_page[:, None] == w_page[None]) & (r[:, None] == r[None])
+    fills = shares[:, :, None] & (slot[None, :, None]
+                                 == jnp.arange(f)[None, None, :])  # [n,m,f]
+    new = rows[jnp.argmax(fills, axis=1)]                          # [n,f,Di]
+    merged = jnp.where(jnp.any(fills, axis=1)[..., None], new, old)
+    return pool.at[layer, 0, w_page, r].set(merged.reshape(n, f * Di))
+
+
+def index_pages(pool: jax.Array, layer, pages: jax.Array,
+                Di: int) -> jax.Array:
+    """Whole pages in order — ``pages`` [B, P] — as the lane's index keys
+    in logical order [B, P * page, Di]."""
+    B, P = pages.shape
+    return pool[layer, 0, pages].reshape(B, -1, Di)
+
+
+def _index_rope(cfg: LlamaConfig, positions: jax.Array):
+    """Rotary tables over ALL ``index_head_dim`` dims, the model's theta."""
+    Di = cfg.index_head_dim
+    inv = jnp.asarray((1.0 / (cfg.rope_theta ** (
+        np.arange(0, Di, 2, dtype=np.float64) / Di))).astype(np.float32))
+    ang = positions[..., None].astype(jnp.float32) * inv
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _index_project(h: jax.Array, lp: Dict[str, Any], l: int,
+                   cfg: LlamaConfig, cos_i: jax.Array, sin_i: jax.Array):
+    """The indexer's three projections of the layer's normed input ``h``
+    [B,T,D]: index queries [B,T,Hi,Di] and THE index key [B,T,Di]
+    (LayerNorm, then rope, as the queries), score weights [B,T,Hi]."""
+    qi = jnp.einsum("btd,dhk->bthk", h, lp["wiq"][l])
+    ki = jnp.einsum("btd,dk->btk", h, lp["wik"][l]).astype(jnp.float32)
+    mu = jnp.mean(ki, axis=-1, keepdims=True)
+    var = jnp.mean((ki - mu) ** 2, axis=-1, keepdims=True)
+    ki = ((ki - mu) * jax.lax.rsqrt(var + cfg.rms_eps) * lp["ln_ik_w"][l]
+          + lp["ln_ik_b"][l]).astype(h.dtype)
+    w = jnp.einsum("btd,dh->bth", h, lp["wiw"][l])
+    qi = apply_rope(qi, cos_i, sin_i)
+    ki = apply_rope(ki[:, :, None, :], cos_i, sin_i)[:, :, 0]
+    return qi, ki, w
+
+
+def _index_step(h: jax.Array, lp: Dict[str, Any], l: int, cfg: LlamaConfig,
+                rope_i, i_pool: jax.Array, w_page: jax.Array,
+                w_off: jax.Array, pages: jax.Array,
+                visible: Optional[jax.Array]):
+    """One layer's indexer, prefill chunk and decode step alike: write the
+    new tokens' index keys, then (``visible`` [B,T,S] given: the context
+    bucket is longer than ``index_topk``) score the lane's cached index keys
+    and keep each query's exact top-k. -> (i_pool, keep [B,T,S] or None)."""
+    from ..ops.attention import index_scores, topk_keep
+
+    qi, ki, wi = _index_project(h, lp, l, cfg, *rope_i)
+    i_pool = index_write(i_pool, l, w_page, w_off,
+                         ki.reshape(-1, ki.shape[-1]))
+    if visible is None:
+        return i_pool, None
+    ctx = index_pages(i_pool, l, pages, cfg.index_head_dim)
+    return i_pool, topk_keep(index_scores(qi, ctx, wi), visible,
+                             cfg.index_topk)
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -804,7 +1134,9 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             embed_override: Optional[Tuple[jax.Array, jax.Array]] = None,
             attn_spans: Optional[Tuple[jax.Array, jax.Array]] = None,
             read_pages: Optional[jax.Array] = None,  # [B, S // page] int32
-            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+            i_pool: Optional[jax.Array] = None,  # index keys (has_indexer)
+            stats: Optional[Dict[str, Any]] = None,
+            ) -> Tuple[jax.Array, ...]:
     """One forward pass over a token chunk against the paged KV pool.
 
     The pool is head-major ([L, Hkv, n_pages, page, Dh], read and written
@@ -826,6 +1158,19 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     ([B] int32), the LM head runs only on each lane's hidden state at that
     chunk position and logits are [B, 1, vocab] — the prefill fast path,
     which never materializes the [B, T, vocab] tensor.
+
+    A model with an indexer (``cfg.has_indexer``) also takes and returns
+    ``i_pool``, the index keys on the same pages (:func:`index_pool_shape`),
+    as a fourth result; it reads its context by page (``read_pages``). Each
+    query attends to its ``index_topk`` best visible keys; where the context
+    bucket S is no longer than ``index_topk`` that is every visible key by
+    construction, so nothing is scored there (the index keys are written all
+    the same). ``stats``, a dict, receives ``experts_hit`` (int32 scalar:
+    experts with at least one row, summed over layers) for a routed model;
+    a caller that put empty lists under ``"keep"`` / ``"chosen"`` gets each
+    layer's keep mask ([B,T,S] bool, None where nothing was scored) and
+    chosen expert ids ([B,T,K]) appended: what the tests compare with the
+    reference's own.
 
     Multimodal (Gemma3 VLM, xla attention only):
 
@@ -914,6 +1259,20 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             "image-span bidirectional attention (Gemma3 VLM) runs on "
             "attn_impl='xla' only; flash/ring kernels take no span inputs")
     _require_xla_attn(cfg, attn_impl)
+    keep = None
+    if cfg.has_indexer:
+        if i_pool is None or read_pages is None:
+            raise ValueError("a model with an indexer needs its index-key "
+                             "pool and reads its context by page")
+        if attn_impl == "ring" or attn_spans is not None:
+            raise ValueError("ring attention / image spans take no "
+                             "selection (model with an indexer)")
+        rope_i = _index_rope(cfg, positions)
+        # a context bucket no longer than topk selects every visible key by
+        # construction: nothing is scored there (exact, not a shortcut)
+        visible = (read_valid[:, None, :]
+                   & (read_pos[:, None, :] <= positions[:, :, None])
+                   ) if read_pos.shape[1] > cfg.index_topk else None
 
     # NOTE: forward_pp.apply_stage mirrors this layer body for the
     # pipeline-parallel stages; test_forward_pp pins their exactness —
@@ -940,6 +1299,9 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
         # scatter chunk KV into the pool (write-then-gather)
         k_pool = kv_write(k_pool, l, wp, wo, k.reshape(B * T, *k.shape[2:]))
         v_pool = kv_write(v_pool, l, wp, wo, v.reshape(B * T, *v.shape[2:]))
+        if cfg.has_indexer:
+            i_pool, keep = _index_step(h, lp, l, cfg, rope_i, i_pool, wp, wo,
+                                       read_pages, visible)
         # gather this sequence's context: [B, S, Hkv, Dh]
         if read_pages is not None:
             k_ctx = kv_pages(k_pool, l, read_pages)
@@ -949,25 +1311,30 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             v_ctx = kv_rows(v_pool, l, rp, ro)
         if attn_impl == "flash":
             attn = flash_for(l)(q, k_ctx, v_ctx, positions, read_pos,
-                                read_valid)
+                                read_valid,
+                                **({} if keep is None else {"keep": keep}))
         elif attn_impl == "ring":
             attn = ring_attention(q, k_ctx, v_ctx, positions, read_pos,
                                   read_valid, mesh=mesh,
                                   head_axis=head_axis,
                                   scale=cfg.attn_scale)
         else:
+            m_l = sliding_mask if cfg.layer_sliding(l) else mask
             attn = attend(q, k_ctx, v_ctx,
-                          sliding_mask if cfg.layer_sliding(l) else mask,
+                          m_l if keep is None else m_l & keep,
                           scale=cfg.attn_scale,
                           softcap=cfg.attn_logit_softcap)
         x = _attn_residual(x, jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l]),
                            lp, l, cfg)
-        x = _ffn_block(x, lp, l, cfg, mesh=mesh)
+        if stats is not None and "keep" in stats:
+            stats["keep"].append(keep)
+        x = _ffn_block(x, lp, l, cfg, mesh=mesh, stats=stats)
 
     if logits_idx is not None:
         x = jnp.take_along_axis(
             x, logits_idx[:, None, None].astype(jnp.int32), axis=1)  # [B,1,D]
-    return _lm_head(x, params, cfg), k_pool, v_pool
+    out = (_lm_head(x, params, cfg), k_pool, v_pool)
+    return out if i_pool is None else (*out, i_pool)
 
 
 def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
@@ -1012,6 +1379,10 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
     L = cfg.num_layers
     pp = _pp_size(mesh)
     _require_xla_attn(cfg, attn_impl)
+    if cfg.has_indexer:
+        raise ValueError(
+            "forward_pp does not carry the index-key pool: a model with an "
+            "indexer (learned top-k attention) is not served with pp")
     if pp == 1:
         outs = []
         li = None
@@ -1037,9 +1408,8 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
     ep_sz = (mesh.shape[AXIS_EP]
              if mesh is not None and AXIS_EP in mesh.axis_names else 1)
     E = cfg.num_experts
-    El = E // ep_sz if E else 0
     moe_tp = (tp_sz if E and tp_sz > 1
-              and cfg.intermediate_size % tp_sz == 0 else 1)
+              and cfg.expert_width % tp_sz == 0 else 1)
     page = k_pool.shape[3]
     lp = params["layers"]
 
@@ -1171,21 +1541,13 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
                     # weights are zero, so the ep psum is exact. Gating and
                     # expert math are moe.py's shared helpers: the pp path
                     # cannot silently diverge from the pp=1 moe_ffn policy.
-                    from .moe import dense_gates, expert_ffn, route_topk
-                    vals, topi = route_topk(h2, lp_loc["wr"][l],
-                                            cfg.experts_per_token)
-                    gates = dense_gates(vals, topi, E)     # [B, T, E]
-                    if ep_sz > 1:
-                        eidx = jax.lax.axis_index(AXIS_EP)
-                        gates = jax.lax.dynamic_slice_in_dim(
-                            gates, eidx * El, El, axis=2)  # local slice
-                    f = expert_ffn(h2, lp_loc["wg"][l], lp_loc["wu"][l],
-                                   lp_loc["wd"][l], gates)
-                    axes = tuple(ax for ax, n in ((AXIS_EP, ep_sz),
-                                                  (AXIS_TP, moe_tp))
-                                 if n > 1)
-                    if axes:
-                        f = jax.lax.psum(f, axes)
+                    from .moe import moe_ffn_in_stage
+                    f = moe_ffn_in_stage(
+                        h2, lp_loc["wr"][l], lp_loc["wg"][l],
+                        lp_loc["wu"][l], lp_loc["wd"][l],
+                        cfg.experts_per_token, ep=ep_sz,
+                        psum_axes=tuple(ax for ax, n in (
+                            (AXIS_EP, ep_sz), (AXIS_TP, moe_tp)) if n > 1))
                 else:
                     g = jnp.einsum("btd,df->btf", h2, lp_loc["wg"][l])
                     u = jnp.einsum("btd,df->btf", h2, lp_loc["wu"][l])
@@ -1351,7 +1713,9 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                    lengths: jax.Array,       # [B] tokens incl. current one
                    attn_impl: str = "xla",   # "xla" gather | "pallas" paged
                    mesh=None,                # for pallas at tp>1 (shard_map)
-                   ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                   i_pool: Optional[jax.Array] = None,
+                   stats: Optional[Dict[str, Any]] = None,
+                   ) -> Tuple[jax.Array, ...]:
     """Single-token decode step addressed purely by page tables.
 
     The current token sits at position ``lengths - 1``; its KV is written
@@ -1359,12 +1723,25 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     ``attn_impl="pallas"`` the paged-attention kernel reads pages straight
     from the HBM pool (no contiguous-context gather at all).
 
-    Returns (logits [B, 1, vocab] fp32, k_pool, v_pool).
+    Returns (logits [B, 1, vocab] fp32, k_pool, v_pool); ``i_pool`` and
+    ``stats`` as in :func:`forward` (the index keys come back as a fourth
+    result). The selection goes INTO the paged kernel as a keep mask over
+    the lane's logical positions.
     """
     B = tokens.shape[0]
     page = k_pool.shape[3]
     lp = params["layers"]
     pos = lengths - 1                                  # [B]
+    keep = None
+    if cfg.has_indexer:
+        if i_pool is None:
+            raise ValueError("a model with an indexer needs its index-key "
+                             "pool")
+        rope_i = _index_rope(cfg, pos[:, None])
+        S_ctx = page_tables.shape[1] * page
+        visible = (jnp.arange(S_ctx, dtype=jnp.int32)[None]
+                   < lengths[:, None])[:, None, :] if (
+            S_ctx > cfg.index_topk) else None           # [B,1,S]
     x = _embed(params, cfg, tokens)[:, None]           # [B,1,D]
     cos, sin = rope_tables(cfg, pos[:, None])
     if cfg.rope_local_theta is not None:
@@ -1432,19 +1809,27 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
             k = apply_rope(k, cos, sin)
         k_pool = kv_write(k_pool, l, w_page, w_off, k[:, 0])
         v_pool = kv_write(v_pool, l, w_page, w_off, v[:, 0])
+        if cfg.has_indexer:
+            i_pool, keep = _index_step(h, lp, l, cfg, rope_i, i_pool, w_page,
+                                       w_off, page_tables, visible)
         if attn_impl == "pallas":
             # the kernel reads the whole pool in place, by layer index
-            attn = paged_for(l)(q[:, 0], k_pool, v_pool, page_tables,
-                                lengths, jnp.int32(l))[:, None]
+            attn = paged_for(l)(
+                q[:, 0], k_pool, v_pool, page_tables, lengths, jnp.int32(l),
+                **({} if keep is None else {"keep": keep[:, 0]}))[:, None]
         else:
             k_ctx = kv_pages(k_pool, l, page_tables)   # [B,S,Hkv,Dh]
             v_ctx = kv_pages(v_pool, l, page_tables)
+            m_l = sliding_mask if cfg.layer_sliding(l) else mask
             attn = attend(q, k_ctx, v_ctx,
-                          sliding_mask if cfg.layer_sliding(l) else mask,
+                          m_l if keep is None else m_l & keep,
                           scale=cfg.attn_scale,
                           softcap=cfg.attn_logit_softcap)
         x = _attn_residual(x, jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l]),
                            lp, l, cfg)
-        x = _ffn_block(x, lp, l, cfg, mesh=mesh)
+        if stats is not None and "keep" in stats:
+            stats["keep"].append(keep)
+        x = _ffn_block(x, lp, l, cfg, mesh=mesh, stats=stats)
 
-    return _lm_head(x, params, cfg), k_pool, v_pool
+    out = (_lm_head(x, params, cfg), k_pool, v_pool)
+    return out if i_pool is None else (*out, i_pool)

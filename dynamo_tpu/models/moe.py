@@ -9,11 +9,12 @@ axis combines them (gate weights for non-local experts are zero on each
 shard, so the sum is exact).
 
 Two dispatch formulations, both exact (no capacity limit, no dropped
-tokens): compute-bound prefill chunks on an unsharded mesh use SORTED
-dispatch (stable-sort assignments by expert + ``lax.ragged_dot`` segment
-matmuls — K-per-token FFN cost); tiny decode batches and ep/tp-sharded
-meshes use DENSE dispatch (every local expert sees every token —
-compile-friendly, combines across shards with one psum).
+tokens): SORTED dispatch (stable-sort assignments by expert +
+``lax.ragged_dot`` segment matmuls — only the experts hit are read) and
+DENSE dispatch (every local expert sees every token — one einsum a matrix,
+combines across shards with one psum). An unsharded mesh chooses by
+:func:`sorted_wins` (measured on the chip for one geometry, the old rule
+outside it); ep/tp-sharded meshes are dense.
 
 Reference capability: the reference inherits MoE/EP from its engines
 (SURVEY §2.5 — vllm patch touches deepseek_v2.py); on TPU the in-tree
@@ -31,6 +32,29 @@ from jax.sharding import PartitionSpec as P
 from ..parallel.mesh import AXIS_EP, AXIS_TP
 
 
+def sorted_wins(rows: int, top_k: int, n_experts: int) -> bool:
+    """The dispatch rule of an unsharded mesh: SORTED (``lax.ragged_dot``
+    over the assignments, only the experts hit are read) or DENSE (every
+    expert sees every row).
+
+    Measured on a v5e at 128 experts of 2048 x 768, 8 a token, milliseconds
+    a layer, dense / sorted (my chip run, PR 28,
+    ``benchmarks/tests/moe_dispatch.py``): 12 rows 1.63 / 1.38 (72 of 128
+    experts hit); 64 rows 1.64 / 4.37; 256 rows 1.77 / 4.74; 512 rows 3.30 /
+    5.06. Dense streams all the weights at 740 GB/s and turns compute-bound
+    near 512 rows (187 TFLOP/s); the grouped matmul pays for 16-row groups.
+    So INSIDE what was measured (many small experts, ``n_experts >= 16 x
+    top_k``, up to 512 rows a call) sorted wins only where a call has fewer
+    assignments than experts, so that some experts are certainly idle.
+    OUTSIDE it (few large experts as Mixtral's 8 with 2 a token, where dense
+    costs 4 x the multiply-adds of a compute-bound chunk, or more than 512
+    rows) nothing is measured, and the rule is the one those models always
+    had: sorted from 16 rows on."""
+    if n_experts >= 16 * top_k and rows <= 512:
+        return rows * top_k < n_experts
+    return rows >= 16
+
+
 def _ep_size(mesh) -> int:
     if mesh is None or AXIS_EP not in mesh.axis_names:
         return 1
@@ -46,7 +70,8 @@ def _tp_size(mesh) -> int:
 def _sorted_dispatch(x: jax.Array,            # [B, T, D]
                      wg: jax.Array, wu: jax.Array, wd: jax.Array,
                      vals: jax.Array,          # [B, T, K] renormalized gates
-                     idx: jax.Array            # [B, T, K] expert ids
+                     idx: jax.Array,           # [B, T, K] expert ids
+                     layer: Optional[int] = None
                      ) -> jax.Array:
     """Exact sorted MoE dispatch: flatten (token, k) assignments, stable-sort
     by expert, run each expert's contiguous group through `lax.ragged_dot`,
@@ -54,9 +79,15 @@ def _sorted_dispatch(x: jax.Array,            # [B, T, D]
     tokens — same math as the dense formulation (summation order aside) —
     at K-per-token FFN cost
     instead of E-per-token. TPU lowers ragged_dot onto the MXU with
-    group-size prefetch."""
+    group-size prefetch.
+
+    With ``layer`` the weights are the STACKED [L, E, ...] tensors, seen as
+    L * E groups of which only this layer's hold rows: ``ragged_dot`` takes
+    its operand as a buffer of its own, so one layer's slice of a stacked
+    tensor is copied whole at every call (compiled for a v5e, PR 28: 2.4 GB
+    of temporaries for six layers of 128 x 2048 x 768, 13.6 MB this way)."""
     B, T, D = x.shape
-    E = wg.shape[0]
+    E = wg.shape[-3]
     K = idx.shape[-1]
     N = B * T
     xf = x.reshape(N, D)
@@ -66,6 +97,10 @@ def _sorted_dispatch(x: jax.Array,            # [B, T, D]
     tok = order // K                                   # source token per slot
     xs = xf[tok]                                       # [N*K, D]
     counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    if layer is not None:
+        L = wg.shape[0]
+        counts = jnp.zeros((L, E), jnp.int32).at[layer].set(counts).reshape(-1)
+        wg, wu, wd = (w.reshape(L * E, *w.shape[2:]) for w in (wg, wu, wd))
     g = jax.lax.ragged_dot(xs, wg, counts)             # [N*K, F]
     u = jax.lax.ragged_dot(xs, wu, counts)
     a = (jax.nn.silu(g.astype(jnp.float32))
@@ -80,8 +115,11 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int):
     """Router: renormalized top-k gate values + expert ids ([B,T,K] each).
     Shared by every dispatch formulation (incl. forward_pp's in-stage MoE)
     so the gating policy has exactly one implementation."""
-    logits = jnp.einsum("btd,de->bte", x, wr.astype(x.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    # float32 logits, not only a float32 softmax: bfloat16 resolves a
+    # router logit of 32-64 to 0.25, i.e. a gate ratio to 25 %
+    logits = jnp.einsum("btd,de->bte", x, wr.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
     vals, idx = jax.lax.top_k(probs, top_k)               # [B,T,K]
     return vals / jnp.sum(vals, axis=-1, keepdims=True), idx
 
@@ -110,24 +148,57 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
             wu: jax.Array,          # [E, D, F] expert up projections
             wd: jax.Array,          # [E, F, D] expert down projections
             top_k: int,
-            mesh=None) -> jax.Array:
-    """Routed MoE feed-forward. Returns [B, T, D] in x.dtype."""
+            mesh=None,
+            layer: Optional[int] = None):
+    """Routed MoE feed-forward (expert width F is the weights' own: a model
+    whose experts are not ``intermediate_size`` wide needs nothing here).
+    With ``layer``, ``wg`` / ``wu`` / ``wd`` are the stacked [L, E, ...]
+    tensors and ``layer`` picks this call's (see :func:`_sorted_dispatch`).
+    Returns ([B, T, D] in x.dtype, experts hit: int32 scalar, the experts
+    that at least one row of this call was routed to, and the chosen expert
+    ids [B, T, K])."""
+    with jax.named_scope("dynamo.moe_ffn"):
+        vals, idx = route_topk(x, wr, top_k)
+        hit = jnp.sum(jnp.zeros((wr.shape[1],), jnp.int32)
+                      .at[idx.reshape(-1)].max(1))
+        return _dispatch(x, wg, wu, wd, vals, idx, mesh, layer), hit, idx
+
+
+def moe_ffn_in_stage(x: jax.Array, wr: jax.Array, wg: jax.Array,
+                     wu: jax.Array, wd: jax.Array, top_k: int,
+                     ep: int = 1, psum_axes=()) -> jax.Array:
+    """The same routing and expert mathematics for a caller ALREADY inside
+    manual SPMD (``forward_pp``'s pp x tp x ep stage body; shard_maps do not
+    nest): ``wg`` / ``wu`` / ``wd`` are this shard's local experts, dense
+    dispatch, the gates of non-local experts are zero on each shard, and one
+    ``psum`` over ``psum_axes`` combines them exactly."""
     E = wr.shape[1]
     vals, idx = route_topk(x, wr, top_k)
+    gates = dense_gates(vals, idx, E)                     # [B, T, E]
+    if ep > 1:
+        El = E // ep
+        gates = jax.lax.dynamic_slice_in_dim(
+            gates, jax.lax.axis_index(AXIS_EP) * El, El, axis=2)
+    y = expert_ffn(x, wg, wu, wd, gates)
+    return jax.lax.psum(y, psum_axes) if psum_axes else y
+
+
+def _dispatch(x, wg, wu, wd, vals, idx, mesh, layer=None):
+    E = wg.shape[-3]
 
     ep = _ep_size(mesh)
     tp = _tp_size(mesh)
-    F = wg.shape[2]
+    F = wg.shape[-1]
     tp_ffn = tp if tp > 1 and F % tp == 0 else 1
     if ep <= 1 and tp_ffn <= 1:
         B, T, _ = x.shape
-        if B * T >= 16:
-            # compute-bound chunks: sorted exact dispatch costs K-per-token
-            # FFN work instead of dense dispatch's E-per-token
-            return _sorted_dispatch(x, wg, wu, wd, vals, idx)
+        if sorted_wins(B * T, idx.shape[-1], E):
+            return _sorted_dispatch(x, wg, wu, wd, vals, idx, layer)
+    if layer is not None:
+        # a layer's slice of the stacked tensor is free for an einsum
+        wg, wu, wd = wg[layer], wu[layer], wd[layer]
 
-    # dense dispatch (tiny decode batches / sharded meshes) consumes the
-    # one-hot gates tensor; only built where used
+    # dense dispatch consumes the one-hot gates tensor; only built where used
     gates = dense_gates(vals, idx, E)                     # [B,T,E]
     experts = expert_ffn
 

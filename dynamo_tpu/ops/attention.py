@@ -61,12 +61,90 @@ def _pick_block(n: int, align: int, cap: int = 128) -> int:
 
 
 # ---------------------------------------------------------------------------
+# Learned top-k selection (DeepSeek-Sparse-Attention indexer)
+#
+# A model with an indexer attends, per query, to the ``k`` visible keys with
+# the largest index score. Both kernels below take that set as a per-key keep
+# mask in the lane's logical order and apply it beside their causal / length
+# mask: every page is still read, which is the whole mathematics and a sound
+# first form (a kernel that gathers the selected keys is PERF.md §7).
+# ---------------------------------------------------------------------------
+
+def index_scores(qi: jax.Array, ki: jax.Array, w: jax.Array) -> jax.Array:
+    """I[b,t,s] = sum_j w[b,t,j] * relu(qi[b,t,j] . ki[b,s]), accumulated in
+    float32. qi [B,T,Hi,Di]; ki [B,S,Di] (ONE index key head); w [B,T,Hi].
+    Any positive scale leaves the selected set as it is, so none is
+    applied."""
+    with jax.named_scope("dynamo.index_select"):
+        dots = jnp.einsum("bthd,bsd->bths", qi, ki,
+                          preferred_element_type=jnp.float32)
+        return jnp.einsum("bths,bth->bts", jax.nn.relu(dots),
+                          w.astype(jnp.float32),
+                          preferred_element_type=jnp.float32)
+
+
+def topk_keep(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
+    """The exact top-``min(k, visible)`` of ``scores`` [..., S] (float32)
+    among the ``visible`` keys, as a bool keep mask [..., S]; a tie at the
+    threshold goes to the LOWER position, as ``lax.top_k`` breaks it.
+
+    No sort: the k-th largest score is found bit by bit on the scores'
+    order-preserving integer image (32 counting passes), then the ties at
+    that value are cut by position the same way (one pass per bit of S).
+    A context no longer than ``k`` is the identity by construction and
+    computes nothing."""
+    S = scores.shape[-1]
+    if S <= k:
+        return visible
+    with jax.named_scope("dynamo.index_select"):
+        # -0.0 and +0.0 compare equal; give them one image
+        scores = jnp.where(scores == 0.0, 0.0, scores.astype(jnp.float32))
+        bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+        # monotone image of the float order in uint32: flip the magnitude
+        # of negatives, then the sign bit; invisible keys take the minimum
+        mono = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+        u = jax.lax.bitcast_convert_type(mono, jnp.uint32) ^ jnp.uint32(
+            0x80000000)
+        u = jnp.where(visible, u, jnp.uint32(0))
+
+        def count(m):
+            return jnp.sum(m.astype(jnp.int32), axis=-1, keepdims=True)
+
+        def value_bit(i, t):
+            cand = t | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(
+                jnp.uint32)))
+            return jnp.where(count(u >= cand) >= k, cand, t)
+
+        # largest t with at least k keys >= t: the k-th largest image
+        thr = jax.lax.fori_loop(
+            0, 32, value_bit, jnp.zeros((*u.shape[:-1], 1), jnp.uint32))
+        above, tied = u > thr, u == thr
+        need = k - count(above)                 # >= 1 of the tied keys
+        pos = jax.lax.broadcasted_iota(jnp.int32, u.shape, u.ndim - 1)
+        nbits = max(1, (S - 1).bit_length())
+
+        def pos_bit(i, p):
+            # largest p with FEWER than ``need`` tied keys below p: the
+            # need-th tied key sits at position p
+            cand = p | (jnp.int32(1) << (jnp.int32(nbits - 1) - i))
+            return jnp.where(count(tied & (pos < cand)) < need, cand, p)
+
+        cut = jax.lax.fori_loop(
+            0, nbits, pos_bit, jnp.zeros((*u.shape[:-1], 1), jnp.int32))
+        return visible & (above | (tied & (pos <= cut)))
+
+
+# ---------------------------------------------------------------------------
 # Flash attention (prefill over gathered context)
 # ---------------------------------------------------------------------------
 
-def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, G: int,
-                  softcap: Optional[float], window: Optional[int]):
+def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
+                  scale: float, G: int, softcap: Optional[float],
+                  window: Optional[int], selected: bool):
+    # a model with an indexer adds ONE operand, the keep mask of its
+    # selection; every other model's kernel is what it always was
+    keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    o_ref, m_scr, l_scr, acc_scr = rest
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -108,6 +186,8 @@ def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, o_ref,
             # rule to staged segments — the two must stay in lockstep or
             # paged and dense forwards diverge on Gemma2/3-style models.
             mask = mask & (kp > qp - window)[None]
+        if keep_ref is not None:
+            mask = mask & (keep_ref[0] > 0)[None]          # [1, BT, BS]
 
         m_prev = m_scr[:]
         m_cur = jnp.max(jnp.where(mask, s, NEG_INF), axis=-1, keepdims=True)
@@ -134,7 +214,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: Optional[bool] = None,
                     scale: Optional[float] = None,
                     softcap: Optional[float] = None,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    keep: Optional[jax.Array] = None) -> jax.Array:
     """Blockwise attention with explicit positions.
 
     q: [B, T, Hq, Dh] ; k, v: [B, S, Hkv, Dh] (gathered context, GQA)
@@ -143,6 +224,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     with ``window`` additionally k_pos > p - window (Gemma2/3 sliding
     layers). ``softcap`` tanh-caps scores before the online softmax;
     ``scale`` overrides the rsqrt(Dh) default (query_pre_attn_scalar).
+    ``keep`` [B, T, S] bool (a model with an indexer: :func:`topk_keep`)
+    restricts each query to its selected keys, every head alike.
     Returns [B, T, Hq, Dh] in q.dtype.
     """
     B, T, Hq, Dh = q.shape
@@ -169,10 +252,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kpos3 = k_pos[:, None, :]                          # [B, 1, S]
     qpos_col = q_pos[:, :, None]                       # [B, T, 1]
 
+    selected = keep is not None
+    sel_specs, sel_args = [], []
+    if selected:
+        sel_specs = [pl.BlockSpec((1, BT, BS),
+                                  lambda bh, i, j: (bh // Hkv, i, j))]
+        sel_args = [keep.astype(jnp.int32)]
     grid = (B * Hkv, T // BT, S // BS)
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, G=G,
-                          softcap=softcap, window=window),
+                          softcap=softcap, window=window, selected=selected),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, BT, 1), lambda bh, i, j: (bh // Hkv, i, 0)),
@@ -181,6 +270,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, G, BT, Dh), lambda bh, i, j: (bh, 0, i, 0)),
             pl.BlockSpec((1, BS, Dh), lambda bh, i, j: (bh, j, 0)),
             pl.BlockSpec((1, BS, Dh), lambda bh, i, j: (bh, j, 0)),
+            *sel_specs,
         ],
         out_specs=pl.BlockSpec((1, G, BT, Dh), lambda bh, i, j: (bh, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, Dh), q.dtype),
@@ -190,7 +280,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, BT, Dh), jnp.float32),   # acc
         ],
         interpret=interpret,
-    )(qpos_col, kpos3, kval, q5, k3, v3)
+    )(qpos_col, kpos3, kval, q5, k3, v3, *sel_args)
 
     out = out.reshape(B, Hkv, G, T, Dh).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, T, Hq, Dh)
@@ -221,11 +311,10 @@ def _lane_block(b, j, *prefetched):
     return (b, 0, 0, 0)
 
 
-def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
-                      k_buf, v_buf, sem, m_scr, l_scr, acc_scr, state,
-                      *, scale: float, page: int, ppb: int, hkv: int,
+def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
+                      scale: float, page: int, ppb: int, hkv: int,
                       fold: int, dh: int, softcap: Optional[float],
-                      window: Optional[int]):
+                      window: Optional[int], selected: bool):
     """Pools are the WHOLE stored pool, [L, Hkv, n_pages, page//fold,
     fold*Dh], left in HBM; ``layer_ref[0]`` picks the layer inside the copy
     descriptor, so no per-layer slice of the pool is ever materialised and
@@ -238,7 +327,13 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
     With ``window``, each lane's active block range is clamped at BOTH ends:
     blocks wholly below ``length - window`` are never DMA'd nor computed
     (the page-range clamp — sliding decode reads O(window) bytes, not
-    O(context)), and in-block tokens below the window start are masked."""
+    O(context)), and in-block tokens below the window start are masked.
+
+    ``selected``: one more operand, the keep mask of a model with an indexer
+    ([1, 1, L2] int32 of this lane and block, logical order, fold 1 only);
+    without it the kernel is what it always was."""
+    keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr, state = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
     L2 = ppb * page           # tokens per compute block
@@ -339,6 +434,8 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
             mask = (base + f) < length
             if window is not None:
                 mask = mask & ((base + f) >= length - window)
+            if keep_ref is not None:
+                mask = mask & (keep_ref[...] > 0)           # [1, 1, rows]
             s_parts.append(jnp.where(mask, s, NEG_INF))
             mask_parts.append(mask)
 
@@ -374,9 +471,10 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
                          scale: Optional[float] = None,
                          softcap: Optional[float] = None,
                          window: Optional[int] = None,
+                         keep: Optional[jax.Array] = None,
                          interpret: bool = False) -> jax.Array:
     """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh]; layer: [1]
-    int32. Returns q4-shaped. ``interpret`` exists for the CPU test suite
+    int32; keep: [B, P * page] bool or None. Returns q4-shaped. ``interpret`` exists for the CPU test suite
     only — the serving path always compiles this variant (paged_attention
     gates it to real TPUs)."""
     B, Hkv, G, Dh = q4.shape
@@ -400,6 +498,19 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     k_pool = k_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
     v_pool = v_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
 
+    selected = keep is not None
+    sel_specs, sel_args = [], []
+    if selected:
+        if fold > 1:
+            raise ValueError(
+                f"the paged dma kernel takes a selection only at head_dim "
+                f">= 128 (got {Dh}): folded rows hold {fold} tokens")
+        L2 = ppb * page
+        keep = keep.astype(jnp.int32)
+        keep = jnp.pad(keep, ((0, 0), (0, NB * L2 - keep.shape[1])))
+        sel_specs = [pl.BlockSpec((1, 1, L2), lambda b, j, *_: (b, 0, j))]
+        sel_args = [keep[:, None, :]]
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, NB),
@@ -407,6 +518,7 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
             pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
+            *sel_specs,
         ],
         out_specs=pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
         scratch_shapes=[
@@ -422,18 +534,20 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     return pl.pallas_call(
         functools.partial(_paged_dma_kernel, scale=scale, page=page,
                           ppb=ppb, hkv=Hkv, fold=fold, dh=Dh,
-                          softcap=softcap, window=window),
+                          softcap=softcap, window=window, selected=selected),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q4.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(page_tables, lengths, layer, q4, k_pool, v_pool)
+    )(page_tables, lengths, layer, q4, k_pool, v_pool, *sel_args)
 
 
-def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_scr, l_scr, acc_scr, *, scale: float, page: int,
-                  softcap: Optional[float], window: Optional[int]):
+def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
+                  scale: float, page: int, softcap: Optional[float],
+                  window: Optional[int], selected: bool):
+    keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -468,6 +582,8 @@ def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
         mask = tok < length
         if window is not None:
             mask = mask & (tok >= length - window)
+        if keep_ref is not None:
+            mask = mask & (keep_ref[...] > 0)              # [1, 1, page]
         m_prev = m_scr[:]
         m_cur = jnp.max(jnp.where(mask, s, NEG_INF), axis=-1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
@@ -508,7 +624,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     interpret: Optional[bool] = None,
                     scale: Optional[float] = None,
                     softcap: Optional[float] = None,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    keep: Optional[jax.Array] = None) -> jax.Array:
     """Decode attention straight over the paged KV pool.
 
     q: [B, Hq, Dh] (one new token per sequence, already rope'd)
@@ -527,7 +644,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     every page. ``softcap`` tanh-caps scores pre-softmax (Gemma2);
     ``scale`` overrides rsqrt(Dh) (query_pre_attn_scalar). ``window`` and
     ``softcap`` are static (one Mosaic kernel per class); ``layer`` is
-    dynamic, so all layers of a class share that kernel.
+    dynamic, so all layers of a class share that kernel. ``keep`` [B, P *
+    page] bool (a model with an indexer: :func:`topk_keep` over the lane's
+    logical positions) restricts the lane to its selected keys; every page
+    is still read.
 
     On a TPU this runs the multi-page double-buffered DMA kernel above
     (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
@@ -579,8 +699,14 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         out = _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables,
                                    lengths, pages_per_block=ppb,
                                    scale=scale, softcap=softcap,
-                                   window=window)
+                                   window=window, keep=keep)
         return out.reshape(B, Hq, Dh)
+    selected = keep is not None
+    if selected and not interpret:
+        raise ValueError(
+            "DYNAMO_TPU_PAGED_KERNEL=simple takes no selection when "
+            "compiled (a [1, 1, page] block does not tile); a model with "
+            "an indexer decodes through the dma kernel")
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
 
@@ -596,6 +722,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
             pl.BlockSpec((1, Hkv, 1, page, Dh), page_map),
             pl.BlockSpec((1, Hkv, 1, page, Dh), page_map),
+            *([pl.BlockSpec((1, 1, page), lambda b, p, *_: (b, 0, p))]
+              if selected else []),
         ],
         out_specs=pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
         scratch_shapes=[
@@ -606,9 +734,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, page=page,
-                          softcap=softcap, window=window),
+                          softcap=softcap, window=window, selected=selected),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
         interpret=interpret,
-    )(page_tables, lengths, layer, q4, k_pool, v_pool)
+    )(page_tables, lengths, layer, q4, k_pool, v_pool,
+      *([keep.astype(jnp.int32)[:, None, :]] if selected else []))
     return out.reshape(B, Hq, Dh)

@@ -514,6 +514,30 @@ class StageMetrics:
             "dyn_engine_dispatch_tokens_total",
             "Token positions computed by those dispatches (prompt tokens "
             "of the chunks; active lanes x steps)", ("kind",))
+        # routed experts and learned top-k attention: what the dispatches
+        # made the device do, counted on the host from what a dispatch
+        # already knows (the experts hit come back with its sampled tokens)
+        self.moe_assignments = r.counter(
+            "dyn_moe_assignments_total",
+            "Token x expert pairs routed, all layers", ("kind",))
+        self.moe_experts_hit = r.counter(
+            "dyn_moe_experts_hit_total",
+            "Experts with at least one row, per layer and step, summed "
+            "on the device", ("kind",))
+        self.sparse_attn_context = r.counter(
+            "dyn_sparse_attn_context_tokens_total",
+            "Keys visible to each query of an indexer model, summed over "
+            "queries (one layer's worth)", ("kind",))
+        self.sparse_attn_selected = r.counter(
+            "dyn_sparse_attn_selected_tokens_total",
+            "Keys each such query attends to: min(visible, topk), summed",
+            ("kind",))
+        self.profile_captured_work = r.counter(
+            "dyn_profile_captured_work_total",
+            "The four counters above (by name), and dispatches and tokens, "
+            "of the dispatches enqueued while a DYN_PROFILE_DIR capture "
+            "ran: the work whose device time the trace holds",
+            ("counter", "kind"))
         # model-mobility plane (fleet/mobility/): weight prefetch + hot
         # swap — a swap that recompiles or silently reloads cold defeats
         # the seconds-scale wake contract, so both are first-class series

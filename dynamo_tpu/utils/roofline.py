@@ -159,6 +159,12 @@ class ModelCosts:
     num_layers: int
     window_groups: Tuple[Tuple[Optional[int], int], ...]
     weight_bytes: float          # total param bytes streamed per step
+    # learned top-k attention (0 = none): a query attends to min(visible,
+    # topk) keys, and scores EVERY visible key first (2 * Hi * Di FLOPs and
+    # one index key of Di * esize bytes per visible position and layer)
+    index_topk: int = 0
+    index_flops_coef: float = 0.0
+    index_bytes_per_tok_layer: float = 0.0
 
 
 def dtype_size(dtype: Any) -> int:
@@ -183,9 +189,15 @@ def model_costs(m: Any, weight_bytes: Optional[float] = None) -> ModelCosts:
     L, I = m.num_layers, m.intermediate_size
     esize = dtype_size(m.dtype)
     attn_proj = D * Hq * Dh + 2 * D * Hkv * Dh + Hq * Dh * D
+    topk = getattr(m, "index_topk", 0)
+    Hi, Di = getattr(m, "index_heads", 0), getattr(m, "index_head_dim", 0)
+    if topk:
+        attn_proj += D * (Hi * Di + Di + Hi)
     if getattr(m, "num_experts", 0):
-        mlp_active = m.experts_per_token * 3 * D * I
-        mlp_weights = m.num_experts * 3 * D * I
+        # the ACTIVE experts at their own width, and the router
+        I = getattr(m, "expert_width", I)
+        mlp_active = m.experts_per_token * 3 * D * I + D * m.num_experts
+        mlp_weights = m.num_experts * (3 * D * I + D)
     else:
         mlp_active = mlp_weights = 3 * D * I
     if weight_bytes is None:
@@ -206,15 +218,34 @@ def model_costs(m: Any, weight_bytes: Optional[float] = None) -> ModelCosts:
         window_groups=tuple(sorted(groups.items(),
                                    key=lambda kv: (kv[0] is None, kv[0]))),
         weight_bytes=float(weight_bytes),
+        index_topk=int(topk),
+        index_flops_coef=2.0 * Hi * Di,
+        index_bytes_per_tok_layer=float(Di * esize),
     )
 
 
 def _clamped_len_sum(groups: Sequence[Tuple[Optional[int], int]],
-                     s: int) -> float:
+                     s: int, topk: int = 0) -> float:
     """sum over layers of min(s, window): the kv positions one query token
-    at kv-length ``s`` actually touches across the layer stack."""
+    at kv-length ``s`` actually touches across the layer stack (``topk``:
+    a model with an indexer attends to its selected keys only)."""
+    if topk:
+        s = min(s, topk)
     return float(sum((min(s, w) if w is not None else s) * n
                      for w, n in groups))
+
+
+def _attn_cost(c: ModelCosts, s: int) -> Tuple[float, float]:
+    """(flops, kv bytes read) of ONE query at kv-length ``s`` across the
+    layer stack: attention over the keys it attends to, plus — for a model
+    with an indexer — the index scores of every visible key."""
+    touched = _clamped_len_sum(c.window_groups, s, c.index_topk)
+    flops = c.attn_flops_coef * touched
+    read = touched * c.kv_bytes_per_tok_layer
+    if c.index_topk:
+        flops += c.index_flops_coef * s * c.num_layers
+        read += c.index_bytes_per_tok_layer * s * c.num_layers
+    return flops, read
 
 
 def decode_cost(c: ModelCosts, lengths: Iterable[int], steps: int
@@ -228,10 +259,9 @@ def decode_cost(c: ModelCosts, lengths: Iterable[int], steps: int
     for s0 in lengths:
         lanes += 1
         for j in range(steps):
-            touched = _clamped_len_sum(c.window_groups, s0 + j)
-            flops += (c.mat_flops_per_token + c.lm_head_flops
-                      + c.attn_flops_coef * touched)
-            kv_read += touched * c.kv_bytes_per_tok_layer
+            fl, rd = _attn_cost(c, s0 + j)
+            flops += c.mat_flops_per_token + c.lm_head_flops + fl
+            kv_read += rd
     tokens = lanes * steps
     bytes_ = (steps * c.weight_bytes + kv_read
               + tokens * c.num_layers * c.kv_bytes_per_tok_layer)
@@ -251,9 +281,9 @@ def prefill_cost(c: ModelCosts, spans: Iterable[Tuple[int, int]]
         tokens += count
         flops += count * c.mat_flops_per_token + c.lm_head_flops
         for p in range(start, start + count):
-            touched = _clamped_len_sum(c.window_groups, p + 1)
-            flops += c.attn_flops_coef * touched
-            kv_read += touched * c.kv_bytes_per_tok_layer
+            fl, rd = _attn_cost(c, p + 1)
+            flops += fl
+            kv_read += rd
     bytes_ = (c.weight_bytes + kv_read
               + tokens * c.num_layers * c.kv_bytes_per_tok_layer)
     return flops, bytes_, tokens
@@ -270,10 +300,9 @@ def verify_cost(c: ModelCosts, lengths: Iterable[int], t: int
     for s0 in lengths:
         lanes += 1
         for j in range(t):
-            touched = _clamped_len_sum(c.window_groups, s0 + j)
-            flops += (c.mat_flops_per_token + c.lm_head_flops
-                      + c.attn_flops_coef * touched)
-            kv_read += touched * c.kv_bytes_per_tok_layer
+            fl, rd = _attn_cost(c, s0 + j)
+            flops += c.mat_flops_per_token + c.lm_head_flops + fl
+            kv_read += rd
     tokens = lanes * t
     bytes_ = (c.weight_bytes + kv_read
               + tokens * c.num_layers * c.kv_bytes_per_tok_layer)

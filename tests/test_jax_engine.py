@@ -467,7 +467,8 @@ def test_moe_sorted_dispatch_matches_dense():
         wg = jax.random.normal(ks[2], (E, D, F), jnp.float32) * 0.3
         wu = jax.random.normal(ks[3], (E, D, F), jnp.float32) * 0.3
         wd = jax.random.normal(ks[4], (E, F, D), jnp.float32) * 0.3
-        got = M.moe_ffn(x, wr, wg, wu, wd, K)          # sorted (B*T >= 16)
+        vals, idx = M.route_topk(x, wr, K)
+        got = M._sorted_dispatch(x, wg, wu, wd, vals, idx)
         logits = jnp.einsum("btd,de->bte", x, wr)
         probs = jax.nn.softmax(logits, axis=-1)
         vals, idx = jax.lax.top_k(probs, K)
