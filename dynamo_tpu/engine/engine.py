@@ -661,16 +661,21 @@ class EngineCore:
             self.proposer = build_proposer(self.spec, cfg, self.s_buckets,
                                            self.c_buckets, device=dev0)
 
-        # --- in-flight decode dispatches (device-chained) -------------
-        # Each record is a dispatch whose results have not been fetched yet.
-        # Chaining feeds the previous dispatch's on-device token/key arrays
-        # straight into the next one, so the host fetch of one dispatch's
-        # results overlaps the next dispatch's execution instead of gating it.
+        # --- in-flight dispatches -------------------------------------
+        # Each record is a dispatch (``kind`` decode or prefill) whose
+        # results have not been fetched yet, in enqueue order, which is the
+        # order the device runs them in. A decode chains off the previous
+        # one's on-device token/key arrays and a prefill chunk's inputs are
+        # all on the host, so the fetch of one dispatch's results overlaps
+        # the next dispatches' execution instead of gating it. ``seq``
+        # numbers the records: a deferred page release names the newest one
+        # it has to outlive (see _free_slot).
         self._inflight: Deque[Dict[str, Any]] = collections.deque()
+        self._dispatch_seq = 0
         # a DYN_PROFILE_DIR capture is running (set by the engine thread's
         # loop before each step): see _count_model_work
         self.capturing = False
-        self._deferred_release: List[str] = []
+        self._deferred_release: List[Tuple[str, int]] = []  # (seq_id, seq)
         self._pending_seeds: List[Tuple[int, int]] = []
         # seq_id -> admission's prefix-restore length, consumed by step()'s
         # tagging post-pass on the sequence's first output
@@ -1494,17 +1499,28 @@ class EngineCore:
     def _step(self) -> List[StepOutput]:
         """Run one engine iteration.
 
-        Steady-state decode is PIPELINED: a dispatch's sampled tokens are
-        fetched one iteration later, while the next dispatch (chained off
-        the previous one's on-device token/key arrays) already executes.
-        The host fetch round-trip therefore overlaps device compute instead
-        of serializing with it. Membership changes (admission, prefill,
-        cancel, finish) are sync points: the in-flight window drains first.
+        Dispatches are PIPELINED: what an iteration enqueues (at most one
+        prefill chunk, then one decode dispatch) goes behind whatever is
+        still in flight, and only then are the earlier iterations' records
+        fetched, oldest first. The host builds the next dispatch while the
+        chip runs the last; the device sees one chunk per decode dispatch,
+        in the order it always did.
 
-        Prefill advances every mid-prefill sequence and admits as many
-        waiting requests as fit, batched into ONE dispatch (up to 8 lanes);
-        fresh first tokens are flushed to callers immediately rather than
-        held through a decode dispatch (TTFT)."""
+        A prefill chunk needs nothing from the window: its tokens,
+        positions and slots come from the prompt and the page pool. A
+        decode dispatch behind a chunk that completes no prompt chains off
+        the newest decode record's on-device tokens (same lanes). A chunk
+        that completes a prompt is fetched within its own iteration, behind
+        everything older, and the decode dispatch that takes the new lane in
+        is built from host tokens right after: the first token never waits
+        behind a deliver. The same goes for any other change of lanes (a
+        finish, an injected sequence): nothing chains, the earlier records
+        are fetched, and the host-token dispatch follows at once. Only at
+        such a change does the window hold no decode dispatch.
+
+        Still sync points (the window drains before anything is enqueued):
+        a reaped cancel, an admission that waits for pages a deferred
+        release holds, and every speculative round."""
         out: List[StepOutput] = []
         phase = self.phase
         phase.to("housekeeping")
@@ -1524,44 +1540,51 @@ class EngineCore:
         self.lane_clock.set(prefilling >= self.b_buckets[-1]
                             and None in self.slots)
         admit_possible = bool(self.waiting) and None in self.slots
-        sync_needed = prefill_work or admit_possible or n_reaped > 0
 
         if self.spec is not None:
             # speculative mode is synchronous per round (acceptance needs
-            # the fetch), so there is never an in-flight decode window
-            self._apply_deferred_release()
+            # the fetch), so there is never an in-flight window
             if prefill_work or admit_possible:
-                self._prefill_round(out)
+                if self._prefill_round(out) is not None:
+                    self._process_inflight(out)
             if any(s is not None and s.prefill_done >= len(s.prompt)
                    for s in self.slots):
                 self._spec_round(out)
             return out
 
-        if self._inflight:
-            phase.to("decode_build")
-            if not sync_needed and self._can_chain():
-                self._dispatch_decode()
-            out.extend(self._process_oldest_inflight())
-            while not self.by_seq and self._inflight:
-                # every live sequence finished: drain the stale window so
-                # its pages release instead of idling in limbo
-                out.extend(self._process_oldest_inflight())
-            if not self._inflight:
-                self._apply_deferred_release()
-            return out
-
-        self._apply_deferred_release()
+        held = len(self._inflight)      # earlier iterations' records
+        if held and (n_reaped or (admit_possible
+                                  and self._admission_awaits_release())):
+            self._process_inflight(out, held)
+            held = 0
+        completes = None
         if prefill_work or admit_possible:
-            self._prefill_round(out)
+            completes = self._prefill_round(out)
             # if no prefill progress was possible (e.g. pool full), fall
             # through to decode so the engine never stalls
-        if any(s is not None and s.prefill_done >= len(s.prompt)
-               for s in self.slots):
-            # non-blocking enqueue — even right after a prefill round, so
-            # decode keeps advancing between chunks of a long prompt; the
-            # results are fetched on a later iteration
+        # non-blocking enqueue, behind the chunk: decode keeps advancing
+        # between the chunks of a long prompt
+        behind = not completes and self._dispatch_decode(out)
+        # with a chunk that completes a prompt, that chunk too: its first
+        # token reaches the new lane's decode dispatch through the host
+        self._process_inflight(out, None if completes else held)
+        if not behind:
+            # the lanes changed (a prompt completed, a sequence finished or
+            # was injected): no decode dispatch is unfetched any more, so
+            # every lane's token is on the host, and the dispatch goes out
+            # before this iteration's outputs are delivered
             self._dispatch_decode(out)
+        if not self.by_seq:
+            # every live sequence finished: drain the stale window so its
+            # pages release instead of idling in limbo
+            self._process_inflight(out)
         return out
+
+    def _admission_awaits_release(self) -> bool:
+        """The head-of-line request does not fit the pool while a deferred
+        release holds pages: fetch what holds them before admitting."""
+        return bool(self._deferred_release) and not self.pool.can_admit(
+            len(self.waiting[0][1].token_ids) + 1)
 
     # ------------------------------------------------------------------
     def _request_span(self, slot: _Slot, name: str, start: float,
@@ -1612,21 +1635,28 @@ class EngineCore:
         if self.proposer is not None:
             self.proposer.drop(slot.seq_id)
         if self._inflight:
-            # an enqueued decode dispatch may still write into this
-            # sequence's pages; hold the release until the window drains so
-            # the pages cannot be reallocated under the in-flight program
-            self._deferred_release.append(slot.seq_id)
+            # a dispatch already enqueued may still write into this
+            # sequence's pages (a chained decode's overshoot, a cancelled
+            # prompt's chunk): hold the release until every record in
+            # flight NOW has been fetched, so the pages cannot be
+            # reallocated under them. Records enqueued later never name
+            # this sequence, so they do not hold its pages.
+            self._deferred_release.append(
+                (slot.seq_id, self._inflight[-1]["seq"]))
         else:
             self.pool.release(slot.seq_id)
         self.by_seq.pop(slot.seq_id, None)
         self.slots[i] = None
 
     def _apply_deferred_release(self) -> None:
-        if self._deferred_release and not self._inflight:
+        """Release the pages of every freed sequence whose barrier record
+        (the newest in flight when it was freed) has been fetched."""
+        oldest = (self._inflight[0]["seq"] if self._inflight
+                  else self._dispatch_seq + 1)
+        # barriers only grow down the list
+        while self._deferred_release and self._deferred_release[0][1] < oldest:
             self.phase.to("housekeeping")
-            for seq_id in self._deferred_release:
-                self.pool.release(seq_id)
-            self._deferred_release.clear()
+            self.pool.release(self._deferred_release.pop(0)[0])
 
     def _offload_evicted(self, seq_hash: int, page: int) -> None:
         """Eviction hook: queue the page for host-tier offload. The data
@@ -1946,10 +1976,12 @@ class EngineCore:
         self._load_sampling(slot_idx, req)
         return slot_idx, slot
 
-    def _prefill_round(self, out: List[StepOutput]) -> bool:
+    def _prefill_round(self, out: List[StepOutput]) -> Optional[int]:
         """Advance every mid-prefill slot by one chunk and admit as many
         waiting requests as fit, all in ONE batched dispatch (up to the
-        prefill lane budget). Returns True if a dispatch ran."""
+        prefill lane budget), enqueued behind whatever is in flight.
+        Returns how many prompts the dispatch completes, or None if
+        nothing was dispatched."""
         self.phase.to("admit")
         max_lanes = self.b_buckets[-1]
         chunks = [(i, s) for i, s in enumerate(self.slots)
@@ -1968,11 +2000,16 @@ class EngineCore:
         self.lane_clock.set(len(chunks) >= max_lanes and None in self.slots)
         chunks = chunks[:max_lanes]
         if not chunks:
-            return False
-        return self._prefill_dispatch(chunks, out)
+            return None
+        return self._prefill_enqueue(chunks, out)
 
     def _load_sampling(self, slot_idx: int, req: BackendInput) -> None:
         s = self.sampling
+        # written into copies: a decode dispatch still in flight was handed
+        # these very arrays, and the CPU backend reads a host argument in
+        # place, when the program runs
+        for name in ("temperature", "top_p", "top_k", "freq_pen", "pres_pen"):
+            setattr(s, name, np.array(getattr(s, name)))
         s.temperature[slot_idx] = float(req.sampling.temperature or 0.0)
         s.top_p[slot_idx] = float(req.sampling.top_p
                                   if req.sampling.top_p is not None else 1.0)
@@ -2006,7 +2043,7 @@ class EngineCore:
                              top_p, top_k, idxs, last_lanes,
                              mm_arrays=None):
         """Execute the batched prefill program + key bookkeeping. The SAME
-        code path runs on the leader (from _prefill_dispatch) and on
+        code path runs on the leader (from _prefill_enqueue) and on
         followers (from mirror_dispatch) so device state stays in lockstep."""
         s = self.sampling
         keys = s.key[jnp.asarray(idxs)]
@@ -2034,10 +2071,25 @@ class EngineCore:
 
     def _prefill_dispatch(self, chunks: List[Tuple[int, _Slot]],
                           out: List[StepOutput]) -> bool:
+        """One chunk dispatch, enqueued and fetched at once, ahead of
+        whatever else is in flight: for callers that need the sampled token
+        before they go on (``prefill_extract``). Returns True if a dispatch
+        ran."""
+        if self._prefill_enqueue(chunks, out) is None:
+            return False
+        out.extend(self._fetch_prefill(newest=True))
+        return True
+
+    def _prefill_enqueue(self, chunks: List[Tuple[int, _Slot]],
+                         out: List[StepOutput]) -> Optional[int]:
         """Advance each (slot_idx, slot) by one prompt chunk in a single
-        batched dispatch; fetch all lanes' sampled tokens with ONE host
-        round-trip and keep results only for lanes whose prompt completed.
-        Returns True if a dispatch ran."""
+        batched dispatch, enqueued WITHOUT fetching its result: nothing a
+        chunk takes in depends on a dispatch in flight. What does not
+        depend on the sampled token is settled here; the rest when the
+        record, now the newest of ``_inflight``, is fetched
+        (:meth:`_fetch_prefill`). Returns how many prompts the dispatch
+        completes (their first tokens come with the fetch), or None if no
+        dispatch ran."""
         cfg = self.cfg
         self.phase.to("prefill_build")
         work = []  # (slot_idx, slot, start, count, is_last)
@@ -2062,7 +2114,7 @@ class EngineCore:
             work.append((i, slot, start, count,
                          start + count == len(prompt)))
         if not work:
-            return False
+            return None
         self._flush_evictions()   # extend() may have evicted pages
 
         Bp = self._bucket(len(work), self.b_buckets)
@@ -2131,47 +2183,73 @@ class EngineCore:
                 "Bp": Bp, "C": C, "S": S, "seeds": seeds,
                 "last_lanes": last_lanes, "mm": bool(mm_arrays),
             }, arrays)
+        for _, slot, start, count, _ in work:
+            slot.chunks += 1
+            slot.prefill_done = start + count
         t_disp = time.perf_counter()
         captured = self.capturing
-        for _, slot, _, _, _ in work:
-            slot.chunks += 1
         packed = self._run_prefill_program(
             Bp, C, S, tokens, positions, write_idx, read_idx, read_pos,
             read_valid, last_i, temp, top_p, top_k, idxs, last_lanes,
             mm_arrays=mm_arrays)
-        self.stage.engine_dispatches.inc("prefill")
         self.stage.engine_dispatch_tokens.inc(
             "prefill", amount=float(sum(w[3] for w in work)))
+        self._inflight.append({"kind": "prefill",
+                               "seq": self._count_dispatch("prefill"),
+                               "packed": packed, "work": work,
+                               "last_lanes": last_lanes,
+                               "compiled": self._take_compiled_flag(),
+                               "captured": captured, "S": S,
+                               "dispatched_at": t_disp})
+        return len(last_lanes)
 
+    def _count_dispatch(self, kind: str) -> int:
+        """Count a dispatch just enqueued (and whether it went behind
+        records still unfetched); returns its number in enqueue order."""
+        self.stage.engine_dispatches.inc(kind)
+        if self._inflight:
+            self.stage.engine_dispatches_behind.inc(kind)
+        self._dispatch_seq += 1
+        return self._dispatch_seq
+
+    def _fetch_prefill(self, newest: bool = False) -> List[StepOutput]:
+        """Fetch (blocking) the sampled tokens of the chunk dispatch at the
+        head of the window (or at its end) with ONE host round-trip and
+        account it; results are kept only for lanes whose prompt the chunk
+        completed."""
+        rec = self._inflight.pop() if newest else self._inflight.popleft()
+        work = rec["work"]
+        spans = [(w[2], w[3]) for w in work]
         self.phase.to("prefill_fetch")
         # dynalint: ok(host-sync) THE designed prefill fetch: one packed
         # [Bp,2] (token,logprob) array per dispatch, batched across lanes
-        packed_np = np.asarray(packed)            # ONE host fetch
+        packed_np = np.asarray(rec["packed"])     # ONE host fetch
         self.phase.to("emit")
         self._count_model_work(
-            "prefill", [(w[2], w[3]) for w in work],
-            packed_np[0, 2] if packed_np.shape[-1] > 2 else None, captured,
-            S)
+            "prefill", spans,
+            packed_np[0, 2] if packed_np.shape[-1] > 2 else None,
+            rec["captured"], rec["S"])
         now = time.monotonic()
-        if not self._take_compiled_flag():
+        if not rec["compiled"]:
             from ..utils.roofline import prefill_cost
 
-            fl, by, tk = prefill_cost(
-                self.costs, [(w[2], w[3]) for w in work])
-            self.goodput.account(fl, by, time.perf_counter() - t_disp, tk)
-        for lane, (i, slot, start, count, is_last) in enumerate(work):
-            slot.prefill_done = start + count
-            if not is_last:
-                continue
+            fl, by, tk = prefill_cost(self.costs, spans)
+            self.goodput.account(
+                fl, by, time.perf_counter() - rec["dispatched_at"], tk)
+        outs: List[StepOutput] = []
+        for lane in rec["last_lanes"]:
+            i, slot = work[lane][:2]
+            if self.slots[i] is not slot:
+                continue   # freed since dispatch (cancel): discard
             t = int(packed_np[lane, 0])
             lp = float(packed_np[lane, 1])
             try:
                 self._append_generated(slot, t)
             except OutOfPages:
-                out.append(StepOutput(slot.seq_id, t, lp,
-                                      FinishReason.ERROR,
-                                      error="out of KV pages appending the "
-                                            "first generated token"))
+                outs.append(StepOutput(slot.seq_id, t, lp,
+                                       FinishReason.ERROR,
+                                       error="out of KV pages appending the "
+                                             "first generated token"))
                 self._free_slot(i)
                 continue
             slot.cum_logprob += lp
@@ -2181,12 +2259,12 @@ class EngineCore:
                 prompt_tokens=len(slot.prompt),
                 prefix_hit_tokens=slot.prefix_hit, chunks=slot.chunks)
             fin = self._finish_reason(slot, t)
-            out.append(StepOutput(slot.seq_id, t, slot.cum_logprob, fin,
-                                  prompt_tokens=len(slot.prompt),
-                                  token_logprob=lp, first_token_at=now))
+            outs.append(StepOutput(slot.seq_id, t, slot.cum_logprob, fin,
+                                   prompt_tokens=len(slot.prompt),
+                                   token_logprob=lp, first_token_at=now))
             if fin is not None:
                 self._free_slot(i)
-        return True
+        return outs
 
     def _append_generated(self, slot: _Slot, token: int) -> None:
         slot.generated += 1
@@ -2228,13 +2306,11 @@ class EngineCore:
             active.append((i, slot, phys))
         return active, deferred
 
-    def _can_chain(self) -> bool:
-        """True if the next decode dispatch can be enqueued straight off the
-        in-flight one's on-device outputs: same membership, pages available
-        for every lane, and only one dispatch currently outstanding."""
-        if len(self._inflight) != 1:
-            return False
-        rec = self._inflight[-1]
+    def _can_chain(self, rec: Dict[str, Any]) -> bool:
+        """True if the next decode dispatch can be enqueued straight off
+        ``rec``'s on-device outputs, ``rec`` being the newest decode record
+        in flight (chunks that completed no prompt may have been enqueued
+        since): same membership, and pages available for every lane."""
         # the chained dispatch feeds the previous dispatch's on-device
         # final_tok to EVERY lane, so the decode-ready set must be EXACTLY
         # the lanes that were active in that dispatch: a newly injected or
@@ -2270,19 +2346,30 @@ class EngineCore:
                   "continue decoding)"))
         self._free_slot(i)
 
-    def _dispatch_decode(self, out: Optional[List[StepOutput]] = None) -> None:
-        """Enqueue one multi-step decode dispatch WITHOUT fetching results.
-        If a dispatch is already in flight, chain off its on-device token
-        and key arrays (no host data dependency)."""
+    def _dispatch_decode(self, out: List[StepOutput]) -> bool:
+        """Enqueue one multi-step decode dispatch WITHOUT fetching results,
+        behind whatever is in flight. With a decode dispatch among it,
+        chain off the newest one's on-device token and key arrays (no host
+        data dependency), or enqueue nothing if its lanes are not the
+        decode-ready ones; with none, every lane's last token is on the
+        host. (The caller fetches a chunk that completes a prompt before
+        it comes here: that lane's token is on neither side until then.)
+        Returns whether a dispatch was enqueued."""
         self.phase.to("decode_build")
         B = self.cfg.max_batch
         N = self.cfg.decode_steps
-        chain = bool(self._inflight)
+        newest = next((r for r in reversed(self._inflight)
+                       if r["kind"] == "decode"), None)
+        chain = newest is not None
+        if chain and not self._can_chain(newest):
+            return False
         active, deferred = self._decode_eligible()
         if not active:
-            if deferred and not chain and out is not None:
+            # (pages a deferred release holds are about to come back: no
+            # lane is evicted for want of them)
+            if deferred and not chain and not self._deferred_release:
                 self._evict_largest_deferred(deferred, out)
-            return
+            return False
         self._flush_evictions()   # ensure_pages() may have evicted pages
         S = self._bucket(max(phys for _, _, phys in active) + N,
                          self.s_buckets)
@@ -2326,10 +2413,11 @@ class EngineCore:
             self.dispatch_hook("decode", {"S": S, "chain": chain}, payload)
         packed, final_tok = self._run_decode_program(
             S, tokens, page_tables, lengths, fresh, active_mask)
-        self.stage.engine_dispatches.inc("decode")
         self.stage.engine_dispatch_tokens.inc(
             "decode", amount=float(len(active) * N))
-        self._inflight.append({"packed": packed, "final_tok": final_tok,
+        self._inflight.append({"kind": "decode",
+                               "seq": self._count_dispatch("decode"),
+                               "packed": packed, "final_tok": final_tok,
                                "active": active,
                                "lengths": [phys for _, _, phys in active],
                                "compiled": self._take_compiled_flag(),
@@ -2340,6 +2428,7 @@ class EngineCore:
         _flightrec.hb_begin("engine.decode", stall="decode")
         _flightrec.note_event("engine.dispatch", depth=len(self._inflight),
                               batch=len(active), steps=S)
+        return True
 
     def _run_decode_program(self, S: int, tokens, page_tables, lengths,
                             fresh, active_mask):
@@ -2584,8 +2673,20 @@ class EngineCore:
         # replayed dispatches (waiting for the leader) is not a phase
         self.phase.close()
 
-    def _process_oldest_inflight(self) -> List[StepOutput]:
-        """Fetch (blocking) and account the oldest in-flight dispatch."""
+    def _process_inflight(self, out: List[StepOutput],
+                          n: Optional[int] = None) -> None:
+        """Fetch (blocking) and account the ``n`` oldest in-flight
+        dispatches (all of them by default), oldest first, and release the
+        pages that only they still held."""
+        for _ in range(len(self._inflight) if n is None else n):
+            out.extend(self._fetch_prefill()
+                       if self._inflight[0]["kind"] == "prefill"
+                       else self._fetch_decode())
+            self._apply_deferred_release()
+
+    def _fetch_decode(self) -> List[StepOutput]:
+        """Fetch (blocking) and account the decode dispatch at the head of
+        the window."""
         rec = self._inflight.popleft()
         self.phase.to("decode_fetch")
         # dynalint: ok(host-sync) THE designed decode fetch: one [N,B,2]
@@ -2633,7 +2734,7 @@ class EngineCore:
                 if fin is not None:
                     # overshoot tokens beyond the finish are discarded; their
                     # page-pool writes are inside this seq's own pages, which
-                    # stay held until the in-flight window drains
+                    # stay held until the records now in flight are fetched
                     self._free_slot(i)
                     break
         return outs

@@ -510,6 +510,10 @@ class StageMetrics:
         self.engine_dispatches = r.counter(
             "dyn_engine_dispatches_total",
             "Device dispatches enqueued by the engine", ("kind",))
+        self.engine_dispatches_behind = r.counter(
+            "dyn_engine_dispatches_behind_total",
+            "Those enqueued while an earlier dispatch's result was still "
+            "unfetched: the host built them while the device ran", ("kind",))
         self.engine_dispatch_tokens = r.counter(
             "dyn_engine_dispatch_tokens_total",
             "Token positions computed by those dispatches (prompt tokens "

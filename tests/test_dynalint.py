@@ -698,7 +698,7 @@ def test_host_sync_report_cli_is_complete_transfer_budget(capsys):
     out = capsys.readouterr().out
     assert "0 open" in out
     # the three dispatch-path fetches are present, each with its reason
-    for token in ("_prefill_dispatch", "_process_oldest_inflight",
+    for token in ("_fetch_prefill", "_fetch_decode",
                   "_spec_round", "extract_kv"):
         assert token in out, f"missing {token} in transfer inventory"
     assert out.count("suppressed") >= 8

@@ -750,6 +750,161 @@ def test_lane_wait_is_the_wait_behind_another_prompt(core, monkeypatch):
     assert second.end >= lw1_prefill.end - 1e-3
 
 
+def _recording_hook(core, log):
+    """A ``dispatch_hook`` that notes, for every enqueue, what was still
+    unfetched at that moment."""
+    def hook(kind, meta, arrays):
+        log.append({"kind": kind, "chain": meta.get("chain"),
+                    "last_lanes": meta.get("last_lanes"),
+                    "host_tokens": "tokens" in arrays,
+                    "inflight": [r["kind"] for r in core._inflight]})
+    return hook
+
+
+def test_streams_with_prefill_behind_the_window_equal_streams_alone(
+        core, monkeypatch):
+    """Multi-chunk prompts, admissions and finishes while records are in
+    flight: every request's greedy stream is the one it gives alone."""
+    monkeypatch.setattr(core, "b_buckets", [1])     # one prefill lane
+    # a second pass must prefill again, not restore the first one's blocks
+    monkeypatch.setattr(core.cfg, "enable_prefix_reuse", False)
+    behind, total = (core.stage.engine_dispatches_behind,
+                     core.stage.engine_dispatches)
+    n0 = behind.get("prefill"), total.get("prefill")
+    reqs = {
+        "w0": req([7, 8, 9], max_tokens=30),
+        "w1": req(list(range(150, 80, -1)), max_tokens=12),     # 3 chunks
+        "w2": req(list(range(60, 100)), max_tokens=20),         # 2 chunks
+        "w3": req([11, 12, 13, 14, 15], max_tokens=9),
+        "w4": req(list(range(5, 105)), max_tokens=6),           # 4 chunks
+        "w5": req(list(range(200, 160, -1)), max_tokens=17),
+        "w6": req([21, 22], max_tokens=25),
+    }
+    due = {0: ["w0", "w1"], 3: ["w2", "w3", "w4"], 9: ["w5"], 14: ["w6"]}
+    got = {k: [] for k in reqs}
+    done = set()
+    for it in range(400):
+        for seq_id in due.get(it, ()):
+            core.submit(seq_id, reqs[seq_id])
+        for so in core.step():
+            assert so.finish in (None, FinishReason.LENGTH), so
+            got[so.seq_id].append(so.token)
+            if so.finish is not None:
+                done.add(so.seq_id)
+        if done == set(reqs):
+            break
+    assert done == set(reqs)
+    while core.has_work:
+        core.step()
+    n_behind = behind.get("prefill") - n0[0]
+    n_total = total.get("prefill") - n0[1]
+    assert n_total == sum(-(-len(r.token_ids) // 32) for r in reqs.values())
+    assert n_behind >= n_total - 2          # all but the cold starts
+    for seq_id, request in reqs.items():
+        core.submit("alone-" + seq_id, request)
+        alone = drain(core, ["alone-" + seq_id])["alone-" + seq_id]
+        assert [so.token for so in alone] == got[seq_id], seq_id
+    while core.has_work:
+        core.step()
+
+
+def test_prefill_is_enqueued_behind_the_decode_in_flight(core, monkeypatch):
+    """The order of enqueues: a chunk goes behind the decode dispatch in
+    flight, one chunk per decode dispatch; the decode behind a chunk that
+    completes no prompt chains on the device; after a change of lanes (a
+    sequence finished, a prompt completed) it takes host tokens, with no
+    decode dispatch unfetched."""
+    monkeypatch.setattr(core, "b_buckets", [1])
+    monkeypatch.setattr(core.cfg, "enable_prefix_reuse", False)
+    log = []
+    core.submit("pb-dec", req([1, 2, 3], max_tokens=60))
+    core.submit("pb-short", req([4, 5, 6], max_tokens=12))  # ends mid-prefill
+    outs = []
+    while not outs:
+        outs = core.step()
+    core.step()
+    monkeypatch.setattr(core, "dispatch_hook", _recording_hook(core, log))
+    core.submit("pb-long", req(list(range(100)), max_tokens=4))  # 4 chunks
+    done = False
+    while not done:
+        done = any(so.seq_id == "pb-long" and so.finish is not None
+                   for so in core.step())
+    core.cancel("pb-dec")
+    while core.has_work:
+        core.step()
+    chunks = [i for i, e in enumerate(log) if e["kind"] == "prefill"]
+    assert [bool(log[i]["last_lanes"]) for i in chunks] == [
+        False, False, False, True]
+    chained = 0
+    for i in chunks:
+        assert "decode" in log[i]["inflight"], log[i]
+        after = log[i + 1]
+        assert after["kind"] == "decode"    # one chunk per decode dispatch
+        if after["chain"]:
+            chained += 1
+            assert not log[i]["last_lanes"] and not after["host_tokens"]
+            assert after["inflight"][-1] == "prefill"
+        else:
+            # pb-long's last chunk, fetched first; or pb-short has finished
+            # and only the chunk is still unfetched
+            assert after["host_tokens"]
+            assert after["inflight"] == (
+                [] if log[i]["last_lanes"] else ["prefill"])
+    assert chained == 2
+
+
+def test_freed_pages_come_back_when_their_records_are_fetched(
+        core, monkeypatch):
+    """A sequence freed with records in flight keeps its pages until
+    exactly those records are fetched, not until the window is empty; and
+    three waves of full-context requests pass through a pool sized for one
+    without an error or an admission left waiting for held pages."""
+    monkeypatch.setattr(core, "b_buckets", [1])
+    monkeypatch.setattr(core.cfg, "enable_prefix_reuse", False)
+    held, released = {}, []
+    free_slot, release, admit = (core._free_slot, core.pool.release,
+                                 core._admit_one)
+
+    def spy_free(i):
+        slot = core.slots[i]
+        if slot is not None and core._inflight:
+            held[slot.seq_id] = {r["seq"] for r in core._inflight}
+        free_slot(i)
+
+    def spy_release(seq_id):
+        if seq_id in held:
+            now = {r["seq"] for r in core._inflight}
+            assert not (held.pop(seq_id) & now), seq_id
+            released.append(len(now))
+        release(seq_id)
+
+    def spy_admit(out):
+        res = admit(out)
+        # never left waiting for pages that a deferred release holds
+        assert res != "blocked" or not core._deferred_release
+        return res
+
+    monkeypatch.setattr(core, "_free_slot", spy_free)
+    monkeypatch.setattr(core.pool, "release", spy_release)
+    monkeypatch.setattr(core, "_admit_one", spy_admit)
+    n = 3 * core.cfg.max_batch
+    # 60 + 52..63 tokens of a 128-token context: a lane's share of the pool
+    reqs = {f"pg{j}": req([(j + 3 * t) % 250 for t in range(60)],
+                          max_tokens=52 + j) for j in range(n)}
+    for seq_id, request in reqs.items():
+        core.submit(seq_id, request)
+    got = drain(core, list(reqs))
+    while core.has_work:
+        core.step()
+    for seq_id, request in reqs.items():
+        assert got[seq_id][-1].finish == FinishReason.LENGTH
+        assert len(got[seq_id]) == request.stop.max_tokens
+        assert all(so.error is None for so in got[seq_id])
+    assert not held and not core._deferred_release
+    assert len(released) >= n // 2      # most finish behind a chained decode
+    assert any(released)                # ... and some with the window busy
+
+
 def test_xla_compile_listener_counts_a_program_once(core):
     import jax
     import jax.numpy as jnp

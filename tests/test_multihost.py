@@ -27,7 +27,15 @@ def free_port() -> int:
 
 
 @pytest.mark.slow
-async def test_two_process_worker_pair_serves_one_endpoint(tmp_path):
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["one_request", "prefill_behind_decode"])
+async def test_two_process_worker_pair_serves_one_endpoint(tmp_path, overlap):
+    """``overlap``: a three-chunk prompt arrives while another request
+    decodes, so the leader enqueues its chunks behind decode dispatches
+    still in flight and chains decodes behind them. The follower replays
+    the hook's sequence, which is the enqueue order: a dispatch it missed
+    or reordered would hang the leader's collectives, and one it ran with
+    other inputs would change the greedy streams."""
     store_port = free_port()
     coord_port = free_port()
     dispatch_port = free_port()
@@ -111,6 +119,8 @@ async def test_two_process_worker_pair_serves_one_endpoint(tmp_path):
         toks2 = [t for o in outs2 for t in o.get("token_ids", [])]
         assert toks2 == toks
 
+        if overlap:
+            await _prefill_behind_decode(cl, caller)
         await caller.close()
     finally:
         for w in workers:
@@ -123,6 +133,48 @@ async def test_two_process_worker_pair_serves_one_endpoint(tmp_path):
         store.terminate()
         for lf in logs:
             lf.close()
+
+
+async def _prefill_behind_decode(cl, caller):
+    from dynamo_tpu.llm.metrics_aggregator import fetch_stage_states
+    from dynamo_tpu.llm.protocols.common import BackendInput, StopConditions
+    from dynamo_tpu.utils.prometheus import render_states
+
+    def request(prompt, n):
+        return BackendInput(token_ids=prompt, stop=StopConditions(
+            max_tokens=n, ignore_eos=True)).to_dict()
+
+    async def stream(req, started=None):
+        toks = []
+        async for item in cl.generate(req):
+            toks += item.get("token_ids", [])
+            if started is not None and toks:
+                started.set()
+        return toks
+
+    dec = request([9, 8, 7], 40)                    # ten decode dispatches
+    long = request(list(range(10, 80)), 6)          # three chunks of 32
+    started = asyncio.Event()
+    decoding = asyncio.create_task(stream(dec, started))
+    await asyncio.wait_for(started.wait(), 60)
+    both = (await asyncio.wait_for(stream(long), 120),
+            await asyncio.wait_for(decoding, 120))
+    assert [len(t) for t in both] == [6, 40]
+    # each alone (its prefix restored, not prefilled again): same streams
+    assert await asyncio.wait_for(stream(long), 60) == both[0]
+    assert await asyncio.wait_for(stream(dec), 60) == both[1]
+    # and the leader did put chunks behind dispatches in flight
+    deadline = time.monotonic() + 20
+    behind = 0.0
+    while not behind and time.monotonic() < deadline:
+        text = render_states(await fetch_stage_states(caller.store,
+                                                      "dynamo"))
+        behind = sum(float(line.rsplit(" ", 1)[1])
+                     for line in text.splitlines()
+                     if line.startswith("dyn_engine_dispatches_behind_total")
+                     and 'kind="prefill"' in line)
+        await asyncio.sleep(0.5)
+    assert behind >= 2
 
 
 @pytest.mark.slow
