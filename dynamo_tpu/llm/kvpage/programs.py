@@ -133,31 +133,11 @@ class PagedPrograms:
 
         def make_qkv(local: bool):
             def qkv(params, l, x, positions, k_pool, v_pool, write_idx):
-                lp = params["layers"]
-                h = llama.rms_norm(x, lp["ln1"][l], m.rms_eps,
-                                   m.norm_offset)
-                q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
-                k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
-                v = jnp.einsum("btd,dhk->bthk", h, lp["wv"][l])
-                if m.attention_bias:
-                    q = q + lp["bq"][l]
-                    k = k + lp["bk"][l]
-                    v = v + lp["bv"][l]
-                if m.qk_norm:
-                    q = llama.rms_norm(q, lp["ln_q"][l], m.rms_eps,
-                                       m.norm_offset)
-                    k = llama.rms_norm(k, lp["ln_k"][l], m.rms_eps,
-                                       m.norm_offset)
-                cos, sin = llama.rope_tables(m, positions, local=local)
-                q = llama.apply_rope(q, cos, sin)
-                k = llama.apply_rope(k, cos, sin)
-                B, T = positions.shape
                 flat_w = write_idx.reshape(-1)
-                wp, wo = flat_w // page, flat_w % page
-                k_pool = llama.kv_write(k_pool, l, wp, wo,
-                                        k.reshape(B * T, *k.shape[2:]))
-                v_pool = llama.kv_write(v_pool, l, wp, wo,
-                                        v.reshape(B * T, *v.shape[2:]))
+                q, (k_pool, v_pool), _ = llama.layer_in(
+                    x, params["layers"], l, m,
+                    llama.rope_tables(m, positions, local=local),
+                    (k_pool, v_pool), flat_w // page, flat_w % page)
                 return q, k_pool, v_pool
 
             return jax.jit(qkv, donate_argnums=(4, 5),
@@ -230,14 +210,11 @@ class PagedPrograms:
             for c in layer_cls]
 
         def layer_out(params, l, x, o, m_, d):
-            lp = params["layers"]
             B, Hkv, G, T, Dh = o.shape
             attn = o / jnp.where(d == 0.0, 1.0, d)[..., None]
             attn = jnp.transpose(attn, (0, 3, 1, 2, 4)).reshape(
                 B, T, Hkv * G, Dh).astype(x.dtype)
-            x = llama._attn_residual(
-                x, jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l]), lp, l, m)
-            return llama._ffn_block(x, lp, l, m)
+            return llama.layer_out(x, attn, params["layers"], l, m)
 
         self.layer_out = jax.jit(layer_out, out_shardings=rep)
 
@@ -276,8 +253,7 @@ class PagedPrograms:
         if m.num_experts:
             return "MoE models"
         if m.has_indexer:
-            return ("models with an indexer (learned top-k attention): the "
-                    "segmented forward carries no index keys")
+            return f"models with an indexer ({llama.NO_INDEX_KEYS})"
         if m.vision is not None:
             return "VLM deployments (image spans need the dense path)"
         if cfg.pp > 1 or cfg.sp > 1:

@@ -11,6 +11,10 @@ Design (TPU-first, not a torch translation):
 - One forward function serves both prefill chunks (T>1) and decode (T=1):
   write-then-gather with a causal+length mask. Static shapes everywhere
   (bucketed T and S) per XLA's compile-once model.
+- One decoder layer (``layer_in`` / ``layer_out``, "The decoder layer"
+  below), which every way of serving calls around its own attention:
+  ``forward``, ``forward_decode``, ``forward_pp``'s stage body and the
+  pager's programs (``llm/kvpage/programs.py``).
 - bf16 weights/activations, fp32 norms/softmax/logits (MXU-friendly).
 
 Reference capability equivalent: the in-engine model executed by vLLM/TRT-LLM
@@ -31,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.mesh import AXIS_TP
+from ..parallel.mesh import AXIS_EP, AXIS_TP
 
 
 # ---------------------------------------------------------------------------
@@ -164,10 +168,13 @@ class LlamaConfig:
     mm_tokens_per_image: int = 256
     image_token_id: Optional[int] = None
 
-    def layer_sliding(self, layer: int) -> bool:
+    def layer_sliding(self, layer):
         """Every ``sliding_pattern``-th layer is full attention, the rest
         sliding (gemma2: 2 — alternating, even layers slide; gemma3: 6 —
-        five sliding then one full)."""
+        five sliding then one full). ``layer`` is the GLOBAL layer index: a
+        Python integer gives a Python bool; a traced one (a pipeline stage's
+        offset + local index) gives a traced bool, or the Python False of a
+        model that has no window. :func:`pick` selects by either."""
         return (self.sliding_window is not None
                 and (layer + 1) % self.sliding_pattern != 0)
 
@@ -884,6 +891,14 @@ def rope_tables(cfg: LlamaConfig, positions: jax.Array,
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def rope_pair(cfg: LlamaConfig, positions: jax.Array):
+    """-> ((cos, sin) at the model's base, (cos, sin) at the sliding layers'
+    own base or None for a model with one base): what :func:`pick` takes."""
+    return rope_tables(cfg, positions), (
+        rope_tables(cfg, positions, local=True)
+        if cfg.rope_local_theta is not None else None)
+
+
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     """x: [..., H, Dh]; cos/sin: [..., Dh/2] (broadcast over H)."""
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
@@ -916,45 +931,22 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
     return out.reshape(B, T, Hq, Dh)
 
 
-def _attn_residual(x: jax.Array, attn_out: jax.Array, lp: Dict[str, Any],
-                   l: int, cfg: LlamaConfig) -> jax.Array:
-    """Residual add after attention; Gemma2 norms the branch output first."""
-    if cfg.sandwich_norms:
-        attn_out = rms_norm(attn_out, lp["ln1_post"][l], cfg.rms_eps,
-                            cfg.norm_offset)
-    return x + attn_out
-
-
-def _ffn_block(x: jax.Array, lp: Dict[str, Any], l: int, cfg: LlamaConfig,
-               mesh=None, stats: Optional[Dict[str, Any]] = None
-               ) -> jax.Array:
-    """Pre-norm FFN (dense or MoE) + residual; Gemma2 adds a post-norm on
-    the branch output (sandwich norms). A routed layer adds its experts hit
-    to ``stats["experts_hit"]`` (see :func:`forward`)."""
-    h2 = rms_norm(x, lp["ln2"][l], cfg.rms_eps, cfg.norm_offset)
-    if cfg.num_experts:
-        from .moe import moe_ffn
-        out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
-                           cfg.experts_per_token, mesh=mesh, layer=l)
-        if stats is not None:
-            stats["experts_hit"] = stats.get("experts_hit", 0) + hit
-            if "chosen" in stats:
-                stats["chosen"].append(chosen)
-    else:
-        g = jnp.einsum("btd,df->btf", h2, lp["wg"][l])
-        u = jnp.einsum("btd,df->btf", h2, lp["wu"][l])
-        out = jnp.einsum("btf,fd->btd", _act(cfg)(g) * u, lp["wd"][l])
-    if cfg.sandwich_norms:
-        out = rms_norm(out, lp["ln2_post"][l], cfg.rms_eps, cfg.norm_offset)
-    return x + out
+def attend_ctx(cfg: LlamaConfig, q: jax.Array, k_ctx: jax.Array,
+               v_ctx: jax.Array, mask: jax.Array,
+               keep: Optional[jax.Array] = None) -> jax.Array:
+    """:func:`attend` over a gathered context with the model's scale and
+    softcap; ``keep`` (an indexer's selection) narrows the mask."""
+    return attend(q, k_ctx, v_ctx, mask if keep is None else mask & keep,
+                  scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap)
 
 
 def _lm_head(x: jax.Array, params: Dict[str, Any],
              cfg: LlamaConfig) -> jax.Array:
-    """Final norm + vocab projection (+ Gemma2 final logit softcap), fp32."""
+    """Final norm + vocab projection (+ Gemma2 final logit softcap), fp32;
+    ``x`` [..., D] with leading dimensions as they come."""
     x = rms_norm(x, params["final_norm"], cfg.rms_eps, cfg.norm_offset)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", x, head.astype(x.dtype))
+    logits = jnp.einsum("...d,dv->...v", x, head.astype(x.dtype))
     logits = logits.astype(jnp.float32)
     if cfg.final_logit_softcap:
         cap = cfg.final_logit_softcap
@@ -1116,6 +1108,148 @@ def _index_step(h: jax.Array, lp: Dict[str, Any], l: int, cfg: LlamaConfig,
 
 
 # ---------------------------------------------------------------------------
+# The decoder layer
+#
+# One layer's mathematics, written once, in two halves around the attention
+# itself. A forward owns what differs between the ways of serving: how the
+# new rows are addressed into the cache (token slots, page tables, a stage's
+# slice, the pager's write index), what attention runs over (rows or pages
+# gathered, the pool in place, a shard, a ring, the pager's segments), and
+# whether ``l`` is a Python integer or traced (a pipeline stage's offset, the
+# pager's one program for every layer of a class). Everything else is here:
+# a term added to the layer is added here, and a forward that does not hand
+# over what the term needs refuses the model from here.
+# ---------------------------------------------------------------------------
+
+# the one refusal of a model with an indexer by a forward whose cache I/O
+# was never given the third pool (forward_pp, the pager: ROADMAP Design 4)
+NO_INDEX_KEYS = ("this cache carries no index-key pool: a model with an "
+                 "indexer (learned top-k attention) writes its index keys "
+                 "beside K/V and selects from them")
+
+
+def pick(sliding, if_sliding, if_full):
+    """The sliding or the full variant of a layer's operand (rotary tables,
+    mask), by ``sliding`` = ``cfg.layer_sliding(l)``: a Python bool picks; a
+    traced one (a pipeline stage) selects between the two, leaf by leaf.
+    ``if_sliding`` is None where the model has one variant only."""
+    if sliding is False or if_sliding is None:
+        return if_full
+    if sliding is True:
+        return if_sliding
+    return jax.tree.map(partial(jnp.where, sliding), if_sliding, if_full)
+
+
+def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
+             rope: Tuple[jax.Array, jax.Array], pools: Tuple[jax.Array, ...],
+             w_page: jax.Array, w_off: jax.Array, mode: Optional[str] = None,
+             index: Optional[Tuple[Any, ...]] = None,
+             stats: Optional[Dict[str, Any]] = None):
+    """The layer before attention: input norm, the three projections (bias,
+    q/k norm), rotary, and the new rows into the cache.
+
+    ``lp`` holds the stacked layer parameters (the whole stack or a stage's
+    slice) and ``l`` indexes them and ``pools`` = (k_pool, v_pool[, i_pool]);
+    ``rope`` is the (cos, sin) the caller chose for this layer (:func:`pick`).
+    The rows of ``x`` [B,T,D] go to token slots (``w_page``, ``w_off``), both
+    [B*T]; ``mode`` as :func:`kv_write`'s. For a model with an indexer
+    ``index`` = (rope_i, pages, visible) is what :func:`_index_step` takes
+    beside the slots, and ``stats["keep"]``, where the caller put a list,
+    receives the layer's keep mask.
+    -> (q [B,T,Hq,Dh], pools, keep [B,T,S] or None)."""
+    h = rms_norm(x, lp["ln1"][l], cfg.rms_eps, cfg.norm_offset)
+    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
+    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
+    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"][l])
+    if cfg.attention_bias:
+        q = q + lp["bq"][l]
+        k = k + lp["bk"][l]
+        v = v + lp["bv"][l]
+    if cfg.qk_norm:
+        # gemma3: per-head RMSNorm on q/k AFTER projection, BEFORE rope
+        q = rms_norm(q, lp["ln_q"][l], cfg.rms_eps, cfg.norm_offset)
+        k = rms_norm(k, lp["ln_k"][l], cfg.rms_eps, cfg.norm_offset)
+    q = apply_rope(q, *rope)
+    k = apply_rope(k, *rope)
+    k_pool, v_pool, *i_pool = pools
+    # write, then attend: the new rows are part of their own context
+    k_pool = kv_write(k_pool, l, w_page, w_off, k.reshape(-1, *k.shape[2:]),
+                      mode)
+    v_pool = kv_write(v_pool, l, w_page, w_off, v.reshape(-1, *v.shape[2:]),
+                      mode)
+    keep = None
+    if cfg.has_indexer:
+        if index is None or not i_pool:
+            raise ValueError(NO_INDEX_KEYS)
+        rope_i, pages, visible = index
+        i_pool[0], keep = _index_step(h, lp, l, cfg, rope_i, i_pool[0],
+                                      w_page, w_off, pages, visible)
+    if stats is not None and "keep" in stats:
+        stats["keep"].append(keep)
+    return q, (k_pool, v_pool, *i_pool), keep
+
+
+def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
+              cfg: LlamaConfig, mesh=None,
+              stats: Optional[Dict[str, Any]] = None,
+              inside: Optional[Dict[str, int]] = None) -> jax.Array:
+    """The layer after attention: out-projection of ``attn`` [B,T,Hq,Dh] and
+    residual (Gemma2 norms the branch output first), then the feed-forward.
+
+    ``inside``: a caller that is ALREADY inside manual SPMD (``forward_pp``'s
+    stage body; shard_maps do not nest) names the mesh axes it is inside of
+    with their sizes (> 1); ``lp`` is then this shard's slice, the two
+    contractions over the sharded dimension leave partial sums, and they are
+    reduced here. Without it the reductions are GSPMD's."""
+    o = jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l])
+    if inside and AXIS_TP in inside:
+        o = jax.lax.psum(o, AXIS_TP)
+    if cfg.sandwich_norms:
+        o = rms_norm(o, lp["ln1_post"][l], cfg.rms_eps, cfg.norm_offset)
+    return _ffn_block(x + o, lp, l, cfg, mesh=mesh, stats=stats,
+                      inside=inside)
+
+
+def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
+               mesh=None, stats: Optional[Dict[str, Any]] = None,
+               inside: Optional[Dict[str, int]] = None) -> jax.Array:
+    """Pre-norm FFN (dense or MoE) + residual; Gemma2 adds a post-norm on
+    the branch output (sandwich norms). A routed layer adds its experts hit
+    to ``stats["experts_hit"]`` (see :func:`forward`). ``inside`` as
+    :func:`layer_out`'s."""
+    h2 = rms_norm(x, lp["ln2"][l], cfg.rms_eps, cfg.norm_offset)
+    if cfg.num_experts and inside is not None:
+        # router replicated, experts sharded over ep and their width over tp
+        # where it divides (param_specs): dense dispatch of this shard's
+        # experts, one psum over both axes
+        from .moe import moe_ffn_in_stage
+        axes = [AXIS_EP] if AXIS_EP in inside else []
+        if AXIS_TP in inside and cfg.expert_width % inside[AXIS_TP] == 0:
+            axes.append(AXIS_TP)
+        out = moe_ffn_in_stage(h2, lp["wr"][l], lp["wg"][l], lp["wu"][l],
+                               lp["wd"][l], cfg.experts_per_token,
+                               ep=inside.get(AXIS_EP, 1),
+                               psum_axes=tuple(axes))
+    elif cfg.num_experts:
+        from .moe import moe_ffn
+        out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
+                           cfg.experts_per_token, mesh=mesh, layer=l)
+        if stats is not None:
+            stats["experts_hit"] = stats.get("experts_hit", 0) + hit
+            if "chosen" in stats:
+                stats["chosen"].append(chosen)
+    else:
+        g = jnp.einsum("btd,df->btf", h2, lp["wg"][l])
+        u = jnp.einsum("btd,df->btf", h2, lp["wu"][l])
+        out = jnp.einsum("btf,fd->btd", _act(cfg)(g) * u, lp["wd"][l])
+        if inside and AXIS_TP in inside:
+            out = jax.lax.psum(out, AXIS_TP)
+    if cfg.sandwich_norms:
+        out = rms_norm(out, lp["ln2_post"][l], cfg.rms_eps, cfg.norm_offset)
+    return x + out
+
+
+# ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
@@ -1183,20 +1317,18 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
       the or-mask applies to full and sliding layers alike
       (modeling_gemma3.py:936-953).
     """
-    B, T = tokens.shape
     page = k_pool.shape[3]
     lp = params["layers"]
     x = _embed(params, cfg, tokens)  # [B,T,D] bf16
     if embed_override is not None:
         ov_vals, ov_mask = embed_override
         x = jnp.where(ov_mask[..., None], ov_vals.astype(x.dtype), x)
-    cos, sin = rope_tables(cfg, positions)
-    if cfg.rope_local_theta is not None:
-        cos_l, sin_l = rope_tables(cfg, positions, local=True)
+    rope, rope_sl = rope_pair(cfg, positions)
     flat_w = write_idx.reshape(-1)
     wp, wo = flat_w // page, flat_w % page
     if read_pages is None:
         rp, ro = read_idx // page, read_idx % page
+    sliding_mask = None
     if attn_impl == "ring":
         from ..parallel.mesh import AXIS_TP as _TP
         from ..parallel.ring_attention import ring_attention
@@ -1259,11 +1391,11 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             "image-span bidirectional attention (Gemma3 VLM) runs on "
             "attn_impl='xla' only; flash/ring kernels take no span inputs")
     _require_xla_attn(cfg, attn_impl)
-    keep = None
-    if cfg.has_indexer:
-        if i_pool is None or read_pages is None:
-            raise ValueError("a model with an indexer needs its index-key "
-                             "pool and reads its context by page")
+    pools, index = (k_pool, v_pool), None
+    if cfg.has_indexer and i_pool is not None:
+        if read_pages is None:
+            raise ValueError("a model with an indexer reads its context by "
+                             "page (read_pages)")
         if attn_impl == "ring" or attn_spans is not None:
             raise ValueError("ring attention / image spans take no "
                              "selection (model with an indexer)")
@@ -1273,42 +1405,19 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
         visible = (read_valid[:, None, :]
                    & (read_pos[:, None, :] <= positions[:, :, None])
                    ) if read_pos.shape[1] > cfg.index_topk else None
+        pools, index = (k_pool, v_pool, i_pool), (rope_i, read_pages, visible)
 
-    # NOTE: forward_pp.apply_stage mirrors this layer body for the
-    # pipeline-parallel stages; test_forward_pp pins their exactness —
-    # change them together.
     for l in range(cfg.num_layers):
-        h = rms_norm(x, lp["ln1"][l], cfg.rms_eps, cfg.norm_offset)
-        q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
-        k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
-        v = jnp.einsum("btd,dhk->bthk", h, lp["wv"][l])
-        if cfg.attention_bias:
-            q = q + lp["bq"][l]
-            k = k + lp["bk"][l]
-            v = v + lp["bv"][l]
-        if cfg.qk_norm:
-            # gemma3: per-head RMSNorm on q/k AFTER projection, BEFORE rope
-            q = rms_norm(q, lp["ln_q"][l], cfg.rms_eps, cfg.norm_offset)
-            k = rms_norm(k, lp["ln_k"][l], cfg.rms_eps, cfg.norm_offset)
-        if cfg.rope_local_theta is not None and cfg.layer_sliding(l):
-            q = apply_rope(q, cos_l, sin_l)
-            k = apply_rope(k, cos_l, sin_l)
-        else:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        # scatter chunk KV into the pool (write-then-gather)
-        k_pool = kv_write(k_pool, l, wp, wo, k.reshape(B * T, *k.shape[2:]))
-        v_pool = kv_write(v_pool, l, wp, wo, v.reshape(B * T, *v.shape[2:]))
-        if cfg.has_indexer:
-            i_pool, keep = _index_step(h, lp, l, cfg, rope_i, i_pool, wp, wo,
-                                       read_pages, visible)
+        sl = cfg.layer_sliding(l)
+        q, pools, keep = layer_in(x, lp, l, cfg, pick(sl, rope_sl, rope),
+                                  pools, wp, wo, index=index, stats=stats)
         # gather this sequence's context: [B, S, Hkv, Dh]
         if read_pages is not None:
-            k_ctx = kv_pages(k_pool, l, read_pages)
-            v_ctx = kv_pages(v_pool, l, read_pages)
+            k_ctx = kv_pages(pools[0], l, read_pages)
+            v_ctx = kv_pages(pools[1], l, read_pages)
         else:
-            k_ctx = kv_rows(k_pool, l, rp, ro)
-            v_ctx = kv_rows(v_pool, l, rp, ro)
+            k_ctx = kv_rows(pools[0], l, rp, ro)
+            v_ctx = kv_rows(pools[1], l, rp, ro)
         if attn_impl == "flash":
             attn = flash_for(l)(q, k_ctx, v_ctx, positions, read_pos,
                                 read_valid,
@@ -1319,22 +1428,14 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                                   head_axis=head_axis,
                                   scale=cfg.attn_scale)
         else:
-            m_l = sliding_mask if cfg.layer_sliding(l) else mask
-            attn = attend(q, k_ctx, v_ctx,
-                          m_l if keep is None else m_l & keep,
-                          scale=cfg.attn_scale,
-                          softcap=cfg.attn_logit_softcap)
-        x = _attn_residual(x, jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l]),
-                           lp, l, cfg)
-        if stats is not None and "keep" in stats:
-            stats["keep"].append(keep)
-        x = _ffn_block(x, lp, l, cfg, mesh=mesh, stats=stats)
+            attn = attend_ctx(cfg, q, k_ctx, v_ctx,
+                              pick(sl, sliding_mask, mask), keep)
+        x = layer_out(x, attn, lp, l, cfg, mesh=mesh, stats=stats)
 
     if logits_idx is not None:
         x = jnp.take_along_axis(
             x, logits_idx[:, None, None].astype(jnp.int32), axis=1)  # [B,1,D]
-    out = (_lm_head(x, params, cfg), k_pool, v_pool)
-    return out if i_pool is None else (*out, i_pool)
+    return (_lm_head(x, params, cfg), *pools)
 
 
 def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
@@ -1373,16 +1474,14 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
     delegates to vLLM `pipeline_parallel_size`); here the model compute
     path itself is pp-partitioned and engine-served (JaxEngineConfig.pp).
     """
-    from ..parallel.mesh import AXIS_EP, AXIS_PP
+    from ..parallel.mesh import AXIS_PP
 
-    M, Bm, T = tokens.shape
+    M = tokens.shape[0]
     L = cfg.num_layers
     pp = _pp_size(mesh)
     _require_xla_attn(cfg, attn_impl)
     if cfg.has_indexer:
-        raise ValueError(
-            "forward_pp does not carry the index-key pool: a model with an "
-            "indexer (learned top-k attention) is not served with pp")
+        raise ValueError(f"forward_pp: {NO_INDEX_KEYS}")
     if pp == 1:
         outs = []
         li = None
@@ -1401,165 +1500,78 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
     # shard would silently pair its local q heads with the wrong kv heads
     assert cfg.num_kv_heads % tp_sz == 0, \
         f"pp with tp={tp_sz} needs kv heads divisible (got {cfg.num_kv_heads})"
-    # pp x ep (round 5): the stage body computes its LOCAL experts' dense
-    # dispatch for the full token set and psums over ep — same math as
-    # moe_ffn's sharded formulation, inlined because we're already inside
-    # the pp(+tp) shard_map and shard_maps don't nest
-    ep_sz = (mesh.shape[AXIS_EP]
-             if mesh is not None and AXIS_EP in mesh.axis_names else 1)
-    E = cfg.num_experts
-    moe_tp = (tp_sz if E and tp_sz > 1
-              and cfg.expert_width % tp_sz == 0 else 1)
+    # the stage body is manual SPMD over pp AND tp / ep (shard_maps do not
+    # nest): each shard computes its head / ffn / expert slice and the layer
+    # reduces the partial sums over the axes named here
+    inside = {ax: mesh.shape[ax] for ax in (AXIS_EP, AXIS_TP)
+              if ax in mesh.axis_names and mesh.shape[ax] > 1}
     page = k_pool.shape[3]
     lp = params["layers"]
 
     # embed + rope for every microbatch, replicated (cheap, not stacked);
     # rope_tables handles arbitrary leading dims
     x0 = _embed(params, cfg, tokens)                   # [M, Bm, T, D]
-    cos, sin = rope_tables(cfg, positions)             # [M, Bm, T, Dh/2]
-    if cfg.rope_local_theta is not None:
-        cos_sl, sin_sl = rope_tables(cfg, positions, local=True)
-    else:
-        cos_sl, sin_sl = cos, sin   # unused; keeps the shard_map arity fixed
+    rope, rope_sl = rope_pair(cfg, positions)      # (cos, sin) [M,Bm,T,Dh/2]
 
     perm_fwd = [(i, (i + 1) % pp) for i in range(pp)]
+    if attn_impl == "flash":
+        # in-stage Pallas flash: already inside manual SPMD, so the kernel
+        # runs on this shard's q/kv head slices directly, the per-shard call
+        # shape of forward()'s tp path
+        from ..ops.attention import flash_attention
+        fl = partial(flash_attention, scale=cfg.attn_scale,
+                     softcap=cfg.attn_logit_softcap,
+                     interpret=_kernel_interpret(mesh))
 
-    def local(lp_loc, kp_loc, vp_loc, x0, cos, sin, cos_sl, sin_sl,
-              positions, widx, ridx, rpos, rvalid):
+    def local(lp_loc, kp_loc, vp_loc, x0, rope, rope_sl, positions, widx,
+              ridx, rpos, rvalid):
         idx = jax.lax.axis_index(AXIS_PP)
         Lloc = L // pp
         cur = jnp.zeros_like(x0[0])
         outs = jnp.zeros_like(x0)
 
         def apply_stage(carry, mb, live):
-            cur, kp, vp = carry
-            c_m = jax.lax.dynamic_index_in_dim(cos, mb, keepdims=False)
-            s_m = jax.lax.dynamic_index_in_dim(sin, mb, keepdims=False)
-            cl_m = jax.lax.dynamic_index_in_dim(cos_sl, mb, keepdims=False)
-            sl_m = jax.lax.dynamic_index_in_dim(sin_sl, mb, keepdims=False)
-            widx_m = jax.lax.dynamic_index_in_dim(widx, mb, keepdims=False)
-            ridx_m = jax.lax.dynamic_index_in_dim(ridx, mb, keepdims=False)
-            rpos_m = jax.lax.dynamic_index_in_dim(rpos, mb, keepdims=False)
-            rval_m = jax.lax.dynamic_index_in_dim(rvalid, mb, keepdims=False)
-            pos_m = jax.lax.dynamic_index_in_dim(positions, mb,
-                                                 keepdims=False)
+            x, *pools = carry
+            (rope_m, rope_sl_m, widx_m, ridx_m, rpos_m, rval_m,
+             pos_m) = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, mb, keepdims=False),
+                (rope, rope_sl, widx, ridx, rpos, rvalid, positions))
             flat_w = widx_m.reshape(-1)
             # bubble steps write NOTHING: out-of-bounds page index + drop
             # mode gates the scatter itself (a whole-pool select per step
             # would copy the dominant HBM tensor twice each step)
-            flat_w = jnp.where(live, flat_w, kp.shape[2] * page)
+            flat_w = jnp.where(live, flat_w, pools[0].shape[2] * page)
             wp, wo = flat_w // page, flat_w % page
             rp, ro = ridx_m // page, ridx_m % page
             mask = (rval_m[:, None, :]
                     & (rpos_m[:, None, :] <= pos_m[:, :, None]))
+            sliding_mask = None
             if cfg.sliding_window is not None:
                 sliding_mask = mask & (
                     rpos_m[:, None, :]
                     > pos_m[:, :, None] - cfg.sliding_window)
-            # mirrors forward's xla layer body (see the NOTE there);
-            # test_forward_pp pins exactness between the two. With tp > 1
-            # each shard computes its head/ffn slice; the wo/wd
-            # contractions produce partial sums reduced over tp.
-            x = cur
             for l in range(Lloc):
-                h = rms_norm(x, lp_loc["ln1"][l], cfg.rms_eps, cfg.norm_offset)
-                q = jnp.einsum("btd,dhk->bthk", h, lp_loc["wq"][l])
-                k = jnp.einsum("btd,dhk->bthk", h, lp_loc["wk"][l])
-                v = jnp.einsum("btd,dhk->bthk", h, lp_loc["wv"][l])
-                if cfg.attention_bias:
-                    q = q + lp_loc["bq"][l]
-                    k = k + lp_loc["bk"][l]
-                    v = v + lp_loc["bv"][l]
-                if cfg.qk_norm:
-                    q = rms_norm(q, lp_loc["ln_q"][l], cfg.rms_eps,
-                                 cfg.norm_offset)
-                    k = rms_norm(k, lp_loc["ln_k"][l], cfg.rms_eps,
-                                 cfg.norm_offset)
-                if (cfg.rope_local_theta is not None
-                        and cfg.sliding_window is not None):
-                    # gemma3 dual-base rope: the GLOBAL layer index (traced
-                    # stage offset) picks local vs global tables — same
-                    # guard as cfg.layer_sliding so pp stays exact vs the
-                    # sequential forward when sliding_window is unset
-                    sl = (idx * Lloc + l + 1) % cfg.sliding_pattern != 0
-                    c_sel = jnp.where(sl, cl_m, c_m)
-                    s_sel = jnp.where(sl, sl_m, s_m)
+                # the GLOBAL layer index (traced stage offset + local index)
+                # decides sliding vs full: rotary tables and masks are
+                # selected, the two compiled kernel variants cond'ed
+                sl = cfg.layer_sliding(idx * Lloc + l)
+                q, pools, _ = layer_in(x, lp_loc, l, cfg,
+                                       pick(sl, rope_sl_m, rope_m), pools,
+                                       wp, wo, mode="drop")
+                k_ctx = kv_rows(pools[0], l, rp, ro)
+                v_ctx = kv_rows(pools[1], l, rp, ro)
+                if attn_impl != "flash":
+                    attn = attend_ctx(cfg, q, k_ctx, v_ctx,
+                                      pick(sl, sliding_mask, mask))
+                elif cfg.sliding_window is None:
+                    attn = fl(q, k_ctx, v_ctx, pos_m, rpos_m, rval_m)
                 else:
-                    c_sel, s_sel = c_m, s_m
-                q = apply_rope(q, c_sel, s_sel)
-                k = apply_rope(k, c_sel, s_sel)
-                kp = kv_write(kp, l, wp, wo, k.reshape(-1, *k.shape[2:]),
-                              mode="drop")
-                vp = kv_write(vp, l, wp, wo, v.reshape(-1, *v.shape[2:]),
-                              mode="drop")
-                k_ctx = kv_rows(kp, l, rp, ro)
-                v_ctx = kv_rows(vp, l, rp, ro)
-                if attn_impl == "flash":
-                    # in-stage Pallas flash: we're already inside manual
-                    # SPMD (pp x tp shard_map), so the kernel runs on this
-                    # shard's q/kv head slices directly — same per-shard
-                    # call shape as forward()'s tp path (removes the
-                    # pp-forfeits-kernels restriction, VERDICT r3 weak #5)
-                    from ..ops.attention import flash_attention
-                    fl = partial(flash_attention, scale=cfg.attn_scale,
-                                 softcap=cfg.attn_logit_softcap,
-                                 interpret=_kernel_interpret(mesh))
-                    if cfg.sliding_window is not None:
-                        # sliding-vs-full depends on the GLOBAL layer index
-                        # (traced stage offset); window is a static kernel
-                        # param — cond picks between the two compiled
-                        # variants at run time
-                        sl = (idx * Lloc + l + 1) % cfg.sliding_pattern != 0
-                        attn = jax.lax.cond(
-                            sl,
-                            partial(fl, window=cfg.sliding_window),
-                            fl, q, k_ctx, v_ctx, pos_m, rpos_m, rval_m)
-                    else:
-                        attn = fl(q, k_ctx, v_ctx, pos_m, rpos_m, rval_m)
-                elif cfg.sliding_window is not None:
-                    # the GLOBAL layer index (stage offset + local index)
-                    # decides sliding vs full — idx is traced, so select
-                    m_l = jnp.where(
-                        (idx * Lloc + l + 1) % cfg.sliding_pattern != 0,
-                        sliding_mask, mask)
-                    attn = attend(q, k_ctx, v_ctx, m_l,
-                                  scale=cfg.attn_scale,
-                                  softcap=cfg.attn_logit_softcap)
-                else:
-                    attn = attend(q, k_ctx, v_ctx, mask,
-                                  scale=cfg.attn_scale,
-                                  softcap=cfg.attn_logit_softcap)
-                o = jnp.einsum("bthk,hkd->btd", attn, lp_loc["wo"][l])
-                if tp_sz > 1:
-                    o = jax.lax.psum(o, AXIS_TP)
-                x = _attn_residual(x, o, lp_loc, l, cfg)
-                h2 = rms_norm(x, lp_loc["ln2"][l], cfg.rms_eps, cfg.norm_offset)
-                if E:
-                    # routed MoE: router replicated, experts sharded over
-                    # ep (and F over tp when divisible). Dense dispatch —
-                    # every local expert sees every token; non-local gate
-                    # weights are zero, so the ep psum is exact. Gating and
-                    # expert math are moe.py's shared helpers: the pp path
-                    # cannot silently diverge from the pp=1 moe_ffn policy.
-                    from .moe import moe_ffn_in_stage
-                    f = moe_ffn_in_stage(
-                        h2, lp_loc["wr"][l], lp_loc["wg"][l],
-                        lp_loc["wu"][l], lp_loc["wd"][l],
-                        cfg.experts_per_token, ep=ep_sz,
-                        psum_axes=tuple(ax for ax, n in (
-                            (AXIS_EP, ep_sz), (AXIS_TP, moe_tp)) if n > 1))
-                else:
-                    g = jnp.einsum("btd,df->btf", h2, lp_loc["wg"][l])
-                    u = jnp.einsum("btd,df->btf", h2, lp_loc["wu"][l])
-                    f = jnp.einsum("btf,fd->btd", _act(cfg)(g) * u,
-                                   lp_loc["wd"][l])
-                    if tp_sz > 1:
-                        f = jax.lax.psum(f, AXIS_TP)
-                if cfg.sandwich_norms:
-                    f = rms_norm(f, lp_loc["ln2_post"][l], cfg.rms_eps,
-                                 cfg.norm_offset)
-                x = x + f
-            return x, kp, vp
+                    # window is a static kernel param
+                    attn = jax.lax.cond(
+                        sl, partial(fl, window=cfg.sliding_window), fl,
+                        q, k_ctx, v_ctx, pos_m, rpos_m, rval_m)
+                x = layer_out(x, attn, lp_loc, l, cfg, inside=inside)
+            return (x, *pools)
 
         for t in range(M + pp - 1):
             if t < M:
@@ -1590,24 +1602,16 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
     rep = P()
     xs, k_pool, v_pool = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(pspec, pool_spec, pool_spec, rep, rep, rep, rep, rep,
-                  rep, rep, rep, rep, rep),
+        in_specs=(pspec, pool_spec, pool_spec) + (rep,) * 8,
         out_specs=(rep, pool_spec, pool_spec),
         check_vma=False,
-    )(lp, k_pool, v_pool, x0, cos, sin, cos_sl, sin_sl, positions,
-      write_idx, read_idx, read_pos, read_valid)
+    )(lp, k_pool, v_pool, x0, rope, rope_sl, positions, write_idx, read_idx,
+      read_pos, read_valid)
 
     if logits_idx is not None:
         xs = jnp.take_along_axis(
             xs, logits_idx[:, :, None, None].astype(jnp.int32), axis=2)
-    xs = rms_norm(xs, params["final_norm"], cfg.rms_eps, cfg.norm_offset)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("mbtd,dv->mbtv", xs, head.astype(xs.dtype))
-    logits = logits.astype(jnp.float32)
-    if cfg.final_logit_softcap:
-        cap = cfg.final_logit_softcap
-        logits = jnp.tanh(logits / cap) * cap
-    return logits, k_pool, v_pool
+    return _lm_head(xs, params, cfg), k_pool, v_pool
 
 
 def forward_decode_pp(params: Dict[str, Any], cfg: LlamaConfig,
@@ -1728,24 +1732,19 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     result). The selection goes INTO the paged kernel as a keep mask over
     the lane's logical positions.
     """
-    B = tokens.shape[0]
     page = k_pool.shape[3]
     lp = params["layers"]
     pos = lengths - 1                                  # [B]
-    keep = None
-    if cfg.has_indexer:
-        if i_pool is None:
-            raise ValueError("a model with an indexer needs its index-key "
-                             "pool")
+    pools, index = (k_pool, v_pool), None
+    if cfg.has_indexer and i_pool is not None:
         rope_i = _index_rope(cfg, pos[:, None])
         S_ctx = page_tables.shape[1] * page
         visible = (jnp.arange(S_ctx, dtype=jnp.int32)[None]
                    < lengths[:, None])[:, None, :] if (
             S_ctx > cfg.index_topk) else None           # [B,1,S]
+        pools, index = (k_pool, v_pool, i_pool), (rope_i, page_tables, visible)
     x = _embed(params, cfg, tokens)[:, None]           # [B,1,D]
-    cos, sin = rope_tables(cfg, pos[:, None])
-    if cfg.rope_local_theta is not None:
-        cos_l, sin_l = rope_tables(cfg, pos[:, None], local=True)
+    rope, rope_sl = rope_pair(cfg, pos[:, None])
     w_page = jnp.take_along_axis(page_tables, (pos // page)[:, None],
                                  axis=1)[:, 0]
     w_off = pos % page
@@ -1784,52 +1783,28 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         t = jnp.arange(S, dtype=jnp.int32)
         # causal == validity here: the query is the last token
         mask = (t[None] < lengths[:, None])[:, None, :]  # [B,1,S]
+        sliding_mask = None
         if cfg.sliding_window is not None:
             # single-query: the window collapses to a per-lane slot range
             sliding_mask = mask & (
                 t[None] > pos[:, None] - cfg.sliding_window)[:, None, :]
 
     for l in range(cfg.num_layers):
-        h = rms_norm(x, lp["ln1"][l], cfg.rms_eps, cfg.norm_offset)
-        q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
-        k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
-        v = jnp.einsum("btd,dhk->bthk", h, lp["wv"][l])
-        if cfg.attention_bias:
-            q = q + lp["bq"][l]
-            k = k + lp["bk"][l]
-            v = v + lp["bv"][l]
-        if cfg.qk_norm:
-            q = rms_norm(q, lp["ln_q"][l], cfg.rms_eps, cfg.norm_offset)
-            k = rms_norm(k, lp["ln_k"][l], cfg.rms_eps, cfg.norm_offset)
-        if cfg.rope_local_theta is not None and cfg.layer_sliding(l):
-            q = apply_rope(q, cos_l, sin_l)
-            k = apply_rope(k, cos_l, sin_l)
-        else:
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-        k_pool = kv_write(k_pool, l, w_page, w_off, k[:, 0])
-        v_pool = kv_write(v_pool, l, w_page, w_off, v[:, 0])
-        if cfg.has_indexer:
-            i_pool, keep = _index_step(h, lp, l, cfg, rope_i, i_pool, w_page,
-                                       w_off, page_tables, visible)
+        sl = cfg.layer_sliding(l)
+        q, pools, keep = layer_in(x, lp, l, cfg, pick(sl, rope_sl, rope),
+                                  pools, w_page, w_off, index=index,
+                                  stats=stats)
         if attn_impl == "pallas":
             # the kernel reads the whole pool in place, by layer index
             attn = paged_for(l)(
-                q[:, 0], k_pool, v_pool, page_tables, lengths, jnp.int32(l),
+                q[:, 0], pools[0], pools[1], page_tables, lengths,
+                jnp.int32(l),
                 **({} if keep is None else {"keep": keep[:, 0]}))[:, None]
         else:
-            k_ctx = kv_pages(k_pool, l, page_tables)   # [B,S,Hkv,Dh]
-            v_ctx = kv_pages(v_pool, l, page_tables)
-            m_l = sliding_mask if cfg.layer_sliding(l) else mask
-            attn = attend(q, k_ctx, v_ctx,
-                          m_l if keep is None else m_l & keep,
-                          scale=cfg.attn_scale,
-                          softcap=cfg.attn_logit_softcap)
-        x = _attn_residual(x, jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l]),
-                           lp, l, cfg)
-        if stats is not None and "keep" in stats:
-            stats["keep"].append(keep)
-        x = _ffn_block(x, lp, l, cfg, mesh=mesh, stats=stats)
+            k_ctx = kv_pages(pools[0], l, page_tables)   # [B,S,Hkv,Dh]
+            v_ctx = kv_pages(pools[1], l, page_tables)
+            attn = attend_ctx(cfg, q, k_ctx, v_ctx,
+                              pick(sl, sliding_mask, mask), keep)
+        x = layer_out(x, attn, lp, l, cfg, mesh=mesh, stats=stats)
 
-    out = (_lm_head(x, params, cfg), k_pool, v_pool)
-    return out if i_pool is None else (*out, i_pool)
+    return (_lm_head(x, params, cfg), *pools)

@@ -240,7 +240,7 @@ def run_profile(engine: str, batches: Sequence[int],
                 ) -> ProfileTable:
     """The sweep: one fresh core per (batch, seq_len) point (decode always
     dispatches at full engine width — a max-sized engine would measure
-    padding, not batch-b latency; same reasoning as bench.py)."""
+    padding, not batch-b latency)."""
     points: List[ProfilePoint] = []
     meta: Dict[str, Any] = {"engine": engine}
     for seq_len in seq_lens:

@@ -307,8 +307,7 @@ _ALL: List[Knob] = [
     # byte-flow ledger (obs/flows.py): the per-process accounting
     # chokepoint every byte-moving site records through
     _k("DYN_FLOWS", "bool", "1", "metrics",
-       "byte-flow ledger master switch; 0 disables all link accounting "
-       "(the flows_overhead A/B arm)"),
+       "byte-flow ledger master switch; 0 disables all link accounting"),
     _k("DYN_LINK_WINDOW", "float", "10.0", "metrics",
        "trailing window for per-link rate/saturation, seconds"),
     _k("DYN_LINK_SAT_THRESHOLD", "float", "0.9", "metrics",

@@ -4,8 +4,7 @@ Three pieces, consumed by the engine's goodput telemetry
 (``dyn_mfu`` / ``dyn_mbu`` / ``dyn_hbm_gbps``):
 
 1. **Peaks** — per-platform peak dense bf16 FLOP/s and HBM bandwidth.
-   TPU generations come from a static table (same figures bench.py has
-   always used, plus memory bandwidth); off-chip (CPU) the peaks are
+   TPU generations come from a static table; off-chip (CPU) the peaks are
    *calibrated once* with a short matmul / memcpy measurement so MFU/MBU
    stay meaningful rather than reading 0.0001 against an imaginary chip.
    ``DYN_PEAK_FLOPS`` / ``DYN_PEAK_GBPS`` override everything (deployments
@@ -24,7 +23,7 @@ Three pieces, consumed by the engine's goodput telemetry
 
 3. :class:`GoodputMeter` — accumulates (flops, bytes, busy-time) per
    dispatch and answers with windowed MFU / MBU / achieved-GB/s rates plus
-   lifetime totals (what bench.py stamps into its artifacts).
+   lifetime totals.
 
 The model is an estimate, not a profiler: it exists so "are we 4% or 40%
 of the chip" is answerable from /metrics on every deployment, and so the
@@ -40,7 +39,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 # device_kind substring -> (peak dense bf16 FLOP/s, peak HBM bytes/s) per
-# chip — THE peak table (bench.py normalizes through here too); bandwidth
+# chip — THE peak table; bandwidth
 # from the public chip datasheets (v5e 819 GB/s, v5p 2765, v6e 1640,
 # v4 1228).
 PEAKS_BY_DEVICE_KIND: Tuple[Tuple[str, float, float], ...] = (

@@ -1,102 +1,8 @@
-"""Bench artifact durability: every (model, batch) point leaves its own
-platform-tagged JSON file the moment it lands, and the rolling partial is
-written atomically — a run cut mid-sweep can no longer erase the
-measurements it already made."""
+"""Committed bench records: the gate validates a recorded measurement, it
+never re-times."""
 
 import json
 import os
-
-
-def test_flush_point_writes_one_artifact_per_point(tmp_path, monkeypatch):
-    import bench
-
-    monkeypatch.setattr(bench, "POINTS_DIR", str(tmp_path / "points"))
-    meta = {"platform": "tpu", "device_kind": "TPU v5e", "tpu": "ok"}
-    bench._flush_point("llama-3.2-1b", {"batch": 8, "decode_tok_s": 123.4},
-                       meta)
-    bench._flush_point("llama-3.2-1b", {"batch": 32, "decode_tok_s": 99.0},
-                       meta)
-    files = sorted(os.listdir(tmp_path / "points"))
-    assert files == ["llama-3.2-1b_b32.json", "llama-3.2-1b_b8.json"]
-    d = json.load(open(tmp_path / "points" / "llama-3.2-1b_b8.json"))
-    assert d["platform"] == "tpu" and d["model"] == "llama-3.2-1b"
-    assert d["batch"] == 8 and d["decode_tok_s"] == 123.4
-    # a later flush of the same point overwrites atomically, not appends
-    bench._flush_point("llama-3.2-1b", {"batch": 8, "decode_tok_s": 200.0},
-                       meta)
-    d = json.load(open(tmp_path / "points" / "llama-3.2-1b_b8.json"))
-    assert d["decode_tok_s"] == 200.0
-
-
-def test_flush_point_never_raises(tmp_path, monkeypatch):
-    import bench
-
-    # an unwritable points dir loses the hedge, not the run
-    monkeypatch.setattr(bench, "POINTS_DIR",
-                        str(tmp_path / "nope" / "\0bad"))
-    bench._flush_point("m", {"batch": 1}, {"platform": "cpu"})
-
-
-def test_flush_partial_atomic(tmp_path, monkeypatch):
-    import bench
-
-    path = str(tmp_path / "BENCH_PARTIAL.json")
-    monkeypatch.setattr(bench, "PARTIAL_PATH", path)
-    bench._flush_partial({"partial": True, "platform": "tpu"})
-    d = json.load(open(path))
-    assert d["partial"] is True and d["platform"] == "tpu"
-    assert not os.path.exists(path + ".tmp")
-
-
-def test_flows_overhead_artifact_verdicts():
-    """The committed byte-flow-ledger overhead artifact proves the
-    ISSUE-20 bar: ledger-on vs ledger-off decode on the real EngineCore
-    costs < 1% tok/s, measured as interleaved same-process A/B lanes.
-    The gate validates the recorded measurement, it never re-times."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "bench_points",
-                           "flows_overhead.json")) as f:
-        art = json.load(f)
-    assert art["verdicts"]["overhead_lt_1pct"]
-    assert art["measured"]["overhead_pct"] < 1.0
-    m = art["measured"]
-    assert m["overhead_pct"] == round(
-        (m["median_off"] - m["median_on"]) / m["median_off"] * 100.0, 3)
-    assert len(m["tok_s_off"]) == len(m["tok_s_on"]) == \
-        art["config"]["reps"]
-    # the chokepoint microbench rode along: a per-record cost exists and
-    # the disabled early-return is far cheaper than the accounted path
-    micro = art["record_microbench"]
-    assert 0 < micro["disabled_us"] < micro["record_us"]
-
-
-def test_link_congestion_artifact_verdicts():
-    """The committed link-congestion artifact proves detection: a wire-
-    paced KV stream through the real receive path pegged
-    dyn_link_saturation under the measured-peak fallback and left a
-    rising-edge trail (counter + flight-recorder event + the
-    flows_from_states fold), while the unthrottled pair moving the same
-    bytes stayed quiet and both wires assembled byte-exact."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(repo, "bench_points",
-                           "link_congestion.json")) as f:
-        art = json.load(f)
-    for gate in ("slow_congested", "slow_saturated", "fast_clean",
-                 "edge_in_flightrec", "fold_shows_congestion",
-                 "wire_exact"):
-        assert art["checks"][gate], gate
-    assert art["arms"]["slow"]["saturation"] >= 0.9
-    assert art["arms"]["fast"]["saturation"] < 0.5
-    # the congested link the ring saw is the one the fold surfaces
-    (edge,) = art["flightrec_edges"][:1] or [{}]
-    slow = art["folded_slow_link"]
-    assert edge["link"] == f"{slow['src']}>{slow['dst']}"
-    assert slow["congested"] >= 1
-    # the throttled arm really was wire-bound: its last stream took at
-    # least the full pacing the lane injected
-    w = art["workload"]
-    assert art["arms"]["slow"]["last_stream_s"] >= \
-        2 * w["layers"] * w["part_delay_ms"] / 1e3
 
 
 def test_long_context_batch_artifact_verdicts():

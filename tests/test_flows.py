@@ -3,7 +3,7 @@ chokepoint for every byte the cluster moves.
 
 - record/snapshot/totals mechanics, per-class default link identity
   (host:/dev:/disk edges adopt the worker hex id), the DYN_FLOWS kill
-  switch (the flows_overhead A/B arm)
+  switch
 - windowed rate over a FIXED DYN_LINK_WINDOW denominator (a single
   burst cannot read as congestion) + measured-peak capacity fallback
 - calibrated-capacity saturation with rising-edge congestion: the

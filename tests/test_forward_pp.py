@@ -17,6 +17,19 @@ def _mesh(pp):
     return Mesh(np.array(jax.devices()[:pp]), (AXIS_PP,))
 
 
+# Every forward runs as one jitted program, a closure over cfg and mesh, as
+# the engine's programs do: dispatched eagerly, a shard_map over virtual
+# devices costs minutes a case.
+
+def _forward(cfg):
+    return jax.jit(lambda params, *a: llama.forward(params, cfg, *a))
+
+
+def _forward_pp(cfg, mesh, **kw):
+    return jax.jit(
+        lambda params, *a: llama.forward_pp(params, cfg, *a, mesh, **kw))
+
+
 @pytest.mark.parametrize("pp,M", [(2, 3), (2, 1), (1, 2)])
 def test_forward_pp_matches_sequential(pp, M):
     cfg = llama.LlamaConfig(
@@ -51,18 +64,17 @@ def test_forward_pp_matches_sequential(pp, M):
     # sequential reference, microbatch by microbatch
     k_ref, v_ref = pools()
     logits_ref = []
+    fwd = _forward(cfg)
     for m in range(M):
-        lg, k_ref, v_ref = llama.forward(
-            params, cfg, tokens[m], positions[m], k_ref, v_ref,
+        lg, k_ref, v_ref = fwd(
+            params, tokens[m], positions[m], k_ref, v_ref,
             widx[m], ridx[m], rpos[m], rvalid[m])
         logits_ref.append(lg)
     logits_ref = jnp.stack(logits_ref)
 
     k0, v0 = pools()
-    mesh = _mesh(pp)
-    logits_pp, k_pp, v_pp = llama.forward_pp(
-        params, cfg, tokens, positions, k0, v0, widx, ridx, rpos, rvalid,
-        mesh)
+    logits_pp, k_pp, v_pp = _forward_pp(cfg, _mesh(pp))(
+        params, tokens, positions, k0, v0, widx, ridx, rpos, rvalid)
 
     np.testing.assert_allclose(np.asarray(logits_pp),
                                np.asarray(logits_ref),
@@ -109,16 +121,17 @@ def test_forward_pp_gemma2_matches_sequential(pp):
                    cfg.head_dim), jnp.float32)
     k_ref, v_ref = z, jnp.zeros_like(z)
     logits_ref = []
+    fwd = _forward(cfg)
     for m in range(M):
-        lg, k_ref, v_ref = llama.forward(
-            params, cfg, tokens[m], positions[m], k_ref, v_ref,
+        lg, k_ref, v_ref = fwd(
+            params, tokens[m], positions[m], k_ref, v_ref,
             widx[m], ridx[m], rpos[m], rvalid[m])
         logits_ref.append(lg)
     logits_ref = jnp.stack(logits_ref)
 
-    logits_pp, _, _ = llama.forward_pp(
-        params, cfg, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
-        rpos, rvalid, _mesh(pp))
+    logits_pp, _, _ = _forward_pp(cfg, _mesh(pp))(
+        params, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
+        rpos, rvalid)
     np.testing.assert_allclose(np.asarray(logits_pp),
                                np.asarray(logits_ref),
                                atol=2e-4, rtol=2e-4)
@@ -153,12 +166,12 @@ def test_forward_pp_flash_in_stage_matches_xla(pp):
     z = jnp.zeros((cfg.num_layers, cfg.num_kv_heads, n_pages, page,
                    cfg.head_dim), jnp.float32)
     mesh = _mesh(pp)
-    ref, k_x, v_x = llama.forward_pp(
-        params, cfg, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
-        rpos, rvalid, mesh, attn_impl="xla")
-    got, k_f, v_f = llama.forward_pp(
-        params, cfg, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
-        rpos, rvalid, mesh, attn_impl="flash")
+    ref, k_x, v_x = _forward_pp(cfg, mesh, attn_impl="xla")(
+        params, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
+        rpos, rvalid)
+    got, k_f, v_f = _forward_pp(cfg, mesh, attn_impl="flash")(
+        params, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
+        rpos, rvalid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-3, rtol=2e-3)
     np.testing.assert_allclose(np.asarray(k_f), np.asarray(k_x), atol=1e-5)
@@ -202,12 +215,12 @@ def test_forward_pp_gemma2_flash_in_stage(pp):
     z = jnp.zeros((cfg.num_layers, cfg.num_kv_heads, n_pages, page,
                    cfg.head_dim), jnp.float32)
     mesh = _mesh(pp)
-    ref, _, _ = llama.forward_pp(
-        params, cfg, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
-        rpos, rvalid, mesh, attn_impl="xla")
-    got, _, _ = llama.forward_pp(
-        params, cfg, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
-        rpos, rvalid, mesh, attn_impl="flash")
+    ref, _, _ = _forward_pp(cfg, mesh, attn_impl="xla")(
+        params, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
+        rpos, rvalid)
+    got, _, _ = _forward_pp(cfg, mesh, attn_impl="flash")(
+        params, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
+        rpos, rvalid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-3, rtol=2e-3)
 
@@ -247,16 +260,17 @@ def test_forward_pp_gemma3_matches_sequential(pp):
                    cfg.head_dim), jnp.float32)
     k_ref, v_ref = z, jnp.zeros_like(z)
     logits_ref = []
+    fwd = _forward(cfg)
     for m in range(M):
-        lg, k_ref, v_ref = llama.forward(
-            params, cfg, tokens[m], positions[m], k_ref, v_ref,
+        lg, k_ref, v_ref = fwd(
+            params, tokens[m], positions[m], k_ref, v_ref,
             widx[m], ridx[m], rpos[m], rvalid[m])
         logits_ref.append(lg)
     logits_ref = jnp.stack(logits_ref)
 
-    logits_pp, k_pp, _ = llama.forward_pp(
-        params, cfg, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
-        rpos, rvalid, _mesh(pp))
+    logits_pp, k_pp, _ = _forward_pp(cfg, _mesh(pp))(
+        params, tokens, positions, z, jnp.zeros_like(z), widx, ridx,
+        rpos, rvalid)
     np.testing.assert_allclose(np.asarray(logits_pp),
                                np.asarray(logits_ref),
                                atol=2e-4, rtol=2e-4)

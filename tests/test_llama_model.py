@@ -126,3 +126,132 @@ def test_llama3_rope_scaling_applies():
     f_base = llama._rope_inv_freq(base)
     f_scaled = llama._rope_inv_freq(scaled)
     assert not np.allclose(f_base, f_scaled)
+
+
+# ---------------------------------------------------------------------------
+# the four forwards share one decoder layer (llama.layer_in / layer_out):
+# each must agree with the sequential ``forward`` for the model families
+# whose layer terms differ. Every forward here runs under jax.jit (a closure
+# over cfg and mesh, as the engine's programs are): eagerly the pp case
+# alone costs 90 s. One set of seeded float32 parameters per preset.
+# ---------------------------------------------------------------------------
+PAGE = 8
+
+
+@pytest.fixture(scope="module")
+def preset_params():
+    made = {}
+
+    def get(name):
+        if name not in made:
+            cfg = llama.preset(name, dtype=jnp.float32)
+            made[name] = cfg, llama.init_params(cfg, jax.random.PRNGKey(7))
+        return made[name]
+    return get
+
+
+def _pools(cfg, n_pages):
+    z = jnp.zeros((cfg.num_layers, cfg.num_kv_heads, n_pages, PAGE,
+                   cfg.head_dim), cfg.dtype)
+    return z, jnp.zeros_like(z)
+
+
+def _lanes(page_tables):
+    """-> (token slot, position) of every slot of each lane's pages, both
+    [B, S]: what ``forward`` addresses by, from what ``forward_decode``
+    addresses by."""
+    pt = jnp.asarray(page_tables, jnp.int32)
+    t = jnp.arange(pt.shape[1] * PAGE, dtype=jnp.int32)
+    return (pt[:, t // PAGE] * PAGE + t % PAGE,
+            jnp.broadcast_to(t, (pt.shape[0], t.shape[0])))
+
+
+def _decode_agrees(cfg, params):
+    """Prefill 12 tokens of two lanes through ``forward``, then 8 steps of
+    ``forward_decode`` (page tables) against ``forward`` (token slots) on
+    the same pools: logits and both pools after every step."""
+    B, T0 = 2, 12
+    fwd = jax.jit(lambda p, *a: llama.forward(p, cfg, *a))
+    dec = jax.jit(lambda p, *a: llama.forward_decode(p, cfg, *a))
+    tokens = np.random.RandomState(0).randint(1, 250, (B, T0 + 8))
+    pt = jnp.asarray([[2, 5, 1], [4, 3, 6]], jnp.int32)     # pages, per lane
+    slots, rpos = _lanes(pt)
+    k, v = _pools(cfg, 7)
+    _, k, v = fwd(params, jnp.asarray(tokens[:, :T0], jnp.int32),
+                  rpos[:, :T0], k, v, slots[:, :T0], slots, rpos, rpos < T0)
+    for n in range(T0, T0 + 8):
+        tok = jnp.asarray(tokens[:, n], jnp.int32)
+        lengths = jnp.full((B,), n + 1, jnp.int32)
+        lg_d, k_d, v_d = dec(params, tok, k, v, pt, lengths)
+        lg_f, k, v = fwd(params, tok[:, None], rpos[:, n:n + 1], k, v,
+                         slots[:, n:n + 1], slots, rpos, rpos <= n)
+        np.testing.assert_allclose(np.asarray(lg_d), np.asarray(lg_f),
+                                   atol=2e-4, rtol=2e-4)
+        np.testing.assert_allclose(np.asarray(k_d), np.asarray(k), atol=1e-5)
+        np.testing.assert_allclose(np.asarray(v_d), np.asarray(v), atol=1e-5)
+
+
+def _pp_agrees(cfg, params):
+    """``forward_pp`` at pp = 2, one microbatch, xla attention, against
+    ``forward``: logits and the K pool the stages wrote."""
+    from jax.sharding import Mesh
+
+    from dynamo_tpu.parallel.mesh import AXIS_PP
+    mesh = Mesh(np.array(jax.devices()[:2]), (AXIS_PP,))
+    B, T = 2, 8
+    tokens = jnp.asarray(np.random.RandomState(1).randint(1, 250, (B, T)),
+                         jnp.int32)
+    slots, rpos = _lanes([[1, 2], [3, 4]])
+    args = (tokens, rpos[:, :T], *_pools(cfg, 5), slots[:, :T], slots, rpos,
+            rpos < T)
+    lg, k, _ = jax.jit(lambda p, *a: llama.forward(p, cfg, *a))(params, *args)
+    lg_pp, k_pp, _ = jax.jit(
+        lambda p, *a: llama.forward_pp(p, cfg, *a, mesh))(
+        params, *(a[None] if a.ndim < 5 else a for a in args))
+    np.testing.assert_allclose(np.asarray(lg_pp[0]), np.asarray(lg),
+                               atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(k_pp), np.asarray(k), atol=1e-5)
+
+
+def _pager_agrees(cfg, params):
+    """The pager's segmented forward through the engine, token identity
+    against the unpaged engine on the same seeded weights: a prompt of 8x
+    the device budget (tests/test_kvpage.py pins 16x on tiny-byte)."""
+    from dynamo_tpu.engine.engine import EngineCore, JaxEngineConfig
+    from dynamo_tpu.llm.protocols.common import BackendInput, StopConditions
+
+    prompt = [(i * 7 + 3) % 251 for i in range(8 * 4 * 16 + 5)]
+    common = dict(model=cfg, max_batch=1, page_size=16, prefill_chunk=32,
+                  decode_steps=4)
+
+    def serve(**kw):
+        core = EngineCore(JaxEngineConfig(**common, **kw))
+        try:
+            core.submit("s", BackendInput(
+                token_ids=prompt, stop=StopConditions(max_tokens=6)))
+            got = []
+            while not (got and got[-1].finish is not None):
+                got += core.step()
+            assert all(so.error is None for so in got)
+            return [so.token for so in got], core
+        finally:
+            core.close()
+
+    ref, _ = serve(max_context=1024, kvpage_budget=0)
+    toks, core = serve(max_context=128, host_cache_blocks=96,
+                       kvpage_budget=4, kvpage_seg_pages=4,
+                       kvpage_prefetch=2, kvpage_max_context=1024)
+    assert toks == ref and len(toks) == 6
+    assert core.kvpager.pager.pageins > 0
+
+
+@pytest.mark.parametrize("preset,agrees", [
+    ("tiny-qwen", _decode_agrees),      # q/k/v bias
+    ("tiny-gemma2", _decode_agrees),    # sandwich norms, softcap, sliding
+    ("tiny-gemma3", _decode_agrees),    # q/k norm, two rotary bases
+    ("tiny-moe", _decode_agrees),       # routed feed-forward
+    ("tiny-qwen", _pp_agrees),          # bias through a pipeline stage
+    ("tiny-qwen", _pager_agrees),       # bias through the pager's programs
+], ids=lambda p: p if isinstance(p, str) else p.__name__.strip("_"))
+def test_forwards_agree(preset_params, preset, agrees):
+    agrees(*preset_params(preset))
