@@ -700,6 +700,14 @@ class EngineCore:
         # upload on one of these as a prefetch stall (vs a plain miss)
         self._h2d_requested: set = set()
         self._last_final_tok = None   # device [B] from the last decode
+        self._last_prefill_tok = None  # device [Bp] from the last chunk
+        # a completed prompt's first token joins the chained decode on the
+        # device: lane l of the chunk becomes slot lane_slot[l]'s token
+        # (an index past the batch is dropped)
+        self._join_fn = jax.jit(
+            lambda final_tok, chunk_tok, lane_slot:
+            final_tok.at[lane_slot].set(chunk_tok, mode="drop"),
+            out_shardings=self._rep_sharding)
         # multi-host lockstep: called with (kind, meta, arrays) right before
         # every device dispatch so follower processes can replay it
         self.dispatch_hook: Optional[Any] = None
@@ -868,7 +876,7 @@ class EngineCore:
                     fn = self._prefill_fn(Bp, C, S)
                     zt = np.zeros((Bp, C), np.int32)
                     keys = s.key[jnp.asarray(np.zeros(Bp, np.int32))]
-                    _, _, _, *pools = fn(
+                    _, tok, _, *pools = fn(
                         self.params, zt, zt, self.k_pool, self.v_pool,
                         zt, np.zeros((Bp, S), np.int32),
                         np.zeros((Bp, S), np.int32),
@@ -878,6 +886,8 @@ class EngineCore:
                         keys, **self._idx())
                     self._take_pools(pools)
                     n += 1
+            # a chunk of this many lanes handing first tokens to a decode
+            self._join_fn(zb, tok, np.full(Bp, B, np.int32))
         if self.proposer is not None:
             n += self.proposer.warmup()   # draft model's own bucket set
         # dynalint: ok(host-sync) warmup barrier: block ONCE at startup so
@@ -1509,14 +1519,19 @@ class EngineCore:
         A prefill chunk needs nothing from the window: its tokens,
         positions and slots come from the prompt and the page pool. A
         decode dispatch behind a chunk that completes no prompt chains off
-        the newest decode record's on-device tokens (same lanes). A chunk
-        that completes a prompt is fetched within its own iteration, behind
-        everything older, and the decode dispatch that takes the new lane in
-        is built from host tokens right after: the first token never waits
-        behind a deliver. The same goes for any other change of lanes (a
-        finish, an injected sequence): nothing chains, the earlier records
-        are fetched, and the host-token dispatch follows at once. Only at
-        such a change does the window hold no decode dispatch.
+        the newest decode record's on-device tokens (same lanes). Behind a
+        chunk that completes a prompt it chains too: the chunk's sampled
+        token is on the device, and is written over the new lane's entry of
+        those tokens there (a lane that takes the place of one that
+        finished included), so the device goes from the chunk into the
+        dispatch that takes the new lane in without waiting for the host.
+        That chunk is still fetched within its own iteration, behind
+        everything older: the first token never waits behind a deliver.
+        At any other change of lanes (a finish with nothing to take its
+        place, an injected sequence, a first prompt with no decode dispatch
+        to chain off) nothing chains: the earlier records are fetched, and
+        a dispatch built from host tokens follows at once. Only at such a
+        change does the window hold no decode dispatch.
 
         Still sync points (the window drains before anything is enqueued):
         a reaped cancel, an admission that waits for pages a deferred
@@ -1563,16 +1578,19 @@ class EngineCore:
             # if no prefill progress was possible (e.g. pool full), fall
             # through to decode so the engine never stalls
         # non-blocking enqueue, behind the chunk: decode keeps advancing
-        # between the chunks of a long prompt
-        behind = not completes and self._dispatch_decode(out)
-        # with a chunk that completes a prompt, that chunk too: its first
-        # token reaches the new lane's decode dispatch through the host
-        self._process_inflight(out, None if completes else held)
+        # between the chunks of a long prompt, and a completed prompt's
+        # first token joins it on the device
+        behind = self._dispatch_decode(out, completing=bool(completes))
+        # a chunk that completes a prompt is fetched within its own
+        # iteration, behind everything older: the first token goes out now
+        self._process_inflight(
+            out, len(self._inflight) - behind if completes else held)
         if not behind:
-            # the lanes changed (a prompt completed, a sequence finished or
-            # was injected): no decode dispatch is unfetched any more, so
-            # every lane's token is on the host, and the dispatch goes out
-            # before this iteration's outputs are delivered
+            # the lanes changed with nothing on the device to chain off (a
+            # sequence finished or was injected, a first prompt completed):
+            # no decode dispatch is unfetched any more, so every lane's
+            # token is on the host, and the dispatch goes out before this
+            # iteration's outputs are delivered
             self._dispatch_decode(out)
         if not self.by_seq:
             # every live sequence finished: drain the stale window so its
@@ -2050,18 +2068,19 @@ class EngineCore:
         fn = self._prefill_fn(Bp, C, S, mm=mm_arrays is not None)
         self.phase.to("prefill", f"dynamo.prefill[B{Bp},C{C},S{S}]")
         if mm_arrays is not None:
-            packed, _tok, new_keys, self.k_pool, self.v_pool = fn(
+            packed, tok, new_keys, self.k_pool, self.v_pool = fn(
                 self.params, tokens, positions, self.k_pool, self.v_pool,
                 write_idx, read_idx, read_pos, read_valid, last_i,
                 temp, top_p, top_k, keys, mm_arrays["ov_vals"],
                 mm_arrays["ov_mask"], mm_arrays["q_span"],
                 mm_arrays["read_span"])
         else:
-            packed, _tok, new_keys, *pools = fn(
+            packed, tok, new_keys, *pools = fn(
                 self.params, tokens, positions, self.k_pool, self.v_pool,
                 write_idx, read_idx, read_pos, read_valid, last_i,
                 temp, top_p, top_k, keys, **self._idx())
             self._take_pools(pools)
+        self._last_prefill_tok = tok
         self.phase.to("prefill_build")
         # persist advanced PRNG keys only for lanes that really sampled
         if last_lanes:
@@ -2294,7 +2313,7 @@ class EngineCore:
         for i, slot in enumerate(self.slots):
             if slot is None or slot.prefill_done < len(slot.prompt):
                 continue
-            phys = slot.sched_len or (len(slot.prompt) + slot.generated)
+            phys = self._phys_len(slot)
             try:
                 # reserve room for N speculative tokens up front
                 self.pool.ensure_pages(slot.seq_id, phys + N)
@@ -2306,28 +2325,38 @@ class EngineCore:
             active.append((i, slot, phys))
         return active, deferred
 
-    def _can_chain(self, rec: Dict[str, Any]) -> bool:
+    @staticmethod
+    def _phys_len(slot: _Slot) -> int:
+        """Tokens in the sequence once its next decode dispatch starts. A
+        decode-ready slot holds its first token, fetched or still on the
+        device (a chunk that completed its prompt in this iteration)."""
+        return slot.sched_len or len(slot.prompt) + max(slot.generated, 1)
+
+    def _can_chain(self, rec: Dict[str, Any], joining: Dict[int, int]) -> bool:
         """True if the next decode dispatch can be enqueued straight off
         ``rec``'s on-device outputs, ``rec`` being the newest decode record
-        in flight (chunks that completed no prompt may have been enqueued
-        since): same membership, and pages available for every lane."""
+        in flight (chunks may have been enqueued since): same membership but
+        for the slots in ``joining`` (slot index -> lane of the newest
+        chunk, which completes their prompts), and pages available for
+        every lane."""
         # the chained dispatch feeds the previous dispatch's on-device
         # final_tok to EVERY lane, so the decode-ready set must be EXACTLY
-        # the lanes that were active in that dispatch: a newly injected or
-        # newly eligible slot (inject_prefilled, deferred slot unblocking)
-        # has a real last_token the device array does not contain
+        # the lanes that were active in that dispatch, plus those whose
+        # first token the newest chunk holds on the device: a newly
+        # injected or newly eligible slot (inject_prefilled, deferred slot
+        # unblocking) has a real last_token no device array contains
         ready_now = {i for i, s in enumerate(self.slots)
                      if s is not None and s.prefill_done >= len(s.prompt)}
-        rec_lanes = {i for i, _, _ in rec["active"]}
-        if ready_now != rec_lanes:
+        if ready_now != {i for i, _, _ in rec["active"]} | set(joining):
             return False
         for i, slot, _ in rec["active"]:
-            if self.slots[i] is not slot:
+            if self.slots[i] is not slot and i not in joining:
                 return False   # membership changed (cancel) -> sync
         N = self.cfg.decode_steps
-        for i, slot, _ in rec["active"]:
+        for i in sorted(ready_now):
+            slot = self.slots[i]
             try:
-                self.pool.ensure_pages(slot.seq_id, slot.sched_len + N)
+                self.pool.ensure_pages(slot.seq_id, self._phys_len(slot) + N)
             except OutOfPages:
                 return False
         return True
@@ -2346,22 +2375,32 @@ class EngineCore:
                   "continue decoding)"))
         self._free_slot(i)
 
-    def _dispatch_decode(self, out: List[StepOutput]) -> bool:
+    def _dispatch_decode(self, out: List[StepOutput],
+                         completing: bool = False) -> bool:
         """Enqueue one multi-step decode dispatch WITHOUT fetching results,
         behind whatever is in flight. With a decode dispatch among it,
         chain off the newest one's on-device token and key arrays (no host
         data dependency), or enqueue nothing if its lanes are not the
         decode-ready ones; with none, every lane's last token is on the
-        host. (The caller fetches a chunk that completes a prompt before
-        it comes here: that lane's token is on neither side until then.)
-        Returns whether a dispatch was enqueued."""
+        host. ``completing``: the newest record is a chunk that completes
+        prompts; their first tokens are on the device too, and join the
+        chained tokens there (with no decode dispatch to chain off, the
+        caller fetches the chunk and comes back). Returns whether a
+        dispatch was enqueued."""
         self.phase.to("decode_build")
         B = self.cfg.max_batch
         N = self.cfg.decode_steps
         newest = next((r for r in reversed(self._inflight)
                        if r["kind"] == "decode"), None)
         chain = newest is not None
-        if chain and not self._can_chain(newest):
+        joining: Dict[int, int] = {}     # slot index -> lane of the chunk
+        if completing:
+            if not chain:
+                return False
+            chunk = self._inflight[-1]
+            joining = {chunk["work"][lane][0]: lane
+                       for lane in chunk["last_lanes"]}
+        if chain and not self._can_chain(newest, joining):
             return False
         active, deferred = self._decode_eligible()
         if not active:
@@ -2390,13 +2429,12 @@ class EngineCore:
 
         # lanes whose SEQUENCE changed since their last decode dispatch
         # restart their penalty counts in-program (a chained dispatch has
-        # identical membership by _can_chain, so fresh is all-False there)
+        # the membership of the one before it but for the lanes that join)
         fresh = np.zeros(B, bool)
-        if not chain:
-            for i, slot, _ in active:
-                if self._decode_seen.get(i) != slot.seq_id:
-                    fresh[i] = True
-                    self._decode_seen[i] = slot.seq_id
+        for i, slot, _ in active:
+            if self._decode_seen.get(i) != slot.seq_id:
+                fresh[i] = True
+                self._decode_seen[i] = slot.seq_id
         active_mask = np.zeros(B, bool)
         for i, _, _ in active:
             active_mask[i] = True
@@ -2410,9 +2448,11 @@ class EngineCore:
                        "freq_pen": s.freq_pen, "pres_pen": s.pres_pen}
             if tokens is not None:
                 payload["tokens"] = tokens
-            self.dispatch_hook("decode", {"S": S, "chain": chain}, payload)
+            self.dispatch_hook("decode", {"S": S, "chain": chain,
+                                          "joining": sorted(joining.items())},
+                               payload)
         packed, final_tok = self._run_decode_program(
-            S, tokens, page_tables, lengths, fresh, active_mask)
+            S, tokens, page_tables, lengths, fresh, active_mask, joining)
         self.stage.engine_dispatch_tokens.inc(
             "decode", amount=float(len(active) * N))
         self._inflight.append({"kind": "decode",
@@ -2431,12 +2471,23 @@ class EngineCore:
         return True
 
     def _run_decode_program(self, S: int, tokens, page_tables, lengths,
-                            fresh, active_mask):
+                            fresh, active_mask, joining=None):
         """Execute the multi-step decode program. ``tokens=None`` chains off
-        the previous dispatch's on-device final tokens. The SAME code path
-        runs on the leader and on follower mirrors (multi-host lockstep)."""
+        the previous dispatch's on-device final tokens, with the newest
+        chunk's sampled tokens written over the slots in ``joining`` (slot
+        index -> lane of that chunk). The SAME code path runs on the leader
+        and on follower mirrors (multi-host lockstep)."""
         if tokens is None:
             tokens = self._last_final_tok
+            if joining:
+                lane_slot = np.full(self._last_prefill_tok.shape[0],
+                                    self.cfg.max_batch, np.int32)
+                for i, lane in joining.items():
+                    lane_slot[lane] = i
+                # dynalint: ok(recompile-hazard) one size per lane bucket
+                # Bp of the chunk programs, each compiled in warm-up
+                tokens = self._join_fn(tokens, self._last_prefill_tok,
+                                       lane_slot)
         else:
             # same placement as the chained case, so both are ONE compiled
             # program per bucket (jit keys on argument placement; a host
@@ -2655,7 +2706,8 @@ class EngineCore:
             s.pres_pen = arrs["pres_pen"]
             self._run_decode_program(
                 meta["S"], arrs.get("tokens"), arrs["page_tables"],
-                arrs["lengths"], arrs["fresh"], arrs["active_mask"])
+                arrs["lengths"], arrs["fresh"], arrs["active_mask"],
+                {int(i): int(lane) for i, lane in meta.get("joining", ())})
         elif kind == "verify":
             s = self.sampling
             s.temperature = arrs["temp"]
@@ -2738,6 +2790,11 @@ class EngineCore:
                     self._free_slot(i)
                     break
         return outs
+
+
+def _put_bursts(puts) -> None:
+    for q, burst in puts:
+        q.put_nowait(burst)
 
 
 def _set_result(fut, res) -> None:
@@ -2949,7 +3006,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                         log.exception("KV injection failed")
                         so = StepOutput(seq_id, 0, 0.0, FinishReason.ERROR,
                                         error=f"KV injection failed: {e}")
-                    self._deliver(so)
+                    self._hand_off([so])
                 elif kind == "ingest_begin":
                     try:
                         self.core.begin_stream_inject(seq_id, payload)
@@ -2968,8 +3025,8 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                 elif kind == "ingest_finish":
                     if seq_id in self.core._stream_injects:
                         try:
-                            self._deliver(self.core.finish_stream_inject(
-                                seq_id, *payload))
+                            self._hand_off([self.core.finish_stream_inject(
+                                seq_id, *payload)])
                         except Exception as e:  # noqa: BLE001
                             log.exception("stream-inject finish failed")
                             self._ingest_fail(seq_id, e)
@@ -3035,11 +3092,10 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                 self._set_goodput_gauges(stage)
             capture.after_step()
             phase.to("deliver")
-            for so in outs:
-                try:
-                    self._deliver(so)
-                except Exception:  # closed loop etc. must not kill the thread
-                    log.exception("failed to deliver step output")
+            try:
+                self._hand_off(outs)
+            except Exception:  # closed loop etc. must not kill the thread
+                log.exception("failed to deliver step outputs")
             if not outs and not self.core.by_seq:
                 # waiting requests that can't be admitted yet: don't busy-spin
                 phase.to("idle")
@@ -3065,18 +3121,44 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
         pages (never sealed, never seen) and deliver ONE typed error the
         consumer turns into a local-prefill fallback."""
         self.core.abort_stream_inject(seq_id)
-        self._deliver(StepOutput(
+        self._hand_off([StepOutput(
             seq_id, 0, 0.0, FinishReason.ERROR,
             error=f"KV stream inject failed: {e}",
-            error_stage="kv_ingest", error_reason="ingest_failed"))
+            error_stage="kv_ingest", error_reason="ingest_failed")])
 
-    def _deliver(self, so: StepOutput) -> None:
+    def _hand_off(self, outs: List[StepOutput]) -> None:
+        """Everything an iteration produced crosses to the event loop in
+        ONE ``call_soon_threadsafe``, and a queue item is a BURST: the
+        consecutive outputs one dispatch gave one sequence (up to
+        ``decode_steps`` of a decode record, K + 1 of a speculative round).
+        A first token, an ERROR and whatever ends a sequence close their
+        burst. The loop thread shares this process's GIL, so what it works
+        off per item the engine thread waits for: per lane, not per token."""
         loop = self._loop
         if loop is None:
             return
-        q = self._queues.get(so.seq_id)
-        if q is not None:
-            loop.call_soon_threadsafe(q.put_nowait, so)
+        puts: List[Tuple[asyncio.Queue, List[StepOutput]]] = []
+        open_bursts: Dict[str, List[StepOutput]] = {}
+        tokens = 0
+        for so in outs:
+            q = self._queues.get(so.seq_id)
+            if q is None:
+                continue
+            error = so.finish == FinishReason.ERROR
+            burst = None if error else open_bursts.get(so.seq_id)
+            if burst is None:
+                burst = open_bursts[so.seq_id] = []
+                puts.append((q, burst))
+            burst.append(so)
+            tokens += not error
+            if so.finish is not None or so.first_token_at is not None:
+                del open_bursts[so.seq_id]
+        if not puts:
+            return
+        stage = self.core.stage
+        stage.engine_handoffs.inc()
+        stage.engine_handoff_tokens.inc(amount=tokens)
+        loop.call_soon_threadsafe(_put_bursts, puts)
 
     # ------------------------------------------------------------------
     async def generate(self, request: BackendInput,
@@ -3175,7 +3257,10 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
         cancel_task = asyncio.ensure_future(watch_cancel())
         try:
             while True:
-                so: StepOutput = await q.get()
+                # one burst: what ONE dispatch gave this sequence (see
+                # _hand_off); it travels the frontend as one output
+                burst: List[StepOutput] = await q.get()
+                so, last = burst[0], burst[-1]
                 if so.finish == FinishReason.ERROR:
                     if ingest_fallback and so.error_stage == "kv_ingest":
                         # torn/failed stream inject: the pages are
@@ -3195,15 +3280,16 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
                 if so.first_token_at is not None:
                     context.stamps["first_token"] = so.first_token_at
                 yield EngineOutput(
-                    token_ids=[so.token],
-                    cum_log_prob=so.logprob,
-                    logprobs=[{str(so.token): so.token_logprob}],
-                    finish_reason=so.finish,
+                    token_ids=[o.token for o in burst],
+                    cum_log_prob=last.logprob,
+                    logprobs=[{str(o.token): o.token_logprob}
+                              for o in burst],
+                    finish_reason=last.finish,
                     # first output only: admission's sealed-prefix restore
                     # length (a resumed stream's re-attach proof)
                     kv_prefix_hit_tokens=so.prefix_hit,
                 )
-                if so.finish is not None:
+                if last.finish is not None:
                     return
         finally:
             cancel_task.cancel()

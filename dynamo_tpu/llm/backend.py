@@ -11,11 +11,23 @@ step loop, stop jail).
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 from typing import AsyncIterator
 
 from ..runtime.engine import AsyncEngine, Context, EngineError
 from .protocols.common import BackendInput, EngineOutput, FinishReason
 from .tokenizer import DecodeStream, StopSequenceDecoder, Tokenizer
+
+
+def _cut(out: EngineOutput, n: int) -> EngineOutput:
+    """``out`` with its first ``n`` tokens only."""
+    cum, lps = out.cum_log_prob, out.logprobs
+    if lps is not None:
+        if cum is not None:
+            cum -= sum(next(iter(m.values())) for m in lps[n:] if m)
+        lps = lps[:n]
+    return dataclasses.replace(out, token_ids=out.token_ids[:n],
+                               logprobs=lps, cum_log_prob=cum)
 
 
 class Backend(AsyncEngine[BackendInput, EngineOutput]):
@@ -61,7 +73,8 @@ class Backend(AsyncEngine[BackendInput, EngineOutput]):
                                       reason=out.error_reason)
                 text_parts = []
                 finish = out.finish_reason
-                for tid in out.token_ids:
+                consumed = 0
+                for consumed, tid in enumerate(out.token_ids, 1):
                     emitted += 1
                     piece = decode.step(tid)
                     if not piece:
@@ -88,6 +101,11 @@ class Backend(AsyncEngine[BackendInput, EngineOutput]):
                         jail = stops.flush()
                         if jail:
                             text_parts.append(jail)
+                if consumed < len(out.token_ids):
+                    # a client stop inside a multi-token output: what
+                    # follows the stop was never given to the client, so
+                    # ids, logprobs and usage end where the text does
+                    out = _cut(out, consumed)
                 text = "".join(text_parts)
                 # always yield (even with empty text) so downstream usage
                 # accounting sees every generated token id

@@ -171,7 +171,15 @@ class BackendInput:
 @dataclass
 class EngineOutput:
     """One streamed step from a core engine: newly generated token ids (and
-    optionally text, if the engine detokenizes itself)."""
+    optionally text, if the engine detokenizes itself).
+
+    An output may hold SEVERAL tokens: the JAX engine streams every token
+    one dispatch gave the sequence as one output (1 for a first token, up to
+    ``decode_steps`` for a decode dispatch, K + 1 for a speculative round),
+    and so may a multi-token user engine. ``logprobs`` then has one entry a
+    token, ``cum_log_prob`` and ``finish_reason`` are the last token's, and
+    an SSE event built from the output carries all of them: count tokens
+    from ``token_ids`` / ``usage``, never by counting outputs."""
 
     token_ids: List[int] = field(default_factory=list)
     text: Optional[str] = None

@@ -518,6 +518,15 @@ class StageMetrics:
             "dyn_engine_dispatch_tokens_total",
             "Token positions computed by those dispatches (prompt tokens "
             "of the chunks; active lanes x steps)", ("kind",))
+        # what leaves the engine thread: one cross-thread call carries
+        # every token an iteration fetched (JaxEngine._hand_off)
+        self.engine_handoffs = r.counter(
+            "dyn_engine_handoffs_total",
+            "Cross-thread calls that carried step outputs from the engine "
+            "thread to the event loop", ())
+        self.engine_handoff_tokens = r.counter(
+            "dyn_engine_handoff_tokens_total",
+            "Tokens those calls carried", ())
         # routed experts and learned top-k attention: what the dispatches
         # made the device do, counted on the host from what a dispatch
         # already knows (the experts hit come back with its sampled tokens)

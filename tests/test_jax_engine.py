@@ -1,6 +1,7 @@
 """Engine-level tests: continuous batching, determinism, cancellation, TP."""
 
 import asyncio
+import time
 
 import numpy as np
 import pytest
@@ -8,6 +9,7 @@ import pytest
 from dynamo_tpu.engine.engine import EngineCore, JaxEngine, JaxEngineConfig
 from dynamo_tpu.llm.protocols.common import (
     BackendInput,
+    EngineOutput,
     FinishReason,
     SamplingOptions,
     StopConditions,
@@ -756,6 +758,7 @@ def _recording_hook(core, log):
     def hook(kind, meta, arrays):
         log.append({"kind": kind, "chain": meta.get("chain"),
                     "last_lanes": meta.get("last_lanes"),
+                    "joining": meta.get("joining"),
                     "host_tokens": "tokens" in arrays,
                     "inflight": [r["kind"] for r in core._inflight]})
     return hook
@@ -810,10 +813,10 @@ def test_streams_with_prefill_behind_the_window_equal_streams_alone(
 
 def test_prefill_is_enqueued_behind_the_decode_in_flight(core, monkeypatch):
     """The order of enqueues: a chunk goes behind the decode dispatch in
-    flight, one chunk per decode dispatch; the decode behind a chunk that
-    completes no prompt chains on the device; after a change of lanes (a
-    sequence finished, a prompt completed) it takes host tokens, with no
-    decode dispatch unfetched."""
+    flight, one chunk per decode dispatch; the decode behind a chunk chains
+    on the device, and a completed prompt's first token joins it there;
+    after a sequence finished with nothing to take its lane it takes host
+    tokens, with no decode dispatch unfetched."""
     monkeypatch.setattr(core, "b_buckets", [1])
     monkeypatch.setattr(core.cfg, "enable_prefix_reuse", False)
     log = []
@@ -842,15 +845,63 @@ def test_prefill_is_enqueued_behind_the_decode_in_flight(core, monkeypatch):
         assert after["kind"] == "decode"    # one chunk per decode dispatch
         if after["chain"]:
             chained += 1
-            assert not log[i]["last_lanes"] and not after["host_tokens"]
+            assert not after["host_tokens"]
             assert after["inflight"][-1] == "prefill"
+            # pb-long's last chunk holds its first token: slot 2 takes it
+            # from the chunk's lane 0 on the device
+            assert after["joining"] == (
+                [(2, 0)] if log[i]["last_lanes"] else [])
         else:
-            # pb-long's last chunk, fetched first; or pb-short has finished
-            # and only the chunk is still unfetched
-            assert after["host_tokens"]
-            assert after["inflight"] == (
-                [] if log[i]["last_lanes"] else ["prefill"])
-    assert chained == 2
+            # pb-short has finished and only the chunk is still unfetched
+            assert after["host_tokens"] and not log[i]["last_lanes"]
+            assert after["inflight"] == ["prefill"]
+    assert chained == 3
+
+
+def test_a_first_token_joins_the_chained_decode_on_the_device(core,
+                                                              monkeypatch):
+    """Every lane taken and more requests waiting (a closed loop): a lane
+    that finishes is refilled, and the chunk that completes the new prompt
+    hands its first token to the chained dispatch on the device, in the
+    finished lane's place. While requests wait no dispatch after the first
+    takes host tokens, and every stream, penalised ones included (the
+    joining lane's counts restart), is the one the request gives alone."""
+    monkeypatch.setattr(core.cfg, "enable_prefix_reuse", False)
+    log = []
+    monkeypatch.setattr(core, "dispatch_hook", _recording_hook(core, log))
+    pen = SamplingOptions(frequency_penalty=0.7, presence_penalty=0.4)
+    reqs = {f"j{k}": req([30 + k, 31, 32 + k], max_tokens=10 + 7 * (k % 4),
+                         **({"sampling": pen} if k % 3 == 0 else {}))
+            for k in range(10)}
+    # a request that ends with its first token, already joined when it does
+    reqs["j5"].stop.max_tokens = 1
+    for seq_id, request in reqs.items():
+        core.submit(seq_id, request)
+    got = drain(core, list(reqs))
+    while core.has_work:
+        core.step()
+    monkeypatch.setattr(core, "dispatch_hook", None)
+    decodes = [e for e in log if e["kind"] == "decode"]
+    joined = [n for n, e in enumerate(decodes) if e["joining"]]
+    # four lanes: the first four prompts start cold, the other six each take
+    # a finished lane's place, every lane at least once
+    assert sum(len(decodes[n]["joining"]) for n in joined) == len(reqs) - 4
+    assert {i for n in joined for i, _ in decodes[n]["joining"]} == \
+        {0, 1, 2, 3}
+    # while requests wait, only the first dispatch takes host tokens
+    assert [e["host_tokens"] for e in decodes[:joined[-1] + 1]] == \
+        [True] + [False] * joined[-1]
+    for seq_id, request in reqs.items():
+        core.submit("alone-" + seq_id, request)
+        alone = drain(core, ["alone-" + seq_id])["alone-" + seq_id]
+        assert [so.token for so in alone] == \
+            [so.token for so in got[seq_id]], seq_id
+        assert alone[-1].logprob == pytest.approx(got[seq_id][-1].logprob,
+                                                  abs=1e-3)
+    while core.has_work:
+        core.step()
+    assert core.active == 0 and not core._deferred_release
+    assert core.pool.free_pages == core.pool.num_pages - 1
 
 
 def test_freed_pages_come_back_when_their_records_are_fetched(
@@ -903,6 +954,254 @@ def test_freed_pages_come_back_when_their_records_are_fetched(
     assert not held and not core._deferred_release
     assert len(released) >= n // 2      # most finish behind a chained decode
     assert any(released)                # ... and some with the window busy
+
+
+# ---- what leaves the engine thread: an iteration in ONE cross-thread call,
+# and a lane's tokens of one dispatch as ONE output all the way out --------
+
+@pytest.fixture(scope="module")
+def burst_engine():
+    eng = JaxEngine(make_cfg(max_batch=4, decode_steps=4))
+    yield eng
+    eng.shutdown()
+
+
+class _StubLoop:
+    """Stands where the event loop stands: counts the cross-thread calls and
+    runs each where it is made."""
+
+    def __init__(self):
+        self.calls = []
+
+    def call_soon_threadsafe(self, fn, *args):
+        self.calls.append(args)
+        fn(*args)
+
+
+class _TokenAtATime:
+    """The parent's path: every ``StepOutput`` its own ``EngineOutput``."""
+
+    def __init__(self, step_outputs):
+        self.step_outputs = step_outputs
+
+    async def generate(self, request, context):
+        for so in self.step_outputs:
+            yield EngineOutput(token_ids=[so.token], cum_log_prob=so.logprob,
+                               logprobs=[{str(so.token): so.token_logprob}],
+                               finish_reason=so.finish)
+
+
+def _token_logprobs(outs):
+    return [lp for o in outs for m in o.logprobs for lp in m.values()]
+
+
+def test_an_iteration_crosses_to_the_loop_in_one_call(burst_engine,
+                                                       monkeypatch):
+    eng, stub, iterations = burst_engine, _StubLoop(), []
+    real_step = eng.core.step
+
+    def step():
+        outs = real_step()
+        if outs:
+            iterations.append(outs)
+        return outs
+
+    monkeypatch.setattr(eng.core, "step", step)
+    monkeypatch.setattr(eng, "_loop", stub)
+    stage = eng.core.stage
+    n0, t0 = stage.engine_handoffs.get(), stage.engine_handoff_tokens.get()
+    seqs = {f"h{i}": req([40 + i, 41, 42], max_tokens=17) for i in range(3)}
+    queues = {s: asyncio.Queue() for s in seqs}
+    eng._queues.update(queues)
+    try:
+        for seq_id, request in seqs.items():
+            eng._inbox.put(("submit", seq_id, (request,)))
+        eng._wake.set()
+        deadline = time.monotonic() + 120
+        while (sum(b[-1].finish is not None
+                   for puts, in stub.calls for _, b in puts) < len(seqs)
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+    finally:
+        for s in seqs:
+            eng._queues.pop(s, None)
+    calls = [puts for puts, in stub.calls]
+    # one call an iteration, whatever the lanes and steps ...
+    assert len(calls) == len(iterations)
+    assert [sum(len(b) for _, b in puts) for puts in calls] == \
+        [len(outs) for outs in iterations]
+    # ... in which a sequence is ONE queue item, holding what the dispatch
+    # gave it: three lanes x decode_steps in the fullest
+    for puts in calls:
+        assert len({id(q) for q, _ in puts}) == len(puts)
+        assert all(len({so.seq_id for so in b}) == 1 for _, b in puts)
+    fullest = max(calls, key=lambda puts: sum(len(b) for _, b in puts))
+    assert sorted(len(b) for _, b in fullest) == [4, 4, 4]
+    for seq_id, q in queues.items():
+        bursts = [q.get_nowait() for _ in range(q.qsize())]
+        assert [so.seq_id for b in bursts for so in b] == [seq_id] * 17
+        assert len(bursts[0]) == 1 and bursts[0][0].first_token_at
+        assert bursts[-1][-1].finish == FinishReason.LENGTH
+    assert stage.engine_handoffs.get() - n0 == len(calls)
+    assert stage.engine_handoff_tokens.get() - t0 == 3 * 17
+
+
+async def test_a_lanes_tokens_of_one_dispatch_are_one_output(burst_engine):
+    reqs = [req([50 + i, 51, 52, 53], max_tokens=11) for i in range(3)]
+
+    async def one(r):
+        return [o async for o in burst_engine.generate(r, Context())]
+
+    for outs in await asyncio.gather(*map(one, reqs)):
+        sizes = [len(o.token_ids) for o in outs]
+        assert sum(sizes) == 11 and sizes[0] == 1
+        assert max(sizes) == 4          # decode_steps tokens in one output
+        assert len(outs) <= 1 + 3       # not an output a token
+        running = 0.0
+        for o in outs:
+            # one logprob entry a token, keyed by the token; the cumulative
+            # one is the last token's
+            assert [list(m) for m in o.logprobs] == \
+                [[str(t)] for t in o.token_ids]
+            running += sum(lp for m in o.logprobs for lp in m.values())
+            assert o.cum_log_prob == pytest.approx(running, abs=1e-4)
+        assert [o.finish_reason for o in outs] == \
+            [None] * (len(outs) - 1) + [FinishReason.LENGTH]
+        assert outs[0].kv_prefix_hit_tokens is not None
+        assert all(o.kv_prefix_hit_tokens is None for o in outs[1:])
+
+
+async def test_bursts_stream_what_a_token_at_a_time_streamed(burst_engine,
+                                                             core):
+    """Greedy, fixed weights: ids, text and per-token logprobs of the burst
+    path equal those of one output a token (what ``_consume`` yielded before
+    a burst was the unit), through the same detokeniser."""
+    from dynamo_tpu.llm.backend import Backend
+    from dynamo_tpu.llm.tokenizer import ByteTokenizer
+
+    tok = ByteTokenizer()
+    prompt = tok.encode("the quick brown fox")
+    core.submit("ref", req(prompt, max_tokens=23))
+    ref = drain(core, ["ref"])["ref"]
+    want = [o async for o in Backend(_TokenAtATime(ref), tok).generate(
+        req(prompt, max_tokens=23), Context())]
+    got = [o async for o in Backend(burst_engine, tok).generate(
+        req(prompt, max_tokens=23), Context())]
+    assert len(got) < len(want) == 23
+    assert [t for o in got for t in o.token_ids] == \
+        [t for o in want for t in o.token_ids] == [so.token for so in ref]
+    assert "".join(o.text for o in got) == "".join(o.text for o in want)
+    assert _token_logprobs(got) == pytest.approx(_token_logprobs(want),
+                                                 abs=1e-4)
+    assert got[-1].cum_log_prob == pytest.approx(want[-1].cum_log_prob,
+                                                 abs=1e-4)
+    assert got[-1].finish_reason == want[-1].finish_reason
+
+
+async def test_a_finish_inside_a_burst_ends_the_burst(burst_engine):
+    # 1 first token + a dispatch of 4 + the first step of the next dispatch
+    outs = [o async for o in burst_engine.generate(
+        req([60, 61, 62], max_tokens=6), Context())]
+    assert [len(o.token_ids) for o in outs] == [1, 4, 1]
+    assert [o.finish_reason for o in outs] == \
+        [None, None, FinishReason.LENGTH]
+    # an EOS at a dispatch's second step: nothing of that dispatch after it
+    ids = [t for o in outs for t in o.token_ids]
+    assert ids[2] not in ids[:2]
+    eos = BackendInput(token_ids=[60, 61, 62],
+                       stop=StopConditions(max_tokens=10),
+                       eos_token_ids=[ids[2]])
+    outs = [o async for o in burst_engine.generate(eos, Context())]
+    assert [o.token_ids for o in outs] == [ids[:1], ids[1:3]]
+    assert len(outs[-1].logprobs) == 2
+    assert outs[-1].finish_reason == FinishReason.EOS
+    while burst_engine.core.active:
+        await asyncio.sleep(0.01)
+
+
+async def test_a_stop_string_inside_a_burst_cuts_the_burst():
+    """The client is given the text up to the stop, so ids, logprobs and
+    usage end at the token that completed it, not at the burst's end."""
+    from dynamo_tpu.llm.model_card import ModelDeploymentCard
+    from dynamo_tpu.llm.pipeline import build_completion_engine
+    from dynamo_tpu.llm.protocols.openai import CompletionRequest
+
+    class Bursts:
+        async def generate(self, request, context):
+            for burst in (b"a", b"bcde", b"f;gh", b"ijkl"):
+                yield EngineOutput(
+                    token_ids=list(burst), cum_log_prob=-0.5 * len(burst),
+                    logprobs=[{str(t): -0.5} for t in burst])
+
+    comp = build_completion_engine(ModelDeploymentCard(name="m"), "core",
+                                   Bursts())
+    chunks = [c async for c in comp.generate(CompletionRequest.from_dict({
+        "model": "m", "prompt": "x", "max_tokens": 32, "stop": [";"],
+        "logprobs": 1}), Context()) if "event" not in c]
+    choices = [c["choices"][0] for c in chunks]
+    assert "".join(c["text"] for c in choices) == "abcdef"
+    assert choices[-1]["finish_reason"] == "stop"
+    lps = [lp for c in choices if c.get("logprobs")
+           for lp in c["logprobs"]["token_logprobs"]]
+    assert len(lps) == 7                    # a … f and the stop's own token
+    assert chunks[-1]["usage"]["completion_tokens"] == 7
+
+
+def test_an_error_crosses_alone(burst_engine, monkeypatch):
+    from dynamo_tpu.engine.engine import StepOutput
+
+    eng, stub = burst_engine, _StubLoop()
+    monkeypatch.setattr(eng, "_loop", stub)
+    qa, qb = asyncio.Queue(), asyncio.Queue()
+    monkeypatch.setitem(eng._queues, "ea", qa)
+    monkeypatch.setitem(eng._queues, "eb", qb)
+    err = StepOutput("ea", 0, 0.0, FinishReason.ERROR, error="boom",
+                     error_code=400, error_stage="engine")
+    eng._hand_off([StepOutput("ea", 1, -0.1), StepOutput("ea", 2, -0.2),
+                   StepOutput("eb", 3, -0.1), err,
+                   StepOutput("gone", 9, -0.1), StepOutput("eb", 4, -0.2)])
+    assert len(stub.calls) == 1
+    assert [[so.token for so in qa.get_nowait()] for _ in range(2)] == \
+        [[1, 2], [0]]
+    assert [so.token for so in qb.get_nowait()] == [3, 4] and qb.empty()
+    eng._hand_off([StepOutput("gone", 9, -0.1)])    # nobody listens: no call
+    assert len(stub.calls) == 1
+
+
+async def test_an_engine_error_is_an_output_of_its_own(burst_engine):
+    # alongside a stream in flight: the refusal neither joins nor cuts it
+    async def one(r):
+        return [o async for o in burst_engine.generate(r, Context())]
+
+    ok, bad = await asyncio.gather(
+        one(req([70, 71, 72], max_tokens=9)),
+        one(req(list(range(200)), max_tokens=4)))      # > max_context 128
+    assert [o.finish_reason for o in bad] == [FinishReason.ERROR]
+    assert bad[0].token_ids == [] and bad[0].error
+    assert sum(len(o.token_ids) for o in ok) == 9
+    assert ok[-1].finish_reason == FinishReason.LENGTH
+
+
+async def test_cancel_between_two_bursts_frees_the_slot(burst_engine):
+    core = burst_engine.core
+    while core.has_work:
+        await asyncio.sleep(0.01)
+    free = core.pool.free_pages
+    ctx, outs = Context(), []
+    async for o in burst_engine.generate(req([80, 81, 82], max_tokens=100),
+                                         ctx):
+        outs.append(o)
+        if len(outs) == 3:          # first token and two whole bursts
+            ctx.stop_generating()
+            break
+    assert [len(o.token_ids) for o in outs] == [1, 4, 4]
+    for _ in range(500):
+        if not core.has_work and core.pool.free_pages == free:
+            break
+        await asyncio.sleep(0.01)
+    assert core.active == 0 and not core.by_seq
+    assert core.pool.free_pages == free
+    assert ctx.id not in burst_engine._queues
 
 
 def test_xla_compile_listener_counts_a_program_once(core):
