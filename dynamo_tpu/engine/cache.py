@@ -21,6 +21,8 @@ manager hooks, event_manager.py stored/removed semantics).
 
 from __future__ import annotations
 
+import math
+
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -33,6 +35,178 @@ from ..llm.tokens import (TokenSequence, chain_hash, hash_tokens,
 
 class OutOfPages(RuntimeError):
     pass
+
+
+@dataclass(frozen=True)
+class CacheKind:
+    """What the layers of one kind keep for a token, and how it pages: the
+    one description the engine sizes its pools by, the byte-honest planes
+    price in and the gauges report (ROADMAP Design 2). A model of one law
+    has one kind, ``global``; a per-kind model (``LlamaConfig.per_kind``)
+    has ``global`` (its full layers: every token of the context) and
+    ``window`` (its window layers: the last ``window`` tokens, in a page
+    pool and page tables of their own, :class:`WindowPages`)."""
+
+    name: str                   # "global" | "window"
+    layers: int
+    kv_heads: int
+    k_dim: int                  # a K row as the model defines it
+    v_dim: int
+    k_store: int                # ... and as the pool stores it
+    window: Optional[int]       # keys a query sees, its own among them
+    index_dim: int = 0          # an indexer's keys on the same pages
+
+    def token_bytes(self, itemsize: int, stored: bool = False) -> int:
+        """Bytes a token holds in this kind, all of its layers: K and V as
+        the model defines them, or (``stored``) as the pool stores them;
+        an indexer's keys beside them."""
+        k = self.k_store if stored else self.k_dim
+        return self.layers * itemsize * (
+            self.kv_heads * (k + self.v_dim) + self.index_dim)
+
+    def pool_shapes(self, num_pages: int, page: int):
+        """-> (K pool shape, V pool shape): [layers of the kind, heads,
+        pages, page, row], head-major, as every program reads and writes
+        them (models/llama.py "KV pool access")."""
+        lead = (self.layers, self.kv_heads, num_pages, page)
+        return (*lead, self.k_store), (*lead, self.v_dim)
+
+
+def cache_kinds(m) -> Tuple[CacheKind, ...]:
+    """The cache kinds of model ``m`` (a ``LlamaConfig``), global first."""
+    if not m.per_kind:
+        return (CacheKind("global", m.num_layers, m.num_kv_heads, m.head_dim,
+                          m.v_dim, m.k_store_dim, None,
+                          m.index_head_dim if m.has_indexer else 0),)
+    return tuple(
+        CacheKind(name, len(m.kind_layers(win)), m.kv_heads_of(win),
+                  m.head_dim, m.v_dim, m.k_store_dim,
+                  m.sliding_window if win else None)
+        for name, win in (("global", False), ("window", True)))
+
+
+class WindowPages:
+    """Host bookkeeping of the WINDOW cache's page pool: a lane holds pages
+    only for the tokens some query of its can still see.
+
+    A sequence's pages are a contiguous run of LOGICAL pages (``position //
+    page_size``), ``first .. first + len(pages) - 1``: :meth:`ensure` leases
+    at the end as the sequence grows, :meth:`release_behind` gives back at
+    the front the pages that lie wholly behind ``position - (window - 1)``,
+    ``position`` being the first query of a dispatch whose record has been
+    fetched (every dispatch enqueued since queries at or past it, so none
+    reads them; the engine calls it from its fetches). A lane's page table
+    into the pool is as wide as its table into the global pool and indexed
+    by the same logical pages; what the lane does not hold names scratch
+    page 0, which every program may read (masked) and padded lanes write.
+    No hashing, no sealing, no reuse: a window page is never matched,
+    offloaded or moved (the engine refuses those features for such a model
+    by name)."""
+
+    def __init__(self, num_pages: int, page_size: int, window: int):
+        self.num_pages, self.page_size, self.window = (num_pages, page_size,
+                                                       window)
+        self._free: List[int] = list(range(num_pages - 1, 0, -1))
+        self.seqs: Dict[str, Tuple[int, List[int]]] = {}   # first, pages
+        self.released_total = 0
+
+    @staticmethod
+    def lane_pages(window: int, chunk: int, page_size: int) -> int:
+        """Pages a lane can hold at most while one chunk a time prefills: a
+        window behind the chunk and the chunk, and one for the page
+        boundary."""
+        return -(-(window - 1 + chunk) // page_size) + 1
+
+    @staticmethod
+    def chunk_read_pages(window: int, chunk: int, page_size: int) -> int:
+        """Pages a prefill program of chunk bucket ``chunk`` reads of the
+        window cache: :meth:`lane_pages` of it, rounded so that the short
+        context tiles in 128 lanes."""
+        n = WindowPages.lane_pages(window, chunk, page_size)
+        if n * page_size <= 128:
+            return n
+        q = math.lcm(page_size, 128) // page_size
+        return -(-n // q) * q
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free)
+
+    @property
+    def pages_in_use(self) -> int:
+        return self.num_pages - 1 - len(self._free)
+
+    def tokens_held(self, seq_id: str, num_tokens: int) -> int:
+        """Positions < ``num_tokens`` of the sequence that are on a page it
+        holds."""
+        first, pages = self.seqs.get(seq_id, (0, []))
+        return max(0, min(num_tokens, (first + len(pages)) * self.page_size)
+                   - first * self.page_size)
+
+    def create(self, seq_id: str) -> None:
+        if seq_id in self.seqs:
+            raise ValueError(f"sequence {seq_id} already exists")
+        self.seqs[seq_id] = (0, [])
+
+    def ensure(self, seq_id: str, total_tokens: int) -> None:
+        """Lease pages so that positions < ``total_tokens`` that are not
+        behind the sequence's released front have one."""
+        first, pages = self.seqs[seq_id]
+        need = -(-total_tokens // self.page_size) - first - len(pages)
+        if need > len(self._free):
+            raise OutOfPages(f"window cache: need {need} pages, "
+                             f"{len(self._free)} free")
+        for _ in range(need):
+            pages.append(self._free.pop())
+
+    def release_behind(self, seq_id: str, position: int) -> int:
+        """Give back the pages wholly behind ``position - (window - 1)``;
+        returns how many."""
+        if seq_id not in self.seqs:
+            return 0
+        first, pages = self.seqs[seq_id]
+        keep_from = max(0, position - (self.window - 1)) // self.page_size
+        n = min(max(0, keep_from - first), len(pages))
+        if n:
+            self._free.extend(pages[:n])
+            self.seqs[seq_id] = (first + n, pages[n:])
+            self.released_total += n
+        return n
+
+    def release(self, seq_id: str) -> None:
+        _, pages = self.seqs.pop(seq_id, (0, []))
+        self._free.extend(pages)
+
+    # -- index computation for the programs ------------------------------
+    def table_row(self, seq_id: str, padded_pages: int) -> np.ndarray:
+        first, pages = self.seqs[seq_id]
+        row = np.zeros(padded_pages, dtype=np.int32)
+        n = max(0, min(len(pages), padded_pages - first))
+        row[first:first + n] = pages[:n]
+        return row
+
+    def write_slots(self, seq_id: str, start: int, count: int) -> np.ndarray:
+        first, pages = self.seqs[seq_id]
+        t = np.arange(start, start + count)
+        return (np.asarray(pages, np.int32)[t // self.page_size - first]
+                * self.page_size + t % self.page_size)
+
+    def read_window(self, seq_id: str, start: int, count: int,
+                    n_pages: int
+                    ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """What a prefill chunk of ``count`` tokens from ``start`` reads:
+        (pages [n_pages], positions and validity of their n_pages x
+        page_size slots), from the page that holds position ``start -
+        (window - 1)`` on; padding names scratch page 0, invalid."""
+        first, pages = self.seqs[seq_id]
+        pg = self.page_size
+        lo = max(first, max(0, start - (self.window - 1)) // pg)
+        ids = np.zeros(n_pages, np.int32)
+        have = max(0, min(n_pages, first + len(pages) - lo))
+        ids[:have] = pages[lo - first:lo - first + have]
+        pos = lo * pg + np.arange(n_pages * pg, dtype=np.int32)
+        valid = (pos < start + count) & (pos < (lo + have) * pg)
+        return ids, pos, valid
 
 
 @dataclass

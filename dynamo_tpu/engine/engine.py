@@ -28,7 +28,7 @@ import queue as thread_queue
 import threading
 import time
 from dataclasses import dataclass, field
-from functools import partial
+from functools import cached_property, partial
 from typing import Any, AsyncIterator, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -46,7 +46,7 @@ from ..parallel.mesh import AXIS_TP, serving_mesh
 from ..runtime.engine import AsyncEngine, Context
 from ..utils import tracing as _tracing
 from ..utils.jaxenv import on_tpu
-from .cache import OutOfPages, PagePool
+from .cache import OutOfPages, PagePool, WindowPages, cache_kinds
 from .sampling import (STATIC_K, SamplingState, apply_penalties,
                        resume_seed, sample)
 
@@ -496,14 +496,59 @@ class EngineCore:
         # pool at a program's entry and exit (PERF.md §6, PR 26) ----------
         kv_spec = llama.kv_cache_spec(m, cfg.tp, cfg.pp)
         self.kv_sharding = NamedSharding(self.mesh, kv_spec)
-        pool_shape = (m.num_layers, m.num_kv_heads, num_pages,
-                      cfg.page_size, m.head_dim)
-        # jitted zeros with explicit out_sharding: allocates straight into
-        # the (possibly multi-process) sharded layout, no host staging
-        zeros = jax.jit(lambda: jnp.zeros(pool_shape, m.dtype),
-                        out_shardings=self.kv_sharding)
-        self.k_pool = zeros()
-        self.v_pool = zeros()
+        # one descriptor a cache kind (engine/cache.py): the global cache
+        # of every model, and the window cache of a per-kind model
+        self.cache_kinds = cache_kinds(m)
+
+        zero_fns: Dict[Tuple[int, ...], Any] = {}
+
+        def zeros(shape):
+            # jitted zeros with explicit out_sharding: allocates straight
+            # into the (possibly multi-process) sharded layout, no host
+            # staging (one program a shape)
+            if shape not in zero_fns:
+                zero_fns[shape] = jax.jit(
+                    lambda: jnp.zeros(shape, m.dtype),
+                    out_shardings=self.kv_sharding)
+            return zero_fns[shape]()
+
+        k_shape, v_shape = self.cache_kinds[0].pool_shapes(num_pages,
+                                                           cfg.page_size)
+        self.k_pool = zeros(k_shape)
+        self.v_pool = zeros(v_shape)
+        # A per-kind model's window layers keep their K/V in a second pair
+        # of pools, of their own head count and pages, through a second
+        # page table a lane: a lane holds there only the pages a query of
+        # its can still see (cache.WindowPages), so the pool is lanes x a
+        # window's and a chunk's pages, whatever the context. Whatever
+        # moves, matches or re-enters blocks knows one cache: such a model
+        # refuses those features by name, as a model with an indexer does.
+        self.win = self.wk_pool = self.wv_pool = None
+        if m.per_kind:
+            for on, what in (
+                    (cfg.sp > 1 or impl == "ring", "sp > 1 / ring prefill"),
+                    (cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0,
+                     "the host / disk KV tiers (host_cache_blocks, "
+                     "disk_cache_blocks), and with them cluster "
+                     "write-through, tier prefetch and the paged "
+                     "long-context lane"),
+                    (cfg.cluster_writethrough, "cluster write-through"),
+                    (self.spec is not None, "speculative decoding (verify)"),
+                    (jax.process_count() > 1, "multi-host serving")):
+                if on:
+                    raise ValueError(self._two_caches_refusal(what))
+            wk = self.cache_kinds[1]
+            self.win_pages = cfg.max_batch * WindowPages.lane_pages(
+                wk.window, cfg.prefill_chunk, cfg.page_size) + 1
+            self.win = WindowPages(self.win_pages, cfg.page_size, wk.window)
+            wk_shape, wv_shape = wk.pool_shapes(self.win_pages,
+                                                cfg.page_size)
+            self.wk_pool, self.wv_pool = zeros(wk_shape), zeros(wv_shape)
+            if cfg.enable_prefix_reuse:
+                log.info("prefix reuse is off for this model: a block of "
+                         "the global cache cannot be re-entered without "
+                         "the window layers' keys (no block is hashed, "
+                         "sealed or published)")
         # a model with an indexer (learned top-k attention) keeps its index
         # keys in a third pool on the SAME pages and page tables: allocated,
         # donated, written and threaded through the programs with the other
@@ -744,33 +789,98 @@ class EngineCore:
                 f"their index keys, and a block that comes back without "
                 f"them selects wrongly without any error")
 
-    def _refuse_indexer(self, what: str) -> None:
+    @staticmethod
+    def _two_caches_refusal(what: str) -> str:
+        return (f"a model whose window layers keep a cache of their own "
+                f"(a second page pool and page table a lane) does not run "
+                f"with {what}: it would move, match or re-enter blocks of "
+                f"the global cache without the window layers' keys")
+
+    def _refuse_block_moves(self, what: str) -> None:
+        """Refuse a feature that moves K/V blocks off or onto the device
+        pool for a model that keeps more than K and V on those pages (an
+        indexer's keys) or keeps a second cache beside them (a per-kind
+        model's window layers)."""
         if self.cfg.model.has_indexer:
             raise ValueError(self._indexer_refusal(what))
+        if self.cfg.model.per_kind:
+            raise ValueError(self._two_caches_refusal(what))
 
     def _idx(self) -> Dict[str, Any]:
-        """The programs' one more operand: the index-key pool of a model
-        with an indexer, nothing for every other model (whose programs
-        therefore compile to what they always did)."""
+        """The programs' further pool operands: the index-key pool of a
+        model with an indexer, the window cache's two pools of a per-kind
+        model, nothing for every other model (whose programs therefore
+        compile to what they always did)."""
+        if self.win is not None:
+            return {"wk_pool": self.wk_pool, "wv_pool": self.wv_pool}
         return {} if self.i_pool is None else {"i_pool": self.i_pool}
 
     def _program_extras(self):
-        """-> (jit options, out_shardings tail, whether ``packed`` carries
-        the experts-hit column) of this model's bucket programs."""
+        """-> (jit options, out_shardings tail, the columns ``packed``
+        carries behind token and log-probability: ``experts_hit``, and
+        ``held``, the assignments to held experts, under a chip's share) of
+        this model's bucket programs."""
         m = self.cfg.model
+        cols = (("experts_hit",) if m.num_experts and (
+            m.has_indexer or self.cfg.pp == 1) else ()) + (
+            ("held",) if m.router_experts else ())
         if m.has_indexer:
             return ({"donate_argnames": ("i_pool",)}, (self.idx_sharding,),
-                    bool(m.num_experts))
-        return {}, (), bool(m.num_experts) and self.cfg.pp == 1
+                    cols)
+        if m.per_kind:
+            return ({"donate_argnames": ("wk_pool", "wv_pool")},
+                    (self.kv_sharding, self.kv_sharding), cols)
+        return {}, (), cols
+
+    @cached_property
+    def _packed_cols(self) -> Tuple[str, ...]:
+        """The columns of ``packed`` behind token and log-probability
+        (:meth:`_program_extras`), as every fetch reads them."""
+        return self._program_extras()[2]
 
     def _take_pools(self, pools) -> None:
-        """(k_pool, v_pool[, i_pool]) as a program returned them."""
+        """(k_pool, v_pool[, i_pool | wk_pool, wv_pool]) as a program
+        returned them."""
         self.k_pool, self.v_pool, *rest = pools
-        if rest:
+        if self.win is not None:
+            self.wk_pool, self.wv_pool = rest
+        elif rest:
             self.i_pool, = rest
 
+    def _win_dummies(self, Bp: int, C: int) -> Dict[str, Any]:
+        """Warm-up's window operands of a prefill program (nothing valid to
+        read, writes to scratch page 0), as serving passes them."""
+        if self.win is None:
+            return {}
+        Sw = WindowPages.chunk_read_pages(self.win.window, C,
+                                          self.page_size) * self.page_size
+        return {"w_write": np.zeros((Bp, C), np.int32),
+                "w_pages": np.zeros((Bp, Sw // self.page_size), np.int32),
+                "w_pos": np.zeros((Bp, Sw), np.int32),
+                "w_valid": np.zeros((Bp, Sw), bool)}
+
+    def _release_seq(self, seq_id: str) -> None:
+        self.pool.release(seq_id)
+        if self.win is not None:
+            self.win.release(seq_id)
+
+    def _ensure_pages(self, seq_id: str, total_tokens: int) -> None:
+        """Room for ``total_tokens`` of the sequence in every cache."""
+        self.pool.ensure_pages(seq_id, total_tokens)
+        if self.win is not None:
+            self.win.ensure(seq_id, total_tokens)
+
+    def _window_fetched(self, seq_id: str, position: int) -> None:
+        """A dispatch of the sequence whose first query stood at
+        ``position`` has been fetched: the window pages wholly behind its
+        window go back (cache.WindowPages.release_behind)."""
+        n = self.win.release_behind(seq_id, position)
+        if n:
+            self.stage.kv_window_pages_released.inc(amount=float(n))
+
     def _count_model_work(self, kind: str, spans, hit,
-                          captured: bool = False, S: int = 0) -> None:
+                          captured: bool = False, S: int = 0,
+                          held: Optional[float] = None) -> None:
         """Host counters of what a dispatch made the experts and the
         indexer do. ``spans``: (first position, queries) per lane; a query
         at position p sees p + 1 keys. ``hit``: experts hit, read from the
@@ -782,15 +892,22 @@ class EngineCore:
         traced dispatches themselves and not a window's mean; ``S``, the
         dispatch's context bucket, says whether its program scored at all
         (a bucket no longer than ``index_topk`` selects every visible key
-        by construction and skips the scoring): ``scored_keys``."""
+        by construction and skips the scoring): ``scored_keys``. ``held``
+        (a chip's share of the experts): the part of the real tokens'
+        assignments that went to experts held here, which is then what
+        ``dyn_moe_assignments_total`` counts (computed here), while
+        ``dyn_moe_routed_assignments_total`` counts all of them."""
         m = self.cfg.model
         if not (m.num_experts or m.has_indexer):
             return
         tokens = sum(n for _, n in spans)
         work = {}
         if m.num_experts:
-            work[self.stage.moe_assignments] = float(
-                tokens * m.experts_per_token * m.num_layers)
+            routed = float(tokens * m.experts_per_token * m.routed_layers)
+            work[self.stage.moe_assignments] = routed
+            if held is not None:
+                work[self.stage.moe_assignments] = float(held)
+                work[self.stage.moe_routed_assignments] = routed
             if hit is not None:
                 work[self.stage.moe_experts_hit] = float(hit)
         if m.has_indexer:
@@ -816,6 +933,29 @@ class EngineCore:
                 seen_by.inc("scored_keys", kind, amount=float(seen))
                 seen_by.inc("scoring_dispatches", kind)
                 seen_by.inc("scoring_tokens", kind, amount=float(tokens))
+            if m.per_kind:
+                # what the traced dispatches' attention had to read and
+                # multiply at least, by kind of layer (one layer's worth):
+                # ``*_keys`` the keys read (a decode query reads its lane's
+                # visible keys; a chunk's queries share one lane's, read
+                # once), ``*_pairs`` the (query, visible key) pairs
+                W = m.sliding_window
+                attn = dict.fromkeys(("attn_full_keys", "attn_full_pairs",
+                                      "attn_window_keys",
+                                      "attn_window_pairs"), 0)
+                for p0, n in spans:
+                    full = n * p0 + n * (n + 1) // 2
+                    below = max(0, min(n, W - p0))  # queries that see all
+                    win = (below * p0 + below * (below + 1) // 2
+                           + (n - below) * W)
+                    attn["attn_full_pairs"] += full
+                    attn["attn_window_pairs"] += win
+                    chunk = kind == "prefill"
+                    attn["attn_full_keys"] += p0 + n if chunk else full
+                    attn["attn_window_keys"] += (min(p0, W - 1) + n if chunk
+                                                 else win)
+                for name, amount in attn.items():
+                    seen_by.inc(name, kind, amount=float(amount))
 
     # ------------------------------------------------------------------
     def warmup(self) -> None:
@@ -854,7 +994,8 @@ class EngineCore:
                 self.params, zb, self.k_pool, self.v_pool, pt, ones,
                 s.temperature, s.top_p, s.top_k, s.key,
                 self.gen_counts, fresh, act, s.freq_pen, s.pres_pen,
-                **self._idx())
+                **self._idx(),
+                **({} if self.win is None else {"w_tables": pt}))
             self._take_pools((kp, vp, *ip))
             n += 1
             if self.spec is not None:
@@ -883,7 +1024,7 @@ class EngineCore:
                         np.zeros((Bp, S), bool),
                         np.zeros(Bp, np.int32), np.zeros(Bp, np.float32),
                         np.ones(Bp, np.float32), np.zeros(Bp, np.int32),
-                        keys, **self._idx())
+                        keys, **self._idx(), **self._win_dummies(Bp, C))
                     self._take_pools(pools)
                     n += 1
             # a chunk of this many lanes handing first tokens to a decode
@@ -940,12 +1081,14 @@ class EngineCore:
             # bucket program compiles a second variant against it
             B = self.cfg.max_batch
             jit_kw, out_tail, hit_col = self._program_extras()
+            per_kind = cfg.model.per_kind
 
             @partial(jax.jit, donate_argnums=(2, 3, 10), **jit_kw,
                      out_shardings=(rep, rep, rep, kv, kv, rep, *out_tail))
             def step(params, tokens, k_pool, v_pool, page_tables, lengths,
                      temp, top_p, top_k, key, counts, fresh, active,
-                     freq_pen, pres_pen, i_pool=None):
+                     freq_pen, pres_pen, i_pool=None, wk_pool=None,
+                     wv_pool=None, w_tables=None):
                 # lanes whose sequence just entered decode restart their
                 # generated-token counts at one-hot(first generated token);
                 # chained dispatches pass fresh all-False
@@ -973,7 +1116,8 @@ class EngineCore:
                             params, cfg.model, tokens, k_pool, v_pool,
                             page_tables, lengths, attn_impl=impl, mesh=mesh,
                             stats=stats,
-                            **({"i_pool": ip[0]} if ip else {}))
+                            **({"win": (*ip, w_tables)} if per_kind
+                               else {"i_pool": ip[0]} if ip else {}))
                     lg = apply_penalties(logits[:, 0], counts, freq_pen,
                                          pres_pen)
                     tok, logp, new_key = sample(lg, temp, top_p, top_k, key)
@@ -981,13 +1125,13 @@ class EngineCore:
                     # a deferred (pool-pressure) lane's garbage tokens must
                     # not poison its penalties when it resumes
                     counts = counts.at[lane, tok].add(act)
-                    ys = (tok, logp) + ((stats["experts_hit"],)
-                                        if hit_col else ())
+                    ys = (tok, logp) + tuple(stats[c] for c in hit_col)
                     return ((tok, lengths + 1, k_pool, v_pool, new_key,
                              counts, *ip), ys)
 
                 carry = (tokens, lengths, k_pool, v_pool, key, counts,
-                         *(() if i_pool is None else (i_pool,)))
+                         *((wk_pool, wv_pool) if per_kind
+                           else () if i_pool is None else (i_pool,)))
                 ((tok, lengths, k_pool, v_pool, key, counts, *ip),
                  (toks, logps, *hit)) = jax.lax.scan(one, carry, None,
                                                      length=N)
@@ -996,9 +1140,9 @@ class EngineCore:
                 # routed model's experts hit in each step ride a third
                 # column (the same number on every lane)
                 cols = [toks.astype(jnp.float32), logps]
-                if hit_col:
+                for h in hit:
                     cols.append(jnp.broadcast_to(
-                        hit[0].astype(jnp.float32)[:, None], toks.shape))
+                        h.astype(jnp.float32)[:, None], toks.shape))
                 packed = jnp.stack(cols, -1)
                 return (packed, tok, key, k_pool, v_pool, counts, *ip)
 
@@ -1031,7 +1175,8 @@ class EngineCore:
             def fn(params, tokens, positions, k_pool, v_pool, write_idx,
                    read_idx, read_pos, read_valid, last_i, temp, top_p,
                    top_k, keys, ov_vals=None, ov_mask=None, q_span=None,
-                   read_span=None, i_pool=None):
+                   read_span=None, i_pool=None, wk_pool=None, wv_pool=None,
+                   w_write=None, w_pages=None, w_pos=None, w_valid=None):
                 stats: Dict[str, Any] = {}
                 ip = ()
                 if cfg.pp > 1:
@@ -1054,6 +1199,9 @@ class EngineCore:
                         attn_impl="xla" if mm else impl, mesh=mesh,
                         logits_idx=last_i, stats=stats,
                         **({} if i_pool is None else {"i_pool": i_pool}),
+                        **({} if wk_pool is None else {"win": (
+                            wk_pool, wv_pool, w_write, w_pages, w_pos,
+                            w_valid)}),
                         embed_override=((ov_vals, ov_mask) if mm else None),
                         attn_spans=((q_span, read_span) if mm else None),
                         # read slots come from PagePool.read_slots: whole
@@ -1063,9 +1211,9 @@ class EngineCore:
                 tok, logp, new_keys = sample(
                     logits[:, 0], temp, top_p, top_k, keys)
                 cols = [tok.astype(jnp.float32), logp]
-                if hit_col:
+                for c in hit_col:
                     cols.append(jnp.broadcast_to(
-                        stats["experts_hit"].astype(jnp.float32), logp.shape))
+                        stats[c].astype(jnp.float32), logp.shape))
                 packed = jnp.stack(cols, -1)
                 return (packed, tok, new_keys, k_pool, v_pool, *ip)
 
@@ -1240,7 +1388,7 @@ class EngineCore:
         With ``layer`` set, returns that layer only ([T,Hkv,Dh] k, v) for
         layer-pipelined transfer; otherwise all layers ([L,T,Hkv,Dh]).
         ``count`` limits extraction to the first N tokens (e.g. the prompt)."""
-        self._refuse_indexer("disaggregated KV extract")
+        self._refuse_block_moves("disaggregated KV extract")
         sc = self.pool.seqs[seq_id]
         n = sc.num_tokens if count is None else min(count, sc.num_tokens)
         slots = jnp.asarray(self.pool.write_slots(seq_id, 0, n))
@@ -1282,7 +1430,7 @@ class EngineCore:
         sample its first token, gather the prompt KV to host, release the
         slot. Returns (k [L,T,Hkv,Dh], v, first_token, first_logprob).
         The caller owns queue/transfer; this runs on the engine thread."""
-        self._refuse_indexer("disaggregated prefill (KV extract)")
+        self._refuse_block_moves("disaggregated prefill (KV extract)")
         from dataclasses import replace
 
         prompt = list(request.token_ids)
@@ -1331,7 +1479,7 @@ class EngineCore:
         """Receive a remotely-prefilled sequence: write its prompt KV into
         this pool and enter it straight into decode (prefill_done=len).
         ``k``/``v``: [L, T, Hkv, Dh] for the prompt tokens."""
-        self._refuse_indexer("disaggregated KV inject")
+        self._refuse_block_moves("disaggregated KV inject")
         if None not in self.slots:
             raise RuntimeError("no free slot for injected sequence")
         prompt = list(request.token_ids)
@@ -1394,7 +1542,7 @@ class EngineCore:
         no stored events, no write-through) until :meth:`
         finish_stream_inject` — a torn stream releases them with nothing
         ever having referenced them."""
-        self._refuse_indexer("layer-streamed KV inject")
+        self._refuse_block_moves("layer-streamed KV inject")
         prompt = list(request.token_ids)
         if None not in self.slots:
             raise RuntimeError("no free slot for streamed sequence")
@@ -1601,8 +1749,20 @@ class EngineCore:
     def _admission_awaits_release(self) -> bool:
         """The head-of-line request does not fit the pool while a deferred
         release holds pages: fetch what holds them before admitting."""
-        return bool(self._deferred_release) and not self.pool.can_admit(
+        return bool(self._deferred_release) and not self._can_admit(
             len(self.waiting[0][1].token_ids) + 1)
+
+    def _can_admit(self, tokens: int) -> bool:
+        """Every cache has room for a sequence of ``tokens``: its whole
+        context in the global pool and, for a per-kind model, a window and
+        a chunk (and the in-flight dispatches' lag behind them) in the
+        window pool."""
+        if not self.pool.can_admit(tokens):
+            return False
+        if self.win is None:
+            return True
+        span = min(tokens, self.win.window - 1 + 3 * self.cfg.prefill_chunk)
+        return self.win.free_pages >= -(-span // self.page_size) + 1
 
     # ------------------------------------------------------------------
     def _request_span(self, slot: _Slot, name: str, start: float,
@@ -1662,7 +1822,7 @@ class EngineCore:
             self._deferred_release.append(
                 (slot.seq_id, self._inflight[-1]["seq"]))
         else:
-            self.pool.release(slot.seq_id)
+            self._release_seq(slot.seq_id)
         self.by_seq.pop(slot.seq_id, None)
         self.slots[i] = None
 
@@ -1674,7 +1834,7 @@ class EngineCore:
         # barriers only grow down the list
         while self._deferred_release and self._deferred_release[0][1] < oldest:
             self.phase.to("housekeeping")
-            self.pool.release(self._deferred_release.pop(0)[0])
+            self._release_seq(self._deferred_release.pop(0)[0])
 
     def _offload_evicted(self, seq_hash: int, page: int) -> None:
         """Eviction hook: queue the page for host-tier offload. The data
@@ -1753,7 +1913,7 @@ class EngineCore:
         Safe concurrently with the engine thread: the tier is internally
         locked, staged arrays are fresh device buffers nothing else
         references, and the stage dict is lock-guarded."""
-        self._refuse_indexer("tier prefetch staging")
+        self._refuse_block_moves("tier prefetch staging")
         from ..llm.tokens import compute_seq_hashes
         from ..utils.knobs import env_float
 
@@ -1929,7 +2089,7 @@ class EngineCore:
                 error_code=400, error_stage="engine_admission",
                 error_reason="context_exceeded"))
             return "rejected"
-        if not self.pool.can_admit(len(prompt) + 1):
+        if not self._can_admit(len(prompt) + 1):
             return "blocked"  # decode will free KV space eventually
         mm_spans = mm_soft = None
         chain_salt = getattr(req, "lora_id", 0)
@@ -1957,9 +2117,13 @@ class EngineCore:
         slot.trace_parent = parent
         self.slots[slot_idx] = slot
         self.by_seq[seq_id] = slot
-        self.pool.create(seq_id, lora_id=chain_salt)
+        # (a per-kind model hashes, seals and matches no block: see __init__)
+        self.pool.create(seq_id, lora_id=chain_salt,
+                         block_hashing=self.win is None)
+        if self.win is not None:
+            self.win.create(seq_id)
         matched = 0
-        if self.cfg.enable_prefix_reuse:
+        if self.cfg.enable_prefix_reuse and self.win is None:
             matched = self._restore_prefix(seq_id, prompt)
             slot.prefill_done = matched
         slot.prefix_hit = matched
@@ -2059,7 +2223,7 @@ class EngineCore:
     def _run_prefill_program(self, Bp, C, S, tokens, positions, write_idx,
                              read_idx, read_pos, read_valid, last_i, temp,
                              top_p, top_k, idxs, last_lanes,
-                             mm_arrays=None):
+                             mm_arrays=None, win_arrays=None):
         """Execute the batched prefill program + key bookkeeping. The SAME
         code path runs on the leader (from _prefill_enqueue) and on
         followers (from mirror_dispatch) so device state stays in lockstep."""
@@ -2078,7 +2242,8 @@ class EngineCore:
             packed, tok, new_keys, *pools = fn(
                 self.params, tokens, positions, self.k_pool, self.v_pool,
                 write_idx, read_idx, read_pos, read_valid, last_i,
-                temp, top_p, top_k, keys, **self._idx())
+                temp, top_p, top_k, keys, **self._idx(),
+                **(win_arrays or {}))
             self._take_pools(pools)
         self._last_prefill_tok = tok
         self.phase.to("prefill_build")
@@ -2123,6 +2288,8 @@ class EngineCore:
             else:
                 count = min(len(prompt) - start, cfg.prefill_chunk)
             try:
+                if self.win is not None:
+                    self.win.ensure(slot.seq_id, start + count)
                 self.pool.extend(slot.seq_id, prompt[start:start + count])
             except OutOfPages:
                 out.append(StepOutput(slot.seq_id, 0, 0.0,
@@ -2151,6 +2318,16 @@ class EngineCore:
         top_p = np.ones(Bp, np.float32)
         top_k = np.zeros(Bp, np.int32)
         idxs = np.zeros(Bp, np.int32)
+        win_arrays = None
+        if self.win is not None:
+            win_arrays = self._win_dummies(Bp, C)
+            Pw = win_arrays["w_pages"].shape[1]
+            for lane, (_, slot, start, count, _) in enumerate(work):
+                win_arrays["w_write"][lane, :count] = self.win.write_slots(
+                    slot.seq_id, start, count)
+                (win_arrays["w_pages"][lane], win_arrays["w_pos"][lane],
+                 win_arrays["w_valid"][lane]) = self.win.read_window(
+                    slot.seq_id, start, count, Pw)
         mm = any(w[1].mm_spans is not None for w in work)
         mm_arrays = None
         if mm:
@@ -2210,7 +2387,8 @@ class EngineCore:
         packed = self._run_prefill_program(
             Bp, C, S, tokens, positions, write_idx, read_idx, read_pos,
             read_valid, last_i, temp, top_p, top_k, idxs, last_lanes,
-            mm_arrays=mm_arrays)
+            mm_arrays=mm_arrays,
+            **({} if win_arrays is None else {"win_arrays": win_arrays}))
         self.stage.engine_dispatch_tokens.inc(
             "prefill", amount=float(sum(w[3] for w in work)))
         self._inflight.append({"kind": "prefill",
@@ -2219,6 +2397,7 @@ class EngineCore:
                                "last_lanes": last_lanes,
                                "compiled": self._take_compiled_flag(),
                                "captured": captured, "S": S,
+                               "rows": Bp * C,
                                "dispatched_at": t_disp})
         return len(last_lanes)
 
@@ -2244,10 +2423,17 @@ class EngineCore:
         # [Bp,2] (token,logprob) array per dispatch, batched across lanes
         packed_np = np.asarray(rec["packed"])     # ONE host fetch
         self.phase.to("emit")
+        cols = self._packed_cols
         self._count_model_work(
             "prefill", spans,
-            packed_np[0, 2] if packed_np.shape[-1] > 2 else None,
-            rec["captured"], rec["S"])
+            packed_np[0, 2] if "experts_hit" in cols else None,
+            rec["captured"], rec["S"],
+            held=(packed_np[0, 2 + cols.index("held")]
+                  * sum(n for _, n in spans) / rec["rows"]
+                  if "held" in cols else None))
+        if self.win is not None:
+            for _, slot, start, _, _ in work:
+                self._window_fetched(slot.seq_id, start)
         now = time.monotonic()
         if not rec["compiled"]:
             from ..utils.roofline import prefill_cost
@@ -2316,7 +2502,7 @@ class EngineCore:
             phys = self._phys_len(slot)
             try:
                 # reserve room for N speculative tokens up front
-                self.pool.ensure_pages(slot.seq_id, phys + N)
+                self._ensure_pages(slot.seq_id, phys + N)
             except OutOfPages:
                 # pool pressure: defer this slot — batchmates finishing will
                 # free pages — rather than killing a healthy request
@@ -2356,7 +2542,7 @@ class EngineCore:
         for i in sorted(ready_now):
             slot = self.slots[i]
             try:
-                self.pool.ensure_pages(slot.seq_id, self._phys_len(slot) + N)
+                self._ensure_pages(slot.seq_id, self._phys_len(slot) + N)
             except OutOfPages:
                 return False
         return True
@@ -2416,9 +2602,12 @@ class EngineCore:
 
         lengths = np.ones(B, np.int32)    # inactive lanes write into page 0
         page_tables = np.zeros((B, P), np.int32)
+        w_tables = None if self.win is None else np.zeros((B, P), np.int32)
         for i, slot, phys in active:
             lengths[i] = phys
             page_tables[i] = self.pool.page_table_row(slot.seq_id, P)
+            if w_tables is not None:
+                w_tables[i] = self.win.table_row(slot.seq_id, P)
             slot.sched_len = phys + N
         if chain:
             tokens = None   # resolved to the previous dispatch's device toks
@@ -2452,7 +2641,8 @@ class EngineCore:
                                           "joining": sorted(joining.items())},
                                payload)
         packed, final_tok = self._run_decode_program(
-            S, tokens, page_tables, lengths, fresh, active_mask, joining)
+            S, tokens, page_tables, lengths, fresh, active_mask, joining,
+            **({} if w_tables is None else {"w_tables": w_tables}))
         self.stage.engine_dispatch_tokens.inc(
             "decode", amount=float(len(active) * N))
         self._inflight.append({"kind": "decode",
@@ -2471,7 +2661,8 @@ class EngineCore:
         return True
 
     def _run_decode_program(self, S: int, tokens, page_tables, lengths,
-                            fresh, active_mask, joining=None):
+                            fresh, active_mask, joining=None,
+                            w_tables=None):
         """Execute the multi-step decode program. ``tokens=None`` chains off
         the previous dispatch's on-device final tokens, with the newest
         chunk's sampled tokens written over the slots in ``joining`` (slot
@@ -2504,7 +2695,8 @@ class EngineCore:
             self.params, tokens, self.k_pool, self.v_pool,
             page_tables, lengths, s.temperature, s.top_p, s.top_k, s.key,
             self.gen_counts, fresh, active_mask, s.freq_pen, s.pres_pen,
-            **self._idx())
+            **self._idx(),
+            **({} if w_tables is None else {"w_tables": w_tables}))
         self._take_pools((kp, vp, *ip))
         self.phase.to("decode_build")
         s.key = new_key
@@ -2747,10 +2939,21 @@ class EngineCore:
         packed_np = np.asarray(rec["packed"])     # [N, B, 2] — ONE fetch
         self.phase.to("emit")
         N = packed_np.shape[0]
+        cols = self._packed_cols
         self._count_model_work(
             "decode", [(s0 - 1, N) for s0 in rec["lengths"]],
-            packed_np[:, 0, 2].sum() if packed_np.shape[-1] > 2 else None,
-            rec.get("captured", False), rec.get("S", 0))
+            packed_np[:, 0, 2].sum() if "experts_hit" in cols else None,
+            rec.get("captured", False), rec.get("S", 0),
+            held=(packed_np[:, 0, 2 + cols.index("held")].sum()
+                  * len(rec["lengths"]) / packed_np.shape[1]
+                  if "held" in cols else None))
+        if self.win is not None:
+            steps = self.stage.kv_resident_token_steps
+            for (_, slot, _), s0 in zip(rec["active"], rec["lengths"]):
+                self._window_fetched(slot.seq_id, s0 - 1)
+                steps.inc("global", amount=s0 + N - 1)
+                steps.inc("window", amount=self.win.tokens_held(
+                    slot.seq_id, s0 + N - 1))
         if N and "dispatched_at" in rec:
             # effective per-token decode latency: dispatch -> results on
             # host, amortized over the dispatch's N steps (pipelined
@@ -2820,28 +3023,40 @@ def _pallas_probe(m, cfg, device) -> None:
     Hq = m.num_heads // tp
     Hkv = (m.num_kv_heads // tp if m.num_kv_heads % tp == 0
            else m.num_kv_heads)
-    Dh = m.head_dim
     page = cfg.page_size
     # probe the exact kernel variants this model will run: softcap and
     # (on sliding models) the windowed variant are distinct Mosaic
-    # lowerings from the plain causal one
+    # lowerings from the plain causal one; a per-kind model's two kinds
+    # differ in head count and sink as well, and its K rows are as wide as
+    # the pools store them
     kw = dict(scale=m.attn_scale, softcap=m.attn_logit_softcap)
-    windows = ([None, m.sliding_window] if m.sliding_window is not None
-               else [None])
+    Dk, Dv = m.k_store_dim, m.v_dim
+    if m.per_kind:
+        variants = [(k.kv_heads, k.window,
+                     m.sink_window if k.window else m.sink_full)
+                    for k in cache_kinds(m)]
+    else:
+        variants = [(Hkv, w, False) for w in (
+            [None, m.sliding_window] if m.sliding_window is not None
+            else [None])]
     with jax.default_device(device):
-        q = jnp.zeros((2, Hq, Dh), m.dtype)
-        kp = jnp.zeros((2, Hkv, 3, page, Dh), m.dtype)   # a pool of 2 layers
         pt = jnp.zeros((2, 1), jnp.int32)
         ln = jnp.ones((2,), jnp.int32)
         T = max(8, min(128, cfg.prefill_chunk))
-        qf = jnp.zeros((2, T, Hq, Dh), m.dtype)
-        kf = jnp.zeros((2, T, Hkv, Dh), m.dtype)
         pos = jnp.zeros((2, T), jnp.int32)
-        for w in windows:
-            paged_attention(q, kp, kp, pt, ln, 1, interpret=False,
-                            window=w, **kw).block_until_ready()
-            flash_attention(qf, kf, kf, pos, pos, pos < 1, interpret=False,
-                            window=w, **kw).block_until_ready()
+        for hkv, w, sunk in variants:
+            q = jnp.zeros((2, Hq, Dk), m.dtype)
+            kp = jnp.zeros((2, hkv, 3, page, Dk), m.dtype)  # 2 layers' pool
+            vp = kp if Dv == Dk else jnp.zeros((2, hkv, 3, page, Dv),
+                                               m.dtype)
+            qf = jnp.zeros((2, T, Hq, Dk), m.dtype)
+            kf = jnp.zeros((2, T, hkv, Dk), m.dtype)
+            vf = kf if Dv == Dk else jnp.zeros((2, T, hkv, Dv), m.dtype)
+            sink = {"sink": jnp.zeros((Hq,), jnp.float32)} if sunk else {}
+            paged_attention(q, kp, vp, pt, ln, 1, interpret=False,
+                            window=w, **kw, **sink).block_until_ready()
+            flash_attention(qf, kf, vf, pos, pos, pos < 1, interpret=False,
+                            window=w, **kw, **sink).block_until_ready()
 
 
 def _has_safetensors(path: str) -> bool:
@@ -2966,7 +3181,9 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
         core.stage.engine_info.set(
             str(os.getpid()), core.attn_impl, core.decode_attn_impl,
             core.paged_kernel or "none", dev0.platform, dev0.device_kind,
-            str(core.mesh.devices.size), core.goodput.peaks.source, value=1)
+            str(core.mesh.devices.size), core.goodput.peaks.source,
+            "+".join(f"{k.name}:{k.layers}x{k.kv_heads}x({k.k_dim}+{k.v_dim})"
+                     for k in core.cache_kinds), value=1)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._queues: Dict[str, asyncio.Queue] = {}
         self._inbox: thread_queue.Queue = thread_queue.Queue()
@@ -3106,6 +3323,11 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
 
     def _set_goodput_gauges(self, stage) -> None:
         pid = str(os.getpid())
+        if self.core.win is not None:
+            stage.kv_pages_in_use.set("global", value=float(
+                self.core.pool.num_pages - 1 - self.core.pool.free_pages))
+            stage.kv_pages_in_use.set(
+                "window", value=float(self.core.win.pages_in_use))
         snap = self.core.goodput.snapshot()
         stage.mfu.set(pid, value=snap["mfu"])
         stage.mbu.set(pid, value=snap["mbu"])

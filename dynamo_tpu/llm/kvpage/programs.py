@@ -250,6 +250,9 @@ class PagedPrograms:
         variants and ARE servable; what remains excluded is structure the
         segmented forward itself cannot express."""
         m = cfg.model
+        if m.per_kind:
+            return (f"models whose window layers keep a cache of their own "
+                    f"({llama.NO_SECOND_CACHE})")
         if m.num_experts:
             return "MoE models"
         if m.has_indexer:

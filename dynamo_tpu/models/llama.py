@@ -23,6 +23,7 @@ behind the reference's engine adapters (SURVEY §2.1, §7 step 3).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import os
@@ -167,6 +168,80 @@ class LlamaConfig:
     vision: Optional[Dict[str, Any]] = None
     mm_tokens_per_image: int = 256
     image_token_id: Optional[int] = None
+    # A per-layer description where one law for all layers does not hold
+    # (MiMo-V2-Flash; ROADMAP Design 2). ``layer_kinds[l]``: 0 full
+    # attention, 1 window attention (``sliding_window`` keys with the
+    # query's own); ``ffn_kinds[l]``: 0 dense feed-forward, 1 routed
+    # experts. With ``layer_kinds`` the two attention kinds have parameter
+    # stacks, head counts and CACHES of their own (:meth:`cache_kinds`):
+    # window layers have ``window_kv_heads`` K/V heads and keep a window of
+    # cache in a page pool of their own.
+    layer_kinds: Optional[Tuple[int, ...]] = None
+    ffn_kinds: Optional[Tuple[int, ...]] = None
+    window_kv_heads: Optional[int] = None
+    # V heads of a width of their own (None: ``head_dim``)
+    v_head_dim: Optional[int] = None
+    # rotary over the first ``rotary_dim`` dims of a q/k head, the rest
+    # pass through (None: all of ``head_dim``)
+    rotary_dim: Optional[int] = None
+    # v is scaled by this before it is cached
+    attn_value_scale: Optional[float] = None
+    # a learned per-head logit that takes softmax weight and gives no
+    # value, in the window layers / in the full layers
+    sink_window: bool = False
+    sink_full: bool = False
+    # the router's law: "softmax" (softmax over all experts, top-k of the
+    # probabilities, renormalised) or "sigmoid_bias" (sigmoid scores; top-k
+    # of score + a learned selection bias; gates = the chosen SCORES over
+    # their sum: the bias chooses and never weighs)
+    router: str = "softmax"
+    # a chip's share of the experts: the router is ``router_experts`` wide
+    # (None: ``num_experts``), this chip holds experts ``expert_first ..
+    # expert_first + num_experts - 1`` of them, routes over all and
+    # computes the held part (models/moe.py)
+    router_experts: Optional[int] = None
+    expert_first: int = 0
+
+    @property
+    def per_kind(self) -> bool:
+        """Window and full layers with stacks and caches of their own."""
+        return self.layer_kinds is not None
+
+    def layer_window(self, l: int) -> bool:
+        """Layer ``l`` keeps its K/V in the window cache (a Python bool)."""
+        return self.per_kind and self.layer_kinds[l] == 1
+
+    def layer_routed(self, l: int) -> bool:
+        if self.ffn_kinds is not None:
+            return self.ffn_kinds[l] == 1
+        return bool(self.num_experts)
+
+    def kind_layers(self, window: bool) -> Tuple[int, ...]:
+        """The layers of one attention kind of a per-kind model, in order."""
+        return tuple(l for l in range(self.num_layers)
+                     if self.layer_window(l) == window)
+
+    def kv_heads_of(self, window: bool) -> int:
+        return (self.window_kv_heads if window and self.window_kv_heads
+                else self.num_kv_heads)
+
+    @property
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
+
+    @property
+    def k_store_dim(self) -> int:
+        """Width a K row is STORED at: a row that is wider than a 128-lane
+        tile and no multiple of it (192) is zero-padded to the next tile, so
+        that what the program addresses is what the device holds (XLA pads
+        such a minor dimension in HBM anyway) and the kernels contract over
+        whole tiles. Rows of up to a tile, or of whole tiles, as they are."""
+        d = self.head_dim
+        return d if d <= 128 or d % 128 == 0 else -(-d // 128) * 128
+
+    @property
+    def routed_layers(self) -> int:
+        return sum(self.layer_routed(l) for l in range(self.num_layers))
 
     def layer_sliding(self, layer):
         """Every ``sliding_pattern``-th layer is full attention, the rest
@@ -174,7 +249,11 @@ class LlamaConfig:
         five sliding then one full). ``layer`` is the GLOBAL layer index: a
         Python integer gives a Python bool; a traced one (a pipeline stage's
         offset + local index) gives a traced bool, or the Python False of a
-        model that has no window. :func:`pick` selects by either."""
+        model that has no window. :func:`pick` selects by either. A model
+        with a per-layer list (``layer_kinds``) reads it (Python integers
+        only: such a model refuses the staged forward)."""
+        if self.layer_kinds is not None:
+            return self.layer_kinds[layer] == 1
         return (self.sliding_window is not None
                 and (layer + 1) % self.sliding_pattern != 0)
 
@@ -233,7 +312,8 @@ class LlamaConfig:
             intermediate_size=cfg["intermediate_size"],
             rope_theta=cfg.get("rope_theta", 10000.0),
             rope_scaling=cfg.get("rope_scaling"),
-            rms_eps=cfg.get("rms_norm_eps", 1e-5),
+            rms_eps=cfg.get("rms_norm_eps", cfg.get("layernorm_epsilon",
+                                                    1e-5)),
             max_position=cfg.get("max_position_embeddings", 8192),
             tie_embeddings=cfg.get("tie_word_embeddings", False),
             # Qwen2 has qkv bias baked into the architecture; HF encodes it
@@ -250,17 +330,20 @@ class LlamaConfig:
             final_logit_softcap=(cfg.get("final_logit_softcapping")
                                  if _is_gemma2(cfg) else None),
             sliding_window=(cfg.get("sliding_window")
-                            if _is_gemma2(cfg) or _is_gemma3(cfg) else None),
+                            if _is_gemma2(cfg) or _is_gemma3(cfg)
+                            or "hybrid_layer_pattern" in cfg else None),
             query_pre_attn_scalar=(cfg.get("query_pre_attn_scalar")
                                    if _is_gemma2(cfg) or _is_gemma3(cfg)
                                    else None),
             sliding_pattern=_sliding_pattern(cfg),
             rope_local_theta=(cfg.get("rope_local_base_freq", 10000.0)
-                              if _is_gemma3(cfg) else None),
+                              if _is_gemma3(cfg)
+                              else cfg.get("swa_rope_theta")),
             qk_norm=_is_gemma3(cfg) or _is_qwen3_family(cfg),
             dtype=dtype,
             **_map_experts(cfg),
             **_map_indexer(cfg),
+            **_map_layer_kinds(cfg),
         )
 
 
@@ -272,7 +355,14 @@ _QWEN3_MODEL_TYPES = ("qwen3", "qwen3_moe", "KeyeVL2")
 # router keys that say nothing of the forward pass
 _EXPERT_KEYS = ("num_experts", "num_local_experts", "num_experts_per_tok",
                 "moe_intermediate_size", "norm_topk_prob",
-                "decoder_sparse_step", "mlp_only_layers")
+                "decoder_sparse_step", "mlp_only_layers",
+                # the DeepSeek-V3 family's spelling (MiMo-V2-Flash)
+                "n_routed_experts", "n_shared_experts", "scoring_func",
+                "topk_method", "n_group", "topk_group",
+                "routed_scaling_factor", "moe_layer_freq",
+                # a chip's share of the experts (not a published key: a
+                # deployment's, see _map_experts)
+                "expert_shard")
 _EXPERT_KEYS_IGNORED = ("router_aux_loss_coef", "output_router_logits",
                         "router_jitter_noise")
 _INDEXER_KEYS = ("indexer_num_heads", "indexer_head_dim",
@@ -288,7 +378,8 @@ def _is_qwen3_family(cfg: Dict[str, Any]) -> bool:
 def _looks_like_expert_key(k: str) -> bool:
     return ("expert" in k or k.startswith("moe_") or "router" in k
             or k in ("norm_topk_prob", "decoder_sparse_step",
-                     "mlp_only_layers"))
+                     "mlp_only_layers", "scoring_func", "topk_method",
+                     "n_group", "topk_group", "routed_scaling_factor"))
 
 
 def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
@@ -305,17 +396,19 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
             f"config carries expert keys this engine does not implement: "
             f"{sorted(unknown)} (known: {', '.join(_EXPERT_KEYS)}); refusing "
             f"to serve a sparse model as a dense one")
-    E = cfg.get("num_experts", cfg.get("num_local_experts"))
+    E = cfg.get("num_experts", cfg.get("num_local_experts",
+                                       cfg.get("n_routed_experts")))
     if not E:
         raise ValueError(f"expert keys {sorted(seen)} without num_experts / "
-                         f"num_local_experts")
+                         f"num_local_experts / n_routed_experts")
     if cfg.get("num_local_experts", E) != E:
         raise ValueError(f"num_experts {E} != num_local_experts "
                          f"{cfg['num_local_experts']}")
     if "num_experts_per_tok" not in cfg:
         raise ValueError("num_experts without num_experts_per_tok")
-    if "num_experts" in cfg and "norm_topk_prob" not in cfg:
-        # the Qwen-MoE family's class default is False
+    if "norm_topk_prob" not in cfg and ("num_experts" in cfg
+                                        or "n_routed_experts" in cfg):
+        # the Qwen-MoE and DeepSeek families' class default is False
         raise ValueError("num_experts without norm_topk_prob: the family's "
                          "default is false, which this engine does not "
                          "implement")
@@ -325,16 +418,141 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
                          "(models/moe.route_topk)")
     if cfg.get("mlp_only_layers"):
         raise ValueError(f"mlp_only_layers {cfg['mlp_only_layers']} is not "
-                         f"implemented: every layer is a routed-expert layer")
+                         f"implemented: the leading dense layers of a model "
+                         f"are named by moe_layer_freq")
     if cfg.get("decoder_sparse_step", 1) != 1:
         raise ValueError(f"decoder_sparse_step "
                          f"{cfg['decoder_sparse_step']} is not implemented: "
                          f"every layer is a routed-expert layer")
-    return {"num_experts": int(E),
-            "experts_per_token": int(cfg["num_experts_per_tok"]),
-            "moe_intermediate_size": (
-                int(cfg["moe_intermediate_size"])
-                if cfg.get("moe_intermediate_size") else None)}
+    if cfg.get("n_shared_experts"):
+        raise ValueError(f"n_shared_experts {cfg['n_shared_experts']} is "
+                         f"not implemented: no expert that every token "
+                         f"passes through")
+    for k in ("n_group", "topk_group"):
+        if cfg.get(k) not in (None, 1):
+            raise ValueError(f"{k} {cfg[k]} is not implemented: the experts "
+                             f"are chosen among all, not by group")
+    if cfg.get("routed_scaling_factor") not in (None, 1, 1.0):
+        raise ValueError(f"routed_scaling_factor "
+                         f"{cfg['routed_scaling_factor']} is not implemented")
+    law = (cfg.get("scoring_func", "softmax"),
+           cfg.get("topk_method", "greedy"))
+    routers = {("softmax", "greedy"): "softmax",
+               ("sigmoid", "noaux_tc"): "sigmoid_bias"}
+    if law not in routers:
+        raise ValueError(
+            f"scoring_func {law[0]!r} with topk_method {law[1]!r} is not "
+            f"implemented (softmax with greedy; sigmoid with noaux_tc)")
+    out = {"num_experts": int(E),
+           "experts_per_token": int(cfg["num_experts_per_tok"]),
+           "moe_intermediate_size": (
+               int(cfg["moe_intermediate_size"])
+               if cfg.get("moe_intermediate_size") else None),
+           "router": routers[law]}
+    freq = cfg.get("moe_layer_freq")
+    if freq is not None:
+        L = cfg["num_hidden_layers"]
+        if (not isinstance(freq, (list, tuple)) or len(freq) < L
+                or any(f not in (0, 1) for f in freq)):
+            raise ValueError(
+                f"moe_layer_freq must list 0 (dense) or 1 (routed) for "
+                f"each of the {L} layers, got {freq!r}")
+        if not any(freq[:L]):
+            raise ValueError("moe_layer_freq names no routed layer")
+        if not all(freq[:L]):
+            out["ffn_kinds"] = tuple(int(f) for f in freq[:L])
+    shard = cfg.get("expert_shard")
+    if shard is not None:
+        # a chip's share of a deployment's experts: the published router
+        # width, and where this chip's ``n_routed_experts`` begin
+        unknown = sorted(set(shard) - {"router_experts", "first_expert"})
+        if unknown or "router_experts" not in shard:
+            raise ValueError(f"expert_shard takes router_experts and "
+                             f"first_expert, got {sorted(shard)}")
+        R, first = int(shard["router_experts"]), int(
+            shard.get("first_expert", 0))
+        if not 0 <= first <= R - E:
+            raise ValueError(f"expert_shard: experts {first} .. "
+                             f"{first + E - 1} are not among {R}")
+        out.update(router_experts=R, expert_first=first)
+    return out
+
+
+# the keys of a model whose window and full layers differ in more than
+# their mask (MiMo-V2-Flash), and the ones that say the same thing twice
+_LAYER_KIND_KEYS = ("hybrid_layer_pattern", "swa_num_key_value_heads",
+                    "swa_num_attention_heads", "swa_head_dim",
+                    "swa_v_head_dim", "swa_rope_theta",
+                    "add_swa_attention_sink_bias",
+                    "add_full_attention_sink_bias", "sliding_window_size",
+                    "attention_chunk_size")
+
+
+def _map_layer_kinds(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """Per-layer attention kinds and what comes with them -> ours; the
+    three keys any family may carry (``v_head_dim``,
+    ``partial_rotary_factor``, ``attention_value_scale``) too. A value this
+    engine cannot honour RAISES."""
+    out: Dict[str, Any] = {}
+    Dh = cfg.get("head_dim",
+                 cfg["hidden_size"] // cfg["num_attention_heads"])
+    if cfg.get("v_head_dim") not in (None, Dh):
+        out["v_head_dim"] = int(cfg["v_head_dim"])
+    f = cfg.get("partial_rotary_factor")
+    if f is not None and f != 1:
+        rot = int(f * Dh)
+        rot -= rot % 2
+        if rot <= 0:
+            raise ValueError(f"partial_rotary_factor {f} of head_dim {Dh} "
+                             f"leaves nothing to rotate")
+        out["rotary_dim"] = rot
+    if cfg.get("attention_value_scale") not in (None, 1, 1.0):
+        out["attn_value_scale"] = float(cfg["attention_value_scale"])
+    pattern = cfg.get("hybrid_layer_pattern")
+    if pattern is None:
+        stray = [k for k in _LAYER_KIND_KEYS if cfg.get(k)]
+        if stray and not (_is_gemma(cfg) or cfg.get("layer_types")):
+            raise ValueError(f"config carries {stray} without "
+                             f"hybrid_layer_pattern: refusing to guess "
+                             f"which layers they describe")
+        return out
+    L = cfg["num_hidden_layers"]
+    if (not isinstance(pattern, (list, tuple)) or len(pattern) < L
+            or any(p not in (0, 1) for p in pattern)):
+        raise ValueError(f"hybrid_layer_pattern must list 0 (full) or 1 "
+                         f"(window) for each of the {L} layers, got "
+                         f"{pattern!r}")
+    kinds = tuple(int(p) for p in pattern[:L])
+    if not 0 < sum(kinds) < L:
+        raise ValueError("hybrid_layer_pattern needs layers of both kinds "
+                         "among the served ones (a model of one kind is the "
+                         "one-law description)")
+    W = cfg.get("sliding_window")
+    if not W or cfg.get("sliding_window_size", W) != W:
+        raise ValueError(f"hybrid_layer_pattern needs ONE sliding_window "
+                         f"(got {W!r} / {cfg.get('sliding_window_size')!r})")
+    same = {"swa_num_attention_heads": cfg["num_attention_heads"],
+            "swa_head_dim": Dh, "swa_v_head_dim": cfg.get("v_head_dim", Dh)}
+    for k, want in same.items():
+        if cfg.get(k, want) != want:
+            raise ValueError(f"{k} {cfg[k]} differs from the full layers' "
+                             f"{want}: only the K/V head COUNT may differ "
+                             f"between the two kinds")
+    if cfg.get("rope_scaling") and (cfg["rope_scaling"].get(
+            "rope_type", cfg["rope_scaling"].get("type", "default"))
+            != "default"):
+        raise ValueError("scaled rotary with per-kind layers is not "
+                         "implemented")
+    # attention_chunk_size tiles the window layers' computation (it equals
+    # the window) and masks nothing: accepted, not read
+    out.update(
+        layer_kinds=kinds,
+        window_kv_heads=int(cfg.get("swa_num_key_value_heads",
+                                    cfg.get("num_key_value_heads",
+                                            cfg["num_attention_heads"]))),
+        sink_window=bool(cfg.get("add_swa_attention_sink_bias", False)),
+        sink_full=bool(cfg.get("add_full_attention_sink_bias", False)))
+    return out
 
 
 def _map_indexer(cfg: Dict[str, Any]) -> Dict[str, Any]:
@@ -589,6 +807,8 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
       the k-th score, and the heavier single keys weigh, the more of the
       sound runs' noise that is (1.5 with N(0, 1 / L) around it read twice
       the noise of 1.4 flat)."""
+    if cfg.per_kind:
+        return _init_per_kind(cfg, key)
     D, Hq, Hkv, Dh, F, L, V = (cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads,
                                cfg.head_dim, cfg.intermediate_size,
                                cfg.num_layers, cfg.vocab_size)
@@ -644,9 +864,10 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             "ln2": jnp.ones((L, D), jnp.float32),
             "wq": stack(ks[1], D, D, Hq * Dh).reshape(L, D, Hq, Dh),
             "wk": stack(ks[2], D, D, Hkv * Dh).reshape(L, D, Hkv, Dh),
-            "wv": stack(ks[3], D, D, Hkv * Dh).reshape(L, D, Hkv, Dh),
-            "wo": stack(ks[4], Hq * Dh, Hq * Dh, D, to_residual=True
-                        ).reshape(L, Hq, Dh, D),
+            "wv": stack(ks[3], D, D, Hkv * cfg.v_dim).reshape(
+                L, D, Hkv, cfg.v_dim),
+            "wo": stack(ks[4], Hq * cfg.v_dim, Hq * cfg.v_dim, D,
+                        to_residual=True).reshape(L, Hq, cfg.v_dim, D),
             **ffn,
         },
         "final_norm": jnp.ones((D,), jnp.float32),
@@ -686,6 +907,105 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+STACKS = "stacks"       # params[STACKS][kind]: a per-kind model's layers
+
+
+def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
+    """Seeded weights of a model whose layers are described one by one
+    (``cfg.per_kind``): no ``layers`` tree but ``stacks``, one stack of
+    stacked tensors a kind, each layer at its index among its kind
+    (:func:`layer_stacks`): ``full`` and ``window`` (the attention of a
+    layer: ln1, wq, wk, wv, wo, and ``sink`` [n, Hq] float32 where the kind
+    has one) and ``dense`` and ``routed`` (its feed-forward: ln2, wg, wu,
+    wd, and the router ``wr`` [n, D, R] with its selection bias ``rbias``
+    [n, R] float32).
+
+    The law is the second of :func:`init_params` (every activation of unit
+    rms) with two seeded terms of this family, each of a size that shows in
+    the logits when it is left out: sink logits 4 + N(0, 1) (a window's 128
+    keys of score spread 1 sum to e^0.5 x 128 = 211; e^4 = 55 takes a fifth
+    of a full window's weight and most of a short one's) and a selection
+    bias N(0, 0.02^2) beside sigmoid scores of spread 0.2. The 8th and 9th
+    of 256 scores lie 0.007 apart in the mean, so that bias still reorders
+    the top-k of most tokens, and it leaves the experts' loads within a
+    factor 1.5 of each other, as a trained ``noaux_tc`` bias exists to do
+    (N(0, 0.1^2), this PR's first choice, gave a held expert between a
+    hundredth and four times its even load: how many of a chip's experts a
+    step hit, and so the step's time, then swung with the seed). The held
+    experts keep the scale every expert has: what a chip's share computes
+    is a partial sum of the layer, E / R of it in variance, and goes into
+    the stream as that."""
+    D, Hq, Dh, Dv, F, L, V = (cfg.hidden_size, cfg.num_heads, cfg.head_dim,
+                              cfg.v_dim, cfg.intermediate_size,
+                              cfg.num_layers, cfg.vocab_size)
+    E, Fe, R = cfg.num_experts, cfg.expert_width, (cfg.router_experts
+                                                   or cfg.num_experts)
+    ks = iter(jax.random.split(key, 32))
+
+    def mat(n, fan_in, *shape, scale=1.0):
+        w = jax.random.normal(next(ks), (n, *shape), jnp.float32)
+        return (w * (scale / math.sqrt(fan_in))).astype(cfg.dtype)
+
+    def experts(n, fan_in, *shape, scale=1.0):
+        # a layer at a time, cast inside the program (init_params)
+        sc = scale / math.sqrt(fan_in)
+
+        def one(kl):
+            return (jax.random.normal(kl, (E, *shape), jnp.float32)
+                    * sc).astype(cfg.dtype)
+        return jax.jit(lambda k: jax.lax.map(one, jax.random.split(k, n))
+                       )(next(ks))
+
+    res = 1.0 / math.sqrt(2 * L)       # projections into the stream
+    stacks: Dict[str, Any] = {}
+    for name, window in (("full", False), ("window", True)):
+        n, Hkv = len(cfg.kind_layers(window)), cfg.kv_heads_of(window)
+        st = {"ln1": jnp.ones((n, D), jnp.float32),
+              "wq": mat(n, D, D, Hq, Dh), "wk": mat(n, D, D, Hkv, Dh),
+              "wv": mat(n, D, D, Hkv, Dv),
+              "wo": mat(n, Hq * Dv, Hq, Dv, D, scale=res)}
+        if cfg.sink_window if window else cfg.sink_full:
+            st["sink"] = 4.0 + jax.random.normal(next(ks), (n, Hq),
+                                                 jnp.float32)
+        stacks[name] = st
+    nd = sum(not cfg.layer_routed(l) for l in range(L))
+    nr = L - nd
+    if nd:
+        stacks["dense"] = {"ln2": jnp.ones((nd, D), jnp.float32),
+                           "wg": mat(nd, D, D, F), "wu": mat(nd, D, D, F),
+                           "wd": mat(nd, F, F, D, scale=res)}
+    if nr:
+        st = {"ln2": jnp.ones((nr, D), jnp.float32),
+              "wr": mat(nr, D, D, R),
+              "wg": experts(nr, D, D, Fe), "wu": experts(nr, D, D, Fe),
+              "wd": experts(nr, Fe, Fe, D, scale=res)}
+        if cfg.router == "sigmoid_bias":
+            st["rbias"] = 0.02 * jax.random.normal(next(ks), (nr, R),
+                                                   jnp.float32)
+        stacks["routed"] = st
+    params = {"embed": jax.random.normal(next(ks), (V, D), jnp.float32
+                                         ).astype(cfg.dtype),
+              STACKS: stacks,
+              "final_norm": jnp.ones((D,), jnp.float32)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = mat(D, D, V)
+    return params
+
+
+def layer_stacks(params: Dict[str, Any], cfg: LlamaConfig, l: int):
+    """-> (attention stack, index in it, feed-forward stack, index in it)
+    of layer ``l``: the one ``layers`` tree at ``l`` twice for a model of
+    one law, its kinds' stacks for a per-kind model."""
+    if not cfg.per_kind:
+        return params["layers"], l, params["layers"], l
+    st = params[STACKS]
+    win, routed = cfg.layer_window(l), cfg.layer_routed(l)
+    la = sum(cfg.layer_window(i) == win for i in range(l))
+    lf = sum(cfg.layer_routed(i) == routed for i in range(l))
+    return (st["window" if win else "full"], la,
+            st["routed" if routed else "dense"], lf)
+
+
 def param_specs(cfg: LlamaConfig, tp_size: int = 1,
                 pp: int = 1) -> Dict[str, Any]:
     """PartitionSpecs: tp shards attention heads, the ffn dimension, and —
@@ -696,6 +1016,11 @@ def param_specs(cfg: LlamaConfig, tp_size: int = 1,
     over the pipeline axis (each stage materializes only its layers)."""
     from ..parallel.mesh import AXIS_EP, AXIS_PP
 
+    if cfg.per_kind:
+        # one chip (validate_tp): every tensor replicated, whatever its rank
+        shapes = jax.eval_shape(partial(_init_per_kind, cfg),
+                                jax.random.PRNGKey(0))
+        return jax.tree.map(lambda a: P(*(None,) * a.ndim), shapes)
     st = AXIS_PP if pp > 1 else None     # the [L, ...] stack dim
     tp = AXIS_TP
     kv = tp if cfg.num_kv_heads % max(tp_size, 1) == 0 else None
@@ -759,6 +1084,11 @@ def validate_tp(cfg: LlamaConfig, tp: int, ep: int = 1) -> None:
         raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp={tp}")
     if not cfg.num_experts and cfg.intermediate_size % tp:
         raise ValueError(f"ffn {cfg.intermediate_size} not divisible by tp={tp}")
+    if cfg.per_kind and (tp > 1 or ep > 1):
+        raise ValueError(
+            "a model with window and full layers of their own head counts "
+            "and caches runs on one chip: its stacks and its two page pools "
+            f"are not sharded (got tp={tp}, ep={ep})")
     if cfg.has_indexer and (tp > 1 or ep > 1):
         raise ValueError(
             "a model with an indexer (learned top-k attention) runs on one "
@@ -776,6 +1106,8 @@ def validate_pp(cfg: LlamaConfig, pp: int, tp: int = 1) -> None:
     """Pipeline-parallel constraints for the staged serving path."""
     if pp <= 1:
         return
+    if cfg.per_kind:
+        raise ValueError(f"pp={pp}: {NO_SECOND_CACHE}")
     if cfg.num_layers % pp:
         raise ValueError(
             f"num_layers {cfg.num_layers} not divisible by pp={pp}")
@@ -789,12 +1121,15 @@ def kv_block_bytes(cfg: LlamaConfig, page_size: int) -> int:
     """Bytes of one KV block (k+v, and the index keys of a model with an
     indexer, all layers) at device precision — the
     ONE unit the byte-honest planes price in (engine residency gauges,
-    paged-lane admission, router bytes scoring). ml_dtypes registers
-    bfloat16 with numpy, so np.dtype resolves every served precision."""
-    per_token = (2 * cfg.num_kv_heads * cfg.head_dim
-                 + (cfg.index_head_dim if cfg.has_indexer else 0))
-    return (cfg.num_layers * page_size * per_token
-            * np.dtype(cfg.dtype).itemsize)
+    paged-lane admission, router bytes scoring): a page of the GLOBAL cache
+    kind as ``engine/cache.CacheKind`` describes it (every layer of a model
+    of one law; a per-kind model's full layers, whose window cache is its
+    second kind's ``token_bytes``). ml_dtypes registers bfloat16 with numpy,
+    so np.dtype resolves every served precision."""
+    from ..engine.cache import cache_kinds
+
+    return page_size * cache_kinds(cfg)[0].token_bytes(
+        np.dtype(cfg.dtype).itemsize)
 
 
 def kv_cache_spec(cfg: LlamaConfig, tp: int, pp: int = 1) -> P:
@@ -805,6 +1140,8 @@ def kv_cache_spec(cfg: LlamaConfig, tp: int, pp: int = 1) -> P:
     from ..parallel.mesh import AXIS_PP
 
     st = AXIS_PP if pp > 1 else None
+    if cfg.per_kind:
+        return P(None, None, None, None, None)    # one chip (validate_tp)
     if cfg.num_kv_heads % tp == 0:
         return P(st, AXIS_TP, None, None, None)
     return P(st, None, None, None, None)
@@ -844,7 +1181,7 @@ def _embed(params: Dict[str, Any], cfg: "LlamaConfig",
 
 
 def _rope_inv_freq(cfg: LlamaConfig, local: bool = False) -> np.ndarray:
-    Dh = cfg.head_dim
+    Dh = cfg.rotary_dim or cfg.head_dim
     if local:
         # gemma3 sliding layers: own base frequency, NO scaling (HF builds
         # the local rotary with default rope_type regardless of
@@ -900,7 +1237,12 @@ def rope_pair(cfg: LlamaConfig, positions: jax.Array):
 
 
 def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x: [..., H, Dh]; cos/sin: [..., Dh/2] (broadcast over H)."""
+    """x: [..., H, Dh]; cos/sin: [..., Dr/2] (broadcast over H). Tables
+    narrower than the head rotate its first Dr dims and pass the rest."""
+    Dr = 2 * cos.shape[-1]
+    if Dr < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :Dr], cos, sin), x[..., Dr:]], axis=-1)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     c = cos[..., None, :]
     s = sin[..., None, :]
@@ -912,10 +1254,13 @@ NEG_INF = -1e30
 
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
            scale: Optional[float] = None,
-           softcap: Optional[float] = None) -> jax.Array:
-    """GQA attention. q: [B,T,Hq,Dh]; k,v: [B,S,Hkv,Dh]; mask: [B,T,S] bool
-    (True = attend). Returns [B,T,Hq,Dh]. fp32 softmax. ``softcap`` applies
-    Gemma2's tanh capping to the scores BEFORE masking (HF order)."""
+           softcap: Optional[float] = None,
+           sink: Optional[jax.Array] = None) -> jax.Array:
+    """GQA attention. q: [B,T,Hq,Dh]; k: [B,S,Hkv,Dh]; v: [B,S,Hkv,Dv];
+    mask: [B,T,S] bool (True = attend). Returns [B,T,Hq,Dv]. fp32 softmax.
+    ``softcap`` applies Gemma2's tanh capping to the scores BEFORE masking
+    (HF order). ``sink`` [Hq] float32: a logit a head that takes softmax
+    weight and gives no value (one more key whose value is zero)."""
     B, T, Hq, Dh = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -926,18 +1271,27 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, mask: jax.Array,
     if softcap:
         scores = jnp.tanh(scores / softcap) * softcap
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
+    if sink is None:
+        w = jax.nn.softmax(scores, axis=-1)
+    else:
+        col = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, Hkv, G, 1, 1),
+            (*scores.shape[:-1], 1))
+        w = jax.nn.softmax(jnp.concatenate([scores, col], -1),
+                           axis=-1)[..., :-1]
     out = jnp.einsum("bhgts,bshd->bthgd", w.astype(v.dtype), v)
-    return out.reshape(B, T, Hq, Dh)
+    return out.reshape(B, T, Hq, v.shape[-1])
 
 
 def attend_ctx(cfg: LlamaConfig, q: jax.Array, k_ctx: jax.Array,
                v_ctx: jax.Array, mask: jax.Array,
-               keep: Optional[jax.Array] = None) -> jax.Array:
+               keep: Optional[jax.Array] = None,
+               sink: Optional[jax.Array] = None) -> jax.Array:
     """:func:`attend` over a gathered context with the model's scale and
     softcap; ``keep`` (an indexer's selection) narrows the mask."""
     return attend(q, k_ctx, v_ctx, mask if keep is None else mask & keep,
-                  scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap)
+                  scale=cfg.attn_scale, softcap=cfg.attn_logit_softcap,
+                  sink=sink)
 
 
 def _lm_head(x: jax.Array, params: Dict[str, Any],
@@ -1123,6 +1477,12 @@ def _index_step(h: jax.Array, lp: Dict[str, Any], l: int, cfg: LlamaConfig,
 
 # the one refusal of a model with an indexer by a forward whose cache I/O
 # was never given the third pool (forward_pp, the pager: ROADMAP Design 4)
+# ... and of a per-kind model (window and full layers with caches of their
+# own) by a forward that was given one cache (forward_pp, the pager, verify)
+NO_SECOND_CACHE = ("this path carries one K/V cache: a model whose window "
+                   "layers keep a window of cache in a page pool of their "
+                   "own, with their own head count, needs both")
+
 NO_INDEX_KEYS = ("this cache carries no index-key pool: a model with an "
                  "indexer (learned top-k attention) writes its index keys "
                  "beside K/V and selects from them")
@@ -1171,7 +1531,15 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         k = rms_norm(k, lp["ln_k"][l], cfg.rms_eps, cfg.norm_offset)
     q = apply_rope(q, *rope)
     k = apply_rope(k, *rope)
+    if cfg.attn_value_scale:
+        v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
     k_pool, v_pool, *i_pool = pools
+    pad = k_pool.shape[-1] - k.shape[-1]
+    if pad:
+        # K rows are stored a whole number of lane tiles wide
+        # (LlamaConfig.k_store_dim); zeros beyond head_dim add nothing to
+        # a score, and q goes to attention as wide as the rows it meets
+        q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),)) for a in (q, k))
     # write, then attend: the new rows are part of their own context
     k_pool = kv_write(k_pool, l, w_page, w_off, k.reshape(-1, *k.shape[2:]),
                       mode)
@@ -1192,7 +1560,9 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
 def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
               cfg: LlamaConfig, mesh=None,
               stats: Optional[Dict[str, Any]] = None,
-              inside: Optional[Dict[str, int]] = None) -> jax.Array:
+              inside: Optional[Dict[str, int]] = None,
+              ffn: Optional[Tuple[Dict[str, Any], Any]] = None
+              ) -> jax.Array:
     """The layer after attention: out-projection of ``attn`` [B,T,Hq,Dh] and
     residual (Gemma2 norms the branch output first), then the feed-forward.
 
@@ -1200,13 +1570,15 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
     stage body; shard_maps do not nest) names the mesh axes it is inside of
     with their sizes (> 1); ``lp`` is then this shard's slice, the two
     contractions over the sharded dimension leave partial sums, and they are
-    reduced here. Without it the reductions are GSPMD's."""
+    reduced here. Without it the reductions are GSPMD's. ``ffn``: the
+    feed-forward's (stack, index) where it is not (``lp``, ``l``)
+    (:func:`layer_stacks`)."""
     o = jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l])
     if inside and AXIS_TP in inside:
         o = jax.lax.psum(o, AXIS_TP)
     if cfg.sandwich_norms:
         o = rms_norm(o, lp["ln1_post"][l], cfg.rms_eps, cfg.norm_offset)
-    return _ffn_block(x + o, lp, l, cfg, mesh=mesh, stats=stats,
+    return _ffn_block(x + o, *(ffn or (lp, l)), cfg, mesh=mesh, stats=stats,
                       inside=inside)
 
 
@@ -1218,7 +1590,8 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     to ``stats["experts_hit"]`` (see :func:`forward`). ``inside`` as
     :func:`layer_out`'s."""
     h2 = rms_norm(x, lp["ln2"][l], cfg.rms_eps, cfg.norm_offset)
-    if cfg.num_experts and inside is not None:
+    routed = cfg.num_experts and "wr" in lp   # a per-kind model's dense layers
+    if routed and inside is not None:
         # router replicated, experts sharded over ep and their width over tp
         # where it divides (param_specs): dense dispatch of this shard's
         # experts, one psum over both axes
@@ -1230,11 +1603,19 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
                                lp["wd"][l], cfg.experts_per_token,
                                ep=inside.get(AXIS_EP, 1),
                                psum_axes=tuple(axes))
-    elif cfg.num_experts:
+    elif routed:
         from .moe import moe_ffn
+        law = {}
+        if cfg.router != "softmax" or cfg.router_experts:
+            law = {"router": cfg.router, "first": cfg.expert_first,
+                   "bias": lp["rbias"][l] if "rbias" in lp else None}
         out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
-                           cfg.experts_per_token, mesh=mesh, layer=l)
+                           cfg.experts_per_token, mesh=mesh, layer=l, **law)
         if stats is not None:
+            if cfg.router_experts:
+                # (experts hit, assignments to held experts) of this call
+                hit, held = hit
+                stats["held"] = stats.get("held", 0) + held
             stats["experts_hit"] = stats.get("experts_hit", 0) + hit
             if "chosen" in stats:
                 stats["chosen"].append(chosen)
@@ -1270,6 +1651,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             read_pages: Optional[jax.Array] = None,  # [B, S // page] int32
             i_pool: Optional[jax.Array] = None,  # index keys (has_indexer)
             stats: Optional[Dict[str, Any]] = None,
+            win: Optional[Tuple[jax.Array, ...]] = None,  # window cache
             ) -> Tuple[jax.Array, ...]:
     """One forward pass over a token chunk against the paged KV pool.
 
@@ -1306,6 +1688,16 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     chosen expert ids ([B,T,K]) appended: what the tests compare with the
     reference's own.
 
+    A per-kind model (``cfg.per_kind``) keeps its FULL layers' K/V in
+    ``k_pool`` / ``v_pool`` ([full layers, Hkv, ...]), addressed as above,
+    and its WINDOW layers' in a second pair of pools of their own pages:
+    ``win`` = (wk_pool, wv_pool [window layers, window Hkv, pages, page, ·],
+    w_write_idx [B, T] token slots of the new tokens there, w_read_pages
+    [B, Pw] the pages that hold each lane's last ``sliding_window - 1``
+    tokens and the chunk, in order, w_read_pos [B, Pw * page] the position
+    of each of their slots, w_read_valid likewise). The two pools come back
+    behind the others. Context is read by page for both kinds.
+
     Multimodal (Gemma3 VLM, xla attention only):
 
     - ``embed_override`` = (vals [B,T,D], mask [B,T] bool) replaces the
@@ -1318,7 +1710,6 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
       (modeling_gemma3.py:936-953).
     """
     page = k_pool.shape[3]
-    lp = params["layers"]
     x = _embed(params, cfg, tokens)  # [B,T,D] bf16
     if embed_override is not None:
         ov_vals, ov_mask = embed_override
@@ -1366,7 +1757,9 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                                   P(None, None)),
                         out_specs=P(None, None, AXIS_TP, None),
                         check_vma=False)   # pallas_call can't declare vma
-                _flash_cache[w] = fn
+                # traced once a variant and program, inlined at every layer
+                # (forward_decode's paged_for says why)
+                _flash_cache[w] = jax.jit(fn, inline=True)
             return _flash_cache[w]
     else:
         # causal/validity mask [B,T,S]
@@ -1392,6 +1785,19 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             "attn_impl='xla' only; flash/ring kernels take no span inputs")
     _require_xla_attn(cfg, attn_impl)
     pools, index = (k_pool, v_pool), None
+    w_pools = ()
+    if cfg.per_kind:
+        if win is None or read_pages is None or attn_impl == "ring":
+            raise ValueError(f"forward: {NO_SECOND_CACHE}")
+        wk_pool, wv_pool, w_write, w_pages, w_pos, w_valid = win
+        w_pools = (wk_pool, wv_pool)
+        flat_ww = w_write.reshape(-1)
+        wwp, wwo = flat_ww // page, flat_ww % page
+        if attn_impl == "xla":
+            w_mask = (w_valid[:, None, :]
+                      & (w_pos[:, None, :] <= positions[:, :, None])
+                      & (w_pos[:, None, :]
+                         > positions[:, :, None] - cfg.sliding_window))
     if cfg.has_indexer and i_pool is not None:
         if read_pages is None:
             raise ValueError("a model with an indexer reads its context by "
@@ -1408,34 +1814,77 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
         pools, index = (k_pool, v_pool, i_pool), (rope_i, read_pages, visible)
 
     for l in range(cfg.num_layers):
+        lp, la, *ffn = layer_stacks(params, cfg, l)
         sl = cfg.layer_sliding(l)
-        q, pools, keep = layer_in(x, lp, l, cfg, pick(sl, rope_sl, rope),
+        if cfg.layer_window(l):
+            # a window layer of a per-kind model: its own pools, slots and
+            # the short context that holds the window
+            x, w_pools = _window_layer(
+                x, lp, la, ffn, cfg, pick(sl, rope_sl, rope), w_pools,
+                wwp, wwo, w_pages, positions, w_pos, w_valid,
+                flash_for(l) if attn_impl == "flash" else w_mask,
+                mesh, stats)
+            continue
+        q, pools, keep = layer_in(x, lp, la, cfg, pick(sl, rope_sl, rope),
                                   pools, wp, wo, index=index, stats=stats)
         # gather this sequence's context: [B, S, Hkv, Dh]
         if read_pages is not None:
-            k_ctx = kv_pages(pools[0], l, read_pages)
-            v_ctx = kv_pages(pools[1], l, read_pages)
+            k_ctx = kv_pages(pools[0], la, read_pages)
+            v_ctx = kv_pages(pools[1], la, read_pages)
         else:
-            k_ctx = kv_rows(pools[0], l, rp, ro)
-            v_ctx = kv_rows(pools[1], l, rp, ro)
-        if attn_impl == "flash":
-            attn = flash_for(l)(q, k_ctx, v_ctx, positions, read_pos,
-                                read_valid,
-                                **({} if keep is None else {"keep": keep}))
-        elif attn_impl == "ring":
-            attn = ring_attention(q, k_ctx, v_ctx, positions, read_pos,
-                                  read_valid, mesh=mesh,
-                                  head_axis=head_axis,
-                                  scale=cfg.attn_scale)
-        else:
-            attn = attend_ctx(cfg, q, k_ctx, v_ctx,
-                              pick(sl, sliding_mask, mask), keep)
-        x = layer_out(x, attn, lp, l, cfg, mesh=mesh, stats=stats)
+            k_ctx = kv_rows(pools[0], la, rp, ro)
+            v_ctx = kv_rows(pools[1], la, rp, ro)
+        extra = {} if keep is None else {"keep": keep}
+        if "sink" in lp:
+            extra["sink"] = lp["sink"][la]
+        with _attn_scope(cfg, False):
+            if attn_impl == "flash":
+                attn = flash_for(l)(q, k_ctx, v_ctx, positions, read_pos,
+                                    read_valid, **extra)
+            elif attn_impl == "ring":
+                attn = ring_attention(q, k_ctx, v_ctx, positions, read_pos,
+                                      read_valid, mesh=mesh,
+                                      head_axis=head_axis,
+                                      scale=cfg.attn_scale)
+            else:
+                attn = attend_ctx(cfg, q, k_ctx, v_ctx,
+                                  pick(sl, sliding_mask, mask), **extra)
+        x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
+                      ffn=ffn if cfg.per_kind else None)
 
     if logits_idx is not None:
         x = jnp.take_along_axis(
             x, logits_idx[:, None, None].astype(jnp.int32), axis=1)  # [B,1,D]
-    return (_lm_head(x, params, cfg), *pools)
+    return (_lm_head(x, params, cfg), *pools, *w_pools)
+
+
+def _attn_scope(cfg: LlamaConfig, window: bool):
+    """The trace scope of a per-kind model's attention, by kind
+    (``dynamo.attn_window`` / ``dynamo.attn_full``: the per-layer roofline
+    metrics read the device time under each); nothing for any other model,
+    whose programs stay what they were."""
+    if not cfg.per_kind:
+        return contextlib.nullcontext()
+    return jax.named_scope("dynamo.attn_window" if window
+                           else "dynamo.attn_full")
+
+
+def _window_layer(x, lp, la, ffn, cfg: LlamaConfig, rope, w_pools, wwp, wwo,
+                  w_pages, q_pos, w_pos, w_valid, attn, mesh, stats):
+    """A window layer of a per-kind model over a prefill chunk: the same
+    layer body around attention over the window cache's short context.
+    ``attn``: the flash kernel of this layer, or the xla path's mask."""
+    q, w_pools, _ = layer_in(x, lp, la, cfg, rope, w_pools, wwp, wwo)
+    k_ctx = kv_pages(w_pools[0], la, w_pages)
+    v_ctx = kv_pages(w_pools[1], la, w_pages)
+    sink = {"sink": lp["sink"][la]} if "sink" in lp else {}
+    with _attn_scope(cfg, True):
+        if callable(attn):
+            a = attn(q, k_ctx, v_ctx, q_pos, w_pos, w_valid, **sink)
+        else:
+            a = attend_ctx(cfg, q, k_ctx, v_ctx, attn, **sink)
+    return (layer_out(x, a, lp, la, cfg, mesh=mesh, stats=stats, ffn=ffn),
+            w_pools)
 
 
 def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
@@ -1482,6 +1931,8 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
     _require_xla_attn(cfg, attn_impl)
     if cfg.has_indexer:
         raise ValueError(f"forward_pp: {NO_INDEX_KEYS}")
+    if cfg.per_kind:
+        raise ValueError(f"forward_pp: {NO_SECOND_CACHE}")
     if pp == 1:
         outs = []
         li = None
@@ -1719,6 +2170,7 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                    mesh=None,                # for pallas at tp>1 (shard_map)
                    i_pool: Optional[jax.Array] = None,
                    stats: Optional[Dict[str, Any]] = None,
+                   win: Optional[Tuple[jax.Array, ...]] = None,
                    ) -> Tuple[jax.Array, ...]:
     """Single-token decode step addressed purely by page tables.
 
@@ -1731,9 +2183,15 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     ``stats`` as in :func:`forward` (the index keys come back as a fourth
     result). The selection goes INTO the paged kernel as a keep mask over
     the lane's logical positions.
+
+    A per-kind model's window layers write and read ``win`` = (wk_pool,
+    wv_pool, w_page_tables [B, P]): the window cache's own pools and each
+    lane's page table INTO THEM, as wide as ``page_tables`` and by the same
+    logical pages, of which only those that still hold a key some query can
+    see name a page of the lane's (the rest: scratch page 0, masked). The
+    two pools come back behind the others.
     """
     page = k_pool.shape[3]
-    lp = params["layers"]
     pos = lengths - 1                                  # [B]
     pools, index = (k_pool, v_pool), None
     if cfg.has_indexer and i_pool is not None:
@@ -1748,6 +2206,14 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     w_page = jnp.take_along_axis(page_tables, (pos // page)[:, None],
                                  axis=1)[:, 0]
     w_off = pos % page
+    w_pools = ()
+    if cfg.per_kind:
+        if win is None:
+            raise ValueError(f"forward_decode: {NO_SECOND_CACHE}")
+        *w_pools, w_tables = win
+        w_pools = tuple(w_pools)
+        ww_page = jnp.take_along_axis(w_tables, (pos // page)[:, None],
+                                      axis=1)[:, 0]
     tp_sz = _tp_size(mesh) if attn_impl == "pallas" else 1
     if attn_impl == "pallas":
         from ..ops.attention import paged_attention as _paged
@@ -1775,7 +2241,11 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                                   P(None, None), P(None), P()),
                         out_specs=P(None, AXIS_TP, None),
                         check_vma=False)   # pallas_call can't declare vma
-                _paged_cache[w] = fn
+                # traced ONCE a variant and program, inlined at every layer:
+                # the layers' equations are what they were, and the kernel's
+                # body (most of a decode program's tracing time) is not
+                # traced again for each of them
+                _paged_cache[w] = jax.jit(fn, inline=True)
             return _paged_cache[w]
     _require_xla_attn(cfg, attn_impl)
     if attn_impl != "pallas":
@@ -1790,21 +2260,37 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                 t[None] > pos[:, None] - cfg.sliding_window)[:, None, :]
 
     for l in range(cfg.num_layers):
+        lp, la, *ffn = layer_stacks(params, cfg, l)
         sl = cfg.layer_sliding(l)
-        q, pools, keep = layer_in(x, lp, l, cfg, pick(sl, rope_sl, rope),
-                                  pools, w_page, w_off, index=index,
-                                  stats=stats)
-        if attn_impl == "pallas":
-            # the kernel reads the whole pool in place, by layer index
-            attn = paged_for(l)(
-                q[:, 0], pools[0], pools[1], page_tables, lengths,
-                jnp.int32(l),
-                **({} if keep is None else {"keep": keep[:, 0]}))[:, None]
+        in_win = cfg.layer_window(l)
+        # a per-kind model's window layers: their own pools and page tables
+        kv, tables, wpg = ((w_pools, w_tables, ww_page) if in_win
+                           else (pools, page_tables, w_page))
+        q, kv, keep = layer_in(x, lp, la, cfg, pick(sl, rope_sl, rope),
+                               kv, wpg, w_off, index=None if in_win else index,
+                               stats=None if in_win else stats)
+        extra = {"sink": lp["sink"][la]} if "sink" in lp else {}
+        with _attn_scope(cfg, in_win):
+            if attn_impl == "pallas":
+                # the kernel reads the whole pool in place, by layer index
+                q0 = q[:, 0]
+                if keep is not None:
+                    extra["keep"] = keep[:, 0]
+                attn = paged_for(l)(
+                    q0, kv[0], kv[1], tables, lengths,
+                    jnp.int32(la), **extra)[:, None]
+            else:
+                k_ctx = kv_pages(kv[0], la, tables)   # [B,S,Hkv,Dh]
+                v_ctx = kv_pages(kv[1], la, tables)
+                if keep is not None:
+                    extra["keep"] = keep
+                attn = attend_ctx(cfg, q, k_ctx, v_ctx,
+                                  pick(sl, sliding_mask, mask), **extra)
+        if in_win:
+            w_pools = kv
         else:
-            k_ctx = kv_pages(pools[0], l, page_tables)   # [B,S,Hkv,Dh]
-            v_ctx = kv_pages(pools[1], l, page_tables)
-            attn = attend_ctx(cfg, q, k_ctx, v_ctx,
-                              pick(sl, sliding_mask, mask), keep)
-        x = layer_out(x, attn, lp, l, cfg, mesh=mesh, stats=stats)
+            pools = kv
+        x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
+                      ffn=ffn if cfg.per_kind else None)
 
-    return (_lm_head(x, params, cfg), *pools)
+    return (_lm_head(x, params, cfg), *pools, *w_pools)

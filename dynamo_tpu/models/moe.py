@@ -32,7 +32,8 @@ from jax.sharding import PartitionSpec as P
 from ..parallel.mesh import AXIS_EP, AXIS_TP
 
 
-def sorted_wins(rows: int, top_k: int, n_experts: int) -> bool:
+def sorted_wins(rows: int, top_k: int, n_experts: int,
+                share: float = 1.0) -> bool:
     """The dispatch rule of an unsharded mesh: SORTED (``lax.ragged_dot``
     over the assignments, only the experts hit are read) or DENSE (every
     expert sees every row).
@@ -49,9 +50,21 @@ def sorted_wins(rows: int, top_k: int, n_experts: int) -> bool:
     OUTSIDE it (few large experts as Mixtral's 8 with 2 a token, where dense
     costs 4 x the multiply-adds of a compute-bound chunk, or more than 512
     rows) nothing is measured, and the rule is the one those models always
-    had: sorted from 16 rows on."""
-    if n_experts >= 16 * top_k and rows <= 512:
-        return rows * top_k < n_experts
+    had: sorted from 16 rows on.
+
+    A chip's SHARE of the experts (``n_experts`` held of ``n_experts /
+    share`` routed over; assignments to absent experts are dropped before
+    dispatch) is the same rule in assignments to held experts, ``rows x
+    top_k x share`` expected a call. Measured for 16 held of 256, 4096 x
+    2048, 8 a token (my chip run, PR 34,
+    ``benchmarks/tests/moe_dispatch_share.py``): dense reads all 16 whatever
+    the rows, 1.13-1.25 ms a layer; sorted 0.51 ms at 8 assignments (5
+    experts hit), 0.82 at 17 (7 hit), 1.48 at 31, 2.2 at 131: sorted pays
+    0.1-0.15 ms an expert hit, so it wins while clearly fewer experts are
+    hit than held, which an even router ends at as many assignments as
+    experts (16 of them hit 10 of 16)."""
+    if n_experts >= 16 * top_k * share and rows <= 512:
+        return rows * top_k * share < n_experts
     return rows >= 16
 
 
@@ -71,8 +84,8 @@ def _sorted_dispatch(x: jax.Array,            # [B, T, D]
                      wg: jax.Array, wu: jax.Array, wd: jax.Array,
                      vals: jax.Array,          # [B, T, K] renormalized gates
                      idx: jax.Array,           # [B, T, K] expert ids
-                     layer: Optional[int] = None
-                     ) -> jax.Array:
+                     layer: Optional[int] = None,
+                     absent: bool = False) -> jax.Array:
     """Exact sorted MoE dispatch: flatten (token, k) assignments, stable-sort
     by expert, run each expert's contiguous group through `lax.ragged_dot`,
     scatter-add the weighted outputs back. No capacity limit, no dropped
@@ -96,7 +109,12 @@ def _sorted_dispatch(x: jax.Array,            # [B, T, D]
     order = jnp.argsort(flat_e, stable=True)           # [N*K]
     tok = order // K                                   # source token per slot
     xs = xf[tok]                                       # [N*K, D]
-    counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    # ``absent``: an assignment to an expert this chip does not hold
+    # carries id E (moe_ffn): it sorts behind every group, belongs to none
+    # and adds nothing
+    counts = jnp.zeros((E + absent,), jnp.int32).at[flat_e].add(1)
+    if absent:
+        counts = counts[:E]
     if layer is not None:
         L = wg.shape[0]
         counts = jnp.zeros((L, E), jnp.int32).at[layer].set(counts).reshape(-1)
@@ -107,20 +125,35 @@ def _sorted_dispatch(x: jax.Array,            # [B, T, D]
          * u.astype(jnp.float32)).astype(x.dtype)
     y = jax.lax.ragged_dot(a, wd, counts)              # [N*K, D]
     y = y.astype(jnp.float32) * flat_g[order][:, None]
+    if absent:
+        y = jnp.where((flat_e[order] < E)[:, None], y, 0.0)
     out = jnp.zeros((N, D), jnp.float32).at[tok].add(y)
     return out.reshape(B, T, D).astype(x.dtype)
 
 
-def route_topk(x: jax.Array, wr: jax.Array, top_k: int):
+def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
+               router: str = "softmax", bias: Optional[jax.Array] = None):
     """Router: renormalized top-k gate values + expert ids ([B,T,K] each).
     Shared by every dispatch formulation (incl. forward_pp's in-stage MoE)
-    so the gating policy has exactly one implementation."""
+    so the gating policy has exactly one implementation. Two laws:
+    ``softmax`` (top-k of the softmax over all experts) and ``sigmoid_bias``
+    (sigmoid scores; the k largest of score + ``bias`` [E], the learned
+    selection bias, are chosen; the gates are the chosen SCORES over their
+    sum: the bias chooses and never weighs)."""
     # float32 logits, not only a float32 softmax: bfloat16 resolves a
     # router logit of 32-64 to 0.25, i.e. a gate ratio to 25 %
     logits = jnp.einsum("btd,de->bte", x, wr.astype(x.dtype),
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    vals, idx = jax.lax.top_k(probs, top_k)               # [B,T,K]
+    if router == "sigmoid_bias":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores if bias is None else scores + bias,
+                               top_k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+    elif router == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        vals, idx = jax.lax.top_k(probs, top_k)           # [B,T,K]
+    else:
+        raise ValueError(f"no router law {router!r}")
     return vals / jnp.sum(vals, axis=-1, keepdims=True), idx
 
 
@@ -149,16 +182,40 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
             wd: jax.Array,          # [E, F, D] expert down projections
             top_k: int,
             mesh=None,
-            layer: Optional[int] = None):
+            layer: Optional[int] = None,
+            router: str = "softmax",
+            bias: Optional[jax.Array] = None,
+            first: Optional[int] = None):
     """Routed MoE feed-forward (expert width F is the weights' own: a model
     whose experts are not ``intermediate_size`` wide needs nothing here).
     With ``layer``, ``wg`` / ``wu`` / ``wd`` are the stacked [L, E, ...]
     tensors and ``layer`` picks this call's (see :func:`_sorted_dispatch`).
     Returns ([B, T, D] in x.dtype, experts hit: int32 scalar, the experts
     that at least one row of this call was routed to, and the chosen expert
-    ids [B, T, K])."""
+    ids [B, T, K]).
+
+    A chip's SHARE of the experts (``first`` given): ``wr`` is as wide as
+    the deployment's router, ``wg`` / ``wu`` / ``wd`` hold experts ``first
+    .. first + E - 1`` of it. The router chooses among all and the gates
+    are normalised over all ``top_k`` chosen, held here or not; an
+    assignment to an absent expert is dropped before dispatch, and the
+    result is the held experts' part of the layer's output: what the
+    absent ones would add is left out, not stood in for. The second result
+    is then (experts hit among the HELD, assignments to held experts), and
+    the chosen ids stay the router's own."""
     with jax.named_scope("dynamo.moe_ffn"):
-        vals, idx = route_topk(x, wr, top_k)
+        vals, idx = route_topk(x, wr, top_k, router, bias)
+        if first is not None:
+            E = wg.shape[-3]
+            held = (idx >= first) & (idx < first + E)
+            n_held = jnp.sum(held.astype(jnp.int32))
+            local = jnp.where(held, idx - first, E)
+            hit = jnp.sum(jnp.zeros((E + 1,), jnp.int32)
+                          .at[local.reshape(-1)].max(1)[:E])
+            # rows: the assignments this call may compute (sorted_wins)
+            out = _dispatch(x, wg, wu, wd, jnp.where(held, vals, 0.0), local,
+                            mesh, layer, share=E / wr.shape[1])
+            return out, (hit, n_held), idx
         hit = jnp.sum(jnp.zeros((wr.shape[1],), jnp.int32)
                       .at[idx.reshape(-1)].max(1))
         return _dispatch(x, wg, wu, wd, vals, idx, mesh, layer), hit, idx
@@ -183,7 +240,10 @@ def moe_ffn_in_stage(x: jax.Array, wr: jax.Array, wg: jax.Array,
     return jax.lax.psum(y, psum_axes) if psum_axes else y
 
 
-def _dispatch(x, wg, wu, wd, vals, idx, mesh, layer=None):
+def _dispatch(x, wg, wu, wd, vals, idx, mesh, layer=None, share=1.0):
+    """``share``: the part of the router's experts that ``wg`` holds (a
+    chip's share: ``idx`` is then local, E for an absent expert, and that
+    part of a call's assignments is what the dispatch rule counts)."""
     E = wg.shape[-3]
 
     ep = _ep_size(mesh)
@@ -192,8 +252,9 @@ def _dispatch(x, wg, wu, wd, vals, idx, mesh, layer=None):
     tp_ffn = tp if tp > 1 and F % tp == 0 else 1
     if ep <= 1 and tp_ffn <= 1:
         B, T, _ = x.shape
-        if sorted_wins(B * T, idx.shape[-1], E):
-            return _sorted_dispatch(x, wg, wu, wd, vals, idx, layer)
+        if sorted_wins(B * T, idx.shape[-1], E, share):
+            return _sorted_dispatch(x, wg, wu, wd, vals, idx, layer,
+                                    absent=share < 1.0)
     if layer is not None:
         # a layer's slice of the stacked tensor is free for an einsum
         wg, wu, wd = wg[layer], wu[layer], wd[layer]

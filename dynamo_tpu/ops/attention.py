@@ -140,10 +140,13 @@ def topk_keep(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
 
 def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
                   scale: float, G: int, softcap: Optional[float],
-                  window: Optional[int], selected: bool):
+                  window: Optional[int], selected: bool,
+                  sunk: bool = False):
     # a model with an indexer adds ONE operand, the keep mask of its
-    # selection; every other model's kernel is what it always was
+    # selection, and a layer with a sink one, the heads' sink logits; every
+    # other model's kernel is what it always was
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
     o_ref, m_scr, l_scr, acc_scr = rest
     j = pl.program_id(2)
 
@@ -169,7 +172,7 @@ def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
         q = q_ref[0]                                       # [G, BT, Dh] bf16
         BS, Dh = k_ref.shape[-2], k_ref.shape[-1]
         k = jnp.broadcast_to(k_ref[0][None], (G, BS, Dh))  # [G, BS, Dh]
-        v = jnp.broadcast_to(v_ref[0][None], (G, BS, Dh))
+        v = jnp.broadcast_to(v_ref[0][None], (G, BS, v_ref.shape[-1]))
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale    # [G, BT, BS]
@@ -205,6 +208,9 @@ def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
         l = l_scr[:]
+        if sink_ref is not None:
+            # the sink: one more key of logit sink[h] and value zero
+            l = l + jnp.exp(sink_ref[0][:, :, None] - m_scr[:])
         o = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = o.astype(o_ref.dtype)
 
@@ -215,10 +221,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     scale: Optional[float] = None,
                     softcap: Optional[float] = None,
                     window: Optional[int] = None,
-                    keep: Optional[jax.Array] = None) -> jax.Array:
+                    keep: Optional[jax.Array] = None,
+                    sink: Optional[jax.Array] = None) -> jax.Array:
     """Blockwise attention with explicit positions.
 
-    q: [B, T, Hq, Dh] ; k, v: [B, S, Hkv, Dh] (gathered context, GQA)
+    q: [B, T, Hq, Dh] ; k: [B, S, Hkv, Dh] ; v: [B, S, Hkv, Dv] (gathered
+    context, GQA; V heads may have a width of their own)
     q_pos: [B, T] int32 ; k_pos: [B, S] int32 ; k_valid: [B, S] bool
     A query at position p attends to context slots with k_pos <= p & valid;
     with ``window`` additionally k_pos > p - window (Gemma2/3 sliding
@@ -226,10 +234,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``scale`` overrides the rsqrt(Dh) default (query_pre_attn_scalar).
     ``keep`` [B, T, S] bool (a model with an indexer: :func:`topk_keep`)
     restricts each query to its selected keys, every head alike.
-    Returns [B, T, Hq, Dh] in q.dtype.
+    ``sink`` [Hq] float32: a logit a head that takes softmax weight and
+    gives no value. Returns [B, T, Hq, Dv] in q.dtype.
     """
     B, T, Hq, Dh = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
+    S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = Hq // Hkv
     if interpret is None:
         interpret = not on_tpu()
@@ -242,7 +251,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     q5 = q.reshape(B, T, Hkv, G, Dh).transpose(0, 2, 3, 1, 4)
     q5 = q5.reshape(B * Hkv, G, T, Dh)
     k3 = k.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
-    v3 = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dh)
+    v3 = v.transpose(0, 2, 1, 3).reshape(B * Hkv, S, Dv)
     # positions/validity carry a singleton middle axis: a [B, S] array with
     # block (1, BS) violates Mosaic's last-two-dims tiling rule whenever
     # B > 1 (block dim 1 is neither 8-divisible nor equal to B); as
@@ -258,10 +267,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         sel_specs = [pl.BlockSpec((1, BT, BS),
                                   lambda bh, i, j: (bh // Hkv, i, j))]
         sel_args = [keep.astype(jnp.int32)]
+    if sink is not None:
+        sel_specs.append(pl.BlockSpec((1, G, 1),
+                                      lambda bh, i, j: (bh % Hkv, 0, 0)))
+        sel_args.append(sink.astype(jnp.float32).reshape(Hkv, G, 1))
     grid = (B * Hkv, T // BT, S // BS)
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, G=G,
-                          softcap=softcap, window=window, selected=selected),
+                          softcap=softcap, window=window, selected=selected,
+                          **({} if sink is None else {"sunk": True})),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, BT, 1), lambda bh, i, j: (bh // Hkv, i, 0)),
@@ -269,21 +283,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, BS), lambda bh, i, j: (bh // Hkv, 0, j)),
             pl.BlockSpec((1, G, BT, Dh), lambda bh, i, j: (bh, 0, i, 0)),
             pl.BlockSpec((1, BS, Dh), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, BS, Dh), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((1, BS, Dv), lambda bh, i, j: (bh, j, 0)),
             *sel_specs,
         ],
-        out_specs=pl.BlockSpec((1, G, BT, Dh), lambda bh, i, j: (bh, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, Dh), q.dtype),
+        out_specs=pl.BlockSpec((1, G, BT, Dv), lambda bh, i, j: (bh, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((G, BT, 1), jnp.float32),    # m
             pltpu.VMEM((G, BT, 1), jnp.float32),    # l
-            pltpu.VMEM((G, BT, Dh), jnp.float32),   # acc
+            pltpu.VMEM((G, BT, Dv), jnp.float32),   # acc
         ],
         interpret=interpret,
     )(qpos_col, kpos3, kval, q5, k3, v3, *sel_args)
 
-    out = out.reshape(B, Hkv, G, T, Dh).transpose(0, 3, 1, 2, 4)
-    return out.reshape(B, T, Hq, Dh)
+    out = out.reshape(B, Hkv, G, T, Dv).transpose(0, 3, 1, 2, 4)
+    return out.reshape(B, T, Hq, Dv)
 
 
 # ---------------------------------------------------------------------------
@@ -314,7 +328,8 @@ def _lane_block(b, j, *prefetched):
 def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                       scale: float, page: int, ppb: int, hkv: int,
                       fold: int, dh: int, softcap: Optional[float],
-                      window: Optional[int], selected: bool):
+                      window: Optional[int], selected: bool,
+                      dv: Optional[int] = None, sunk: bool = False):
     """Pools are the WHOLE stored pool, [L, Hkv, n_pages, page//fold,
     fold*Dh], left in HBM; ``layer_ref[0]`` picks the layer inside the copy
     descriptor, so no per-layer slice of the pool is ever materialised and
@@ -331,9 +346,13 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 
     ``selected``: one more operand, the keep mask of a model with an indexer
     ([1, 1, L2] int32 of this lane and block, logical order, fold 1 only);
-    without it the kernel is what it always was."""
+    without it the kernel is what it always was. ``dv``: V rows of a width
+    of their own (fold 1 only). ``sunk``: one more operand, the heads' sink
+    logits [Hkv, G, 1] float32 (a key of that logit and value zero)."""
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
     o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr, state = rest
+    dv = dh if dv is None else dv
     b = pl.program_id(0)
     j = pl.program_id(1)
     L2 = ppb * page           # tokens per compute block
@@ -415,7 +434,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 
         q = q_ref[0]                                        # [Hkv, G, Dh]
         kf = k_buf[slot].reshape(hkv, rows, fold * dh)
-        vf = v_buf[slot].reshape(hkv, rows, fold * dh)
+        vf = v_buf[slot].reshape(hkv, rows, fold * dv)
         # token index of folded row r, slice f: within this block the page
         # is r // rows_pp and the in-page row r % rows_pp
         ridx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
@@ -450,7 +469,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         for f in range(fold):
             p = jnp.where(mask_parts[f], jnp.exp(s_parts[f] - m_new), 0.0)
             l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
-            vslice = vf[:, :, f * dh:(f + 1) * dh]          # [Hkv, rows, Dh]
+            vslice = vf[:, :, f * dv:(f + 1) * dv]          # [Hkv, rows, Dv]
             acc = acc + jax.lax.dot_general(
                 p.astype(vf.dtype), vslice, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)         # [Hkv, G, Dh]
@@ -462,6 +481,8 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         @pl.when(j == nb - 1)
         def _():
             l = l_scr[:]
+            if sink_ref is not None:
+                l = l + jnp.exp(sink_ref[...] - m_scr[:])
             o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
                         ).astype(o_ref.dtype)
 
@@ -472,13 +493,15 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
                          softcap: Optional[float] = None,
                          window: Optional[int] = None,
                          keep: Optional[jax.Array] = None,
+                         sink: Optional[jax.Array] = None,
                          interpret: bool = False) -> jax.Array:
-    """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh]; layer: [1]
+    """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh] (V: Dv); layer: [1]
     int32; keep: [B, P * page] bool or None. Returns q4-shaped. ``interpret`` exists for the CPU test suite
     only — the serving path always compiles this variant (paged_attention
     gates it to real TPUs)."""
     B, Hkv, G, Dh = q4.shape
     L, _, n_pages, page, _ = k_pool.shape
+    Dv = v_pool.shape[-1]
     P = page_tables.shape[1]
     ppb = min(pages_per_block, P)
     if P % ppb:
@@ -495,8 +518,11 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     fold = max(1, 128 // Dh)
     if page % fold:
         raise ValueError(f"page size {page} not divisible by fold {fold}")
+    if fold > 1 and Dv != Dh:
+        raise ValueError(f"V rows of their own width ({Dv}) need K rows of "
+                         f"at least a lane tile (got {Dh})")
     k_pool = k_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
-    v_pool = v_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
+    v_pool = v_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dv)
 
     selected = keep is not None
     sel_specs, sel_args = [], []
@@ -510,6 +536,13 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
         keep = jnp.pad(keep, ((0, 0), (0, NB * L2 - keep.shape[1])))
         sel_specs = [pl.BlockSpec((1, 1, L2), lambda b, j, *_: (b, 0, j))]
         sel_args = [keep[:, None, :]]
+    own = {}                 # static parameters only a per-kind model sets
+    if sink is not None:
+        sel_specs.append(pl.BlockSpec((Hkv, G, 1), lambda b, j, *_: (0, 0, 0)))
+        sel_args.append(sink.astype(jnp.float32).reshape(Hkv, G, 1))
+        own["sunk"] = True
+    if Dv != Dh:
+        own["dv"] = Dv
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -520,23 +553,24 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             *sel_specs,
         ],
-        out_specs=pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
+        out_specs=pl.BlockSpec((1, Hkv, G, Dv), _lane_block),
         scratch_shapes=[
             pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dh), k_pool.dtype),
-            pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dh), v_pool.dtype),
+            pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),                 # [slot, k/v]
             pltpu.VMEM((Hkv, G, 1), jnp.float32),            # m
             pltpu.VMEM((Hkv, G, 1), jnp.float32),            # l
-            pltpu.VMEM((Hkv, G, Dh), jnp.float32),           # acc
+            pltpu.VMEM((Hkv, G, Dv), jnp.float32),           # acc
             pltpu.SMEM((1,), jnp.int32),                     # buffer slot
         ],
     )
     return pl.pallas_call(
         functools.partial(_paged_dma_kernel, scale=scale, page=page,
                           ppb=ppb, hkv=Hkv, fold=fold, dh=Dh,
-                          softcap=softcap, window=window, selected=selected),
+                          softcap=softcap, window=window, selected=selected,
+                          **own),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q4.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q4.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
@@ -545,8 +579,10 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
 
 def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                   scale: float, page: int, softcap: Optional[float],
-                  window: Optional[int], selected: bool):
+                  window: Optional[int], selected: bool,
+                  sunk: bool = False):
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
+    sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
     o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     p = pl.program_id(1)
@@ -598,6 +634,8 @@ def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(p == pl.num_programs(1) - 1)
     def _():
         l = l_scr[:]
+        if sink_ref is not None:
+            l = l + jnp.exp(sink_ref[...] - m_scr[:])
         o = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = o.astype(o_ref.dtype)
 
@@ -625,7 +663,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     scale: Optional[float] = None,
                     softcap: Optional[float] = None,
                     window: Optional[int] = None,
-                    keep: Optional[jax.Array] = None) -> jax.Array:
+                    keep: Optional[jax.Array] = None,
+                    sink: Optional[jax.Array] = None) -> jax.Array:
     """Decode attention straight over the paged KV pool.
 
     q: [B, Hq, Dh] (one new token per sequence, already rope'd)
@@ -647,7 +686,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     dynamic, so all layers of a class share that kernel. ``keep`` [B, P *
     page] bool (a model with an indexer: :func:`topk_keep` over the lane's
     logical positions) restricts the lane to its selected keys; every page
-    is still read.
+    is still read. V rows may have a width of their own (``v_pool`` [...,
+    Dv]: the result is [B, Hq, Dv]); ``sink`` [Hq] float32 is a logit a head
+    that takes softmax weight and gives no value.
 
     On a TPU this runs the multi-page double-buffered DMA kernel above
     (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
@@ -662,6 +703,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, Hq, Dh = q.shape
     _, Hkv, n_pages, page, _ = k_pool.shape
+    Dv = v_pool.shape[-1]
     G = Hq // Hkv
     P = page_tables.shape[1]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
@@ -699,8 +741,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         out = _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables,
                                    lengths, pages_per_block=ppb,
                                    scale=scale, softcap=softcap,
-                                   window=window, keep=keep)
-        return out.reshape(B, Hq, Dh)
+                                   window=window, keep=keep,
+                                   **({} if sink is None else {"sink": sink}))
+        return out.reshape(B, Hq, Dv)
     selected = keep is not None
     if selected and not interpret:
         raise ValueError(
@@ -721,23 +764,28 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         in_specs=[
             pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
             pl.BlockSpec((1, Hkv, 1, page, Dh), page_map),
-            pl.BlockSpec((1, Hkv, 1, page, Dh), page_map),
+            pl.BlockSpec((1, Hkv, 1, page, Dv), page_map),
             *([pl.BlockSpec((1, 1, page), lambda b, p, *_: (b, 0, p))]
               if selected else []),
+            *([pl.BlockSpec((Hkv, G, 1), lambda b, p, *_: (0, 0, 0))]
+              if sink is not None else []),
         ],
-        out_specs=pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
+        out_specs=pl.BlockSpec((1, Hkv, G, Dv), _lane_block),
         scratch_shapes=[
             pltpu.VMEM((Hkv, G, 1), jnp.float32),    # m
             pltpu.VMEM((Hkv, G, 1), jnp.float32),    # l
-            pltpu.VMEM((Hkv, G, Dh), jnp.float32),   # acc
+            pltpu.VMEM((Hkv, G, Dv), jnp.float32),   # acc
         ],
     )
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, page=page,
-                          softcap=softcap, window=window, selected=selected),
+                          softcap=softcap, window=window, selected=selected,
+                          **({} if sink is None else {"sunk": True})),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
         interpret=interpret,
     )(page_tables, lengths, layer, q4, k_pool, v_pool,
-      *([keep.astype(jnp.int32)[:, None, :]] if selected else []))
-    return out.reshape(B, Hq, Dh)
+      *([keep.astype(jnp.int32)[:, None, :]] if selected else []),
+      *([sink.astype(jnp.float32).reshape(Hkv, G, 1)]
+        if sink is not None else []))
+    return out.reshape(B, Hq, Dv)

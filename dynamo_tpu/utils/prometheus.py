@@ -474,9 +474,11 @@ class StageMetrics:
             "dyn_engine_info",
             "Engine build facts as labels (value 1): selected attention "
             "paths, paged-kernel variant, device platform/kind/count, peak "
-            "source",
+            "source, cache kinds (name:layers x kv heads x (K + V row), "
+            "+ joined)",
             ("worker", "attn_impl", "decode_attn_impl", "paged_kernel",
-             "platform", "device_kind", "devices", "peak_source"))
+             "platform", "device_kind", "devices", "peak_source",
+             "cache_kinds"))
         self.device_peak_bytes = r.gauge(
             "dyn_device_peak_bytes_in_use",
             "Peak device memory in use per engine device "
@@ -532,7 +534,30 @@ class StageMetrics:
         # already knows (the experts hit come back with its sampled tokens)
         self.moe_assignments = r.counter(
             "dyn_moe_assignments_total",
-            "Token x expert pairs routed, all layers", ("kind",))
+            "Token x expert pairs computed here, all layers (all that were "
+            "routed, but under a chip's share of the experts: then those "
+            "to experts held here)", ("kind",))
+        self.moe_routed_assignments = r.counter(
+            "dyn_moe_routed_assignments_total",
+            "Token x expert pairs the router chose, all layers, held here "
+            "or not (only a model served as a chip's share of its experts "
+            "counts here)", ("kind",))
+        # the two page pools of a model whose window layers keep a cache of
+        # their own (engine/cache.py)
+        self.kv_pages_in_use = r.gauge(
+            "dyn_kv_pages_in_use",
+            "Pages leased to live sequences, by page pool (global: every "
+            "model; window: a per-kind model's window cache)", ("pool",))
+        self.kv_resident_token_steps = r.counter(
+            "dyn_kv_resident_token_steps_total",
+            "Tokens of a decode dispatch's lanes that a page of the pool "
+            "held when the dispatch was fetched, summed over dispatches "
+            "(global: their whole contexts): the ratio of the two pools is "
+            "the share of a context the window cache keeps", ("pool",))
+        self.kv_window_pages_released = r.counter(
+            "dyn_kv_window_pages_released_total",
+            "Window-cache pages given back while their sequence lived on "
+            "(they lay wholly behind the window of a fetched dispatch)", ())
         self.moe_experts_hit = r.counter(
             "dyn_moe_experts_hit_total",
             "Experts with at least one row, per layer and step, summed "

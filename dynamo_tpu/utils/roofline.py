@@ -164,6 +164,20 @@ class ModelCosts:
     index_topk: int = 0
     index_flops_coef: float = 0.0
     index_bytes_per_tok_layer: float = 0.0
+    # a per-kind model (window and full layers with head counts and caches
+    # of their own): K/V bytes a token a layer of each of ``window_groups``,
+    # in its order (empty: ``kv_bytes_per_tok_layer`` for every layer)
+    group_kv_bytes: Tuple[float, ...] = ()
+
+    def kv_bytes_of(self, i: int) -> float:
+        return (self.group_kv_bytes[i] if self.group_kv_bytes
+                else self.kv_bytes_per_tok_layer)
+
+    @property
+    def kv_write_bytes_per_token(self) -> float:
+        """K/V bytes one new token writes, all layers."""
+        return float(sum(n * self.kv_bytes_of(i)
+                         for i, (_, n) in enumerate(self.window_groups)))
 
 
 def dtype_size(dtype: Any) -> int:
@@ -187,7 +201,10 @@ def model_costs(m: Any, weight_bytes: Optional[float] = None) -> ModelCosts:
     Hq, Hkv, Dh = m.num_heads, m.num_kv_heads, m.head_dim
     L, I = m.num_layers, m.intermediate_size
     esize = dtype_size(m.dtype)
-    attn_proj = D * Hq * Dh + 2 * D * Hkv * Dh + Hq * Dh * D
+    if getattr(m, "per_kind", False):
+        return _per_kind_costs(m, weight_bytes, esize)
+    Dv = getattr(m, "v_dim", Dh)
+    attn_proj = D * Hq * Dh + D * Hkv * (Dh + Dv) + Hq * Dv * D
     topk = getattr(m, "index_topk", 0)
     Hi, Di = getattr(m, "index_heads", 0), getattr(m, "index_head_dim", 0)
     if topk:
@@ -211,8 +228,8 @@ def model_costs(m: Any, weight_bytes: Optional[float] = None) -> ModelCosts:
     return ModelCosts(
         mat_flops_per_token=2.0 * L * (attn_proj + mlp_active),
         lm_head_flops=2.0 * D * V,
-        attn_flops_coef=4.0 * Hq * Dh,
-        kv_bytes_per_tok_layer=2.0 * Hkv * Dh * esize,
+        attn_flops_coef=2.0 * Hq * (Dh + Dv),
+        kv_bytes_per_tok_layer=float(Hkv * (Dh + Dv) * esize),
         num_layers=L,
         window_groups=tuple(sorted(groups.items(),
                                    key=lambda kv: (kv[0] is None, kv[0]))),
@@ -221,6 +238,46 @@ def model_costs(m: Any, weight_bytes: Optional[float] = None) -> ModelCosts:
         index_flops_coef=2.0 * Hi * Di,
         index_bytes_per_tok_layer=float(Di * esize),
     )
+
+
+def _per_kind_costs(m: Any, weight_bytes: Optional[float],
+                    esize: int) -> ModelCosts:
+    """:class:`ModelCosts` of a model described layer by layer: attention
+    projections and cache bytes by attention kind, the feed-forward by its
+    kind; of routed experts the part a token's assignments compute HERE
+    (``experts_per_token`` x the chip's share of the router's experts)."""
+    D, V, Hq, Dh, Dv = (m.hidden_size, m.vocab_size, m.num_heads,
+                        m.head_dim, m.v_dim)
+    Fe = m.expert_width
+    R = m.router_experts or m.num_experts
+    share = m.num_experts / R
+    from ..engine.cache import cache_kinds
+
+    mat = n_params = 0.0
+    groups, kv = [], []
+    # window layers first, as the one-law model's groups sort
+    for kind in sorted(cache_kinds(m), key=lambda k: k.window is None):
+        proj = (D * Hq * Dh + D * kind.kv_heads * (Dh + Dv) + Hq * Dv * D)
+        mat += kind.layers * proj
+        n_params += kind.layers * proj
+        groups.append((kind.window, kind.layers))
+        kv.append(float(kind.token_bytes(esize) // kind.layers))
+    for l in range(m.num_layers):
+        if m.layer_routed(l):
+            mat += m.experts_per_token * share * 3 * D * Fe + D * R
+            n_params += m.num_experts * 3 * D * Fe + D * R
+        else:
+            mat += 3 * D * m.intermediate_size
+            n_params += 3 * D * m.intermediate_size
+    if weight_bytes is None:
+        n_params += V * D * (1 if m.tie_embeddings else 2)
+        weight_bytes = n_params * esize
+    return ModelCosts(
+        mat_flops_per_token=2.0 * mat, lm_head_flops=2.0 * D * V,
+        attn_flops_coef=2.0 * Hq * (Dh + Dv),
+        kv_bytes_per_tok_layer=kv[1], num_layers=m.num_layers,
+        window_groups=tuple(groups), weight_bytes=float(weight_bytes),
+        group_kv_bytes=tuple(kv))
 
 
 def _clamped_len_sum(groups: Sequence[Tuple[Optional[int], int]],
@@ -240,7 +297,12 @@ def _attn_cost(c: ModelCosts, s: int) -> Tuple[float, float]:
     with an indexer — the index scores of every visible key."""
     touched = _clamped_len_sum(c.window_groups, s, c.index_topk)
     flops = c.attn_flops_coef * touched
-    read = touched * c.kv_bytes_per_tok_layer
+    if c.group_kv_bytes:
+        read = float(sum((min(s, w) if w is not None else s) * n * b
+                         for (w, n), b in zip(c.window_groups,
+                                              c.group_kv_bytes)))
+    else:
+        read = touched * c.kv_bytes_per_tok_layer
     if c.index_topk:
         flops += c.index_flops_coef * s * c.num_layers
         read += c.index_bytes_per_tok_layer * s * c.num_layers
@@ -263,7 +325,7 @@ def decode_cost(c: ModelCosts, lengths: Iterable[int], steps: int
             kv_read += rd
     tokens = lanes * steps
     bytes_ = (steps * c.weight_bytes + kv_read
-              + tokens * c.num_layers * c.kv_bytes_per_tok_layer)
+              + tokens * c.kv_write_bytes_per_token)
     return flops, bytes_, tokens
 
 
@@ -284,7 +346,7 @@ def prefill_cost(c: ModelCosts, spans: Iterable[Tuple[int, int]]
             flops += fl
             kv_read += rd
     bytes_ = (c.weight_bytes + kv_read
-              + tokens * c.num_layers * c.kv_bytes_per_tok_layer)
+              + tokens * c.kv_write_bytes_per_token)
     return flops, bytes_, tokens
 
 
@@ -304,7 +366,7 @@ def verify_cost(c: ModelCosts, lengths: Iterable[int], t: int
             kv_read += rd
     tokens = lanes * t
     bytes_ = (c.weight_bytes + kv_read
-              + tokens * c.num_layers * c.kv_bytes_per_tok_layer)
+              + tokens * c.kv_write_bytes_per_token)
     return flops, bytes_, tokens
 
 
