@@ -47,8 +47,8 @@ from ..runtime.engine import AsyncEngine, Context
 from ..utils import tracing as _tracing
 from ..utils.jaxenv import on_tpu
 from .cache import OutOfPages, PagePool, WindowPages, cache_kinds
-from .sampling import (STATIC_K, SamplingState, apply_penalties,
-                       resume_seed, sample)
+from .sampling import (STATIC_K, SamplingState, any_sampling,
+                       apply_penalties, resume_seed, sample)
 
 log = logging.getLogger("dynamo_tpu.engine")
 
@@ -1120,7 +1120,8 @@ class EngineCore:
                                else {"i_pool": ip[0]} if ip else {}))
                     lg = apply_penalties(logits[:, 0], counts, freq_pen,
                                          pres_pen)
-                    tok, logp, new_key = sample(lg, temp, top_p, top_k, key)
+                    tok, logp, new_key = sample(lg, temp, top_p, top_k, key,
+                                                active)
                     # only lanes ACTIVE in this dispatch count their sample:
                     # a deferred (pool-pressure) lane's garbage tokens must
                     # not poison its penalties when it resumes
@@ -2392,7 +2393,8 @@ class EngineCore:
         self.stage.engine_dispatch_tokens.inc(
             "prefill", amount=float(sum(w[3] for w in work)))
         self._inflight.append({"kind": "prefill",
-                               "seq": self._count_dispatch("prefill"),
+                               "seq": self._count_dispatch(
+                                   "prefill", greedy=not any_sampling(temp)),
                                "packed": packed, "work": work,
                                "last_lanes": last_lanes,
                                "compiled": self._take_compiled_flag(),
@@ -2401,10 +2403,15 @@ class EngineCore:
                                "dispatched_at": t_disp})
         return len(last_lanes)
 
-    def _count_dispatch(self, kind: str) -> int:
-        """Count a dispatch just enqueued (and whether it went behind
-        records still unfetched); returns its number in enqueue order."""
+    def _count_dispatch(self, kind: str, greedy: bool) -> int:
+        """Count a dispatch just enqueued (whether it went behind records
+        still unfetched; whether no lane it served sampled, so that its
+        program skipped the sampler's window: ``greedy`` is the host's
+        reading of the predicate the program reads, ``any_sampling``);
+        returns its number in enqueue order."""
         self.stage.engine_dispatches.inc(kind)
+        if greedy:
+            self.stage.engine_greedy_dispatches.inc(kind)
         if self._inflight:
             self.stage.engine_dispatches_behind.inc(kind)
         self._dispatch_seq += 1
@@ -2646,7 +2653,9 @@ class EngineCore:
         self.stage.engine_dispatch_tokens.inc(
             "decode", amount=float(len(active) * N))
         self._inflight.append({"kind": "decode",
-                               "seq": self._count_dispatch("decode"),
+                               "seq": self._count_dispatch(
+                                   "decode", greedy=not any_sampling(
+                                       s.temperature, active_mask)),
                                "packed": packed, "final_tok": final_tok,
                                "active": active,
                                "lengths": [phys for _, _, phys in active],

@@ -5,6 +5,15 @@ vectors select behavior lane-wise (greedy lanes use argmax; sampling lanes use
 temperature + nucleus/top-k restricted to a static K window — restriction to
 the top-K=64 candidates is exact for top-k<=64 and a standard approximation
 for pure top-p, since mass beyond the top-64 logits is negligible for LLMs).
+
+The window (``lax.top_k`` over the whole vocabulary, its softmax, masks and
+one categorical draw a lane) runs under a ``lax.cond`` and only in a dispatch
+where some ACTIVE lane samples (:func:`any_sampling`: the one predicate, read
+by the program from its own inputs and by the engine's
+``dyn_engine_greedy_dispatches_total`` from the same host vectors). A
+dispatch whose active lanes are all greedy returns argmax and its
+log-probability and never issues ``TopK``: 0.84 ms of a 6.43 ms decode step
+at 32 lanes x 151,936 logits on a v5e (PERF.md section 6, PR 35).
 """
 
 from __future__ import annotations
@@ -71,17 +80,22 @@ def apply_penalties(logits: jax.Array, counts: jax.Array,
             - pres_pen[:, None] * (cf > 0).astype(jnp.float32))
 
 
-def sample(logits: jax.Array, temperature: jax.Array, top_p: jax.Array,
-           top_k: jax.Array, key: jax.Array
-           ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """logits [B,V] f32 -> (tokens [B] i32, logprob [B] f32, new_keys [B]).
+def any_sampling(temperature, active=None):
+    """Does any lane that counts in this dispatch sample (temperature > 0)?
+    ``active`` masks out lanes the dispatch carries but does not serve: a
+    slot keeps its last request's temperature after release. Works on the
+    program's traced vectors and on the host's NumPy ones alike."""
+    sampling = temperature > 0.0
+    if active is not None:
+        sampling = sampling & active
+    return sampling.any()
 
-    Greedy lanes (temperature==0) take argmax; others sample within the
-    top-STATIC_K window with temperature, then top-k/top-p masks.
-    """
-    B, V = logits.shape
-    greedy_tok = jnp.argmax(logits, axis=-1)
 
+def _window_draw(logits: jax.Array, temperature: jax.Array,
+                 top_p: jax.Array, top_k: jax.Array, sub: jax.Array
+                 ) -> jax.Array:
+    """One draw a lane [B] from the top-STATIC_K window: temperature, then
+    top-k/top-p masks, then a categorical draw with the lane's subkey."""
     vals, idxs = jax.lax.top_k(logits, STATIC_K)  # [B,K]
     temp = jnp.maximum(temperature, 1e-6)[:, None]
     scaled = vals / temp
@@ -95,11 +109,31 @@ def sample(logits: jax.Array, temperature: jax.Array, top_p: jax.Array,
     pmask = (cum - probs) < top_p[:, None]
     mask = kmask & pmask
     masked = jnp.where(mask, scaled, -jnp.inf)
+    draw = jax.vmap(jax.random.categorical)(sub, masked)
+    return jnp.take_along_axis(idxs, draw[:, None], axis=-1)[:, 0]
 
+
+def sample(logits: jax.Array, temperature: jax.Array, top_p: jax.Array,
+           top_k: jax.Array, key: jax.Array,
+           active: Optional[jax.Array] = None
+           ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """logits [B,V] f32 -> (tokens [B] i32, logprob [B] f32, new_keys [B]).
+
+    Greedy lanes (temperature==0) take argmax; others sample within the
+    top-STATIC_K window with temperature, then top-k/top-p masks. The window
+    is computed, for every lane, only if :func:`any_sampling` of
+    (``temperature``, ``active`` [B] bool or None = every lane counts);
+    otherwise no lane's result needs it and the branch costs nothing. Keys
+    advance the same way in both cases, so a seeded lane's stream does not
+    depend on whether its neighbours sampled.
+    """
+    greedy_tok = jnp.argmax(logits, axis=-1)
     split = jax.vmap(lambda k: jax.random.split(k, 2))(key)  # [B,2] typed
     new_keys, sub = split[:, 0], split[:, 1]
-    draw = jax.vmap(jax.random.categorical)(sub, masked)
-    sampled_tok = jnp.take_along_axis(idxs, draw[:, None], axis=-1)[:, 0]
+    sampled_tok = jax.lax.cond(
+        any_sampling(temperature, active),
+        lambda: _window_draw(logits, temperature, top_p, top_k, sub),
+        lambda: greedy_tok)
 
     token = jnp.where(temperature <= 0.0, greedy_tok, sampled_tok)
     logp_all = jax.nn.log_softmax(logits, axis=-1)

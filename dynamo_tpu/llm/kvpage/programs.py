@@ -225,7 +225,7 @@ class PagedPrograms:
                 x, last_i[:, None, None].astype(jnp.int32), axis=1)
             logits = llama._lm_head(xs, params, m)[:, 0]       # [B, V]
             lg = apply_penalties(logits, counts, freq_pen, pres_pen)
-            tok, logp, new_key = sample(lg, temp, top_p, top_k, key)
+            tok, logp, new_key = sample(lg, temp, top_p, top_k, key, active)
             B = tok.shape[0]
             # inactive rows (padded decode lanes) must not perturb the
             # lane-persistent sampling state: their penalty counts stay

@@ -516,6 +516,10 @@ class StageMetrics:
             "dyn_engine_dispatches_behind_total",
             "Those enqueued while an earlier dispatch's result was still "
             "unfetched: the host built them while the device ran", ("kind",))
+        self.engine_greedy_dispatches = r.counter(
+            "dyn_engine_greedy_dispatches_total",
+            "Those whose active lanes were all at temperature 0: the "
+            "program skipped the sampler's top-k window", ("kind",))
         self.engine_dispatch_tokens = r.counter(
             "dyn_engine_dispatch_tokens_total",
             "Token positions computed by those dispatches (prompt tokens "

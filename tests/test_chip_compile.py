@@ -235,6 +235,34 @@ def test_bucket_program_bodies_keep_the_pool_as_stored(v5e, program):
     assert ma.temp_size_in_bytes < pool_bytes // 8
 
 
+def test_the_samplers_window_stays_a_branch_on_the_v5e(v5e):
+    """``sample`` at qwen2's head shape inside a decode scan: the v5e
+    compiler keeps the ``lax.cond`` a real ``conditional`` (it does not
+    compute both branches and select), and ``TopK`` over the vocabulary is
+    issued inside the branch alone, so an all-greedy dispatch never runs it."""
+    import re
+
+    from dynamo_tpu.engine.sampling import sample
+
+    B, V = 32, 151936
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), B))
+
+    def steps(logits, temp, top_p, top_k, key, active):
+        def one(carry, _):
+            lg, key = carry
+            tok, logp, key = sample(lg, temp, top_p, top_k, key, active)
+            return (lg + logp[:, None], key), tok
+        return jax.lax.scan(one, (logits, key), None, length=2)
+
+    txt = _compiled_text(
+        steps, _sds(v5e, (B, V), jnp.float32), _sds(v5e, (B,), jnp.float32),
+        _sds(v5e, (B,), jnp.float32), _sds(v5e, (B,), jnp.int32),
+        _sds(v5e, keys.shape, keys.dtype), _sds(v5e, (B,), jnp.bool_))
+    assert re.search(r"= .* conditional\(", txt)
+    topk = [ln for ln in txt.splitlines() if 'custom_call_target="TopK"' in ln]
+    assert topk and all("/cond/branch_1_fun/" in ln for ln in topk), topk
+
+
 @pytest.mark.parametrize("full_tracebacks", [False, True])
 def test_kernel_program_text_vs_call_stack(v5e, full_tracebacks):
     """A Pallas kernel is serialised into its program with its locations.
