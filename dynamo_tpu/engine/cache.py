@@ -45,9 +45,12 @@ class CacheKind:
     has one kind, ``global``; a per-kind model (``LlamaConfig.per_kind``)
     has ``global`` (its full layers: every token of the context) and
     ``window`` (its window layers: the last ``window`` tokens, in a page
-    pool and page tables of their own, :class:`WindowPages`)."""
+    pool and page tables of their own, :class:`WindowPages`); a model with
+    state-space layers has ``global`` (its attention layers) and ``state``:
+    what those layers keep per LANE, whatever the lane's tokens — nothing
+    pages it, nothing hashes it, and ``token_bytes`` of it is 0."""
 
-    name: str                   # "global" | "window"
+    name: str                   # "global" | "window" | "state"
     layers: int
     kv_heads: int
     k_dim: int                  # a K row as the model defines it
@@ -55,6 +58,10 @@ class CacheKind:
     k_store: int                # ... and as the pool stores it
     window: Optional[int]       # keys a query sees, its own among them
     index_dim: int = 0          # an indexer's keys on the same pages
+    fold: int = 1               # tokens stored to a pool row (kv_fold)
+    # per lane and layer (the ``state`` kind): the recurrent state's shape
+    # (float32) and the convolution tail's (the model's dtype)
+    state: Optional[Tuple[Tuple[int, ...], Tuple[int, ...]]] = None
 
     def token_bytes(self, itemsize: int, stored: bool = False) -> int:
         """Bytes a token holds in this kind, all of its layers: K and V as
@@ -68,8 +75,34 @@ class CacheKind:
         """-> (K pool shape, V pool shape): [layers of the kind, heads,
         pages, page, row], head-major, as every program reads and writes
         them (models/llama.py "KV pool access")."""
-        lead = (self.layers, self.kv_heads, num_pages, page)
-        return (*lead, self.k_store), (*lead, self.v_dim)
+        lead = (self.layers, self.kv_heads, num_pages, page // self.fold)
+        return ((*lead, self.fold * self.k_store),
+                (*lead, self.fold * self.v_dim))
+
+    def label(self) -> str:
+        """The kind as ``dyn_engine_info{cache_kinds}`` names it: layers x
+        heads x (K + V) a token, or what a lane keeps in a state kind."""
+        if self.state is None:
+            return (f"{self.name}:{self.layers}x{self.kv_heads}x"
+                    f"({self.k_dim}+{self.v_dim})")
+        s, c = self.state
+        return (f"{self.name}:{self.layers}x(" + "x".join(map(str, s))
+                + "f32+" + "x".join(map(str, c)) + ")")
+
+    def lane_bytes(self, itemsize: int) -> int:
+        """Bytes a LANE holds in this kind, all of its layers, whatever its
+        tokens: the ``state`` kind's recurrent state and convolution tail;
+        0 for a kind that keeps K and V per token."""
+        if self.state is None:
+            return 0
+        s, c = self.state
+        return self.layers * (4 * math.prod(s) + itemsize * math.prod(c))
+
+    def state_shapes(self, lanes: int):
+        """-> (state pool shape, convolution-tail pool shape): [layers of
+        the kind, lanes, ...]."""
+        s, c = self.state
+        return (self.layers, lanes, *s), (self.layers, lanes, *c)
 
 
 def cache_kinds(m) -> Tuple[CacheKind, ...]:
@@ -78,6 +111,17 @@ def cache_kinds(m) -> Tuple[CacheKind, ...]:
         return (CacheKind("global", m.num_layers, m.num_kv_heads, m.head_dim,
                           m.v_dim, m.k_store_dim, None,
                           m.index_head_dim if m.has_indexer else 0),)
+    if m.has_state:
+        if m.has_window:
+            raise ValueError("window layers beside state-space layers are "
+                             "not implemented")
+        return (CacheKind("global", len(m.kind_layers(False)),
+                          m.num_kv_heads, m.head_dim, m.v_dim, m.k_store_dim,
+                          None, fold=m.kv_fold),
+                CacheKind("state", len(m.state_layers), 0, 0, 0, 0, None,
+                          # (the tail's [conv - 1, channels] as ONE flat row)
+                          state=((m.ssm_heads, m.ssm_head_dim, m.ssm_state),
+                                 ((m.ssm_conv - 1) * m.ssm_conv_dim,))))
     return tuple(
         CacheKind(name, len(m.kind_layers(win)), m.kv_heads_of(win),
                   m.head_dim, m.v_dim, m.k_store_dim,

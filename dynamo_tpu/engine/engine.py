@@ -524,19 +524,8 @@ class EngineCore:
         # moves, matches or re-enters blocks knows one cache: such a model
         # refuses those features by name, as a model with an indexer does.
         self.win = self.wk_pool = self.wv_pool = None
-        if m.per_kind:
-            for on, what in (
-                    (cfg.sp > 1 or impl == "ring", "sp > 1 / ring prefill"),
-                    (cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0,
-                     "the host / disk KV tiers (host_cache_blocks, "
-                     "disk_cache_blocks), and with them cluster "
-                     "write-through, tier prefetch and the paged "
-                     "long-context lane"),
-                    (cfg.cluster_writethrough, "cluster write-through"),
-                    (self.spec is not None, "speculative decoding (verify)"),
-                    (jax.process_count() > 1, "multi-host serving")):
-                if on:
-                    raise ValueError(self._two_caches_refusal(what))
+        if m.has_window:
+            self._refuse_configured(impl, self._two_caches_refusal)
             wk = self.cache_kinds[1]
             self.win_pages = cfg.max_batch * WindowPages.lane_pages(
                 wk.window, cfg.prefill_chunk, cfg.page_size) + 1
@@ -557,23 +546,41 @@ class EngineCore:
         # without any error: such a model refuses those features by name.
         self.i_pool = None
         if m.has_indexer:
-            for on, what in (
-                    (cfg.pp > 1, "pp > 1 (the staged forward)"),
-                    (cfg.sp > 1 or impl == "ring", "sp > 1 / ring prefill"),
-                    (cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0,
-                     "the host / disk KV tiers (host_cache_blocks, "
-                     "disk_cache_blocks), and with them cluster "
-                     "write-through, tier prefetch and the paged "
-                     "long-context lane"),
-                    (cfg.cluster_writethrough, "cluster write-through"),
-                    (self.spec is not None, "speculative decoding (verify)"),
-                    (jax.process_count() > 1, "multi-host serving")):
-                if on:
-                    raise ValueError(self._indexer_refusal(what))
+            if cfg.pp > 1:
+                raise ValueError(self._indexer_refusal(
+                    "pp > 1 (the staged forward)"))
+            self._refuse_configured(impl, self._indexer_refusal)
             self.idx_sharding = NamedSharding(self.mesh, P())
             i_shape = llama.index_pool_shape(m, num_pages, cfg.page_size)
             self.i_pool = jax.jit(lambda: jnp.zeros(i_shape, m.dtype),
                                   out_shardings=self.idx_sharding)()
+
+        # A model with state-space layers keeps, per LANE and not per token,
+        # a recurrent state and a convolution tail for each such layer:
+        # [state layers, max_batch, ...] beside the K/V pools of its
+        # attention layers, donated and threaded through the programs with
+        # them. Nothing pages or hashes it, and a K/V block re-entered or
+        # moved without the state at its boundary would decode from the
+        # wrong state: such a model refuses those features by name too.
+        self.s_pool = self.c_pool = None
+        if m.has_state:
+            self._refuse_configured(impl, self._state_refusal)
+            self.state_sharding = NamedSharding(self.mesh, P())
+            s_shape, c_shape = self.cache_kinds[1].state_shapes(cfg.max_batch)
+            self.s_pool = jax.jit(lambda: jnp.zeros(s_shape, jnp.float32),
+                                  out_shardings=self.state_sharding)()
+            self.c_pool = jax.jit(lambda: jnp.zeros(c_shape, m.dtype),
+                                  out_shardings=self.state_sharding)()
+            self.stage.ssm_state_bytes.set(value=float(
+                self.cache_kinds[1].lane_bytes(np.dtype(m.dtype).itemsize)
+                * cfg.max_batch))
+            if cfg.enable_prefix_reuse:
+                log.info("prefix reuse is off for this model: a block of "
+                         "the K/V cache cannot be re-entered without the "
+                         "state-space layers' state at its boundary (no "
+                         "block is hashed, sealed or published)")
+        # no block of such a model is hashed, matched or adopted
+        self._no_block_reuse = self.win is not None or m.has_state
 
         # --- KV block manager: tiered offload + prefix reuse ----------
         from ..llm.kvbm.transfer import CopyStream
@@ -782,6 +789,25 @@ class EngineCore:
         if cfg.warmup:
             self.warmup()
 
+    def _refuse_configured(self, impl: str, refusal) -> None:
+        """Raise ``refusal(what)`` for the first feature this engine was
+        CONFIGURED with that moves, matches or re-enters K/V blocks (the
+        calls that do so at run time go through
+        :meth:`_refuse_block_moves`)."""
+        cfg = self.cfg
+        for on, what in (
+                (cfg.sp > 1 or impl == "ring", "sp > 1 / ring prefill"),
+                (cfg.host_cache_blocks > 0 or cfg.disk_cache_blocks > 0,
+                 "the host / disk KV tiers (host_cache_blocks, "
+                 "disk_cache_blocks), and with them cluster "
+                 "write-through, tier prefetch and the paged "
+                 "long-context lane"),
+                (cfg.cluster_writethrough, "cluster write-through"),
+                (self.spec is not None, "speculative decoding (verify)"),
+                (jax.process_count() > 1, "multi-host serving")):
+            if on:
+                raise ValueError(refusal(what))
+
     @staticmethod
     def _indexer_refusal(what: str) -> str:
         return (f"a model with an indexer (learned top-k attention) does "
@@ -796,23 +822,37 @@ class EngineCore:
                 f"with {what}: it would move, match or re-enter blocks of "
                 f"the global cache without the window layers' keys")
 
+    @staticmethod
+    def _state_refusal(what: str) -> str:
+        return (f"a model with state-space layers (a recurrent state a lane "
+                f"beside the K/V cache) does not run with {what}: it would "
+                f"move, match or re-enter K/V blocks without the state at "
+                f"their boundary, or advance a state that cannot be rolled "
+                f"back")
+
     def _refuse_block_moves(self, what: str) -> None:
         """Refuse a feature that moves K/V blocks off or onto the device
         pool for a model that keeps more than K and V on those pages (an
-        indexer's keys) or keeps a second cache beside them (a per-kind
-        model's window layers)."""
+        indexer's keys), keeps a second cache beside them (a per-kind
+        model's window layers) or keeps a state a lane that no block holds
+        (state-space layers)."""
         if self.cfg.model.has_indexer:
             raise ValueError(self._indexer_refusal(what))
-        if self.cfg.model.per_kind:
+        if self.cfg.model.has_window:
             raise ValueError(self._two_caches_refusal(what))
+        if self.cfg.model.has_state:
+            raise ValueError(self._state_refusal(what))
 
     def _idx(self) -> Dict[str, Any]:
         """The programs' further pool operands: the index-key pool of a
         model with an indexer, the window cache's two pools of a per-kind
-        model, nothing for every other model (whose programs therefore
-        compile to what they always did)."""
+        model, the state and convolution-tail pools of a model with
+        state-space layers, nothing for every other model (whose programs
+        therefore compile to what they always did)."""
         if self.win is not None:
             return {"wk_pool": self.wk_pool, "wv_pool": self.wv_pool}
+        if self.s_pool is not None:
+            return {"s_pool": self.s_pool, "c_pool": self.c_pool}
         return {} if self.i_pool is None else {"i_pool": self.i_pool}
 
     def _program_extras(self):
@@ -827,9 +867,12 @@ class EngineCore:
         if m.has_indexer:
             return ({"donate_argnames": ("i_pool",)}, (self.idx_sharding,),
                     cols)
-        if m.per_kind:
+        if m.has_window:
             return ({"donate_argnames": ("wk_pool", "wv_pool")},
                     (self.kv_sharding, self.kv_sharding), cols)
+        if m.has_state:
+            return ({"donate_argnames": ("s_pool", "c_pool")},
+                    (self.state_sharding, self.state_sharding), cols)
         return {}, (), cols
 
     @cached_property
@@ -839,11 +882,13 @@ class EngineCore:
         return self._program_extras()[2]
 
     def _take_pools(self, pools) -> None:
-        """(k_pool, v_pool[, i_pool | wk_pool, wv_pool]) as a program
-        returned them."""
+        """(k_pool, v_pool[, i_pool | wk_pool, wv_pool | s_pool, c_pool])
+        as a program returned them."""
         self.k_pool, self.v_pool, *rest = pools
         if self.win is not None:
             self.wk_pool, self.wv_pool = rest
+        elif self.s_pool is not None:
+            self.s_pool, self.c_pool = rest
         elif rest:
             self.i_pool, = rest
 
@@ -858,6 +903,40 @@ class EngineCore:
                 "w_pages": np.zeros((Bp, Sw // self.page_size), np.int32),
                 "w_pos": np.zeros((Bp, Sw), np.int32),
                 "w_valid": np.zeros((Bp, Sw), bool)}
+
+    def _ssm_rows(self, Bp: int) -> Dict[str, Any]:
+        """The state operands of a prefill program of ``Bp`` rows with no
+        row filled in: every row names a lane past the pool (writes
+        nothing) and holds no real token. Warm-up passes them as they are;
+        a dispatch fills in its rows."""
+        if self.s_pool is None:
+            return {}
+        return {"s_lanes": np.full(Bp, self.cfg.max_batch, np.int32),
+                "s_reset": np.zeros(Bp, bool),
+                "s_valid": np.zeros(Bp, np.int32)}
+
+    def _count_state_work(self, kind: str, lane_steps: int, served: int,
+                          tokens: int, resets: int, captured: bool) -> None:
+        """Host counters of what a dispatch made the state-space layers do
+        (one layer's worth): ``lane_steps`` whose state it read and wrote
+        (decode: every lane of the pool, each step), those of lanes it
+        ``served``, the real ``tokens`` through the mixers, the lanes it
+        started from a zero state; and the traced dispatches' share while a
+        ``DYN_PROFILE_DIR`` capture runs, as :meth:`_count_model_work`."""
+        st = self.stage
+        work = {st.ssm_lane_steps: float(lane_steps),
+                st.ssm_active_lane_steps: float(served),
+                st.ssm_tokens: float(tokens)}
+        for counter, amount in work.items():
+            counter.inc(kind, amount=amount)
+        if resets:
+            st.ssm_state_resets.inc(amount=float(resets))
+        if captured:
+            seen_by = st.profile_captured_work
+            for counter, amount in work.items():
+                seen_by.inc(counter.name, kind, amount=amount)
+            seen_by.inc("dispatches", kind)
+            seen_by.inc("tokens", kind, amount=float(tokens))
 
     def _release_seq(self, seq_id: str) -> None:
         self.pool.release(seq_id)
@@ -933,7 +1012,7 @@ class EngineCore:
                 seen_by.inc("scored_keys", kind, amount=float(seen))
                 seen_by.inc("scoring_dispatches", kind)
                 seen_by.inc("scoring_tokens", kind, amount=float(tokens))
-            if m.per_kind:
+            if m.has_window:
                 # what the traced dispatches' attention had to read and
                 # multiply at least, by kind of layer (one layer's worth):
                 # ``*_keys`` the keys read (a decode query reads its lane's
@@ -1024,7 +1103,8 @@ class EngineCore:
                         np.zeros((Bp, S), bool),
                         np.zeros(Bp, np.int32), np.zeros(Bp, np.float32),
                         np.ones(Bp, np.float32), np.zeros(Bp, np.int32),
-                        keys, **self._idx(), **self._win_dummies(Bp, C))
+                        keys, **self._idx(), **self._win_dummies(Bp, C),
+                        **self._ssm_rows(Bp))
                     self._take_pools(pools)
                     n += 1
             # a chunk of this many lanes handing first tokens to a decode
@@ -1081,14 +1161,14 @@ class EngineCore:
             # bucket program compiles a second variant against it
             B = self.cfg.max_batch
             jit_kw, out_tail, hit_col = self._program_extras()
-            per_kind = cfg.model.per_kind
+            windowed, stateful = cfg.model.has_window, cfg.model.has_state
 
             @partial(jax.jit, donate_argnums=(2, 3, 10), **jit_kw,
                      out_shardings=(rep, rep, rep, kv, kv, rep, *out_tail))
             def step(params, tokens, k_pool, v_pool, page_tables, lengths,
                      temp, top_p, top_k, key, counts, fresh, active,
                      freq_pen, pres_pen, i_pool=None, wk_pool=None,
-                     wv_pool=None, w_tables=None):
+                     wv_pool=None, w_tables=None, s_pool=None, c_pool=None):
                 # lanes whose sequence just entered decode restart their
                 # generated-token counts at one-hot(first generated token);
                 # chained dispatches pass fresh all-False
@@ -1116,7 +1196,10 @@ class EngineCore:
                             params, cfg.model, tokens, k_pool, v_pool,
                             page_tables, lengths, attn_impl=impl, mesh=mesh,
                             stats=stats,
-                            **({"win": (*ip, w_tables)} if per_kind
+                            **({"win": (*ip, w_tables)} if windowed
+                               # a lane this dispatch does not serve keeps
+                               # its state: it cannot be trimmed afterwards
+                               else {"ssm": (*ip, active)} if stateful
                                else {"i_pool": ip[0]} if ip else {}))
                     lg = apply_penalties(logits[:, 0], counts, freq_pen,
                                          pres_pen)
@@ -1131,7 +1214,8 @@ class EngineCore:
                              counts, *ip), ys)
 
                 carry = (tokens, lengths, k_pool, v_pool, key, counts,
-                         *((wk_pool, wv_pool) if per_kind
+                         *((wk_pool, wv_pool) if windowed
+                           else (s_pool, c_pool) if stateful
                            else () if i_pool is None else (i_pool,)))
                 ((tok, lengths, k_pool, v_pool, key, counts, *ip),
                  (toks, logps, *hit)) = jax.lax.scan(one, carry, None,
@@ -1177,7 +1261,9 @@ class EngineCore:
                    read_idx, read_pos, read_valid, last_i, temp, top_p,
                    top_k, keys, ov_vals=None, ov_mask=None, q_span=None,
                    read_span=None, i_pool=None, wk_pool=None, wv_pool=None,
-                   w_write=None, w_pages=None, w_pos=None, w_valid=None):
+                   w_write=None, w_pages=None, w_pos=None, w_valid=None,
+                   s_pool=None, c_pool=None, s_lanes=None, s_reset=None,
+                   s_valid=None):
                 stats: Dict[str, Any] = {}
                 ip = ()
                 if cfg.pp > 1:
@@ -1203,6 +1289,8 @@ class EngineCore:
                         **({} if wk_pool is None else {"win": (
                             wk_pool, wv_pool, w_write, w_pages, w_pos,
                             w_valid)}),
+                        **({} if s_pool is None else {"ssm": (
+                            s_pool, c_pool, s_lanes, s_reset, s_valid)}),
                         embed_override=((ov_vals, ov_mask) if mm else None),
                         attn_spans=((q_span, read_span) if mm else None),
                         # read slots come from PagePool.read_slots: whole
@@ -2118,13 +2206,14 @@ class EngineCore:
         slot.trace_parent = parent
         self.slots[slot_idx] = slot
         self.by_seq[seq_id] = slot
-        # (a per-kind model hashes, seals and matches no block: see __init__)
+        # (a model with a window cache or a state a lane hashes, seals and
+        # matches no block: see __init__)
         self.pool.create(seq_id, lora_id=chain_salt,
-                         block_hashing=self.win is None)
+                         block_hashing=not self._no_block_reuse)
         if self.win is not None:
             self.win.create(seq_id)
         matched = 0
-        if self.cfg.enable_prefix_reuse and self.win is None:
+        if self.cfg.enable_prefix_reuse and not self._no_block_reuse:
             matched = self._restore_prefix(seq_id, prompt)
             slot.prefill_done = matched
         slot.prefix_hit = matched
@@ -2224,7 +2313,8 @@ class EngineCore:
     def _run_prefill_program(self, Bp, C, S, tokens, positions, write_idx,
                              read_idx, read_pos, read_valid, last_i, temp,
                              top_p, top_k, idxs, last_lanes,
-                             mm_arrays=None, win_arrays=None):
+                             mm_arrays=None, win_arrays=None,
+                             ssm_arrays=None):
         """Execute the batched prefill program + key bookkeeping. The SAME
         code path runs on the leader (from _prefill_enqueue) and on
         followers (from mirror_dispatch) so device state stays in lockstep."""
@@ -2244,7 +2334,7 @@ class EngineCore:
                 self.params, tokens, positions, self.k_pool, self.v_pool,
                 write_idx, read_idx, read_pos, read_valid, last_i,
                 temp, top_p, top_k, keys, **self._idx(),
-                **(win_arrays or {}))
+                **(win_arrays or {}), **(ssm_arrays or {}))
             self._take_pools(pools)
         self._last_prefill_tok = tok
         self.phase.to("prefill_build")
@@ -2329,6 +2419,15 @@ class EngineCore:
                 (win_arrays["w_pages"][lane], win_arrays["w_pos"][lane],
                  win_arrays["w_valid"][lane]) = self.win.read_window(
                     slot.seq_id, start, count, Pw)
+        # a row's chunk starts from the state its lane holds (from zeros
+        # where its sequence starts here: admission, or a re-prefill after
+        # preemption) and leaves the state behind its last real token
+        ssm_arrays = self._ssm_rows(Bp)
+        if ssm_arrays:
+            for lane, (i, _, start, count, _) in enumerate(work):
+                ssm_arrays["s_lanes"][lane] = i
+                ssm_arrays["s_reset"][lane] = start == 0
+                ssm_arrays["s_valid"][lane] = count
         mm = any(w[1].mm_spans is not None for w in work)
         mm_arrays = None
         if mm:
@@ -2389,7 +2488,12 @@ class EngineCore:
             Bp, C, S, tokens, positions, write_idx, read_idx, read_pos,
             read_valid, last_i, temp, top_p, top_k, idxs, last_lanes,
             mm_arrays=mm_arrays,
-            **({} if win_arrays is None else {"win_arrays": win_arrays}))
+            **({} if win_arrays is None else {"win_arrays": win_arrays}),
+            **({"ssm_arrays": ssm_arrays} if ssm_arrays else {}))
+        if ssm_arrays:
+            self._count_state_work(
+                "prefill", Bp, len(work), sum(w[3] for w in work),
+                int(ssm_arrays["s_reset"].sum()), captured)
         self.stage.engine_dispatch_tokens.inc(
             "prefill", amount=float(sum(w[3] for w in work)))
         self._inflight.append({"kind": "prefill",
@@ -2650,6 +2754,9 @@ class EngineCore:
         packed, final_tok = self._run_decode_program(
             S, tokens, page_tables, lengths, fresh, active_mask, joining,
             **({} if w_tables is None else {"w_tables": w_tables}))
+        if self.s_pool is not None:
+            self._count_state_work("decode", B * N, len(active) * N,
+                                   len(active) * N, 0, self.capturing)
         self.stage.engine_dispatch_tokens.inc(
             "decode", amount=float(len(active) * N))
         self._inflight.append({"kind": "decode",
@@ -3040,7 +3147,7 @@ def _pallas_probe(m, cfg, device) -> None:
     # the pools store them
     kw = dict(scale=m.attn_scale, softcap=m.attn_logit_softcap)
     Dk, Dv = m.k_store_dim, m.v_dim
-    if m.per_kind:
+    if m.has_window:
         variants = [(k.kv_heads, k.window,
                      m.sink_window if k.window else m.sink_full)
                     for k in cache_kinds(m)]
@@ -3055,7 +3162,8 @@ def _pallas_probe(m, cfg, device) -> None:
         pos = jnp.zeros((2, T), jnp.int32)
         for hkv, w, sunk in variants:
             q = jnp.zeros((2, Hq, Dk), m.dtype)
-            kp = jnp.zeros((2, hkv, 3, page, Dk), m.dtype)  # 2 layers' pool
+            f = m.kv_fold     # 2 layers' pool, as the engine stores it
+            kp = jnp.zeros((2, hkv, 3, page // f, f * Dk), m.dtype)
             vp = kp if Dv == Dk else jnp.zeros((2, hkv, 3, page, Dv),
                                                m.dtype)
             qf = jnp.zeros((2, T, Hq, Dk), m.dtype)
@@ -3063,7 +3171,9 @@ def _pallas_probe(m, cfg, device) -> None:
             vf = kf if Dv == Dk else jnp.zeros((2, T, hkv, Dv), m.dtype)
             sink = {"sink": jnp.zeros((Hq,), jnp.float32)} if sunk else {}
             paged_attention(q, kp, vp, pt, ln, 1, interpret=False,
-                            window=w, **kw, **sink).block_until_ready()
+                            window=w, **kw, **sink,
+                            **({"fold": f} if f > 1 else {})
+                            ).block_until_ready()
             flash_attention(qf, kf, vf, pos, pos, pos < 1, interpret=False,
                             window=w, **kw, **sink).block_until_ready()
 
@@ -3191,8 +3301,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
             str(os.getpid()), core.attn_impl, core.decode_attn_impl,
             core.paged_kernel or "none", dev0.platform, dev0.device_kind,
             str(core.mesh.devices.size), core.goodput.peaks.source,
-            "+".join(f"{k.name}:{k.layers}x{k.kv_heads}x({k.k_dim}+{k.v_dim})"
-                     for k in core.cache_kinds), value=1)
+            "+".join(k.label() for k in core.cache_kinds), value=1)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._queues: Dict[str, asyncio.Queue] = {}
         self._inbox: thread_queue.Queue = thread_queue.Queue()

@@ -250,6 +250,8 @@ class PagedPrograms:
         variants and ARE servable; what remains excluded is structure the
         segmented forward itself cannot express."""
         m = cfg.model
+        if m.has_state:
+            return f"models with state-space layers ({llama.NO_STATE})"
         if m.per_kind:
             return (f"models whose window layers keep a cache of their own "
                     f"({llama.NO_SECOND_CACHE})")

@@ -171,8 +171,8 @@ class LlamaConfig:
     # A per-layer description where one law for all layers does not hold
     # (MiMo-V2-Flash; ROADMAP Design 2). ``layer_kinds[l]``: 0 full
     # attention, 1 window attention (``sliding_window`` keys with the
-    # query's own); ``ffn_kinds[l]``: 0 dense feed-forward, 1 routed
-    # experts. With ``layer_kinds`` the two attention kinds have parameter
+    # query's own), 2 a state-space mixer in place of attention (below);
+    # ``ffn_kinds[l]``: 0 dense feed-forward, 1 routed experts. With ``layer_kinds`` the two attention kinds have parameter
     # stacks, head counts and CACHES of their own (:meth:`cache_kinds`):
     # window layers have ``window_kv_heads`` K/V heads and keep a window of
     # cache in a page pool of their own.
@@ -201,15 +201,81 @@ class LlamaConfig:
     # computes the held part (models/moe.py)
     router_experts: Optional[int] = None
     expert_first: int = 0
+    # State-space layers (Granite-4.0-H; ``layer_kinds[l] == 2``): a
+    # Mamba-2 mixer in place of attention, which keeps per LANE a recurrent
+    # state [ssm_heads, ssm_head_dim, ssm_state] float32 and the last
+    # ``ssm_conv - 1`` inputs of its depthwise convolution, and nothing per
+    # token (:meth:`cache_kinds`' ``state`` kind; "The state-space mixer"
+    # below). ``ssm_heads`` 0: no such layer.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_conv_bias: bool = True
+    # attention without positional encoding (``position_embedding_type``
+    # "nope"): q and k go to the scores as projected
+    use_rope: bool = True
+    # the Granite family's four multipliers, each None where the config
+    # carries none (= 1): the softmax scale itself (not 1/sqrt(head_dim)),
+    # the embedding rows, both residual adds, and logits / logits_scaling
+    attn_multiplier: Optional[float] = None
+    embed_multiplier: Optional[float] = None
+    residual_multiplier: Optional[float] = None
+    logits_scaling: Optional[float] = None
+    # K/V rows narrower than a 128-lane tile stored ``kv_fold`` tokens to a
+    # pool row ([.., page // kv_fold, kv_fold * head_dim]; "KV pool access")
+    kv_fold: int = 1
 
     @property
     def per_kind(self) -> bool:
-        """Window and full layers with stacks and caches of their own."""
+        """Layers described one by one, with parameter stacks by kind."""
         return self.layer_kinds is not None
+
+    @property
+    def has_window(self) -> bool:
+        """Window layers that keep a window of cache in pools of their own."""
+        return self.per_kind and 1 in self.layer_kinds
+
+    @property
+    def has_state(self) -> bool:
+        """State-space layers that keep a recurrent state a lane."""
+        return self.per_kind and 2 in self.layer_kinds
 
     def layer_window(self, l: int) -> bool:
         """Layer ``l`` keeps its K/V in the window cache (a Python bool)."""
         return self.per_kind and self.layer_kinds[l] == 1
+
+    def layer_state(self, l: int) -> bool:
+        """Layer ``l`` is a state-space mixer (a Python bool)."""
+        return self.per_kind and self.layer_kinds[l] == 2
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        return tuple(l for l in range(self.num_layers) if self.layer_state(l))
+
+    @property
+    def stream_dtype(self):
+        """The residual stream's dtype: the model's, but float32 for a model
+        that DAMPS every branch (``residual_multiplier``): its 2 L branch
+        outputs enter the stream at a tenth of the stream's own size, and a
+        bfloat16 stream loses 2^-9 of ITSELF at every add, a fiftieth of
+        what is being added (with the head's bfloat16 logits, :func:`_lm_head`,
+        40 layers read 0.011-0.017 sigma rms against the float32 reference,
+        int8 weights in the same range; 0.0025-0.0033 with both in float32,
+        int8 0.0073-0.0197: my chip runs, PR 36, calls C and E). Norms, projections and kernels see the normed
+        activations in the model's dtype as before; only the adds and the
+        norms' inputs are wider."""
+        return (jnp.float32 if self.residual_multiplier is not None
+                else self.dtype)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels through the convolution: X and the one group's B, C."""
+        return self.ssm_inner + 2 * self.ssm_state
 
     def layer_routed(self, l: int) -> bool:
         if self.ffn_kinds is not None:
@@ -219,7 +285,7 @@ class LlamaConfig:
     def kind_layers(self, window: bool) -> Tuple[int, ...]:
         """The layers of one attention kind of a per-kind model, in order."""
         return tuple(l for l in range(self.num_layers)
-                     if self.layer_window(l) == window)
+                     if self.layer_kinds[l] == int(window))
 
     def kv_heads_of(self, window: bool) -> int:
         return (self.window_kv_heads if window and self.window_kv_heads
@@ -267,6 +333,8 @@ class LlamaConfig:
 
     @property
     def attn_scale(self) -> float:
+        if self.attn_multiplier is not None:
+            return float(self.attn_multiplier)
         base = (self.query_pre_attn_scalar
                 if self.query_pre_attn_scalar is not None else self.head_dim)
         return 1.0 / math.sqrt(base)
@@ -301,6 +369,10 @@ class LlamaConfig:
         if cfg.get("model_type") == "gemma3_text":
             # sparse real-checkpoint text_config: class defaults fill the gaps
             cfg = {**_GEMMA3_TEXT_DEFAULTS, **cfg}
+        hybrid = _map_hybrid(cfg)
+        if hybrid:
+            # the feed-forward every layer has is the SHARED one's width
+            cfg = {**cfg, "intermediate_size": cfg["shared_intermediate_size"]}
         return cls(
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
@@ -335,7 +407,7 @@ class LlamaConfig:
             query_pre_attn_scalar=(cfg.get("query_pre_attn_scalar")
                                    if _is_gemma2(cfg) or _is_gemma3(cfg)
                                    else None),
-            sliding_pattern=_sliding_pattern(cfg),
+            sliding_pattern=2 if hybrid else _sliding_pattern(cfg),
             rope_local_theta=(cfg.get("rope_local_base_freq", 10000.0)
                               if _is_gemma3(cfg)
                               else cfg.get("swa_rope_theta")),
@@ -344,6 +416,8 @@ class LlamaConfig:
             **_map_experts(cfg),
             **_map_indexer(cfg),
             **_map_layer_kinds(cfg),
+            **_map_multipliers(cfg),
+            **hybrid,
         )
 
 
@@ -398,6 +472,17 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
             f"to serve a sparse model as a dense one")
     E = cfg.get("num_experts", cfg.get("num_local_experts",
                                        cfg.get("n_routed_experts")))
+    if (not E and cfg.get("num_local_experts") == 0
+            and not cfg.get("num_experts_per_tok")
+            and "shared_intermediate_size" in cfg):
+        # Granite-4.0-H: "no routed experts; shared_intermediate_size is the
+        # feed-forward width" (a dense model that says so in expert keys)
+        return {}
+    if E and "shared_intermediate_size" in cfg:
+        raise ValueError(
+            f"num_local_experts {E} beside shared_intermediate_size: routed "
+            f"experts beside a shared feed-forward in every layer are not "
+            f"implemented (the larger Granite-4.0-H models)")
     if not E:
         raise ValueError(f"expert keys {sorted(seen)} without num_experts / "
                          f"num_local_experts / n_routed_experts")
@@ -553,6 +638,79 @@ def _map_layer_kinds(cfg: Dict[str, Any]) -> Dict[str, Any]:
         sink_window=bool(cfg.get("add_swa_attention_sink_bias", False)),
         sink_full=bool(cfg.get("add_full_attention_sink_bias", False)))
     return out
+
+
+def _map_multipliers(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The Granite family's four multipliers, for any model that carries
+    them; a key that is absent means 1 and changes no program."""
+    names = {"attention_multiplier": "attn_multiplier",
+             "embedding_multiplier": "embed_multiplier",
+             "residual_multiplier": "residual_multiplier",
+             "logits_scaling": "logits_scaling"}
+    return {ours: float(cfg[k]) for k, ours in names.items()
+            if cfg.get(k) is not None}
+
+
+# every ``mamba_*`` key of a published hybrid config, and what this engine
+# implements of each
+_HYBRID_KEYS = ("mamba_chunk_size", "mamba_conv_bias", "mamba_d_conv",
+                "mamba_d_head", "mamba_d_state", "mamba_expand",
+                "mamba_n_groups", "mamba_n_heads", "mamba_proj_bias")
+
+
+def _map_hybrid(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """``layer_types`` with state-space layers (Granite-4.0-H,
+    ``granitemoehybrid``) -> ours: ``layer_kinds`` 2 (mamba) / 0
+    (attention), the mixer's sizes, rotary on or off, and the K/V rows'
+    fold. Every key the engine cannot honour RAISES; a config without a
+    ``mamba`` layer is none of this function's business."""
+    lt = cfg.get("layer_types")
+    stray = [k for k in cfg if k.startswith("mamba_")]
+    if not lt or "mamba" not in lt:
+        if stray and cfg.get("model_type") == "granitemoehybrid":
+            raise ValueError(f"config carries {sorted(stray)} and no "
+                             f"layer_types that names a mamba layer")
+        return {}
+    L = cfg["num_hidden_layers"]
+    bad = sorted({t for t in lt if t not in ("mamba", "attention")})
+    if bad or len(lt) < L:
+        raise ValueError(f"layer_types must list 'mamba' or 'attention' for "
+                         f"each of the {L} layers (got {bad or len(lt)})")
+    unknown = sorted(set(stray) - set(_HYBRID_KEYS))
+    if unknown:
+        raise ValueError(f"config carries mamba keys this engine does not "
+                         f"implement: {unknown}")
+    pos = cfg.get("position_embedding_type", "rope")
+    if pos not in ("nope", "rope"):
+        raise ValueError(f"position_embedding_type {pos!r} is not "
+                         f"implemented (nope, rope)")
+    if cfg.get("normalization_function", "rmsnorm") != "rmsnorm":
+        raise ValueError(f"normalization_function "
+                         f"{cfg['normalization_function']!r} is not "
+                         f"implemented (rmsnorm)")
+    if cfg.get("mamba_n_groups", 1) != 1:
+        raise ValueError(f"mamba_n_groups {cfg['mamba_n_groups']} is not "
+                         f"implemented: all heads share one B and one C")
+    H, P = int(cfg["mamba_n_heads"]), int(cfg["mamba_d_head"])
+    if H * P != int(cfg.get("mamba_expand", 2)) * cfg["hidden_size"]:
+        raise ValueError(
+            f"mamba_d_head x mamba_n_heads = {H * P} is not mamba_expand x "
+            f"hidden_size = {cfg.get('mamba_expand', 2) * cfg['hidden_size']}")
+    if cfg.get("mamba_proj_bias", False):
+        raise ValueError("mamba_proj_bias true is not implemented")
+    if "shared_intermediate_size" not in cfg:
+        raise ValueError("a hybrid config names its feed-forward width in "
+                         "shared_intermediate_size")
+    Dh = cfg.get("head_dim",
+                 cfg["hidden_size"] // cfg["num_attention_heads"])
+    return {"layer_kinds": tuple(2 if t == "mamba" else 0 for t in lt[:L]),
+            "ssm_heads": H, "ssm_head_dim": P,
+            "ssm_state": int(cfg["mamba_d_state"]),
+            "ssm_conv": int(cfg.get("mamba_d_conv", 4)),
+            "ssm_conv_bias": bool(cfg.get("mamba_conv_bias", True)),
+            "use_rope": pos == "rope",
+            # rows narrower than a lane tile: whole tiles, tokens folded
+            "kv_fold": max(1, 128 // Dh) if 128 % Dh == 0 else 1}
 
 
 def _map_indexer(cfg: Dict[str, Any]) -> Dict[str, Any]:
@@ -956,12 +1114,28 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         return jax.jit(lambda k: jax.lax.map(one, jax.random.split(k, n))
                        )(next(ks))
 
-    res = 1.0 / math.sqrt(2 * L)       # projections into the stream
+    # projections into the stream: GPT-2's 1 / sqrt(2 L), or the model's
+    # own damping of both residual adds where it has one
+    res = (1.0 / math.sqrt(2 * L) if cfg.residual_multiplier is None
+           else 1.0)
     stacks: Dict[str, Any] = {}
     for name, window in (("full", False), ("window", True)):
         n, Hkv = len(cfg.kind_layers(window)), cfg.kv_heads_of(window)
+        if not n:
+            continue
+        qk = 1.0
+        if cfg.attn_multiplier is not None:
+            # scores of spread 1.5 under the model's OWN scale, as
+            # init_params' second law sets it and for its reasons: at N(0,
+            # 1 / fan-in) a scale of 1 / head_dim leaves the scores a spread
+            # of 1 / 8, attention is a plain average of hundreds of values,
+            # and neither the scale nor a rotary switched on shows in the
+            # logits (rel_rms 0.0146 / 0.0138 beside a sound 0.0123; my
+            # chip run, PR 36, call B)
+            qk = math.sqrt(1.5 / (math.sqrt(Dh) * cfg.attn_scale))
         st = {"ln1": jnp.ones((n, D), jnp.float32),
-              "wq": mat(n, D, D, Hq, Dh), "wk": mat(n, D, D, Hkv, Dh),
+              "wq": mat(n, D, D, Hq, Dh, scale=qk),
+              "wk": mat(n, D, D, Hkv, Dh, scale=qk),
               "wv": mat(n, D, D, Hkv, Dv),
               "wo": mat(n, Hq * Dv, Hq, Dv, D, scale=res)}
         if cfg.sink_window if window else cfg.sink_full:
@@ -983,13 +1157,51 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             st["rbias"] = 0.02 * jax.random.normal(next(ks), (nr, R),
                                                    jnp.float32)
         stacks["routed"] = st
-    params = {"embed": jax.random.normal(next(ks), (V, D), jnp.float32
-                                         ).astype(cfg.dtype),
+    if cfg.has_state:
+        stacks["mamba"] = _init_mamba(cfg, ks, mat, res)
+    params = {"embed": (jax.random.normal(next(ks), (V, D), jnp.float32)
+                        / (cfg.embed_multiplier or 1.0)).astype(cfg.dtype),
               STACKS: stacks,
               "final_norm": jnp.ones((D,), jnp.float32)}
     if not cfg.tie_embeddings:
         params["lm_head"] = mat(D, D, V)
     return params
+
+
+def _init_mamba(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
+    """The state-space layers' stack, seeded as Mamba-2 is published to be
+    initialised, so that the state's memory spans a few tokens to a few
+    thousand and a lost carry shows in the logits: ``A_log`` = log U(1, 16),
+    ``dt_bias`` = softplus^-1 of a log-uniform step in (0.001, 0.1), ``D``
+    = 1, the depthwise convolution and its bias U(-1/sqrt(k), 1/sqrt(k))
+    (PyTorch's Conv1d default at fan-in k), the gated norm's weight 1; the
+    two projections by the law of :func:`_init_per_kind`. The published
+    fused input projection [z | X, B, C | dt] is held as two matrices:
+    ``w_in`` [D, I + Cd] = [z | X, B, C], 8448 columns at the published
+    widths, whole 128-lane tiles, and ``w_dt`` [D, H], the step logits'
+    64 columns: a stack 8512 columns wide is no whole number of tiles, and
+    XLA re-laid all of it (1.25 GB) at every decode dispatch's entry (my
+    chip run, PR 36)."""
+    n, D, K = len(cfg.state_layers), cfg.hidden_size, cfg.ssm_conv
+    H, I, Cd = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+
+    def uniform(shape, lo, hi):
+        return jax.random.uniform(next(ks), shape, jnp.float32, lo, hi)
+
+    dt = jnp.exp(uniform((n, H), math.log(1e-3), math.log(1e-1)))
+    b = 1.0 / math.sqrt(K)
+    st = {"ln1": jnp.ones((n, D), jnp.float32),
+          "w_in": mat(n, D, D, I + Cd),
+          "w_dt": mat(n, D, D, H),
+          "conv_w": uniform((n, K, Cd), -b, b).astype(cfg.dtype),
+          "A_log": jnp.log(uniform((n, H), 1.0, 16.0)),
+          "D": jnp.ones((n, H), jnp.float32),
+          "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+          "norm": jnp.ones((n, I), jnp.float32),
+          "w_out": mat(n, I, I, D, scale=res)}
+    if cfg.ssm_conv_bias:
+        st["conv_b"] = uniform((n, Cd), -b, b).astype(cfg.dtype)
+    return st
 
 
 def layer_stacks(params: Dict[str, Any], cfg: LlamaConfig, l: int):
@@ -999,10 +1211,10 @@ def layer_stacks(params: Dict[str, Any], cfg: LlamaConfig, l: int):
     if not cfg.per_kind:
         return params["layers"], l, params["layers"], l
     st = params[STACKS]
-    win, routed = cfg.layer_window(l), cfg.layer_routed(l)
-    la = sum(cfg.layer_window(i) == win for i in range(l))
+    kind, routed = cfg.layer_kinds[l], cfg.layer_routed(l)
+    la = sum(k == kind for k in cfg.layer_kinds[:l])
     lf = sum(cfg.layer_routed(i) == routed for i in range(l))
-    return (st["window" if win else "full"], la,
+    return (st[("full", "window", "mamba")[kind]], la,
             st["routed" if routed else "dense"], lf)
 
 
@@ -1084,6 +1296,11 @@ def validate_tp(cfg: LlamaConfig, tp: int, ep: int = 1) -> None:
         raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp={tp}")
     if not cfg.num_experts and cfg.intermediate_size % tp:
         raise ValueError(f"ffn {cfg.intermediate_size} not divisible by tp={tp}")
+    if cfg.has_state and (tp > 1 or ep > 1):
+        raise ValueError(
+            "a model with state-space layers runs on one chip: its stacks, "
+            "its per-lane state pool and its K/V pool are not sharded "
+            f"(got tp={tp}, ep={ep})")
     if cfg.per_kind and (tp > 1 or ep > 1):
         raise ValueError(
             "a model with window and full layers of their own head counts "
@@ -1107,7 +1324,8 @@ def validate_pp(cfg: LlamaConfig, pp: int, tp: int = 1) -> None:
     if pp <= 1:
         return
     if cfg.per_kind:
-        raise ValueError(f"pp={pp}: {NO_SECOND_CACHE}")
+        raise ValueError(f"pp={pp}: "
+                         f"{NO_STATE if cfg.has_state else NO_SECOND_CACHE}")
     if cfg.num_layers % pp:
         raise ValueError(
             f"num_layers {cfg.num_layers} not divisible by pp={pp}")
@@ -1163,6 +1381,14 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float,
     return (xf * scale * wf).astype(x.dtype)
 
 
+def _normed(x: jax.Array, w: jax.Array, cfg: "LlamaConfig") -> jax.Array:
+    """RMSNorm of the stream ``x`` as the layer's matrices take it: as it
+    comes for every model whose stream is in the model's dtype, in the
+    model's dtype where the stream is wider (``LlamaConfig.stream_dtype``)."""
+    h = rms_norm(x, w, cfg.rms_eps, cfg.norm_offset)
+    return h if cfg.stream_dtype == cfg.dtype else h.astype(cfg.dtype)
+
+
 def _act(cfg: "LlamaConfig"):
     if cfg.hidden_act == "gelu_tanh":
         return partial(jax.nn.gelu, approximate=True)
@@ -1177,7 +1403,10 @@ def _embed(params: Dict[str, Any], cfg: "LlamaConfig",
     if cfg.embed_scale:
         # Gemma scales inputs by sqrt(D), rounded through the embed dtype
         x = x * jnp.asarray(math.sqrt(cfg.hidden_size), x.dtype)
-    return x
+    if cfg.embed_multiplier is not None:
+        x = x * jnp.asarray(cfg.embed_multiplier, x.dtype)
+    return (x if cfg.stream_dtype == cfg.dtype
+            else x.astype(cfg.stream_dtype))
 
 
 def _rope_inv_freq(cfg: LlamaConfig, local: bool = False) -> np.ndarray:
@@ -1298,10 +1527,19 @@ def _lm_head(x: jax.Array, params: Dict[str, Any],
              cfg: LlamaConfig) -> jax.Array:
     """Final norm + vocab projection (+ Gemma2 final logit softcap), fp32;
     ``x`` [..., D] with leading dimensions as they come."""
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps, cfg.norm_offset)
+    x = _normed(x, params["final_norm"], cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("...d,dv->...v", x, head.astype(x.dtype))
-    logits = logits.astype(jnp.float32)
+    if cfg.logits_scaling is None:
+        logits = jnp.einsum("...d,dv->...v", x, head.astype(x.dtype))
+        logits = logits.astype(jnp.float32)
+    else:
+        # float32 straight from the accumulator: a bfloat16 result rounds
+        # the LARGEST logits hardest (a spacing of 0.03 sigma at the top of
+        # a 100k vocabulary), which was most of this model's distance from
+        # its float32 reference (LlamaConfig.stream_dtype has the numbers)
+        logits = jnp.einsum("...d,dv->...v", x, head.astype(x.dtype),
+                            preferred_element_type=jnp.float32
+                            ) / cfg.logits_scaling
     if cfg.final_logit_softcap:
         cap = cfg.final_logit_softcap
         logits = jnp.tanh(logits / cap) * cap
@@ -1335,28 +1573,65 @@ def _require_xla_attn(cfg: LlamaConfig, attn_impl: str) -> None:
 # PR 26. Every program that touches a pool goes through these three.
 # ---------------------------------------------------------------------------
 
+#
+# A FOLDED pool (``LlamaConfig.kv_fold`` f > 1: rows narrower than a 128-lane
+# tile) is [L, Hkv, n_pages, page // f, f * Dh]: f consecutive tokens of a
+# page share one pool row, token ``off`` in row ``off // f`` at lanes ``(off
+# % f) * Dh ..``, which is the order the paged dma kernel's copies take. XLA
+# keeps a pool of 64-lane rows pages-minor and re-lays it whole at a decode
+# program's entry and exit; whole-tile rows it leaves as stored (PERF.md §7
+# b, and the index keys' fold below). The three accessors take either.
+
 def kv_write(pool: jax.Array, layer, w_page: jax.Array, w_off: jax.Array,
              rows: jax.Array, mode: Optional[str] = None) -> jax.Array:
     """Write ``rows`` [n, Hkv, Dh] into layer ``layer`` at token slots
-    (``w_page``, ``w_off``), both [n]."""
+    (``w_page``, ``w_off``), both [n]. Into a folded pool (its rows f times
+    as wide as ``rows``) whole pool rows are written: each token's row is
+    read, overlaid with every token of this call that shares it, and
+    scattered back (:func:`index_write` says why)."""
     heads = jnp.arange(pool.shape[1])[None, :]
-    return pool.at[layer, heads, w_page[:, None], w_off[:, None]].set(
-        rows, mode=mode)
+    f = pool.shape[-1] // rows.shape[-1]
+    if f == 1:
+        return pool.at[layer, heads, w_page[:, None], w_off[:, None]].set(
+            rows, mode=mode)
+    n, Hkv, Dh = rows.shape
+    r, slot = w_off // f, w_off % f
+    at = pool.at[layer, heads, w_page[:, None], r[:, None]]
+    old = at.get(mode="clip").reshape(n, Hkv, f, Dh)
+    fills = _fold_fills(w_page, r, slot, f)                      # [n,m,f]
+    new = rows.astype(pool.dtype)[jnp.argmax(fills, axis=1)]     # [n,f,H,Dh]
+    merged = jnp.where(jnp.any(fills, axis=1)[:, None, :, None],
+                       new.transpose(0, 2, 1, 3), old)
+    return at.set(merged.reshape(n, Hkv, f * Dh), mode=mode)
+
+
+def _fold_fills(w_page, r, slot, f: int) -> jax.Array:
+    """[n, m, f] bool: token m of the call fills slot f of token n's row."""
+    shares = (w_page[:, None] == w_page[None]) & (r[:, None] == r[None])
+    return shares[:, :, None] & (slot[None, :, None]
+                                 == jnp.arange(f)[None, None, :])
 
 
 def kv_rows(pool: jax.Array, layer, r_page: jax.Array,
-            r_off: jax.Array) -> jax.Array:
+            r_off: jax.Array, fold: int = 1) -> jax.Array:
     """The rows at token slots (``r_page``, ``r_off``), both [...]:
     [..., Hkv, Dh]."""
     heads = jnp.arange(pool.shape[1])
-    return pool[layer, heads, r_page[..., None], r_off[..., None]]
+    if fold == 1:
+        return pool[layer, heads, r_page[..., None], r_off[..., None]]
+    wide = pool[layer, heads, r_page[..., None], (r_off // fold)[..., None]]
+    wide = wide.reshape(*wide.shape[:-1], fold, -1)
+    return jnp.take_along_axis(
+        wide, (r_off % fold)[..., None, None, None], axis=-2)[..., 0, :]
 
 
-def kv_pages(pool: jax.Array, layer, pages: jax.Array) -> jax.Array:
+def kv_pages(pool: jax.Array, layer, pages: jax.Array,
+             fold: int = 1) -> jax.Array:
     """Whole pages in order — ``pages`` [B, P] page ids — as a context
     [B, P * page, Hkv, Dh]: one [page, Dh] window per (head, page) instead
-    of ``page`` rows."""
-    Hkv, page, Dh = pool.shape[1], pool.shape[3], pool.shape[4]
+    of ``page`` rows (a folded page's rows are its tokens in order)."""
+    Hkv = pool.shape[1]
+    page, Dh = pool.shape[3] * fold, pool.shape[4] // fold
     B, P = pages.shape
     ctx = pool[layer, jnp.arange(Hkv)[None, :, None], pages[:, None, :]]
     return ctx.reshape(B, Hkv, P * page, Dh).transpose(0, 2, 1, 3)
@@ -1399,9 +1674,7 @@ def index_write(pool: jax.Array, layer, w_page: jax.Array, w_off: jax.Array,
         return pool.at[layer, 0, w_page, w_off].set(rows)
     r, slot = w_off // f, w_off % f
     old = pool[layer, 0, w_page, r].reshape(n, f, Di)
-    shares = (w_page[:, None] == w_page[None]) & (r[:, None] == r[None])
-    fills = shares[:, :, None] & (slot[None, :, None]
-                                 == jnp.arange(f)[None, None, :])  # [n,m,f]
+    fills = _fold_fills(w_page, r, slot, f)                        # [n,m,f]
     new = rows[jnp.argmax(fills, axis=1)]                          # [n,f,Di]
     merged = jnp.where(jnp.any(fills, axis=1)[..., None], new, old)
     return pool.at[layer, 0, w_page, r].set(merged.reshape(n, f * Di))
@@ -1483,6 +1756,11 @@ NO_SECOND_CACHE = ("this path carries one K/V cache: a model whose window "
                    "layers keep a window of cache in a page pool of their "
                    "own, with their own head count, needs both")
 
+NO_STATE = ("this path carries K/V blocks alone: a model with state-space "
+            "layers keeps a recurrent state a lane beside them, which no "
+            "block holds, and a block re-entered or moved without the "
+            "state at its boundary would decode from the wrong state")
+
 NO_INDEX_KEYS = ("this cache carries no index-key pool: a model with an "
                  "indexer (learned top-k attention) writes its index keys "
                  "beside K/V and selects from them")
@@ -1517,7 +1795,7 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     beside the slots, and ``stats["keep"]``, where the caller put a list,
     receives the layer's keep mask.
     -> (q [B,T,Hq,Dh], pools, keep [B,T,S] or None)."""
-    h = rms_norm(x, lp["ln1"][l], cfg.rms_eps, cfg.norm_offset)
+    h = _normed(x, lp["ln1"][l], cfg)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
     v = jnp.einsum("btd,dhk->bthk", h, lp["wv"][l])
@@ -1529,12 +1807,13 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         # gemma3: per-head RMSNorm on q/k AFTER projection, BEFORE rope
         q = rms_norm(q, lp["ln_q"][l], cfg.rms_eps, cfg.norm_offset)
         k = rms_norm(k, lp["ln_k"][l], cfg.rms_eps, cfg.norm_offset)
-    q = apply_rope(q, *rope)
-    k = apply_rope(k, *rope)
+    if cfg.use_rope:
+        q = apply_rope(q, *rope)
+        k = apply_rope(k, *rope)
     if cfg.attn_value_scale:
         v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
     k_pool, v_pool, *i_pool = pools
-    pad = k_pool.shape[-1] - k.shape[-1]
+    pad = k_pool.shape[-1] // cfg.kv_fold - k.shape[-1]
     if pad:
         # K rows are stored a whole number of lane tiles wide
         # (LlamaConfig.k_store_dim); zeros beyond head_dim add nothing to
@@ -1578,8 +1857,17 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
         o = jax.lax.psum(o, AXIS_TP)
     if cfg.sandwich_norms:
         o = rms_norm(o, lp["ln1_post"][l], cfg.rms_eps, cfg.norm_offset)
-    return _ffn_block(x + o, *(ffn or (lp, l)), cfg, mesh=mesh, stats=stats,
-                      inside=inside)
+    return _ffn_block(_residual(x, o, cfg), *(ffn or (lp, l)), cfg,
+                      mesh=mesh, stats=stats, inside=inside)
+
+
+def _residual(x: jax.Array, branch: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    """``x`` + the branch's output, damped where the model says so
+    (``residual_multiplier``)."""
+    if cfg.residual_multiplier is not None:
+        # (in the stream's float32: LlamaConfig.stream_dtype)
+        branch = branch.astype(x.dtype) * cfg.residual_multiplier
+    return x + branch
 
 
 def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
@@ -1589,7 +1877,7 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     the branch output (sandwich norms). A routed layer adds its experts hit
     to ``stats["experts_hit"]`` (see :func:`forward`). ``inside`` as
     :func:`layer_out`'s."""
-    h2 = rms_norm(x, lp["ln2"][l], cfg.rms_eps, cfg.norm_offset)
+    h2 = _normed(x, lp["ln2"][l], cfg)
     routed = cfg.num_experts and "wr" in lp   # a per-kind model's dense layers
     if routed and inside is not None:
         # router replicated, experts sharded over ep and their width over tp
@@ -1627,7 +1915,188 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
             out = jax.lax.psum(out, AXIS_TP)
     if cfg.sandwich_norms:
         out = rms_norm(out, lp["ln2_post"][l], cfg.rms_eps, cfg.norm_offset)
-    return x + out
+    return _residual(x, out, cfg)
+
+
+# ---------------------------------------------------------------------------
+# The state-space mixer (Mamba-2, ``layer_kinds[l] == 2``)
+#
+# In place of attention such a layer keeps, per LANE and not per token, a
+# recurrent state H [heads, head_dim, state] float32 and the last ``ssm_conv
+# - 1`` inputs of its depthwise causal convolution. For the normed input v_t:
+# [z | c | d] = W_in v_t; c' = silu(b + sum_k w[k] * c_{t-K+1+k}); c' = [X |
+# B | C]; per head h: dt = softplus(d[h] + dt_bias[h]), a = exp(-dt
+# exp(A_log[h])), H <- a H + dt X[h] (x) B, y[h] = H C + D[h] X[h]; out =
+# W_out RMSNorm_w(y * silu(z)). Two forms that agree: a CHUNK form (state
+# in, T tokens, state out: the structured-matrix form over the whole chunk,
+# T <= a prefill chunk) and a one-TOKEN form for decode. A position that is
+# not valid (padding behind a lane's tokens) has dt = 0: a = 1 and nothing
+# is added, so state and convolution tail are what they were, bit for bit.
+# The pools are [state layers, lanes, ...]; a run of such layers between two
+# attention layers is ONE ``lax.scan`` whose carry holds both pools, indexed
+# by layer inside it and updated in place (a program holds a handful of
+# layer bodies, whatever the depth: set-up time).
+# ---------------------------------------------------------------------------
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _ssm_conv(window: jax.Array, lp: Dict[str, Any], l, T: int) -> jax.Array:
+    """Depthwise causal convolution + silu over ``window`` [B, K - 1 + T,
+    Cd] (the tail, then the new inputs): [B, T, Cd] float32."""
+    w = lp["conv_w"][l].astype(jnp.float32)                       # [K, Cd]
+    acc = sum(w[k] * window[:, k:k + T].astype(jnp.float32)
+              for k in range(w.shape[0]))
+    if "conv_b" in lp:
+        acc = acc + lp["conv_b"][l].astype(jnp.float32)
+    return jax.nn.silu(acc)
+
+
+def _ssm_split(xbc: jax.Array, d: jax.Array, lp: Dict[str, Any], l,
+               cfg: LlamaConfig):
+    """-> (X [..., H, P], B [..., N], C [..., N], dt [..., H], A [H] < 0)."""
+    I, N, H = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
+    X = xbc[..., :I].reshape(*xbc.shape[:-1], H, cfg.ssm_head_dim)
+    dt = jax.nn.softplus(d.astype(jnp.float32) + lp["dt_bias"][l])
+    return (X, xbc[..., I:I + N], xbc[..., I + N:], dt,
+            -jnp.exp(lp["A_log"][l]))
+
+
+def ssm_chunk(c: jax.Array, d: jax.Array, lp: Dict[str, Any], l,
+              cfg: LlamaConfig, state: jax.Array, tail: jax.Array,
+              n_valid: jax.Array):
+    """The chunk form. ``c`` [B,T,Cd], ``d`` [B,T,H]: the projected
+    convolution inputs and step logits; ``state`` [B,H,P,N] float32 and
+    ``tail`` [B,K-1,Cd]: what the lane's previous chunk left (zeros at a
+    sequence's start); ``n_valid`` [B]: the lane's real tokens, the first of
+    the chunk. -> (y [B,T,H,P] float32, state, tail)."""
+    B, T, _ = c.shape
+    K1 = tail.shape[1]
+    window = jnp.concatenate([tail, c.astype(tail.dtype)], axis=1)
+    X, Bm, Cm, dt, A = _ssm_split(_ssm_conv(window, lp, l, T), d, lp, l, cfg)
+    valid = jnp.arange(T)[None, :] < n_valid[:, None]             # [B,T]
+    dt = jnp.where(valid[..., None], dt, 0.0)
+    cum = jnp.cumsum(dt * A, axis=1)                              # [B,T,H] <= 0
+    # y_t = sum_{s<=t} exp(cum_t - cum_s) dt_s (C_t . B_s) X_s
+    #       + exp(cum_t) H_0 C_t + D X_t
+    G = jnp.einsum("btn,bsn->bts", Cm, Bm, precision=_HIGHEST)
+    diff = cum[:, :, None, :] - cum[:, None, :, :]                # [B,T,S,H]
+    causal = (jnp.arange(T)[:, None] >= jnp.arange(T)[None, :])
+    W = jnp.exp(jnp.where(causal[None, :, :, None], diff, -jnp.inf))
+    W = W * (G[..., None] * dt[:, None, :, :])
+    y = jnp.einsum("btsh,bshp->bthp", W, X, precision=_HIGHEST)
+    y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+        "bhpn,btn->bthp", state, Cm, precision=_HIGHEST)
+    y = y + lp["D"][l][:, None] * X
+    # H_T = exp(cum_T) H_0 + sum_s exp(cum_T - cum_s) dt_s X_s (x) B_s
+    last = cum[:, -1]                                             # [B,H]
+    w_in = jnp.exp(last[:, None, :] - cum) * dt                   # [B,S,H]
+    state = jnp.exp(last)[..., None, None] * state + jnp.einsum(
+        "bsh,bshp,bsn->bhpn", w_in, X, Bm, precision=_HIGHEST)
+    # the last K - 1 real inputs: window rows n_valid .. n_valid + K - 2
+    rows = n_valid[:, None] + jnp.arange(K1)[None, :]
+    tail = jnp.take_along_axis(window, rows[..., None], axis=1)
+    return y, state, tail
+
+
+def ssm_step(c: jax.Array, d: jax.Array, lp: Dict[str, Any], l,
+             cfg: LlamaConfig, state: jax.Array, tail: jax.Array,
+             active: jax.Array):
+    """The one-token form. ``c`` [B,Cd], ``d`` [B,H]; ``state`` [B,H,P,N],
+    ``tail`` [B,K-1,Cd]; a lane that is not ``active`` [B] keeps both bit
+    for bit (its y is computed and discarded with the lane's token).
+    -> (y [B,H,P] float32, state, tail)."""
+    window = jnp.concatenate([tail, c.astype(tail.dtype)[:, None]], axis=1)
+    X, Bm, Cm, dt, A = _ssm_split(_ssm_conv(window, lp, l, 1)[:, 0], d, lp,
+                                  l, cfg)
+    new = (jnp.exp(dt * A)[..., None, None] * state
+           + (dt[..., None] * X)[..., None] * Bm[:, None, None, :])
+    y = jnp.sum(new * Cm[:, None, None, :], axis=-1) + lp["D"][l][:, None] * X
+    state = jnp.where(active[:, None, None, None], new, state)
+    tail = jnp.where(active[:, None, None], window[:, 1:], tail)
+    return y, state, tail
+
+
+def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
+               l0: int, n: int, pools: Tuple[jax.Array, jax.Array],
+               lanes: Optional[jax.Array], reset: Optional[jax.Array],
+               gate: jax.Array, mesh=None):
+    """Layers ``l0 .. l0 + n - 1``, all state-space layers, as one scan
+    over x [B,T,D]: mixer and feed-forward of each, the layer's slice of
+    both pools read and written inside the body. ``lanes`` [B]: the pool
+    lane of each row (a prefill chunk; a row past the pool is dropped) or
+    None: row b IS lane b (decode, T == 1). ``reset`` [B] bool: the row's
+    sequence starts here, from a zero state. ``gate``: the chunk's
+    ``n_valid`` [B], or decode's ``active`` [B].
+    -> (x, (state_pool, conv_pool))."""
+    st = params[STACKS]
+    mp, fp = st["mamba"], st["dense"]
+    m0 = sum(cfg.layer_state(i) for i in range(l0))
+    I, Cd = cfg.ssm_inner, cfg.ssm_conv_dim
+    decode = lanes is None
+    B = x.shape[0]
+
+    def body(carry, i):
+        x, s_pool, c_pool = carry
+        lm, lf = m0 + i, l0 + i
+        h = _normed(x, mp["ln1"][lm], cfg)
+        zc = jnp.einsum("btd,de->bte", h, mp["w_in"][lm])
+        z, c = zc[..., :I], zc[..., I:]
+        d = jnp.einsum("btd,dh->bth", h, mp["w_dt"][lm])
+        # the scope holds what the recurrence is: the layer's slice of both
+        # pools in, convolution, state update, read-out and gated norm, the
+        # slice out; the two projections are outside it
+        with jax.named_scope("dynamo.ssm_step" if decode
+                             else "dynamo.ssm_scan"):
+            # (a lane's convolution tail is ONE flat pool row: [K - 1, Cd]
+            # rows made XLA re-lay the whole pool twice a chunk)
+            if decode:
+                state = jax.lax.dynamic_index_in_dim(s_pool, lm,
+                                                     keepdims=False)
+                tail = jax.lax.dynamic_index_in_dim(
+                    c_pool, lm, keepdims=False).reshape(B, -1, Cd)
+                y, state, tail = ssm_step(c[:, 0], d[:, 0], mp, lm, cfg,
+                                          state, tail, gate)
+                y = y[:, None]
+                s_pool = jax.lax.dynamic_update_index_in_dim(s_pool, state,
+                                                             lm, 0)
+                c_pool = jax.lax.dynamic_update_index_in_dim(
+                    c_pool, tail.reshape(B, -1), lm, 0)
+            else:
+                state = s_pool.at[lm, lanes].get(mode="clip")
+                tail = c_pool.at[lm, lanes].get(mode="clip").reshape(
+                    B, -1, Cd)
+                state = jnp.where(reset[:, None, None, None], 0.0, state)
+                tail = jnp.where(reset[:, None, None], jnp.zeros_like(tail),
+                                 tail)
+                y, state, tail = ssm_chunk(c, d, mp, lm, cfg, state, tail,
+                                           gate)
+                s_pool = s_pool.at[lm, lanes].set(state, mode="drop")
+                c_pool = c_pool.at[lm, lanes].set(tail.reshape(B, -1),
+                                                  mode="drop")
+            g = y.reshape(*y.shape[:2], I) * jax.nn.silu(
+                z.astype(jnp.float32))
+            g = rms_norm(g, mp["norm"][lm], cfg.rms_eps).astype(cfg.dtype)
+        o = jnp.einsum("bti,id->btd", g, mp["w_out"][lm])
+        x = _ffn_block(_residual(x, o, cfg), fp, lf, cfg, mesh=mesh)
+        return (x, s_pool, c_pool), None
+
+    (x, *pools), _ = jax.lax.scan(body, (x, *pools), jnp.arange(n))
+    return x, tuple(pools)
+
+
+def _segments(cfg: LlamaConfig):
+    """-> [(first layer, layers)]: a run of state-space layers is one
+    segment (one scan), every other layer a segment of its own."""
+    out, l = [], 0
+    while l < cfg.num_layers:
+        n = 1
+        if cfg.layer_state(l):
+            while l + n < cfg.num_layers and cfg.layer_state(l + n):
+                n += 1
+        out.append((l, n))
+        l += n
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -1652,6 +2121,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             i_pool: Optional[jax.Array] = None,  # index keys (has_indexer)
             stats: Optional[Dict[str, Any]] = None,
             win: Optional[Tuple[jax.Array, ...]] = None,  # window cache
+            ssm: Optional[Tuple[jax.Array, ...]] = None,  # state pools
             ) -> Tuple[jax.Array, ...]:
     """One forward pass over a token chunk against the paged KV pool.
 
@@ -1698,6 +2168,16 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     of each of their slots, w_read_valid likewise). The two pools come back
     behind the others. Context is read by page for both kinds.
 
+    A model with state-space layers (``cfg.has_state``) keeps its ATTENTION
+    layers' K/V in ``k_pool`` / ``v_pool`` ([attention layers, ...], folded
+    by ``cfg.kv_fold``) and takes ``ssm`` = (state_pool [state layers,
+    lanes, H, P, N] float32, conv_pool [state layers, lanes, K - 1, Cd],
+    lanes [B] the pool lane of each row (one past the pool: a padded row,
+    which writes nothing), reset [B] bool the row's sequence starts with
+    this chunk, n_valid [B] the row's real tokens): a row's chunk starts
+    from the state its lane holds (zeros under ``reset``) and leaves the
+    state after its last REAL token there. Both pools come back last.
+
     Multimodal (Gemma3 VLM, xla attention only):
 
     - ``embed_override`` = (vals [B,T,D], mask [B,T] bool) replaces the
@@ -1709,7 +2189,8 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
       the or-mask applies to full and sliding layers alike
       (modeling_gemma3.py:936-953).
     """
-    page = k_pool.shape[3]
+    fold = cfg.kv_fold
+    page = k_pool.shape[3] * fold
     x = _embed(params, cfg, tokens)  # [B,T,D] bf16
     if embed_override is not None:
         ov_vals, ov_mask = embed_override
@@ -1785,8 +2266,12 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             "attn_impl='xla' only; flash/ring kernels take no span inputs")
     _require_xla_attn(cfg, attn_impl)
     pools, index = (k_pool, v_pool), None
-    w_pools = ()
-    if cfg.per_kind:
+    w_pools = s_pools = ()
+    if cfg.has_state:
+        if ssm is None or read_pages is None or attn_impl == "ring":
+            raise ValueError(f"forward: {NO_STATE}")
+        *s_pools, s_lanes, s_reset, s_valid = ssm
+    if cfg.has_window:
         if win is None or read_pages is None or attn_impl == "ring":
             raise ValueError(f"forward: {NO_SECOND_CACHE}")
         wk_pool, wv_pool, w_write, w_pages, w_pos, w_valid = win
@@ -1813,7 +2298,11 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                    ) if read_pos.shape[1] > cfg.index_topk else None
         pools, index = (k_pool, v_pool, i_pool), (rope_i, read_pages, visible)
 
-    for l in range(cfg.num_layers):
+    for l, n in _segments(cfg):
+        if cfg.layer_state(l):
+            x, s_pools = _state_run(x, params, cfg, l, n, s_pools, s_lanes,
+                                    s_reset, s_valid, mesh)
+            continue
         lp, la, *ffn = layer_stacks(params, cfg, l)
         sl = cfg.layer_sliding(l)
         if cfg.layer_window(l):
@@ -1829,11 +2318,11 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                                   pools, wp, wo, index=index, stats=stats)
         # gather this sequence's context: [B, S, Hkv, Dh]
         if read_pages is not None:
-            k_ctx = kv_pages(pools[0], la, read_pages)
-            v_ctx = kv_pages(pools[1], la, read_pages)
+            k_ctx = kv_pages(pools[0], la, read_pages, fold)
+            v_ctx = kv_pages(pools[1], la, read_pages, fold)
         else:
-            k_ctx = kv_rows(pools[0], la, rp, ro)
-            v_ctx = kv_rows(pools[1], la, rp, ro)
+            k_ctx = kv_rows(pools[0], la, rp, ro, fold)
+            v_ctx = kv_rows(pools[1], la, rp, ro, fold)
         extra = {} if keep is None else {"keep": keep}
         if "sink" in lp:
             extra["sink"] = lp["sink"][la]
@@ -1855,7 +2344,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     if logits_idx is not None:
         x = jnp.take_along_axis(
             x, logits_idx[:, None, None].astype(jnp.int32), axis=1)  # [B,1,D]
-    return (_lm_head(x, params, cfg), *pools, *w_pools)
+    return (_lm_head(x, params, cfg), *pools, *w_pools, *s_pools)
 
 
 def _attn_scope(cfg: LlamaConfig, window: bool):
@@ -1863,7 +2352,7 @@ def _attn_scope(cfg: LlamaConfig, window: bool):
     (``dynamo.attn_window`` / ``dynamo.attn_full``: the per-layer roofline
     metrics read the device time under each); nothing for any other model,
     whose programs stay what they were."""
-    if not cfg.per_kind:
+    if not cfg.has_window:
         return contextlib.nullcontext()
     return jax.named_scope("dynamo.attn_window" if window
                            else "dynamo.attn_full")
@@ -1931,6 +2420,8 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
     _require_xla_attn(cfg, attn_impl)
     if cfg.has_indexer:
         raise ValueError(f"forward_pp: {NO_INDEX_KEYS}")
+    if cfg.has_state:
+        raise ValueError(f"forward_pp: {NO_STATE}")
     if cfg.per_kind:
         raise ValueError(f"forward_pp: {NO_SECOND_CACHE}")
     if pp == 1:
@@ -2171,6 +2662,7 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                    i_pool: Optional[jax.Array] = None,
                    stats: Optional[Dict[str, Any]] = None,
                    win: Optional[Tuple[jax.Array, ...]] = None,
+                   ssm: Optional[Tuple[jax.Array, ...]] = None,
                    ) -> Tuple[jax.Array, ...]:
     """Single-token decode step addressed purely by page tables.
 
@@ -2190,8 +2682,16 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     logical pages, of which only those that still hold a key some query can
     see name a page of the lane's (the rest: scratch page 0, masked). The
     two pools come back behind the others.
+
+    A model with state-space layers takes ``ssm`` = (state_pool, conv_pool,
+    active [B] bool): row b IS lane b of both pools, and a lane that is not
+    ``active`` (an empty slot, a lane deferred under pool pressure) keeps
+    its state and convolution tail bit for bit: unlike K/V written past a
+    sequence's end, an advanced state cannot be trimmed afterwards. Both
+    pools come back last.
     """
-    page = k_pool.shape[3]
+    fold = cfg.kv_fold
+    page = k_pool.shape[3] * fold
     pos = lengths - 1                                  # [B]
     pools, index = (k_pool, v_pool), None
     if cfg.has_indexer and i_pool is not None:
@@ -2206,8 +2706,12 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     w_page = jnp.take_along_axis(page_tables, (pos // page)[:, None],
                                  axis=1)[:, 0]
     w_off = pos % page
-    w_pools = ()
-    if cfg.per_kind:
+    w_pools = s_pools = ()
+    if cfg.has_state:
+        if ssm is None:
+            raise ValueError(f"forward_decode: {NO_STATE}")
+        *s_pools, s_active = ssm
+    if cfg.has_window:
         if win is None:
             raise ValueError(f"forward_decode: {NO_SECOND_CACHE}")
         *w_pools, w_tables = win
@@ -2230,7 +2734,8 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
             if w not in _paged_cache:
                 fn = partial(_paged, scale=cfg.attn_scale,
                              softcap=cfg.attn_logit_softcap, window=w,
-                             interpret=_kernel_interpret(mesh))
+                             interpret=_kernel_interpret(mesh),
+                             **({"fold": fold} if fold > 1 else {}))
                 if tp_sz > 1:
                     kv_spec = (P(None, AXIS_TP, None, None, None)
                                if cfg.num_kv_heads % tp_sz == 0
@@ -2259,7 +2764,11 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
             sliding_mask = mask & (
                 t[None] > pos[:, None] - cfg.sliding_window)[:, None, :]
 
-    for l in range(cfg.num_layers):
+    for l, n in _segments(cfg):
+        if cfg.layer_state(l):
+            x, s_pools = _state_run(x, params, cfg, l, n, s_pools, None,
+                                    None, s_active, mesh)
+            continue
         lp, la, *ffn = layer_stacks(params, cfg, l)
         sl = cfg.layer_sliding(l)
         in_win = cfg.layer_window(l)
@@ -2280,8 +2789,8 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                     q0, kv[0], kv[1], tables, lengths,
                     jnp.int32(la), **extra)[:, None]
             else:
-                k_ctx = kv_pages(kv[0], la, tables)   # [B,S,Hkv,Dh]
-                v_ctx = kv_pages(kv[1], la, tables)
+                k_ctx = kv_pages(kv[0], la, tables, fold)   # [B,S,Hkv,Dh]
+                v_ctx = kv_pages(kv[1], la, tables, fold)
                 if keep is not None:
                     extra["keep"] = keep
                 attn = attend_ctx(cfg, q, k_ctx, v_ctx,
@@ -2293,4 +2802,4 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
                       ffn=ffn if cfg.per_kind else None)
 
-    return (_lm_head(x, params, cfg), *pools, *w_pools)
+    return (_lm_head(x, params, cfg), *pools, *w_pools, *s_pools)

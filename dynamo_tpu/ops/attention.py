@@ -494,14 +494,18 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
                          window: Optional[int] = None,
                          keep: Optional[jax.Array] = None,
                          sink: Optional[jax.Array] = None,
-                         interpret: bool = False) -> jax.Array:
-    """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh] (V: Dv); layer: [1]
+                         interpret: bool = False,
+                         stored_fold: int = 1) -> jax.Array:
+    """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh] (V: Dv), or
+    STORED folded ([.., page // f, f * Dh], ``stored_fold`` f = 128 // Dh:
+    the order the copies take, read in place); layer: [1]
     int32; keep: [B, P * page] bool or None. Returns q4-shaped. ``interpret`` exists for the CPU test suite
     only — the serving path always compiles this variant (paged_attention
     gates it to real TPUs)."""
     B, Hkv, G, Dh = q4.shape
     L, _, n_pages, page, _ = k_pool.shape
-    Dv = v_pool.shape[-1]
+    page *= stored_fold
+    Dv = v_pool.shape[-1] // stored_fold
     P = page_tables.shape[1]
     ppb = min(pages_per_block, P)
     if P % ppb:
@@ -512,10 +516,15 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
         scale = 1.0 / math.sqrt(Dh)
 
     # Dh < 128: fold tokens so DMA rows are 128-lane aligned. At Dh >= 128
-    # nothing is folded and the kernel reads the pool as stored; below, the
-    # fold is a relayout in tiled HBM, which is why paged_attention hands
-    # this function one layer's slice there and never the pool.
+    # nothing is folded and the kernel reads the pool as stored, as it does
+    # a pool that is STORED folded (``LlamaConfig.kv_fold``); a pool stored
+    # [.., page, Dh] with Dh < 128 is folded here, a relayout in tiled HBM,
+    # which is why paged_attention hands this function one layer's slice of
+    # such a pool and never the pool.
     fold = max(1, 128 // Dh)
+    if stored_fold not in (1, fold):
+        raise ValueError(f"a pool stored folded by {stored_fold} at head_dim "
+                         f"{Dh}: the kernel's rows hold {fold} tokens")
     if page % fold:
         raise ValueError(f"page size {page} not divisible by fold {fold}")
     if fold > 1 and Dv != Dh:
@@ -664,7 +673,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     softcap: Optional[float] = None,
                     window: Optional[int] = None,
                     keep: Optional[jax.Array] = None,
-                    sink: Optional[jax.Array] = None) -> jax.Array:
+                    sink: Optional[jax.Array] = None,
+                    fold: int = 1) -> jax.Array:
     """Decode attention straight over the paged KV pool.
 
     q: [B, Hq, Dh] (one new token per sequence, already rope'd)
@@ -688,7 +698,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     logical positions) restricts the lane to its selected keys; every page
     is still read. V rows may have a width of their own (``v_pool`` [...,
     Dv]: the result is [B, Hq, Dv]); ``sink`` [Hq] float32 is a logit a head
-    that takes softmax weight and gives no value.
+    that takes softmax weight and gives no value. ``fold`` f > 1: the pools
+    are STORED folded, [L, Hkv, n_pages, page // f, f * Dh] (models/llama.py
+    "KV pool access"): the dma kernel copies such rows as they lie, whole
+    pool and traced ``layer`` as at Dh >= 128.
 
     On a TPU this runs the multi-page double-buffered DMA kernel above
     (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
@@ -702,16 +715,28 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
             raise ValueError("a layer index needs the whole 5-D pool")
         k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, Hq, Dh = q.shape
+    if interpret is None:
+        interpret = not on_tpu()
+    if fold > 1 and paged_kernel_variant(interpret) != "dma":
+        # the one-page-a-step kernel blocks [page, Dh]: unfold (a plain
+        # reshape: a folded page's rows are its tokens in order)
+        k_pool, v_pool = (p.reshape(*p.shape[:3], p.shape[3] * fold,
+                                    p.shape[4] // fold)
+                          for p in (k_pool, v_pool))
+        fold = 1
     _, Hkv, n_pages, page, _ = k_pool.shape
-    Dv = v_pool.shape[-1]
+    page *= fold
+    Dv = v_pool.shape[-1] // fold
     G = Hq // Hkv
     P = page_tables.shape[1]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
-    if Dh < 128 and k_pool.shape[0] > 1:
-        # rows narrower than a lane tile: XLA's programs keep such a pool in
-        # another order than the kernels' operands take (pages minor; seen
-        # on a v5e), so every call re-lays what it is given. Give it one
-        # layer's slice to re-lay, never the pool (PERF.md §7).
+    if Dh < 128 and fold == 1 and k_pool.shape[0] > 1:
+        # rows narrower than a lane tile, stored [.., page, Dh]: XLA's
+        # programs keep such a pool in another order than the kernels'
+        # operands take (pages minor; seen on a v5e), so every call re-lays
+        # what it is given. Give it one layer's slice to re-lay, never the
+        # pool. A pool stored FOLDED (``fold``) has whole-tile rows, is kept
+        # as stored and is read in place (PERF.md §7 b).
         k_pool, v_pool = (jax.lax.dynamic_index_in_dim(p, layer[0], 0)
                           for p in (k_pool, v_pool))
         layer = jnp.zeros_like(layer)
@@ -720,8 +745,6 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     # active lane). Enforce the invariant here rather than relying on
     # callers to pad lengths.
     lengths = jnp.maximum(lengths, 1)
-    if interpret is None:
-        interpret = not on_tpu()
     if paged_kernel_variant(interpret) == "dma":
         q4 = q.reshape(B, Hkv, G, Dh)
         # DMA depth knob for on-chip tuning sweeps (read the kernel's time
@@ -742,7 +765,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                    lengths, pages_per_block=ppb,
                                    scale=scale, softcap=softcap,
                                    window=window, keep=keep,
-                                   **({} if sink is None else {"sink": sink}))
+                                   **({} if sink is None else {"sink": sink}),
+                                   **({} if fold == 1
+                                      else {"stored_fold": fold}))
         return out.reshape(B, Hq, Dv)
     selected = keep is not None
     if selected and not interpret:

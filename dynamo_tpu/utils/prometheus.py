@@ -574,6 +574,28 @@ class StageMetrics:
             "dyn_sparse_attn_selected_tokens_total",
             "Keys each such query attends to: min(visible, topk), summed",
             ("kind",))
+        # state-space layers (a recurrent state a lane beside the K/V
+        # cache): one layer's worth, as the indexer's counters above
+        self.ssm_lane_steps = r.counter(
+            "dyn_ssm_lane_steps_total",
+            "Lane-steps whose recurrent state a dispatch read and wrote "
+            "(decode: every lane of the state pool, each step; prefill: "
+            "each row of the chunk program)", ("kind",))
+        self.ssm_active_lane_steps = r.counter(
+            "dyn_ssm_active_lane_steps_total",
+            "Those lane-steps that belonged to a lane the dispatch served "
+            "(the rest kept their state unchanged)", ("kind",))
+        self.ssm_tokens = r.counter(
+            "dyn_ssm_tokens_total",
+            "Real tokens through the state-space mixers", ("kind",))
+        self.ssm_state_resets = r.counter(
+            "dyn_ssm_state_resets_total",
+            "Lanes started from a zero state (a request admitted to the "
+            "slot, or re-prefilled after preemption)", ())
+        self.ssm_state_bytes = r.gauge(
+            "dyn_ssm_state_bytes",
+            "Bytes of the per-lane state pool and convolution-tail pool, "
+            "all state-space layers and lanes", ())
         self.profile_captured_work = r.counter(
             "dyn_profile_captured_work_total",
             "The four counters above (by name), and dispatches and tokens, "

@@ -168,6 +168,11 @@ class ModelCosts:
     # of their own): K/V bytes a token a layer of each of ``window_groups``,
     # in its order (empty: ``kv_bytes_per_tok_layer`` for every layer)
     group_kv_bytes: Tuple[float, ...] = ()
+    # state-space layers: the bytes a LANE holds in them (recurrent state
+    # and convolution tail, all such layers), read and written once by a
+    # dispatch that serves the lane, and the recurrence's FLOPs a token
+    state_bytes_per_lane: float = 0.0
+    state_flops_per_token: float = 0.0
 
     def kv_bytes_of(self, i: int) -> float:
         return (self.group_kv_bytes[i] if self.group_kv_bytes
@@ -201,6 +206,8 @@ def model_costs(m: Any, weight_bytes: Optional[float] = None) -> ModelCosts:
     Hq, Hkv, Dh = m.num_heads, m.num_kv_heads, m.head_dim
     L, I = m.num_layers, m.intermediate_size
     esize = dtype_size(m.dtype)
+    if getattr(m, "has_state", False):
+        return _state_costs(m, weight_bytes, esize)
     if getattr(m, "per_kind", False):
         return _per_kind_costs(m, weight_bytes, esize)
     Dv = getattr(m, "v_dim", Dh)
@@ -280,6 +287,34 @@ def _per_kind_costs(m: Any, weight_bytes: Optional[float],
         group_kv_bytes=tuple(kv))
 
 
+def _state_costs(m: Any, weight_bytes: Optional[float],
+                 esize: int) -> ModelCosts:
+    """:class:`ModelCosts` of a model with state-space layers: K/V and
+    attention FLOPs of its attention layers alone; the mixers' two
+    projections and every layer's feed-forward among the matrix FLOPs; the
+    recurrence (state update and read-out: 4 FLOPs a state element a token)
+    and the per-lane state bytes by ``CacheKind``'s description."""
+    from ..engine.cache import cache_kinds
+
+    D, V, Hq, Dh = m.hidden_size, m.vocab_size, m.num_heads, m.head_dim
+    glob, state = cache_kinds(m)
+    attn = D * Hq * Dh + 2 * D * glob.kv_heads * Dh + Hq * Dh * D
+    I, Cd = m.ssm_inner, m.ssm_conv_dim
+    mix = D * (I + Cd + m.ssm_heads) + I * D
+    mat = (glob.layers * attn + state.layers * mix
+           + m.num_layers * 3 * D * m.intermediate_size)
+    if weight_bytes is None:
+        weight_bytes = (mat + V * D * (1 if m.tie_embeddings else 2)) * esize
+    return ModelCosts(
+        mat_flops_per_token=2.0 * mat, lm_head_flops=2.0 * D * V,
+        attn_flops_coef=4.0 * Hq * Dh,
+        kv_bytes_per_tok_layer=float(glob.token_bytes(esize) // glob.layers),
+        num_layers=glob.layers, window_groups=((None, glob.layers),),
+        weight_bytes=float(weight_bytes),
+        state_bytes_per_lane=float(state.lane_bytes(esize)),
+        state_flops_per_token=4.0 * state.layers * I * m.ssm_state)
+
+
 def _clamped_len_sum(groups: Sequence[Tuple[Optional[int], int]],
                      s: int, topk: int = 0) -> float:
     """sum over layers of min(s, window): the kv positions one query token
@@ -321,11 +356,13 @@ def decode_cost(c: ModelCosts, lengths: Iterable[int], steps: int
         lanes += 1
         for j in range(steps):
             fl, rd = _attn_cost(c, s0 + j)
-            flops += c.mat_flops_per_token + c.lm_head_flops + fl
+            flops += (c.mat_flops_per_token + c.lm_head_flops + fl
+                      + c.state_flops_per_token)
             kv_read += rd
     tokens = lanes * steps
     bytes_ = (steps * c.weight_bytes + kv_read
-              + tokens * c.kv_write_bytes_per_token)
+              + tokens * c.kv_write_bytes_per_token
+              + 2 * lanes * c.state_bytes_per_lane)
     return flops, bytes_, tokens
 
 
@@ -338,15 +375,19 @@ def prefill_cost(c: ModelCosts, spans: Iterable[Tuple[int, int]]
     flops = 0.0
     kv_read = 0.0
     tokens = 0
+    lanes = 0
     for start, count in spans:
         tokens += count
-        flops += count * c.mat_flops_per_token + c.lm_head_flops
+        lanes += 1
+        flops += (count * (c.mat_flops_per_token + c.state_flops_per_token)
+                  + c.lm_head_flops)
         for p in range(start, start + count):
             fl, rd = _attn_cost(c, p + 1)
             flops += fl
             kv_read += rd
     bytes_ = (c.weight_bytes + kv_read
-              + tokens * c.kv_write_bytes_per_token)
+              + tokens * c.kv_write_bytes_per_token
+              + 2 * lanes * c.state_bytes_per_lane)
     return flops, bytes_, tokens
 
 
