@@ -67,16 +67,18 @@ def dims(config: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     }
 
 
-def attn_least(scrapes, trace, config, window: bool) -> Optional[tuple]:
+def attn_least(scrapes, trace, config, window: bool, kinds=tuple(KINDS)
+               ) -> Optional[tuple]:
     """-> (bytes, operations, {kind: keys read}) the traced dispatches'
-    attention of one kind of layer needs."""
+    (``kinds``: of those kinds of program) attention of one kind of layer
+    needs."""
     d = dims(config)
     if d is None:
         return None
     name = "attn_window" if window else "attn_full"
     n = d["layers"][window]
-    work = {k: traced(scrapes, trace, name + "_keys", k) for k in KINDS}
-    pairs = sum(traced(scrapes, trace, name + "_pairs", k) for k in KINDS)
+    work = {k: traced(scrapes, trace, name + "_keys", k) for k in kinds}
+    pairs = sum(traced(scrapes, trace, name + "_pairs", k) for k in kinds)
     row = d["Dh"] + d["Dv"]
     return (sum(work.values()) * d["Hkv"][window] * row * ITEMSIZE * n,
             2.0 * pairs * d["Hq"] * row * n, work)

@@ -154,11 +154,13 @@ def roofline_share(bytes_: float, flops: float, seconds: float,
     return 100.0 * least / seconds
 
 
-def index_select_least(scrapes, trace, config) -> Optional[tuple]:
+def index_select_least(scrapes, trace, config, kinds=tuple(KINDS)
+                       ) -> Optional[tuple]:
     """-> (bytes, operations, {kind: visible keys scored}) the traced
-    dispatches' index scores need. Only dispatches whose program SCORES
-    count (``scored_keys``: a context bucket no longer than ``topk`` selects
-    every visible key by construction and the program skips the scoring)."""
+    dispatches' index scores need (``kinds``: of those kinds of program).
+    Only dispatches whose program SCORES count (``scored_keys``: a context
+    bucket no longer than ``topk`` selects every visible key by construction
+    and the program skips the scoring)."""
     sa = config.get("sa_config")
     if not sa:
         return None
@@ -166,7 +168,7 @@ def index_select_least(scrapes, trace, config) -> Optional[tuple]:
                  sa["indexer_head_dim"])
     bytes_ = flops = 0.0
     work = {}
-    for kind in KINDS:
+    for kind in kinds:
         visible = work[kind] = traced(scrapes, trace, "scored_keys", kind)
         flops += 2.0 * visible * Hi * Di * L
         if kind == "decode":

@@ -24,14 +24,20 @@ norm: the scopes ``dynamo.ssm_step`` / ``dynamo.ssm_scan``; the two
 projections are outside), per state-space layer:
 
 - bytes: a lane the dispatch SERVED has its state read once and written
-  once per DISPATCH (a decode dispatch of ``decode_steps`` steps; a prefill
-  chunk), ``2 x H x P x N x 4`` bytes in float32 as the configuration keeps
-  it (``assumed``): a kernel that holds the state on the chip across a
-  dispatch's steps moves no more, and the program's present form, which
-  crosses HBM once a STEP and for every lane of the pool, served or not,
-  reads well under 100 %. Beside it each real token's X, B, C, dt and z in
-  and y out, ``(3 x I + 2 x N + H) x 2`` bytes in bfloat16. The convolution
-  tail (3 x 4352 a lane) is left out: a lower bound.
+  once per STEP in decode (and per chunk in prefill), ``2 x H x P x N x 4``
+  bytes in float32 as the configuration keeps it (``assumed``). A step
+  passes every state-space layer before the next step's token exists, and
+  one layer's states of the pool's lanes are far more than the chip's fast
+  memory holds beside the weights' stream (the cell's pool is 4.8 GB over 36
+  layers), so no kernel keeps a layer's block on the chip from one step of
+  a dispatch to the next: once in and once out a step IS the floor, a
+  kernel at the peak reads 100 %. (Until PR 37 the count was a dispatch,
+  ``decode_steps`` times less: a bound of which a perfect kernel read
+  25 %.) The program's present form crosses HBM a third time for the
+  read-out and does so for every lane of the pool, served or not, and reads
+  well under 100 %. Beside it each real token's X, B, C, dt and z in and y
+  out, ``(3 x I + 2 x N + H) x 2`` bytes in bfloat16. The convolution tail
+  (3 x 4352 a lane) is left out: a lower bound.
 - operations: the state update and the read-out are a multiply-add each a
   state element a token, 2 operations each: ``4 x H x P x N`` a token,
   whatever form computes them (the chunk form's matrix products count as
@@ -69,10 +75,9 @@ def ssm_least(scrapes, trace, run, kind: str) -> Optional[tuple]:
     if d is None:
         return None
     tokens = traced(scrapes, trace, TOKENS, kind)
+    # decode: lane-steps, a served lane's state once in and once out a step;
+    # prefill: the rows of the chunk programs, once a chunk
     served = traced(scrapes, trace, ACTIVE, kind)
-    if kind == "decode":
-        # lane-steps -> lanes a dispatch: each served lane counts once
-        served /= float(run["engine"].get("decode_steps", 1))
     state = d["H"] * d["P"] * d["N"]
     token_bytes = (3 * d["I"] + 2 * d["N"] + d["H"]) * ITEMSIZE
     return (d["layers"] * (2.0 * served * state * STATE_ITEMSIZE

@@ -1,6 +1,7 @@
 """The readers of the per-layer metrics of a model with state-space layers
 on counters and a trace summary written by hand: what each divides by what,
-that the scope lists name the cell's own state pool, and that a program
+that a served lane's state counts once in and once out a STEP, that the
+scope lists name the cell's own state pool, and that a program
 without the counters (the parent commit, a model without such layers) reads
 as no value."""
 
@@ -70,12 +71,13 @@ def test_the_counter_share_and_a_program_without_counters(cat, config):
                            "decode") is None
 
 
-def test_the_decode_share_counts_a_state_once_a_dispatch(cat, config):
+def test_the_decode_share_counts_a_state_once_a_step(cat, config):
     """One traced decode dispatch of 4 steps that served 48 of the pool's
-    64 lanes: the least is 48 states in and out ONCE (not once a step, not
-    64 of them) and 192 tokens' activations, in each of 36 layers; the
-    program's time is what the listed operations took, of which the keys
-    it shares with the norms outside the scope count nothing."""
+    64 lanes: the least is 48 states in and out EACH STEP (192 served
+    lane-steps: not 64 lanes, and not once a dispatch as until PR 37) and
+    192 tokens' activations, in each of 36 layers; the program's time is
+    what the listed operations took, of which the keys it shares with the
+    norms outside the scope count nothing."""
     work = captured("decode", dispatches=1, tokens=192,
                     **{state.ACTIVE: 192, state.TOKENS: 192,
                        state.LANE_STEPS: 256})
@@ -88,10 +90,15 @@ def test_the_decode_share_counts_a_state_once_a_dispatch(cat, config):
                                            "total_s": 600 * 300e-6},
         "tpu_custom_call bf16[64,8,4,64]": {"events": 16,
                                             "total_s": 16 * 80e-6}}}
-    least = 36 * (2 * 48 * STATE * 4 + 192 * TOKEN) / 819e9
+    least = 36 * (2 * 192 * STATE * 4 + 192 * TOKEN) / 819e9
     got = reduce(cat, "kernel.ssm_step_roofline_share", s, trace, config)
     assert got == pytest.approx(100 * least / (144 * 450e-6))
     assert 0 < got < 100
+    # the same operations at the peak read 100: a served lane's state once
+    # in and once out a step is the floor
+    update_at = dict(trace, ops={update: {"events": 144, "total_s": least}})
+    assert reduce(cat, "kernel.ssm_step_roofline_share", s, update_at,
+                  config) == pytest.approx(100.0)
     # the chunk's metric reads nothing of a run that traced no chunk
     assert reduce(cat, "kernel.ssm_scan_roofline_share", s, trace,
                   config) is None
