@@ -110,7 +110,8 @@ def cache_kinds(m) -> Tuple[CacheKind, ...]:
     if not m.per_kind:
         return (CacheKind("global", m.num_layers, m.num_kv_heads, m.head_dim,
                           m.v_dim, m.k_store_dim, None,
-                          m.index_head_dim if m.has_indexer else 0),)
+                          m.index_head_dim if m.has_indexer else 0,
+                          fold=m.kv_fold),)
     if m.has_state:
         if m.has_window:
             raise ValueError("window layers beside state-space layers are "

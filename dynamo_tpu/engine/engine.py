@@ -499,6 +499,16 @@ class EngineCore:
         # one descriptor a cache kind (engine/cache.py): the global cache
         # of every model, and the window cache of a per-kind model
         self.cache_kinds = cache_kinds(m)
+        # what puts a decode step's new K/V rows into each kind's pools: the
+        # paged kernel or the row scatter (llama.kernel_writes, the predicate
+        # forward_decode itself asks) — reported, never used to select
+        how = {k.name: "kernel" if cfg.pp == 1 and llama.kernel_writes(
+                   self.mesh, self.decode_attn_impl, k.k_store, k.fold)
+               else "scatter"
+               for k in self.cache_kinds if k.state is None}
+        self.decode_kv_write = (
+            next(iter(how.values())) if len(set(how.values())) == 1
+            else ",".join(f"{n}:{h}" for n, h in how.items()))
 
         zero_fns: Dict[Tuple[int, ...], Any] = {}
 
@@ -3301,7 +3311,8 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
             str(os.getpid()), core.attn_impl, core.decode_attn_impl,
             core.paged_kernel or "none", dev0.platform, dev0.device_kind,
             str(core.mesh.devices.size), core.goodput.peaks.source,
-            "+".join(k.label() for k in core.cache_kinds), value=1)
+            "+".join(k.label() for k in core.cache_kinds),
+            core.decode_kv_write, value=1)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._queues: Dict[str, asyncio.Queue] = {}
         self._inbox: thread_queue.Queue = thread_queue.Queue()

@@ -29,7 +29,7 @@ import math
 import os
 from dataclasses import dataclass, field
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1782,7 +1782,8 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
              rope: Tuple[jax.Array, jax.Array], pools: Tuple[jax.Array, ...],
              w_page: jax.Array, w_off: jax.Array, mode: Optional[str] = None,
              index: Optional[Tuple[Any, ...]] = None,
-             stats: Optional[Dict[str, Any]] = None):
+             stats: Optional[Dict[str, Any]] = None,
+             hold: Optional[List[jax.Array]] = None):
     """The layer before attention: input norm, the three projections (bias,
     q/k norm), rotary, and the new rows into the cache.
 
@@ -1793,7 +1794,10 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     [B*T]; ``mode`` as :func:`kv_write`'s. For a model with an indexer
     ``index`` = (rope_i, pages, visible) is what :func:`_index_step` takes
     beside the slots, and ``stats["keep"]``, where the caller put a list,
-    receives the layer's keep mask.
+    receives the layer's keep mask. ``hold``: a caller whose attention
+    kernel writes the new rows itself (:func:`kernel_writes`) passes a list,
+    which receives the K and V rows [B*T, Hkv, D] as stored; the two pools
+    then come back as they went in.
     -> (q [B,T,Hq,Dh], pools, keep [B,T,S] or None)."""
     h = _normed(x, lp["ln1"][l], cfg)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
@@ -1820,10 +1824,12 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         # a score, and q goes to attention as wide as the rows it meets
         q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, pad),)) for a in (q, k))
     # write, then attend: the new rows are part of their own context
-    k_pool = kv_write(k_pool, l, w_page, w_off, k.reshape(-1, *k.shape[2:]),
-                      mode)
-    v_pool = kv_write(v_pool, l, w_page, w_off, v.reshape(-1, *v.shape[2:]),
-                      mode)
+    rows = [a.reshape(-1, *a.shape[2:]) for a in (k, v)]
+    if hold is None:
+        k_pool, v_pool = (kv_write(p, l, w_page, w_off, r, mode)
+                          for p, r in zip((k_pool, v_pool), rows))
+    else:
+        hold.extend(rows)
     keep = None
     if cfg.has_indexer:
         if index is None or not i_pool:
@@ -2627,6 +2633,23 @@ def _kernel_interpret(mesh) -> bool:
     return not on_tpu(None if mesh is None else mesh.devices.flat[0])
 
 
+def kernel_writes(mesh, attn_impl: str, row: int, fold: int) -> bool:
+    """Whether :func:`forward_decode`'s attention kernel writes the step's
+    new K/V rows itself, into pools whose K rows are stored ``row`` wide,
+    ``fold`` tokens to a pool row: the compiled paged dma kernel on one
+    shard, over a pool stored as it reads it
+    (``ops.attention.paged_kernel_writes``). Every other decode step
+    scatters them first (:func:`kv_write`): the dense and interpreted paths,
+    ``DYNAMO_TPU_PAGED_KERNEL=simple``, a tensor-parallel mesh (the kernel
+    runs inside a ``shard_map`` whose results are the attention output
+    alone), rows narrower than a lane tile stored unfolded. The engine
+    reports the answer for each of its cache kinds
+    (``dyn_engine_info{decode_kv_write}``)."""
+    from ..ops.attention import paged_kernel_writes
+    return (attn_impl == "pallas" and _tp_size(mesh) == 1
+            and paged_kernel_writes(_kernel_interpret(mesh), row, fold))
+
+
 def _tp_size(mesh) -> int:
     from ..parallel.mesh import AXIS_TP as _TP
     if mesh is None or _TP not in mesh.axis_names:
@@ -2775,9 +2798,13 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         # a per-kind model's window layers: their own pools and page tables
         kv, tables, wpg = ((w_pools, w_tables, ww_page) if in_win
                            else (pools, page_tables, w_page))
+        # the paged kernel holds the page of each lane's new token and
+        # writes the rows itself where it can; elsewhere they are scattered
+        new = [] if kernel_writes(mesh, attn_impl, kv[0].shape[-1] // fold,
+                                  fold) else None
         q, kv, keep = layer_in(x, lp, la, cfg, pick(sl, rope_sl, rope),
                                kv, wpg, w_off, index=None if in_win else index,
-                               stats=None if in_win else stats)
+                               stats=None if in_win else stats, hold=new)
         extra = {"sink": lp["sink"][la]} if "sink" in lp else {}
         with _attn_scope(cfg, in_win):
             if attn_impl == "pallas":
@@ -2787,7 +2814,12 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                     extra["keep"] = keep[:, 0]
                 attn = paged_for(l)(
                     q0, kv[0], kv[1], tables, lengths,
-                    jnp.int32(la), **extra)[:, None]
+                    jnp.int32(la), **extra,
+                    **({"new": tuple(new)} if new else {}))
+                if new:
+                    attn, *written = attn
+                    kv = (*written, *kv[2:])
+                attn = attn[:, None]
             else:
                 k_ctx = kv_pages(kv[0], la, tables, fold)   # [B,S,Hkv,Dh]
                 v_ctx = kv_pages(kv[1], la, tables, fold)

@@ -29,7 +29,7 @@ from __future__ import annotations
 import functools
 import math
 import os
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -329,7 +329,8 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                       scale: float, page: int, ppb: int, hkv: int,
                       fold: int, dh: int, softcap: Optional[float],
                       window: Optional[int], selected: bool,
-                      dv: Optional[int] = None, sunk: bool = False):
+                      dv: Optional[int] = None, sunk: bool = False,
+                      writes: bool = False):
     """Pools are the WHOLE stored pool, [L, Hkv, n_pages, page//fold,
     fold*Dh], left in HBM; ``layer_ref[0]`` picks the layer inside the copy
     descriptor, so no per-layer slice of the pool is ever materialised and
@@ -348,10 +349,34 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     ([1, 1, L2] int32 of this lane and block, logical order, fold 1 only);
     without it the kernel is what it always was. ``dv``: V rows of a width
     of their own (fold 1 only). ``sunk``: one more operand, the heads' sink
-    logits [Hkv, G, 1] float32 (a key of that logit and value zero)."""
+    logits [Hkv, G, 1] float32 (a key of that logit and value zero).
+
+    ``writes``: a one-token decode step's new rows come as two more operands
+    (``k_new`` / ``v_new`` [1, 1, Hkv*fold*D]: this lane's rows, the heads
+    side by side, a row repeated ``fold`` times across its lanes) and the
+    pools come back as two more
+    results, aliased to the operands. The row of token ``length - 1``
+    lies in the LAST page of the lane's last active block, which the kernel
+    holds in VMEM once that block's copies are waited for: there the tile
+    group of rows around it (:func:`_write_group`) is overlaid with the new
+    row (write, then attend: the scores see it as they would after
+    ``kv_write``), staged, and copied back to HBM, all heads in one strided
+    copy a pool. The copy is NOT waited for in its grid step: the staging
+    ring holds ``_WRITE_RING`` lanes' groups, a ring slot's copy is waited
+    for when the slot comes round again and every one still out at the last
+    grid step. A lane of length 0 (an empty slot) attends like a lane of
+    length 1, as it always did, and writes nothing. What keeps the copy back
+    from racing a read: the page that holds ``length - 1`` belongs to its
+    lane alone (prefix reuse shares sealed pages only: engine/cache.py), so
+    no other lane's prefetch names it, and the lane's own read of it was
+    waited for before the overlay."""
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
-    o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr, state = rest
+    if writes:
+        (kn_ref, vn_ref, o_ref, k_out, v_out, k_buf, v_buf, sem, m_scr, l_scr,
+         acc_scr, state, kw_buf, vw_buf, wsem) = rest
+    else:
+        o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr, state = rest
     dv = dh if dv is None else dv
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -359,15 +384,20 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     rows_pp = page // fold    # folded rows per page
     rows = L2 // fold         # folded rows per compute block
 
+    def length_of(bb):
+        # every lane covers >= 1 block: the prefetch chain below would leave
+        # a DMA slot un-consumed after a lane of none and stall the next
+        return jnp.maximum(len_ref[bb], 1)
+
     def nblocks(bb):
-        return (len_ref[bb] + L2 - 1) // L2
+        return (length_of(bb) + L2 - 1) // L2
 
     def jstart(bb):
         # first block holding any in-window token. The decode query sits at
         # length-1, so the window covers [length - window, length).
         if window is None:
             return 0
-        return jnp.maximum(len_ref[bb] - window, 0) // L2
+        return jnp.maximum(length_of(bb) - window, 0) // L2
 
     layer = layer_ref[0]
 
@@ -400,7 +430,52 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     @pl.when(first)
     def _():
         state[0] = 0
+        if writes:
+            state[1] = 0          # write-backs started so far
         start(b, j, 0)
+
+    def write_wait(w):
+        # a wait needs the semaphore and the copy's size, not its address
+        for i, (wbuf, pool) in enumerate(((kw_buf, k_out), (vw_buf, v_out))):
+            pltpu.make_async_copy(
+                wbuf.at[w], pool.at[layer, :, 0, pl.ds(0, wbuf.shape[2])],
+                wsem.at[w, i]).wait()
+
+    def write_back(slot):
+        """Overlay the new rows on block ``slot`` and start their way back
+        to the pool (the last active block of lane ``b``, copies waited
+        for)."""
+        tok = len_ref[b] - 1
+        pg = tok // page
+        off = tok % page
+        r = off // fold                       # the pool row of the token
+        grp = kw_buf.shape[2]
+        g0 = pl.multiple_of((r // grp) * grp, grp)
+        n = state[1]
+        w = n % _WRITE_RING
+
+        @pl.when(n >= _WRITE_RING)
+        def _():
+            write_wait(w)
+
+        for i, (buf, new_ref, wbuf, pool, d) in enumerate((
+                (k_buf, kn_ref, kw_buf, k_out, dh),
+                (v_buf, vn_ref, vw_buf, v_out, dv))):
+            shape = (grp, fold * d)
+            hit = jax.lax.broadcasted_iota(jnp.int32, shape, 0) == r - g0
+            if fold > 1:
+                hit = hit & (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                             // d == off % fold)
+            for h in range(hkv):
+                at = (slot, h, pg % ppb, pl.ds(g0, grp), slice(None))
+                row = new_ref[0, :, h * fold * d:(h + 1) * fold * d]
+                merged = jnp.where(hit, row, buf[at])        # [grp, f*d]
+                buf[at] = merged
+                wbuf[w, h] = merged
+            pltpu.make_async_copy(
+                wbuf.at[w], pool.at[layer, :, pt_ref[b, pg], pl.ds(g0, grp)],
+                wsem.at[w, i]).start()
+        state[1] = n + 1
 
     @pl.when(active)
     def _():
@@ -432,6 +507,11 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         for d in copy_descs(b, j, slot):
             d.wait()
 
+        if writes:
+            @pl.when((j == nb - 1) & (len_ref[b] > 0))
+            def _():
+                write_back(slot)
+
         q = q_ref[0]                                        # [Hkv, G, Dh]
         kf = k_buf[slot].reshape(hkv, rows, fold * dh)
         vf = v_buf[slot].reshape(hkv, rows, fold * dv)
@@ -439,7 +519,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         # is r // rows_pp and the in-page row r % rows_pp
         ridx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
         base = (ridx // rows_pp) * page + (ridx % rows_pp) * fold + j * L2
-        length = len_ref[b]
+        length = length_of(b)
 
         s_parts, mask_parts = [], []
         for f in range(fold):
@@ -486,6 +566,28 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
                         ).astype(o_ref.dtype)
 
+    if writes:
+        @pl.when((b == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
+        def _():
+            for w in range(_WRITE_RING):
+                @pl.when(state[1] > w)
+                def _():
+                    write_wait(w)
+
+
+# lanes whose write-back may be on its way at once (the staging ring of
+# _paged_dma_kernel): a lane's grid steps take about as long as one copy
+_WRITE_RING = 4
+
+
+def _write_group(rows_pp: int, dtype) -> int:
+    """Rows of a page the kernel copies back around a new one: the
+    sublane-tile group that holds it (16 rows of bfloat16: no copy starts or
+    ends inside a tile), or the whole page where a page's rows do not divide
+    into tiles."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return tile if rows_pp % tile == 0 else rows_pp
+
 
 def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
                          *, pages_per_block: int = 8,
@@ -495,13 +597,18 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
                          keep: Optional[jax.Array] = None,
                          sink: Optional[jax.Array] = None,
                          interpret: bool = False,
-                         stored_fold: int = 1) -> jax.Array:
+                         stored_fold: int = 1,
+                         new: Optional[Tuple[jax.Array, jax.Array]] = None):
     """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh] (V: Dv), or
     STORED folded ([.., page // f, f * Dh], ``stored_fold`` f = 128 // Dh:
     the order the copies take, read in place); layer: [1]
     int32; keep: [B, P * page] bool or None. Returns q4-shaped. ``interpret`` exists for the CPU test suite
     only — the serving path always compiles this variant (paged_attention
-    gates it to real TPUs)."""
+    gates it to real TPUs). ``new`` = (k_new [B, Hkv, Dh], v_new [B, Hkv,
+    Dv]): the kernel writes the rows of token ``lengths - 1`` itself
+    (``lengths`` unclamped: a lane of 0 writes nothing) and the result is
+    (out, k_pool, v_pool), the pools aliased to the operands; they must be
+    stored as the kernel reads them (:func:`paged_kernel_writes`)."""
     B, Hkv, G, Dh = q4.shape
     L, _, n_pages, page, _ = k_pool.shape
     page *= stored_fold
@@ -530,6 +637,11 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     if fold > 1 and Dv != Dh:
         raise ValueError(f"V rows of their own width ({Dv}) need K rows of "
                          f"at least a lane tile (got {Dh})")
+    if new is not None and stored_fold != fold:
+        raise ValueError(
+            f"the kernel writes only into a pool stored as it reads it: "
+            f"rows of {fold} token(s) at head_dim {Dh}, got {stored_fold}")
+    stored = k_pool.shape, v_pool.shape
     k_pool = k_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
     v_pool = v_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dv)
 
@@ -552,6 +664,38 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
         own["sunk"] = True
     if Dv != Dh:
         own["dv"] = Dv
+    out_specs = pl.BlockSpec((1, Hkv, G, Dv), _lane_block)
+    out_shape = jax.ShapeDtypeStruct((B, Hkv, G, Dv), q4.dtype)
+    scratch, aliases = [], {}
+    if new is not None:
+        # the new rows as [B, 1, Hkv * fold * D], a lane a block: the heads
+        # side by side, a head's row repeated across its lanes so that one
+        # select places it in a folded pool row. Of the forms tried this is
+        # the one XLA builds the rest of the step around best (rows heads
+        # outermost cost fewer copies a layer and more overall: the sampler's
+        # operands and ``wv`` leave the chip's fast memory; PERF.md section
+        # 6, PR 38)
+        for a, pool in zip(new, (k_pool, v_pool)):
+            sel_specs.append(pl.BlockSpec((1, 1, Hkv * pool.shape[-1]),
+                                          lambda b, j, *_: (b, 0, 0)))
+            sel_args.append(jnp.tile(a.astype(pool.dtype), (1, 1, fold))
+                            .reshape(B, 1, -1))
+        own["writes"] = True
+        hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+        # the attention output stays the FIRST result: a trace names a
+        # tuple-valued custom call by its first type, and the benchmark's
+        # operation lists name the kernel by it
+        out_specs = [out_specs, hbm, hbm]
+        out_shape = [out_shape, *(jax.ShapeDtypeStruct(p.shape, p.dtype)
+                                  for p in (k_pool, v_pool))]
+        grp = _write_group(page // fold, k_pool.dtype)
+        scratch = [
+            pltpu.VMEM((_WRITE_RING, Hkv, grp, fold * Dh), k_pool.dtype),
+            pltpu.VMEM((_WRITE_RING, Hkv, grp, fold * Dv), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((_WRITE_RING, 2)),       # [ring, k/v]
+        ]
+        # operands count from the three prefetched scalars: q4 is 3
+        aliases = {4: 1, 5: 2}
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -562,7 +706,7 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             *sel_specs,
         ],
-        out_specs=pl.BlockSpec((1, Hkv, G, Dv), _lane_block),
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dh), k_pool.dtype),
             pltpu.VMEM((2, Hkv, ppb, page // fold, fold * Dv), v_pool.dtype),
@@ -570,20 +714,27 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
             pltpu.VMEM((Hkv, G, 1), jnp.float32),            # m
             pltpu.VMEM((Hkv, G, 1), jnp.float32),            # l
             pltpu.VMEM((Hkv, G, Dv), jnp.float32),           # acc
-            pltpu.SMEM((1,), jnp.int32),                     # buffer slot
+            # buffer slot; with ``new``, the write-backs started
+            pltpu.SMEM((1 if new is None else 2,), jnp.int32),
+            *scratch,
         ],
     )
-    return pl.pallas_call(
+    res = pl.pallas_call(
         functools.partial(_paged_dma_kernel, scale=scale, page=page,
                           ppb=ppb, hkv=Hkv, fold=fold, dh=Dh,
                           softcap=softcap, window=window, selected=selected,
                           **own),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q4.dtype),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        **({"input_output_aliases": aliases} if aliases else {}),
     )(page_tables, lengths, layer, q4, k_pool, v_pool, *sel_args)
+    if new is None:
+        return res
+    out, k_pool, v_pool = res
+    return out, k_pool.reshape(stored[0]), v_pool.reshape(stored[1])
 
 
 def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
@@ -665,6 +816,16 @@ def paged_kernel_variant(interpret: bool) -> str:
     return "simple[interpret]" if interpret else variant
 
 
+def paged_kernel_writes(interpret: bool, head_dim: int, fold: int) -> bool:
+    """Whether :func:`paged_attention` can take a decode step's new rows
+    (``new``) and write them itself: on the dma kernel, into a pool stored as
+    that kernel reads it (rows of ``head_dim`` >= 128, or ``fold`` = 128 //
+    ``head_dim`` tokens to a row). Any other pool keeps ``kv_write``; the
+    engine reports which (``dyn_engine_info{decode_kv_write}``)."""
+    return (paged_kernel_variant(interpret) == "dma"
+            and fold == max(1, 128 // head_dim))
+
+
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     page_tables: jax.Array, lengths: jax.Array,
                     layer=None,
@@ -674,7 +835,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     window: Optional[int] = None,
                     keep: Optional[jax.Array] = None,
                     sink: Optional[jax.Array] = None,
-                    fold: int = 1) -> jax.Array:
+                    fold: int = 1,
+                    new: Optional[Tuple[jax.Array, jax.Array]] = None):
     """Decode attention straight over the paged KV pool.
 
     q: [B, Hq, Dh] (one new token per sequence, already rope'd)
@@ -701,7 +863,12 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     that takes softmax weight and gives no value. ``fold`` f > 1: the pools
     are STORED folded, [L, Hkv, n_pages, page // f, f * Dh] (models/llama.py
     "KV pool access"): the dma kernel copies such rows as they lie, whole
-    pool and traced ``layer`` as at Dh >= 128.
+    pool and traced ``layer`` as at Dh >= 128. ``new`` = (k_new [B, Hkv,
+    Dh], v_new [B, Hkv, Dv]): the rows of each lane's token ``lengths - 1``,
+    NOT yet in the pools: the dma kernel puts them there and attends over
+    them (a lane of length 0 writes nothing), and the result is (out,
+    k_pool, v_pool) with the pools updated in place where the caller donates
+    them. Only where :func:`paged_kernel_writes` says so.
 
     On a TPU this runs the multi-page double-buffered DMA kernel above
     (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
@@ -710,13 +877,20 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     CPU test suite exercises the same contract. See
     :func:`paged_kernel_variant`.
     """
-    if k_pool.ndim == 4:
+    whole = k_pool.ndim == 5
+    if not whole:
         if layer is not None:
             raise ValueError("a layer index needs the whole 5-D pool")
         k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     B, Hq, Dh = q.shape
     if interpret is None:
         interpret = not on_tpu()
+    if new is not None:
+        if not paged_kernel_writes(interpret, Dh, fold):
+            raise ValueError(
+                f"no paged kernel writes these pools (kernel "
+                f"{paged_kernel_variant(interpret)!r}, head_dim {Dh}, rows "
+                f"of {fold} token(s)): the caller scatters (kv_write)")
     if fold > 1 and paged_kernel_variant(interpret) != "dma":
         # the one-page-a-step kernel blocks [page, Dh]: unfold (a plain
         # reshape: a folded page's rows are its tokens in order)
@@ -743,8 +917,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     # The TPU kernel's prefetch chain assumes every lane covers >=1 block
     # (nblocks==0 would leave a DMA slot un-consumed and stall the next
     # active lane). Enforce the invariant here rather than relying on
-    # callers to pad lengths.
-    lengths = jnp.maximum(lengths, 1)
+    # callers to pad lengths (a kernel that writes tells a lane of 0, which
+    # writes nothing, by the unclamped value, and clamps for itself).
+    if new is None:
+        lengths = jnp.maximum(lengths, 1)
     if paged_kernel_variant(interpret) == "dma":
         q4 = q.reshape(B, Hkv, G, Dh)
         # DMA depth knob for on-chip tuning sweeps (read the kernel's time
@@ -765,10 +941,17 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                    lengths, pages_per_block=ppb,
                                    scale=scale, softcap=softcap,
                                    window=window, keep=keep,
+                                   interpret=interpret,
                                    **({} if sink is None else {"sink": sink}),
                                    **({} if fold == 1
-                                      else {"stored_fold": fold}))
-        return out.reshape(B, Hq, Dv)
+                                      else {"stored_fold": fold}),
+                                   **({} if new is None else {"new": new}))
+        if new is None:
+            return out.reshape(B, Hq, Dv)
+        out, k_pool, v_pool = out
+        if not whole:
+            k_pool, v_pool = k_pool[0], v_pool[0]
+        return out.reshape(B, Hq, Dv), k_pool, v_pool
     selected = keep is not None
     if selected and not interpret:
         raise ValueError(

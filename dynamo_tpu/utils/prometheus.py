@@ -475,10 +475,11 @@ class StageMetrics:
             "Engine build facts as labels (value 1): selected attention "
             "paths, paged-kernel variant, device platform/kind/count, peak "
             "source, cache kinds (name:layers x kv heads x (K + V row), "
-            "+ joined)",
+            "+ joined), what writes a decode step's new K/V rows (kernel | "
+            "scatter, or kind:how comma-joined where the kinds differ)",
             ("worker", "attn_impl", "decode_attn_impl", "paged_kernel",
              "platform", "device_kind", "devices", "peak_source",
-             "cache_kinds"))
+             "cache_kinds", "decode_kv_write"))
         self.device_peak_bytes = r.gauge(
             "dyn_device_peak_bytes_in_use",
             "Peak device memory in use per engine device "

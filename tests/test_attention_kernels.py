@@ -109,17 +109,26 @@ def test_paged_matches_dense(B, Hq, Hkv, Dh, page, P):
         atol=3e-2, rtol=3e-2)
 
 
-@pytest.mark.parametrize("L", [None, 3])
-def test_paged_inside_scan_with_donated_pool(L):
+@pytest.mark.parametrize("L,kernel_writes", [
+    (None, False), (3, False), (None, True), (3, True)])
+def test_paged_inside_scan_with_donated_pool(monkeypatch, L, kernel_writes):
     """The decode loop shape: pools carried through lax.scan and donated,
     every layer's row written in place (head index spelt out, as
     forward_decode does) and read by the kernel from the whole pool by
     layer index. ``L=None`` is the single-layer [Hkv, n_pages, page, Dh]
-    form."""
+    form. ``kernel_writes``: the dma kernel (in the interpreter here) takes
+    the rows as ``new`` and hands the pools back as its second and third
+    result, aliased to its operands."""
     B, Hq, Hkv, Dh, page, P = 2, 4, 2, 16, 8, 2
+    if kernel_writes:
+        from dynamo_tpu.ops import attention as A
+        monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
+        Dh = 128            # rows the kernel reads as stored
     n_pages = 8
     lead = () if L is None else (L,)
-    q = jnp.ones((B, Hq, Dh), jnp.bfloat16)
+    # (scores of a fraction, at either width: the new rows must not take all
+    # the softmax weight a bfloat16 can tell from one)
+    q = jnp.full((B, Hq, Dh), 1.0 / Dh, jnp.bfloat16)
     k_pool = jnp.ones(lead + (Hkv, n_pages, page, Dh), jnp.bfloat16)
     v_pool = jnp.ones(lead + (Hkv, n_pages, page, Dh), jnp.bfloat16)
     pt = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
@@ -137,10 +146,16 @@ def test_paged_inside_scan_with_donated_pool(L):
                 at = ((hh, wp, (pos % page)[:, None]) if l is None
                       else (l, hh, wp, (pos % page)[:, None]))
                 new = jnp.full((B, Hkv, Dh), 2.0, kp.dtype)
+                layer = None if l is None else jnp.int32(l)
+                if kernel_writes:
+                    out, kp, vp = paged_attention(
+                        q, kp, vp, pt, ln, layer, interpret=True,
+                        new=(new, new))
+                    outs.append(out)
+                    continue
                 kp, vp = kp.at[at].set(new), vp.at[at].set(new)
-                outs.append(paged_attention(
-                    q, kp, vp, pt, ln, None if l is None else jnp.int32(l),
-                    interpret=True))
+                outs.append(paged_attention(q, kp, vp, pt, ln, layer,
+                                            interpret=True))
             return (ln + 1, kp, vp), jnp.stack(outs)
         (_, kp, vp), outs = jax.lax.scan(
             body, (lengths, k_pool, v_pool), None, length=3)
@@ -158,6 +173,9 @@ def test_paged_inside_scan_with_donated_pool(L):
     # among equal keys, so the output lies strictly between 1 and 2
     assert (out > 1.0).all() and (out < 2.0).all()
     assert float(np.asarray(kp, np.float32).max()) == 2.0
+    # three rows a lane and layer, every head, and nothing else
+    assert int((np.asarray(kp, np.float32) == 2.0).all(-1).sum()) == (
+        3 * B * Hkv * (L or 1))
 
 
 @pytest.mark.parametrize("window,softcap,scale", [
@@ -313,3 +331,119 @@ def test_paged_layer_needs_whole_pool():
         paged_attention(jnp.zeros((1, 4, 16), jnp.bfloat16), z, z,
                         jnp.zeros((1, 1), jnp.int32),
                         jnp.ones((1,), jnp.int32), layer=1, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# The dma kernel that writes a decode step's new rows itself
+# ---------------------------------------------------------------------------
+
+# lengths: the new token on the first row of a fresh page, the last row of a
+# page, rows 15 / 16 of a tile group, mid-page in a second block, the table's
+# last row, an EMPTY lane (length 0), one token
+_WRITE_LENGTHS = [33, 32, 16, 17, 77, 128, 0, 1]
+
+
+@pytest.mark.parametrize("case,Dh,Dv,fold,window,selected,sunk,ppb,lanes", [
+    ("fold1", 128, 128, 1, None, False, False, 2, 8),
+    ("fold2", 64, 64, 2, None, False, False, 2, 8),
+    ("fold1-window", 128, 128, 1, 40, False, False, 2, 8),
+    ("fold2-window", 64, 64, 2, 40, False, False, 3, 8),
+    ("dv", 256, 128, 1, None, False, False, 2, 8),       # mimo's full layers
+    ("dv-window-sunk", 256, 128, 1, 40, False, True, 2, 8),  # ... window ones
+    ("selected", 128, 128, 1, None, True, False, 2, 8),  # keye
+    ("selected-sunk", 128, 128, 1, None, True, True, 4, 8),
+    ("fold2-sunk", 64, 64, 2, None, False, True, 8, 8),  # ppb wider than P
+    # the new rows reach the kernel eight lanes a block
+    ("two-row-blocks", 128, 128, 1, None, False, False, 2, 16),
+    ("no-multiple-of-8", 64, 64, 2, 40, False, False, 2, 12),
+])
+def test_the_kernel_that_writes_is_kv_write_then_the_kernel(
+        case, Dh, Dv, fold, window, selected, sunk, ppb, lanes):
+    """``new`` rows handed to the dma kernel (interpreter) against
+    ``kv_write`` followed by the kernel as it was: the SAME attention output
+    and the SAME pools, bit for bit, every variant a cell runs. Excepted and
+    named: the empty lane's scratch row. ``kv_write`` takes position -1 for
+    it and puts its rows at the page table's LAST entry, offset page - 1;
+    the kernel writes nothing for a lane of no tokens."""
+    from dynamo_tpu.models.llama import kv_write
+    from dynamo_tpu.ops.attention import _paged_attention_tpu
+
+    L, layer, Hkv, G, page, P = 2, 1, 2, 2, 32, 4
+    lanes = (_WRITE_LENGTHS * 2)[:lanes]
+    B = len(lanes)
+    n_pages = B * P + 1
+    ks = jax.random.split(jax.random.PRNGKey(23), 7)
+    bf = jnp.bfloat16
+
+    def rand(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(bf)
+
+    q = rand(ks[0], B, Hkv, G, Dh)
+    k_pool = rand(ks[1], L, Hkv, n_pages, page // fold, fold * Dh)
+    v_pool = rand(ks[2], L, Hkv, n_pages, page // fold, fold * Dv)
+    k_new, v_new = rand(ks[3], B, Hkv, Dh), rand(ks[4], B, Hkv, Dv)
+    page_tables = (jnp.arange(P, dtype=jnp.int32)[None]
+                   + jnp.arange(B, dtype=jnp.int32)[:, None] * P + 1)
+    lengths = jnp.asarray(lanes, jnp.int32)
+    kw = dict(pages_per_block=ppb, window=window, interpret=True,
+              stored_fold=fold)
+    if selected:
+        kw["keep"] = jax.random.bernoulli(ks[5], 0.5, (B, P * page))
+    if sunk:
+        kw["sink"] = jax.random.normal(ks[6], (Hkv * G,), jnp.float32)
+    ly = jnp.asarray([layer], jnp.int32)
+
+    pos = lengths - 1
+    w_page = jnp.take_along_axis(page_tables, (pos // page)[:, None], 1)[:, 0]
+    k_want = kv_write(k_pool, layer, w_page, pos % page, k_new)
+    v_want = kv_write(v_pool, layer, w_page, pos % page, v_new)
+    want = _paged_attention_tpu(q, k_want, v_want, ly, page_tables,
+                                jnp.maximum(lengths, 1), **kw)
+    got, k_got, v_got = _paged_attention_tpu(
+        q, k_pool, v_pool, ly, page_tables, lengths, new=(k_new, v_new), **kw)
+
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    empty = [b for b, n in enumerate(lanes) if n == 0]
+    assert empty
+    for got_pool, want_pool, old in ((k_got, k_want, k_pool),
+                                     (v_got, v_want, v_pool)):
+        got_pool, want_pool = (np.array(a, np.float32)
+                               for a in (got_pool, want_pool))
+        for b in empty:
+            scratch = (layer, slice(None), int(page_tables[b, -1]),
+                       (page - 1) // fold)
+            # the one row they differ by, and the kernel left it as it was
+            assert (got_pool[scratch] != want_pool[scratch]).any()
+            np.testing.assert_array_equal(
+                got_pool[scratch], np.asarray(old, np.float32)[scratch])
+            want_pool[scratch] = got_pool[scratch]
+        np.testing.assert_array_equal(got_pool, want_pool)
+
+
+def test_new_rows_only_where_a_kernel_writes():
+    """Off the dma kernel, or over rows narrower than a lane tile stored
+    unfolded, nothing writes ``new`` rows: the entry point says so rather
+    than attend over a pool that lacks them."""
+    from dynamo_tpu.ops.attention import paged_kernel_writes
+
+    assert not paged_kernel_writes(True, 128, 1)      # the interpreter
+    z = jnp.zeros((2, 2, 3, 8, 128), jnp.bfloat16)
+    rows = jnp.zeros((1, 2, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="the caller scatters"):
+        paged_attention(jnp.zeros((1, 4, 128), jnp.bfloat16), z, z,
+                        jnp.zeros((1, 1), jnp.int32),
+                        jnp.ones((1,), jnp.int32), layer=1, interpret=True,
+                        new=(rows, rows))
+
+
+@pytest.mark.parametrize("kernel,Dh,fold,writes", [
+    ("dma", 128, 1, True), ("dma", 256, 1, True), ("dma", 64, 2, True),
+    ("dma", 64, 1, False),       # 64-lane rows stored unfolded: re-laid
+    ("dma", 16, 1, False), ("simple", 128, 1, False),
+])
+def test_which_pools_the_kernel_writes(monkeypatch, kernel, Dh, fold, writes):
+    from dynamo_tpu.ops.attention import paged_kernel_writes
+
+    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", kernel)
+    assert paged_kernel_writes(False, Dh, fold) is writes

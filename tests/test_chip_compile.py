@@ -235,6 +235,67 @@ def test_bucket_program_bodies_keep_the_pool_as_stored(v5e, program):
     assert ma.temp_size_in_bytes < pool_bytes // 8
 
 
+def test_the_decode_steps_rows_are_written_by_the_paged_kernel(v5e,
+                                                               monkeypatch):
+    """qwen2-1.5b's decode step at 32 lanes, all 28 layers: with the write
+    in the paged kernel the program holds no scatter into the pools
+    (``bf16[28,2,...,64,128]``: 56 of them a step before), every pool-shaped
+    value is the donated parameter or a kernel's aliased result, nothing
+    pool-sized is copied, and the program's temporaries are no larger than
+    with ``kv_write`` but for ONE re-laid ``wk`` (22 MB, once a dispatch). The
+    program with ``kv_write`` re-lays ``wk`` and ``wv`` too, into the chip's
+    fast memory, which ``temp_size_in_bytes`` does not count; this one re-lays
+    ``wk`` into HBM, which it does (read on the chip as 11.1 against 30.5 MB:
+    PERF.md section 6, PR 38)."""
+    import re
+
+    from dynamo_tpu.parallel.mesh import serving_mesh
+
+    cfg = llama.preset("qwen2-1.5b")
+    mesh = serving_mesh(1, devices=[v5e])
+    B, S = 32, 1152
+    pshape = (cfg.num_layers, cfg.num_kv_heads, 1089, PAGE, cfg.head_dim)
+    pshapes = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), pshapes)
+    pool, i32 = _sds(v5e, pshape, cfg.dtype), jnp.int32
+    args = (params, _sds(v5e, (B,), i32), pool, pool,
+            _sds(v5e, (B, S // PAGE), i32), _sds(v5e, (B,), i32))
+
+    def compiled():
+        return jax.jit(
+            lambda p, t, k, v, pt, ln: llama.forward_decode(
+                p, cfg, t, k, v, pt, ln, attn_impl="pallas", mesh=mesh),
+            donate_argnums=(2, 3)).lower(*args).compile()
+
+    assert llama.kernel_writes(mesh, "pallas", cfg.k_store_dim, cfg.kv_fold)
+    kernel = compiled()
+    monkeypatch.setattr(llama, "kernel_writes", lambda *a: False)
+    scatter = compiled()
+
+    whole = ",".join(map(str, pshape))
+    made = {"kernel": {}, "scatter": {}}
+    for how, c in (("kernel", kernel), ("scatter", scatter)):
+        for op in re.findall(r"= \(?\w+\[%s\][^=]*? ([\w\-]+)\(" % whole,
+                             c.as_text()):
+            made[how][op] = made[how].get(op, 0) + 1
+    assert made["scatter"].get("scatter") == 2 * cfg.num_layers, made
+    assert set(made["kernel"]) <= {"parameter", "get-tuple-element",
+                                   "custom-call", "bitcast"}, made
+    txt = kernel.as_text()
+    assert txt.count('custom_call_target="tpu_custom_call"') == cfg.num_layers
+    found = _pool_relayouts(txt, pshape)
+    assert not found["pool"] and not found["layer"], found
+    pool_bytes = 2 * jnp.dtype(cfg.dtype).itemsize
+    for d in pshape:
+        pool_bytes *= d
+    ma, was = kernel.memory_analysis(), scatter.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool_bytes
+    wk = pshapes["layers"]["wk"]
+    assert ma.temp_size_in_bytes <= (
+        was.temp_size_in_bytes + wk.size * wk.dtype.itemsize + (1 << 20))
+
+
 def test_the_samplers_window_stays_a_branch_on_the_v5e(v5e):
     """``sample`` at qwen2's head shape inside a decode scan: the v5e
     compiler keeps the ``lax.cond`` a real ``conditional`` (it does not
@@ -371,6 +432,33 @@ def test_explicit_pallas_off_tpu_reports_interpreted_kernel():
 
     core = EngineCore(_tiny_engine_cfg(attn_impl="pallas"))
     assert core.paged_kernel == "simple[interpret]"
+
+
+@pytest.mark.parametrize("model,kernel,expect", [
+    ({}, None, "scatter"),                       # off a TPU: interpreted
+    ({"head_dim": 128}, None, "scatter"),
+    ({"head_dim": 128}, "dma", "kernel"),        # ... the test steers it
+    ({"head_dim": 64, "kv_fold": 2}, "dma", "kernel"),
+    ({"head_dim": 64}, "dma", "scatter"),        # 64-lane rows, unfolded
+    ({"head_dim": 128}, "simple", "scatter"),
+])
+def test_the_engine_reports_what_writes_the_decode_rows(monkeypatch, model,
+                                                        kernel, expect):
+    """``dyn_engine_info{decode_kv_write}`` is what ``forward_decode``'s own
+    predicate says for the engine's pools, and the label rides the gauge."""
+    from dynamo_tpu.engine.engine import JaxEngine
+
+    if kernel:
+        monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: kernel)
+    eng = JaxEngine(_tiny_engine_cfg(
+        model=llama.preset("tiny-byte", **model), attn_impl="pallas",
+        warmup=False))
+    try:
+        assert eng.core.decode_kv_write == expect
+        text = eng.core.stage.registry.render()
+        assert f'decode_kv_write="{expect}"' in text
+    finally:
+        eng.shutdown()
 
 
 @pytest.mark.parametrize("env,interpret,expect", [

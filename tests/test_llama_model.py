@@ -255,3 +255,68 @@ def _pager_agrees(cfg, params):
 ], ids=lambda p: p if isinstance(p, str) else p.__name__.strip("_"))
 def test_forwards_agree(preset_params, preset, agrees):
     agrees(*preset_params(preset))
+
+
+# ---------------------------------------------------------------------------
+# a decode step whose paged kernel writes the new K/V rows itself against the
+# same step with ``kv_write`` in front of the kernel (the dma kernel in the
+# interpreter: off a TPU the entry point picks the one-page kernel, which
+# never writes, so the test steers it, not the program)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("preset,over", [
+    ("tiny-qwen", {"head_dim": 128}),                   # bias, rows of a tile
+    ("tiny-gemma2", {"head_dim": 128}),                 # softcap, sliding
+    ("tiny-keye", {"head_dim": 128}),                   # a selection beside it
+    ("tiny-byte", {"head_dim": 64, "kv_fold": 2}),      # two tokens a row
+    ("tiny-byte", {"head_dim": 64}),                    # ... stored unfolded
+], ids=["qwen-128", "gemma2-128", "keye-128", "fold2", "unfolded-64"])
+def test_decode_with_the_write_in_the_kernel_is_kv_write_then_the_kernel(
+        monkeypatch, preset, over):
+    """Four chained decode steps of three lanes (one of them the engine's
+    inactive lane: length 1, page 0) through ``forward_decode``: the same
+    tokens, the same logits and the same pools, bit for bit. A pool the
+    kernel cannot write (64-lane rows stored unfolded) keeps ``kv_write``
+    by what ``kernel_writes`` observes, and is then the same program."""
+    from dynamo_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
+    page = 16
+    cfg = llama.preset(preset, **over)
+    fold = cfg.kv_fold
+    writes = llama.kernel_writes(None, "pallas", cfg.k_store_dim, fold)
+    assert writes is (over != {"head_dim": 64})
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    n_pages = 7
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    lead = (cfg.num_layers, cfg.num_kv_heads, n_pages, page // fold)
+    pools = {"k": jax.random.normal(ks[0], (*lead, fold * cfg.k_store_dim),
+                                    jnp.float32).astype(cfg.dtype),
+             "v": jax.random.normal(ks[1], (*lead, fold * cfg.v_dim),
+                                    jnp.float32).astype(cfg.dtype)}
+    if cfg.has_indexer:
+        pools["i"] = jax.random.normal(
+            ks[2], llama.index_pool_shape(cfg, n_pages, page),
+            jnp.float32).astype(cfg.dtype)
+    pt = jnp.asarray([[2, 5, 1], [4, 3, 6], [0, 0, 0]], jnp.int32)
+
+    def serve():
+        dec = jax.jit(lambda p, t, k, v, ln, *i: llama.forward_decode(
+            p, cfg, t, k, v, pt, ln, attn_impl="pallas",
+            **({"i_pool": i[0]} if i else {})))
+        tok = jnp.asarray([5, 7, 9], jnp.int32)
+        ln = jnp.asarray([15, 31, 1], jnp.int32)   # ... 16/17 and 32/33 next
+        state, toks = list(pools.values()), []
+        for _ in range(4):
+            lg, *state = dec(params, tok, *state[:2], ln, *state[2:])
+            tok = jnp.argmax(lg[:, 0], -1).astype(jnp.int32)
+            toks.append(np.asarray(tok))
+            ln = ln + 1
+        return (np.stack(toks), np.asarray(lg, np.float32),
+                *(np.asarray(a, np.float32) for a in state))
+
+    got = serve()
+    monkeypatch.setattr(llama, "kernel_writes", lambda *a: False)
+    want = serve()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)

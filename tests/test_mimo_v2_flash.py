@@ -371,6 +371,54 @@ def test_block_moving_calls_refuse_and_no_block_is_hashed(core):
     assert core.prefix_hit_tokens == hit0 == 0
 
 
+def test_both_kinds_rows_are_written_by_the_paged_kernel(monkeypatch):
+    """At the published head widths (K 192 stored 256 wide, V 128; the tiny
+    model's 24 / 16 fill no lane tile and keep ``kv_write``) the dma kernel
+    (in the interpreter) writes the decode step's rows of BOTH kinds, the
+    full layers' into the global pools and the window layers' into the
+    window pools through their own page tables, sink and all: the same
+    tokens, logits and four pools, bit for bit, as ``kv_write`` in front of
+    the same kernel."""
+    from dynamo_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
+    cfg = llama.LlamaConfig.from_hf_config(dict(
+        TINY, head_dim=192, v_head_dim=128, swa_head_dim=192,
+        swa_v_head_dim=128))
+    kinds = cache_kinds(cfg)
+    assert [llama.kernel_writes(None, "pallas", k.k_store, k.fold)
+            for k in kinds] == [True, True]
+    assert not llama.kernel_writes(None, "pallas", 24, 1)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    page, n_pages = 16, 7
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 4))
+    pools = [jax.random.normal(next(keys), shape, jnp.float32)
+             .astype(cfg.dtype)
+             for k in kinds for shape in k.pool_shapes(n_pages, page)]
+    pt = jnp.asarray([[2, 5, 1], [4, 3, 6]], jnp.int32)
+    # a window lane holds only the pages a query can still see
+    wt = jnp.asarray([[3, 0, 0], [0, 1, 5]], jnp.int32)
+
+    def serve():
+        dec = jax.jit(lambda p, t, k, v, wk, wv, ln: llama.forward_decode(
+            p, cfg, t, k, v, pt, ln, attn_impl="pallas", win=(wk, wv, wt)))
+        tok = jnp.asarray([5, 7], jnp.int32)
+        ln = jnp.asarray([15, 31], jnp.int32)       # ... 16/17, 32/33 next
+        state, toks = list(pools), []
+        for _ in range(3):
+            lg, *state = dec(params, tok, *state, ln)
+            tok = jnp.argmax(lg[:, 0], -1).astype(jnp.int32)
+            toks.append(np.asarray(tok))
+            ln = ln + 1
+        return (np.stack(toks), np.asarray(lg, np.float32),
+                *(np.asarray(a, np.float32) for a in state))
+
+    got = serve()
+    monkeypatch.setattr(llama, "kernel_writes", lambda *a: False)
+    for g, w in zip(got, serve()):
+        np.testing.assert_array_equal(g, w)
+
+
 # ---- (g) -----------------------------------------------------------------
 def test_counters_say_what_the_dispatches_did(core):
     st = core.stage
