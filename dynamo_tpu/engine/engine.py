@@ -41,7 +41,8 @@ from ..llm.protocols.common import BackendInput, EngineOutput, FinishReason
 from ..models import llama
 from ..obs import flightrec as _flightrec
 from ..ops.attention import (flash_attention, paged_attention,
-                             paged_kernel_variant)
+                             paged_kernel_variant, paged_live_pages,
+                             paged_pages_per_block)
 from ..parallel.mesh import AXIS_TP, serving_mesh
 from ..runtime.engine import AsyncEngine, Context
 from ..utils import tracing as _tracing
@@ -487,6 +488,17 @@ class EngineCore:
         log.info("attention: prefill=%s decode=%s paged_kernel=%s on %s (%s)",
                  self.attn_impl, self.decode_attn_impl, self.paged_kernel,
                  dev0.platform, dev0.device_kind)
+        # the decode step's calls of the dma kernel, by the window they take
+        # (forward_decode's ``paged_for``): kind -> (window, layers), for
+        # _count_attn_pages; none where another kernel runs the step
+        self._attn_calls: Dict[str, Tuple[Optional[int], int]] = {}
+        if self.paged_kernel == "dma" and cfg.pp == 1:
+            slides = [bool(m.layer_sliding(l)) for l in range(m.num_layers)
+                      if not m.layer_state(l)]
+            calls = {"full": (None, slides.count(False)),
+                     "window": (m.sliding_window, slides.count(True))}
+            self._attn_calls = {k: c for k, c in calls.items() if c[1]}
+            self._paged_ppb = paged_pages_per_block()
 
         # --- KV pools: [L, Hkv, n_pages, page, Dh], head-major, stored
         # once in XLA's default tiled layout. The paged kernel reads the
@@ -947,6 +959,27 @@ class EngineCore:
                 seen_by.inc(counter.name, kind, amount=amount)
             seen_by.inc("dispatches", kind)
             seen_by.inc("tokens", kind, amount=float(tokens))
+
+    def _count_attn_pages(self, lengths: np.ndarray, P: int) -> None:
+        """Host counters of the pages a decode dispatch makes the paged dma
+        kernel copy a pool, and of the pages of the blocks it is in for them
+        (``ops.attention.paged_live_pages``, the kernel's own arithmetic):
+        every lane of the program as the dispatch hands it over (``lengths``
+        [B]: a lane it does not serve has length 1), a token longer each
+        step, times the attention layers of a kind; mirrored while a
+        ``DYN_PROFILE_DIR`` capture runs, as :meth:`_count_state_work`."""
+        st = self.stage
+        at = lengths[:, None] + np.arange(self.cfg.decode_steps)
+        for kind, (window, layers) in self._attn_calls.items():
+            pages = paged_live_pages(at, P, self.page_size, self._paged_ppb,
+                                     window)
+            for counter, n in zip((st.attn_pages_live, st.attn_pages_visited),
+                                  pages):
+                amount = float(n.sum() * layers)
+                counter.inc(kind, amount=amount)
+                if self.capturing:
+                    st.profile_captured_work.inc(counter.name, kind,
+                                                 amount=amount)
 
     def _release_seq(self, seq_id: str) -> None:
         self.pool.release(seq_id)
@@ -2775,6 +2808,8 @@ class EngineCore:
         if self.s_pool is not None:
             self._count_state_work("decode", B * N, len(active) * N,
                                    len(active) * N, 0, self.capturing)
+        if self._attn_calls:
+            self._count_attn_pages(lengths, P)
         self.stage.engine_dispatch_tokens.inc(
             "decode", amount=float(len(active) * N))
         self._inflight.append({"kind": "decode",

@@ -33,6 +33,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -335,7 +336,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # per-(b,h,j) variant, which moved the same bytes in 8× more copies and
 # reached only ~9% of HBM bandwidth. Work is skipped (copies AND compute)
 # for page blocks beyond a sequence's length, so cost scales with actual
-# context, not the padded table width. This is the same design as
+# context, not the padded table width; and inside an active block only the
+# pages that hold a token the lane can see are copied (the kernel is bound by
+# the NUMBER of copies it issues, 50-60 ns each, not by their bytes): copies
+# scale with visible pages, not with the block. A lane of one token copies
+# one page a pool, not ``pages_per_block``; a window layer copies the pages
+# its window touches. :func:`paged_live_pages` is the same arithmetic on the
+# host, for the engine's counters. This is the same design as
 # jax.experimental.pallas.ops.tpu.paged_attention, which we cannot use
 # directly: for GQA group sizes not divisible by 8 (Llama 8B/1B are 32q/8kv
 # = 4) its m/l pallas outputs lower to illegal (…,1) blocks in this JAX
@@ -373,6 +380,18 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     without it the kernel is what it always was. ``dv``: V rows of a width
     of their own (fold 1 only). ``sunk``: one more operand, the heads' sink
     logits [Hkv, G, 1] float32 (a key of that logit and value zero).
+
+    Inside an active block only LIVE pages are copied (``live``: the page
+    intersects ``[lo, length)``, the tokens the lane's query can see): the
+    copies of a block scale with its visible pages, not with ``ppb``. A page
+    that is not copied leaves its rows of the buffer as they were, and every
+    such row is masked: its score is replaced before the softmax and its
+    weight is an exact 0. The V buffer is zeroed once a call, before the
+    first copy, so that such a row holds zeros or rows some copy brought
+    from the pool, which are finite (what the kernel has always assumed of
+    the masked rows of a live page): 0 x finite adds nothing, where 0 x the
+    NaN of a never-written buffer would poison the lane. A K row needs no
+    such care: a NaN score is replaced with the rest.
 
     ``writes``: a one-token decode step's new rows come as two more operands
     (``k_new`` / ``v_new`` [1, 1, Hkv*fold*D]: this lane's rows, the heads
@@ -424,22 +443,37 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 
     layer = layer_ref[0]
 
-    def copy_descs(bb, jj, slot):
-        descs = []
-        for i in range(ppb):
+    def block_copies(bb, jj, slot, go):
+        """``go`` (start, or wait for) the K and V copy of every LIVE page
+        of lane ``bb``'s block ``jj``: the pages that hold a token of
+        [lo, length), a run of the block's pages from ``first`` to ``last``
+        (never empty in an active block; the page of token ``length - 1`` is
+        the last live one). Start and wait walk it by the same prefetched
+        scalars, so a wait finds its copy; the semaphore is one a slot and
+        pool, and a page's copies are all of one size. A loop over the run
+        and not a branch a page: ``ppb`` predicates a site cost the scalar
+        core what the copies they skip save (PERF.md section 6, PR 41)."""
+        n = length_of(bb)
+        first = 0
+        if window is not None:
+            first = jnp.maximum(
+                jnp.maximum(n - window, 0) // page - jj * ppb, 0)
+        last = jnp.minimum((n - 1) // page - jj * ppb, ppb - 1)
+
+        def one(i, carry):
             pidx = pt_ref[bb, jj * ppb + i]
             # one strided DMA per page covering every kv head
-            descs.append(pltpu.make_async_copy(
+            go(pltpu.make_async_copy(
                 k_hbm.at[layer, :, pidx], k_buf.at[slot, :, i],
                 sem.at[slot, 0]))
-            descs.append(pltpu.make_async_copy(
+            go(pltpu.make_async_copy(
                 v_hbm.at[layer, :, pidx], v_buf.at[slot, :, i],
                 sem.at[slot, 1]))
-        return descs
+            return carry
+        jax.lax.fori_loop(first, last + 1, one, 0)
 
     def start(bb, jj, slot):
-        for d in copy_descs(bb, jj, slot):
-            d.start()
+        block_copies(bb, jj, slot, lambda d: d.start())
 
     nb = nblocks(b)
     j0 = jstart(b)
@@ -455,6 +489,9 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         state[0] = 0
         if writes:
             state[1] = 0          # write-backs started so far
+        # rows no copy ever fills meet a weight of exactly 0 in p . V: they
+        # must be finite. From here on the buffer holds zeros or pool rows
+        v_buf[...] = jnp.zeros_like(v_buf)
         start(b, j, 0)
 
     def write_wait(w):
@@ -527,8 +564,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             start(nb_, nj, slot ^ 1)
 
         # wait for our block's DMAs
-        for d in copy_descs(b, j, slot):
-            d.wait()
+        block_copies(b, j, slot, lambda d: d.wait())
 
         if writes:
             @pl.when((j == nb - 1) & (len_ref[b] > 0))
@@ -839,6 +875,46 @@ def paged_kernel_variant(interpret: bool) -> str:
     return "simple[interpret]" if interpret else variant
 
 
+def paged_pages_per_block() -> int:
+    """Pages the dma kernel copies a grid step (``DYNAMO_TPU_PAGED_PPB``,
+    default 8): the DMA depth knob for on-chip tuning sweeps (read the
+    kernel's time in a traced benchmark run's ops_by_module) — larger blocks
+    amortize DMA issue latency, smaller ones cut the tail wasted on the
+    final partial block. Validated like the sibling DYNAMO_TPU_PAGED_KERNEL
+    knob: a typo must fail loudly, not surface as a ZeroDivisionError deep
+    in the grid math."""
+    raw_ppb = os.environ.get("DYNAMO_TPU_PAGED_PPB", "8")
+    try:
+        ppb = int(raw_ppb)
+    except ValueError:
+        ppb = -1
+    if not 1 <= ppb <= 64:
+        raise ValueError(f"DYNAMO_TPU_PAGED_PPB={raw_ppb!r} "
+                         f"(expected an integer in [1, 64])")
+    return ppb
+
+
+def paged_live_pages(lengths, P: int, page: int, ppb: int,
+                     window: Optional[int] = None):
+    """The dma kernel's own arithmetic on the host (NumPy), for one call of
+    it (one layer, one step): lanes of ``lengths`` tokens (any shape; a lane
+    of 0 counts as 1, as in the kernel) over page tables ``P`` wide ->
+    ``(live, visited)``, pages of ONE pool (K and V each copy as many), each
+    shaped as ``lengths``. ``visited``: the pages of the lane's ACTIVE
+    blocks, ``ppb`` (at most ``P``) a block, blocks ``[lo // L2,
+    ceil(length / L2))`` with ``L2 = ppb * page`` and ``lo = max(length -
+    window, 0)`` (0 without a window): what the kernel copied before it
+    told a block's pages apart. ``live``: of those, the pages that hold a
+    token of ``[lo, length)``, which is what it copies."""
+    lengths = np.maximum(np.asarray(lengths, np.int64), 1)
+    ppb = min(ppb, P)
+    L2 = ppb * page
+    lo = 0 if window is None else np.maximum(lengths - window, 0)
+    visited = (-(-lengths // L2) - lo // L2) * ppb
+    live = (lengths - 1) // page - lo // page + 1
+    return live, visited
+
+
 def paged_kernel_writes(interpret: bool, head_dim: int, fold: int) -> bool:
     """Whether :func:`paged_attention` can take a decode step's new rows
     (``new``) and write them itself: on the dma kernel, into a pool stored as
@@ -872,16 +948,20 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     lengths: [B] int32 — tokens to attend per sequence (including current)
     Returns [B, Hq, Dh]. Sequences attend to tokens [0, length); with
     ``window`` only [max(0, length - window), length). The DMA kernel
-    clamps its active block range, so out-of-window pages cost neither
-    copies nor compute (sliding decode reads O(window) bytes); the simple
-    kernel skips only their compute — its BlockSpec pipeline still copies
-    every page. ``softcap`` tanh-caps scores pre-softmax (Gemma2);
+    clamps its active block range, so out-of-window blocks cost neither
+    copies nor compute (sliding decode reads O(window) bytes), and inside an
+    active block it copies only the pages that hold a visible token: copies
+    scale with visible pages, not with the block (:func:`paged_live_pages`
+    counts both on the host). Table entries past a lane's length, or behind
+    its window, are never followed. The simple kernel skips only the
+    compute — its BlockSpec pipeline still copies every page. ``softcap``
+    tanh-caps scores pre-softmax (Gemma2);
     ``scale`` overrides rsqrt(Dh) (query_pre_attn_scalar). ``window`` and
     ``softcap`` are static (one Mosaic kernel per class); ``layer`` is
     dynamic, so all layers of a class share that kernel. ``keep`` [B, P *
     page] bool (a model with an indexer: :func:`topk_keep` over the lane's
-    logical positions) restricts the lane to its selected keys; every page
-    is still read. V rows may have a width of their own (``v_pool`` [...,
+    logical positions) restricts the lane to its selected keys; every live
+    page is still read. V rows may have a width of their own (``v_pool`` [...,
     Dv]: the result is [B, Hq, Dv]); ``sink`` [Hq] float32 is a logit a head
     that takes softmax weight and gives no value. ``fold`` f > 1: the pools
     are STORED folded, [L, Hkv, n_pages, page // f, f * Dh] (models/llama.py
@@ -946,22 +1026,9 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         lengths = jnp.maximum(lengths, 1)
     if paged_kernel_variant(interpret) == "dma":
         q4 = q.reshape(B, Hkv, G, Dh)
-        # DMA depth knob for on-chip tuning sweeps (read the kernel's time
-        # in a traced benchmark run's ops_by_module) — larger blocks
-        # amortize DMA issue latency, smaller ones cut the tail wasted on
-        # the final partial block. Validated like the sibling
-        # DYNAMO_TPU_PAGED_KERNEL knob: a typo must fail loudly, not
-        # surface as a ZeroDivisionError deep in the grid math.
-        raw_ppb = os.environ.get("DYNAMO_TPU_PAGED_PPB", "8")
-        try:
-            ppb = int(raw_ppb)
-        except ValueError:
-            ppb = -1
-        if not 1 <= ppb <= 64:
-            raise ValueError(f"DYNAMO_TPU_PAGED_PPB={raw_ppb!r} "
-                             f"(expected an integer in [1, 64])")
         out = _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables,
-                                   lengths, pages_per_block=ppb,
+                                   lengths,
+                                   pages_per_block=paged_pages_per_block(),
                                    scale=scale, softcap=softcap,
                                    window=window, keep=keep,
                                    interpret=interpret,

@@ -597,9 +597,24 @@ class StageMetrics:
             "dyn_ssm_state_bytes",
             "Bytes of the per-lane state pool and convolution-tail pool, "
             "all state-space layers and lanes", ())
+        # the paged decode kernel (ops/attention.py, the dma variant): pages
+        # of one pool, summed over the attention layers of a kind
+        self.attn_pages_live = r.counter(
+            "dyn_attn_pages_live_total",
+            "Pages the paged decode kernel copied a pool (K and V each as "
+            "many): those that hold a token the lane's query sees, every "
+            "lane of the decode program, served or not, each step, times "
+            "the attention layers of the kind (full / window)", ("kind",))
+        self.attn_pages_visited = r.counter(
+            "dyn_attn_pages_visited_total",
+            "Pages of the blocks the kernel was in for them (a block of "
+            "DYNAMO_TPU_PAGED_PPB pages that holds a visible token): what "
+            "it copied before it told a block's pages apart; live / "
+            "visited is the share of a block's copies that is left",
+            ("kind",))
         self.profile_captured_work = r.counter(
             "dyn_profile_captured_work_total",
-            "The four counters above (by name), and dispatches and tokens, "
+            "The counters above (by name), and dispatches and tokens, "
             "of the dispatches enqueued while a DYN_PROFILE_DIR capture "
             "ran: the work whose device time the trace holds",
             ("counter", "kind"))

@@ -447,3 +447,172 @@ def test_which_pools_the_kernel_writes(monkeypatch, kernel, Dh, fold, writes):
 
     monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", kernel)
     assert paged_kernel_writes(False, Dh, fold) is writes
+
+
+# ---------------------------------------------------------------------------
+# The dma kernel copies only the pages that hold a token the lane can see
+# ---------------------------------------------------------------------------
+
+# lengths over a table of 8 pages of 32 (four blocks at ppb 2): an EMPTY lane
+# and one token (both on an all-zero table, as the engine hands over a lane
+# it does not serve), a whole page and a page + 1, a whole block and a block
+# + 1, mid-page in the second block, several blocks, the table's last rows
+_LIVE_LENGTHS = [0, 1, 32, 33, 64, 65, 77, 128, 130, 200, 255, 256]
+_LIVE_CASES = [
+    # case, Dh, Dv, fold, window, selected, sunk
+    ("plain", 128, 128, 1, None, False, False),
+    ("window", 128, 128, 1, 40, False, False),
+    ("selected", 128, 128, 1, None, True, False),        # keye
+    ("sunk-dv", 256, 128, 1, None, False, True),
+    ("window-sunk-dv", 256, 128, 1, 40, False, True),    # mimo's window layers
+    ("fold2", 64, 64, 2, None, False, False),            # granite
+    ("fold2-window", 64, 64, 2, 70, False, False),
+]
+
+
+def _visible_pages(n, page, window):
+    """Pages that hold a token a query at ``n - 1`` sees, token by token (a
+    lane of 0 attends like a lane of 1)."""
+    n = max(n, 1)
+    return sorted({t // page
+                   for t in range(max(n - window, 0) if window else 0, n)})
+
+
+@pytest.mark.parametrize("writes", [False, True], ids=["reads", "writes"])
+@pytest.mark.parametrize("case,Dh,Dv,fold,window,selected,sunk", _LIVE_CASES,
+                         ids=[c[0] for c in _LIVE_CASES])
+def test_dead_pages_are_never_read(case, Dh, Dv, fold, window, selected,
+                                   sunk, writes):
+    """Every table entry past a lane's last token, and behind its window,
+    names ONE page that is NaN in both pools and every layer: the dma kernel
+    (interpreter) still gives what a float32 reference gives over the visible
+    tokens, and with ``new`` the pools it hands back are ``kv_write``'s, the
+    poisoned page as it was. The kernel as it stood before it told a block's
+    pages apart FAILS this by construction: it copied every page of an
+    active block and ``p . V`` multiplied a weight of 0 by the NaN (0 x NaN
+    = NaN). So a pass also says which pages the interpreted kernel fetched:
+    all the visible ones (or the output is wrong) and no other (or it is
+    NaN), and their count a lane is ``paged_live_pages``'."""
+    from dynamo_tpu.models.llama import kv_write
+    from dynamo_tpu.ops.attention import (_paged_attention_tpu,
+                                          paged_live_pages)
+
+    L, layer, Hkv, G, page, ppb, P = 2, 1, 2, 2, 32, 2, 8
+    lanes = _LIVE_LENGTHS
+    B = len(lanes)
+    n_pages = B * P + 2
+    poison = n_pages - 1
+    ks = jax.random.split(jax.random.PRNGKey(41), 7)
+    bf = jnp.bfloat16
+
+    def rand(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(bf)
+
+    tables = np.full((B, P), poison, np.int32)
+    for b, n in enumerate(lanes):
+        if n <= 1:
+            tables[b] = 0       # a lane the dispatch does not serve
+        else:
+            seen = _visible_pages(n, page, window)
+            tables[b, seen] = 1 + b * P + np.asarray(seen)
+    live, visited = paged_live_pages(lanes, P, page, ppb, window)
+    assert list(live) == [len(_visible_pages(n, page, window))
+                          for n in lanes]
+    assert list(live[2:]) == list((tables[2:] != poison).sum(1))
+    assert (live < visited).any() and (live <= visited).all()
+
+    q = rand(ks[0], B, Hkv, G, Dh)
+    pools = (rand(ks[1], L, Hkv, n_pages, page // fold, fold * Dh),
+             rand(ks[2], L, Hkv, n_pages, page // fold, fold * Dv))
+    k_pool, v_pool = (p.at[:, :, poison].set(jnp.nan) for p in pools)
+    k_new, v_new = rand(ks[3], B, Hkv, Dh), rand(ks[4], B, Hkv, Dv)
+    lengths = jnp.asarray(lanes, jnp.int32)
+    page_tables = jnp.asarray(tables)
+    keep = sink = None
+    kw = dict(pages_per_block=ppb, window=window, interpret=True,
+              stored_fold=fold)
+    if selected:
+        kw["keep"] = keep = jax.random.bernoulli(ks[5], 0.5, (B, P * page))
+    if sunk:
+        kw["sink"] = sink = jax.random.normal(ks[6], (Hkv * G,), jnp.float32)
+    ly = jnp.asarray([layer], jnp.int32)
+
+    if writes:
+        pos = lengths - 1
+        w_page = jnp.take_along_axis(page_tables, (pos // page)[:, None],
+                                     1)[:, 0]
+        want_pools = [kv_write(p, layer, w_page, pos % page, new)
+                      for p, new in ((k_pool, k_new), (v_pool, v_new))]
+        got, *got_pools = _paged_attention_tpu(
+            q, k_pool, v_pool, ly, page_tables, lengths, new=(k_new, v_new),
+            **kw)
+        for got_pool, want_pool, old in zip(got_pools, want_pools,
+                                            (k_pool, v_pool)):
+            got_pool, want_pool, old = (np.array(a, np.float32)
+                                        for a in (got_pool, want_pool, old))
+            # kv_write's scratch row for the empty lane (position -1: the
+            # table's last entry, offset page - 1); the kernel writes none
+            scratch = (layer, slice(None), 0, (page - 1) // fold)
+            np.testing.assert_array_equal(got_pool[scratch], old[scratch])
+            want_pool[scratch] = old[scratch]
+            assert np.isnan(got_pool[:, :, poison]).all()
+            np.testing.assert_array_equal(got_pool, want_pool)
+        ref_pools = got_pools
+    else:
+        got = _paged_attention_tpu(q, k_pool, v_pool, ly, page_tables,
+                                   jnp.maximum(lengths, 1), **kw)
+        ref_pools = (k_pool, v_pool)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+
+    # the reference: float32, over the tokens a lane's query sees alone. With
+    # ``new`` the empty lane reads row 0 of page 0 before the lane of one
+    # token (the next in the grid) writes its own there
+    def rows(pools):
+        return [np.asarray(p[layer], np.float32).reshape(Hkv, n_pages, page,
+                                                         -1) for p in pools]
+
+    before, after = rows((k_pool, v_pool)), rows(ref_pools)
+    qf = np.asarray(q, np.float32)
+    for b, n in enumerate(lanes):
+        rk, rv = before if n == 0 else after
+        n = max(n, 1)
+        t = np.arange(max(n - window, 0) if window else 0, n)
+        if keep is not None:
+            t = t[np.asarray(keep[b])[t]]
+        at = (slice(None), tables[b, t // page], t % page)
+        s = np.einsum("hgd,htd->hgt", qf[b], rk[at]) / math.sqrt(Dh)
+        m = s.max(-1, keepdims=True) if len(t) else np.zeros((Hkv, G, 1))
+        p = np.exp(s - m)
+        den = p.sum(-1, keepdims=True)
+        if sink is not None:
+            den = den + np.exp(np.asarray(sink).reshape(Hkv, G, 1) - m)
+        want = np.einsum("hgt,htd->hgd", p, rv[at]) / np.where(den == 0, 1,
+                                                              den)
+        np.testing.assert_allclose(got[b], want, atol=2e-2, rtol=2e-2,
+                                   err_msg=f"lane {b} of {n}")
+
+
+@pytest.mark.parametrize("window", [None, 40, 64, 200])
+@pytest.mark.parametrize("ppb,P", [(2, 8), (8, 8), (8, 3), (3, 8)])
+def test_live_pages_counted_token_by_token(window, ppb, P):
+    """``paged_live_pages`` (the host's copy of the kernel's predicate)
+    against a count over tokens: a page is live if it holds a token the
+    query sees, a block is active if it holds a live page; shaped as the
+    lengths it is given."""
+    from dynamo_tpu.ops.attention import paged_live_pages
+
+    page = 32
+    lengths = [n for n in _LIVE_LENGTHS + [31, 96, 97, 127] if n <= P * page]
+    width = min(ppb, P)
+    seen = [_visible_pages(n, page, window) for n in lengths]
+    live, visited = paged_live_pages(lengths, P, page, ppb, window)
+    assert list(live) == [len(pgs) for pgs in seen]
+    assert list(visited) == [len({p // width for p in pgs}) * width
+                             for pgs in seen]
+    steps = np.asarray(lengths)[:, None] + np.arange(3)
+    steps = np.minimum(steps, P * page)
+    live2, visited2 = paged_live_pages(steps, P, page, ppb, window)
+    assert live2.shape == visited2.shape == steps.shape
+    assert list(live2[:, 0]) == list(live)
+    assert list(visited2[:, 0]) == list(visited)

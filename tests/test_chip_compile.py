@@ -111,6 +111,54 @@ def test_paged_compiles_for_v5e(v5e, monkeypatch, geom, kernel, variant):
     assert "tpu_custom_call" in txt
 
 
+# the cells' decode kernels as their programs call them (PERF.md section 4):
+# lanes, q heads, kv heads, K row as stored, V row, tokens a pool row, table
+# pages, window, sink, keep mask
+CELL_KERNELS = {
+    "qwen2": (32, 12, 2, 128, 128, 1, 18, None, False, False),
+    "mistral": (16, 32, 8, 128, 128, 1, 34, None, False, False),
+    "mimo-full": (32, 64, 4, 256, 128, 1, 128, None, False, False),
+    "mimo-window": (32, 64, 8, 256, 128, 1, 128, 128, True, False),
+    "granite": (64, 32, 8, 64, 64, 2, 32, None, False, False),
+    "keye": (12, 32, 4, 128, 128, 1, 232, None, False, True),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_KERNELS))
+def test_the_kernel_that_copies_live_pages_compiles_at_the_cells_shapes(
+        v5e, cell):
+    """The dma kernel with its loop over a block's LIVE pages (dynamic
+    bounds, a dynamic page of the buffer as a copy's target) and the V buffer
+    zeroed at the first grid step, at every cell's shapes and variant, the
+    new rows written by the kernel: the v5e compiler takes it, and the call
+    keeps its first result type, by which the chip's trace names it."""
+    B, Hq, Hkv, Dk, Dv, fold, P, window, sunk, selected = CELL_KERNELS[cell]
+    L, n_pages, bf, i32 = 2, 40, jnp.bfloat16, jnp.int32
+    extra, extra_args = [], []
+    if sunk:
+        extra.append("sink")
+        extra_args.append(_sds(v5e, (Hq,), jnp.float32))
+    if selected:
+        extra.append("keep")
+        extra_args.append(_sds(v5e, (B, P * PAGE), jnp.bool_))
+
+    def call(q, k, v, pt, ln, ly, kn, vn, *rest):
+        return A.paged_attention(
+            q, k, v, pt, ln, ly, interpret=False, window=window,
+            new=(kn, vn), **dict(zip(extra, rest)),
+            **({"fold": fold} if fold > 1 else {}))
+
+    txt = _compiled_text(
+        call, _sds(v5e, (B, Hq, Dk), bf),
+        _sds(v5e, (L, Hkv, n_pages, PAGE // fold, fold * Dk), bf),
+        _sds(v5e, (L, Hkv, n_pages, PAGE // fold, fold * Dv), bf),
+        _sds(v5e, (B, P), i32), _sds(v5e, (B,), i32), _sds(v5e, (), i32),
+        _sds(v5e, (B, Hkv, Dk), bf), _sds(v5e, (B, Hkv, Dv), bf),
+        *extra_args)
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"(bf16[{B},{Hkv},{Hq // Hkv},{Dv}]" in txt
+
+
 def test_decode_step_compiles_with_kernels_for_v5e(v5e):
     """One whole decode step at llama-3.2-1b widths (depth cut to two layers
     to stay within seconds), placed on the described device through a mesh:

@@ -1389,3 +1389,77 @@ def test_pallas_decode_path_serves_what_the_xla_path_serves():
         assert [g.token for g in x] == [g.token for g in p]
         np.testing.assert_allclose([g.token_logprob for g in p],
                                    [g.token_logprob for g in x], atol=2e-5)
+
+
+def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
+    """tiny-gemma2 (a full layer and a window layer, window 8 = one page)
+    on four lanes through the dma kernel (interpreter): the tokens are the
+    dense path's (float32, as above), and every decode dispatch moves
+    ``dyn_attn_pages_live_total`` / ``dyn_attn_pages_visited_total`` by what
+    ``paged_live_pages`` gives for ALL lanes of the program as the dispatch
+    hands them over (the two it does not serve: length 1), a token longer
+    each step, by attention kind; a capture counts its own dispatches a
+    second time, under the counters' names."""
+    from dynamo_tpu.engine import engine as E
+    from dynamo_tpu.ops import attention as A
+
+    import jax.numpy as jnp
+
+    cfg = dict(model=llama.preset("tiny-gemma2", dtype=jnp.float32),
+               max_batch=4)
+    reqs = {"a": req(list(range(3, 40)), max_tokens=9),
+            "b": req([7, 8, 9], max_tokens=6)}
+
+    def serve(core):
+        for name, r in reqs.items():
+            core.submit(name, r)
+        got = drain(core, list(reqs))
+        while core.has_work:        # the overshoot dispatch behind the finish
+            core.step()
+        return {n: [g.token for g in got[n]] for n in reqs}
+
+    dense = EngineCore(make_cfg(attn_impl="xla", **cfg))
+    want = serve(dense)
+    assert not dense.stage.attn_pages_visited._values   # no kernel, no pages
+    for mod in (A, E):
+        monkeypatch.setattr(mod, "paged_kernel_variant",
+                            lambda interpret: "dma")
+    # a model without a window has one kind: every layer's call is ``full``
+    assert EngineCore(make_cfg(attn_impl="pallas", max_batch=2))._attn_calls \
+        == {"full": (None, llama.preset("tiny-byte").num_layers)}
+    core = EngineCore(make_cfg(attn_impl="pallas", **cfg))
+    assert core.paged_kernel == "dma"
+    assert core._attn_calls == {"full": (None, 1), "window": (8, 1)}
+    seen = []
+    core.dispatch_hook = lambda kind, meta, arrs: kind == "decode" and (
+        seen.append((meta["S"], arrs["lengths"].copy(), core.capturing)))
+    st = core.stage
+    assert serve(core) == want
+    assert not st.profile_captured_work._values
+    core.capturing = True
+    try:
+        reqs = {"c": req(list(range(5, 30)), max_tokens=5)}
+        serve(core)
+    finally:
+        core.capturing = False
+
+    N, page = core.cfg.decode_steps, core.page_size
+    want = {}
+    for S, lengths, captured in seen:
+        assert (lengths == 1).sum() >= 2          # the lanes not served
+        at = lengths[:, None] + np.arange(N)
+        for kind, window in (("full", None), ("window", 8)):
+            pages = A.paged_live_pages(at, S // page, page, 8, window)
+            for name, n in zip(("live", "visited"), pages):
+                for key in [(name, kind)] + [(name, kind, "cap")] * captured:
+                    want[key] = want.get(key, 0) + n.sum()    # one layer each
+    assert any(c for *_, c in seen) and not all(c for *_, c in seen)
+    for name, counter in (("live", st.attn_pages_live),
+                          ("visited", st.attn_pages_visited)):
+        for kind in ("full", "window"):
+            assert counter.get(kind) == want[name, kind] > 0
+            assert st.profile_captured_work.get(counter.name, kind) == \
+                want[name, kind, "cap"] > 0
+    # a window of one page sees two pages at most; a block holds eight
+    assert st.attn_pages_live.get("window") < st.attn_pages_live.get("full")
+    assert st.attn_pages_live.get("full") < st.attn_pages_visited.get("full")
