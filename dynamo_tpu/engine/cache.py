@@ -48,7 +48,13 @@ class CacheKind:
     pool and page tables of their own, :class:`WindowPages`); a model with
     state-space layers has ``global`` (its attention layers) and ``state``:
     what those layers keep per LANE, whatever the lane's tokens — nothing
-    pages it, nothing hashes it, and ``token_bytes`` of it is 0."""
+    pages it, nothing hashes it, and ``token_bytes`` of it is 0. A model
+    with latent attention has ONE kind, ``global``, whose token is one row
+    for all heads (``latent``): the rotated shared key (``k_dim``, stored
+    zero-padded to a lane tile in the K pool) and the compressed vector
+    every head's K and V are expanded from (``v_dim``, the V pool), under
+    one "head". It is per token, hashed and sealed like any K/V page: prefix
+    match and adoption stay on."""
 
     name: str                   # "global" | "window" | "state"
     layers: int
@@ -59,6 +65,7 @@ class CacheKind:
     window: Optional[int]       # keys a query sees, its own among them
     index_dim: int = 0          # an indexer's keys on the same pages
     fold: int = 1               # tokens stored to a pool row (kv_fold)
+    latent: bool = False        # one row for all heads (latent attention)
     # per lane and layer (the ``state`` kind): the recurrent state's shape
     # (float32) and the convolution tail's (the model's dtype)
     state: Optional[Tuple[Tuple[int, ...], Tuple[int, ...]]] = None
@@ -82,6 +89,9 @@ class CacheKind:
     def label(self) -> str:
         """The kind as ``dyn_engine_info{cache_kinds}`` names it: layers x
         heads x (K + V) a token, or what a lane keeps in a state kind."""
+        if self.latent:
+            return (f"{self.name}:{self.layers}x(latent {self.v_dim}"
+                    f"+rope {self.k_dim})")
         if self.state is None:
             return (f"{self.name}:{self.layers}x{self.kv_heads}x"
                     f"({self.k_dim}+{self.v_dim})")
@@ -107,6 +117,10 @@ class CacheKind:
 
 def cache_kinds(m) -> Tuple[CacheKind, ...]:
     """The cache kinds of model ``m`` (a ``LlamaConfig``), global first."""
+    if m.has_latent:
+        return (CacheKind("global", m.num_layers, 1, m.qk_rope_dim,
+                          m.kv_lora_rank, m.latent_k_store, None,
+                          latent=True),)
     if not m.per_kind:
         return (CacheKind("global", m.num_layers, m.num_kv_heads, m.head_dim,
                           m.v_dim, m.k_store_dim, None,

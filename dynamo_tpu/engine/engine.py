@@ -603,6 +603,17 @@ class EngineCore:
                          "block is hashed, sealed or published)")
         # no block of such a model is hashed, matched or adopted
         self._no_block_reuse = self.win is not None or m.has_state
+        # A model with latent attention keeps ONE row a token for all heads,
+        # in the two pools above (the shared rotary key; the compressed
+        # vector), per token and on the global pages: hashed, sealed,
+        # matched and adopted like any K/V page. What MOVES blocks off the
+        # device pool sizes its buffers by kv heads x head_dim of one shape
+        # for both pools: refused by name.
+        if m.has_latent:
+            if cfg.pp > 1:
+                raise ValueError(self._latent_refusal(
+                    "pp > 1 (the staged forward)"))
+            self._refuse_configured(impl, self._latent_refusal)
 
         # --- KV block manager: tiered offload + prefix reuse ----------
         from ..llm.kvbm.transfer import CopyStream
@@ -838,6 +849,14 @@ class EngineCore:
                 f"them selects wrongly without any error")
 
     @staticmethod
+    def _latent_refusal(what: str) -> str:
+        return (f"a model with latent attention (the latent cache kind: one "
+                f"compressed row a token for all heads, its rotary key and "
+                f"its compressed vector in pools of two widths) does not "
+                f"run with {what}: that path's cache I/O knows K and V "
+                f"pools of one shape, kv heads x head_dim")
+
+    @staticmethod
     def _two_caches_refusal(what: str) -> str:
         return (f"a model whose window layers keep a cache of their own "
                 f"(a second page pool and page table a lane) does not run "
@@ -856,14 +875,17 @@ class EngineCore:
         """Refuse a feature that moves K/V blocks off or onto the device
         pool for a model that keeps more than K and V on those pages (an
         indexer's keys), keeps a second cache beside them (a per-kind
-        model's window layers) or keeps a state a lane that no block holds
-        (state-space layers)."""
+        model's window layers), keeps a state a lane that no block holds
+        (state-space layers) or keeps its blocks in pools of two widths (the
+        latent cache kind)."""
         if self.cfg.model.has_indexer:
             raise ValueError(self._indexer_refusal(what))
         if self.cfg.model.has_window:
             raise ValueError(self._two_caches_refusal(what))
         if self.cfg.model.has_state:
             raise ValueError(self._state_refusal(what))
+        if self.cfg.model.has_latent:
+            raise ValueError(self._latent_refusal(what))
 
     def _idx(self) -> Dict[str, Any]:
         """The programs' further pool operands: the index-key pool of a
@@ -1043,6 +1065,16 @@ class EngineCore:
                         + (n - below) * k)
             work[self.stage.sparse_attn_context] = float(seen)
             work[self.stage.sparse_attn_selected] = float(sel)
+        if m.has_latent:
+            # what the dispatch's attention had to read and multiply at
+            # least (one layer's worth): the latent rows (a decode query
+            # reads its lane's visible rows; a chunk's queries share their
+            # lane's, read once) and the (query, visible key) pairs
+            pairs = [n * p0 + n * (n + 1) // 2 for p0, n in spans]
+            work[self.stage.attn_latent_pairs] = float(sum(pairs))
+            work[self.stage.attn_latent_keys] = float(
+                sum(p0 + n for p0, n in spans) if kind == "prefill"
+                else sum(pairs))
         for counter, amount in work.items():
             counter.inc(kind, amount=amount)
         if captured:
@@ -3200,6 +3232,27 @@ def _pallas_probe(m, cfg, device) -> None:
     # the pools store them
     kw = dict(scale=m.attn_scale, softcap=m.attn_logit_softcap)
     Dk, Dv = m.k_store_dim, m.v_dim
+    if m.has_latent:
+        # all heads against one row a key: the shared rotary key's pool and
+        # the compressed vectors', the queries' second part beside them
+        Dk, Dv = m.latent_k_store, m.kv_lora_rank
+        with jax.default_device(device):
+            T = max(8, min(128, cfg.prefill_chunk))
+            pos = jnp.zeros((2, T), jnp.int32)
+            paged_attention(
+                jnp.zeros((2, Hq, Dk), m.dtype),
+                jnp.zeros((2, 1, 3, page, Dk), m.dtype),
+                jnp.zeros((2, 1, 3, page, Dv), m.dtype),
+                jnp.zeros((2, 1), jnp.int32), jnp.ones((2,), jnp.int32), 1,
+                interpret=False, latent=jnp.zeros((2, Hq, Dv), m.dtype),
+                **kw).block_until_ready()
+            flash_attention(
+                jnp.zeros((2, T, Hq, Dk), m.dtype),
+                jnp.zeros((2, T, 1, Dk), m.dtype),
+                jnp.zeros((2, T, 1, Dv), m.dtype), pos, pos, pos < 1,
+                interpret=False, latent=jnp.zeros((2, T, Hq, Dv), m.dtype),
+                **kw).block_until_ready()
+        return
     if m.has_window:
         variants = [(k.kv_heads, k.window,
                      m.sink_window if k.window else m.sink_full)

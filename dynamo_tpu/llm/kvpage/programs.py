@@ -252,6 +252,8 @@ class PagedPrograms:
         m = cfg.model
         if m.has_state:
             return f"models with state-space layers ({llama.NO_STATE})"
+        if m.has_latent:
+            return f"models with latent attention ({llama.NO_LATENT})"
         if m.per_kind:
             return (f"models whose window layers keep a cache of their own "
                     f"({llama.NO_SECOND_CACHE})")

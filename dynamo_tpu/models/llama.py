@@ -200,6 +200,28 @@ class LlamaConfig:
     # computes the held part (models/moe.py)
     router_experts: Optional[int] = None
     expert_first: int = 0
+    # the third law, ``softmax_group`` (DeepSeek-V2's group_limited_greedy):
+    # softmax scores; the experts lie in ``router_groups[0]`` equal groups,
+    # a group scores as its best expert, the ``router_groups[1]`` best
+    # groups stay and the top-k is taken inside them; the gates are the
+    # chosen scores x ``routed_scaling``, NOT renormalised
+    router_groups: Optional[Tuple[int, int]] = None
+    routed_scaling: float = 1.0
+    # ``shared_experts`` experts of ``expert_width`` that EVERY token passes
+    # through (one SwiGLU of their joint width), added to the routed sum
+    shared_experts: int = 0
+    # Latent attention (DeepSeek-V2's MLA; ``kv_lora_rank`` 0 = none): q is
+    # low-rank (``q_lora_rank``, with an RMSNorm between its two matrices),
+    # K and V are expanded from ONE compressed vector a token
+    # (``kv_lora_rank`` wide, RMSNorm'd) and every head shares ONE rotary
+    # key (``qk_rope_dim``); a head's q / k are [``qk_nope_dim`` |
+    # ``qk_rope_dim``] = ``head_dim`` wide, its v ``v_head_dim``. The cache
+    # keeps the normed compressed vector and the rotated key and nothing per
+    # head ("Latent attention" below; :meth:`has_latent`).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
     # State-space layers (Granite-4.0-H; ``layer_kinds[l] == 2``): a
     # Mamba-2 mixer in place of attention, which keeps per LANE a recurrent
     # state [ssm_heads, ssm_head_dim, ssm_state] float32 and the last
@@ -240,6 +262,17 @@ class LlamaConfig:
         """State-space layers that keep a recurrent state a lane."""
         return self.per_kind and 2 in self.layer_kinds
 
+    @property
+    def has_latent(self) -> bool:
+        """Latent attention: the cache keeps one compressed row a token."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_k_store(self) -> int:
+        """Width the shared rotary key is STORED at: zero-padded to a lane
+        tile (64 -> 128), for :meth:`k_store_dim`'s reason."""
+        return -(-self.qk_rope_dim // 128) * 128
+
     def layer_window(self, l: int) -> bool:
         """Layer ``l`` keeps its K/V in the window cache (a Python bool)."""
         return self.per_kind and self.layer_kinds[l] == 1
@@ -261,11 +294,19 @@ class LlamaConfig:
         what is being added (with the head's bfloat16 logits, :func:`_lm_head`,
         40 layers read 0.011-0.017 sigma rms against the float32 reference,
         int8 weights in the same range; 0.0025-0.0033 with both in float32,
-        int8 0.0073-0.0197: my chip runs, PR 36, calls C and E). Norms, projections and kernels see the normed
-        activations in the model's dtype as before; only the adds and the
-        norms' inputs are wider."""
-        return (jnp.float32 if self.residual_multiplier is not None
-                else self.dtype)
+        int8 0.0073-0.0197: my chip runs, PR 36, calls C and E). And float32
+        for a model with GROUP-LIMITED routing (``router_groups``): what the
+        stream loses reaches the router, a near-tie between two experts or
+        two groups then falls the other way than in the float32 reference,
+        and one such flip at a scored position moves its logits by 0.04-0.35
+        sigma; a float32 stream halves the flips (PERF.md section 6, PR 42,
+        has the readings). Any routed model would gain so; the two older
+        ones keep their programs until a PR judges the change on their
+        cells. Norms, projections and kernels see the normed activations in
+        the model's dtype as before; only the adds and the norms' inputs are
+        wider."""
+        wide = self.residual_multiplier is not None or self.router_groups
+        return jnp.float32 if wide else self.dtype
 
     @property
     def ssm_inner(self) -> int:
@@ -372,6 +413,23 @@ class LlamaConfig:
         if hybrid:
             # the feed-forward every layer has is the SHARED one's width
             cfg = {**cfg, "intermediate_size": cfg["shared_intermediate_size"]}
+        latent = _map_latent(cfg)
+        rs = cfg.get("rope_scaling") or {}
+        if latent:
+            # a head's q / k width, and the ONE row a token the cache keeps
+            cfg = {**cfg, "head_dim": latent["qk_nope_dim"]
+                   + latent["qk_rope_dim"], "num_key_value_heads": 1}
+        elif rs.get("rope_type", rs.get("type")) == "yarn":
+            raise ValueError("rope_scaling type 'yarn' is implemented for "
+                             "latent attention alone (kv_lora_rank)")
+        experts, kinds = _map_experts(cfg), _map_layer_kinds(cfg)
+        if "ffn_kinds" in experts and not (
+                {"layer_kinds"} & {*kinds, *hybrid, *latent}):
+            raise ValueError(
+                "dense layers among routed ones (moe_layer_freq / "
+                "first_k_dense_replace) are implemented for a model whose "
+                "layers are described one by one (hybrid_layer_pattern, "
+                "layer_types, latent attention)")
         return cls(
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
@@ -412,11 +470,12 @@ class LlamaConfig:
                               else cfg.get("swa_rope_theta")),
             qk_norm=_is_gemma3(cfg) or _is_qwen3_family(cfg),
             dtype=dtype,
-            **_map_experts(cfg),
+            **experts,
             **_map_indexer(cfg),
-            **_map_layer_kinds(cfg),
+            **kinds,
             **_map_multipliers(cfg),
             **hybrid,
+            **latent,
         )
 
 
@@ -433,6 +492,7 @@ _EXPERT_KEYS = ("num_experts", "num_local_experts", "num_experts_per_tok",
                 "n_routed_experts", "n_shared_experts", "scoring_func",
                 "topk_method", "n_group", "topk_group",
                 "routed_scaling_factor", "moe_layer_freq",
+                "first_k_dense_replace",
                 # a chip's share of the experts (not a published key: a
                 # deployment's, see _map_experts)
                 "expert_shard")
@@ -480,8 +540,8 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
     if E and "shared_intermediate_size" in cfg:
         raise ValueError(
             f"num_local_experts {E} beside shared_intermediate_size: routed "
-            f"experts beside a shared feed-forward in every layer are not "
-            f"implemented (the larger Granite-4.0-H models)")
+            f"experts beside a shared feed-forward in every layer of a "
+            f"hybrid stack are not implemented")
     if not E:
         raise ValueError(f"expert keys {sorted(seen)} without num_experts / "
                          f"num_local_experts / n_routed_experts")
@@ -496,10 +556,26 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
         raise ValueError("num_experts without norm_topk_prob: the family's "
                          "default is false, which this engine does not "
                          "implement")
-    if not cfg.get("norm_topk_prob", True):
-        raise ValueError("norm_topk_prob false is not implemented: the "
-                         "router renormalises the chosen gates "
-                         "(models/moe.route_topk)")
+    law = (cfg.get("scoring_func", "softmax"),
+           cfg.get("topk_method", "greedy"))
+    routers = {("softmax", "greedy"): "softmax",
+               ("sigmoid", "noaux_tc"): "sigmoid_bias",
+               ("softmax", "group_limited_greedy"): "softmax_group"}
+    if law not in routers:
+        raise ValueError(
+            f"scoring_func {law[0]!r} with topk_method {law[1]!r} is not "
+            f"implemented (softmax with greedy or group_limited_greedy; "
+            f"sigmoid with noaux_tc)")
+    grouped = routers[law] == "softmax_group"
+    if bool(cfg.get("norm_topk_prob", True)) == grouped:
+        # each law as its family publishes it: the two that choose among
+        # all experts renormalise the chosen gates, the grouped one scales
+        # them by routed_scaling_factor and does not
+        raise ValueError(
+            f"norm_topk_prob {cfg.get('norm_topk_prob')!r} with topk_method "
+            f"{law[1]!r} is not implemented: greedy / noaux_tc renormalise "
+            f"the chosen gates, group_limited_greedy does not "
+            f"(models/moe.route_topk)")
     if cfg.get("mlp_only_layers"):
         raise ValueError(f"mlp_only_layers {cfg['mlp_only_layers']} is not "
                          f"implemented: the leading dense layers of a model "
@@ -508,33 +584,58 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
         raise ValueError(f"decoder_sparse_step "
                          f"{cfg['decoder_sparse_step']} is not implemented: "
                          f"every layer is a routed-expert layer")
-    if cfg.get("n_shared_experts"):
-        raise ValueError(f"n_shared_experts {cfg['n_shared_experts']} is "
-                         f"not implemented: no expert that every token "
-                         f"passes through")
-    for k in ("n_group", "topk_group"):
-        if cfg.get(k) not in (None, 1):
-            raise ValueError(f"{k} {cfg[k]} is not implemented: the experts "
-                             f"are chosen among all, not by group")
-    if cfg.get("routed_scaling_factor") not in (None, 1, 1.0):
-        raise ValueError(f"routed_scaling_factor "
-                         f"{cfg['routed_scaling_factor']} is not implemented")
-    law = (cfg.get("scoring_func", "softmax"),
-           cfg.get("topk_method", "greedy"))
-    routers = {("softmax", "greedy"): "softmax",
-               ("sigmoid", "noaux_tc"): "sigmoid_bias"}
-    if law not in routers:
-        raise ValueError(
-            f"scoring_func {law[0]!r} with topk_method {law[1]!r} is not "
-            f"implemented (softmax with greedy; sigmoid with noaux_tc)")
+    shared = cfg.get("n_shared_experts") or 0
+    if shared and not (grouped and cfg.get("moe_intermediate_size")):
+        raise ValueError(f"n_shared_experts {shared} is implemented beside "
+                         f"group-limited routed experts of a width of their "
+                         f"own (moe_intermediate_size) alone")
+    shard = cfg.get("expert_shard")
+    R = int(shard.get("router_experts", E)) if shard else int(E)
     out = {"num_experts": int(E),
            "experts_per_token": int(cfg["num_experts_per_tok"]),
            "moe_intermediate_size": (
                int(cfg["moe_intermediate_size"])
                if cfg.get("moe_intermediate_size") else None),
            "router": routers[law]}
+    if grouped:
+        G, Gk = cfg.get("n_group"), cfg.get("topk_group")
+        if not G or not Gk or R % int(G) or not 0 < int(Gk) <= int(G):
+            raise ValueError(
+                f"group_limited_greedy needs n_group that divides the "
+                f"router's {R} experts and 0 < topk_group <= n_group (got "
+                f"{G!r}, {Gk!r})")
+        if int(Gk) * (R // int(G)) < int(cfg["num_experts_per_tok"]):
+            raise ValueError(f"topk_group {Gk} groups of {R // int(G)} hold "
+                             f"fewer experts than num_experts_per_tok")
+        out.update(router_groups=(int(G), int(Gk)),
+                   routed_scaling=float(cfg.get("routed_scaling_factor", 1)),
+                   shared_experts=int(shared))
+    else:
+        for k in ("n_group", "topk_group"):
+            if cfg.get(k) not in (None, 1):
+                raise ValueError(f"{k} {cfg[k]} is not implemented with "
+                                 f"topk_method {law[1]!r}: the experts are "
+                                 f"chosen among all, not by group")
+        if cfg.get("routed_scaling_factor") not in (None, 1, 1.0):
+            raise ValueError(
+                f"routed_scaling_factor {cfg['routed_scaling_factor']} is "
+                f"not implemented with topk_method {law[1]!r}")
     freq = cfg.get("moe_layer_freq")
-    if freq is not None:
+    first_dense = cfg.get("first_k_dense_replace")
+    if isinstance(freq, int) and not isinstance(freq, bool):
+        # the DeepSeek-V2 spelling: ``first_k_dense_replace`` leading dense
+        # layers, then every ``moe_layer_freq``-th layer routed
+        L = cfg["num_hidden_layers"]
+        k0 = int(first_dense or 0)
+        if freq < 1 or not 0 <= k0 < L:
+            raise ValueError(f"moe_layer_freq {freq} / first_k_dense_replace "
+                             f"{first_dense!r}: no routed layer among {L}")
+        kinds = tuple(int(l >= k0 and l % freq == 0) for l in range(L))
+        if not any(kinds):
+            raise ValueError("moe_layer_freq names no routed layer")
+        if not all(kinds):
+            out["ffn_kinds"] = kinds
+    elif freq is not None:
         L = cfg["num_hidden_layers"]
         if (not isinstance(freq, (list, tuple)) or len(freq) < L
                 or any(f not in (0, 1) for f in freq)):
@@ -545,7 +646,10 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
             raise ValueError("moe_layer_freq names no routed layer")
         if not all(freq[:L]):
             out["ffn_kinds"] = tuple(int(f) for f in freq[:L])
-    shard = cfg.get("expert_shard")
+    elif first_dense:
+        raise ValueError(f"first_k_dense_replace {first_dense} without "
+                         f"moe_layer_freq: refusing to guess the layers "
+                         f"behind the dense ones")
     if shard is not None:
         # a chip's share of a deployment's experts: the published router
         # width, and where this chip's ``n_routed_experts`` begin
@@ -710,6 +814,81 @@ def _map_hybrid(cfg: Dict[str, Any]) -> Dict[str, Any]:
             "use_rope": pos == "rope",
             # rows narrower than a lane tile: whole tiles, tokens folded
             "kv_fold": max(1, 128 // Dh) if 128 % Dh == 0 else 1}
+
+
+# the keys of latent attention (DeepSeek-V2's MLA)
+_LATENT_KEYS = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                "qk_rope_head_dim")
+_YARN_KEYS = ("type", "rope_type", "factor", "beta_fast", "beta_slow",
+              "mscale", "mscale_all_dim", "original_max_position_embeddings")
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term (``yarn_get_mscale`` of the
+    published modeling file): 1 up to a factor of 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _map_latent(cfg: Dict[str, Any]) -> Dict[str, Any]:
+    """The latent-attention keys -> ours (``kv_lora_rank`` names the
+    mechanism; without it the other three must be absent too), YaRN's
+    softmax-scale correction with them: the scores are scaled by
+    ``(nope + rope)^-1/2 x mscale(factor, mscale_all_dim)^2`` and the
+    rotary tables by ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``, which has to be 1. Every layer is of ONE attention
+    kind, described layer by layer (``layer_kinds`` all 0) so that the
+    feed-forwards may differ (``ffn_kinds``)."""
+    have = [k for k in _LATENT_KEYS if cfg.get(k) is not None]
+    if cfg.get("kv_lora_rank") is None:
+        if have:
+            raise ValueError(f"config carries {have} without kv_lora_rank: "
+                             f"refusing to guess an attention")
+        return {}
+    missing = [k for k in _LATENT_KEYS if cfg.get(k) is None]
+    if missing:
+        raise ValueError(
+            f"latent attention (kv_lora_rank) without {missing}: a "
+            f"full-rank q (q_lora_rank null) is not implemented")
+    if "v_head_dim" not in cfg:
+        raise ValueError("latent attention (kv_lora_rank) without v_head_dim")
+    if cfg.get("num_key_value_heads",
+               cfg["num_attention_heads"]) != cfg["num_attention_heads"]:
+        raise ValueError(
+            f"latent attention expands K and V for every query head: "
+            f"num_key_value_heads {cfg['num_key_value_heads']} != "
+            f"num_attention_heads {cfg['num_attention_heads']}")
+    stray = [k for k in ("attention_bias", "partial_rotary_factor",
+                         "hybrid_layer_pattern", "layer_types", "sa_config",
+                         "attention_multiplier", "sliding_window")
+             if cfg.get(k)]
+    if stray:
+        raise ValueError(f"{stray} with latent attention is not implemented")
+    nope, rope = int(cfg["qk_nope_head_dim"]), int(cfg["qk_rope_head_dim"])
+    if rope <= 0 or rope % 2 or nope <= 0:
+        raise ValueError(f"qk_nope_head_dim {nope} / qk_rope_head_dim {rope}")
+    scale = 1.0 / math.sqrt(nope + rope)
+    rs = cfg.get("rope_scaling")
+    if rs:
+        kind = rs.get("rope_type", rs.get("type"))
+        unknown = sorted(set(rs) - set(_YARN_KEYS))
+        if kind != "yarn" or unknown:
+            raise ValueError(
+                f"rope_scaling {kind!r} {unknown or ''} with latent "
+                f"attention is not implemented (yarn: "
+                f"{', '.join(_YARN_KEYS[2:])})")
+        f = float(rs["factor"])
+        m, m_all = rs.get("mscale", 1), rs.get("mscale_all_dim", 0)
+        if not m_all or yarn_mscale(f, m) != yarn_mscale(f, m_all):
+            raise ValueError(
+                f"yarn with mscale {m!r} != mscale_all_dim {m_all!r} scales "
+                f"the rotary tables by their ratio: not implemented")
+        scale *= yarn_mscale(f, m_all) ** 2
+    L = cfg["num_hidden_layers"]
+    return {"q_lora_rank": int(cfg["q_lora_rank"]),
+            "kv_lora_rank": int(cfg["kv_lora_rank"]),
+            "qk_nope_dim": nope, "qk_rope_dim": rope, "rotary_dim": rope,
+            # (v_head_dim: _map_layer_kinds, as for every family)
+            "attn_multiplier": scale, "layer_kinds": (0,) * L}
 
 
 def _map_indexer(cfg: Dict[str, Any]) -> Dict[str, Any]:
@@ -1118,9 +1297,11 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     res = (1.0 / math.sqrt(2 * L) if cfg.residual_multiplier is None
            else 1.0)
     stacks: Dict[str, Any] = {}
+    if cfg.has_latent:
+        stacks["full"] = _init_latent(cfg, ks, mat, res)
     for name, window in (("full", False), ("window", True)):
         n, Hkv = len(cfg.kind_layers(window)), cfg.kv_heads_of(window)
-        if not n:
+        if not n or cfg.has_latent:
             continue
         qk = 1.0
         if cfg.attn_multiplier is not None:
@@ -1155,6 +1336,14 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         if cfg.router == "sigmoid_bias":
             st["rbias"] = 0.02 * jax.random.normal(next(ks), (nr, R),
                                                    jnp.float32)
+        if cfg.shared_experts:
+            # the expert every token passes through, by the law of the
+            # routed ones: its output is of the size of ONE routed expert's
+            # (and of the attention's), beside a routed sum of 6 gates of a
+            # few tenths x 16 each, of which this chip computes its share
+            Fs = cfg.shared_experts * Fe
+            st.update(ws_g=mat(nr, D, D, Fs), ws_u=mat(nr, D, D, Fs),
+                      ws_d=mat(nr, Fs, Fs, D, scale=res))
         stacks["routed"] = st
     if cfg.has_state:
         stacks["mamba"] = _init_mamba(cfg, ks, mat, res)
@@ -1165,6 +1354,45 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["lm_head"] = mat(D, D, V)
     return params
+
+
+def _init_latent(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
+    """The attention stack of a model with latent attention, by the law of
+    :func:`_init_per_kind` (every activation of unit rms): ``w_dq`` [D, Rq]
+    and, around the RMSNorm ``ln_dq``, the published ``W_uq`` [Rq, Hq x
+    (nope + rope)] held as its two column sets, each TRANSPOSED: ``w_uq``
+    [Hq x nope, Rq] and ``w_uqr`` [Hq x rope, Rq], the heads flat;
+    ``w_dkv`` [D, Rkv + rope] = [compressed vector | the ONE rotary key];
+    ``ln_kv`` the compressed vector's RMSNorm; the published fused
+    expansion [Rkv, Hq, nope + v] held as its two halves, each head-major as
+    the absorbed form multiplies by them, a batch of matrices a head:
+    ``w_uk`` [Hq, nope, Rkv] (q^ = q_nope w_uk) and ``w_uv`` [Hq, Rkv, v];
+    ``wo``. (Stored as the published [Rq, Hq, 192] and [Rkv, Hq, .], XLA
+    re-laid all three stacks whole at every decode dispatch's entry; as
+    stored here it still copies the two q matrices once a dispatch and the
+    step reads the same, PERF.md section 6, PR 42: the order is the one
+    every measurement of that PR was made with, not a gain.) A head's
+    score is a sum of nope + rope = 192 products of unit-rms terms under the
+    model's own scale (0.1147 with YaRN's correction): a spread of 1.6, as
+    the other families' inits set theirs.
+    The two inner norms' weights are U(0.5, 1.5), not 1: at unit-rms inputs
+    an RMSNorm of weight 1 is nearly the identity and a dropped one would
+    not show in the logits."""
+    n, D, Hq = cfg.num_layers, cfg.hidden_size, cfg.num_heads
+    Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_dim
+
+    def weight(width):
+        return jax.random.uniform(next(ks), (n, width), jnp.float32,
+                                  0.5, 1.5)
+
+    return {"ln1": jnp.ones((n, D), jnp.float32),
+            "w_dq": mat(n, D, D, Rq), "ln_dq": weight(Rq),
+            "w_uq": mat(n, Rq, Hq * Dn, Rq), "w_uqr": mat(n, Rq, Hq * Dr, Rq),
+            "w_dkv": mat(n, D, D, Rkv + Dr), "ln_kv": weight(Rkv),
+            "w_uk": mat(n, Rkv, Hq, Dn, Rkv),
+            "w_uv": mat(n, Rkv, Hq, Rkv, Dv),
+            "wo": mat(n, Hq * Dv, Hq, Dv, D, scale=res)}
 
 
 def _init_mamba(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
@@ -1300,6 +1528,12 @@ def validate_tp(cfg: LlamaConfig, tp: int, ep: int = 1) -> None:
             "a model with state-space layers runs on one chip: its stacks, "
             "its per-lane state pool and its K/V pool are not sharded "
             f"(got tp={tp}, ep={ep})")
+    if cfg.has_latent and (tp > 1 or ep > 1):
+        raise ValueError(
+            "a model with latent attention runs on one chip: every head "
+            "reads the ONE row a token its cache keeps, which a head-sharded "
+            "mesh would replicate, and its stacks are not sharded "
+            f"(got tp={tp}, ep={ep})")
     if cfg.per_kind and (tp > 1 or ep > 1):
         raise ValueError(
             "a model with window and full layers of their own head counts "
@@ -1322,6 +1556,8 @@ def validate_pp(cfg: LlamaConfig, pp: int, tp: int = 1) -> None:
     """Pipeline-parallel constraints for the staged serving path."""
     if pp <= 1:
         return
+    if cfg.has_latent:
+        raise ValueError(f"pp={pp}: {NO_LATENT}")
     if cfg.per_kind:
         raise ValueError(f"pp={pp}: "
                          f"{NO_STATE if cfg.has_state else NO_SECOND_CACHE}")
@@ -1478,6 +1714,23 @@ def _rope_inv_freq(cfg: LlamaConfig, local: bool = False) -> np.ndarray:
                 f"rope_freqs tensor has {factors.shape[0]} factors but "
                 f"head_dim {Dh} needs {inv.shape[0]}")
         inv = inv / factors
+    if rs.get("rope_type") == "yarn" or rs.get("type") == "yarn":
+        # YaRN: each frequency between extrapolation (as trained) and
+        # interpolation (/ factor), by where its wavelength lies in the
+        # correction range of beta_fast .. beta_slow turns over the
+        # original context (``_yarn_find_correction_range`` of the source)
+        factor = float(rs["factor"])
+        orig = rs.get("original_max_position_embeddings", 4096)
+
+        def correction(rot):
+            return (Dh * math.log(orig / (rot * 2 * math.pi))
+                    / (2 * math.log(cfg.rope_theta)))
+        low = max(math.floor(correction(rs.get("beta_fast", 32))), 0)
+        high = min(math.ceil(correction(rs.get("beta_slow", 1))), Dh - 1)
+        ramp = np.clip((np.arange(Dh // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        keep = 1.0 - ramp                 # 1: extrapolate, 0: interpolate
+        inv = inv / factor * (1.0 - keep) + inv * keep
     if rs.get("rope_type") == "llama3" or rs.get("type") == "llama3":
         # llama3 frequency-dependent NTK-style scaling
         factor = rs.get("factor", 8.0)
@@ -1576,17 +1829,20 @@ def _lm_head(x: jax.Array, params: Dict[str, Any],
     ``x`` [..., D] with leading dimensions as they come."""
     x = _normed(x, params["final_norm"], cfg)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    if cfg.logits_scaling is None:
+    if cfg.logits_scaling is None and cfg.stream_dtype == cfg.dtype:
         logits = jnp.einsum("...d,dv->...v", x, head.astype(x.dtype))
         logits = logits.astype(jnp.float32)
     else:
-        # float32 straight from the accumulator: a bfloat16 result rounds
-        # the LARGEST logits hardest (a spacing of 0.03 sigma at the top of
-        # a 100k vocabulary), which was most of this model's distance from
-        # its float32 reference (LlamaConfig.stream_dtype has the numbers)
+        # a model that keeps a float32 stream: float32 straight from the
+        # accumulator. A bfloat16 result rounds the LARGEST logits hardest
+        # (a spacing of 0.03 sigma at the top of a 100k vocabulary; 0.007
+        # sigma rms at the greedy token of 25,600), which was most of such
+        # a model's distance from its float32 reference
+        # (LlamaConfig.stream_dtype has the numbers)
         logits = jnp.einsum("...d,dv->...v", x, head.astype(x.dtype),
-                            preferred_element_type=jnp.float32
-                            ) / cfg.logits_scaling
+                            preferred_element_type=jnp.float32)
+        if cfg.logits_scaling is not None:
+            logits = logits / cfg.logits_scaling
     if cfg.final_logit_softcap:
         cap = cfg.final_logit_softcap
         logits = jnp.tanh(logits / cap) * cap
@@ -1809,6 +2065,10 @@ NO_STATE = ("this path carries K/V blocks alone: a model with state-space "
             "block holds, and a block re-entered or moved without the "
             "state at its boundary would decode from the wrong state")
 
+NO_LATENT = ("this path projects K and V a head and caches them: a model "
+             "with latent attention keeps one compressed row a token for "
+             "all heads and attends in a form of its own")
+
 NO_INDEX_KEYS = ("this cache carries no index-key pool: a model with an "
                  "indexer (learned top-k attention) writes its index keys "
                  "beside K/V and selects from them")
@@ -1847,7 +2107,11 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     kernel writes the new rows itself (:func:`kernel_writes`) passes a list,
     which receives the K and V rows [B*T, Hkv, D] as stored; the two pools
     then come back as they went in.
-    -> (q [B,T,Hq,Dh], pools, keep [B,T,S] or None)."""
+    -> (q [B,T,Hq,Dh], pools, keep [B,T,S] or None). A model with latent
+    attention: :func:`_latent_in`, whose q is a pair."""
+    if cfg.has_latent:
+        return _latent_in(x, lp, l, cfg, rope, pools, w_page, w_off, mode,
+                          hold)
     h = _normed(x, lp["ln1"][l], cfg)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
@@ -1892,13 +2156,75 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     return q, (k_pool, v_pool, *i_pool), keep
 
 
+# ---------------------------------------------------------------------------
+# Latent attention (DeepSeek-V2's MLA, ``cfg.has_latent``)
+#
+# For the layer's normed input h: q = W_uq RMSNorm(W_dq h), a head's q =
+# [q_nope | q_pe]; [c, k_pe] = W_dkv h; c~ = RMSNorm(c); rotary on q_pe and
+# on the ONE k_pe every head shares. The cache keeps c~ (``kv_lora_rank``
+# wide, in the V pool) and the rotated k_pe (in the K pool, zero-padded to a
+# lane tile) a token, and nothing per head. Published, a head's k = [W_uk c~
+# | k_pe] and v = W_uv c~ (the float32 reference expands them so). Served,
+# decode steps and prefill chunks alike run ABSORBED: q^ = W_uk^T q_nope
+# (``kv_lora_rank`` wide), score = (q^ . c~ + q_pe . k_pe) x scale, o = W_uv
+# (sum p c~): the kernels read the cached rows as they lie, all heads against
+# ONE row a key, and no K or V per head ever exists. (Expanding a chunk's
+# context to the published head widths for the flash kernel lost at every
+# context measured: PERF.md section 6, PR 42.)
+# ---------------------------------------------------------------------------
+
+def _latent_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
+               rope, pools, w_page, w_off, mode, hold):
+    """:func:`layer_in` of a model with latent attention. The new rows: the
+    rotated shared key [B*T, 1, rope -> a lane tile] into the K pool, the
+    normed compressed vector [B*T, 1, Rkv] into the V pool. -> (q, pools,
+    None) with q = (q_pe [B,T,Hq,K-pool row], q^ [B,T,Hq,Rkv])."""
+    Rkv = cfg.kv_lora_rank
+    h = _normed(x, lp["ln1"][l], cfg)
+    cq = rms_norm(jnp.einsum("btd,dr->btr", h, lp["w_dq"][l]),
+                  lp["ln_dq"][l], cfg.rms_eps)
+    q_nope, q_pe = (
+        jnp.einsum("btr,kr->btk", cq, lp[w][l]).reshape(
+            *cq.shape[:2], cfg.num_heads, -1) for w in ("w_uq", "w_uqr"))
+    ckv = jnp.einsum("btd,dr->btr", h, lp["w_dkv"][l])
+    c = rms_norm(ckv[..., :Rkv], lp["ln_kv"][l], cfg.rms_eps)
+    q_pe = apply_rope(q_pe, *rope)
+    k_pe = apply_rope(ckv[..., None, Rkv:], *rope)              # [B,T,1,rope]
+    k_pool, v_pool = pools
+    pad = ((0, 0),) * 3 + ((0, k_pool.shape[-1] - k_pe.shape[-1]),)
+    rows = [jnp.pad(k_pe, pad).reshape(-1, 1, k_pool.shape[-1]),
+            c.reshape(-1, 1, Rkv)]
+    if hold is None:
+        with scope("kv_write"):
+            k_pool, v_pool = (kv_write(p, l, w_page, w_off, r, mode)
+                              for p, r in zip((k_pool, v_pool), rows))
+    else:
+        hold.extend(rows)
+    q_lat = jnp.einsum("bthn,hnr->bthr", q_nope, lp["w_uk"][l])
+    return (jnp.pad(q_pe, pad), q_lat), (k_pool, v_pool), None
+
+
+def latent_attend(cfg: LlamaConfig, q, k_ctx: jax.Array, c_ctx: jax.Array,
+                  mask: jax.Array) -> jax.Array:
+    """The absorbed form over a gathered context, dense: q = (q_pe, q^) of
+    :func:`_latent_in`, ``k_ctx`` [B,S,1,K-pool row] the shared keys and
+    ``c_ctx`` [B,S,1,Rkv] the compressed vectors as the pools hold them,
+    ``mask`` [B,T,S]. -> [B,T,Hq,Rkv], float32 softmax."""
+    q_pe, q_lat = q
+    s = (jnp.einsum("bthk,bsk->bhts", q_pe, k_ctx[:, :, 0],
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bthr,bsr->bhts", q_lat, c_ctx[:, :, 0],
+                      preferred_element_type=jnp.float32)) * cfg.attn_scale
+    w = jax.nn.softmax(jnp.where(mask[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhts,bsr->bthr", w.astype(c_ctx.dtype), c_ctx[:, :, 0])
+
+
 @scope("attn_out")
 def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
               cfg: LlamaConfig, mesh=None,
               stats: Optional[Dict[str, Any]] = None,
               inside: Optional[Dict[str, int]] = None,
-              ffn: Optional[Tuple[Dict[str, Any], Any]] = None
-              ) -> jax.Array:
+              ffn: Optional[Tuple[Dict[str, Any], Any]] = None) -> jax.Array:
     """The layer after attention: out-projection of ``attn`` [B,T,Hq,Dh] and
     residual (Gemma2 norms the branch output first), then the feed-forward.
 
@@ -1908,7 +2234,11 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
     contractions over the sharded dimension leave partial sums, and they are
     reduced here. Without it the reductions are GSPMD's. ``ffn``: the
     feed-forward's (stack, index) where it is not (``lp``, ``l``)
-    (:func:`layer_stacks`)."""
+    (:func:`layer_stacks`). Latent attention hands over the heads' weighted
+    sums of compressed vectors [B,T,Hq,Rkv], and their values are expanded
+    here (``w_uv``)."""
+    if cfg.has_latent:
+        attn = jnp.einsum("bthr,hrv->bthv", attn, lp["w_uv"][l])
     o = jnp.einsum("bthk,hkd->btd", attn, lp["wo"][l])
     if inside and AXIS_TP in inside:
         o = jax.lax.psum(o, AXIS_TP)
@@ -1953,8 +2283,13 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         from .moe import moe_ffn
         law = {}
         if cfg.router != "softmax" or cfg.router_experts:
-            law = {"router": cfg.router, "first": cfg.expert_first,
+            law = {"router": cfg.router,
+                   "first": cfg.expert_first if cfg.router_experts else None,
                    "bias": lp["rbias"][l] if "rbias" in lp else None}
+        if cfg.router_groups:
+            law.update(groups=cfg.router_groups, scaling=cfg.routed_scaling)
+        if cfg.shared_experts:
+            law["shared"] = tuple(lp[k][l] for k in ("ws_g", "ws_u", "ws_d"))
         out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
                            cfg.experts_per_token, mesh=mesh, layer=l, **law)
         if stats is not None:
@@ -2240,6 +2575,11 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     from the state its lane holds (zeros under ``reset``) and leaves the
     state after its last REAL token there. Both pools come back last.
 
+    A model with latent attention (``cfg.has_latent``) keeps the rotated
+    shared key in ``k_pool`` ([L, 1, pages, page, a lane tile]) and the
+    compressed vector in ``v_pool`` ([L, 1, pages, page, kv_lora_rank]),
+    addressed as above.
+
     Multimodal (Gemma3 VLM, xla attention only):
 
     - ``embed_override`` = (vals [B,T,D], mask [B,T] bool) replaces the
@@ -2398,7 +2738,15 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
         with _attn_scope(cfg, False):
             if "sink" in lp:
                 extra["sink"] = lp["sink"][la]
-            if attn_impl == "flash":
+            if cfg.has_latent:
+                # all heads against the lane's cached rows as they lie
+                if attn_impl == "ring":
+                    raise ValueError("ring attention takes no latent cache")
+                attn = (flash_for(l)(q[0], k_ctx, v_ctx, positions, read_pos,
+                                     read_valid, latent=q[1])
+                        if attn_impl == "flash"
+                        else latent_attend(cfg, q, k_ctx, v_ctx, mask))
+            elif attn_impl == "flash":
                 attn = flash_for(l)(q, k_ctx, v_ctx, positions, read_pos,
                                     read_valid, **extra)
             elif attn_impl == "ring":
@@ -2485,6 +2833,8 @@ def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
         raise ValueError(f"forward_pp: {NO_INDEX_KEYS}")
     if cfg.has_state:
         raise ValueError(f"forward_pp: {NO_STATE}")
+    if cfg.has_latent:
+        raise ValueError(f"forward_pp: {NO_LATENT}")
     if cfg.per_kind:
         raise ValueError(f"forward_pp: {NO_SECOND_CACHE}")
     if pp == 1:
@@ -2873,9 +3223,11 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
             extra = {"sink": lp["sink"][la]} if "sink" in lp else {}
             if attn_impl == "pallas":
                 # the kernel reads the whole pool in place, by layer index
-                q0 = q[:, 0]
+                q0 = None if cfg.has_latent else q[:, 0]
                 if keep is not None:
                     extra["keep"] = keep[:, 0]
+                if cfg.has_latent:
+                    q0, extra["latent"] = q[0][:, 0], q[1][:, 0]
                 attn = paged_for(l)(
                     q0, kv[0], kv[1], tables, lengths,
                     jnp.int32(la), **extra,
@@ -2889,8 +3241,10 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                 v_ctx = kv_pages(kv[1], la, tables, fold)
                 if keep is not None:
                     extra["keep"] = keep
-                attn = attend_ctx(cfg, q, k_ctx, v_ctx,
-                                  pick(sl, sliding_mask, mask), **extra)
+                attn = (latent_attend(cfg, q, k_ctx, v_ctx, mask)
+                        if cfg.has_latent else attend_ctx(
+                            cfg, q, k_ctx, v_ctx,
+                            pick(sl, sliding_mask, mask), **extra))
         if in_win:
             w_pools = kv
         else:

@@ -23,7 +23,7 @@ engine owns it, so this module IS the capability.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +62,15 @@ def sorted_wins(rows: int, top_k: int, n_experts: int,
     experts hit), 0.82 at 17 (7 hit), 1.48 at 31, 2.2 at 131: sorted pays
     0.1-0.15 ms an expert hit, so it wins while clearly fewer experts are
     hit than held, which an even router ends at as many assignments as
-    experts (16 of them hit 10 of 16)."""
+    experts (16 of them hit 10 of 16). And for 40 held of 160, 5120 x 1536,
+    6 a token, group-limited, beside a shared expert (my chip run, PR 42; ms
+    a layer dense / sorted): 12 rows (21
+    assignments, 18 experts hit) 2.67 / 1.36; 16 rows (27, 21) 2.68 / 1.61;
+    32 rows (50, 28) 2.68 / 2.13; 64 rows (96, 36) 2.69 / 3.16; 256 rows
+    (387, all 40) 2.92 / 6.92: sorted pays 0.07 ms an expert hit, the rule
+    (sorted under 40 expected assignments: 26 rows) is right at every row
+    count that cell's programs have but the 32-row chunk bucket, where it
+    says dense and sorted is a fifth faster."""
     if n_experts >= 16 * top_k * share and rows <= 512:
         return rows * top_k * share < n_experts
     return rows >= 16
@@ -132,14 +140,20 @@ def _sorted_dispatch(x: jax.Array,            # [B, T, D]
 
 
 def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
-               router: str = "softmax", bias: Optional[jax.Array] = None):
-    """Router: renormalized top-k gate values + expert ids ([B,T,K] each).
+               router: str = "softmax", bias: Optional[jax.Array] = None,
+               groups: Optional[Tuple[int, int]] = None,
+               scaling: float = 1.0):
+    """Router: top-k gate values + expert ids ([B,T,K] each).
     Shared by every dispatch formulation (incl. forward_pp's in-stage MoE)
-    so the gating policy has exactly one implementation. Two laws:
-    ``softmax`` (top-k of the softmax over all experts) and ``sigmoid_bias``
-    (sigmoid scores; the k largest of score + ``bias`` [E], the learned
-    selection bias, are chosen; the gates are the chosen SCORES over their
-    sum: the bias chooses and never weighs)."""
+    so the gating policy has exactly one implementation. Three laws:
+    ``softmax`` (top-k of the softmax over all experts, renormalised),
+    ``sigmoid_bias`` (sigmoid scores; the k largest of score + ``bias`` [E],
+    the learned selection bias, are chosen; the gates are the chosen SCORES
+    over their sum: the bias chooses and never weighs) and ``softmax_group``
+    (softmax scores; the experts lie in ``groups[0]`` equal groups, a group
+    scores as its best expert, the ``groups[1]`` best groups stay and the
+    top-k is taken among their experts; the gates are the chosen scores x
+    ``scaling`` and are NOT renormalised)."""
     # float32 logits, not only a float32 softmax: bfloat16 resolves a
     # router logit of 32-64 to 0.25, i.e. a gate ratio to 25 %
     logits = jnp.einsum("btd,de->bte", x, wr.astype(x.dtype),
@@ -149,6 +163,16 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
         _, idx = jax.lax.top_k(scores if bias is None else scores + bias,
                                top_k)
         vals = jnp.take_along_axis(scores, idx, axis=-1)
+    elif router == "softmax_group":
+        probs = jax.nn.softmax(logits, axis=-1)
+        G, Gk = groups
+        best = probs.reshape(*probs.shape[:-1], G, -1).max(axis=-1)
+        _, gi = jax.lax.top_k(best, Gk)                   # [B,T,Gk]
+        stay = jnp.sum(jax.nn.one_hot(gi, G, dtype=jnp.int32), axis=-2) > 0
+        stay = jnp.repeat(stay, probs.shape[-1] // G, axis=-1)
+        # (a softmax score is positive: 0 never beats an expert that stays)
+        vals, idx = jax.lax.top_k(jnp.where(stay, probs, 0.0), top_k)
+        return vals * scaling, idx
     elif router == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
         vals, idx = jax.lax.top_k(probs, top_k)           # [B,T,K]
@@ -185,7 +209,10 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
             layer: Optional[int] = None,
             router: str = "softmax",
             bias: Optional[jax.Array] = None,
-            first: Optional[int] = None):
+            first: Optional[int] = None,
+            groups: Optional[Tuple[int, int]] = None,
+            scaling: float = 1.0,
+            shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None):
     """Routed MoE feed-forward (expert width F is the weights' own: a model
     whose experts are not ``intermediate_size`` wide needs nothing here).
     With ``layer``, ``wg`` / ``wu`` / ``wd`` are the stacked [L, E, ...]
@@ -202,23 +229,34 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
     result is the held experts' part of the layer's output: what the
     absent ones would add is left out, not stood in for. The second result
     is then (experts hit among the HELD, assignments to held experts), and
-    the chosen ids stay the router's own."""
+    the chosen ids stay the router's own.
+
+    ``shared`` = (wg [D, Fs], wu [D, Fs], wd [Fs, D]): an expert EVERY token
+    passes through, added to the routed sum whole (under a chip's share
+    too: every chip of the deployment computes it alike, and it counts once
+    when the shares are added up)."""
     with jax.named_scope("dynamo.moe_ffn"):
-        vals, idx = route_topk(x, wr, top_k, router, bias)
+        vals, idx = route_topk(x, wr, top_k, router, bias, groups, scaling)
         if first is not None:
             E = wg.shape[-3]
             held = (idx >= first) & (idx < first + E)
             n_held = jnp.sum(held.astype(jnp.int32))
             local = jnp.where(held, idx - first, E)
-            hit = jnp.sum(jnp.zeros((E + 1,), jnp.int32)
-                          .at[local.reshape(-1)].max(1)[:E])
+            hit = (jnp.sum(jnp.zeros((E + 1,), jnp.int32)
+                           .at[local.reshape(-1)].max(1)[:E]), n_held)
             # rows: the assignments this call may compute (sorted_wins)
             out = _dispatch(x, wg, wu, wd, jnp.where(held, vals, 0.0), local,
                             mesh, layer, share=E / wr.shape[1])
-            return out, (hit, n_held), idx
-        hit = jnp.sum(jnp.zeros((wr.shape[1],), jnp.int32)
-                      .at[idx.reshape(-1)].max(1))
-        return _dispatch(x, wg, wu, wd, vals, idx, mesh, layer), hit, idx
+        else:
+            hit = jnp.sum(jnp.zeros((wr.shape[1],), jnp.int32)
+                          .at[idx.reshape(-1)].max(1))
+            out = _dispatch(x, wg, wu, wd, vals, idx, mesh, layer)
+        if shared is not None:
+            sg, su, sd = shared
+            a = jax.nn.silu(jnp.einsum("btd,df->btf", x, sg)) * jnp.einsum(
+                "btd,df->btf", x, su)
+            out = out + jnp.einsum("btf,fd->btd", a, sd)
+        return out, hit, idx
 
 
 def moe_ffn_in_stage(x: jax.Array, wr: jax.Array, wg: jax.Array,

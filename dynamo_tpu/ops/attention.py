@@ -165,12 +165,14 @@ def topk_keep(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
 def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
                   scale: float, G: int, softcap: Optional[float],
                   window: Optional[int], selected: bool,
-                  sunk: bool = False):
+                  sunk: bool = False, latent: bool = False):
     # a model with an indexer adds ONE operand, the keep mask of its
-    # selection, and a layer with a sink one, the heads' sink logits; every
-    # other model's kernel is what it always was
+    # selection, a layer with a sink one, the heads' sink logits, and latent
+    # attention one, the queries' second part, which meets the VALUE rows;
+    # every other model's kernel is what it always was
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
+    lat_ref, rest = (rest[0], rest[1:]) if latent else (None, rest)
     o_ref, m_scr, l_scr, acc_scr = rest
     j = pl.program_id(2)
 
@@ -199,7 +201,12 @@ def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
         v = jnp.broadcast_to(v_ref[0][None], (G, BS, v_ref.shape[-1]))
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale    # [G, BT, BS]
+            preferred_element_type=jnp.float32)            # [G, BT, BS]
+        if lat_ref is not None:
+            s = s + jax.lax.dot_general(
+                lat_ref[0], v, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+        s = s * scale
         if softcap is not None:
             # Gemma2 attention-score softcapping, BEFORE masking (tanh of
             # the NEG_INF sentinel would turn masked slots into finite ±cap)
@@ -246,7 +253,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     softcap: Optional[float] = None,
                     window: Optional[int] = None,
                     keep: Optional[jax.Array] = None,
-                    sink: Optional[jax.Array] = None) -> jax.Array:
+                    sink: Optional[jax.Array] = None,
+                    latent: Optional[jax.Array] = None) -> jax.Array:
     """Blockwise attention with explicit positions.
 
     q: [B, T, Hq, Dh] ; k: [B, S, Hkv, Dh] ; v: [B, S, Hkv, Dv] (gathered
@@ -260,14 +268,39 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     restricts each query to its selected keys, every head alike.
     ``sink`` [Hq] float32: a logit a head that takes softmax weight and
     gives no value. Returns [B, T, Hq, Dv] in q.dtype.
+
+    ``latent`` [B, T, Hq, Dv] (the absorbed form of latent attention: ``k``
+    [B, S, 1, Dh] the one shared rotary key, ``v`` [B, S, 1, Dv] the
+    compressed vectors): a score is ``q . k + latent . v`` and the result
+    the weighted sum of ``v``. Every (token, head) is then a query ROW of
+    its own against the one K/V head, a token's heads side by side in one
+    block, and the key blocks are wider (512): such a block's work is two
+    [128, Dv] x [Dv, BS] products, which a 128-key block does not amortise.
     """
+    B, T, Hq, Dh = q.shape
+    S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
+    if latent is not None:
+        if Hkv != 1 or keep is not None or sink is not None:
+            raise ValueError("latent attention: one K/V head, no selection, "
+                             "no sink")
+        rows = lambda a: a.reshape(B, T * Hq, 1, a.shape[-1])
+        out = _flash_call(rows(q), k, v, jnp.repeat(q_pos, Hq, axis=1),
+                          k_pos, k_valid, interpret, scale, softcap, window,
+                          None, None, rows(latent))
+        return out.reshape(B, T, Hq, Dv)
+    return _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale,
+                       softcap, window, keep, sink, None)
+
+
+def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
+                window, keep, sink, latent):
     B, T, Hq, Dh = q.shape
     S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = Hq // Hkv
     if interpret is None:
         interpret = not on_tpu()
     BT = _pick_block(T, 8)
-    BS = _pick_block(S, 128)
+    BS = _pick_block(S, 128, **({} if latent is None else {"cap": 512}))
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
 
@@ -295,11 +328,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         sel_specs.append(pl.BlockSpec((1, G, 1),
                                       lambda bh, i, j: (bh % Hkv, 0, 0)))
         sel_args.append(sink.astype(jnp.float32).reshape(Hkv, G, 1))
+    if latent is not None:
+        sel_specs.append(pl.BlockSpec((1, G, BT, Dv),
+                                      lambda bh, i, j: (bh, 0, i, 0)))
+        sel_args.append(latent.reshape(B, T, Hkv, G, Dv).transpose(
+            0, 2, 3, 1, 4).reshape(B * Hkv, G, T, Dv))
     grid = (B * Hkv, T // BT, S // BS)
     out = _pallas_call(
         functools.partial(_flash_kernel, scale=scale, G=G,
                           softcap=softcap, window=window, selected=selected,
-                          **({} if sink is None else {"sunk": True})),
+                          **({} if sink is None else {"sunk": True}),
+                          **({} if latent is None else {"latent": True})),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, BT, 1), lambda bh, i, j: (bh // Hkv, i, 0)),
@@ -360,7 +399,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                       fold: int, dh: int, softcap: Optional[float],
                       window: Optional[int], selected: bool,
                       dv: Optional[int] = None, sunk: bool = False,
-                      writes: bool = False):
+                      writes: bool = False, latent: bool = False):
     """Pools are the WHOLE stored pool, [L, Hkv, n_pages, page//fold,
     fold*Dh], left in HBM; ``layer_ref[0]`` picks the layer inside the copy
     descriptor, so no per-layer slice of the pool is ever materialised and
@@ -414,6 +453,9 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     waited for before the overlay."""
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
+    # latent attention (fold 1): the queries' second part [1, Hkv, G, Dv],
+    # which meets the VALUE rows: a score is q . k + latent . v
+    lat_ref, rest = (rest[0], rest[1:]) if latent else (None, rest)
     if writes:
         (kn_ref, vn_ref, o_ref, k_out, v_out, k_buf, v_buf, sem, m_scr, l_scr,
          acc_scr, state, kw_buf, vw_buf, wsem) = rest
@@ -585,7 +627,12 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             kslice = kf[:, :, f * dh:(f + 1) * dh]          # [Hkv, rows, Dh]
             s = jax.lax.dot_general(
                 q, kslice, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32) * scale  # [Hkv, G, rows]
+                preferred_element_type=jnp.float32)          # [Hkv, G, rows]
+            if lat_ref is not None:
+                s = s + jax.lax.dot_general(
+                    lat_ref[0], vf, (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)
+            s = s * scale
             if softcap is not None:
                 # cap BEFORE masking (tanh(NEG_INF) would be a finite ±cap)
                 s = jnp.tanh(s / softcap) * softcap
@@ -657,7 +704,8 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
                          sink: Optional[jax.Array] = None,
                          interpret: bool = False,
                          stored_fold: int = 1,
-                         new: Optional[Tuple[jax.Array, jax.Array]] = None):
+                         new: Optional[Tuple[jax.Array, jax.Array]] = None,
+                         latent: Optional[jax.Array] = None):
     """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh] (V: Dv), or
     STORED folded ([.., page // f, f * Dh], ``stored_fold`` f = 128 // Dh:
     the order the copies take, read in place); layer: [1]
@@ -723,6 +771,12 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
         own["sunk"] = True
     if Dv != Dh:
         own["dv"] = Dv
+    if latent is not None:
+        if fold > 1:
+            raise ValueError("latent attention reads unfolded rows")
+        sel_specs.append(pl.BlockSpec((1, Hkv, G, Dv), _lane_block))
+        sel_args.append(latent)
+        own["latent"] = True
     out_specs = pl.BlockSpec((1, Hkv, G, Dv), _lane_block)
     out_shape = jax.ShapeDtypeStruct((B, Hkv, G, Dv), q4.dtype)
     scratch, aliases = [], {}
@@ -799,9 +853,10 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
 def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
                   scale: float, page: int, softcap: Optional[float],
                   window: Optional[int], selected: bool,
-                  sunk: bool = False):
+                  sunk: bool = False, latent: bool = False):
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
+    lat_ref, rest = (rest[0], rest[1:]) if latent else (None, rest)
     o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     p = pl.program_id(1)
@@ -829,7 +884,12 @@ def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
         v = v_ref[0, :, 0]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale    # [Hkv, G, page]
+            preferred_element_type=jnp.float32)            # [Hkv, G, page]
+        if lat_ref is not None:
+            s = s + jax.lax.dot_general(
+                lat_ref[0], v, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+        s = s * scale
         if softcap is not None:
             # cap BEFORE masking (tanh(NEG_INF) would be a finite ±cap)
             s = jnp.tanh(s / softcap) * softcap
@@ -935,7 +995,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     keep: Optional[jax.Array] = None,
                     sink: Optional[jax.Array] = None,
                     fold: int = 1,
-                    new: Optional[Tuple[jax.Array, jax.Array]] = None):
+                    new: Optional[Tuple[jax.Array, jax.Array]] = None,
+                    latent: Optional[jax.Array] = None):
     """Decode attention straight over the paged KV pool.
 
     q: [B, Hq, Dh] (one new token per sequence, already rope'd)
@@ -971,7 +1032,12 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     NOT yet in the pools: the dma kernel puts them there and attends over
     them (a lane of length 0 writes nothing), and the result is (out,
     k_pool, v_pool) with the pools updated in place where the caller donates
-    them. Only where :func:`paged_kernel_writes` says so.
+    them. Only where :func:`paged_kernel_writes` says so. ``latent`` [B, Hq,
+    Dv] (the absorbed form of latent attention; one K/V head, ``k_pool`` the
+    shared rotary keys, ``v_pool`` the compressed vectors): a score is ``q .
+    k + latent . v`` and the result [B, Hq, Dv] the weighted sum of the
+    compressed vectors: all heads against ONE row a key, each page copied
+    once.
 
     On a TPU this runs the multi-page double-buffered DMA kernel above
     (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
@@ -1035,7 +1101,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                                    **({} if sink is None else {"sink": sink}),
                                    **({} if fold == 1
                                       else {"stored_fold": fold}),
-                                   **({} if new is None else {"new": new}))
+                                   **({} if new is None else {"new": new}),
+                                   **({} if latent is None else {
+                                       "latent": latent.reshape(
+                                           B, Hkv, G, Dv)}))
         if new is None:
             return out.reshape(B, Hq, Dv)
         out, k_pool, v_pool = out
@@ -1067,6 +1136,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
               if selected else []),
             *([pl.BlockSpec((Hkv, G, 1), lambda b, p, *_: (0, 0, 0))]
               if sink is not None else []),
+            *([pl.BlockSpec((1, Hkv, G, Dv), _lane_block)]
+              if latent is not None else []),
         ],
         out_specs=pl.BlockSpec((1, Hkv, G, Dv), _lane_block),
         scratch_shapes=[
@@ -1078,12 +1149,14 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     out = _pallas_call(
         functools.partial(_paged_kernel, scale=scale, page=page,
                           softcap=softcap, window=window, selected=selected,
-                          **({} if sink is None else {"sunk": True})),
+                          **({} if sink is None else {"sunk": True}),
+                          **({} if latent is None else {"latent": True})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
         interpret=interpret,
     )(page_tables, lengths, layer, q4, k_pool, v_pool,
       *([keep.astype(jnp.int32)[:, None, :]] if selected else []),
       *([sink.astype(jnp.float32).reshape(Hkv, G, 1)]
-        if sink is not None else []))
+        if sink is not None else []),
+      *([latent.reshape(B, Hkv, G, Dv)] if latent is not None else []))
     return out.reshape(B, Hq, Dv)

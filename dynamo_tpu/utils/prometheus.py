@@ -612,6 +612,18 @@ class StageMetrics:
             "it copied before it told a block's pages apart; live / "
             "visited is the share of a block's copies that is left",
             ("kind",))
+        # latent attention (one compressed row a token for all heads): one
+        # layer's worth, as the indexer's counters above
+        self.attn_latent_keys = r.counter(
+            "dyn_attn_latent_keys_total",
+            "Latent rows a dispatch's attention had to read: a decode "
+            "query its lane's visible rows, each step; a chunk's queries "
+            "share their lane's rows, read once (one layer's worth)",
+            ("kind",))
+        self.attn_latent_pairs = r.counter(
+            "dyn_attn_latent_pairs_total",
+            "(query, visible key) pairs of a latent-attention model's "
+            "dispatches (one layer's worth)", ("kind",))
         self.profile_captured_work = r.counter(
             "dyn_profile_captured_work_total",
             "The counters above (by name), and dispatches and tokens, "

@@ -208,6 +208,8 @@ def model_costs(m: Any, weight_bytes: Optional[float] = None) -> ModelCosts:
     esize = dtype_size(m.dtype)
     if getattr(m, "has_state", False):
         return _state_costs(m, weight_bytes, esize)
+    if getattr(m, "has_latent", False):
+        return _latent_costs(m, weight_bytes, esize)
     if getattr(m, "per_kind", False):
         return _per_kind_costs(m, weight_bytes, esize)
     Dv = getattr(m, "v_dim", Dh)
@@ -285,6 +287,48 @@ def _per_kind_costs(m: Any, weight_bytes: Optional[float],
         kv_bytes_per_tok_layer=kv[1], num_layers=m.num_layers,
         window_groups=tuple(groups), weight_bytes=float(weight_bytes),
         group_kv_bytes=tuple(kv))
+
+
+def _latent_costs(m: Any, weight_bytes: Optional[float],
+                  esize: int) -> ModelCosts:
+    """:class:`ModelCosts` of a model with latent attention, priced as it is
+    served: the low-rank projections (q through ``q_lora_rank``, the
+    compressed vector and the shared rotary key, both halves of the
+    expansion: ``w_uk`` absorbs q, ``w_uv`` expands the heads' sums, each a
+    query token's work) and ``wo``; a (query, key) pair in the absorbed
+    form, 2 x Hq x (Rkv + rope + Rkv); the ONE row a token a layer the cache
+    holds; of the feed-forward the dense layers, and for a routed layer the
+    router, the shared expert (whole) and the chip's share of a token's
+    assignments."""
+    from ..engine.cache import cache_kinds
+
+    D, V, Hq = m.hidden_size, m.vocab_size, m.num_heads
+    Rq, Rkv, Dn, Dr, Dv = (m.q_lora_rank, m.kv_lora_rank, m.qk_nope_dim,
+                           m.qk_rope_dim, m.v_dim)
+    kind, = cache_kinds(m)
+    Fe = m.expert_width
+    R = m.router_experts or m.num_experts
+    share = m.num_experts / R if R else 0.0
+    proj = (D * Rq + Rq * Hq * (Dn + Dr) + D * (Rkv + Dr)
+            + Rkv * Hq * (Dn + Dv) + Hq * Dv * D)
+    mat = n_params = float(m.num_layers * proj)
+    for l in range(m.num_layers):
+        if m.layer_routed(l):
+            every = D * R + 3 * D * m.shared_experts * Fe
+            mat += m.experts_per_token * share * 3 * D * Fe + every
+            n_params += m.num_experts * 3 * D * Fe + every
+        else:
+            mat += 3 * D * m.intermediate_size
+            n_params += 3 * D * m.intermediate_size
+    if weight_bytes is None:
+        n_params += V * D * (1 if m.tie_embeddings else 2)
+        weight_bytes = n_params * esize
+    return ModelCosts(
+        mat_flops_per_token=2.0 * mat, lm_head_flops=2.0 * D * V,
+        attn_flops_coef=2.0 * Hq * (2 * Rkv + Dr),
+        kv_bytes_per_tok_layer=float(kind.token_bytes(esize) // kind.layers),
+        num_layers=m.num_layers, window_groups=((None, m.num_layers),),
+        weight_bytes=float(weight_bytes))
 
 
 def _state_costs(m: Any, weight_bytes: Optional[float],

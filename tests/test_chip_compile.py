@@ -159,6 +159,42 @@ def test_the_kernel_that_copies_live_pages_compiles_at_the_cells_shapes(
     assert f"(bf16[{B},{Hkv},{Hq // Hkv},{Dv}]" in txt
 
 
+def test_the_latent_decode_kernel_compiles_at_the_cells_shapes(v5e):
+    """deepseek-v2-5l's decode kernel as its program calls it: 16 lanes, all
+    128 heads against ONE row a key (the shared rotary key's pool, 128 lanes
+    as stored, and the compressed vectors' pool, 512 wide), the queries'
+    second part as one more operand, the two new rows written by the
+    kernel, 258 pages a lane."""
+    B, Hq, Dk, Dv, P, L, n_pages = 16, 128, 128, 512, 258, 5, 40
+    bf, i32 = jnp.bfloat16, jnp.int32
+    txt = _compiled_text(
+        lambda q, ql, k, v, pt, ln, ly, kn, vn: A.paged_attention(
+            q, k, v, pt, ln, ly, interpret=False, scale=0.1147, latent=ql,
+            new=(kn, vn)),
+        _sds(v5e, (B, Hq, Dk), bf), _sds(v5e, (B, Hq, Dv), bf),
+        _sds(v5e, (L, 1, n_pages, PAGE, Dk), bf),
+        _sds(v5e, (L, 1, n_pages, PAGE, Dv), bf),
+        _sds(v5e, (B, P), i32), _sds(v5e, (B,), i32), _sds(v5e, (), i32),
+        _sds(v5e, (B, 1, Dk), bf), _sds(v5e, (B, 1, Dv), bf))
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"(bf16[{B},1,{Hq},{Dv}]" in txt
+
+
+@pytest.mark.parametrize("T,S", [(32, 256), (256, 4096), (256, 16512)])
+def test_the_latent_flash_form_compiles_at_the_cells_shapes(v5e, T, S):
+    """A chunk's absorbed form: every (token, head) a query row against the
+    one shared row a key, key blocks of up to 512."""
+    bf, i32 = jnp.bfloat16, jnp.int32
+    txt = _compiled_text(
+        lambda q, ql, k, v, qp, kp, kv: A.flash_attention(
+            q, k, v, qp, kp, kv, interpret=False, scale=0.1147, latent=ql),
+        _sds(v5e, (1, T, 128, 128), bf), _sds(v5e, (1, T, 128, 512), bf),
+        _sds(v5e, (1, S, 1, 128), bf), _sds(v5e, (1, S, 1, 512), bf),
+        _sds(v5e, (1, T), i32), _sds(v5e, (1, S), i32),
+        _sds(v5e, (1, S), jnp.bool_))
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+
+
 def test_decode_step_compiles_with_kernels_for_v5e(v5e):
     """One whole decode step at llama-3.2-1b widths (depth cut to two layers
     to stay within seconds), placed on the described device through a mesh:
