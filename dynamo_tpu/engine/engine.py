@@ -40,7 +40,9 @@ from ..llm.model_card import ModelDeploymentCard
 from ..llm.protocols.common import BackendInput, EngineOutput, FinishReason
 from ..models import llama
 from ..obs import flightrec as _flightrec
-from ..ops.attention import (flash_attention, paged_attention,
+from ..ops.attention import (flash_attention, latent_flash_blocks,
+                             latent_flash_copies, latent_flash_fetch,
+                             paged_attention,
                              paged_kernel_variant, paged_live_pages,
                              paged_pages_per_block)
 from ..parallel.mesh import AXIS_TP, serving_mesh
@@ -1022,9 +1024,24 @@ class EngineCore:
         if n:
             self.stage.kv_window_pages_released.inc(amount=float(n))
 
+    @staticmethod
+    def _latent_key_blocks(q_pos: np.ndarray, k_pos: np.ndarray,
+                           k_valid: np.ndarray, heads: int) -> Tuple[int, int]:
+        """(key blocks of the grid, those copied) of ONE latent flash call
+        of a chunk program, from the positions and validity the dispatch
+        hands it ([Bp, C], [Bp, S], [Bp, S]) by the call's own block shape
+        and table (``ops.attention.latent_flash_blocks`` /
+        ``latent_flash_fetch``)."""
+        return latent_flash_copies(latent_flash_fetch(
+            q_pos, k_pos, k_valid,
+            *latent_flash_blocks(q_pos.shape[1], k_pos.shape[1], heads),
+            xp=np))
+
     def _count_model_work(self, kind: str, spans, hit,
                           captured: bool = False, S: int = 0,
-                          held: Optional[float] = None) -> None:
+                          held: Optional[float] = None,
+                          key_blocks: Optional[Tuple[int, int]] = None
+                          ) -> None:
         """Host counters of what a dispatch made the experts and the
         indexer do. ``spans``: (first position, queries) per lane; a query
         at position p sees p + 1 keys. ``hit``: experts hit, read from the
@@ -1040,7 +1057,9 @@ class EngineCore:
         (a chip's share of the experts): the part of the real tokens'
         assignments that went to experts held here, which is then what
         ``dyn_moe_assignments_total`` counts (computed here), while
-        ``dyn_moe_routed_assignments_total`` counts all of them."""
+        ``dyn_moe_routed_assignments_total`` counts all of them.
+        ``key_blocks``: :meth:`_latent_key_blocks` of a chunk dispatch whose
+        attention is the latent flash call."""
         m = self.cfg.model
         if not (m.num_experts or m.has_indexer):
             return
@@ -1075,12 +1094,19 @@ class EngineCore:
             work[self.stage.attn_latent_keys] = float(
                 sum(p0 + n for p0, n in spans) if kind == "prefill"
                 else sum(pairs))
+        blocks = dict(zip(("bucket", "copied"), key_blocks or ()))
         for counter, amount in work.items():
             counter.inc(kind, amount=amount)
+        for state, amount in blocks.items():
+            self.stage.attn_latent_key_blocks.inc(kind, state,
+                                                  amount=float(amount))
         if captured:
             seen_by = self.stage.profile_captured_work
             for counter, amount in work.items():
                 seen_by.inc(counter.name, kind, amount=amount)
+            for state, amount in blocks.items():
+                seen_by.inc(f"latent_key_blocks_{state}", kind,
+                            amount=float(amount))
             seen_by.inc("dispatches", kind)
             seen_by.inc("tokens", kind, amount=float(tokens))
             if m.has_indexer and S > m.index_topk:
@@ -2565,6 +2591,12 @@ class EngineCore:
         for _, slot, start, count, _ in work:
             slot.chunks += 1
             slot.prefill_done = start + count
+        key_blocks = None
+        if cfg.model.has_latent and self.attn_impl == "pallas":
+            # the latent flash call's grid and what it copies of it
+            key_blocks = self._latent_key_blocks(
+                positions, read_pos, read_valid,
+                cfg.model.num_heads // max(1, cfg.tp))
         t_disp = time.perf_counter()
         captured = self.capturing
         packed = self._run_prefill_program(
@@ -2587,6 +2619,7 @@ class EngineCore:
                                "compiled": self._take_compiled_flag(),
                                "captured": captured, "S": S,
                                "rows": Bp * C,
+                               "key_blocks": key_blocks,
                                "dispatched_at": t_disp})
         return len(last_lanes)
 
@@ -2624,7 +2657,8 @@ class EngineCore:
             rec["captured"], rec["S"],
             held=(packed_np[0, 2 + cols.index("held")]
                   * sum(n for _, n in spans) / rec["rows"]
-                  if "held" in cols else None))
+                  if "held" in cols else None),
+            key_blocks=rec["key_blocks"])
         if self.win is not None:
             for _, slot, start, _, _ in work:
                 self._window_fetched(slot.seq_id, start)

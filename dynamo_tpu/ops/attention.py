@@ -165,7 +165,7 @@ def topk_keep(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
 def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
                   scale: float, G: int, softcap: Optional[float],
                   window: Optional[int], selected: bool,
-                  sunk: bool = False, latent: bool = False):
+                  sunk: bool = False, latent: bool = False, fetched=None):
     # a model with an indexer adds ONE operand, the keep mask of its
     # selection, a layer with a sink one, the heads' sink logits, and latent
     # attention one, the queries' second part, which meets the VALUE rows;
@@ -182,19 +182,28 @@ def _flash_kernel(qpos_ref, kpos_ref, kval_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    qp = qpos_ref[0]                                       # [BT, 1]
-    kp = kpos_ref[0]                                       # [1, BS]
-    kv = kval_ref[0]
-    # dead-block skip: a key block entirely in the causal future — or, on
-    # sliding layers, entirely below every query's window — contributes
-    # nothing; skip its matmuls (positions are dynamic, so this is a
-    # run-time guard; the BlockSpec copies still happen)
-    live = jnp.min(kp) <= jnp.max(qp)
-    if window is not None:
-        live = live & (jnp.max(kp) > jnp.min(qp) - window)
+    def positions():
+        return qpos_ref[0], kpos_ref[0], kval_ref[0]   # [BT,1] [1,BS] [1,BS]
+
+    if fetched is None:
+        qp, kp, kv = at = positions()
+        # dead-block skip: a key block entirely in the causal future — or,
+        # on sliding layers, entirely below every query's window —
+        # contributes nothing; skip its matmuls (positions are dynamic, so
+        # this is a run-time guard; the BlockSpec copies still happen)
+        live = jnp.min(kp) <= jnp.max(qp)
+        if window is not None:
+            live = live & (jnp.max(kp) > jnp.min(qp) - window)
+    else:
+        # the latent call: its table made this test, and validity's, on the
+        # way in (a scalar here: a dead step loads and reduces nothing) and
+        # copied no dead key block: what lies in k_ref on a dead step is the
+        # last live block over again, which already had its turn
+        at, live = None, fetched
 
     @pl.when(live)
     def _():
+        qp, kp, kv = at or positions()
         q = q_ref[0]                                       # [G, BT, Dh] bf16
         BS, Dh = k_ref.shape[-2], k_ref.shape[-1]
         k = jnp.broadcast_to(k_ref[0][None], (G, BS, Dh))  # [G, BS, Dh]
@@ -254,7 +263,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     window: Optional[int] = None,
                     keep: Optional[jax.Array] = None,
                     sink: Optional[jax.Array] = None,
-                    latent: Optional[jax.Array] = None) -> jax.Array:
+                    latent: Optional[jax.Array] = None,
+                    blocks: Optional[Tuple[int, int]] = None) -> jax.Array:
     """Blockwise attention with explicit positions.
 
     q: [B, T, Hq, Dh] ; k: [B, S, Hkv, Dh] ; v: [B, S, Hkv, Dv] (gathered
@@ -273,9 +283,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     [B, S, 1, Dh] the one shared rotary key, ``v`` [B, S, 1, Dv] the
     compressed vectors): a score is ``q . k + latent . v`` and the result
     the weighted sum of ``v``. Every (token, head) is then a query ROW of
-    its own against the one K/V head, a token's heads side by side in one
-    block, and the key blocks are wider (512): such a block's work is two
-    [128, Dv] x [Dv, BS] products, which a 128-key block does not amortise.
+    its own against the one K/V head, and the call has a block schedule of
+    its own (:func:`latent_flash_blocks`, :func:`latent_flash_fetch`):
+    several tokens' heads side by side a query block, so that a key block
+    copied once serves them all, key blocks 512 wide, and no copy of a key
+    block that no query of the block can see.
     """
     B, T, Hq, Dh = q.shape
     S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
@@ -283,24 +295,101 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         if Hkv != 1 or keep is not None or sink is not None:
             raise ValueError("latent attention: one K/V head, no selection, "
                              "no sink")
+        tokens, BS = blocks or latent_flash_blocks(T, S, Hq)
         rows = lambda a: a.reshape(B, T * Hq, 1, a.shape[-1])
         out = _flash_call(rows(q), k, v, jnp.repeat(q_pos, Hq, axis=1),
                           k_pos, k_valid, interpret, scale, softcap, window,
-                          None, None, rows(latent))
+                          None, None, rows(latent), (tokens * Hq, BS),
+                          latent_flash_fetch(q_pos, k_pos, k_valid, tokens,
+                                             BS, window).reshape(-1))
         return out.reshape(B, T, Hq, Dv)
     return _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale,
                        softcap, window, keep, sink, None)
 
 
+# Query rows a block of the latent call: 128 heads x 8 tokens. PERF.md PR 43
+# has the sweep of the kernel alone on a v5e that chose it (128 -> 512 rows
+# is most of the gain, 1024 a twentieth more, 2048 and 4096 another fortieth
+# each at twice and four times the fast memory; at 1024 a call takes 10-12
+# MiB of the 16 the compiler gives a kernel, compile only).
+_LATENT_ROWS = 1024
+
+
+def latent_flash_blocks(T: int, S: int, heads: int) -> Tuple[int, int]:
+    """The block shape of the latent flash call over a chunk of ``T`` tokens
+    of ``heads`` heads and a context of ``S``: (tokens a query block, keys a
+    key block). A query block is whole tokens, all their heads (rows are
+    token-major): the largest power of two of them that divides ``T`` and
+    keeps the block within ``_LATENT_ROWS`` rows, at least one; the whole
+    chunk where that many rows are no multiple of 8 (Mosaic's tiling rule,
+    :func:`_pick_block`)."""
+    tokens = 1
+    while tokens * 2 * heads <= _LATENT_ROWS and T % (tokens * 2) == 0:
+        tokens *= 2
+    if (tokens * heads) % 8:
+        tokens = T
+    return tokens, _pick_block(S, 128, cap=512)
+
+
+def latent_flash_fetch(q_pos, k_pos, k_valid, tokens: int, BS: int,
+                       window: Optional[int] = None, xp=jnp):
+    """The key block each grid step of the latent flash call is GIVEN
+    [B, T // tokens, S // BS] int32: its own where some query of the block
+    may see a valid key of it (a key at or before the block's last position
+    and, with a window, one above its first position's window: never fewer
+    blocks than the mask inside the kernel lets through), else the nearest
+    such block before it, else the first after it (0 where the query block
+    sees none). Pallas copies a block only when its index changes from one
+    grid step to the next, so a step whose entry is not its own ``j`` copies
+    no K, V, position or validity block, and the kernel computes nothing
+    there. Live blocks need not be a prefix. ``xp=np`` is the same
+    arithmetic on the host (:func:`latent_flash_copies`)."""
+    B, T = q_pos.shape
+    S = k_pos.shape[1]
+    nJ = S // BS
+    qb = q_pos.reshape(B, T // tokens, tokens)
+    kb = k_pos.reshape(B, nJ, BS)
+    vb = k_valid.reshape(B, nJ, BS)
+    far = np.iinfo(np.int32).max
+    live = (xp.min(xp.where(vb, kb, far), axis=-1)[:, None, :]
+            <= xp.max(qb, axis=-1)[:, :, None])            # [B, nI, nJ]
+    if window is not None:
+        live = live & (xp.max(xp.where(vb, kb, -far), axis=-1)[:, None, :]
+                       > xp.min(qb, axis=-1)[:, :, None] - window)
+    j = xp.arange(nJ, dtype=np.int32)
+    before = live[:, :, None, :] & (j[None, :] <= j[:, None])
+    last = xp.max(xp.where(before, j, -1), axis=-1)
+    first = xp.argmax(live, axis=-1).astype(np.int32)[..., None]
+    return xp.where(last >= 0, last, first).astype(np.int32)
+
+
+def latent_flash_copies(fetch) -> Tuple[int, int]:
+    """(grid steps, key blocks copied) of one latent flash call, from its
+    table (NumPy, :func:`latent_flash_fetch` with ``xp=np``): a step copies
+    its K block (and V, position, validity alike) when the block it is given
+    is not the one the step before held; a lane's first step always does."""
+    flat = np.asarray(fetch).reshape(fetch.shape[0], -1)
+    return flat.size, int(flat.shape[0]
+                          + np.count_nonzero(flat[:, 1:] != flat[:, :-1]))
+
+
+def _latent_flash_kernel(fetch_ref, *refs, **kw):
+    """:func:`_flash_kernel` behind the table of :func:`latent_flash_fetch`:
+    a step computes only on a block that is its own."""
+    j = pl.program_id(2)
+    at = ((pl.program_id(0) * pl.num_programs(1) + pl.program_id(1))
+          * pl.num_programs(2) + j)
+    _flash_kernel(*refs, fetched=fetch_ref[at] == j, **kw)
+
+
 def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
-                window, keep, sink, latent):
+                window, keep, sink, latent, blocks=None, fetch=None):
     B, T, Hq, Dh = q.shape
     S, Hkv, Dv = k.shape[1], k.shape[2], v.shape[3]
     G = Hq // Hkv
     if interpret is None:
         interpret = not on_tpu()
-    BT = _pick_block(T, 8)
-    BS = _pick_block(S, 128, **({} if latent is None else {"cap": 512}))
+    BT, BS = blocks or (_pick_block(T, 8), _pick_block(S, 128))
     if scale is None:
         scale = 1.0 / math.sqrt(Dh)
 
@@ -330,34 +419,55 @@ def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
         sel_args.append(sink.astype(jnp.float32).reshape(Hkv, G, 1))
     if latent is not None:
         sel_specs.append(pl.BlockSpec((1, G, BT, Dv),
-                                      lambda bh, i, j: (bh, 0, i, 0)))
+                                      lambda bh, i, j, *t: (bh, 0, i, 0)))
         sel_args.append(latent.reshape(B, T, Hkv, G, Dv).transpose(
             0, 2, 3, 1, 4).reshape(B * Hkv, G, T, Dv))
     grid = (B * Hkv, T // BT, S // BS)
-    out = _pallas_call(
-        functools.partial(_flash_kernel, scale=scale, G=G,
-                          softcap=softcap, window=window, selected=selected,
-                          **({} if sink is None else {"sunk": True}),
-                          **({} if latent is None else {"latent": True})),
+    nI, nJ = grid[1:]
+
+    def given(bh, i, j, *table):
+        """The key block of grid step (bh, i, j): ``j``, or what the latent
+        call's table holds (Hkv = 1 there)."""
+        return table[0][(bh * nI + i) * nJ + j] if table else j
+
+    kernel = functools.partial(
+        _flash_kernel if fetch is None else _latent_flash_kernel,
+        scale=scale, G=G, softcap=softcap, window=window, selected=selected,
+        **({} if sink is None else {"sunk": True}),
+        **({} if latent is None else {"latent": True}))
+    shape = dict(
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, BT, 1), lambda bh, i, j: (bh // Hkv, i, 0)),
-            pl.BlockSpec((1, 1, BS), lambda bh, i, j: (bh // Hkv, 0, j)),
-            pl.BlockSpec((1, 1, BS), lambda bh, i, j: (bh // Hkv, 0, j)),
-            pl.BlockSpec((1, G, BT, Dh), lambda bh, i, j: (bh, 0, i, 0)),
-            pl.BlockSpec((1, BS, Dh), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, BS, Dv), lambda bh, i, j: (bh, j, 0)),
+            pl.BlockSpec((1, BT, 1), lambda bh, i, j, *t: (bh // Hkv, i, 0)),
+            pl.BlockSpec((1, 1, BS),
+                         lambda bh, i, j, *t: (bh // Hkv, 0,
+                                               given(bh, i, j, *t))),
+            pl.BlockSpec((1, 1, BS),
+                         lambda bh, i, j, *t: (bh // Hkv, 0,
+                                               given(bh, i, j, *t))),
+            pl.BlockSpec((1, G, BT, Dh), lambda bh, i, j, *t: (bh, 0, i, 0)),
+            pl.BlockSpec((1, BS, Dh),
+                         lambda bh, i, j, *t: (bh, given(bh, i, j, *t), 0)),
+            pl.BlockSpec((1, BS, Dv),
+                         lambda bh, i, j, *t: (bh, given(bh, i, j, *t), 0)),
             *sel_specs,
         ],
-        out_specs=pl.BlockSpec((1, G, BT, Dv), lambda bh, i, j: (bh, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, G, BT, Dv),
+                               lambda bh, i, j, *t: (bh, 0, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, BT, 1), jnp.float32),    # m
             pltpu.VMEM((G, BT, 1), jnp.float32),    # l
             pltpu.VMEM((G, BT, Dv), jnp.float32),   # acc
-        ],
+        ])
+    if fetch is not None:
+        shape = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **shape))
+    out = _pallas_call(
+        kernel, **shape,
+        out_shape=jax.ShapeDtypeStruct((B * Hkv, G, T, Dv), q.dtype),
         interpret=interpret,
-    )(qpos_col, kpos3, kval, q5, k3, v3, *sel_args)
+    )(*(() if fetch is None else (fetch,)),
+      qpos_col, kpos3, kval, q5, k3, v3, *sel_args)
 
     out = out.reshape(B, Hkv, G, T, Dv).transpose(0, 3, 1, 2, 4)
     return out.reshape(B, T, Hq, Dv)

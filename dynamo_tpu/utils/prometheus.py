@@ -624,6 +624,14 @@ class StageMetrics:
             "dyn_attn_latent_pairs_total",
             "(query, visible key) pairs of a latent-attention model's "
             "dispatches (one layer's worth)", ("kind",))
+        self.attn_latent_key_blocks = r.counter(
+            "dyn_attn_latent_key_blocks_total",
+            "Key blocks of the latent flash call's grid, a chunk "
+            "dispatch's lanes and query blocks all (one layer's worth): "
+            "state=bucket every (query block, key block) of the "
+            "program's bucket, state=copied those the call copies (a "
+            "block no query of the query block can see is neither "
+            "copied nor computed on)", ("kind", "state"))
         self.profile_captured_work = r.counter(
             "dyn_profile_captured_work_total",
             "The counters above (by name), and dispatches and tokens, "

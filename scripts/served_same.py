@@ -2,7 +2,7 @@
 """Builder's tool, on the chip: what a tree SERVES under one seed, kept to
 the bit, so that two trees can be held equal.
 
-    cd <tree> && python <this file> --out <file.npz> [config ...]
+    cd <tree> && python <this file> --out <file.npz> [--lengths 4100,9023] [config ...]
     python <this file> --compare <a.npz> <b.npz>
 
 Imports ``dynamo_tpu`` from the CURRENT directory. For a benchmark
@@ -11,7 +11,9 @@ arguments, weights seeded as the benchmark seeds them) it builds the engine
 in this process, without warm-up, serves a handful of prompts of lengths
 around page and block edges at once (so decode runs them as lanes beside
 lanes it does not serve) and keeps every token and its log-probability
-(float32). ``--compare`` holds two such files equal bit for bit. PERF.md
+(float32). ``--lengths`` serves prompts of those lengths instead (PR 43: a
+4k and a 9k prompt through the latent configuration's chunk programs up to
+its 16k bucket). ``--compare`` holds two such files equal bit for bit. PERF.md
 section 6, PR 41. ``REHEARSE=1 JAX_PLATFORMS=cpu ... tiny-byte`` runs a
 preset at toy size (a rehearsal of the script).
 """
@@ -31,7 +33,7 @@ LENGTHS = [5, 65, 130, 511, 513, 900]
 NEW = 24
 
 
-def serve(name):
+def serve(name, lengths=LENGTHS):
     from dynamo_tpu.engine.engine import EngineCore, JaxEngineConfig
     from dynamo_tpu.llm.protocols.common import BackendInput, StopConditions
     from dynamo_tpu.models import llama
@@ -52,7 +54,7 @@ def serve(name):
                                       **eng))
     rng = np.random.default_rng(SEED)
     names = []
-    for n in LENGTHS:
+    for n in lengths:
         if n + NEW > eng["max_context"]:
             continue
         names.append(f"p{n}")
@@ -98,9 +100,12 @@ def main(argv) -> int:
     out = None
     if argv[:1] == ["--out"]:
         out, argv = argv[1], argv[2:]
+    lengths = LENGTHS
+    if argv[:1] == ["--lengths"]:
+        lengths, argv = [int(n) for n in argv[1].split(",")], argv[2:]
     kept = {}
     for name in argv:
-        said, arrays = serve(name)
+        said, arrays = serve(name, lengths)
         print(name, json.dumps(said), flush=True)
         kept.update(arrays)
     if out:

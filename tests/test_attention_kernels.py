@@ -616,3 +616,222 @@ def test_live_pages_counted_token_by_token(window, ppb, P):
     assert live2.shape == visited2.shape == steps.shape
     assert list(live2[:, 0]) == list(live)
     assert list(visited2[:, 0]) == list(visited)
+
+
+# ---------------------------------------------------------------------------
+# The latent flash call's block schedule: several tokens' heads a query
+# block, and no copy (nor computation) of a key block no query of it sees
+# ---------------------------------------------------------------------------
+
+_LAT = dict(Hq=8, Dh=128, Dv=128, BS=128, S=512)
+
+
+def _latent_inputs(T, p0, S, holes=(), seed=0):
+    """A chunk of ``T`` tokens at positions ``[p0, p0 + T)`` of one lane over
+    a bucket of ``S`` slots of which the first ``p0 + T`` are written, the
+    slots of ``holes`` (key-block numbers) marked invalid; a second lane
+    that is all padding, as a bucket's unused lane is."""
+    Hq, Dh, Dv, BS = (_LAT[k] for k in ("Hq", "Dh", "Dv", "BS"))
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    bf = lambda key, shape: jax.random.normal(
+        key, shape, jnp.float32).astype(jnp.bfloat16)
+    q, lat = bf(ks[0], (2, T, Hq, Dh)), bf(ks[1], (2, T, Hq, Dv))
+    k, v = bf(ks[2], (2, S, 1, Dh)), bf(ks[3], (2, S, 1, Dv))
+    n = p0 + T
+    slots = np.arange(S)
+    k_valid = np.zeros((2, S), bool)
+    k_valid[0] = slots < n
+    for j in holes:
+        k_valid[0, j * BS:(j + 1) * BS] = False
+    k_pos = np.zeros((2, S), np.int32)
+    k_pos[0] = np.where(slots < n, slots, 0)     # cache.read_slots' padding
+    q_pos = np.zeros((2, T), np.int32)
+    q_pos[0] = p0 + np.arange(T)
+    return (q, k, v, jnp.asarray(q_pos), jnp.asarray(k_pos),
+            jnp.asarray(k_valid)), lat
+
+
+def _latent_dense(args, lat, scale):
+    from dynamo_tpu.models.llama import latent_attend
+
+    q, k, v, q_pos, k_pos, k_valid = args
+    mask = k_valid[:, None, :] & (k_pos[:, None, :] <= q_pos[:, :, None])
+
+    class cfg:
+        attn_scale = scale
+    return latent_attend(cfg, (q, lat), k, v, mask)
+
+
+def _parent_schedule(args, lat, scale):
+    """The call as it was before it had a schedule of its own: one token's
+    heads a query block, every key block copied, the positions' guard
+    alone."""
+    from dynamo_tpu.ops import attention as A
+
+    q, k, v, q_pos, k_pos, k_valid = args
+    B, T, Hq, _ = q.shape
+    rows = lambda a: a.reshape(B, T * Hq, 1, a.shape[-1])
+    out = A._flash_call(rows(q), k, v, jnp.repeat(q_pos, Hq, axis=1), k_pos,
+                        k_valid, True, scale, None, None, None, None,
+                        rows(lat), (Hq, _LAT["BS"]))
+    return out.reshape(B, T, Hq, -1)
+
+
+_SHARES = {"30%": 0.30, "75%": 0.75, "100%": 1.0}
+_LATENT_CASES = [
+    pytest.param(T, int(_LAT["S"] * share) - T, (), tokens,
+                 id=f"T{T}-live{name}-{tokens}tok")
+    for T in (8, 32) for name, share in _SHARES.items()
+    for tokens in (1, 4, 8)
+] + [
+    # the live key blocks are NOT a prefix: block 1 of 0..2 holds nothing
+    pytest.param(32, 352, (1,), 4, id="hole-in-the-middle"),
+    # the chunk's positions 252..259 lie on both sides of a key block's edge
+    pytest.param(8, 252, (), 4, id="chunk-across-a-key-block-edge"),
+    # a chunk at the lane's start: one live block, a query block of the lot
+    pytest.param(32, 0, (), 8, id="first-chunk"),
+]
+
+
+@pytest.mark.parametrize("T,p0,holes,tokens", _LATENT_CASES)
+def test_latent_flash_schedule(T, p0, holes, tokens):
+    """The latent call with ``tokens`` tokens' heads a query block and no
+    dead key block copied: the dense absorbed form within the flash
+    tolerance, the schedule it had before BIT FOR BIT, and the same bits
+    again with every dead key block full of NaN (a block the call is not
+    given cannot reach the result; one it computed on would)."""
+    from dynamo_tpu.ops import attention as A
+
+    scale = 0.1147
+    BS, S = _LAT["BS"], _LAT["S"]
+    args, lat = _latent_inputs(T, p0, S, holes)
+    got = flash_attention(*args, interpret=True, scale=scale, latent=lat,
+                          blocks=(tokens, BS))
+    want = _latent_dense(args, lat, scale)
+    np.testing.assert_allclose(
+        np.asarray(got[0], np.float32), np.asarray(want[0], np.float32),
+        atol=3e-2, rtol=3e-2)
+    before = _parent_schedule(args, lat, scale)
+    assert np.array_equal(np.asarray(got, np.float32),
+                          np.asarray(before, np.float32))
+
+    q, k, v, q_pos, k_pos, k_valid = args
+    fetch = np.asarray(A.latent_flash_fetch(
+        np.asarray(q_pos), np.asarray(k_pos), np.asarray(k_valid), tokens,
+        BS, xp=np))
+    dead = ~(fetch == np.arange(S // BS)).any(axis=1)         # [B, nJ]
+    assert dead[0].sum() == S // BS - (-(-(p0 + T) // BS) - len(holes))
+    poison = jnp.asarray(np.repeat(dead, BS, axis=1))[:, :, None, None]
+    again = flash_attention(q, jnp.where(poison, jnp.nan, k),
+                            jnp.where(poison, jnp.nan, v), q_pos, k_pos,
+                            k_valid, interpret=True, scale=scale, latent=lat,
+                            blocks=(tokens, BS))
+    assert np.array_equal(np.asarray(again, np.float32),
+                          np.asarray(got, np.float32))
+
+
+@pytest.mark.parametrize("T,S,heads,want", [
+    (256, 16384, 128, (8, 512)),     # the benchmark's cell: 1024 rows a block
+    (32, 1024, 128, (8, 512)),
+    (2, 256, 128, (2, 256)),
+    (1, 128, 128, (1, 128)),
+    (32, 512, 16, (32, 512)),        # a tensor-parallel shard's 16 heads
+    (12, 128, 3, (12, 128)),         # 4 x 3 rows, no multiple of 8: the lot
+    (6, 384, 8, (2, 128)),
+])
+def test_latent_flash_blocks(T, S, heads, want):
+    from dynamo_tpu.ops.attention import latent_flash_blocks
+
+    assert latent_flash_blocks(T, S, heads) == want
+
+
+def test_latent_flash_copies_are_counted_step_by_step():
+    """``latent_flash_copies`` against a walk of the grid, a step at a time,
+    over tables with a window, a hole and an empty lane."""
+    from dynamo_tpu.ops import attention as A
+
+    rng = np.random.default_rng(0)
+    for window in (None, 200):
+        for p0, T in ((0, 32), (100, 32), (480, 32), (250, 8)):
+            q_pos = np.zeros((2, T), np.int32)
+            q_pos[0] = p0 + np.arange(T)
+            k_pos = np.zeros((2, 512), np.int32)
+            k_pos[0, :p0 + T] = np.arange(p0 + T)
+            k_valid = np.zeros((2, 512), bool)
+            k_valid[0, :p0 + T] = True
+            k_valid[0, 128:256] &= rng.random() < 0.5
+            fetch = A.latent_flash_fetch(q_pos, k_pos, k_valid, 4, 128,
+                                         window, xp=np)
+            steps = copies = 0
+            for lane in fetch:
+                held = None
+                for given in lane.reshape(-1):
+                    steps += 1
+                    copies += given != held
+                    held = given
+            assert A.latent_flash_copies(fetch) == (steps, copies)
+            # a block is given to the steps that follow it until the next
+            # live one, never to a step before the first
+            mask = (k_valid[:, None, :] & (k_pos[:, None, :]
+                                           <= q_pos[:, :, None]))
+            if window is not None:
+                mask &= k_pos[:, None, :] > q_pos[:, :, None] - window
+            seen = mask.reshape(2, T // 4, 4, 4, 128).any(axis=(2, 4))
+            own = fetch == np.arange(4)
+            assert (own | ~seen).all()       # every visible block is given
+
+
+def test_a_call_without_a_latent_operand_lowers_as_it_did():
+    """The schedule hangs on the ``latent`` operand: a flash call without it
+    (GQA, a window, a selection, a sink) lowers for the chip to the text it
+    had before the latent call got a table (SHA-256 taken on the parent
+    commit with this very function; a change MEANT to alter every flash call
+    renews it)."""
+    import hashlib
+
+    from dynamo_tpu.utils import jaxenv
+
+    B, T, S, Hq, Hkv, Dh = 1, 64, 512, 8, 2, 128
+    sds = jax.ShapeDtypeStruct
+    shapes = (sds((B, T, Hq, Dh), jnp.bfloat16),
+              sds((B, S, Hkv, Dh), jnp.bfloat16),
+              sds((B, S, Hkv, Dh), jnp.bfloat16),
+              sds((B, T), jnp.int32), sds((B, S), jnp.int32),
+              sds((B, S), jnp.bool_))
+
+    def text(**kw):
+        extra = []
+        if kw.pop("keep", False):
+            extra.append(("keep", sds((B, T, S), jnp.bool_)))
+        if kw.pop("sink", False):
+            extra.append(("sink", sds((Hq,), jnp.float32)))
+        names = [n for n, _ in extra]
+
+        def f(*a):
+            return flash_attention(*a[:6], interpret=False,
+                                   **dict(zip(names, a[6:])), **kw)
+        low = jax.jit(f).trace(*shapes, *[s for _, s in extra]).lower(
+            lowering_platforms=("tpu",))
+        return hashlib.sha256(low.as_text().encode()).hexdigest()
+
+    # locations as every engine entry point sets them: names, no file paths
+    was = {k: getattr(jax.config, k) for k in jaxenv.PROGRAM_LOCATIONS}
+    for name, value in jaxenv.PROGRAM_LOCATIONS.items():
+        jax.config.update(name, value)
+    try:
+        got = {"plain": text(), "window": text(window=128, softcap=30.0),
+               "selected-sunk": text(keep=True, sink=True)}
+    finally:
+        for name, value in was.items():
+            jax.config.update(name, value)
+    assert got == _PARENT_FLASH_TEXT, got
+
+
+_PARENT_FLASH_TEXT = {
+    "plain":
+        "047450bd56be15c836a63456af2f4693f393bd71d01d33f7b1318701f9fd3768",
+    "window":
+        "f3f1f39fac045c421e1f6b6ca1e37c9a40f557eaf689c8b809f29c69ca845a32",
+    "selected-sunk":
+        "34acc360fd59f372c9a3fed8ffe0fc3608013beedce6850402a30b7e3f7da352",
+}

@@ -526,6 +526,49 @@ def test_counters_say_what_the_dispatches_did(core):
     assert moved["dyn_attn_latent_pairs_total", "decode"] == want
 
 
+@pytest.mark.parametrize("p0,n,S,want", [
+    # 128 heads: 8 tokens a query block, 4 of them a 32-token chunk; key
+    # blocks of 512, and a second lane of the program that serves nothing
+    # (one copy: it holds block 0 from its first step to its last)
+    (0, 32, 2048, (4 * 4 * 2, 1 + 1)),        # block 0 alone, copied once
+    # positions 1000..1031: the first three query blocks end before 1024
+    # and see blocks 0-1, the last sees 0-2; copied again for each
+    (1000, 32, 2048, (4 * 4 * 2, 3 * 2 + 3 + 1)),
+    (2016, 32, 2048, (4 * 4 * 2, 4 * 4 + 1)),  # the bucket's end: all four
+    # 20 tokens: three query blocks hold one, the fourth is padding (block 0)
+    (2016, 20, 2048, (4 * 4 * 2, 3 * 4 + 1 + 1)),
+])
+def test_key_blocks_of_the_latent_flash_call_on_the_host(core, p0, n, S,
+                                                         want):
+    """What a chunk dispatch counts, at the lane's start, mid-bucket and at
+    the bucket's end: the grid of ONE latent flash call and the key blocks
+    it copies of it, by the call's own table on the arrays the dispatch
+    hands its program (``_prefill_enqueue``: unused queries and the slots
+    past the chunk's end at position 0, those slots invalid)."""
+    q_pos = np.zeros((2, 32), np.int32)
+    k_pos = np.zeros((2, S), np.int32)
+    k_valid = np.zeros((2, S), bool)
+    q_pos[0, :n] = np.arange(p0, p0 + n)
+    k_pos[0, :p0 + n] = np.arange(p0 + n)
+    k_valid[0, :p0 + n] = True
+    assert core._latent_key_blocks(q_pos, k_pos, k_valid, 128) == want
+
+
+def test_chunk_dispatches_count_their_key_blocks(core):
+    """Through the counter, as fetched chunk dispatches count it, and only
+    where chunks run the flash kernel (the tiny model's 4 heads and contexts
+    under 128: every chunk one query block against one key block)."""
+    series = core.stage.attn_latent_key_blocks
+    was = dict(series._values)
+    generate(core, "blk", prompt_of(37, 7), 2)
+    moved = {k: v - was.get(k, 0.0) for k, v in series._values.items()}
+    if core.attn_impl == "pallas":
+        # chunks of 16, 16 and 5
+        assert moved == {("prefill", "bucket"): 3.0, ("prefill", "copied"): 3.0}
+    else:
+        assert not series._values
+
+
 def test_costs_price_the_latent_row_and_the_shared_expert(core):
     from dynamo_tpu.utils import roofline
 
