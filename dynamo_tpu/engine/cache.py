@@ -48,7 +48,9 @@ class CacheKind:
     pool and page tables of their own, :class:`WindowPages`); a model with
     state-space layers has ``global`` (its attention layers) and ``state``:
     what those layers keep per LANE, whatever the lane's tokens — nothing
-    pages it, nothing hashes it, and ``token_bytes`` of it is 0. A model
+    pages it, nothing hashes it, and ``token_bytes`` of it is 0; a model
+    with gated short-convolution layers has the same two, its ``state`` a
+    convolution tail ALONE (no recurrent state: one pool, not two). A model
     with latent attention has ONE kind, ``global``, whose token is one row
     for all heads (``latent``): the rotated shared key (``k_dim``, stored
     zero-padded to a lane tile in the K pool) and the compressed vector
@@ -67,8 +69,9 @@ class CacheKind:
     fold: int = 1               # tokens stored to a pool row (kv_fold)
     latent: bool = False        # one row for all heads (latent attention)
     # per lane and layer (the ``state`` kind): the recurrent state's shape
-    # (float32) and the convolution tail's (the model's dtype)
-    state: Optional[Tuple[Tuple[int, ...], Tuple[int, ...]]] = None
+    # (float32; None where the kind keeps a tail alone) and the convolution
+    # tail's (the model's dtype)
+    state: Optional[Tuple[Optional[Tuple[int, ...]], Tuple[int, ...]]] = None
 
     def token_bytes(self, itemsize: int, stored: bool = False) -> int:
         """Bytes a token holds in this kind, all of its layers: K and V as
@@ -96,23 +99,26 @@ class CacheKind:
             return (f"{self.name}:{self.layers}x{self.kv_heads}x"
                     f"({self.k_dim}+{self.v_dim})")
         s, c = self.state
-        return (f"{self.name}:{self.layers}x(" + "x".join(map(str, s))
-                + "f32+" + "x".join(map(str, c)) + ")")
+        rec = "" if s is None else "x".join(map(str, s)) + "f32+"
+        return (f"{self.name}:{self.layers}x(" + rec
+                + "x".join(map(str, c)) + ")")
 
     def lane_bytes(self, itemsize: int) -> int:
         """Bytes a LANE holds in this kind, all of its layers, whatever its
-        tokens: the ``state`` kind's recurrent state and convolution tail;
-        0 for a kind that keeps K and V per token."""
+        tokens: the ``state`` kind's recurrent state (where it has one) and
+        convolution tail; 0 for a kind that keeps K and V per token."""
         if self.state is None:
             return 0
         s, c = self.state
-        return self.layers * (4 * math.prod(s) + itemsize * math.prod(c))
+        return self.layers * ((4 * math.prod(s) if s is not None else 0)
+                              + itemsize * math.prod(c))
 
     def state_shapes(self, lanes: int):
-        """-> (state pool shape, convolution-tail pool shape): [layers of
-        the kind, lanes, ...]."""
-        s, c = self.state
-        return (self.layers, lanes, *s), (self.layers, lanes, *c)
+        """-> the shapes of the pools this kind HAS, [layers of the kind,
+        lanes, ...] each: (state pool, convolution-tail pool), or (tail
+        pool,) of a kind that keeps a tail alone."""
+        return tuple((self.layers, lanes, *part) for part in self.state
+                     if part is not None)
 
 
 def cache_kinds(m) -> Tuple[CacheKind, ...]:
@@ -130,13 +136,18 @@ def cache_kinds(m) -> Tuple[CacheKind, ...]:
         if m.has_window:
             raise ValueError("window layers beside state-space layers are "
                              "not implemented")
+        if m.has_conv and 2 in m.layer_kinds:
+            raise ValueError("state-space layers beside gated short-"
+                             "convolution layers are not implemented")
+        # (a tail's [taps - 1, channels] as ONE flat row)
+        state = ((None, ((m.conv_cache - 1) * m.hidden_size,)) if m.has_conv
+                 else ((m.ssm_heads, m.ssm_head_dim, m.ssm_state),
+                       ((m.ssm_conv - 1) * m.ssm_conv_dim,)))
         return (CacheKind("global", len(m.kind_layers(False)),
                           m.num_kv_heads, m.head_dim, m.v_dim, m.k_store_dim,
                           None, fold=m.kv_fold),
                 CacheKind("state", len(m.state_layers), 0, 0, 0, 0, None,
-                          # (the tail's [conv - 1, channels] as ONE flat row)
-                          state=((m.ssm_heads, m.ssm_head_dim, m.ssm_state),
-                                 ((m.ssm_conv - 1) * m.ssm_conv_dim,))))
+                          state=state))
     return tuple(
         CacheKind(name, len(m.kind_layers(win)), m.kv_heads_of(win),
                   m.head_dim, m.v_dim, m.k_store_dim,

@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import itertools
 import logging
 import math
 import os
@@ -583,16 +584,21 @@ class EngineCore:
         # a recurrent state and a convolution tail for each such layer:
         # [state layers, max_batch, ...] beside the K/V pools of its
         # attention layers, donated and threaded through the programs with
-        # them. Nothing pages or hashes it, and a K/V block re-entered or
-        # moved without the state at its boundary would decode from the
-        # wrong state: such a model refuses those features by name too.
+        # them; a model with gated short-convolution layers keeps a tail
+        # ALONE (``s_pool`` stays None: one pool, not two). Nothing pages or
+        # hashes it, and a K/V block re-entered or moved without the state
+        # at its boundary would decode from the wrong state: such a model
+        # refuses those features by name too.
         self.s_pool = self.c_pool = None
         if m.has_state:
-            self._refuse_configured(impl, self._state_refusal)
+            self._refuse_configured(impl, self._lane_state_refusal)
             self.state_sharding = NamedSharding(self.mesh, P())
-            s_shape, c_shape = self.cache_kinds[1].state_shapes(cfg.max_batch)
-            self.s_pool = jax.jit(lambda: jnp.zeros(s_shape, jnp.float32),
-                                  out_shardings=self.state_sharding)()
+            *s_shape, c_shape = self.cache_kinds[1].state_shapes(
+                cfg.max_batch)
+            if s_shape:
+                self.s_pool = jax.jit(
+                    lambda: jnp.zeros(s_shape[0], jnp.float32),
+                    out_shardings=self.state_sharding)()
             self.c_pool = jax.jit(lambda: jnp.zeros(c_shape, m.dtype),
                                   out_shardings=self.state_sharding)()
             self.stage.ssm_state_bytes.set(value=float(
@@ -733,6 +739,7 @@ class EngineCore:
         # dominate TTFT when the host link is slow
         lanes = cfg.prefill_lanes or cfg.max_batch
         self.b_buckets = _buckets(1, max(1, min(lanes, cfg.max_batch)))
+        self.moe_dispatch = self._moe_dispatch_forms()
         self._decode_fns: Dict[int, Any] = {}
         self._prefill_batch_fns: Dict[Tuple[int, int, int], Any] = {}
         # verify programs, keyed (S, K): compiled lazily, and ONLY when spec
@@ -873,19 +880,33 @@ class EngineCore:
                 f"their boundary, or advance a state that cannot be rolled "
                 f"back")
 
+    @staticmethod
+    def _conv_refusal(what: str) -> str:
+        return (f"a model with gated short-convolution layers (a "
+                f"convolution tail a lane beside the K/V cache) does not "
+                f"run with {what}: it would move, match or re-enter K/V "
+                f"blocks without the tail at their boundary, or advance a "
+                f"tail that cannot be rolled back")
+
+    @property
+    def _lane_state_refusal(self):
+        """The refusal that names what this model keeps a lane."""
+        return (self._conv_refusal if self.cfg.model.has_conv
+                else self._state_refusal)
+
     def _refuse_block_moves(self, what: str) -> None:
         """Refuse a feature that moves K/V blocks off or onto the device
         pool for a model that keeps more than K and V on those pages (an
         indexer's keys), keeps a second cache beside them (a per-kind
         model's window layers), keeps a state a lane that no block holds
-        (state-space layers) or keeps its blocks in pools of two widths (the
-        latent cache kind)."""
+        (state-space layers' state, gated short-convolution layers' tail) or
+        keeps its blocks in pools of two widths (the latent cache kind)."""
         if self.cfg.model.has_indexer:
             raise ValueError(self._indexer_refusal(what))
         if self.cfg.model.has_window:
             raise ValueError(self._two_caches_refusal(what))
         if self.cfg.model.has_state:
-            raise ValueError(self._state_refusal(what))
+            raise ValueError(self._lane_state_refusal(what))
         if self.cfg.model.has_latent:
             raise ValueError(self._latent_refusal(what))
 
@@ -897,9 +918,41 @@ class EngineCore:
         therefore compile to what they always did)."""
         if self.win is not None:
             return {"wk_pool": self.wk_pool, "wv_pool": self.wv_pool}
-        if self.s_pool is not None:
-            return {"s_pool": self.s_pool, "c_pool": self.c_pool}
+        if self.c_pool is not None:
+            return self._state_pools()
         return {} if self.i_pool is None else {"i_pool": self.i_pool}
+
+    def _moe_dispatch_forms(self) -> str:
+        """What the routed experts' dispatch is in this engine's programs
+        (``moe.dispatch_form``, the rule ``moe_ffn`` itself asks), as
+        ``dyn_engine_info{moe_dispatch}`` shows it: ``decode:<form>`` and
+        ``chunk:<form>`` with the rows of the chunk programs that take it
+        (``chunk:dense32-512,sorted1024-1024``); ``none`` for a dense model.
+        Reported, never used to select."""
+        m = self.cfg.model
+        if not m.num_experts:
+            return "none"
+        if self.cfg.pp > 1:
+            return "decode:dense,chunk:dense"     # moe_ffn_in_stage
+        from ..models.moe import dispatch_form
+        R = m.router_experts or m.num_experts
+
+        def form(rows: int) -> str:
+            return dispatch_form(rows, m.experts_per_token, m.num_experts,
+                                 m.num_experts / R, self.mesh, m.expert_width)
+
+        rows = sorted({b * c for b in self.b_buckets for c in self.c_buckets})
+        runs = [(f, list(g)) for f, g in itertools.groupby(rows, key=form)]
+        chunk = (runs[0][0] if len(runs) == 1 else ",".join(
+            f"{f}{g[0]}-{g[-1]}" for f, g in runs))
+        return f"decode:{form(self.cfg.max_batch)},chunk:{chunk}"
+
+    def _state_pools(self) -> Dict[str, Any]:
+        """The state pools this model has, by their program operand: both
+        of a state-space model, the tail pool alone of a gated short
+        convolution."""
+        return {n: p for n, p in (("s_pool", self.s_pool),
+                                  ("c_pool", self.c_pool)) if p is not None}
 
     def _program_extras(self):
         """-> (jit options, out_shardings tail, the columns ``packed``
@@ -917,8 +970,9 @@ class EngineCore:
             return ({"donate_argnames": ("wk_pool", "wv_pool")},
                     (self.kv_sharding, self.kv_sharding), cols)
         if m.has_state:
-            return ({"donate_argnames": ("s_pool", "c_pool")},
-                    (self.state_sharding, self.state_sharding), cols)
+            names = tuple(self._state_pools())
+            return ({"donate_argnames": names},
+                    (self.state_sharding,) * len(names), cols)
         return {}, (), cols
 
     @cached_property
@@ -933,8 +987,10 @@ class EngineCore:
         self.k_pool, self.v_pool, *rest = pools
         if self.win is not None:
             self.wk_pool, self.wv_pool = rest
-        elif self.s_pool is not None:
-            self.s_pool, self.c_pool = rest
+        elif self.c_pool is not None:
+            *s_pool, self.c_pool = rest
+            if s_pool:
+                self.s_pool, = s_pool
         elif rest:
             self.i_pool, = rest
 
@@ -955,7 +1011,7 @@ class EngineCore:
         row filled in: every row names a lane past the pool (writes
         nothing) and holds no real token. Warm-up passes them as they are;
         a dispatch fills in its rows."""
-        if self.s_pool is None:
+        if self.c_pool is None:
             return {}
         return {"s_lanes": np.full(Bp, self.cfg.max_batch, np.int32),
                 "s_reset": np.zeros(Bp, bool),
@@ -1040,8 +1096,8 @@ class EngineCore:
     def _count_model_work(self, kind: str, spans, hit,
                           captured: bool = False, S: int = 0,
                           held: Optional[float] = None,
-                          key_blocks: Optional[Tuple[int, int]] = None
-                          ) -> None:
+                          key_blocks: Optional[Tuple[int, int]] = None,
+                          steps: int = 1) -> None:
         """Host counters of what a dispatch made the experts and the
         indexer do. ``spans``: (first position, queries) per lane; a query
         at position p sees p + 1 keys. ``hit``: experts hit, read from the
@@ -1059,7 +1115,8 @@ class EngineCore:
         ``dyn_moe_assignments_total`` counts (computed here), while
         ``dyn_moe_routed_assignments_total`` counts all of them.
         ``key_blocks``: :meth:`_latent_key_blocks` of a chunk dispatch whose
-        attention is the latent flash call."""
+        attention is the latent flash call. ``steps``: the steps of a decode
+        dispatch (``dyn_moe_layer_calls_total``)."""
         m = self.cfg.model
         if not (m.num_experts or m.has_indexer):
             return
@@ -1073,6 +1130,10 @@ class EngineCore:
                 work[self.stage.moe_routed_assignments] = routed
             if hit is not None:
                 work[self.stage.moe_experts_hit] = float(hit)
+                # the calls those experts were hit in: every routed layer,
+                # each step of a decode dispatch, once of a chunk
+                work[self.stage.moe_layer_calls] = float(
+                    m.routed_layers * steps)
         if m.has_indexer:
             k = m.index_topk
             seen = sel = 0
@@ -1320,7 +1381,8 @@ class EngineCore:
 
                 carry = (tokens, lengths, k_pool, v_pool, key, counts,
                          *((wk_pool, wv_pool) if windowed
-                           else (s_pool, c_pool) if stateful
+                           else tuple(p for p in (s_pool, c_pool)
+                                      if p is not None) if stateful
                            else () if i_pool is None else (i_pool,)))
                 ((tok, lengths, k_pool, v_pool, key, counts, *ip),
                  (toks, logps, *hit)) = jax.lax.scan(one, carry, None,
@@ -1400,8 +1462,9 @@ class EngineCore:
                         **({} if wk_pool is None else {"win": (
                             wk_pool, wv_pool, w_write, w_pages, w_pos,
                             w_valid)}),
-                        **({} if s_pool is None else {"ssm": (
-                            s_pool, c_pool, s_lanes, s_reset, s_valid)}),
+                        **({} if c_pool is None else {"ssm": (
+                            *(p for p in (s_pool, c_pool) if p is not None),
+                            s_lanes, s_reset, s_valid)}),
                         embed_override=((ov_vals, ov_mask) if mm else None),
                         attn_spans=((q_span, read_span) if mm else None),
                         read_pages=read_pages)
@@ -2871,7 +2934,7 @@ class EngineCore:
         packed, final_tok = self._run_decode_program(
             S, tokens, page_tables, lengths, fresh, active_mask, joining,
             **({} if w_tables is None else {"w_tables": w_tables}))
-        if self.s_pool is not None:
+        if self.c_pool is not None:
             self._count_state_work("decode", B * N, len(active) * N,
                                    len(active) * N, 0, self.capturing)
         if self._attn_calls:
@@ -3181,7 +3244,7 @@ class EngineCore:
             rec.get("captured", False), rec.get("S", 0),
             held=(packed_np[:, 0, 2 + cols.index("held")].sum()
                   * len(rec["lengths"]) / packed_np.shape[1]
-                  if "held" in cols else None))
+                  if "held" in cols else None), steps=N)
         if self.win is not None:
             steps = self.stage.kv_resident_token_steps
             for (_, slot, _), s0 in zip(rec["active"], rec["lengths"]):
@@ -3442,7 +3505,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
             core.paged_kernel or "none", dev0.platform, dev0.device_kind,
             str(core.mesh.devices.size), core.goodput.peaks.source,
             "+".join(k.label() for k in core.cache_kinds),
-            core.decode_kv_write, value=1)
+            core.decode_kv_write, core.moe_dispatch, value=1)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._queues: Dict[str, asyncio.Queue] = {}
         self._inbox: thread_queue.Queue = thread_queue.Queue()

@@ -251,7 +251,9 @@ class PagedPrograms:
         segmented forward itself cannot express."""
         m = cfg.model
         if m.has_state:
-            return f"models with state-space layers ({llama.NO_STATE})"
+            what = ("gated short-convolution" if m.has_conv
+                    else "state-space")
+            return f"models with {what} layers ({llama.NO_STATE})"
         if m.has_latent:
             return f"models with latent attention ({llama.NO_LATENT})"
         if m.per_kind:

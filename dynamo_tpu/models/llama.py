@@ -170,7 +170,8 @@ class LlamaConfig:
     # A per-layer description where one law for all layers does not hold
     # (MiMo-V2-Flash; ROADMAP Design 2). ``layer_kinds[l]``: 0 full
     # attention, 1 window attention (``sliding_window`` keys with the
-    # query's own), 2 a state-space mixer in place of attention (below);
+    # query's own), 2 a state-space mixer in place of attention (below), 3
+    # a gated short convolution in its place (below);
     # ``ffn_kinds[l]``: 0 dense feed-forward, 1 routed experts. With ``layer_kinds`` the two attention kinds have parameter
     # stacks, head counts and CACHES of their own (:meth:`cache_kinds`):
     # window layers have ``window_kv_heads`` K/V heads and keep a window of
@@ -233,6 +234,15 @@ class LlamaConfig:
     ssm_state: int = 0
     ssm_conv: int = 4
     ssm_conv_bias: bool = True
+    # Gated short-convolution layers (LFM2; ``layer_kinds[l] == 3``): in
+    # place of attention [B, C, z] = W_in h, u = B * z, a depthwise causal
+    # convolution of ``conv_cache`` taps over u, y = C * c, W_out y. Per LANE
+    # such a layer keeps the last ``conv_cache - 1`` rows of u in the
+    # model's dtype and nothing else: a ``state`` cache kind that is a tail
+    # alone ("The gated short convolution" below). 0: no such layer.
+    conv_cache: int = 0
+    # a sigmoid router's denominator is the chosen scores' sum + this
+    router_norm_eps: float = 0.0
     # attention without positional encoding (``position_embedding_type``
     # "nope"): q and k go to the scores as projected
     use_rope: bool = True
@@ -259,8 +269,16 @@ class LlamaConfig:
 
     @property
     def has_state(self) -> bool:
-        """State-space layers that keep a recurrent state a lane."""
-        return self.per_kind and 2 in self.layer_kinds
+        """Layers that keep something per LANE and nothing per token: a
+        state-space mixer's recurrent state and convolution tail, or a
+        gated short convolution's tail alone."""
+        return self.per_kind and (2 in self.layer_kinds
+                                  or 3 in self.layer_kinds)
+
+    @property
+    def has_conv(self) -> bool:
+        """Gated short-convolution layers: the state kind is a tail alone."""
+        return self.per_kind and 3 in self.layer_kinds
 
     @property
     def has_latent(self) -> bool:
@@ -278,8 +296,9 @@ class LlamaConfig:
         return self.per_kind and self.layer_kinds[l] == 1
 
     def layer_state(self, l: int) -> bool:
-        """Layer ``l`` is a state-space mixer (a Python bool)."""
-        return self.per_kind and self.layer_kinds[l] == 2
+        """Layer ``l`` keeps a state a lane: a state-space mixer or a gated
+        short convolution (a Python bool)."""
+        return self.per_kind and self.layer_kinds[l] in (2, 3)
 
     @property
     def state_layers(self) -> Tuple[int, ...]:
@@ -413,6 +432,7 @@ class LlamaConfig:
         if hybrid:
             # the feed-forward every layer has is the SHARED one's width
             cfg = {**cfg, "intermediate_size": cfg["shared_intermediate_size"]}
+        conv, cfg = _map_shortconv(cfg)
         latent = _map_latent(cfg)
         rs = cfg.get("rope_scaling") or {}
         if latent:
@@ -424,7 +444,7 @@ class LlamaConfig:
                              "latent attention alone (kv_lora_rank)")
         experts, kinds = _map_experts(cfg), _map_layer_kinds(cfg)
         if "ffn_kinds" in experts and not (
-                {"layer_kinds"} & {*kinds, *hybrid, *latent}):
+                {"layer_kinds"} & {*kinds, *hybrid, *latent, *conv}):
             raise ValueError(
                 "dense layers among routed ones (moe_layer_freq / "
                 "first_k_dense_replace) are implemented for a model whose "
@@ -468,9 +488,10 @@ class LlamaConfig:
             rope_local_theta=(cfg.get("rope_local_base_freq", 10000.0)
                               if _is_gemma3(cfg)
                               else cfg.get("swa_rope_theta")),
-            qk_norm=_is_gemma3(cfg) or _is_qwen3_family(cfg),
+            qk_norm=(_is_gemma3(cfg) or _is_qwen3_family(cfg)
+                     or bool(conv)),
             dtype=dtype,
-            **experts,
+            **{**experts, **conv},
             **_map_indexer(cfg),
             **kinds,
             **_map_multipliers(cfg),
@@ -804,16 +825,99 @@ def _map_hybrid(cfg: Dict[str, Any]) -> Dict[str, Any]:
     if "shared_intermediate_size" not in cfg:
         raise ValueError("a hybrid config names its feed-forward width in "
                          "shared_intermediate_size")
-    Dh = cfg.get("head_dim",
-                 cfg["hidden_size"] // cfg["num_attention_heads"])
     return {"layer_kinds": tuple(2 if t == "mamba" else 0 for t in lt[:L]),
             "ssm_heads": H, "ssm_head_dim": P,
             "ssm_state": int(cfg["mamba_d_state"]),
             "ssm_conv": int(cfg.get("mamba_d_conv", 4)),
             "ssm_conv_bias": bool(cfg.get("mamba_conv_bias", True)),
-            "use_rope": pos == "rope",
-            # rows narrower than a lane tile: whole tiles, tokens folded
-            "kv_fold": max(1, 128 // Dh) if 128 % Dh == 0 else 1}
+            "use_rope": pos == "rope", "kv_fold": _kv_fold(cfg)}
+
+
+def _kv_fold(cfg: Dict[str, Any]) -> int:
+    """Tokens stored to a 128-lane pool row: K/V rows narrower than a lane
+    tile are folded to whole tiles (``LlamaConfig.kv_fold``)."""
+    Dh = cfg.get("head_dim",
+                 cfg["hidden_size"] // cfg["num_attention_heads"])
+    return max(1, 128 // Dh) if 128 % Dh == 0 else 1
+
+
+# the keys of a gated short-convolution model (``model_type lfm2_moe``) that
+# no other family spells so, and what :func:`_map_shortconv` makes of each
+_SHORTCONV_KEYS = ("conv_L_cache", "conv_bias", "num_dense_layers",
+                   "use_expert_bias", "norm_eps", "rope_parameters")
+
+
+def _map_shortconv(cfg: Dict[str, Any]):
+    """``model_type lfm2_moe`` (LFM2: gated short-convolution layers beside
+    GQA layers with per-head q / k norms, under bias-selected sigmoid-routed
+    experts behind ``num_dense_layers`` dense layers) -> (ours, the config
+    with this family's keys re-spelt as the keys the other mappers read). A
+    key or value the engine cannot honour RAISES; ``use_expert_bias`` or a
+    ``conv`` layer under another model type raises too (the family's keys
+    mean what its modeling file says, nowhere else)."""
+    lt = cfg.get("layer_types") or ()
+    if cfg.get("model_type") != "lfm2_moe":
+        stray = [k for k in ("conv_L_cache", "conv_bias", "use_expert_bias",
+                             "num_dense_layers") if k in cfg]
+        if stray or "conv" in lt:
+            raise ValueError(
+                f"config carries {stray or ['layer_types: conv']} without "
+                f"model_type 'lfm2_moe': refusing to guess what they mean")
+        return {}, cfg
+    L = cfg["num_hidden_layers"]
+    bad = sorted({t for t in lt if t not in ("conv", "full_attention")})
+    if bad or len(lt) < L:
+        raise ValueError(f"layer_types must list 'conv' or 'full_attention' "
+                         f"for each of the {L} layers (got {bad or len(lt)})")
+    if cfg.get("conv_bias", False):
+        raise ValueError("conv_bias true is not implemented: the two "
+                         "projections and the taps carry no bias")
+    K = int(cfg.get("conv_L_cache", 3))
+    if K < 2:
+        raise ValueError(f"conv_L_cache {K}: a convolution of one tap keeps "
+                         f"no tail")
+    if not cfg.get("use_expert_bias", False):
+        raise ValueError("use_expert_bias false is not implemented: the "
+                         "experts are chosen by score + expert_bias")
+    if not cfg.get("norm_topk_prob", False):
+        raise ValueError("norm_topk_prob false is not implemented: the "
+                         "chosen scores are divided by their sum + 1e-6")
+    rp = cfg.get("rope_parameters")
+    if rp is not None:
+        unknown = sorted(set(rp) - {"rope_theta", "rope_type"})
+        if unknown or rp.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_parameters {rp!r}: plain rotary "
+                             f"(rope_type default, rope_theta) is what is "
+                             f"implemented")
+    thetas = {(rp or {}).get("rope_theta"), cfg.get("rope_theta")} - {None}
+    if len(thetas) > 1:
+        raise ValueError("rope_theta is given twice and differs")
+    if not thetas:
+        raise ValueError("no rope_theta (flat or under rope_parameters)")
+    theta, = thetas
+    nd = int(cfg.get("num_dense_layers", 0))
+    if not 0 <= nd < L:
+        raise ValueError(f"num_dense_layers {nd}: no routed layer among {L}")
+    ours = {"layer_kinds": tuple(3 if t == "conv" else 0 for t in lt[:L]),
+            "conv_cache": K, "router_norm_eps": 1e-6,
+            "routed_scaling": float(cfg.get("routed_scaling_factor", 1)),
+            "kv_fold": _kv_fold(cfg)}
+    rest = {k: v for k, v in cfg.items()
+            if k not in _SHORTCONV_KEYS + ("layer_types",
+                                           "routed_scaling_factor")}
+    rest.update(rope_theta=theta,
+                rms_norm_eps=cfg.get("norm_eps", cfg.get("rms_norm_eps", 1e-5)),
+                # the family ties its head to the embedding (``tie_embedding``
+                # in its dense models' files): the default where the file
+                # carries neither spelling
+                tie_word_embeddings=bool(cfg.get(
+                    "tie_word_embeddings", cfg.get("tie_embedding", True))),
+                # sigmoid scores, the bias chooses and never weighs: the
+                # law the DeepSeek-V3 family spells so (route_topk)
+                scoring_func="sigmoid", topk_method="noaux_tc",
+                moe_layer_freq=[0] * nd + [1] * (L - nd))
+    rest.pop("tie_embedding", None)
+    return ours, rest
 
 
 # the keys of latent attention (DeepSeek-V2's MLA)
@@ -1321,6 +1425,14 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         if cfg.sink_window if window else cfg.sink_full:
             st["sink"] = 4.0 + jax.random.normal(next(ks), (n, Hq),
                                                  jnp.float32)
+        if cfg.qk_norm:
+            # one weight vector for all heads of q, one for k: sqrt(1.5) x
+            # U(0.6, 1.4), so that the scores' spread under 1 / sqrt(Dh) is
+            # about 1.5 (the normed q and k are of unit rms whatever wq and
+            # wk are) and a dropped norm shows in the logits
+            for w in ("ln_q", "ln_k"):
+                st[w] = math.sqrt(1.5) * jax.random.uniform(
+                    next(ks), (n, Dh), jnp.float32, 0.6, 1.4)
         stacks[name] = st
     nd = sum(not cfg.layer_routed(l) for l in range(L))
     nr = L - nd
@@ -1345,10 +1457,22 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             st.update(ws_g=mat(nr, D, D, Fs), ws_u=mat(nr, D, D, Fs),
                       ws_d=mat(nr, Fs, Fs, D, scale=res))
         stacks["routed"] = st
-    if cfg.has_state:
+    if cfg.has_conv:
+        stacks["conv"] = _init_conv(cfg, ks, mat, res)
+    elif cfg.has_state:
         stacks["mamba"] = _init_mamba(cfg, ks, mat, res)
+    # a head TIED to the embedding with no multiplier of the family's to
+    # set its size: rows of norm 1, so that the logits of the normed stream
+    # are of unit spread (rows of N(0, 1) give them a spread of sqrt(D), 45
+    # at 2048: a softmax that is an argmax, whose best token's
+    # log-probability is 0 whatever the weights are, and the comparison
+    # with the reference then sees nothing). The stream starts that small
+    # and is the layers' outputs from the first layer on
+    small = math.sqrt(D) if (cfg.tie_embeddings and cfg.embed_multiplier
+                             is None and cfg.logits_scaling is None) else 1.0
     params = {"embed": (jax.random.normal(next(ks), (V, D), jnp.float32)
-                        / (cfg.embed_multiplier or 1.0)).astype(cfg.dtype),
+                        / ((cfg.embed_multiplier or 1.0) * small)
+                        ).astype(cfg.dtype),
               STACKS: stacks,
               "final_norm": jnp.ones((D,), jnp.float32)}
     if not cfg.tie_embeddings:
@@ -1431,6 +1555,25 @@ def _init_mamba(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
     return st
 
 
+def _init_conv(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
+    """The gated short-convolution layers' stack: ``w_in`` [D, 3 D] = [B | C
+    | z] in the published order, the taps ``conv_w`` [K, D] U(-1 / sqrt(K),
+    1 / sqrt(K)) (PyTorch's Conv1d default at fan-in K; tap K - 1 meets the
+    token itself), ``w_out`` [D, D]. With unit-rms B, C, z the convolved
+    product is of rms 1 / sqrt(3) and so is y; ``w_out`` is scaled by sqrt(3)
+    beside the other projections into the stream, so that the branch is of
+    the size of the attention layers' and a lost tail or a dropped gate
+    shows in the logits."""
+    n = sum(k == 3 for k in cfg.layer_kinds)
+    D, K = cfg.hidden_size, cfg.conv_cache
+    b = 1.0 / math.sqrt(K)
+    return {"ln1": jnp.ones((n, D), jnp.float32),
+            "w_in": mat(n, D, D, 3 * D),
+            "conv_w": jax.random.uniform(next(ks), (n, K, D), jnp.float32,
+                                         -b, b).astype(cfg.dtype),
+            "w_out": mat(n, D, D, D, scale=res * math.sqrt(3.0))}
+
+
 def layer_stacks(params: Dict[str, Any], cfg: LlamaConfig, l: int):
     """-> (attention stack, index in it, feed-forward stack, index in it)
     of layer ``l``: the one ``layers`` tree at ``l`` twice for a model of
@@ -1441,7 +1584,7 @@ def layer_stacks(params: Dict[str, Any], cfg: LlamaConfig, l: int):
     kind, routed = cfg.layer_kinds[l], cfg.layer_routed(l)
     la = sum(k == kind for k in cfg.layer_kinds[:l])
     lf = sum(cfg.layer_routed(i) == routed for i in range(l))
-    return (st[("full", "window", "mamba")[kind]], la,
+    return (st[("full", "window", "mamba", "conv")[kind]], la,
             st["routed" if routed else "dense"], lf)
 
 
@@ -2288,6 +2431,9 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
                    "bias": lp["rbias"][l] if "rbias" in lp else None}
         if cfg.router_groups:
             law.update(groups=cfg.router_groups, scaling=cfg.routed_scaling)
+        if cfg.router_norm_eps:
+            law.update(norm_eps=cfg.router_norm_eps,
+                       scaling=cfg.routed_scaling)
         if cfg.shared_experts:
             law["shared"] = tuple(lp[k][l] for k in ("ws_g", "ws_u", "ws_d"))
         out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
@@ -2410,29 +2556,106 @@ def ssm_step(c: jax.Array, d: jax.Array, lp: Dict[str, Any], l,
     return y, state, tail
 
 
+# ---------------------------------------------------------------------------
+# The gated short convolution (LFM2, ``layer_kinds[l] == 3``)
+#
+# For the normed input h_t: [B | C | z] = W_in h_t; u_t = B * z; c_t = sum_k
+# w[k] * u_{t-K+1+k} (depthwise, causal, K = ``conv_cache`` taps, u before a
+# sequence's start = 0); y_t = C * c_t; out = W_out y_t. No activation: both
+# gates are plain products. Per LANE a layer keeps the last K - 1 rows of u
+# in the model's dtype (u is rounded to it once, before the taps, in a chunk
+# and in a step alike: the tail IS the window's earlier rows) and nothing
+# else: the ``state`` cache kind with a tail alone, one pool [conv layers,
+# lanes, (K - 1) x D], read and written inside :func:`_state_run`'s scan as
+# the state-space mixer's two are.
+# ---------------------------------------------------------------------------
+
+def conv_mix(bcz: jax.Array, lp: Dict[str, Any], l, tail: jax.Array,
+             gate: jax.Array, decode: bool):
+    """``bcz`` [B,T,3 D] the projected input, ``tail`` [B,K-1,D] what the
+    lane's previous tokens left (zeros at a sequence's start). ``gate``: a
+    chunk's ``n_valid`` [B] (the row's real tokens, the first of the chunk:
+    the new tail is the K - 1 rows before position ``n_valid``) or decode's
+    ``active`` [B] (a lane that is not keeps its tail bit for bit).
+    -> (y [B,T,D] in the input's dtype, tail)."""
+    T, D = bcz.shape[1], tail.shape[-1]
+    Bg, Cg, z = (bcz[..., i * D:(i + 1) * D].astype(jnp.float32)
+                 for i in range(3))
+    u = (Bg * z).astype(tail.dtype)
+    window = jnp.concatenate([tail, u], axis=1)               # [B,K-1+T,D]
+    w = lp["conv_w"][l].astype(jnp.float32)                   # [K, D]
+    c = sum(w[k] * window[:, k:k + T].astype(jnp.float32)
+            for k in range(w.shape[0]))
+    y = (Cg * c).astype(bcz.dtype)
+    if decode:
+        new = jnp.where(gate[:, None, None], window[:, 1:], tail)
+    else:
+        rows = gate[:, None] + jnp.arange(tail.shape[1])[None, :]
+        new = jnp.take_along_axis(window, rows[..., None], axis=1)
+    return y, new
+
+
 def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
-               l0: int, n: int, pools: Tuple[jax.Array, jax.Array],
+               l0: int, n: int, pools: Tuple[jax.Array, ...],
                lanes: Optional[jax.Array], reset: Optional[jax.Array],
-               gate: jax.Array, mesh=None):
-    """Layers ``l0 .. l0 + n - 1``, all state-space layers, as one scan
-    over x [B,T,D]: mixer and feed-forward of each, the layer's slice of
-    both pools read and written inside the body. ``lanes`` [B]: the pool
-    lane of each row (a prefill chunk; a row past the pool is dropped) or
-    None: row b IS lane b (decode, T == 1). ``reset`` [B] bool: the row's
-    sequence starts here, from a zero state. ``gate``: the chunk's
-    ``n_valid`` [B], or decode's ``active`` [B].
-    -> (x, (state_pool, conv_pool))."""
+               gate: jax.Array, mesh=None,
+               stats: Optional[Dict[str, Any]] = None):
+    """Layers ``l0 .. l0 + n - 1``, all of ONE mixer kind that keeps a state
+    a lane (state-space or gated short convolution) and ONE feed-forward
+    kind, as one scan over x [B,T,D]: mixer and feed-forward of each, the
+    layer's slice of the state pools read and written inside the body.
+    ``pools``: (state_pool, conv_pool) of a state-space model, (tail_pool,)
+    of a gated short convolution. ``lanes`` [B]: the pool lane of each row
+    (a prefill chunk; a row past the pool is dropped) or None: row b IS lane
+    b (decode, T == 1). ``reset`` [B] bool: the row's sequence starts here,
+    from a zero state. ``gate``: the chunk's ``n_valid`` [B], or decode's
+    ``active`` [B]. A routed feed-forward's experts hit, held assignments
+    and chosen ids leave the scan as its outputs and are added to ``stats``
+    as :func:`_ffn_block` adds them outside a scan.
+    -> (x, pools)."""
     st = params[STACKS]
-    mp, fp = st["mamba"], st["dense"]
-    m0 = sum(cfg.layer_state(i) for i in range(l0))
+    conv, routed = cfg.layer_kinds[l0] == 3, cfg.layer_routed(l0)
+    mp = st["conv" if conv else "mamba"]
+    fp = st["routed" if routed else "dense"]
+    m0 = sum(k == cfg.layer_kinds[l0] for k in cfg.layer_kinds[:l0])
+    f0 = sum(cfg.layer_routed(i) == routed for i in range(l0))
     I, Cd = cfg.ssm_inner, cfg.ssm_conv_dim
     decode = lanes is None
-    B = x.shape[0]
+    B, D = x.shape[0], cfg.hidden_size
+    # what a routed layer of the run adds to ``stats``, as scan outputs
+    want = []
+    if stats is not None and routed:
+        want = (["experts_hit"] + ["held"] * bool(cfg.router_experts)
+                + ["chosen"] * ("chosen" in stats))
 
-    def body(carry, i):
-        x, s_pool, c_pool = carry
+    def conv_body(x, c_pool, lm):
         with scope("ssm_in"):
-            lm, lf = m0 + i, l0 + i
+            h = _normed(x, mp["ln1"][lm], cfg)
+            bcz = jnp.einsum("btd,de->bte", h, mp["w_in"][lm])
+        # the scope holds the recurrence: the lane's tail in, both gates,
+        # the taps, the tail out; the two projections are outside it
+        with scope("ssm_step" if decode else "ssm_scan"):
+            if decode:
+                tail = jax.lax.dynamic_index_in_dim(
+                    c_pool, lm, keepdims=False).reshape(B, -1, D)
+            else:
+                tail = c_pool.at[lm, lanes].get(mode="clip").reshape(B, -1, D)
+                tail = jnp.where(reset[:, None, None], jnp.zeros_like(tail),
+                                 tail)
+            y, tail = conv_mix(bcz, mp, lm, tail, gate, decode)
+            if decode:
+                c_pool = jax.lax.dynamic_update_index_in_dim(
+                    c_pool, tail.reshape(B, -1), lm, 0)
+            else:
+                c_pool = c_pool.at[lm, lanes].set(tail.reshape(B, -1),
+                                                  mode="drop")
+        with scope("ssm_out"):
+            o = jnp.einsum("bti,id->btd", y, mp["w_out"][lm])
+            x = _residual(x, o, cfg)
+        return x, (c_pool,)
+
+    def ssm_body(x, s_pool, c_pool, lm):
+        with scope("ssm_in"):
             h = _normed(x, mp["ln1"][lm], cfg)
             zc = jnp.einsum("btd,de->bte", h, mp["w_in"][lm])
             z, c = zc[..., :I], zc[..., I:]
@@ -2473,23 +2696,46 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
         with scope("ssm_out"):
             o = jnp.einsum("bti,id->btd", g, mp["w_out"][lm])
             x = _residual(x, o, cfg)
-        x = _ffn_block(x, fp, lf, cfg, mesh=mesh)
-        return (x, s_pool, c_pool), None
+        return x, (s_pool, c_pool)
+
+    def body(carry, i):
+        x, *pools = carry
+        with scope("ssm_in"):
+            lm, lf = m0 + i, f0 + i
+        x, pools = (conv_body if conv else ssm_body)(x, *pools, lm)
+        seen: Optional[Dict[str, Any]] = None
+        if want:
+            seen = {"chosen": []} if "chosen" in want else {}
+        x = _ffn_block(x, fp, lf, cfg, mesh=mesh, stats=seen)
+        out = None
+        if want:
+            out = tuple(seen["chosen"][0] if c == "chosen" else seen[c]
+                        for c in want)
+        return (x, *pools), out
 
     with scope("ssm_in"):
         layers = jnp.arange(n)
-    (x, *pools), _ = jax.lax.scan(body, (x, *pools), layers)
+    (x, *pools), out = jax.lax.scan(body, (x, *pools), layers)
+    for c, v in zip(want, out or ()):
+        if c == "chosen":
+            stats["chosen"].extend(v[i] for i in range(n))
+        else:
+            with scope("moe_ffn"):
+                stats[c] = stats.get(c, 0) + jnp.sum(v)
     return x, tuple(pools)
 
 
 def _segments(cfg: LlamaConfig):
-    """-> [(first layer, layers)]: a run of state-space layers is one
-    segment (one scan), every other layer a segment of its own."""
+    """-> [(first layer, layers)]: a run of layers that keep a state a lane,
+    of one mixer kind and one feed-forward kind, is one segment (one scan),
+    every other layer a segment of its own."""
     out, l = [], 0
     while l < cfg.num_layers:
         n = 1
         if cfg.layer_state(l):
-            while l + n < cfg.num_layers and cfg.layer_state(l + n):
+            same = (cfg.layer_kinds[l], cfg.layer_routed(l))
+            while l + n < cfg.num_layers and (
+                    cfg.layer_kinds[l + n], cfg.layer_routed(l + n)) == same:
                 n += 1
         out.append((l, n))
         l += n
@@ -2711,7 +2957,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     for l, n in _segments(cfg):
         if cfg.layer_state(l):
             x, s_pools = _state_run(x, params, cfg, l, n, s_pools, s_lanes,
-                                    s_reset, s_valid, mesh)
+                                    s_reset, s_valid, mesh, stats)
             continue
         lp, la, *ffn = layer_stacks(params, cfg, l)
         sl = cfg.layer_sliding(l)
@@ -3204,7 +3450,7 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     for l, n in _segments(cfg):
         if cfg.layer_state(l):
             x, s_pools = _state_run(x, params, cfg, l, n, s_pools, None,
-                                    None, s_active, mesh)
+                                    None, s_active, mesh, stats)
             continue
         lp, la, *ffn = layer_stacks(params, cfg, l)
         sl = cfg.layer_sliding(l)

@@ -70,10 +70,37 @@ def sorted_wins(rows: int, top_k: int, n_experts: int,
     (387, all 40) 2.92 / 6.92: sorted pays 0.07 ms an expert hit, the rule
     (sorted under 40 expected assignments: 26 rows) is right at every row
     count that cell's programs have but the 32-row chunk bucket, where it
-    says dense and sorted is a fifth faster."""
+    says dense and sorted is a fifth faster. And for 64 experts of 2048 x
+    1536, 4 a token, no share: ``n_experts >= 16 x top_k`` holds with
+    equality, so the rule says sorted under 16 rows, dense from 16 to 512,
+    sorted past 512 (my chip run, PR 44; ms a layer dense / sorted): 8 rows
+    (24.6 experts hit) 1.62 / 0.72; 16 rows (40.6) 1.62 / 1.20; 32 rows
+    (57.0) 1.62 / 1.83; 256 rows 1.75 / 4.01; 512 rows 3.27 / 4.24; 1,024
+    rows 6.50 / 4.82. Sorted pays 0.03 ms an expert hit; dense streams the
+    1.2 GB of a layer's experts in 1.62 ms (745 GB/s) and turns
+    compute-bound between 256 and 512 rows (1.24 TFLOP a layer at 512: 190
+    TFLOP/s). The rule is on the measured side at every row count but 16
+    itself, where it says dense and sorted is a quarter faster: a decode
+    program of 32 rows is dense, chunks of 32 to 512 rows dense, a chunk of
+    1,024 sorted. (A decode program routes every row it has, busy lane or
+    not: what sorted would save at a low occupancy needs the idle rows
+    masked out of the dispatch first, ROADMAP Reach A2.)"""
     if n_experts >= 16 * top_k * share and rows <= 512:
         return rows * top_k * share < n_experts
     return rows >= 16
+
+
+def dispatch_form(rows: int, top_k: int, n_experts: int, share: float = 1.0,
+                  mesh=None, width: int = 0) -> str:
+    """``sorted`` or ``dense``: the form :func:`moe_ffn` gives a call of
+    ``rows`` rows (experts ``width`` wide) on ``mesh``: :func:`sorted_wins`
+    on an unsharded mesh, dense where the experts or their width are
+    sharded. The engine reports it for its programs
+    (``dyn_engine_info{moe_dispatch}``)."""
+    tp = _tp_size(mesh)
+    sharded = _ep_size(mesh) > 1 or (tp > 1 and width % tp == 0)
+    return ("sorted" if not sharded and sorted_wins(rows, top_k, n_experts,
+                                                    share) else "dense")
 
 
 def _ep_size(mesh) -> int:
@@ -142,14 +169,15 @@ def _sorted_dispatch(x: jax.Array,            # [B, T, D]
 def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
                router: str = "softmax", bias: Optional[jax.Array] = None,
                groups: Optional[Tuple[int, int]] = None,
-               scaling: float = 1.0):
+               scaling: float = 1.0, norm_eps: float = 0.0):
     """Router: top-k gate values + expert ids ([B,T,K] each).
     Shared by every dispatch formulation (incl. forward_pp's in-stage MoE)
     so the gating policy has exactly one implementation. Three laws:
     ``softmax`` (top-k of the softmax over all experts, renormalised),
     ``sigmoid_bias`` (sigmoid scores; the k largest of score + ``bias`` [E],
     the learned selection bias, are chosen; the gates are the chosen SCORES
-    over their sum: the bias chooses and never weighs) and ``softmax_group``
+    over their sum + ``norm_eps`` (LFM2's 1e-6), x ``scaling``: the bias
+    chooses and never weighs) and ``softmax_group``
     (softmax scores; the experts lie in ``groups[0]`` equal groups, a group
     scores as its best expert, the ``groups[1]`` best groups stay and the
     top-k is taken among their experts; the gates are the chosen scores x
@@ -178,7 +206,11 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
         vals, idx = jax.lax.top_k(probs, top_k)           # [B,T,K]
     else:
         raise ValueError(f"no router law {router!r}")
-    return vals / jnp.sum(vals, axis=-1, keepdims=True), idx
+    total = jnp.sum(vals, axis=-1, keepdims=True)
+    if norm_eps:
+        total = total + norm_eps
+    vals = vals / total
+    return (vals if scaling == 1.0 else vals * scaling), idx
 
 
 def dense_gates(vals: jax.Array, idx: jax.Array, n_experts: int) -> jax.Array:
@@ -212,7 +244,8 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
             first: Optional[int] = None,
             groups: Optional[Tuple[int, int]] = None,
             scaling: float = 1.0,
-            shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None):
+            shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+            norm_eps: float = 0.0):
     """Routed MoE feed-forward (expert width F is the weights' own: a model
     whose experts are not ``intermediate_size`` wide needs nothing here).
     With ``layer``, ``wg`` / ``wu`` / ``wd`` are the stacked [L, E, ...]
@@ -236,7 +269,8 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
     too: every chip of the deployment computes it alike, and it counts once
     when the shares are added up)."""
     with jax.named_scope("dynamo.moe_ffn"):
-        vals, idx = route_topk(x, wr, top_k, router, bias, groups, scaling)
+        vals, idx = route_topk(x, wr, top_k, router, bias, groups, scaling,
+                               norm_eps)
         if first is not None:
             E = wg.shape[-3]
             held = (idx >= first) & (idx < first + E)
@@ -288,11 +322,10 @@ def _dispatch(x, wg, wu, wd, vals, idx, mesh, layer=None, share=1.0):
     tp = _tp_size(mesh)
     F = wg.shape[-1]
     tp_ffn = tp if tp > 1 and F % tp == 0 else 1
-    if ep <= 1 and tp_ffn <= 1:
-        B, T, _ = x.shape
-        if sorted_wins(B * T, idx.shape[-1], E, share):
-            return _sorted_dispatch(x, wg, wu, wd, vals, idx, layer,
-                                    absent=share < 1.0)
+    B, T, _ = x.shape
+    if dispatch_form(B * T, idx.shape[-1], E, share, mesh, F) == "sorted":
+        return _sorted_dispatch(x, wg, wu, wd, vals, idx, layer,
+                                absent=share < 1.0)
     if layer is not None:
         # a layer's slice of the stacked tensor is free for an einsum
         wg, wu, wd = wg[layer], wu[layer], wd[layer]

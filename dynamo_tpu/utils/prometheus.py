@@ -476,10 +476,13 @@ class StageMetrics:
             "paths, paged-kernel variant, device platform/kind/count, peak "
             "source, cache kinds (name:layers x kv heads x (K + V row), "
             "+ joined), what writes a decode step's new K/V rows (kernel | "
-            "scatter, or kind:how comma-joined where the kinds differ)",
+            "scatter, or kind:how comma-joined where the kinds differ), the "
+            "form the routed experts' dispatch takes in the decode and the "
+            "chunk programs (dense: every expert's weights are read; "
+            "sorted: those of the experts hit; none for a dense model)",
             ("worker", "attn_impl", "decode_attn_impl", "paged_kernel",
              "platform", "device_kind", "devices", "peak_source",
-             "cache_kinds", "decode_kv_write"))
+             "cache_kinds", "decode_kv_write", "moe_dispatch"))
         self.device_peak_bytes = r.gauge(
             "dyn_device_peak_bytes_in_use",
             "Peak device memory in use per engine device "
@@ -567,6 +570,12 @@ class StageMetrics:
             "dyn_moe_experts_hit_total",
             "Experts with at least one row, per layer and step, summed "
             "on the device", ("kind",))
+        self.moe_layer_calls = r.counter(
+            "dyn_moe_layer_calls_total",
+            "Calls of a routed layer by the dispatches: routed layers x "
+            "steps of a decode dispatch, x 1 of a chunk (experts hit over "
+            "this x the experts is the share of the expert weights a call "
+            "touched)", ("kind",))
         self.sparse_attn_context = r.counter(
             "dyn_sparse_attn_context_tokens_total",
             "Keys visible to each query of an indexer model, summed over "
@@ -595,8 +604,9 @@ class StageMetrics:
             "slot, or re-prefilled after preemption)", ())
         self.ssm_state_bytes = r.gauge(
             "dyn_ssm_state_bytes",
-            "Bytes of the per-lane state pool and convolution-tail pool, "
-            "all state-space layers and lanes", ())
+            "Bytes of the per-lane state pool and convolution-tail pool "
+            "(a gated short convolution: the tail pool alone), all such "
+            "layers and lanes", ())
         # the paged decode kernel (ops/attention.py, the dma variant): pages
         # of one pool, summed over the attention layers of a kind
         self.attn_pages_live = r.counter(

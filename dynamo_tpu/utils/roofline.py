@@ -333,30 +333,51 @@ def _latent_costs(m: Any, weight_bytes: Optional[float],
 
 def _state_costs(m: Any, weight_bytes: Optional[float],
                  esize: int) -> ModelCosts:
-    """:class:`ModelCosts` of a model with state-space layers: K/V and
-    attention FLOPs of its attention layers alone; the mixers' two
-    projections and every layer's feed-forward among the matrix FLOPs; the
-    recurrence (state update and read-out: 4 FLOPs a state element a token)
-    and the per-lane state bytes by ``CacheKind``'s description."""
+    """:class:`ModelCosts` of a model with state-space or gated
+    short-convolution layers: K/V and attention FLOPs of its attention
+    layers alone; the mixers' two projections and every layer's
+    feed-forward (dense, or a token's routed experts and the router) among
+    the matrix FLOPs; the recurrence (a state-space mixer: state update and
+    read-out, 4 FLOPs a state element a token; a short convolution: two
+    gates and the taps, 8 a channel at 3 taps) and the per-lane state bytes
+    by ``CacheKind``'s description (a tail alone for the latter)."""
     from ..engine.cache import cache_kinds
 
     D, V, Hq, Dh = m.hidden_size, m.vocab_size, m.num_heads, m.head_dim
     glob, state = cache_kinds(m)
     attn = D * Hq * Dh + 2 * D * glob.kv_heads * Dh + Hq * Dh * D
-    I, Cd = m.ssm_inner, m.ssm_conv_dim
-    mix = D * (I + Cd + m.ssm_heads) + I * D
-    mat = (glob.layers * attn + state.layers * mix
-           + m.num_layers * 3 * D * m.intermediate_size)
+    if getattr(m, "has_conv", False):
+        # a gated short convolution: [B | C | z] in, W_out back, the taps;
+        # the recurrence is two gates and ``conv_cache`` multiply-adds a
+        # channel a token, and a lane holds its tail alone
+        mix = D * 3 * D + D * D + m.conv_cache * D
+        rec = (2.0 + 2.0 * m.conv_cache) * state.layers * D
+    else:
+        I, Cd = m.ssm_inner, m.ssm_conv_dim
+        mix = D * (I + Cd + m.ssm_heads) + I * D
+        rec = 4.0 * state.layers * I * m.ssm_state
+    # the feed-forward by its kind: a token computes its assignments'
+    # experts and the router; the weights hold every expert
+    dense = 3 * D * m.intermediate_size
+    routed = m.routed_layers
+    Fe = m.expert_width
+    ffn_active = ((m.num_layers - routed) * dense + routed * (
+        m.experts_per_token * 3 * D * Fe + D * m.num_experts))
+    ffn_held = ((m.num_layers - routed) * dense + routed * m.num_experts * (
+        3 * D * Fe + D))
+    mat = glob.layers * attn + state.layers * mix
     if weight_bytes is None:
-        weight_bytes = (mat + V * D * (1 if m.tie_embeddings else 2)) * esize
+        weight_bytes = (mat + ffn_held
+                        + V * D * (1 if m.tie_embeddings else 2)) * esize
     return ModelCosts(
-        mat_flops_per_token=2.0 * mat, lm_head_flops=2.0 * D * V,
+        mat_flops_per_token=2.0 * (mat + ffn_active),
+        lm_head_flops=2.0 * D * V,
         attn_flops_coef=4.0 * Hq * Dh,
         kv_bytes_per_tok_layer=float(glob.token_bytes(esize) // glob.layers),
         num_layers=glob.layers, window_groups=((None, glob.layers),),
         weight_bytes=float(weight_bytes),
         state_bytes_per_lane=float(state.lane_bytes(esize)),
-        state_flops_per_token=4.0 * state.layers * I * m.ssm_state)
+        state_flops_per_token=rec)
 
 
 def _clamped_len_sum(groups: Sequence[Tuple[Optional[int], int]],

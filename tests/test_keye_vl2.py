@@ -153,7 +153,12 @@ def test_a_capture_counts_its_own_dispatches_a_second_time(core):
               st.sparse_attn_selected)
     read = lambda: {(c.name, k[0]): v for c in series
                     for k, v in c._values.items()}
-    seen = lambda: dict(st.profile_captured_work._values)
+    # (the series is the process's: what earlier tests of this worker left
+    # there is the baseline)
+    base = dict(st.profile_captured_work._values)
+    seen = lambda: {k: v - base.get(k, 0.0)
+                    for k, v in st.profile_captured_work._values.items()
+                    if v != base.get(k, 0.0)}
     generate(core, "cap0", prompt_of(21, 7), 3)
     assert not seen()
     before = read()
