@@ -925,7 +925,8 @@ class EngineCore:
     def _moe_dispatch_forms(self) -> str:
         """What the routed experts' dispatch is in this engine's programs
         (``moe.dispatch_form``, the rule ``moe_ffn`` itself asks), as
-        ``dyn_engine_info{moe_dispatch}`` shows it: ``decode:<form>`` and
+        ``dyn_engine_info{moe_dispatch}`` shows it: ``decode:<form>``
+        (``by_hit``: the program holds both forms) and
         ``chunk:<form>`` with the rows of the chunk programs that take it
         (``chunk:dense32-512,sorted1024-1024``); ``none`` for a dense model.
         Reported, never used to select."""
@@ -934,18 +935,38 @@ class EngineCore:
             return "none"
         if self.cfg.pp > 1:
             return "decode:dense,chunk:dense"     # moe_ffn_in_stage
-        from ..models.moe import dispatch_form
-        R = m.router_experts or m.num_experts
-
-        def form(rows: int) -> str:
-            return dispatch_form(rows, m.experts_per_token, m.num_experts,
-                                 m.num_experts / R, self.mesh, m.expert_width)
-
         rows = sorted({b * c for b in self.b_buckets for c in self.c_buckets})
-        runs = [(f, list(g)) for f, g in itertools.groupby(rows, key=form)]
+        runs = [(f, list(g)) for f, g in itertools.groupby(
+            rows, key=self._moe_form)]
         chunk = (runs[0][0] if len(runs) == 1 else ",".join(
             f"{f}{g[0]}-{g[-1]}" for f, g in runs))
-        return f"decode:{form(self.cfg.max_batch)},chunk:{chunk}"
+        return f"decode:{self._decode_moe_form},chunk:{chunk}"
+
+    def _moe_form(self, rows: int, masked: bool = False) -> str:
+        """``moe.dispatch_form`` of a call of ``rows`` rows of this model on
+        this mesh (``masked``: a decode step, which knows its busy rows)."""
+        from ..models.moe import dispatch_form
+        m = self.cfg.model
+        return dispatch_form(rows, m.experts_per_token, m.num_experts,
+                             self._moe_share, self.mesh, m.expert_width,
+                             masked=masked)
+
+    @property
+    def _moe_share(self) -> float:
+        """The part of the router's experts this chip holds."""
+        m = self.cfg.model
+        return m.num_experts / (m.router_experts or m.num_experts)
+
+    @cached_property
+    def _decode_moe_form(self) -> Optional[str]:
+        """The routed experts' dispatch in the decode programs: ``sorted``,
+        ``dense``, or ``by_hit`` (both forms, chosen each call on the device
+        from the experts the busy rows hit; its calls that went sorted ride
+        a ``sorted`` column of ``packed``). None: no routed call that knows
+        its busy rows (a dense model, pp > 1)."""
+        if not self.cfg.model.num_experts or self.cfg.pp > 1:
+            return None
+        return self._moe_form(self.cfg.max_batch, masked=True)
 
     def _state_pools(self) -> Dict[str, Any]:
         """The state pools this model has, by their program operand: both
@@ -980,6 +1001,22 @@ class EngineCore:
         """The columns of ``packed`` behind token and log-probability
         (:meth:`_program_extras`), as every fetch reads them."""
         return self._program_extras()[2]
+
+    @cached_property
+    def _decode_routes_busy(self) -> bool:
+        """Whether a decode step's routed calls dispatch and count their
+        busy rows alone (``moe.heeds_active``)."""
+        from ..models.moe import heeds_active
+        form = self._decode_moe_form
+        return form is not None and heeds_active(form, self._moe_share)
+
+    @cached_property
+    def _decode_cols(self) -> Tuple[str, ...]:
+        """The columns of a DECODE program's ``packed``: a program that
+        holds both dispatch forms adds ``sorted``, the routed layers of the
+        step that took the sorted one."""
+        return self._packed_cols + (
+            ("sorted",) if self._decode_moe_form == "by_hit" else ())
 
     def _take_pools(self, pools) -> None:
         """(k_pool, v_pool[, i_pool | wk_pool, wv_pool | s_pool, c_pool])
@@ -1097,7 +1134,8 @@ class EngineCore:
                           captured: bool = False, S: int = 0,
                           held: Optional[float] = None,
                           key_blocks: Optional[Tuple[int, int]] = None,
-                          steps: int = 1) -> None:
+                          steps: int = 1,
+                          sorted_calls: Optional[float] = None) -> None:
         """Host counters of what a dispatch made the experts and the
         indexer do. ``spans``: (first position, queries) per lane; a query
         at position p sees p + 1 keys. ``hit``: experts hit, read from the
@@ -1116,7 +1154,9 @@ class EngineCore:
         ``dyn_moe_routed_assignments_total`` counts all of them.
         ``key_blocks``: :meth:`_latent_key_blocks` of a chunk dispatch whose
         attention is the latent flash call. ``steps``: the steps of a decode
-        dispatch (``dyn_moe_layer_calls_total``)."""
+        dispatch (``dyn_moe_layer_calls_total``); ``sorted_calls``: those of
+        its routed-layer calls that were dispatched sorted
+        (``dyn_moe_sorted_calls_total``)."""
         m = self.cfg.model
         if not (m.num_experts or m.has_indexer):
             return
@@ -1134,6 +1174,8 @@ class EngineCore:
                 # each step of a decode dispatch, once of a chunk
                 work[self.stage.moe_layer_calls] = float(
                     m.routed_layers * steps)
+                if sorted_calls is not None:
+                    work[self.stage.moe_sorted_calls] = float(sorted_calls)
         if m.has_indexer:
             k = m.index_topk
             seen = sel = 0
@@ -1322,7 +1364,8 @@ class EngineCore:
             # equivalent-but-differently-spec'd sharding and every *other*
             # bucket program compiles a second variant against it
             B = self.cfg.max_batch
-            jit_kw, out_tail, hit_col = self._program_extras()
+            jit_kw, out_tail, _ = self._program_extras()
+            hit_col = self._decode_cols
             windowed, stateful = cfg.model.has_window, cfg.model.has_state
 
             @partial(jax.jit, donate_argnums=(2, 3, 10), **jit_kw,
@@ -1358,7 +1401,7 @@ class EngineCore:
                         logits, k_pool, v_pool, *ip = llama.forward_decode(
                             params, cfg.model, tokens, k_pool, v_pool,
                             page_tables, lengths, attn_impl=impl, mesh=mesh,
-                            stats=stats,
+                            stats=stats, active=active,
                             **({"win": (*ip, w_tables)} if windowed
                                # a lane this dispatch does not serve keeps
                                # its state: it cannot be trimmed afterwards
@@ -3237,14 +3280,21 @@ class EngineCore:
         packed_np = np.asarray(rec["packed"])     # [N, B, 2] — ONE fetch
         self.phase.to("emit")
         N = packed_np.shape[0]
-        cols = self._packed_cols
+        cols = self._decode_cols
+        col = lambda c: packed_np[:, 0, 2 + cols.index(c)].sum()
+        form = self._decode_moe_form
         self._count_model_work(
             "decode", [(s0 - 1, N) for s0 in rec["lengths"]],
-            packed_np[:, 0, 2].sum() if "experts_hit" in cols else None,
+            col("experts_hit") if "experts_hit" in cols else None,
             rec.get("captured", False), rec.get("S", 0),
-            held=(packed_np[:, 0, 2 + cols.index("held")].sum()
-                  * len(rec["lengths"]) / packed_np.shape[1]
-                  if "held" in cols else None), steps=N)
+            # (a program that routes its busy rows alone counts theirs
+            # alone; a share dispatched dense counts every row's)
+            held=(col("held") * (1.0 if self._decode_routes_busy else len(
+                rec["lengths"]) / packed_np.shape[1])
+                  if "held" in cols else None), steps=N,
+            sorted_calls=(col("sorted") if form == "by_hit" else
+                          self.cfg.model.routed_layers * N * (
+                              form == "sorted")))
         if self.win is not None:
             steps = self.stage.kv_resident_token_steps
             for (_, slot, _), s0 in zip(rec["active"], rec["lengths"]):

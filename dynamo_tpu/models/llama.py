@@ -2367,9 +2367,11 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
               cfg: LlamaConfig, mesh=None,
               stats: Optional[Dict[str, Any]] = None,
               inside: Optional[Dict[str, int]] = None,
-              ffn: Optional[Tuple[Dict[str, Any], Any]] = None) -> jax.Array:
+              ffn: Optional[Tuple[Dict[str, Any], Any]] = None,
+              active: Optional[jax.Array] = None) -> jax.Array:
     """The layer after attention: out-projection of ``attn`` [B,T,Hq,Dh] and
-    residual (Gemma2 norms the branch output first), then the feed-forward.
+    residual (Gemma2 norms the branch output first), then the feed-forward
+    (``active``: :func:`_ffn_block`'s).
 
     ``inside``: a caller that is ALREADY inside manual SPMD (``forward_pp``'s
     stage body; shard_maps do not nest) names the mesh axes it is inside of
@@ -2388,7 +2390,7 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
     if cfg.sandwich_norms:
         o = rms_norm(o, lp["ln1_post"][l], cfg.rms_eps, cfg.norm_offset)
     return _ffn_block(_residual(x, o, cfg), *(ffn or (lp, l)), cfg,
-                      mesh=mesh, stats=stats, inside=inside)
+                      mesh=mesh, stats=stats, inside=inside, active=active)
 
 
 def _residual(x: jax.Array, branch: jax.Array, cfg: LlamaConfig) -> jax.Array:
@@ -2403,11 +2405,15 @@ def _residual(x: jax.Array, branch: jax.Array, cfg: LlamaConfig) -> jax.Array:
 @scope("ffn")
 def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
                mesh=None, stats: Optional[Dict[str, Any]] = None,
-               inside: Optional[Dict[str, int]] = None) -> jax.Array:
+               inside: Optional[Dict[str, int]] = None,
+               active: Optional[jax.Array] = None) -> jax.Array:
     """Pre-norm FFN (dense or MoE) + residual; Gemma2 adds a post-norm on
     the branch output (sandwich norms). A routed layer adds its experts hit
     to ``stats["experts_hit"]`` (see :func:`forward`). ``inside`` as
-    :func:`layer_out`'s."""
+    :func:`layer_out`'s. ``active`` [B] bool (a decode step: the rows the
+    dispatch serves): a routed layer dispatches the busy rows' assignments
+    alone and counts theirs alone (``moe.moe_ffn``), and adds 1 to
+    ``stats["sorted"]`` if it was dispatched sorted."""
     h2 = _normed(x, lp["ln2"][l], cfg)
     routed = cfg.num_experts and "wr" in lp   # a per-kind model's dense layers
     if routed and inside is not None:
@@ -2437,7 +2443,8 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         if cfg.shared_experts:
             law["shared"] = tuple(lp[k][l] for k in ("ws_g", "ws_u", "ws_d"))
         out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
-                           cfg.experts_per_token, mesh=mesh, layer=l, **law)
+                           cfg.experts_per_token, mesh=mesh, layer=l,
+                           active=active, stats=stats, **law)
         if stats is not None:
             if cfg.router_experts:
                 # (experts hit, assignments to held experts) of this call
@@ -2609,9 +2616,10 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
     (a prefill chunk; a row past the pool is dropped) or None: row b IS lane
     b (decode, T == 1). ``reset`` [B] bool: the row's sequence starts here,
     from a zero state. ``gate``: the chunk's ``n_valid`` [B], or decode's
-    ``active`` [B]. A routed feed-forward's experts hit, held assignments
-    and chosen ids leave the scan as its outputs and are added to ``stats``
-    as :func:`_ffn_block` adds them outside a scan.
+    ``active`` [B], which a routed feed-forward takes too. Its experts
+    hit, held assignments, sorted calls (decode) and chosen ids leave the
+    scan as its outputs and are added to ``stats`` as :func:`_ffn_block`
+    adds them outside a scan.
     -> (x, pools)."""
     st = params[STACKS]
     conv, routed = cfg.layer_kinds[l0] == 3, cfg.layer_routed(l0)
@@ -2626,7 +2634,7 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
     want = []
     if stats is not None and routed:
         want = (["experts_hit"] + ["held"] * bool(cfg.router_experts)
-                + ["chosen"] * ("chosen" in stats))
+                + ["sorted"] * decode + ["chosen"] * ("chosen" in stats))
 
     def conv_body(x, c_pool, lm):
         with scope("ssm_in"):
@@ -2706,11 +2714,12 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
         seen: Optional[Dict[str, Any]] = None
         if want:
             seen = {"chosen": []} if "chosen" in want else {}
-        x = _ffn_block(x, fp, lf, cfg, mesh=mesh, stats=seen)
+        x = _ffn_block(x, fp, lf, cfg, mesh=mesh, stats=seen,
+                       active=gate if decode else None)
         out = None
         if want:
-            out = tuple(seen["chosen"][0] if c == "chosen" else seen[c]
-                        for c in want)
+            out = tuple(seen["chosen"][0] if c == "chosen"
+                        else jnp.asarray(seen[c], jnp.int32) for c in want)
         return (x, *pools), out
 
     with scope("ssm_in"):
@@ -3339,6 +3348,7 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                    stats: Optional[Dict[str, Any]] = None,
                    win: Optional[Tuple[jax.Array, ...]] = None,
                    ssm: Optional[Tuple[jax.Array, ...]] = None,
+                   active: Optional[jax.Array] = None,
                    ) -> Tuple[jax.Array, ...]:
     """Single-token decode step addressed purely by page tables.
 
@@ -3365,6 +3375,13 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     its state and convolution tail bit for bit: unlike K/V written past a
     sequence's end, an advanced state cannot be trimmed afterwards. Both
     pools come back last.
+
+    ``active`` [B] bool, the same mask for any model (a model with state
+    layers may leave it to ``ssm``'s): a routed feed-forward dispatches the
+    active rows' assignments alone, counts theirs alone in ``stats``
+    (``experts_hit``, ``held``) and adds the routed layers it dispatched
+    sorted to ``stats["sorted"]`` (:func:`_ffn_block`). An idle row's
+    logits are never read.
     """
     fold = cfg.kv_fold
     page = k_pool.shape[3] * fold
@@ -3392,6 +3409,8 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         if ssm is None:
             raise ValueError(f"forward_decode: {NO_STATE}")
         *s_pools, s_active = ssm
+        if active is None:
+            active = s_active
     if cfg.has_window:
         if win is None:
             raise ValueError(f"forward_decode: {NO_SECOND_CACHE}")
@@ -3496,6 +3515,6 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         else:
             pools = kv
         x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
-                      ffn=ffn if cfg.per_kind else None)
+                      ffn=ffn if cfg.per_kind else None, active=active)
 
     return (_lm_head(x, params, cfg), *pools, *w_pools, *s_pools)

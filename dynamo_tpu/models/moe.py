@@ -23,7 +23,7 @@ engine owns it, so this module IS the capability.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -82,25 +82,68 @@ def sorted_wins(rows: int, top_k: int, n_experts: int,
     TFLOP/s). The rule is on the measured side at every row count but 16
     itself, where it says dense and sorted is a quarter faster: a decode
     program of 32 rows is dense, chunks of 32 to 512 rows dense, a chunk of
-    1,024 sorted. (A decode program routes every row it has, busy lane or
-    not: what sorted would save at a low occupancy needs the idle rows
-    masked out of the dispatch first, ROADMAP Reach A2.)"""
+    1,024 sorted.
+
+    A DECODE step knows which of its rows are busy (``moe_ffn(active=)``):
+    an idle row's assignments are absent ones, and where this rule says
+    dense with every expert held the call holds both forms and takes the
+    sorted one while its busy rows hit fewer than
+    :data:`SORTED_UNDER_HIT_SHARE` of the experts (``dispatch_form``
+    ``by_hit``). Measured at the decode program's own shape: 32 rows of
+    which b are busy and the rest absent, 64 experts of 2048 x 1536, 4 a
+    token, the stacked ``layer=`` form inside one ``lax.scan`` (my chip
+    run, PR 45, ``scripts/moe_by_hit.py``; ms a layer sorted, experts hit
+    a layer; dense 1.617 at every b): b = 1 0.179 (4.0 hit); 2 0.285 (7.5);
+    4 0.472 (13.7); 6 0.649 (19.5); 8 0.786 (24.0); 10 0.952 (29.5); 12
+    1.084 (33.8); 16 1.301 (41.0); 20 1.489 (47.2); 24 1.631 (51.8); 28
+    1.741 (55.5); 32 1.808 (57.7). Sorted costs 0.06 ms + 0.030 ms an
+    expert hit, whatever rows are absent behind them (8 busy of 32 rows
+    0.786 where an 8-row program read 0.72), and crosses dense at 51 experts
+    hit; the choice is taken at 48 (three quarters: 6 % under dense there, 1
+    % over it at 52), and the ``lax.cond`` itself costs 0.005 ms a layer
+    (by_hit 0.791 at b = 8, 1.620 at b = 32). A chip's SHARE that this rule
+    sends dense keeps its program as it is (its crossing is another one,
+    0.10-0.15 ms an expert hit above: ROADMAP ``held-experts-hit``)."""
     if n_experts >= 16 * top_k * share and rows <= 512:
         return rows * top_k * share < n_experts
     return rows >= 16
 
 
+# Where a ``by_hit`` call crosses from sorted to dense, as a share of the
+# experts (the readings: :func:`sorted_wins`).
+SORTED_UNDER_HIT_SHARE = 0.75
+
+
 def dispatch_form(rows: int, top_k: int, n_experts: int, share: float = 1.0,
-                  mesh=None, width: int = 0) -> str:
-    """``sorted`` or ``dense``: the form :func:`moe_ffn` gives a call of
-    ``rows`` rows (experts ``width`` wide) on ``mesh``: :func:`sorted_wins`
-    on an unsharded mesh, dense where the experts or their width are
-    sharded. The engine reports it for its programs
+                  mesh=None, width: int = 0, masked: bool = False) -> str:
+    """``sorted``, ``dense`` or ``by_hit``: the form :func:`moe_ffn` gives a
+    call of ``rows`` rows (experts ``width`` wide) on ``mesh``:
+    :func:`sorted_wins` on an unsharded mesh, dense where the experts or
+    their width are sharded. ``masked``: the call knows which of its rows
+    are busy (a decode step); where the rule would send such a call dense
+    with every expert held, it holds BOTH forms and chooses on the device
+    from the experts its busy rows hit (``by_hit``: sorted under
+    :func:`sorted_under`). The engine reports it for its programs
     (``dyn_engine_info{moe_dispatch}``)."""
     tp = _tp_size(mesh)
-    sharded = _ep_size(mesh) > 1 or (tp > 1 and width % tp == 0)
-    return ("sorted" if not sharded and sorted_wins(rows, top_k, n_experts,
-                                                    share) else "dense")
+    if _ep_size(mesh) > 1 or (tp > 1 and width % tp == 0):
+        return "dense"
+    if sorted_wins(rows, top_k, n_experts, share):
+        return "sorted"
+    return "by_hit" if masked and share == 1.0 else "dense"
+
+
+def heeds_active(form: str, share: float) -> bool:
+    """Whether a decode call of ``form`` drops its idle rows' assignments:
+    every call but a chip's share dispatched dense (:func:`moe_ffn`)."""
+    return not (share < 1.0 and form == "dense")
+
+
+def sorted_under(n_experts: int) -> int:
+    """The experts-hit count under which a ``by_hit`` call goes sorted
+    (:data:`SORTED_UNDER_HIT_SHARE` of the experts, at least 1: a call whose
+    rows are all idle hits none and reads none)."""
+    return max(1, int(n_experts * SORTED_UNDER_HIT_SHARE))
 
 
 def _ep_size(mesh) -> int:
@@ -245,7 +288,9 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
             groups: Optional[Tuple[int, int]] = None,
             scaling: float = 1.0,
             shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
-            norm_eps: float = 0.0):
+            norm_eps: float = 0.0,
+            active: Optional[jax.Array] = None,
+            stats: Optional[Dict[str, Any]] = None):
     """Routed MoE feed-forward (expert width F is the weights' own: a model
     whose experts are not ``intermediate_size`` wide needs nothing here).
     With ``layer``, ``wg`` / ``wu`` / ``wd`` are the stacked [L, E, ...]
@@ -267,24 +312,50 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
     ``shared`` = (wg [D, Fs], wu [D, Fs], wd [Fs, D]): an expert EVERY token
     passes through, added to the routed sum whole (under a chip's share
     too: every chip of the deployment computes it alike, and it counts once
-    when the shares are added up)."""
+    when the shares are added up).
+
+    ``active`` [B] bool (a DECODE step: which rows are lanes the dispatch
+    serves): an idle row's assignments become absent ones (expert id E, gate
+    0) before dispatch, so a sorted call reads the busy rows' experts alone,
+    and experts hit and held assignments count the busy rows alone; the busy
+    rows' results are what they were, an idle row's is the shared expert's
+    part at most and is never read. A call that holds both forms
+    (:func:`dispatch_form` ``by_hit``) chooses by that count on the device.
+    A chip's share that the rule sends dense takes no notice of ``active``
+    (its crossing is another one; ROADMAP ``held-experts-hit``). With
+    ``stats`` such a call adds 1 to ``stats["sorted"]`` if it was dispatched
+    sorted."""
     with jax.named_scope("dynamo.moe_ffn"):
         vals, idx = route_topk(x, wr, top_k, router, bias, groups, scaling,
                                norm_eps)
+        E = wg.shape[-3]
+        share = 1.0 if first is None else E / wr.shape[1]
+        form = dispatch_form(x.shape[0] * x.shape[1], top_k, E, share, mesh,
+                             wg.shape[-1], masked=active is not None)
+        masked = active is not None and heeds_active(form, share)
+        busy = active[:, None, None] if masked else None
         if first is not None:
-            E = wg.shape[-3]
             held = (idx >= first) & (idx < first + E)
+            if masked:
+                held = held & busy
             n_held = jnp.sum(held.astype(jnp.int32))
-            local = jnp.where(held, idx - first, E)
-            hit = (jnp.sum(jnp.zeros((E + 1,), jnp.int32)
-                           .at[local.reshape(-1)].max(1)[:E]), n_held)
-            # rows: the assignments this call may compute (sorted_wins)
-            out = _dispatch(x, wg, wu, wd, jnp.where(held, vals, 0.0), local,
-                            mesh, layer, share=E / wr.shape[1])
+            keep = held
         else:
-            hit = jnp.sum(jnp.zeros((wr.shape[1],), jnp.int32)
-                          .at[idx.reshape(-1)].max(1))
-            out = _dispatch(x, wg, wu, wd, vals, idx, mesh, layer)
+            keep = busy
+        # an assignment that is not kept (held elsewhere, an idle row's) is
+        # an ABSENT one: expert id E, gate 0
+        absent = keep is not None
+        local = idx if not absent else jnp.where(
+            keep, idx if first is None else idx - first, E)
+        n_hit = jnp.zeros((E + absent,), jnp.int32).at[
+            local.reshape(-1)].max(1)
+        n_hit = jnp.sum(n_hit[:E] if absent else n_hit)
+        hit = n_hit if first is None else (n_hit, n_held)
+        gates = vals if not absent else jnp.where(keep, vals, 0.0)
+        out, took_sorted = _dispatch(x, wg, wu, wd, gates, local, mesh, layer,
+                                     form, absent, n_hit)
+        if active is not None and stats is not None:
+            stats["sorted"] = stats.get("sorted", 0) + took_sorted
         if shared is not None:
             sg, su, sd = shared
             a = jax.nn.silu(jnp.einsum("btd,df->btf", x, sg)) * jnp.einsum(
@@ -312,20 +383,33 @@ def moe_ffn_in_stage(x: jax.Array, wr: jax.Array, wg: jax.Array,
     return jax.lax.psum(y, psum_axes) if psum_axes else y
 
 
-def _dispatch(x, wg, wu, wd, vals, idx, mesh, layer=None, share=1.0):
-    """``share``: the part of the router's experts that ``wg`` holds (a
-    chip's share: ``idx`` is then local, E for an absent expert, and that
-    part of a call's assignments is what the dispatch rule counts)."""
+def _dispatch(x, wg, wu, wd, vals, idx, mesh, layer, form, absent, n_hit):
+    """-> (the routed sum, 1 if the call was dispatched sorted else 0).
+    ``form``: :func:`dispatch_form` of the call; ``absent``: ``idx`` may
+    hold E, an assignment that belongs to no expert here (a chip's share,
+    an idle row); ``n_hit``: the experts the call's assignments hit, what a
+    ``by_hit`` call chooses by."""
+    if form == "sorted":
+        return _sorted_dispatch(x, wg, wu, wd, vals, idx, layer, absent), 1
+    if form == "by_hit":
+        under = n_hit < sorted_under(wg.shape[-3])
+        return jax.lax.cond(
+            under,
+            lambda: _sorted_dispatch(x, wg, wu, wd, vals, idx, layer, absent),
+            lambda: _dense_dispatch(x, wg, wu, wd, vals, idx, mesh, layer)
+        ), under.astype(jnp.int32)
+    return _dense_dispatch(x, wg, wu, wd, vals, idx, mesh, layer), 0
+
+
+def _dense_dispatch(x, wg, wu, wd, vals, idx, mesh, layer=None):
+    """Every local expert sees every row (an assignment to expert id E, an
+    absent one, has no column in the gates and adds nothing)."""
     E = wg.shape[-3]
 
     ep = _ep_size(mesh)
     tp = _tp_size(mesh)
     F = wg.shape[-1]
     tp_ffn = tp if tp > 1 and F % tp == 0 else 1
-    B, T, _ = x.shape
-    if dispatch_form(B * T, idx.shape[-1], E, share, mesh, F) == "sorted":
-        return _sorted_dispatch(x, wg, wu, wd, vals, idx, layer,
-                                absent=share < 1.0)
     if layer is not None:
         # a layer's slice of the stacked tensor is free for an einsum
         wg, wu, wd = wg[layer], wu[layer], wd[layer]

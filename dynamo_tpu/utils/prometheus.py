@@ -576,6 +576,14 @@ class StageMetrics:
             "steps of a decode dispatch, x 1 of a chunk (experts hit over "
             "this x the experts is the share of the expert weights a call "
             "touched)", ("kind",))
+        self.moe_sorted_calls = r.counter(
+            "dyn_moe_sorted_calls_total",
+            "Of the decode dispatches' calls of a routed layer, those "
+            "dispatched SORTED (only the experts the busy rows hit are "
+            "read): all of a program whose form is sorted, none of a dense "
+            "one, counted on the device for one that holds both and "
+            "chooses each call (dyn_engine_info moe_dispatch decode:by_hit)",
+            ("kind",))
         self.sparse_attn_context = r.counter(
             "dyn_sparse_attn_context_tokens_total",
             "Keys visible to each query of an indexer model, summed over "

@@ -408,6 +408,48 @@ def test_the_samplers_window_stays_a_branch_on_the_v5e(v5e):
     assert topk and all("/cond/branch_1_fun/" in ln for ln in topk), topk
 
 
+def test_the_routed_decode_layer_holds_both_forms_on_the_v5e(v5e):
+    """A routed layer of the lfm2 cell's decode program (32 rows, 64 experts
+    of 2048 x 1536, 4 a token, the stacked ``layer=`` form inside a scan)
+    with the dispatch's ``active`` mask: the v5e compiler keeps the choice
+    between sorted and dense a real ``conditional``, the ``ragged_dot``
+    calls sit in one branch and the dense einsums in the other, and neither
+    branch copies the stacked expert weights (7 GB: the program's
+    temporaries stay a few MB)."""
+    import re
+
+    from dynamo_tpu.models import moe
+
+    L, E, D, F, K, B = 6, 64, 2048, 1536, 4, 32
+    assert moe.dispatch_form(B, K, E, masked=True) == "by_hit"
+
+    def layers(x, active, wr, wg, wu, wd):
+        def body(x, l):
+            y, hit, _ = moe.moe_ffn(x, wr[l], wg, wu, wd, K, layer=l,
+                                    active=active)
+            return x + y, hit
+        return jax.lax.scan(body, x, jnp.arange(L))
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(layers).lower(
+        _sds(v5e, (B, 1, D), bf), _sds(v5e, (B,), jnp.bool_),
+        _sds(v5e, (L, D, E), bf), _sds(v5e, (L, E, D, F), bf),
+        _sds(v5e, (L, E, D, F), bf), _sds(v5e, (L, E, F, D), bf)).compile()
+    txt = compiled.as_text()
+    branches = re.search(
+        r"= .* conditional\(.*branch_computations=\{([^}]*)\}", txt)
+    assert branches, "no conditional"
+    dense, sorted_ = branches.group(1).replace(" ", "").split(",")
+    holder, holds = None, []          # the computation of each ragged-dot
+    for ln in txt.splitlines():
+        if ln[:1] in "%E" and ln.rstrip().endswith("{"):
+            holder = ln.split(" ")[1 if ln.startswith("ENTRY") else 0]
+        elif re.match(r"\s+%ragged-dot\S* = .* custom-call\(", ln):
+            holds.append(holder)
+    assert holds and set(holds) == {sorted_} != {dense}, (holds, branches)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
 @pytest.mark.parametrize("full_tracebacks,limit,same", [
     (False, 10, True), (True, 10, False), (True, 0, True)],
     ids=["outermost-name", "jax-default", "name-stack-no-frames"])

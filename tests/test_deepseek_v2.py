@@ -609,3 +609,57 @@ def test_a_group_routed_model_adds_and_scores_in_float32():
     text = jax.jit(lambda x: llama._lm_head(x, params, m)).lower(x).as_text()
     dots = [l for l in text.splitlines() if "dot_general" in l]
     assert dots and all("-> tensor<1x3x259xf32>" in l for l in dots)
+
+
+# ---- a decode step routes its busy rows alone ------------------------------
+@pytest.mark.parametrize("rows, form", [(16, "sorted"), (2, "dense")])
+def test_a_decode_step_routes_its_busy_rows_alone(state, rows, form):
+    """4 held of 16, 3 a token: a 16-row decode step dispatches its share
+    SORTED (as the benchmark's 16-lane program does, 40 held of 160) and
+    then reads and counts the busy rows' held assignments alone, which is
+    what the host's routed count beside it counts; a 2-row step is dense and
+    takes no notice of the mask (ROADMAP ``held-experts-hit``)."""
+    from tests.test_lfm2_moe import check_busy_rows_alone, primitives
+
+    cfg = llama.LlamaConfig.from_hf_config(TINY, dtype=jnp.float32)
+    params = f32(state["params"])
+    assert moe.dispatch_form(rows, 3, 4, 0.25, masked=True) == form
+    assert moe.dispatch_form(16, 6, 40, 0.25, masked=True) == "sorted"
+    kind, = cache_kinds(cfg)
+    P, page = 2, 8
+    k_pool, v_pool = (jnp.zeros(s, jnp.float32)
+                      for s in kind.pool_shapes(rows * P + 1, page))
+    tables = jnp.arange(1, rows * P + 1, dtype=jnp.int32).reshape(rows, P)
+    tokens = jnp.asarray(prompt_of(rows, 3), jnp.int32)
+
+    def step(stats, active):
+        return llama.forward_decode(
+            params, cfg, tokens, k_pool, v_pool, tables,
+            jnp.ones(rows, jnp.int32), stats=stats, active=active)[0]
+
+    active = jnp.arange(rows) % 3 != 1
+    check_busy_rows_alone(step, cfg, active, form)
+    assert "cond" not in primitives(lambda: step({}, active))
+    assert ("ragged_dot" in primitives(lambda: step({}, active))) == (
+        form == "sorted")
+
+
+def test_a_sorted_share_counts_its_busy_lanes_held_assignments(state):
+    """A 16-lane engine serving ONE request: the decode program's ``held``
+    is the busy lane's own (no idle row's), so the host takes it as it is,
+    and ``dyn_moe_assignments_total`` lies inside the routed count whatever
+    the idle lanes' stale tokens would have been routed to."""
+    core = engine(TINY, state, "xla", max_batch=16)
+    assert core.moe_dispatch.startswith("decode:sorted")
+    assert core._decode_routes_busy and core._decode_cols == (
+        "experts_hit", "held")
+    st = core.stage
+    series = (st.moe_assignments, st.moe_routed_assignments,
+              st.moe_experts_hit, st.moe_layer_calls, st.moe_sorted_calls)
+    read = lambda: [c._values.get(("decode",), 0.0) for c in series]
+    before = read()
+    generate(core, "one", prompt_of(21, 5), 9)
+    held, routed, hit, calls, took = np.subtract(read(), before)
+    assert held == int(held) and 0 <= held <= routed     # a count, unscaled
+    assert hit <= held                       # one row: an expert an assignment
+    assert took == calls > 0                 # every call sorted

@@ -465,3 +465,48 @@ def test_goodput_costs_count_active_experts_and_selected_keys():
     assert dense.index_topk == 0 and roofline._attn_cost(dense, 100) == (
         dense.attn_flops_coef * 100 * 32,
         100 * 32 * dense.kv_bytes_per_tok_layer)
+
+
+# ---- a decode step routes its busy rows alone ------------------------------
+@pytest.mark.parametrize("rows, form", [(16, "sorted"), (4, "by_hit")])
+def test_a_decode_step_routes_its_busy_rows_alone(model, params, rows, form):
+    """8 experts, 2 a token: the old rule (sorted from 16 rows on), so a
+    16-row decode step is sorted and stays sorted (the benchmark's 12-row
+    program is, by the measured rule), a 4-row one holds both forms (7
+    experts hit at most: under the crossing's 6 or not). Either way the busy
+    rows' logits are the unmasked step's and the counts are theirs alone."""
+    from dynamo_tpu.models import moe
+    from tests.test_lfm2_moe import check_busy_rows_alone, primitives
+
+    assert moe.dispatch_form(rows, 2, 8, masked=True) == form
+    assert moe.dispatch_form(12, 8, 128, masked=True) == "sorted"
+    P = 2
+    shape = (model.num_layers, model.num_kv_heads, rows * P + 1, PAGE,
+             model.head_dim)
+    kp, vp = jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
+    ip = jnp.zeros(llama.index_pool_shape(model, rows * P + 1, PAGE),
+                   jnp.float32)
+    tables = jnp.arange(1, rows * P + 1, dtype=jnp.int32).reshape(rows, P)
+    tokens = jnp.asarray(prompt_of(rows, 3), jnp.int32)
+
+    def step(stats, active):
+        return llama.forward_decode(
+            params, model, tokens, kp, vp, tables,
+            jnp.ones(rows, jnp.int32), i_pool=ip, stats=stats,
+            active=active)[0]
+
+    active = jnp.arange(rows) % 3 == 1
+    if form == "by_hit":
+        # the branch is the device's: count what it took
+        stats = {}
+        step(stats, active)
+        assert 0 <= int(stats["sorted"]) <= model.routed_layers
+        assert "cond" in primitives(lambda: step({}, active))
+        assert "cond" not in primitives(lambda: step({}, None))
+        form = "sorted" if int(stats["sorted"]) == model.routed_layers else (
+            "dense" if int(stats["sorted"]) == 0 else None)
+        if form is None:
+            pytest.skip("the two layers took different branches")
+    else:
+        assert "cond" not in primitives(lambda: step({}, active))
+    check_busy_rows_alone(step, model, active, form)

@@ -14,7 +14,9 @@ each key it cannot honour raises, ``num_dense_layers`` gives ``ffn_kinds``;
 (f) the router against a plain loop; (g) experts hit and assignments counted
 the same inside a scan and outside; (h) cache kinds, costs, counters, the
 engine's labels; (i) what moves blocks refuses the model by name; (j) the
-scopes of the lowered programs.
+scopes of the lowered programs; (k) a decode step routes its busy rows
+alone, and where the rule says dense it chooses sorted or dense on the device
+from the experts they hit.
 """
 
 import json
@@ -533,11 +535,211 @@ def test_costs_cache_kinds_and_labels(core):
     assert roofline.model_costs(m).weight_bytes == 4.0 * (
         6 * conv + 2 * attn + held + 259 * D)
     # 8 experts, 2 a token: few large experts, the old rule (sorted from 16
-    # rows on); the published geometry (64 of them, 4 a token) below
-    assert core.moe_dispatch == "decode:dense,chunk:sorted"
+    # rows on); the published geometry (64 of them, 4 a token) below. A
+    # decode step knows its busy rows: where the rule says dense it holds
+    # both forms and chooses by the experts they hit
+    assert core.moe_dispatch == "decode:by_hit,chunk:sorted"
+    assert core._decode_cols == ("experts_hit", "sorted")
     assert moe.dispatch_form(32, 4, 64) == "dense"
+    assert moe.dispatch_form(32, 4, 64, masked=True) == "by_hit"
+    assert moe.dispatch_form(8, 4, 64, masked=True) == "sorted"
     assert moe.dispatch_form(8, 4, 64) == "sorted"
     assert moe.dispatch_form(1024, 4, 64) == "sorted"
+
+
+# ---- (k) a decode step routes its busy rows alone --------------------------
+def routed_layer(rows, E=16, K=3, D=32, F=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(ks[0], (rows, 1, D), jnp.float32)
+    wr = jax.random.normal(ks[1], (D, E), jnp.float32) / np.sqrt(D) * 2
+    wg, wu = (jax.random.normal(k, (E, D, F), jnp.float32) / np.sqrt(D)
+              for k in ks[2:4])
+    wd = jax.random.normal(ks[4], (E, F, D), jnp.float32) / np.sqrt(F)
+    return x, wr, wg, wu, wd, K
+
+
+def primitives(fn, *args, under="", **kw):
+    """The names of every primitive ``fn`` traces to, sub-programs too
+    (``under``: those traced under that scope alone)."""
+    def names(jp, above=""):
+        for eqn in jp.eqns:
+            here = f"{above}/{eqn.source_info.name_stack}"
+            if under in here:
+                # (``ragged_dot_general``: by its stem)
+                yield eqn.primitive.name.removesuffix("_general")
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from names(sub, here)
+    return set(names(jax.make_jaxpr(fn)(*args, **kw).jaxpr))
+
+
+@pytest.mark.parametrize("form, share", [
+    ("sorted", False), ("sorted", True), ("dense", False), ("dense", True),
+    ("by_hit", False)])         # (a share never holds both forms)
+def test_idle_rows_are_absent_and_busy_rows_get_what_they_got(
+        monkeypatch, form, share):
+    """``moe_ffn(active=)``: the busy rows' results are the unmasked call's
+    under every form, and experts hit (and a share's held assignments) count
+    the busy rows alone. A chip's SHARE dispatched dense takes no notice of
+    the mask: its counts are every row's, as they were."""
+    x, wr, wg, wu, wd, K = routed_layer(12)
+    E = wr.shape[1]
+    first = {"first": 4} if share else {}
+    sl = slice(4, 12) if share else slice(None)
+    active = jnp.asarray([1, 0, 1, 1, 0, 0, 0, 1, 0, 0, 1, 0], bool)
+    busy = np.flatnonzero(np.asarray(active))
+    monkeypatch.setattr(moe, "dispatch_form", lambda *a, **k: "dense")
+    want, hit_all, chosen = moe.moe_ffn(x, wr, wg[sl], wu[sl], wd[sl], K,
+                                        **first)
+    alone, hit_busy, _ = moe.moe_ffn(x[busy], wr, wg[sl], wu[sl], wd[sl], K,
+                                     **first)
+    monkeypatch.setattr(moe, "dispatch_form", lambda *a, **k: form)
+    stats = {}
+    got, hit, ch = moe.moe_ffn(x, wr, wg[sl], wu[sl], wd[sl], K, **first,
+                               active=active, stats=stats)
+    np.testing.assert_array_equal(ch, chosen)        # the router's own
+    np.testing.assert_allclose(got[busy], want[busy], atol=2e-5)
+    np.testing.assert_allclose(got[busy], alone, atol=2e-5)
+    heeds = not (share and form == "dense")
+    assert jax.tree.map(int, hit) == jax.tree.map(
+        int, hit_busy if heeds else hit_all)
+    if heeds and not share:
+        seen = {int(e) for e in np.asarray(chosen)[busy].reshape(-1)}
+        assert int(hit) == len(seen) < int(hit_all) <= E
+        # an idle row's result is nothing at all
+        assert float(jnp.abs(got[~np.asarray(active)]).max()) == 0.0
+    assert int(stats["sorted"]) == (form != "dense")   # 7 of 16 hit: under
+
+
+@pytest.mark.parametrize("busy, took", [(1, 1), (4, 1), (8, 0), (12, 0)])
+def test_the_device_takes_sorted_under_the_crossing_and_dense_over_it(
+        busy, took):
+    """16 experts, 3 a token, 12 rows: the rule says dense (36 assignments),
+    so a decode call holds both forms and goes sorted while its busy rows
+    hit fewer than ``sorted_under(16)`` = 12 experts; the branch it took is
+    what ``stats["sorted"]`` says, and both branches agree."""
+    x, wr, wg, wu, wd, K = routed_layer(12, seed=1)
+    assert moe.dispatch_form(12, K, 16, masked=True) == "by_hit"
+    assert moe.dispatch_form(12, K, 16) == "dense"
+    assert moe.sorted_under(16) == 12 and moe.sorted_under(1) == 1
+    active = jnp.arange(12) < busy
+
+    @jax.jit
+    def call(x, active):
+        stats = {}
+        y, hit, _ = moe.moe_ffn(x, wr, wg, wu, wd, K, active=active,
+                                stats=stats)
+        return y, hit, stats["sorted"]
+
+    y, hit, sorted_ = call(x, active)
+    assert int(sorted_) == took == (int(hit) < 12), int(hit)
+    assert {"ragged_dot", "cond"} <= primitives(call, x, active)
+    for forced in ("sorted", "dense"):
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(moe, "dispatch_form", lambda *a, **k: forced)
+            want, hit_f, _ = moe.moe_ffn(x, wr, wg, wu, wd, K, active=active)
+        assert int(hit_f) == int(hit)
+        np.testing.assert_allclose(y[:busy], want[:busy], atol=2e-5)
+    # without the mask the call is what it was: dense, no branch
+    assert not {"ragged_dot", "cond"} & primitives(
+        lambda x: moe.moe_ffn(x, wr, wg, wu, wd, K)[0], x)
+
+
+def check_busy_rows_alone(step, cfg, active, form):
+    """``step(stats, active) -> logits [B, 1, V]``: one ``forward_decode``
+    call of a routed model over fixed operands. With the mask the busy rows'
+    logits are the unmasked call's, ``experts_hit`` / ``held`` are what the
+    BUSY rows' chosen experts make them (every row's for a share dispatched
+    dense, which takes no notice), and ``sorted`` counts the routed layers
+    of a sorted form."""
+    every = {"chosen": []}
+    want = np.asarray(step(every, None))
+    stats = {}
+    got = np.asarray(step(stats, active))
+    busy = np.asarray(active)
+    np.testing.assert_allclose(got[busy], want[busy], atol=2e-5)
+    E, R = cfg.num_experts, cfg.router_experts or cfg.num_experts
+    first = cfg.expert_first if cfg.router_experts else 0
+    counted = busy if moe.heeds_active(form, E / R) else np.ones_like(busy)
+    hit = held = 0
+    for chosen in every["chosen"]:
+        mine = np.asarray(chosen)[counted].reshape(-1) - first
+        mine = mine[(mine >= 0) & (mine < E)]
+        hit, held = hit + len(set(mine.tolist())), held + len(mine)
+    assert int(stats["experts_hit"]) == hit
+    if cfg.router_experts:
+        assert int(stats["held"]) == held
+    assert "sorted" not in every                    # a call without a mask
+    assert int(stats["sorted"]) == cfg.routed_layers * (form == "sorted")
+
+
+def parent_routing(monkeypatch):
+    """The decode programs as they were: every row routed, busy or not, and
+    dense where the rule says dense."""
+    was = moe.dispatch_form
+    monkeypatch.setattr(moe, "heeds_active", lambda form, share: False)
+    monkeypatch.setattr(
+        moe, "dispatch_form",
+        lambda *a, masked=False, **k: was(*a, **k))
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_busy_lanes_decode_the_same_beside_idle_and_deferred_lanes(
+        state, impl, monkeypatch):
+    """Five lanes, four requests of different lengths (so 4, 3, 2, 1 lanes
+    decode while one slot stays empty), one of them DEFERRED for a few
+    dispatches (pool pressure: its row stays in the program, inactive): the
+    tokens and log-probabilities are those of an engine whose decode program
+    routes every row and dispatches dense (the parent's), both branches of
+    the device's choice were taken, and the counters count the busy rows."""
+    from dynamo_tpu.engine.cache import OutOfPages
+
+    def serve(core):
+        ensure, n = core._ensure_pages, {"decodes": 0}
+
+        def pressed(seq_id, total):
+            # "b" finds no pages for its 3rd to 6th decode dispatch
+            if seq_id == "b" and core.by_seq["b"].generated >= 1:
+                n["decodes"] += 1
+                if 3 <= n["decodes"] <= 6:
+                    raise OutOfPages("pressed")
+            return ensure(seq_id, total)
+
+        core._ensure_pages = pressed
+        prompts = {"a": prompt_of(19, 1), "b": prompt_of(9, 2),
+                   "c": prompt_of(30, 3), "d": prompt_of(12, 4)}
+        for (name, pr), n_out in zip(prompts.items(), (24, 10, 5, 16)):
+            core.submit(name, request_of(pr, n_out))
+        outs = run(core, list(prompts))
+        return {k: ([o.token for o in v], [o.token_logprob for o in v])
+                for k, v in outs.items()}
+
+    kw = dict(max_batch=5, prefill_lanes=4, decode_steps=2)
+    new = engine(state, impl, **kw)
+    assert new.moe_dispatch.startswith("decode:by_hit")
+    st = new.stage                               # (one registry a process)
+    series = (st.moe_layer_calls, st.moe_sorted_calls, st.moe_experts_hit,
+              st.moe_assignments)
+    read = lambda: [c._values.get(("decode",), 0.0) for c in series]
+    before = read()
+    got = serve(new)
+    calls, took, hit, assigned = np.subtract(read(), before)
+    assert 0 < took < calls                      # both branches ran
+    # a busy row hits 2 experts a routed layer, an idle row none: at most
+    # the busy rows' assignments (the host's count), fewer where two rows
+    # share an expert
+    assert 2 * calls <= hit < assigned
+    with monkeypatch.context() as mp:
+        parent_routing(mp)
+        old = engine(state, impl, **kw)
+        assert old.moe_dispatch.startswith("decode:dense")
+        before = read()
+        want = serve(old)
+    calls_old, took_old, hit_old, assigned_old = np.subtract(read(), before)
+    assert (calls_old, assigned_old, took_old) == (calls, assigned, 0)
+    assert hit_old > hit                         # the idle rows' experts too
+    for name in want:
+        assert got[name][0] == want[name][0], name
+        assert np.abs(np.subtract(got[name][1], want[name][1])).max() < TOL
 
 
 # ---- (i) -----------------------------------------------------------------

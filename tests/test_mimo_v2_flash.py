@@ -464,3 +464,45 @@ def test_costs_and_block_bytes_are_by_kind(core):
     fl, by, tk = roofline.decode_cost(costs, [50], 1)
     assert by == 1.0 + 5 * 8 * 320 + 2 * 50 * 160 + 5 * 320 + 2 * 160
     assert [k.name for k in core.cache_kinds] == ["global", "window"]
+
+
+# ---- a share dispatched dense keeps its decode program ---------------------
+def test_a_share_dispatched_dense_keeps_its_decode_program(state,
+                                                           monkeypatch):
+    """4 held of the router's 8, two rows: the rule says dense, and such a
+    share takes no notice of the dispatch's ``active`` mask (its crossing is
+    another one: ROADMAP ``held-experts-hit``). The engine's own decode
+    step lowers to the text it had before a decode step knew its busy rows
+    (no ``ragged_dot``, no conditional under the experts' scope, no
+    ``sorted`` column), and the host still scales ``held`` by the lanes."""
+    from dynamo_tpu.utils import roofline
+    from tests.test_lfm2_moe import parent_routing, primitives
+
+    def decode_program(mp):
+        seen = {}
+
+        def spy(kind, fn, record):
+            def call(*args, **kw):
+                if kind == "decode" and not seen:
+                    seen["text"] = fn.lower(*args, **kw).as_text()
+                    seen["inside"] = primitives(
+                        fn, *args, under="dynamo.moe_ffn", **kw)
+                return fn(*args, **kw)
+            return call
+
+        mp.setattr(roofline, "instrument_compile", spy)
+        core = engine(TINY, state, "xla")
+        generate(core, "r", prompt_of(20, 1), 3)
+        return core, seen
+
+    with monkeypatch.context() as mp:
+        core, now = decode_program(mp)
+    assert core.moe_dispatch.startswith("decode:dense")
+    assert not core._decode_routes_busy
+    assert core._decode_cols == core._packed_cols == ("experts_hit", "held")
+    inside = now["inside"]
+    assert "top_k" in inside and not {"ragged_dot", "cond"} & inside, inside
+    with monkeypatch.context() as mp:
+        parent_routing(mp)
+        _, was = decode_program(mp)
+    assert now["text"] == was["text"]
