@@ -1077,22 +1077,29 @@ class EngineCore:
             seen_by.inc("dispatches", kind)
             seen_by.inc("tokens", kind, amount=float(tokens))
 
-    def _count_attn_pages(self, lengths: np.ndarray, P: int) -> None:
+    def _count_attn_pages(self, lengths: np.ndarray, served: np.ndarray,
+                          P: int) -> None:
         """Host counters of the pages a decode dispatch makes the paged dma
         kernel copy a pool, and of the pages of the blocks it is in for them
         (``ops.attention.paged_live_pages``, the kernel's own arithmetic):
-        every lane of the program as the dispatch hands it over (``lengths``
-        [B]: a lane it does not serve has length 1), a token longer each
-        step, times the attention layers of a kind; mirrored while a
+        every lane of the program as the kernel is handed it (``lengths``
+        [B], a token longer each step; a lane that is not ``served`` [B]
+        reaches the kernel as length 0 and is skipped: no page), times the
+        attention layers of a kind. Beside them the lanes the kernel was run
+        over and those of them it skipped. All mirrored while a
         ``DYN_PROFILE_DIR`` capture runs, as :meth:`_count_state_work`."""
         st = self.stage
-        at = lengths[:, None] + np.arange(self.cfg.decode_steps)
+        steps = self.cfg.decode_steps
+        at = np.where(served[:, None], lengths[:, None] + np.arange(steps), 0)
         for kind, (window, layers) in self._attn_calls.items():
-            pages = paged_live_pages(at, P, self.page_size, self._paged_ppb,
-                                     window)
-            for counter, n in zip((st.attn_pages_live, st.attn_pages_visited),
-                                  pages):
-                amount = float(n.sum() * layers)
+            live, visited = paged_live_pages(at, P, self.page_size,
+                                             self._paged_ppb, window)
+            for counter, n in ((st.attn_pages_live, live.sum()),
+                               (st.attn_pages_visited, visited.sum()),
+                               (st.attn_lane_calls, at.size),
+                               (st.attn_lane_calls_skipped,
+                                steps * int((~served).sum()))):
+                amount = float(n * layers)
                 counter.inc(kind, amount=amount)
                 if self.capturing:
                     st.profile_captured_work.inc(counter.name, kind,
@@ -2981,7 +2988,7 @@ class EngineCore:
             self._count_state_work("decode", B * N, len(active) * N,
                                    len(active) * N, 0, self.capturing)
         if self._attn_calls:
-            self._count_attn_pages(lengths, P)
+            self._count_attn_pages(lengths, active_mask, P)
         self.stage.engine_dispatch_tokens.inc(
             "decode", amount=float(len(active) * N))
         self._inflight.append({"kind": "decode",

@@ -3381,7 +3381,11 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     active rows' assignments alone, counts theirs alone in ``stats``
     (``experts_hit``, ``held``) and adds the routed layers it dispatched
     sorted to ``stats["sorted"]`` (:func:`_ffn_block`). An idle row's
-    logits are never read.
+    logits are never read. The paged kernel is handed an idle lane as length
+    0, which it skips (no page copied, no row written, zeros out:
+    ``ops.attention.paged_attention``); ``lengths`` itself stays as given
+    for everything else (positions, rotary tables, ``kv_write``, the state
+    layers).
     """
     fold = cfg.kv_fold
     page = k_pool.shape[3] * fold
@@ -3423,6 +3427,11 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     if attn_impl == "pallas":
         from ..ops.attention import paged_attention as _paged
         _paged_cache: Dict[Optional[int], Any] = {}
+        # what the kernel attends over, once a step for every layer: a lane
+        # that is not served has nothing (the kernel skips a length of 0)
+        with scope("attn_in"):
+            attended = (lengths if active is None
+                        else jnp.where(active, lengths, 0))
 
         def paged_for(layer: int):
             """Per-layer kernel variant (window on sliding layers; softcap/
@@ -3494,7 +3503,7 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                 if cfg.has_latent:
                     q0, extra["latent"] = q[0][:, 0], q[1][:, 0]
                 attn = paged_for(l)(
-                    q0, kv[0], kv[1], tables, lengths,
+                    q0, kv[0], kv[1], tables, attended,
                     jnp.int32(la), **extra,
                     **({"new": tuple(new)} if new else {}))
                 if new:

@@ -477,8 +477,12 @@ def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
 # Paged attention (decode directly over the HBM page pool)
 #
 # TPU path: multi-page double-buffered DMA kernel. The KV pool stays in HBM
-# (memory_space=ANY); each grid step (b, j) copies the next block of
-# ``pages_per_block`` pages for sequence b — ALL kv heads in one strided
+# (memory_space=ANY); the kernel walks the lanes, and each lane's blocks of
+# ``pages_per_block`` pages, in loops of its own (one grid step for the
+# whole batch where its per-lane operands fit in VMEM: a grid step costs a
+# fraction of a microsecond whatever it does, and a grid of lanes x blocks
+# charged every call for the table's width and the batch's), copies the
+# next block — ALL kv heads in one strided
 # DMA per page — into a VMEM double buffer while the previous block
 # computes, and accumulates online softmax in VMEM scratch. One DMA per
 # page (not per page×head) matters: DMA issue overhead dominated the
@@ -490,7 +494,8 @@ def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
 # the NUMBER of copies it issues, 50-60 ns each, not by their bytes): copies
 # scale with visible pages, not with the block. A lane of one token copies
 # one page a pool, not ``pages_per_block``; a window layer copies the pages
-# its window touches. :func:`paged_live_pages` is the same arithmetic on the
+# its window touches; a lane of NO tokens (one the dispatch does not serve)
+# is skipped whole. :func:`paged_live_pages` is the same arithmetic on the
 # host, for the engine's counters. This is the same design as
 # jax.experimental.pallas.ops.tpu.paged_attention, which we cannot use
 # directly: for GQA group sizes not divisible by 8 (Llama 8B/1B are 32q/8kv
@@ -500,14 +505,14 @@ def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
 
 
 def _lane_block(b, j, *prefetched):
-    """Index map of the per-lane q / output block of both paged kernels."""
+    """Index map of the per-lane q / output block of the one-page kernel."""
     return (b, 0, 0, 0)
 
 
 def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                       scale: float, page: int, ppb: int, hkv: int,
                       fold: int, dh: int, softcap: Optional[float],
-                      window: Optional[int], selected: bool,
+                      window: Optional[int], selected: bool, lanes: int,
                       dv: Optional[int] = None, sunk: bool = False,
                       writes: bool = False, latent: bool = False):
     """Pools are the WHOLE stored pool, [L, Hkv, n_pages, page//fold,
@@ -519,16 +524,34 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     rows, fold*Dh]) so the per-page all-head DMA lands as a contiguous
     per-head reshape for the batched matmul.
 
+    The lanes and a lane's blocks are LOOPS inside the kernel, not grid
+    steps: a grid step holds a group of lanes (all ``lanes`` of the call
+    where their operands fit in VMEM, which is every cell's case), walks
+    them in order, and walks each lane's ACTIVE blocks alone. A grid step
+    costs 0.15-0.4 us whatever it does, so a grid of lanes x blocks charged
+    a call for every block of the table's width and for every lane of the
+    batch, served or not (PERF.md section 6, PR 46). The operands with a row
+    a lane (queries, ``latent``, the keep mask, the new rows) and the output
+    come a group a block; a lane takes its row by its index in the group.
+
+    A lane of length 0 (an empty slot, a lane the dispatch does not serve) is
+    SKIPPED: it has no block: no page copy is started or waited for, nothing
+    is scored and nothing written back; its output rows are zeros (the
+    group's output block is zeroed before its lanes are walked). The
+    prefetch chain hops over such lanes (:func:`served_from`: a walk over
+    the prefetched lengths on the scalar core, at a lane's last block only),
+    so every copy started is waited for by the lane it was started for.
+
     With ``window``, each lane's active block range is clamped at BOTH ends:
     blocks wholly below ``length - window`` are never DMA'd nor computed
     (the page-range clamp — sliding decode reads O(window) bytes, not
     O(context)), and in-block tokens below the window start are masked.
 
     ``selected``: one more operand, the keep mask of a model with an indexer
-    ([1, 1, L2] int32 of this lane and block, logical order, fold 1 only);
-    without it the kernel is what it always was. ``dv``: V rows of a width
-    of their own (fold 1 only). ``sunk``: one more operand, the heads' sink
-    logits [Hkv, G, 1] float32 (a key of that logit and value zero).
+    ([group, blocks, L2] int32, logical order, fold 1 only); without it the
+    kernel is what it always was. ``dv``: V rows of a width of their own
+    (fold 1 only). ``sunk``: one more operand, the heads' sink logits [Hkv,
+    G, 1] float32 (a key of that logit and value zero).
 
     Inside an active block only LIVE pages are copied (``live``: the page
     intersects ``[lo, length)``, the tokens the lane's query can see): the
@@ -543,7 +566,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     such care: a NaN score is replaced with the rest.
 
     ``writes``: a one-token decode step's new rows come as two more operands
-    (``k_new`` / ``v_new`` [1, 1, Hkv*fold*D]: this lane's rows, the heads
+    (``k_new`` / ``v_new`` [group, 1, Hkv*fold*D]: a lane's rows, the heads
     side by side, a row repeated ``fold`` times across its lanes) and the
     pools come back as two more
     results, aliased to the operands. The row of token ``length - 1``
@@ -552,19 +575,17 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     group of rows around it (:func:`_write_group`) is overlaid with the new
     row (write, then attend: the scores see it as they would after
     ``kv_write``), staged, and copied back to HBM, all heads in one strided
-    copy a pool. The copy is NOT waited for in its grid step: the staging
+    copy a pool. The copy is NOT waited for where it is started: the staging
     ring holds ``_WRITE_RING`` lanes' groups, a ring slot's copy is waited
-    for when the slot comes round again and every one still out at the last
-    grid step. A lane of length 0 (an empty slot) attends like a lane of
-    length 1, as it always did, and writes nothing. What keeps the copy back
-    from racing a read: the page that holds ``length - 1`` belongs to its
-    lane alone (prefix reuse shares sealed pages only: engine/cache.py), so
-    no other lane's prefetch names it, and the lane's own read of it was
-    waited for before the overlay."""
+    for when the slot comes round again and every one still out behind the
+    last lane. What keeps the copy back from racing a read: the page that
+    holds ``length - 1`` belongs to its lane alone (prefix reuse shares
+    sealed pages only: engine/cache.py), so no other lane's prefetch names
+    it, and the lane's own read of it was waited for before the overlay."""
     keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
     sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
-    # latent attention (fold 1): the queries' second part [1, Hkv, G, Dv],
-    # which meets the VALUE rows: a score is q . k + latent . v
+    # latent attention (fold 1): the queries' second part [group, Hkv, G,
+    # Dv], which meets the VALUE rows: a score is q . k + latent . v
     lat_ref, rest = (rest[0], rest[1:]) if latent else (None, rest)
     if writes:
         (kn_ref, vn_ref, o_ref, k_out, v_out, k_buf, v_buf, sem, m_scr, l_scr,
@@ -572,26 +593,31 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     else:
         o_ref, k_buf, v_buf, sem, m_scr, l_scr, acc_scr, state = rest
     dv = dh if dv is None else dv
-    b = pl.program_id(0)
-    j = pl.program_id(1)
+    group = q_ref.shape[0]    # lanes a grid step holds
+    first_lane = pl.program_id(0) * group
     L2 = ppb * page           # tokens per compute block
     rows_pp = page // fold    # folded rows per page
     rows = L2 // fold         # folded rows per compute block
 
-    def length_of(bb):
-        # every lane covers >= 1 block: the prefetch chain below would leave
-        # a DMA slot un-consumed after a lane of none and stall the next
-        return jnp.maximum(len_ref[bb], 1)
-
     def nblocks(bb):
-        return (length_of(bb) + L2 - 1) // L2
+        return (len_ref[bb] + L2 - 1) // L2       # a lane of 0 has none
+
+    def served_from(bb):
+        """The first lane at or after ``bb`` that has a block, or ``lanes``.
+        The lengths are read in the body, never in the condition."""
+        def length_at(i):
+            return len_ref[jnp.minimum(i, lanes - 1)]
+        return jax.lax.while_loop(
+            lambda c: (c[0] < lanes) & (c[1] == 0),
+            lambda c: (c[0] + 1, length_at(c[0] + 1)),
+            (bb, length_at(bb)))[0]
 
     def jstart(bb):
         # first block holding any in-window token. The decode query sits at
         # length-1, so the window covers [length - window, length).
         if window is None:
             return 0
-        return jnp.maximum(length_of(bb) - window, 0) // L2
+        return jnp.maximum(len_ref[bb] - window, 0) // L2
 
     layer = layer_ref[0]
 
@@ -605,7 +631,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         pool, and a page's copies are all of one size. A loop over the run
         and not a branch a page: ``ppb`` predicates a site cost the scalar
         core what the copies they skip save (PERF.md section 6, PR 41)."""
-        n = length_of(bb)
+        n = len_ref[bb]
         first = 0
         if window is not None:
             first = jnp.maximum(
@@ -627,16 +653,9 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     def start(bb, jj, slot):
         block_copies(bb, jj, slot, lambda d: d.start())
 
-    nb = nblocks(b)
-    j0 = jstart(b)
-    active = (j >= j0) & (j < nb)
-
-    # first grid step: prime the pipeline with lane 0's first active block.
-    # Steps of lane 0 before its window start are dead, so the prime fires
-    # at (0, jstart(0)) — for full attention that is (0, 0) as before.
-    first = (b == 0) & (j == jstart(0))
-
-    @pl.when(first)
+    # first grid step: prime the chain with the first active block of the
+    # first lane that has one (with no lane served nothing is ever started)
+    @pl.when(first_lane == 0)
     def _():
         state[0] = 0
         if writes:
@@ -644,7 +663,15 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         # rows no copy ever fills meet a weight of exactly 0 in p . V: they
         # must be finite. From here on the buffer holds zeros or pool rows
         v_buf[...] = jnp.zeros_like(v_buf)
-        start(b, j, 0)
+        head = served_from(0)
+
+        @pl.when(head < lanes)
+        def _():
+            start(head, jstart(head), 0)
+
+    # a skipped lane's output rows are defined: a row of the step's
+    # activations is never memory nobody wrote
+    o_ref[...] = jnp.zeros_like(o_ref)
 
     def write_wait(w):
         # a wait needs the semaphore and the copy's size, not its address
@@ -653,10 +680,10 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                 wbuf.at[w], pool.at[layer, :, 0, pl.ds(0, wbuf.shape[2])],
                 wsem.at[w, i]).wait()
 
-    def write_back(slot):
-        """Overlay the new rows on block ``slot`` and start their way back
-        to the pool (the last active block of lane ``b``, copies waited
-        for)."""
+    def write_back(b, row, slot):
+        """Overlay lane ``b``'s new rows (``row`` of its group's) on block
+        ``slot`` and start their way back to the pool (the lane's last
+        active block, copies waited for)."""
         tok = len_ref[b] - 1
         pg = tok // page
         off = tok % page
@@ -680,8 +707,8 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                              // d == off % fold)
             for h in range(hkv):
                 at = (slot, h, pg % ppb, pl.ds(g0, grp), slice(None))
-                row = new_ref[0, :, h * fold * d:(h + 1) * fold * d]
-                merged = jnp.where(hit, row, buf[at])        # [grp, f*d]
+                new = new_ref[row, :, h * fold * d:(h + 1) * fold * d]
+                merged = jnp.where(hit, new, buf[at])        # [grp, f*d]
                 buf[at] = merged
                 wbuf[w, h] = merged
             pltpu.make_async_copy(
@@ -689,27 +716,20 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                 wsem.at[w, i]).start()
         state[1] = n + 1
 
-    @pl.when(active)
-    def _():
+    def block(b, row, nb, j):
+        """Block ``j`` of lane ``b``, one of its ``nb`` (its copies started
+        by the block before it in the chain)."""
         slot = state[0]
 
-        @pl.when(j == j0)
-        def _():
-            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-            l_scr[:] = jnp.zeros_like(l_scr)
-            acc_scr[:] = jnp.zeros_like(acc_scr)
-
-        # prefetch the next ACTIVE step's block into the other buffer.
-        # flat order: j within b, then b; j outside [jstart, nblocks) is
-        # dead (never copied, never computed).
-        nj, nb_ = j + 1, b
-        wrap_b = nj >= nb
-        nb_ = jnp.where(wrap_b, b + 1, nb_)
-        # clamp the lookup lane: when nb_ == num_programs there is no next
-        # step (has_next gates the start), but jstart still indexes len_ref
-        nj = jnp.where(wrap_b,
-                       jstart(jnp.minimum(nb_, pl.num_programs(0) - 1)), nj)
-        has_next = nb_ < pl.num_programs(0)
+        # prefetch the next ACTIVE block into the other buffer. The chain's
+        # order: a lane's blocks from jstart to its last, then the first
+        # block of the next lane that has one.
+        wrap_b = j + 1 >= nb
+        nb_ = jax.lax.cond(wrap_b, lambda: served_from(b + 1), lambda: b)
+        # clamp the lookup lane: when nb_ == lanes there is no next block
+        # (has_next gates the start), but jstart still indexes len_ref
+        nj = jnp.where(wrap_b, jstart(jnp.minimum(nb_, lanes - 1)), j + 1)
+        has_next = nb_ < lanes
 
         @pl.when(has_next)
         def _():
@@ -719,18 +739,18 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         block_copies(b, j, slot, lambda d: d.wait())
 
         if writes:
-            @pl.when((j == nb - 1) & (len_ref[b] > 0))
+            @pl.when(wrap_b)
             def _():
-                write_back(slot)
+                write_back(b, row, slot)
 
-        q = q_ref[0]                                        # [Hkv, G, Dh]
+        q = q_ref[row]                                      # [Hkv, G, Dh]
         kf = k_buf[slot].reshape(hkv, rows, fold * dh)
         vf = v_buf[slot].reshape(hkv, rows, fold * dv)
         # token index of folded row r, slice f: within this block the page
         # is r // rows_pp and the in-page row r % rows_pp
         ridx = jax.lax.broadcasted_iota(jnp.int32, (1, 1, rows), 2)
         base = (ridx // rows_pp) * page + (ridx % rows_pp) * fold + j * L2
-        length = length_of(b)
+        length = len_ref[b]
 
         s_parts, mask_parts = [], []
         for f in range(fold):
@@ -740,7 +760,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                 preferred_element_type=jnp.float32)          # [Hkv, G, rows]
             if lat_ref is not None:
                 s = s + jax.lax.dot_general(
-                    lat_ref[0], vf, (((2,), (2,)), ((0,), (0,))),
+                    lat_ref[row], vf, (((2,), (2,)), ((0,), (0,))),
                     preferred_element_type=jnp.float32)
             s = s * scale
             if softcap is not None:
@@ -750,7 +770,7 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             if window is not None:
                 mask = mask & ((base + f) >= length - window)
             if keep_ref is not None:
-                mask = mask & (keep_ref[...] > 0)           # [1, 1, rows]
+                mask = mask & (keep_ref[row, pl.ds(j, 1), :] > 0)[None]
             s_parts.append(jnp.where(mask, s, NEG_INF))
             mask_parts.append(mask)
 
@@ -774,16 +794,30 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         m_scr[:] = m_new
         state[0] = slot ^ 1
 
-        @pl.when(j == nb - 1)
+    def lane(row, carry):
+        b = first_lane + row
+        nb = nblocks(b)
+
+        @pl.when(nb > 0)
         def _():
+            m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+            def one(j, carry):
+                block(b, row, nb, j)
+                return carry
+            jax.lax.fori_loop(jstart(b), nb, one, 0)
             l = l_scr[:]
             if sink_ref is not None:
                 l = l + jnp.exp(sink_ref[...] - m_scr[:])
-            o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
-                        ).astype(o_ref.dtype)
+            o_ref[row] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
+                          ).astype(o_ref.dtype)
+        return carry
+    jax.lax.fori_loop(0, group, lane, 0)
 
     if writes:
-        @pl.when((b == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1))
+        @pl.when(pl.program_id(0) == pl.num_programs(0) - 1)
         def _():
             for w in range(_WRITE_RING):
                 @pl.when(state[1] > w)
@@ -794,6 +828,34 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 # lanes whose write-back may be on its way at once (the staging ring of
 # _paged_dma_kernel): a lane's grid steps take about as long as one copy
 _WRITE_RING = 4
+
+
+# most bytes of VMEM the operands with a row a lane (and the output) may take
+# in one grid step of _paged_dma_kernel. The cells' calls take 1 to 8 MiB for
+# the whole batch; a batch past it is walked in several grid steps, a group
+# of lanes each
+_GROUP_BYTES = 12 << 20
+
+
+def _lane_groups(lanes: int, a_lane: int) -> int:
+    """Grid steps of _paged_dma_kernel for a batch of ``lanes`` whose
+    per-lane operands take ``a_lane`` bytes of VMEM a lane: ONE where the
+    whole batch fits (single-buffered: there is nothing to overlap), else
+    the fewest equal groups whose blocks fit (the grid's pipeline holds two
+    of each), down to a lane a step."""
+    if lanes * a_lane <= _GROUP_BYTES:
+        return 1
+    return next(n for n in range(2, lanes + 1) if n == lanes or (
+        lanes % n == 0 and 2 * (lanes // n) * a_lane <= _GROUP_BYTES))
+
+
+def _vmem_bytes(shape, dtype) -> int:
+    """Bytes of an array in VMEM: its last two dimensions padded to the
+    dtype's tile (8 sublanes of 32 bits x 128 lanes)."""
+    size = jnp.dtype(dtype).itemsize
+    sub = 8 * max(1, 4 // size)
+    *lead, s, l = shape
+    return math.prod(lead) * -(-s // sub) * sub * -(-l // 128) * 128 * size
 
 
 def _write_group(rows_pp: int, dtype) -> int:
@@ -823,7 +885,7 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     only — the serving path always compiles this variant (paged_attention
     gates it to real TPUs). ``new`` = (k_new [B, Hkv, Dh], v_new [B, Hkv,
     Dv]): the kernel writes the rows of token ``lengths - 1`` itself
-    (``lengths`` unclamped: a lane of 0 writes nothing) and the result is
+    (a lane of length 0 is skipped whole and writes nothing) and the result is
     (out, k_pool, v_pool), the pools aliased to the operands; they must be
     stored as the kernel reads them (:func:`paged_kernel_writes`)."""
     B, Hkv, G, Dh = q4.shape
@@ -862,6 +924,23 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     k_pool = k_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dh)
     v_pool = v_pool.reshape(L, Hkv, n_pages, page // fold, fold * Dv)
 
+    # the operands with a row a lane, and the output: the whole batch in ONE
+    # grid step where that fits in VMEM, else groups of lanes
+    by_lane = [((Hkv, G, Dh), q4.dtype), ((Hkv, G, Dv), q4.dtype)]
+    if latent is not None:
+        by_lane.append(((Hkv, G, Dv), latent.dtype))
+    if keep is not None:
+        by_lane.append(((NB, ppb * page), jnp.int32))
+    if new is not None:
+        by_lane += [((1, Hkv * fold * d), k_pool.dtype) for d in (Dh, Dv)]
+    groups = _lane_groups(B, sum(_vmem_bytes(*a) for a in by_lane))
+
+    def lane_spec(*block):
+        if groups == 1:
+            return pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)
+        return pl.BlockSpec((B // groups, *block),
+                            lambda c, *_: (c, *(0 for _ in block)))
+
     selected = keep is not None
     sel_specs, sel_args = [], []
     if selected:
@@ -872,11 +951,11 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
         L2 = ppb * page
         keep = keep.astype(jnp.int32)
         keep = jnp.pad(keep, ((0, 0), (0, NB * L2 - keep.shape[1])))
-        sel_specs = [pl.BlockSpec((1, 1, L2), lambda b, j, *_: (b, 0, j))]
-        sel_args = [keep[:, None, :]]
+        sel_specs = [lane_spec(NB, L2)]
+        sel_args = [keep.reshape(B, NB, L2)]    # a row a block
     own = {}                 # static parameters only a per-kind model sets
     if sink is not None:
-        sel_specs.append(pl.BlockSpec((Hkv, G, 1), lambda b, j, *_: (0, 0, 0)))
+        sel_specs.append(pl.BlockSpec((Hkv, G, 1), lambda c, *_: (0, 0, 0)))
         sel_args.append(sink.astype(jnp.float32).reshape(Hkv, G, 1))
         own["sunk"] = True
     if Dv != Dh:
@@ -884,14 +963,14 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     if latent is not None:
         if fold > 1:
             raise ValueError("latent attention reads unfolded rows")
-        sel_specs.append(pl.BlockSpec((1, Hkv, G, Dv), _lane_block))
+        sel_specs.append(lane_spec(Hkv, G, Dv))
         sel_args.append(latent)
         own["latent"] = True
-    out_specs = pl.BlockSpec((1, Hkv, G, Dv), _lane_block)
+    out_specs = lane_spec(Hkv, G, Dv)
     out_shape = jax.ShapeDtypeStruct((B, Hkv, G, Dv), q4.dtype)
     scratch, aliases = [], {}
     if new is not None:
-        # the new rows as [B, 1, Hkv * fold * D], a lane a block: the heads
+        # the new rows as [B, 1, Hkv * fold * D], a lane a row: the heads
         # side by side, a head's row repeated across its lanes so that one
         # select places it in a folded pool row. Of the forms tried this is
         # the one XLA builds the rest of the step around best (rows heads
@@ -899,8 +978,7 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
         # operands and ``wv`` leave the chip's fast memory; PERF.md section
         # 6, PR 38)
         for a, pool in zip(new, (k_pool, v_pool)):
-            sel_specs.append(pl.BlockSpec((1, 1, Hkv * pool.shape[-1]),
-                                          lambda b, j, *_: (b, 0, 0)))
+            sel_specs.append(lane_spec(1, Hkv * pool.shape[-1]))
             sel_args.append(jnp.tile(a.astype(pool.dtype), (1, 1, fold))
                             .reshape(B, 1, -1))
         own["writes"] = True
@@ -922,9 +1000,9 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, NB),
+        grid=(groups,),
         in_specs=[
-            pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
+            lane_spec(Hkv, G, Dh),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM),
             *sel_specs,
@@ -946,11 +1024,11 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
         functools.partial(_paged_dma_kernel, scale=scale, page=page,
                           ppb=ppb, hkv=Hkv, fold=fold, dh=Dh,
                           softcap=softcap, window=window, selected=selected,
-                          **own),
+                          lanes=B, **own),
         grid_spec=grid_spec,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         **({"input_output_aliases": aliases} if aliases else {}),
     )(page_tables, lengths, layer, q4, k_pool, v_pool, *sel_args)
@@ -1068,7 +1146,8 @@ def paged_live_pages(lengths, P: int, page: int, ppb: int,
                      window: Optional[int] = None):
     """The dma kernel's own arithmetic on the host (NumPy), for one call of
     it (one layer, one step): lanes of ``lengths`` tokens (any shape; a lane
-    of 0 counts as 1, as in the kernel) over page tables ``P`` wide ->
+    of 0 is skipped by the kernel: 0 live, 0 visited) over page tables ``P``
+    wide ->
     ``(live, visited)``, pages of ONE pool (K and V each copy as many), each
     shaped as ``lengths``. ``visited``: the pages of the lane's ACTIVE
     blocks, ``ppb`` (at most ``P``) a block, blocks ``[lo // L2,
@@ -1076,7 +1155,7 @@ def paged_live_pages(lengths, P: int, page: int, ppb: int,
     window, 0)`` (0 without a window): what the kernel copied before it
     told a block's pages apart. ``live``: of those, the pages that hold a
     token of ``[lo, length)``, which is what it copies."""
-    lengths = np.maximum(np.asarray(lengths, np.int64), 1)
+    lengths = np.asarray(lengths, np.int64)
     ppb = min(ppb, P)
     L2 = ppb * page
     lo = 0 if window is None else np.maximum(lengths - window, 0)
@@ -1116,7 +1195,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
       A single layer's [Hkv, n_pages, page, Dh] is taken as a pool of one
       layer (``layer`` must then be left out).
     page_tables: [B, P] int32 page ids (rows padded with page 0)
-    lengths: [B] int32 — tokens to attend per sequence (including current)
+    lengths: [B] int32 — tokens to attend per sequence (including current);
+      0 = a lane nobody serves: both kernels give it zeros, and the dma
+      kernel SKIPS it (no page of its table is read, nothing is computed
+      and nothing written for it)
     Returns [B, Hq, Dh]. Sequences attend to tokens [0, length); with
     ``window`` only [max(0, length - window), length). The DMA kernel
     clamps its active block range, so out-of-window blocks cost neither
@@ -1193,13 +1275,6 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         k_pool, v_pool = (jax.lax.dynamic_index_in_dim(p, layer[0], 0)
                           for p in (k_pool, v_pool))
         layer = jnp.zeros_like(layer)
-    # The TPU kernel's prefetch chain assumes every lane covers >=1 block
-    # (nblocks==0 would leave a DMA slot un-consumed and stall the next
-    # active lane). Enforce the invariant here rather than relying on
-    # callers to pad lengths (a kernel that writes tells a lane of 0, which
-    # writes nothing, by the unclamped value, and clamps for itself).
-    if new is None:
-        lengths = jnp.maximum(lengths, 1)
     if paged_kernel_variant(interpret) == "dma":
         q4 = q.reshape(B, Hkv, G, Dh)
         out = _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables,

@@ -621,14 +621,25 @@ class StageMetrics:
             "dyn_attn_pages_live_total",
             "Pages the paged decode kernel copied a pool (K and V each as "
             "many): those that hold a token the lane's query sees, every "
-            "lane of the decode program, served or not, each step, times "
-            "the attention layers of the kind (full / window)", ("kind",))
+            "lane the decode dispatch serves (the kernel skips the others), "
+            "each step, times the attention layers of the kind (full / "
+            "window)", ("kind",))
         self.attn_pages_visited = r.counter(
             "dyn_attn_pages_visited_total",
             "Pages of the blocks the kernel was in for them (a block of "
             "DYNAMO_TPU_PAGED_PPB pages that holds a visible token): what "
             "it copied before it told a block's pages apart; live / "
             "visited is the share of a block's copies that is left",
+            ("kind",))
+        self.attn_lane_calls = r.counter(
+            "dyn_attn_lane_calls_total",
+            "Lanes the paged decode kernel was run over: every lane of the "
+            "decode program, each step, times the attention layers of the "
+            "kind (full / window)", ("kind",))
+        self.attn_lane_calls_skipped = r.counter(
+            "dyn_attn_lane_calls_skipped_total",
+            "Those of them the kernel skipped (length 0: a lane the "
+            "dispatch does not serve; no page copied, no row written)",
             ("kind",))
         # latent attention (one compressed row a token for all heads): one
         # layer's worth, as the indexer's counters above

@@ -9,8 +9,15 @@ kernel of any checkout (the parent's unpacked beside the change's). A case is
 a cell's decode kernel as its program calls it (lanes, heads, rows, layers,
 pool, window, sink; the new rows written by the kernel) under a length mix as
 the cell's ``engine.batch_occupancy`` gives it: served lanes drawn from a
-seed, the others as ``_dispatch_decode`` hands them over (length 1, an
-all-zero table). One dispatch is 4 steps of one call a layer. Prints
+seed, the others as the decode program hands them to the kernel since PR 46
+(length 0, an all-zero table: the kernel skips them; ``--unserved 1`` hands
+them over as the programs before it did, length 1, for a timing of such a
+tree as it ran). ``<case>-only`` is the case with ONLY its served lanes in
+the batch (``lanes = served``: the same lanes, lengths and pages, no other
+grid step), the bound of what skipping a lane can give. One dispatch is 4
+steps of one call a layer. ``--profile`` also traces one dispatch and prints
+its device operations by total time (events, microseconds an event): what of
+a call is the kernel and what the operations around it. Prints
 microseconds a call (best of three runs of ten dispatches) and, where the
 tree has ``paged_live_pages``, the page copies a call and pool; ``--out``
 keeps the served lanes' attention outputs and the pools (scratch page 0
@@ -73,18 +80,47 @@ def prints(a):
     return of(a)
 
 
-def case(name):
+def device_ops(fn, *args):
+    """One traced call of ``fn``: [name, events, us an event] of the device's
+    XLA operations, the ten that took longest in all."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            jax.block_until_ready(fn(*args))
+        pb, = glob.glob(os.path.join(d, "plugins/profile/*/*.xplane.pb"))
+        took = {}
+        for plane in ProfileData.from_file(pb).planes:
+            if not plane.name.startswith("/device:"):
+                continue
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    for e in line.events:
+                        took.setdefault(e.name.split(" = ")[0].rstrip(
+                            ".0123456789"), []).append(e.duration_ns * 1e-3)
+    top = sorted(took.items(), key=lambda kv: -sum(kv[1]))[:10]
+    return [[n, len(v), round(sum(v) / len(v), 3)] for n, v in top]
+
+
+def case(name, unserved=0, profile=False):
     import jax
     import jax.numpy as jnp
 
     from dynamo_tpu.ops import attention
 
+    only = name.endswith("-only")
     (B, Hkv, G, Dk, Dv, fold, L, n_pages, P, window, sunk, served,
-     draw) = CASES[name]
+     draw) = CASES[name.removesuffix("-only")]
     rng = np.random.default_rng(SEED)
-    lengths = np.ones(B, np.int32)
+    lengths = np.full(B, unserved, np.int32)
     lanes = np.sort(rng.permutation(B)[:served])
     lengths[lanes] = draw(rng, served)
+    if only:
+        B, lengths, lanes = served, lengths[lanes], np.arange(served)
     tables = np.zeros((B, P), np.int32)
     free, used = rng.permutation(np.arange(1, n_pages)), 0
     for b in lanes:
@@ -113,7 +149,9 @@ def case(name):
                 o, k, v = attention.paged_attention(
                     q[l], k, v, pt, ln, l, new=new, **kw)
                 outs.append(o)
-            return (k, v, ln + 1), jnp.stack(outs)
+            # a lane the dispatch does not serve stays one: the program
+            # hands the kernel 0 for it at every step
+            return (k, v, ln + (ln > 0)), jnp.stack(outs)
         (k, v, _), outs = jax.lax.scan(step, (k, v, ln), (kn, vn))
         return outs, k, v
 
@@ -138,6 +176,8 @@ def case(name):
         best = min(best, (time.perf_counter() - t0) / 10)
     said = {"us_a_call": best * 1e6 / (L * STEPS), "lanes": B,
             "served": served, "finite": finite}
+    if profile:
+        said["device_ops"] = device_ops(fn, k, v, ln)   # (the pools' last use)
     count = getattr(attention, "paged_live_pages", None)
     if count is not None:
         live, visited = count(
@@ -153,15 +193,18 @@ def main(argv) -> int:
         same = {n: bool(np.array_equal(a[n], b[n])) for n in a.files}
         print(json.dumps(same))
         return 0 if all(same.values()) and set(a.files) == set(b.files) else 1
-    out = None
-    if argv[:1] == ["--out"]:
-        out, argv = argv[1], argv[2:]
+    profile = "--profile" in argv
+    argv = [a for a in argv if a != "--profile"]
+    given = {"--out": None, "--unserved": "0"}
+    while argv[:1] and argv[0] in given:
+        given[argv[0]], argv = argv[1], argv[2:]
+    out, unserved = given["--out"], int(given["--unserved"])
     if os.environ.get("REHEARSE"):
         from dynamo_tpu.ops import attention
         attention.paged_kernel_variant = lambda interpret: "dma"
     kept = {}
     for name in argv or [c for c in CASES if c != "tiny"]:
-        said, arrays = case(name)
+        said, arrays = case(name, unserved, profile)
         print(name, json.dumps(said), flush=True)
         kept.update({f"{name}.{n}": a for n, a in arrays.items()})
     if out:

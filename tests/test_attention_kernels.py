@@ -360,11 +360,12 @@ _WRITE_LENGTHS = [33, 32, 16, 17, 77, 128, 0, 1]
 def test_the_kernel_that_writes_is_kv_write_then_the_kernel(
         case, Dh, Dv, fold, window, selected, sunk, ppb, lanes):
     """``new`` rows handed to the dma kernel (interpreter) against
-    ``kv_write`` followed by the kernel as it was: the SAME attention output
-    and the SAME pools, bit for bit, every variant a cell runs. Excepted and
-    named: the empty lane's scratch row. ``kv_write`` takes position -1 for
-    it and puts its rows at the page table's LAST entry, offset page - 1;
-    the kernel writes nothing for a lane of no tokens."""
+    ``kv_write`` followed by the kernel that does not write: the SAME
+    attention output and the SAME pools, bit for bit, every variant a cell
+    runs. Excepted and named: the empty lane's scratch row. ``kv_write``
+    takes position -1 for it and puts its rows at the page table's LAST
+    entry, offset page - 1; the kernel skips a lane of no tokens: exact
+    zeros out, nothing written."""
     from dynamo_tpu.models.llama import kv_write
     from dynamo_tpu.ops.attention import _paged_attention_tpu
 
@@ -398,7 +399,7 @@ def test_the_kernel_that_writes_is_kv_write_then_the_kernel(
     k_want = kv_write(k_pool, layer, w_page, pos % page, k_new)
     v_want = kv_write(v_pool, layer, w_page, pos % page, v_new)
     want = _paged_attention_tpu(q, k_want, v_want, ly, page_tables,
-                                jnp.maximum(lengths, 1), **kw)
+                                lengths, **kw)
     got, k_got, v_got = _paged_attention_tpu(
         q, k_pool, v_pool, ly, page_tables, lengths, new=(k_new, v_new), **kw)
 
@@ -406,6 +407,8 @@ def test_the_kernel_that_writes_is_kv_write_then_the_kernel(
                                   np.asarray(want, np.float32))
     empty = [b for b, n in enumerate(lanes) if n == 0]
     assert empty
+    assert not np.asarray(got, np.float32)[empty].any()
+    assert np.asarray(got, np.float32)[lanes.index(1)].any()
     for got_pool, want_pool, old in ((k_got, k_want, k_pool),
                                      (v_got, v_want, v_pool)):
         got_pool, want_pool = (np.array(a, np.float32)
@@ -454,9 +457,9 @@ def test_which_pools_the_kernel_writes(monkeypatch, kernel, Dh, fold, writes):
 # ---------------------------------------------------------------------------
 
 # lengths over a table of 8 pages of 32 (four blocks at ppb 2): an EMPTY lane
-# and one token (both on an all-zero table, as the engine hands over a lane
-# it does not serve), a whole page and a page + 1, a whole block and a block
-# + 1, mid-page in the second block, several blocks, the table's last rows
+# (as the decode program hands the kernel a lane the dispatch does not serve)
+# and one token, a whole page and a page + 1, a whole block and a block + 1,
+# mid-page in the second block, several blocks, the table's last rows
 _LIVE_LENGTHS = [0, 1, 32, 33, 64, 65, 77, 128, 130, 200, 255, 256]
 _LIVE_CASES = [
     # case, Dh, Dv, fold, window, selected, sunk
@@ -472,8 +475,7 @@ _LIVE_CASES = [
 
 def _visible_pages(n, page, window):
     """Pages that hold a token a query at ``n - 1`` sees, token by token (a
-    lane of 0 attends like a lane of 1)."""
-    n = max(n, 1)
+    lane of 0 sees none)."""
     return sorted({t // page
                    for t in range(max(n - window, 0) if window else 0, n)})
 
@@ -484,7 +486,10 @@ def _visible_pages(n, page, window):
 def test_dead_pages_are_never_read(case, Dh, Dv, fold, window, selected,
                                    sunk, writes):
     """Every table entry past a lane's last token, and behind its window,
-    names ONE page that is NaN in both pools and every layer: the dma kernel
+    names ONE page that is NaN in both pools and every layer, and so does
+    EVERY entry of the empty lane's table; scratch page 0 is NaN too. The
+    empty lane comes out as exact zeros (it is skipped: no page read, no
+    row written); for the others the dma kernel
     (interpreter) still gives what a float32 reference gives over the visible
     tokens, and with ``new`` the pools it hands back are ``kv_write``'s, the
     poisoned page as it was. The kernel as it stood before it told a block's
@@ -510,21 +515,19 @@ def test_dead_pages_are_never_read(case, Dh, Dv, fold, window, selected,
 
     tables = np.full((B, P), poison, np.int32)
     for b, n in enumerate(lanes):
-        if n <= 1:
-            tables[b] = 0       # a lane the dispatch does not serve
-        else:
-            seen = _visible_pages(n, page, window)
-            tables[b, seen] = 1 + b * P + np.asarray(seen)
+        seen = _visible_pages(n, page, window)      # none for the empty lane
+        tables[b, seen] = 1 + b * P + np.asarray(seen, np.int32)
     live, visited = paged_live_pages(lanes, P, page, ppb, window)
     assert list(live) == [len(_visible_pages(n, page, window))
                           for n in lanes]
-    assert list(live[2:]) == list((tables[2:] != poison).sum(1))
+    assert list(live) == list((tables != poison).sum(1))
     assert (live < visited).any() and (live <= visited).all()
+    assert (live[0], visited[0]) == (0, 0)
 
     q = rand(ks[0], B, Hkv, G, Dh)
     pools = (rand(ks[1], L, Hkv, n_pages, page // fold, fold * Dh),
              rand(ks[2], L, Hkv, n_pages, page // fold, fold * Dv))
-    k_pool, v_pool = (p.at[:, :, poison].set(jnp.nan) for p in pools)
+    k_pool, v_pool = (p.at[:, :, (0, poison), :].set(jnp.nan) for p in pools)
     k_new, v_new = rand(ks[3], B, Hkv, Dh), rand(ks[4], B, Hkv, Dv)
     lengths = jnp.asarray(lanes, jnp.int32)
     page_tables = jnp.asarray(tables)
@@ -551,32 +554,28 @@ def test_dead_pages_are_never_read(case, Dh, Dv, fold, window, selected,
             got_pool, want_pool, old = (np.array(a, np.float32)
                                         for a in (got_pool, want_pool, old))
             # kv_write's scratch row for the empty lane (position -1: the
-            # table's last entry, offset page - 1); the kernel writes none
-            scratch = (layer, slice(None), 0, (page - 1) // fold)
-            np.testing.assert_array_equal(got_pool[scratch], old[scratch])
+            # table's last entry, offset page - 1: the poisoned page, NaN
+            # before the scatter); the kernel writes none: both NaN pages
+            # are as they were and no other row differs from kv_write's
+            scratch = (layer, slice(None), poison, (page - 1) // fold)
             want_pool[scratch] = old[scratch]
-            assert np.isnan(got_pool[:, :, poison]).all()
+            assert np.isnan(got_pool[:, :, (0, poison)]).all()
             np.testing.assert_array_equal(got_pool, want_pool)
         ref_pools = got_pools
     else:
         got = _paged_attention_tpu(q, k_pool, v_pool, ly, page_tables,
-                                   jnp.maximum(lengths, 1), **kw)
+                                   lengths, **kw)
         ref_pools = (k_pool, v_pool)
     got = np.asarray(got, np.float32)
     assert np.isfinite(got).all()
+    assert lanes[0] == 0 and not got[0].any()
 
-    # the reference: float32, over the tokens a lane's query sees alone. With
-    # ``new`` the empty lane reads row 0 of page 0 before the lane of one
-    # token (the next in the grid) writes its own there
-    def rows(pools):
-        return [np.asarray(p[layer], np.float32).reshape(Hkv, n_pages, page,
-                                                         -1) for p in pools]
-
-    before, after = rows((k_pool, v_pool)), rows(ref_pools)
+    # the reference: float32, over the tokens a lane's query sees alone (none
+    # for the empty lane: zeros)
+    rk, rv = [np.asarray(p[layer], np.float32).reshape(Hkv, n_pages, page, -1)
+              for p in ref_pools]
     qf = np.asarray(q, np.float32)
     for b, n in enumerate(lanes):
-        rk, rv = before if n == 0 else after
-        n = max(n, 1)
         t = np.arange(max(n - window, 0) if window else 0, n)
         if keep is not None:
             t = t[np.asarray(keep[b])[t]]
@@ -591,6 +590,187 @@ def test_dead_pages_are_never_read(case, Dh, Dv, fold, window, selected,
                                                               den)
         np.testing.assert_allclose(got[b], want, atol=2e-2, rtol=2e-2,
                                    err_msg=f"lane {b} of {n}")
+
+
+# ---------------------------------------------------------------------------
+# A lane of no tokens is skipped: zeros out, no page read, nothing written
+# ---------------------------------------------------------------------------
+
+_SKIP_CASES = [
+    # case, Dh, Dv, fold, window, selected, sunk, latent
+    ("plain", 128, 128, 1, None, False, False, False),
+    ("window", 128, 128, 1, 40, False, False, False),
+    ("selected", 128, 128, 1, None, True, False, False),     # keye
+    ("sunk-dv", 256, 128, 1, None, False, True, False),      # mimo, full
+    ("window-sunk-dv", 256, 128, 1, 40, False, True, False),  # mimo, window
+    ("latent", 128, 256, 1, None, False, False, True),       # deepseek
+    ("fold2", 64, 64, 2, None, False, False, False),         # granite, lfm2
+    ("fold2-window", 64, 64, 2, 40, False, False, False),
+]
+# six lanes over tables of four pages of 32 (two blocks at ppb 2); 0 = a lane
+# the dispatch does not serve. The prime has to find the first served lane,
+# the chain to hop over one empty lane, over several, and off the end
+_SKIP_LAYOUTS = {
+    "first": [0, 70, 33, 128, 1, 64],
+    "last": [70, 33, 128, 1, 64, 0],
+    "adjacent": [70, 0, 0, 0, 128, 33],
+    "ends-and-between": [0, 0, 65, 0, 1, 0],
+    "all": [0, 0, 0, 0, 0, 0],
+}
+
+
+# every layout with the kernel that writes (what the cells run); the kernel
+# that only reads where the chain hops most and where it never starts
+_SKIP_RUNS = [(layout, writes) for layout in _SKIP_LAYOUTS
+              for writes in (True, False)
+              if writes or layout in ("ends-and-between", "all")]
+
+
+@pytest.mark.parametrize("shape,dtype,want", [
+    ((32, 2, 6, 128), jnp.bfloat16, 32 * 2 * 16 * 128 * 2),   # chat's queries
+    ((64, 1, 1024), jnp.bfloat16, 64 * 16 * 1024 * 2),  # granite's new rows
+    ((16, 1, 128, 512), jnp.bfloat16, 16 * 128 * 512 * 2),  # deepseek: whole
+    ((8, 4, 1), jnp.float32, 8 * 8 * 128 * 4),
+])
+def test_vmem_bytes_pad_the_last_two_dimensions_to_a_tile(shape, dtype, want):
+    from dynamo_tpu.ops.attention import _vmem_bytes
+
+    assert _vmem_bytes(shape, dtype) == want
+
+
+@pytest.mark.parametrize("lanes,a_lane,want", [
+    (32, 1 << 15, 1),                    # chat: 1 MiB in all
+    (64, (12 << 20) // 64, 1),           # just fits, whole
+    (64, (12 << 20) // 64 + 1, 4),       # ... and just not: the pipeline
+    (64, 1 << 18, 4),                    # holds two blocks of 16 lanes
+    (64, 1 << 16, 1), (64, 3 << 16, 1),
+    (6, 5 << 20, 6), (7, 2 << 20, 7),    # a lane a step; 7 has no divisor
+])
+def test_lane_groups_are_the_fewest_that_fit(lanes, a_lane, want):
+    from dynamo_tpu.ops.attention import _lane_groups
+
+    assert _lane_groups(lanes, a_lane) == want
+
+
+@pytest.mark.parametrize("groups", [2, 6], ids=["two-groups", "a-lane-a-step"])
+@pytest.mark.parametrize("case", ["plain", "latent", "selected",
+                                  "fold2-window"])
+def test_a_batch_walked_in_groups_of_lanes_is_skipped_the_same(
+        monkeypatch, case, groups):
+    """The hardest layout of the test below, kernel that writes, for a batch
+    whose per-lane operands do not fit in VMEM whole (every call of this file
+    does): several grid steps, the chain of copies and the ring of
+    write-backs running on across them."""
+    from dynamo_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_lane_groups", lambda lanes, a_lane: (
+        groups if lanes % groups == 0 else lanes))
+    at = [c[0] for c in _SKIP_CASES].index(case)
+    test_a_lane_of_no_tokens_is_skipped(*_SKIP_CASES[at], "ends-and-between",
+                                        True)
+
+
+@pytest.mark.parametrize("layout,writes", _SKIP_RUNS, ids=[
+    f"{layout}-{'writes' if writes else 'reads'}"
+    for layout, writes in _SKIP_RUNS])
+@pytest.mark.parametrize("case,Dh,Dv,fold,window,selected,sunk,latent",
+                         _SKIP_CASES, ids=[c[0] for c in _SKIP_CASES])
+def test_a_lane_of_no_tokens_is_skipped(case, Dh, Dv, fold, window, selected,
+                                        sunk, latent, layout, writes):
+    """Lanes of length 0 as the decode program hands the dma kernel
+    (interpreter) the lanes a dispatch does not serve: an all-zero table,
+    and scratch page 0, which every table's unused entries name too, NaN in
+    both pools and every layer. (a) No page of such a lane is read: every
+    output is finite. (b) Its output is exact zeros. (c) Nothing is written
+    for it: page 0 stays NaN, and with no lane served the pools come back as
+    they went in. And the lanes that ARE served are not touched by the
+    skipping: outputs and pools are, bit for bit, those of a call whose batch
+    holds the served lanes alone."""
+    from dynamo_tpu.ops.attention import _paged_attention_tpu
+
+    L, layer, G, page, ppb, P = 2, 1, 2, 32, 2, 4
+    Hkv = 1 if latent else 2
+    lanes = np.asarray(_SKIP_LAYOUTS[layout])
+    B = len(lanes)
+    served = np.flatnonzero(lanes)
+    n_pages = B * P + 1
+    ks = jax.random.split(jax.random.PRNGKey(46), 8)
+    bf = jnp.bfloat16
+
+    def rand(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(bf)
+
+    tables = np.zeros((B, P), np.int32)
+    for b in served:
+        held = -(-int(lanes[b]) // page)
+        tables[b, :held] = 1 + b * P + np.arange(held)
+    q = rand(ks[0], B, Hkv, G, Dh)
+    k_pool, v_pool = (
+        rand(key, L, Hkv, n_pages, page // fold, fold * d)
+        .at[:, :, 0].set(jnp.nan) for key, d in ((ks[1], Dh), (ks[2], Dv)))
+    new = (rand(ks[3], B, Hkv, Dh), rand(ks[4], B, Hkv, Dv))
+    by_lane = {}                      # operands with a row a lane
+    if selected:
+        # a lane's own token is always among its selected keys
+        by_lane["keep"] = jax.random.bernoulli(
+            ks[5], 0.5, (B, P * page)).at[served, lanes[served] - 1].set(True)
+    if latent:
+        by_lane["latent"] = rand(ks[7], B, Hkv, G, Dv)
+    kw = dict(pages_per_block=ppb, window=window, interpret=True,
+              stored_fold=fold)
+    if sunk:
+        kw["sink"] = jax.random.normal(ks[6], (Hkv * G,), jnp.float32)
+    ly = jnp.asarray([layer], jnp.int32)
+
+    def call(rows):
+        res = _paged_attention_tpu(
+            q[rows], k_pool, v_pool, ly, jnp.asarray(tables[rows]),
+            jnp.asarray(lanes[rows], jnp.int32),
+            **{n: a[rows] for n, a in by_lane.items()}, **kw,
+            **({"new": tuple(a[rows] for a in new)} if writes else {}))
+        out, *pools = res if writes else (res,)
+        return (np.asarray(out, np.float32),
+                *(np.asarray(p, np.float32) for p in pools))
+
+    got, *got_pools = call(np.arange(B))
+    assert np.isfinite(got).all()
+    assert not got[lanes == 0].any()
+    for pool in got_pools:
+        assert np.isnan(pool[:, :, 0]).all()
+    if not len(served):
+        for pool, old in zip(got_pools, (k_pool, v_pool)):
+            np.testing.assert_array_equal(pool, np.asarray(old, np.float32))
+        return
+    want, *want_pools = call(served)
+    assert want.any(axis=(1, 2, 3)).all()
+    np.testing.assert_array_equal(got[served], want)
+    for pool, other in zip(got_pools, want_pools):
+        np.testing.assert_array_equal(pool, other)
+
+
+@pytest.mark.parametrize("sunk", [False, True], ids=["plain", "sunk"])
+def test_the_one_page_kernel_gives_a_lane_of_no_tokens_zeros(sunk):
+    """The kernel the CPU and ``DYNAMO_TPU_PAGED_KERNEL=simple`` run stays
+    interchangeable with the dma kernel: exact zeros for a lane of length 0
+    (its pipeline still copies the page its table names, here a NaN one, and
+    computes nothing over it), the other lanes as they were."""
+    B, Hq, Hkv, Dh, page, P = 4, 4, 2, 128, 32, 2
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(ks[0], (B, Hq, Dh), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (Hkv, B * P + 1, page, Dh), jnp.bfloat16)
+            .at[:, 0].set(jnp.nan) for key in ks[1:3])
+    lengths = jnp.asarray([0, 40, 0, 1], jnp.int32)
+    tables = (1 + jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
+              ) * (lengths > 0)[:, None]
+    kw = {"sink": jax.random.normal(ks[3], (Hq,), jnp.float32)} if sunk else {}
+    got = np.asarray(paged_attention(q, k, v, tables, lengths, interpret=True,
+                                     **kw), np.float32)
+    assert np.isfinite(got).all()
+    assert not got[[0, 2]].any() and got[[1, 3]].any(axis=(1, 2)).all()
+    alone = np.asarray(paged_attention(q[1::2], k, v, tables[1::2],
+                                       lengths[1::2], interpret=True, **kw),
+                       np.float32)
+    np.testing.assert_array_equal(got[1::2], alone)
 
 
 @pytest.mark.parametrize("window", [None, 40, 64, 200])
@@ -610,6 +790,8 @@ def test_live_pages_counted_token_by_token(window, ppb, P):
     assert list(live) == [len(pgs) for pgs in seen]
     assert list(visited) == [len({p // width for p in pgs}) * width
                              for pgs in seen]
+    # the lane the kernel skips: nothing copied, no block it is in
+    assert lengths[0] == 0 and (live[0], visited[0]) == (0, 0)
     steps = np.asarray(lengths)[:, None] + np.arange(3)
     steps = np.minimum(steps, P * page)
     live2, visited2 = paged_live_pages(steps, P, page, ppb, window)

@@ -159,6 +159,27 @@ def test_the_kernel_that_copies_live_pages_compiles_at_the_cells_shapes(
     assert f"(bf16[{B},{Hkv},{Hq // Hkv},{Dv}]" in txt
 
 
+def test_a_batch_past_vmem_compiles_in_groups_of_lanes(v5e):
+    """256 lanes at llama-8b's heads: the per-lane operands (queries, new
+    rows, output: 128 KiB a lane as VMEM lays them out) do not fit whole, so
+    the call walks them in grid steps of a group each, blocks through the
+    grid's pipeline; the v5e compiler takes that form too."""
+    B, Hq, Hkv, D, P, L, n_pages = 256, 32, 8, 128, 32, 2, 40
+    bf, i32 = jnp.bfloat16, jnp.int32
+    a_lane = (2 * A._vmem_bytes((Hkv, Hq // Hkv, D), bf)
+              + 2 * A._vmem_bytes((1, Hkv * D), bf))
+    assert A._lane_groups(B, a_lane) == 8
+    txt = _compiled_text(
+        lambda q, k, v, pt, ln, ly, kn, vn: A.paged_attention(
+            q, k, v, pt, ln, ly, interpret=False, new=(kn, vn)),
+        _sds(v5e, (B, Hq, D), bf), _sds(v5e, (L, Hkv, n_pages, PAGE, D), bf),
+        _sds(v5e, (L, Hkv, n_pages, PAGE, D), bf), _sds(v5e, (B, P), i32),
+        _sds(v5e, (B,), i32), _sds(v5e, (), i32),
+        _sds(v5e, (B, Hkv, D), bf), _sds(v5e, (B, Hkv, D), bf))
+    assert txt.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"(bf16[{B},{Hkv},{Hq // Hkv},{D}]" in txt
+
+
 def test_the_latent_decode_kernel_compiles_at_the_cells_shapes(v5e):
     """deepseek-v2-5l's decode kernel as its program calls it: 16 lanes, all
     128 heads against ONE row a key (the shared rotary key's pool, 128 lanes

@@ -1396,10 +1396,13 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
     on four lanes through the dma kernel (interpreter): the tokens are the
     dense path's (float32, as above), and every decode dispatch moves
     ``dyn_attn_pages_live_total`` / ``dyn_attn_pages_visited_total`` by what
-    ``paged_live_pages`` gives for ALL lanes of the program as the dispatch
-    hands them over (the two it does not serve: length 1), a token longer
-    each step, by attention kind; a capture counts its own dispatches a
-    second time, under the counters' names."""
+    ``paged_live_pages`` gives for the lanes of the program as the KERNEL is
+    handed them (the two the dispatch does not serve: length 0, skipped, no
+    page), a token longer each step, by attention kind;
+    ``dyn_attn_lane_calls_total`` by lanes x steps x layers and
+    ``dyn_attn_lane_calls_skipped_total`` by the unserved part of them; a
+    capture counts its own dispatches a second time, under the counters'
+    names."""
     from dynamo_tpu.engine import engine as E
     from dynamo_tpu.ops import attention as A
 
@@ -1416,10 +1419,11 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
         got = drain(core, list(reqs))
         while core.has_work:        # the overshoot dispatch behind the finish
             core.step()
-        return {n: [g.token for g in got[n]] for n in reqs}
+        return ({n: [g.token for g in got[n]] for n in reqs},
+                np.asarray([g.token_logprob for n in reqs for g in got[n]]))
 
     dense = EngineCore(make_cfg(attn_impl="xla", **cfg))
-    want = serve(dense)
+    want, want_logps = serve(dense)
     assert not dense.stage.attn_pages_visited._values   # no kernel, no pages
     for mod in (A, E):
         monkeypatch.setattr(mod, "paged_kernel_variant",
@@ -1432,12 +1436,15 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
     assert core._attn_calls == {"full": (None, 1), "window": (8, 1)}
     seen = []
     core.dispatch_hook = lambda kind, meta, arrs: kind == "decode" and (
-        seen.append((meta["S"], arrs["lengths"].copy(), core.capturing)))
+        seen.append((meta["S"], arrs["lengths"].copy(),
+                     arrs["active_mask"].copy(), core.capturing)))
     st = core.stage
     # (the series are the process's: what earlier tests of this worker left
     # there is the baseline)
     base = dict(st.profile_captured_work._values)
-    assert serve(core) == want
+    got, logps = serve(core)
+    assert got == want
+    np.testing.assert_allclose(logps, want_logps, atol=2e-5)
     assert st.profile_captured_work._values == base
     core.capturing = True
     try:
@@ -1448,17 +1455,23 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
 
     N, page = core.cfg.decode_steps, core.page_size
     want = {}
-    for S, lengths, captured in seen:
-        assert (lengths == 1).sum() >= 2          # the lanes not served
-        at = lengths[:, None] + np.arange(N)
+    for S, lengths, served, captured in seen:
+        # the lanes not served: a slot for their row, nothing to attend over
+        assert (~served).sum() >= 2 and (lengths[~served] == 1).all()
+        at = np.where(served[:, None], lengths[:, None] + np.arange(N), 0)
         for kind, window in (("full", None), ("window", 8)):
             pages = A.paged_live_pages(at, S // page, page, 8, window)
-            for name, n in zip(("live", "visited"), pages):
+            assert not pages[0][~served].any() and pages[0][served].all()
+            for name, n in zip(
+                    ("live", "visited", "lanes", "skipped"),
+                    (*pages, np.int64(4 * N), (~served).sum() * N)):
                 for key in [(name, kind)] + [(name, kind, "cap")] * captured:
                     want[key] = want.get(key, 0) + n.sum()    # one layer each
     assert any(c for *_, c in seen) and not all(c for *_, c in seen)
     for name, counter in (("live", st.attn_pages_live),
-                          ("visited", st.attn_pages_visited)):
+                          ("visited", st.attn_pages_visited),
+                          ("lanes", st.attn_lane_calls),
+                          ("skipped", st.attn_lane_calls_skipped)):
         for kind in ("full", "window"):
             assert counter.get(kind) == want[name, kind] > 0
             assert st.profile_captured_work.get(counter.name, kind) == \
@@ -1466,3 +1479,7 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
     # a window of one page sees two pages at most; a block holds eight
     assert st.attn_pages_live.get("window") < st.attn_pages_live.get("full")
     assert st.attn_pages_live.get("full") < st.attn_pages_visited.get("full")
+    # half the program's lanes or more were never served
+    assert (st.attn_lane_calls.get("full") / 2
+            <= st.attn_lane_calls_skipped.get("full")
+            < st.attn_lane_calls.get("full"))

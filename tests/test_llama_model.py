@@ -320,3 +320,75 @@ def test_decode_with_the_write_in_the_kernel_is_kv_write_then_the_kernel(
     want = serve()
     for g, w in zip(got, want):
         np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("preset,over", [
+    ("tiny-qwen", {"head_dim": 128}),
+    ("tiny-gemma2", {"head_dim": 128}),
+    ("tiny-keye", {"head_dim": 128}),
+    ("tiny-byte", {"head_dim": 64, "kv_fold": 2}),
+    ("tiny-byte", {"head_dim": 64}),                    # kv_write scatters
+], ids=["qwen-128", "gemma2-128", "keye-128", "fold2", "unfolded-64"])
+def test_a_lane_the_step_does_not_serve_is_skipped_by_the_paged_kernel(
+        monkeypatch, preset, over):
+    """Two chained decode steps of four lanes, two of them the engine's
+    unserved lanes (length 1, an all-zero table), through ``forward_decode``
+    on the dma kernel (interpreter), scratch page 0 NaN in both pools. With
+    ``active`` the kernel is handed those lanes as length 0 and skips them:
+    every logit of every lane is finite (page 0 was never read) and a kernel
+    that writes leaves page 0 as it was. The served lanes' tokens, logits
+    and pages are, bit for bit, those of the program as it was before the
+    kernel skipped (the same step, the kernel handed length 1 for those
+    lanes, over a page 0 that can be read)."""
+    from dynamo_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
+    page = 16
+    cfg = llama.preset(preset, **over)
+    fold = cfg.kv_fold
+    writes = llama.kernel_writes(None, "pallas", cfg.k_store_dim, fold)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    n_pages = 7
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    lead = (cfg.num_layers, cfg.num_kv_heads, n_pages, page // fold)
+    pools = [jax.random.normal(ks[0], (*lead, fold * cfg.k_store_dim),
+                               jnp.float32).astype(cfg.dtype),
+             jax.random.normal(ks[1], (*lead, fold * cfg.v_dim),
+                               jnp.float32).astype(cfg.dtype)]
+    if cfg.has_indexer:
+        pools.append(jax.random.normal(
+            ks[2], llama.index_pool_shape(cfg, n_pages, page),
+            jnp.float32).astype(cfg.dtype))
+    poisoned = [p.at[:, :, 0].set(jnp.nan) for p in pools[:2]] + pools[2:]
+    pt = jnp.asarray([[0, 0, 0], [2, 5, 1], [0, 0, 0], [4, 3, 6]], jnp.int32)
+    served = np.asarray([False, True, False, True])
+
+    def serve(pools):
+        dec = jax.jit(lambda p, t, k, v, ln, *i: llama.forward_decode(
+            p, cfg, t, k, v, pt, ln, attn_impl="pallas",
+            active=jnp.asarray(served), **({"i_pool": i[0]} if i else {})))
+        tok = jnp.asarray([0, 5, 0, 7], jnp.int32)
+        ln = jnp.asarray([1, 16, 1, 31], jnp.int32)
+        state, out = list(pools), []
+        for _ in range(2):
+            lg, *state = dec(params, tok, *state[:2], ln, *state[2:])
+            tok = jnp.argmax(lg[:, 0], -1).astype(jnp.int32)
+            out += [np.asarray(tok), np.asarray(lg, np.float32)]
+            ln = jnp.where(served, ln + 1, 1)     # as the engine: 1 again
+        return out, [np.asarray(a, np.float32) for a in state]
+
+    got, got_pools = serve(poisoned)
+    skipping = A.paged_attention
+    monkeypatch.setattr(
+        A, "paged_attention", lambda q, k, v, pt, ln, *a, **kw: skipping(
+            q, k, v, pt, jnp.maximum(ln, 1), *a, **kw))
+    want, want_pools = serve(pools)
+    for g, w in zip(got, want):
+        assert np.isfinite(g).all()
+        np.testing.assert_array_equal(g[served], w[served])
+        # (the other lanes attended over page 0 there, over nothing here)
+        assert g.dtype != np.float32 or (g[~served] != w[~served]).any()
+    for g, w in zip(got_pools[:2], want_pools[:2]):
+        np.testing.assert_array_equal(g[:, :, 1:], w[:, :, 1:])
+        if writes:
+            assert np.isnan(g[:, :, 0]).all()
