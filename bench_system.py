@@ -988,6 +988,7 @@ def long_context_batch_lane(batch: int = 8, multiple: int = 4,
     arms: Dict[str, Any] = {}
     for name, nlanes in (("serial", 1), ("batched", batch)):
         core = EngineCore(paged_cfg(nlanes))
+        paged_kernel = core.paged_kernel or "none"
         try:
             arms[name] = _drive_backlog(core, prompts, max_tokens,
                                         rounds=rounds)
@@ -1046,11 +1047,9 @@ def long_context_batch_lane(batch: int = 8, multiple: int = 4,
                     if k != "tokens"},
         "decode_tok_s_speedup": speedup,
         "sliding": sliding_point,
-        # kernel provenance: which paged attention backend produced the
-        # numbers (CPU CI runs the interpreted simple kernel; a TPU run
-        # records the DMA kernel unless overridden)
-        "paged_kernel": (os.environ.get("DYNAMO_TPU_PAGED_KERNEL", "dma")
-                         if platform == "tpu" else "simple[interpret]"),
+        # kernel provenance: how the engine that produced the numbers ran
+        # decode attention (EngineCore.paged_kernel; "none" = dense XLA)
+        "paged_kernel": paged_kernel,
         "platform": platform,
     }
     point["checks"] = {

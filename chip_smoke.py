@@ -24,7 +24,7 @@ and exits 1):
   build      ``make -C native`` from native/*.cpp (nothing pre-built is
              trusted); the one-chip serve path is single-process and uses
              neither the store nor the data plane — the line says so.
-  kernels    flash + paged (dma, simple) numerics, compiled, at llama-3.2-1b
+  kernels    flash + paged numerics, compiled, at llama-3.2-1b
              head geometry, B up to 32, windowed/softcapped variants, against
              a dense float32 reference (max abs err < KERNEL_TOL).
   serve      the HTTP server at ENGINE_ARGS with warm-up; /v1/models, one
@@ -32,8 +32,8 @@ and exits 1):
              of mixed length (some longer than prefill_chunk), an over-length
              prompt (typed 400), /metrics. Asserts on usage and finish
              reasons, never on text (byte tokenizer vs 128k-id sampling).
-  what_ran   read from the live engine over /metrics: attention paths, paged
-             kernel variant, programs compiled before/after the requests (no
+  what_ran   read from the live engine over /metrics: attention paths, how the
+             paged kernel runs, programs compiled before/after the requests (no
              growth after warm-up), dyn_mfu / dyn_hbm_gbps > 0 against
              table:* peaks, peak HBM, cold start-up seconds.
   agreement  in one child, EngineCore at library level: first the SAME
@@ -624,9 +624,9 @@ def _dense_ref(q, k, v, q_pos, k_pos, k_valid, scale=None, softcap=None,
 
 
 def child_kernels(args) -> dict:
-    """Both Pallas kernels, compiled, against the dense f32 reference at
-    llama-3.2-1b head geometry (the numerics queue of the former
-    scripts/tpu_smoke.py)."""
+    """The flash and the paged kernel, compiled, against the dense f32
+    reference at llama-3.2-1b head geometry (the numerics queue of the
+    former scripts/tpu_smoke.py)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -660,8 +660,7 @@ def child_kernels(args) -> dict:
                                interpret=interpret, **kw),
                _dense_ref(q, k, v, q_pos, k_pos, k_valid, **kw))
 
-    def paged_case(B, variant, kw, name):
-        os.environ["DYNAMO_TPU_PAGED_KERNEL"] = variant
+    def paged_case(B, kw, name):
         n_pages = B * P + 1
         kq, kk, kv = jax.random.split(jax.random.PRNGKey(100 + B), 3)
         q = jax.random.normal(kq, (B, Hq, Dh), jnp.bfloat16)
@@ -693,15 +692,10 @@ def child_kernels(args) -> dict:
     flash_case(2, 5, 256, {}, "flash T=5")
     for B in batches[:3:2]:
         flash_case(B, 128, 256, gem, f"flash[window,softcap] B={B}")
-    try:
-        for variant in ("dma", "simple"):
-            for B in batches[::2] + batches[-1:]:
-                paged_case(B, variant, {}, f"paged[{variant}] B={B}")
-            for B in batches[:3:2]:
-                paged_case(B, variant, gem,
-                           f"paged[{variant}][window,softcap] B={B}")
-    finally:
-        os.environ.pop("DYNAMO_TPU_PAGED_KERNEL", None)
+    for B in batches[::2] + batches[-1:]:
+        paged_case(B, {}, f"paged B={B}")
+    for B in batches[:3:2]:
+        paged_case(B, gem, f"paged[window,softcap] B={B}")
     return {"phase": "kernels", "ok": True, "compiled": not interpret,
             "geometry": {"Hq": Hq, "Hkv": Hkv, "Dh": Dh, "page": page},
             "tolerance": KERNEL_TOL, "cases": cases,

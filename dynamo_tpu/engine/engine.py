@@ -41,11 +41,10 @@ from ..llm.model_card import ModelDeploymentCard
 from ..llm.protocols.common import BackendInput, EngineOutput, FinishReason
 from ..models import llama
 from ..obs import flightrec as _flightrec
-from ..ops.attention import (flash_attention, latent_flash_blocks,
-                             latent_flash_copies, latent_flash_fetch,
-                             paged_attention,
-                             paged_kernel_variant, paged_live_pages,
-                             paged_pages_per_block)
+from ..ops.attention import (PAGES_PER_BLOCK, flash_attention,
+                             latent_flash_blocks, latent_flash_copies,
+                             latent_flash_fetch, paged_attention,
+                             paged_live_pages)
 from ..parallel.mesh import AXIS_TP, serving_mesh
 from ..runtime.engine import AsyncEngine, Context
 from ..utils import tracing as _tracing
@@ -438,9 +437,6 @@ class EngineCore:
 
         # --- attention backend ---------------------------------------
         impl = cfg.attn_impl
-        if impl == "auto":
-            import os
-            impl = os.environ.get("DYNAMO_TPU_ATTN", "auto")
         if m.attn_logit_softcap or m.sliding_window is not None:
             # Gemma2/3: the Pallas flash/paged kernels take softcap +
             # sliding windows natively (round 5); only ring attention
@@ -484,24 +480,23 @@ class EngineCore:
                                      else "xla")
         else:
             self.decode_attn_impl = impl
-        # what decode's paged_attention call resolves to on these devices
-        # (None on the dense path) — reported, never used to select
-        self.paged_kernel = (paged_kernel_variant(not tpu)
+        # how decode's paged_attention call runs on these devices (None on
+        # the dense path) — reported, never used to select
+        self.paged_kernel = (("dma" if tpu else "dma[interpret]")
                              if self.decode_attn_impl == "pallas" else None)
         log.info("attention: prefill=%s decode=%s paged_kernel=%s on %s (%s)",
                  self.attn_impl, self.decode_attn_impl, self.paged_kernel,
                  dev0.platform, dev0.device_kind)
-        # the decode step's calls of the dma kernel, by the window they take
-        # (forward_decode's ``paged_for``): kind -> (window, layers), for
-        # _count_attn_pages; none where another kernel runs the step
+        # the decode step's calls of the paged kernel, by the window they
+        # take (forward_decode's ``paged_for``): kind -> (window, layers), for
+        # _count_attn_pages; none on the dense path
         self._attn_calls: Dict[str, Tuple[Optional[int], int]] = {}
-        if self.paged_kernel == "dma" and cfg.pp == 1:
+        if self.decode_attn_impl == "pallas" and cfg.pp == 1:
             slides = [bool(m.layer_sliding(l)) for l in range(m.num_layers)
                       if not m.layer_state(l)]
             calls = {"full": (None, slides.count(False)),
                      "window": (m.sliding_window, slides.count(True))}
             self._attn_calls = {k: c for k, c in calls.items() if c[1]}
-            self._paged_ppb = paged_pages_per_block()
 
         # --- KV pools: [L, Hkv, n_pages, page, Dh], head-major, stored
         # once in XLA's default tiled layout. The paged kernel reads the
@@ -1093,7 +1088,7 @@ class EngineCore:
         at = np.where(served[:, None], lengths[:, None] + np.arange(steps), 0)
         for kind, (window, layers) in self._attn_calls.items():
             live, visited = paged_live_pages(at, P, self.page_size,
-                                             self._paged_ppb, window)
+                                             PAGES_PER_BLOCK, window)
             for counter, n in ((st.attn_pages_live, live.sum()),
                                (st.attn_pages_visited, visited.sum()),
                                (st.attn_lane_calls, at.size),

@@ -1060,7 +1060,7 @@ PRESETS: Dict[str, Dict[str, Any]] = {
     # width (2 a token), q/k norm, an indexer (2 index heads, top-8) whose
     # selection binds at the tests' contexts of 24-48
     "tiny-keye": dict(vocab_size=259, hidden_size=64, num_layers=2,
-                      num_heads=4, num_kv_heads=2, head_dim=16,
+                      num_heads=4, num_kv_heads=2, head_dim=128,
                       intermediate_size=128, moe_intermediate_size=48,
                       rope_theta=10000.0, max_position=1024, rms_eps=1e-6,
                       num_experts=8, experts_per_token=2, qk_norm=True,
@@ -3298,18 +3298,16 @@ def _kernel_interpret(mesh) -> bool:
 def kernel_writes(mesh, attn_impl: str, row: int, fold: int) -> bool:
     """Whether :func:`forward_decode`'s attention kernel writes the step's
     new K/V rows itself, into pools whose K rows are stored ``row`` wide,
-    ``fold`` tokens to a pool row: the compiled paged dma kernel on one
-    shard, over a pool stored as it reads it
-    (``ops.attention.paged_kernel_writes``). Every other decode step
-    scatters them first (:func:`kv_write`): the dense and interpreted paths,
-    ``DYNAMO_TPU_PAGED_KERNEL=simple``, a tensor-parallel mesh (the kernel
-    runs inside a ``shard_map`` whose results are the attention output
-    alone), rows narrower than a lane tile stored unfolded. The engine
-    reports the answer for each of its cache kinds
-    (``dyn_engine_info{decode_kv_write}``)."""
+    ``fold`` tokens to a pool row: the paged kernel on one shard, over a pool
+    stored as it reads it (``ops.attention.paged_kernel_writes``). Every
+    other decode step scatters them first (:func:`kv_write`): the dense
+    path, a tensor-parallel mesh (the kernel runs inside a ``shard_map``
+    whose results are the attention output alone), rows narrower than a lane
+    tile stored unfolded. The engine reports the answer for each of its
+    cache kinds (``dyn_engine_info{decode_kv_write}``)."""
     from ..ops.attention import paged_kernel_writes
     return (attn_impl == "pallas" and _tp_size(mesh) == 1
-            and paged_kernel_writes(_kernel_interpret(mesh), row, fold))
+            and paged_kernel_writes(row, fold))
 
 
 def _tp_size(mesh) -> int:

@@ -7,8 +7,8 @@ Two kernels cover the two hot paths:
   the engine's paged write-then-gather scheme unchanged: the [T,S] score
   matrix never materializes in HBM.
 - :func:`paged_attention` — decode attention that reads KV *pages* directly
-  from the HBM pool through a scalar-prefetched page table (one grid step per
-  page, Pallas double-buffers the page DMAs). This removes the
+  from the HBM pool through a scalar-prefetched page table (blocks of pages
+  copied into a double buffer by the kernel's own DMAs). This removes the
   gather-into-contiguous-context copy entirely, which is the dominant HBM
   traffic of decode.
 
@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -476,7 +475,7 @@ def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
 # ---------------------------------------------------------------------------
 # Paged attention (decode directly over the HBM page pool)
 #
-# TPU path: multi-page double-buffered DMA kernel. The KV pool stays in HBM
+# One multi-page double-buffered DMA kernel. The KV pool stays in HBM
 # (memory_space=ANY); the kernel walks the lanes, and each lane's blocks of
 # ``pages_per_block`` pages, in loops of its own (one grid step for the
 # whole batch where its per-lane operands fit in VMEM: a grid step costs a
@@ -502,11 +501,6 @@ def _flash_call(q, k, v, q_pos, k_pos, k_valid, interpret, scale, softcap,
 # = 4) its m/l pallas outputs lower to illegal (…,1) blocks in this JAX
 # version. Keeping m/l in scratch sidesteps that and drops two HBM outputs.
 # ---------------------------------------------------------------------------
-
-
-def _lane_block(b, j, *prefetched):
-    """Index map of the per-lane q / output block of the one-page kernel."""
-    return (b, 0, 0, 0)
 
 
 def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
@@ -825,6 +819,12 @@ def _paged_dma_kernel(pt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                     write_wait(w)
 
 
+# pages of a lane the kernel copies and attends over as one block: larger
+# blocks amortise the issue of their copies, smaller ones cut what the last,
+# partial block of a lane wastes
+PAGES_PER_BLOCK = 8
+
+
 # lanes whose write-back may be on its way at once (the staging ring of
 # _paged_dma_kernel): a lane's grid steps take about as long as one copy
 _WRITE_RING = 4
@@ -868,7 +868,7 @@ def _write_group(rows_pp: int, dtype) -> int:
 
 
 def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
-                         *, pages_per_block: int = 8,
+                         *, pages_per_block: int = PAGES_PER_BLOCK,
                          scale: Optional[float] = None,
                          softcap: Optional[float] = None,
                          window: Optional[int] = None,
@@ -881,9 +881,9 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     """q4: [B, Hkv, G, Dh]; pools [L, Hkv, n_pages, page, Dh] (V: Dv), or
     STORED folded ([.., page // f, f * Dh], ``stored_fold`` f = 128 // Dh:
     the order the copies take, read in place); layer: [1]
-    int32; keep: [B, P * page] bool or None. Returns q4-shaped. ``interpret`` exists for the CPU test suite
-    only — the serving path always compiles this variant (paged_attention
-    gates it to real TPUs). ``new`` = (k_new [B, Hkv, Dh], v_new [B, Hkv,
+    int32; keep: [B, P * page] bool or None. Returns q4-shaped.
+    ``interpret`` runs the kernel in the Pallas interpreter (every process
+    that is not on a TPU). ``new`` = (k_new [B, Hkv, Dh], v_new [B, Hkv,
     Dv]): the kernel writes the rows of token ``lengths - 1`` itself
     (a lane of length 0 is skipped whole and writes nothing) and the result is
     (out, k_pool, v_pool), the pools aliased to the operands; they must be
@@ -1038,110 +1038,6 @@ def _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables, lengths,
     return out, k_pool.reshape(stored[0]), v_pool.reshape(stored[1])
 
 
-def _paged_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, *rest,
-                  scale: float, page: int, softcap: Optional[float],
-                  window: Optional[int], selected: bool,
-                  sunk: bool = False, latent: bool = False):
-    keep_ref, rest = (rest[0], rest[1:]) if selected else (None, rest)
-    sink_ref, rest = (rest[0], rest[1:]) if sunk else (None, rest)
-    lat_ref, rest = (rest[0], rest[1:]) if latent else (None, rest)
-    o_ref, m_scr, l_scr, acc_scr = rest
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-
-    @pl.when(p == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[b]
-    npages = (length + page - 1) // page
-    if window is None:
-        in_range = p < npages
-    else:
-        # page-range clamp: pages wholly below the window start contribute
-        # nothing — skip their compute entirely
-        pstart = jnp.maximum(length - window, 0) // page
-        in_range = (p >= pstart) & (p < npages)
-
-    @pl.when(in_range)
-    def _():
-        q = q_ref[0]                                       # [Hkv, G, Dh]
-        k = k_ref[0, :, 0]                                 # [Hkv, page, Dh]
-        v = v_ref[0, :, 0]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [Hkv, G, page]
-        if lat_ref is not None:
-            s = s + jax.lax.dot_general(
-                lat_ref[0], v, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-        s = s * scale
-        if softcap is not None:
-            # cap BEFORE masking (tanh(NEG_INF) would be a finite ±cap)
-            s = jnp.tanh(s / softcap) * softcap
-        tok = jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2) + p * page
-        mask = tok < length
-        if window is not None:
-            mask = mask & (tok >= length - window)
-        if keep_ref is not None:
-            mask = mask & (keep_ref[...] > 0)              # [1, 1, page]
-        m_prev = m_scr[:]
-        m_cur = jnp.max(jnp.where(mask, s, NEG_INF), axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        pw = jnp.where(mask, jnp.exp(s - m_new), 0.0)      # [Hkv, G, page]
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = alpha * l_scr[:] + jnp.sum(pw, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            pw.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [Hkv, G, Dh]
-        m_scr[:] = m_new
-
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _():
-        l = l_scr[:]
-        if sink_ref is not None:
-            l = l + jnp.exp(sink_ref[...] - m_scr[:])
-        o = acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = o.astype(o_ref.dtype)
-
-
-def paged_kernel_variant(interpret: bool) -> str:
-    """Which paged-attention kernel :func:`paged_attention` runs:
-    ``dma`` (multi-page double-buffered, compiled — the default on a TPU),
-    ``simple`` (one page per grid step, compiled —
-    ``DYNAMO_TPU_PAGED_KERNEL=simple``) or ``simple[interpret]`` (off-TPU).
-    The engine reports this same value, so what a run says it ran is what
-    the kernel entry point selected."""
-    variant = os.environ.get("DYNAMO_TPU_PAGED_KERNEL", "dma")
-    if variant not in ("dma", "simple"):
-        # repo convention: a typo'd env flag must not silently select the
-        # slow path (cf. DYNAMO_TPU_DATAPLANE / DYNAMO_TPU_STORE)
-        raise ValueError(f"DYNAMO_TPU_PAGED_KERNEL={variant!r} "
-                         f"(expected dma|simple)")
-    return "simple[interpret]" if interpret else variant
-
-
-def paged_pages_per_block() -> int:
-    """Pages the dma kernel copies a grid step (``DYNAMO_TPU_PAGED_PPB``,
-    default 8): the DMA depth knob for on-chip tuning sweeps (read the
-    kernel's time in a traced benchmark run's ops_by_module) — larger blocks
-    amortize DMA issue latency, smaller ones cut the tail wasted on the
-    final partial block. Validated like the sibling DYNAMO_TPU_PAGED_KERNEL
-    knob: a typo must fail loudly, not surface as a ZeroDivisionError deep
-    in the grid math."""
-    raw_ppb = os.environ.get("DYNAMO_TPU_PAGED_PPB", "8")
-    try:
-        ppb = int(raw_ppb)
-    except ValueError:
-        ppb = -1
-    if not 1 <= ppb <= 64:
-        raise ValueError(f"DYNAMO_TPU_PAGED_PPB={raw_ppb!r} "
-                         f"(expected an integer in [1, 64])")
-    return ppb
-
-
 def paged_live_pages(lengths, P: int, page: int, ppb: int,
                      window: Optional[int] = None):
     """The dma kernel's own arithmetic on the host (NumPy), for one call of
@@ -1164,14 +1060,13 @@ def paged_live_pages(lengths, P: int, page: int, ppb: int,
     return live, visited
 
 
-def paged_kernel_writes(interpret: bool, head_dim: int, fold: int) -> bool:
+def paged_kernel_writes(head_dim: int, fold: int) -> bool:
     """Whether :func:`paged_attention` can take a decode step's new rows
-    (``new``) and write them itself: on the dma kernel, into a pool stored as
-    that kernel reads it (rows of ``head_dim`` >= 128, or ``fold`` = 128 //
-    ``head_dim`` tokens to a row). Any other pool keeps ``kv_write``; the
-    engine reports which (``dyn_engine_info{decode_kv_write}``)."""
-    return (paged_kernel_variant(interpret) == "dma"
-            and fold == max(1, 128 // head_dim))
+    (``new``) and write them itself: into a pool stored as the kernel reads
+    it (rows of ``head_dim`` >= 128, or ``fold`` = 128 // ``head_dim`` tokens
+    to a row). Any other pool keeps ``kv_write``; the engine reports which
+    (``dyn_engine_info{decode_kv_write}``)."""
+    return fold == max(1, 128 // head_dim)
 
 
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
@@ -1196,32 +1091,31 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
       layer (``layer`` must then be left out).
     page_tables: [B, P] int32 page ids (rows padded with page 0)
     lengths: [B] int32 — tokens to attend per sequence (including current);
-      0 = a lane nobody serves: both kernels give it zeros, and the dma
-      kernel SKIPS it (no page of its table is read, nothing is computed
-      and nothing written for it)
+      0 = a lane nobody serves: the kernel SKIPS it (no page of its table is
+      read, nothing is computed and nothing written for it) and gives it
+      zeros
     Returns [B, Hq, Dh]. Sequences attend to tokens [0, length); with
-    ``window`` only [max(0, length - window), length). The DMA kernel
+    ``window`` only [max(0, length - window), length). The kernel
     clamps its active block range, so out-of-window blocks cost neither
     copies nor compute (sliding decode reads O(window) bytes), and inside an
     active block it copies only the pages that hold a visible token: copies
     scale with visible pages, not with the block (:func:`paged_live_pages`
     counts both on the host). Table entries past a lane's length, or behind
-    its window, are never followed. The simple kernel skips only the
-    compute — its BlockSpec pipeline still copies every page. ``softcap``
-    tanh-caps scores pre-softmax (Gemma2);
-    ``scale`` overrides rsqrt(Dh) (query_pre_attn_scalar). ``window`` and
-    ``softcap`` are static (one Mosaic kernel per class); ``layer`` is
-    dynamic, so all layers of a class share that kernel. ``keep`` [B, P *
+    its window, are never followed. ``softcap`` tanh-caps scores pre-softmax
+    (Gemma2); ``scale`` overrides rsqrt(Dh) (query_pre_attn_scalar).
+    ``window`` and ``softcap`` are static (one Mosaic kernel per class);
+    ``layer`` is dynamic, so all layers of a class share that kernel. ``keep``
+    [B, P *
     page] bool (a model with an indexer: :func:`topk_keep` over the lane's
     logical positions) restricts the lane to its selected keys; every live
     page is still read. V rows may have a width of their own (``v_pool`` [...,
     Dv]: the result is [B, Hq, Dv]); ``sink`` [Hq] float32 is a logit a head
     that takes softmax weight and gives no value. ``fold`` f > 1: the pools
     are STORED folded, [L, Hkv, n_pages, page // f, f * Dh] (models/llama.py
-    "KV pool access"): the dma kernel copies such rows as they lie, whole
+    "KV pool access"): the kernel copies such rows as they lie, whole
     pool and traced ``layer`` as at Dh >= 128. ``new`` = (k_new [B, Hkv,
     Dh], v_new [B, Hkv, Dv]): the rows of each lane's token ``lengths - 1``,
-    NOT yet in the pools: the dma kernel puts them there and attends over
+    NOT yet in the pools: the kernel puts them there and attends over
     them (a lane of length 0 writes nothing), and the result is (out,
     k_pool, v_pool) with the pools updated in place where the caller donates
     them. Only where :func:`paged_kernel_writes` says so. ``latent`` [B, Hq,
@@ -1231,12 +1125,10 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     compressed vectors: all heads against ONE row a key, each page copied
     once.
 
-    On a TPU this runs the multi-page double-buffered DMA kernel above
-    (``DYNAMO_TPU_PAGED_KERNEL=simple`` selects the BlockSpec-pipelined
-    one-page-per-step kernel below, compiled); off-TPU (and under
-    ``interpret=True``) the simple kernel runs in interpreter mode so the
-    CPU test suite exercises the same contract. See
-    :func:`paged_kernel_variant`.
+    One kernel on every platform, the multi-page double-buffered DMA kernel
+    above: compiled on a TPU, run by the Pallas interpreter elsewhere (and
+    under ``interpret=True``), so the CPU test suite runs the decode
+    attention the chip runs.
     """
     whole = k_pool.ndim == 5
     if not whole:
@@ -1246,28 +1138,17 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     B, Hq, Dh = q.shape
     if interpret is None:
         interpret = not on_tpu()
-    if new is not None:
-        if not paged_kernel_writes(interpret, Dh, fold):
-            raise ValueError(
-                f"no paged kernel writes these pools (kernel "
-                f"{paged_kernel_variant(interpret)!r}, head_dim {Dh}, rows "
-                f"of {fold} token(s)): the caller scatters (kv_write)")
-    if fold > 1 and paged_kernel_variant(interpret) != "dma":
-        # the one-page-a-step kernel blocks [page, Dh]: unfold (a plain
-        # reshape: a folded page's rows are its tokens in order)
-        k_pool, v_pool = (p.reshape(*p.shape[:3], p.shape[3] * fold,
-                                    p.shape[4] // fold)
-                          for p in (k_pool, v_pool))
-        fold = 1
-    _, Hkv, n_pages, page, _ = k_pool.shape
-    page *= fold
+    if new is not None and not paged_kernel_writes(Dh, fold):
+        raise ValueError(
+            f"the paged kernel does not write these pools (head_dim {Dh}, "
+            f"rows of {fold} token(s)): the caller scatters (kv_write)")
+    Hkv = k_pool.shape[1]
     Dv = v_pool.shape[-1] // fold
     G = Hq // Hkv
-    P = page_tables.shape[1]
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if Dh < 128 and fold == 1 and k_pool.shape[0] > 1:
         # rows narrower than a lane tile, stored [.., page, Dh]: XLA's
-        # programs keep such a pool in another order than the kernels'
+        # programs keep such a pool in another order than the kernel's
         # operands take (pages minor; seen on a v5e), so every call re-lays
         # what it is given. Give it one layer's slice to re-lay, never the
         # pool. A pool stored FOLDED (``fold``) has whole-tile rows, is kept
@@ -1275,73 +1156,17 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         k_pool, v_pool = (jax.lax.dynamic_index_in_dim(p, layer[0], 0)
                           for p in (k_pool, v_pool))
         layer = jnp.zeros_like(layer)
-    if paged_kernel_variant(interpret) == "dma":
-        q4 = q.reshape(B, Hkv, G, Dh)
-        out = _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables,
-                                   lengths,
-                                   pages_per_block=paged_pages_per_block(),
-                                   scale=scale, softcap=softcap,
-                                   window=window, keep=keep,
-                                   interpret=interpret,
-                                   **({} if sink is None else {"sink": sink}),
-                                   **({} if fold == 1
-                                      else {"stored_fold": fold}),
-                                   **({} if new is None else {"new": new}),
-                                   **({} if latent is None else {
-                                       "latent": latent.reshape(
-                                           B, Hkv, G, Dv)}))
-        if new is None:
-            return out.reshape(B, Hq, Dv)
-        out, k_pool, v_pool = out
-        if not whole:
-            k_pool, v_pool = k_pool[0], v_pool[0]
-        return out.reshape(B, Hq, Dv), k_pool, v_pool
-    selected = keep is not None
-    if selected and not interpret:
-        raise ValueError(
-            "DYNAMO_TPU_PAGED_KERNEL=simple takes no selection when "
-            "compiled (a [1, 1, page] block does not tile); a model with "
-            "an indexer decodes through the dma kernel")
-    if scale is None:
-        scale = 1.0 / math.sqrt(Dh)
-
     q4 = q.reshape(B, Hkv, G, Dh)
-
-    def page_map(b, p, pt, ln, ly):
-        return (ly[0], 0, pt[b, p], 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, G, Dh), _lane_block),
-            pl.BlockSpec((1, Hkv, 1, page, Dh), page_map),
-            pl.BlockSpec((1, Hkv, 1, page, Dv), page_map),
-            *([pl.BlockSpec((1, 1, page), lambda b, p, *_: (b, 0, p))]
-              if selected else []),
-            *([pl.BlockSpec((Hkv, G, 1), lambda b, p, *_: (0, 0, 0))]
-              if sink is not None else []),
-            *([pl.BlockSpec((1, Hkv, G, Dv), _lane_block)]
-              if latent is not None else []),
-        ],
-        out_specs=pl.BlockSpec((1, Hkv, G, Dv), _lane_block),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # m
-            pltpu.VMEM((Hkv, G, 1), jnp.float32),    # l
-            pltpu.VMEM((Hkv, G, Dv), jnp.float32),   # acc
-        ],
-    )
-    out = _pallas_call(
-        functools.partial(_paged_kernel, scale=scale, page=page,
-                          softcap=softcap, window=window, selected=selected,
-                          **({} if sink is None else {"sunk": True}),
-                          **({} if latent is None else {"latent": True})),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), q.dtype),
-        interpret=interpret,
-    )(page_tables, lengths, layer, q4, k_pool, v_pool,
-      *([keep.astype(jnp.int32)[:, None, :]] if selected else []),
-      *([sink.astype(jnp.float32).reshape(Hkv, G, 1)]
-        if sink is not None else []),
-      *([latent.reshape(B, Hkv, G, Dv)] if latent is not None else []))
-    return out.reshape(B, Hq, Dv)
+    if latent is not None:
+        latent = latent.reshape(B, Hkv, G, Dv)
+    out = _paged_attention_tpu(q4, k_pool, v_pool, layer, page_tables,
+                               lengths, scale=scale, softcap=softcap,
+                               window=window, keep=keep, sink=sink,
+                               interpret=interpret, stored_fold=fold,
+                               new=new, latent=latent)
+    if new is None:
+        return out.reshape(B, Hq, Dv)
+    out, k_pool, v_pool = out
+    if not whole:
+        k_pool, v_pool = k_pool[0], v_pool[0]
+    return out.reshape(B, Hq, Dv), k_pool, v_pool

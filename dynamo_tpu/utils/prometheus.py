@@ -626,10 +626,10 @@ class StageMetrics:
             "window)", ("kind",))
         self.attn_pages_visited = r.counter(
             "dyn_attn_pages_visited_total",
-            "Pages of the blocks the kernel was in for them (a block of "
-            "DYNAMO_TPU_PAGED_PPB pages that holds a visible token): what "
-            "it copied before it told a block's pages apart; live / "
-            "visited is the share of a block's copies that is left",
+            "Pages of the blocks the kernel was in for them (a block of 8 "
+            "pages, ops.attention.PAGES_PER_BLOCK, that holds a visible "
+            "token): what it copied before it told a block's pages apart; "
+            "live / visited is the share of a block's copies that is left",
             ("kind",))
         self.attn_lane_calls = r.counter(
             "dyn_attn_lane_calls_total",

@@ -11,7 +11,7 @@ only; each side a NEW function object, since ``jax.jit`` caches a trace by
 function identity): greedy tokens compared, ``memory_analysis()`` of both
 programs, then ms a step (best of three runs of ten dispatches). One process,
 about four minutes for both presets. PERF.md section 6, PR 38, has the
-readings. ``REHEARSE=1 JAX_PLATFORMS=cpu ... tiny`` runs a toy size through
+readings. ``JAX_PLATFORMS=cpu ... tiny`` runs a toy size through
 the interpreter (a rehearsal of the script, never a timing).
 """
 
@@ -28,7 +28,6 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from dynamo_tpu.models import llama  # noqa: E402
-from dynamo_tpu.ops import attention  # noqa: E402
 
 PAGE, STEPS = 64, 4
 
@@ -91,8 +90,6 @@ def case(name, layers, lanes, context, pages, shortest, longest, **over):
 
 
 def main(which) -> int:
-    if os.environ.get("REHEARSE"):
-        attention.paged_kernel_variant = lambda interpret: "dma"
     cases = {
         "tiny": ("tiny-qwen", 2, 4, 256, 40, 40, 200, {"head_dim": 128}),
         "qwen2-1.5b": ("qwen2-1.5b", 28, 32, 512, 1089, 40, 480, {}),

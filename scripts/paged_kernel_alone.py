@@ -24,7 +24,7 @@ keeps the served lanes' attention outputs and the pools (scratch page 0
 apart: every unserved lane writes there, in no order; large ones as a
 fingerprint of their bits) for ``--compare``,
 which holds two trees' results equal bit for bit. PERF.md section 6, PR 41.
-``REHEARSE=1 JAX_PLATFORMS=cpu ... tiny`` runs a toy size through the
+``JAX_PLATFORMS=cpu ... tiny`` runs a toy size through the
 interpreter (a rehearsal of the script, never a timing).
 """
 
@@ -181,7 +181,8 @@ def case(name, unserved=0, profile=False):
     count = getattr(attention, "paged_live_pages", None)
     if count is not None:
         live, visited = count(
-            lengths, P, PAGE, attention.paged_pages_per_block(), window)
+            lengths, P, PAGE, getattr(attention, "PAGES_PER_BLOCK", 8),
+            window)
         said["copies_a_call_and_pool"] = int(live.sum())
         said["before"] = int(visited.sum())
     return said, kept
@@ -199,9 +200,6 @@ def main(argv) -> int:
     while argv[:1] and argv[0] in given:
         given[argv[0]], argv = argv[1], argv[2:]
     out, unserved = given["--out"], int(given["--unserved"])
-    if os.environ.get("REHEARSE"):
-        from dynamo_tpu.ops import attention
-        attention.paged_kernel_variant = lambda interpret: "dma"
     kept = {}
     for name in argv or [c for c in CASES if c != "tiny"]:
         said, arrays = case(name, unserved, profile)

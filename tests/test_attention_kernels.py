@@ -109,20 +109,87 @@ def test_paged_matches_dense(B, Hq, Hkv, Dh, page, P):
         atol=3e-2, rtol=3e-2)
 
 
+# tests/test_chip_compile.py's GEOMETRIES x VARIANTS (what the v5e compiler
+# is shown), cut to what the interpreter runs in a second: a quarter of the
+# heads at the same group and widths, a window the short contexts straddle
+_GEOMETRIES = [(8, 2, 64), (8, 2, 128), (4, 2, 256),
+               (4, 2, 16)]      # ... and the tiny presets' rows: fold 8
+_VARIANTS = {
+    "plain": {},
+    "window": {"window": 40},
+    "softcap": {"softcap": 50.0, "scale": 0.0625},
+}
+
+
+@pytest.mark.parametrize("variant", list(_VARIANTS))
+@pytest.mark.parametrize("geom", _GEOMETRIES,
+                         ids=lambda g: "x".join(map(str, g)))
+def test_the_public_entry_matches_dense(geom, variant):
+    """``paged_attention`` as the decode step calls it (no kernel picked, no
+    block size given: the one kernel at its own ``PAGES_PER_BLOCK``) over a
+    table wider than a block, a lane of no tokens among the batch: the
+    served lanes are the dense reference's, the empty one zeros."""
+    Hq, Hkv, Dh = geom
+    B, page, P = 4, 16, 10
+    kw = _VARIANTS[variant]
+    n_pages = B * P + 1
+    ks = jax.random.split(jax.random.PRNGKey(23), 3)
+    q = jax.random.normal(ks[0], (B, Hq, Dh), jnp.bfloat16)
+    k_pages, v_pages = (jax.random.normal(
+        key, (Hkv, n_pages, page, Dh), jnp.bfloat16) for key in ks[1:])
+    lengths = jnp.asarray([page * P, 0, 1, 7 * page + 3], jnp.int32)
+    tables = (1 + jnp.arange(B * P, dtype=jnp.int32).reshape(B, P)
+              ) * (lengths > 0)[:, None]
+    got = np.asarray(paged_attention(q, k_pages, v_pages, tables, lengths,
+                                     **kw), np.float32)
+    served = np.asarray(lengths) > 0
+    assert not got[~served].any()
+    want = _dense_paged_ref(q[served], k_pages, v_pages, tables[served],
+                            lengths[served], **kw)
+    np.testing.assert_allclose(got[served], np.asarray(want, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("Dh,Dv,page,kw,says", [
+    (16, 16, 16, {"fold": 4}, "stored folded by 4 at head_dim 16"),
+    (24, 24, 8, {}, "page size 8 not divisible by fold 5"),
+    (16, 16, 8, {"keep": True}, "a selection only at head_dim >= 128"),
+    (64, 32, 8, {}, "V rows of their own width"),
+    (64, 64, 8, {"latent": True}, "latent attention reads unfolded rows"),
+], ids=["fold-not-the-kernels", "page-not-whole-rows", "selection-narrow",
+        "v-width-narrow", "latent-narrow"])
+def test_the_kernel_refuses_a_geometry_it_cannot_tile(Dh, Dv, page, kw, says):
+    """The rules of ``_paged_attention_tpu`` hold on every platform, the
+    interpreter included: a page is a whole number of 128-lane rows, a pool
+    is stored folded by ``128 // Dh`` or not at all, and a selection, V rows
+    of their own width and latent rows need K rows of a lane tile."""
+    B, Hq, Hkv, P = 2, 4, 2, 2
+    kw = dict(kw)
+    f = kw.get("fold", 1)
+    k = jnp.zeros((Hkv, 5, page // f, f * Dh), jnp.bfloat16)
+    v = jnp.zeros((Hkv, 5, page // f, f * Dv), jnp.bfloat16)
+    if kw.get("keep"):
+        kw["keep"] = jnp.ones((B, P * page), bool)
+    if kw.get("latent"):
+        kw["latent"] = jnp.zeros((B, Hq, Dv), jnp.bfloat16)
+    with pytest.raises(ValueError, match=says):
+        paged_attention(jnp.zeros((B, Hq, Dh), jnp.bfloat16), k, v,
+                        jnp.ones((B, P), jnp.int32),
+                        jnp.full((B,), 3, jnp.int32), **kw)
+
+
 @pytest.mark.parametrize("L,kernel_writes", [
     (None, False), (3, False), (None, True), (3, True)])
-def test_paged_inside_scan_with_donated_pool(monkeypatch, L, kernel_writes):
+def test_paged_inside_scan_with_donated_pool(L, kernel_writes):
     """The decode loop shape: pools carried through lax.scan and donated,
     every layer's row written in place (head index spelt out, as
     forward_decode does) and read by the kernel from the whole pool by
     layer index. ``L=None`` is the single-layer [Hkv, n_pages, page, Dh]
-    form. ``kernel_writes``: the dma kernel (in the interpreter here) takes
+    form. ``kernel_writes``: the kernel (in the interpreter here) takes
     the rows as ``new`` and hands the pools back as its second and third
     result, aliased to its operands."""
     B, Hq, Hkv, Dh, page, P = 2, 4, 2, 16, 8, 2
     if kernel_writes:
-        from dynamo_tpu.ops import attention as A
-        monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
         Dh = 128            # rows the kernel reads as stored
     n_pages = 8
     lead = () if L is None else (L,)
@@ -271,7 +338,6 @@ def test_paged_dma_variant_window_softcap(window, softcap, ppb):
         atol=3e-2, rtol=3e-2)
 
 
-@pytest.mark.parametrize("variant", ["simple", "dma"])
 @pytest.mark.parametrize("Dh,G,window,softcap,scale,ppb", [
     (128, 4, None, None, None, 2),     # the cells' head geometry class
     (64, 4, None, None, None, 2),      # llama-3.2-1b: fold 2
@@ -280,8 +346,7 @@ def test_paged_dma_variant_window_softcap(window, softcap, ppb):
     (64, 4, 12, 30.0, 1.0 / math.sqrt(24.0), 1),
     (16, 4, None, None, None, 2),      # fold 8 == page: one folded row
 ])
-def test_paged_whole_pool_by_layer(variant, Dh, G, window, softcap, scale,
-                                   ppb):
+def test_paged_whole_pool_by_layer(Dh, G, window, softcap, scale, ppb):
     """The kernel contract forward_decode relies on: the WHOLE 5-D pool and
     a (traced) layer index give, for every layer, what the dense reference
     and the single-layer form give for that layer's slice."""
@@ -301,17 +366,13 @@ def test_paged_whole_pool_by_layer(variant, Dh, G, window, softcap, scale,
     lengths = jnp.asarray([5, 12, page * P], jnp.int32)
     kw = dict(scale=scale, softcap=softcap, window=window)
 
-    if variant == "simple":
-        def whole(layer):
-            return paged_attention(q, k_pool, v_pool, page_tables, lengths,
-                                   layer, interpret=True, **kw)
-    else:
-        def whole(layer):
-            return _paged_attention_tpu(
-                q.reshape(B, Hkv, G, Dh), k_pool, v_pool, layer.reshape(1),
-                page_tables, lengths, pages_per_block=ppb, interpret=True,
-                **kw).reshape(B, Hq, Dh)
-    whole = jax.jit(whole)          # the layer index is traced: one program
+    @jax.jit                        # the layer index is traced: one program
+    def whole(layer):
+        return _paged_attention_tpu(
+            q.reshape(B, Hkv, G, Dh), k_pool, v_pool, layer.reshape(1),
+            page_tables, lengths, pages_per_block=ppb, interpret=True,
+            **kw).reshape(B, Hq, Dh)
+
     for l in range(L):
         got = np.asarray(whole(jnp.int32(l)), np.float32)
         want = _dense_paged_ref(q, k_pool[l], v_pool[l], page_tables,
@@ -425,31 +486,35 @@ def test_the_kernel_that_writes_is_kv_write_then_the_kernel(
 
 
 def test_new_rows_only_where_a_kernel_writes():
-    """Off the dma kernel, or over rows narrower than a lane tile stored
-    unfolded, nothing writes ``new`` rows: the entry point says so rather
-    than attend over a pool that lacks them."""
+    """Over rows narrower than a lane tile stored unfolded nothing writes
+    ``new`` rows (the kernel is handed a re-laid slice of such a pool, never
+    the pool): the entry point says so rather than attend over a pool that
+    lacks them. One predicate, whatever the platform."""
     from dynamo_tpu.ops.attention import paged_kernel_writes
 
-    assert not paged_kernel_writes(True, 128, 1)      # the interpreter
-    z = jnp.zeros((2, 2, 3, 8, 128), jnp.bfloat16)
-    rows = jnp.zeros((1, 2, 128), jnp.bfloat16)
+    assert paged_kernel_writes(128, 1) and not paged_kernel_writes(64, 1)
+    z = jnp.zeros((2, 2, 3, 8, 64), jnp.bfloat16)
+    rows = jnp.zeros((1, 2, 64), jnp.bfloat16)
     with pytest.raises(ValueError, match="the caller scatters"):
-        paged_attention(jnp.zeros((1, 4, 128), jnp.bfloat16), z, z,
+        paged_attention(jnp.zeros((1, 4, 64), jnp.bfloat16), z, z,
                         jnp.zeros((1, 1), jnp.int32),
                         jnp.ones((1,), jnp.int32), layer=1, interpret=True,
                         new=(rows, rows))
 
 
-@pytest.mark.parametrize("kernel,Dh,fold,writes", [
-    ("dma", 128, 1, True), ("dma", 256, 1, True), ("dma", 64, 2, True),
-    ("dma", 64, 1, False),       # 64-lane rows stored unfolded: re-laid
-    ("dma", 16, 1, False), ("simple", 128, 1, False),
+@pytest.mark.parametrize("Dh,fold,writes", [
+    (128, 1, True), (256, 1, True), (64, 2, True),
+    (64, 1, False),              # 64-lane rows stored unfolded: re-laid
+    (16, 1, False),
 ])
-def test_which_pools_the_kernel_writes(monkeypatch, kernel, Dh, fold, writes):
+def test_which_pools_the_kernel_writes(Dh, fold, writes):
+    from dynamo_tpu.models import llama
     from dynamo_tpu.ops.attention import paged_kernel_writes
 
-    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", kernel)
-    assert paged_kernel_writes(False, Dh, fold) is writes
+    assert paged_kernel_writes(Dh, fold) is writes
+    # the decode step's own question: the same, on the kernel's path alone
+    assert llama.kernel_writes(None, "pallas", Dh, fold) is writes
+    assert not llama.kernel_writes(None, "xla", Dh, fold)
 
 
 # ---------------------------------------------------------------------------
@@ -749,11 +814,10 @@ def test_a_lane_of_no_tokens_is_skipped(case, Dh, Dv, fold, window, selected,
 
 
 @pytest.mark.parametrize("sunk", [False, True], ids=["plain", "sunk"])
-def test_the_one_page_kernel_gives_a_lane_of_no_tokens_zeros(sunk):
-    """The kernel the CPU and ``DYNAMO_TPU_PAGED_KERNEL=simple`` run stays
-    interchangeable with the dma kernel: exact zeros for a lane of length 0
-    (its pipeline still copies the page its table names, here a NaN one, and
-    computes nothing over it), the other lanes as they were."""
+def test_the_public_entry_gives_a_lane_of_no_tokens_zeros(sunk):
+    """``paged_attention`` as every caller reaches it: exact zeros for a
+    lane of length 0 (the page its table names is a NaN one and is never
+    read), the other lanes as they are without it in the batch."""
     B, Hq, Hkv, Dh, page, P = 4, 4, 2, 128, 32, 2
     ks = jax.random.split(jax.random.PRNGKey(5), 4)
     q = jax.random.normal(ks[0], (B, Hq, Dh), jnp.bfloat16)

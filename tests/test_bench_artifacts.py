@@ -28,5 +28,7 @@ def test_long_context_batch_artifact_verdicts():
     assert art["checks"]["sliding_exact"] and art["sliding"]["exact"]
     assert art["sliding"]["batch"] >= 2 and art["sliding"]["pageins"] > 0
     # kernel provenance: the numbers say which paged backend made them
-    assert art["paged_kernel"] in ("dma", "simple", "simple[interpret]")
+    # (a record made before PR 47 may name the one-page kernel)
+    assert art["paged_kernel"] in ("dma", "dma[interpret]", "none",
+                                   "simple", "simple[interpret]")
     assert art["platform"]

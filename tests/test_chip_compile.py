@@ -94,13 +94,11 @@ def test_flash_compiles_at_engine_edge_shapes(v5e, T, S):
 
 
 @pytest.mark.parametrize("variant", list(VARIANTS))
-@pytest.mark.parametrize("kernel", ["dma", "simple"])
 @pytest.mark.parametrize("geom", GEOMETRIES, ids=lambda g: "x".join(map(str, g)))
-def test_paged_compiles_for_v5e(v5e, monkeypatch, geom, kernel, variant):
+def test_paged_compiles_for_v5e(v5e, geom, variant):
     Hq, Hkv, Dh = geom
     B, P = 32, 34                       # 34 pages: max_context 2048 + pad
     n_pages = B * P + 1
-    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", kernel)
     txt = _compiled_text(
         lambda q, k, v, pt, ln: A.paged_attention(
             q, k, v, pt, ln, interpret=False, **VARIANTS[variant]),
@@ -257,11 +255,9 @@ def _pool_relayouts(txt: str, pool_shape) -> dict:
                       if re.search(r"= \w+\[(1,)?%s\]" % layer, ln)]}
 
 
-@pytest.mark.parametrize("kernel", ["dma", "simple"])
 @pytest.mark.parametrize("geom", GEOMETRIES[:2],
                          ids=lambda g: "x".join(map(str, g)))
-def test_paged_whole_pool_by_layer_compiles_for_v5e(v5e, monkeypatch, geom,
-                                                    kernel):
+def test_paged_whole_pool_by_layer_compiles_for_v5e(v5e, geom):
     """The form forward_decode calls: the whole [L, Hkv, n_pages, page, Dh]
     pool and a traced layer index. At Dh = 128 the custom call takes the
     pool itself (no pool-shaped operand is produced by a copy); at Dh = 64
@@ -269,7 +265,6 @@ def test_paged_whole_pool_by_layer_compiles_for_v5e(v5e, monkeypatch, geom,
     Hq, Hkv, Dh = geom
     L, B, P = 4, 32, 34
     n_pages = B * P + 1
-    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", kernel)
     txt = _compiled_text(
         lambda q, k, v, pt, ln, l: A.paged_attention(
             q, k, v, pt, ln, l, interpret=False),
@@ -340,8 +335,7 @@ def test_bucket_program_bodies_keep_the_pool_as_stored(v5e, program):
     assert ma.temp_size_in_bytes < pool_bytes // 8
 
 
-def test_the_decode_steps_rows_are_written_by_the_paged_kernel(v5e,
-                                                               monkeypatch):
+def test_the_decode_steps_rows_are_written_by_the_kernel(v5e, monkeypatch):
     """qwen2-1.5b's decode step at 32 lanes, all 28 layers: with the write
     in the paged kernel the program holds no scatter into the pools
     (``bf16[28,2,...,64,128]``: 56 of them a step before), every pool-shaped
@@ -569,7 +563,6 @@ def test_auto_on_tpu_with_failing_probe_raises(monkeypatch):
     mocked; the probe is real and cannot compile on the CPU backend."""
     from dynamo_tpu.engine import engine as E
 
-    monkeypatch.delenv("DYNAMO_TPU_ATTN", raising=False)
     monkeypatch.setattr(E, "on_tpu", lambda device=None: True)
     # the mocked platform would otherwise also fail the peak-table lookup
     monkeypatch.setenv("DYN_PEAK_FLOPS", "1e12")
@@ -587,30 +580,34 @@ def test_auto_off_tpu_is_dense_and_reported():
 
 
 def test_explicit_pallas_off_tpu_reports_interpreted_kernel():
+    """The one paged kernel, in the interpreter: the decode program the chip
+    compiles, the kernel writing the rows where the pool is stored as it
+    reads it (``benchmarks/harness/cell.py`` holds a cell to ``dma``)."""
     from dynamo_tpu.engine.engine import EngineCore
 
-    core = EngineCore(_tiny_engine_cfg(attn_impl="pallas"))
-    assert core.paged_kernel == "simple[interpret]"
+    core = EngineCore(_tiny_engine_cfg(
+        model=llama.preset("tiny-byte", head_dim=128), attn_impl="pallas"))
+    assert core.paged_kernel == "dma[interpret]"
+    assert core.decode_kv_write == "kernel"
 
 
-@pytest.mark.parametrize("model,kernel,expect", [
-    ({}, None, "scatter"),                       # off a TPU: interpreted
-    ({"head_dim": 128}, None, "scatter"),
-    ({"head_dim": 128}, "dma", "kernel"),        # ... the test steers it
-    ({"head_dim": 64, "kv_fold": 2}, "dma", "kernel"),
-    ({"head_dim": 64}, "dma", "scatter"),        # 64-lane rows, unfolded
-    ({"head_dim": 128}, "simple", "scatter"),
+@pytest.mark.parametrize("model,impl,expect", [
+    ({}, "pallas", "scatter"),           # rows of 16 stored unfolded
+    ({"head_dim": 128}, "pallas", "kernel"),
+    ({"head_dim": 256}, "pallas", "kernel"),
+    ({"head_dim": 64, "kv_fold": 2}, "pallas", "kernel"),
+    ({"kv_fold": 8}, "pallas", "kernel"),        # rows of 16, a page a row
+    ({"head_dim": 64}, "pallas", "scatter"),     # 64-lane rows, unfolded
+    ({"head_dim": 128}, "xla", "scatter"),       # the dense path
 ])
-def test_the_engine_reports_what_writes_the_decode_rows(monkeypatch, model,
-                                                        kernel, expect):
+def test_the_engine_reports_what_writes_the_decode_rows(model, impl, expect):
     """``dyn_engine_info{decode_kv_write}`` is what ``forward_decode``'s own
-    predicate says for the engine's pools, and the label rides the gauge."""
+    predicate says for the engine's pools, on a CPU as on the chip, and the
+    label rides the gauge."""
     from dynamo_tpu.engine.engine import JaxEngine
 
-    if kernel:
-        monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: kernel)
     eng = JaxEngine(_tiny_engine_cfg(
-        model=llama.preset("tiny-byte", **model), attn_impl="pallas",
+        model=llama.preset("tiny-byte", **model), attn_impl=impl,
         warmup=False))
     try:
         assert eng.core.decode_kv_write == expect
@@ -620,21 +617,25 @@ def test_the_engine_reports_what_writes_the_decode_rows(monkeypatch, model,
         eng.shutdown()
 
 
-@pytest.mark.parametrize("env,interpret,expect", [
-    (None, False, "dma"), ("simple", False, "simple"),
-    ("dma", True, "simple[interpret]"), (None, True, "simple[interpret]")])
-def test_paged_kernel_variant(monkeypatch, env, interpret, expect):
-    if env is None:
-        monkeypatch.delenv("DYNAMO_TPU_PAGED_KERNEL", raising=False)
-    else:
-        monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", env)
-    assert A.paged_kernel_variant(interpret) == expect
+def test_the_hot_path_reads_no_switch_from_the_environment():
+    """What a program runs is decided by its arguments and its devices: the
+    package reads two ``DYNAMO_TPU_*`` names, both in ``runtime/`` (which
+    store and which data plane a process talks to), none in the modules a
+    dispatch goes through."""
+    import re
 
-
-def test_paged_kernel_variant_rejects_typo(monkeypatch):
-    monkeypatch.setenv("DYNAMO_TPU_PAGED_KERNEL", "dmaa")
-    with pytest.raises(ValueError, match="DYNAMO_TPU_PAGED_KERNEL"):
-        A.paged_kernel_variant(False)
+    root = os.path.dirname(os.path.dirname(          # dynamo_tpu/
+        os.path.abspath(jaxenv.__file__)))
+    read = {}
+    for folder, _, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(folder, name)) as f:
+                    for var in re.findall(r"DYNAMO_TPU_[A-Z_]+", f.read()):
+                        read.setdefault(var, set()).add(
+                            os.path.relpath(folder, root))
+    assert read == {"DYNAMO_TPU_STORE": {"runtime"},
+                    "DYNAMO_TPU_DATAPLANE": {"runtime"}}
 
 
 @pytest.mark.parametrize("n,align,expect", [

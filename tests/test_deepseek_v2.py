@@ -191,23 +191,15 @@ def test_chunks_then_decode_are_the_published_attention(state, whole, chunk):
 
 
 # ---- (g) -----------------------------------------------------------------
-@pytest.mark.parametrize("variant", ["simple", "dma"])
-def test_the_latent_kernels_are_the_dense_path(state, whole, variant,
-                                               monkeypatch):
+def test_the_latent_kernels_are_the_dense_path(state, whole):
     """The flash kernel's absorbed form (every (token, head) a query row
-    against the one shared row a key) and both paged kernels (the dma
-    kernel writes the step's two rows itself) in the interpreter, against
-    the reference, as the dense path is."""
-    from dynamo_tpu.ops import attention as A
-
-    if variant == "dma":
-        monkeypatch.setattr(A, "paged_kernel_variant",
-                            lambda interpret: "dma")
+    against the one shared row a key) and the paged kernel (it writes the
+    step's two rows itself) in the interpreter, against the reference, as
+    the dense path is."""
     toks, want = whole
     cfg = llama.LlamaConfig.from_hf_config(TINY, dtype=jnp.float32)
     kind, = cache_kinds(cfg)
-    assert llama.kernel_writes(None, "pallas", kind.k_store,
-                               kind.fold) == (variant == "dma")
+    assert llama.kernel_writes(None, "pallas", kind.k_store, kind.fold)
     got = through_the_cache(cfg, f32(state["params"]), toks,
                             impl="pallas", flash="flash")
     assert np.abs(got - want).max() < TOL

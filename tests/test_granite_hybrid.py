@@ -331,7 +331,7 @@ def test_a_carry_lost_at_one_chunk_boundary_fails_the_tolerance(state):
 # ---- (e) -----------------------------------------------------------------
 def test_the_folded_pool_reads_and_writes_what_the_plain_one_does():
     rng = np.random.default_rng(0)
-    L, H, N, page, Dh, f = 2, 2, 5, 16, 16, 4
+    L, H, N, page, Dh, f = 2, 2, 5, 16, 16, 8
     plain = jnp.asarray(rng.normal(size=(L, H, N, page, Dh)), jnp.float32)
     folded = plain.reshape(L, H, N, page // f, f * Dh)
     n = 24   # neighbours in a row, rows written twice, two pages

@@ -1367,20 +1367,60 @@ def test_serving_dispatches_donate_the_pools(core, spec_core):
         assert all(n1[k] > n0[k] for k in kinds) and swaps >= 3
 
 
-def test_pallas_decode_path_serves_what_the_xla_path_serves():
-    """A seeded greedy run through ``EngineCore`` with the paged kernel
-    reading the whole pool by layer index (interpret mode) gives the tokens
-    of the same run on the dense gather path, computed here, in this
-    process, and log-probabilities within float32 round-off. The model is
-    float32 so that the comparison sees the paths and not bf16 ties."""
+def _hf(module):
+    """The tiny published-style configuration a model's own test file
+    serves against its reference."""
+    import importlib
+
     import jax.numpy as jnp
 
-    model = llama.preset("tiny-byte", dtype=jnp.float32)
+    tiny = importlib.import_module(f"tests.{module}").TINY
+    return llama.LlamaConfig.from_hf_config(tiny, dtype=jnp.float32)
+
+
+def _preset(name, **over):
+    import jax.numpy as jnp
+
+    return llama.preset(name, dtype=jnp.float32, **over)
+
+
+# one case a cache kind: model, page size, what writes each kind's decode rows
+CACHE_KINDS = {
+    "rows-128": (lambda: _preset("tiny-qwen", head_dim=128), 8, "kernel"),
+    "rows-64-folded": (lambda: _preset("tiny-byte", head_dim=64, kv_fold=2),
+                       8, "kernel"),
+    "rows-16-folded": (lambda: _preset("tiny-byte", kv_fold=8), 8, "kernel"),
+    "narrow-unfolded": (lambda: _preset("tiny-byte"), 8, "scatter"),
+    "window-softcap": (lambda: _preset("tiny-gemma2", head_dim=128), 8,
+                       "kernel"),
+    "mimo-window-sink": (lambda: _hf("test_mimo_v2_flash"), 8, "kernel"),
+    "keye-selection": (lambda: _preset("tiny-keye"), 8, "kernel"),
+    "deepseek-latent": (lambda: _hf("test_deepseek_v2"), 8, "kernel"),
+    "granite-state": (lambda: _hf("test_granite_hybrid"), 16, "kernel"),
+    "lfm2-conv-tail": (lambda: _hf("test_lfm2_moe"), 16, "kernel"),
+}
+
+
+@pytest.mark.parametrize("kind", list(CACHE_KINDS))
+def test_pallas_decode_path_serves_what_the_xla_path_serves(kind):
+    """A seeded greedy run through ``EngineCore`` on the decode program the
+    chip runs (the paged kernel in the interpreter, reading the whole pool
+    by layer index and writing the step's rows wherever the pool is stored
+    as it reads it) gives the tokens of the same run on the dense gather
+    path, computed here, in this process, and log-probabilities within
+    float32 round-off. The models are float32 so that the comparison sees
+    the paths and not bf16 ties."""
+    build, page, writes = CACHE_KINDS[kind]
+    model = build()
     runs = {}
     for impl in ("xla", "pallas"):
         c = EngineCore(make_cfg(model=model, max_batch=2, attn_impl=impl,
-                                seed=3))
+                                seed=3, page_size=page, max_context=96,
+                                prefill_chunk=16, decode_steps=2))
         assert c.decode_attn_impl == impl
+        if impl == "pallas":
+            assert c.paged_kernel == "dma[interpret]"
+            assert c.decode_kv_write == writes
         c.submit("p", req([7, 3, 9, 250, 14, 15, 92, 65, 35], max_tokens=12))
         c.submit("q", req(list(range(40, 85)), max_tokens=12))
         runs[impl] = drain(c, ["p", "q"])
@@ -1391,9 +1431,20 @@ def test_pallas_decode_path_serves_what_the_xla_path_serves():
                                    [g.token_logprob for g in x], atol=2e-5)
 
 
-def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
+def test_the_page_counters_count_on_a_cpu_engine():
+    """What ``attn.live_page_share`` reads on the chip moves under the CPU
+    tests too: one request through a pallas engine, and
+    ``dyn_attn_pages_live_total`` has counted its decode dispatches."""
+    c = EngineCore(make_cfg(attn_impl="pallas", max_batch=2))
+    live0 = c.stage.attn_pages_live.get("full")
+    c.submit("n", req([5, 6, 7, 8], max_tokens=4))
+    drain(c, ["n"])
+    assert c.stage.attn_pages_live.get("full") > live0
+
+
+def test_a_decode_dispatch_counts_the_pages_its_kernel_copies():
     """tiny-gemma2 (a full layer and a window layer, window 8 = one page)
-    on four lanes through the dma kernel (interpreter): the tokens are the
+    on four lanes through the paged kernel (interpreter): the tokens are the
     dense path's (float32, as above), and every decode dispatch moves
     ``dyn_attn_pages_live_total`` / ``dyn_attn_pages_visited_total`` by what
     ``paged_live_pages`` gives for the lanes of the program as the KERNEL is
@@ -1403,7 +1454,6 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
     ``dyn_attn_lane_calls_skipped_total`` by the unserved part of them; a
     capture counts its own dispatches a second time, under the counters'
     names."""
-    from dynamo_tpu.engine import engine as E
     from dynamo_tpu.ops import attention as A
 
     import jax.numpy as jnp
@@ -1423,29 +1473,35 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
                 np.asarray([g.token_logprob for n in reqs for g in got[n]]))
 
     dense = EngineCore(make_cfg(attn_impl="xla", **cfg))
+    st = dense.stage
+    counters = {"live": st.attn_pages_live, "visited": st.attn_pages_visited,
+                "lanes": st.attn_lane_calls,
+                "skipped": st.attn_lane_calls_skipped}
+
+    def counted():
+        # (the series are the process's: what earlier engines of this worker
+        # left there is the baseline)
+        return {(name, kind): c.get(kind) for name, c in counters.items()
+                for kind in ("full", "window")}
+
+    base = counted()
     want, want_logps = serve(dense)
-    assert not dense.stage.attn_pages_visited._values   # no kernel, no pages
-    for mod in (A, E):
-        monkeypatch.setattr(mod, "paged_kernel_variant",
-                            lambda interpret: "dma")
+    assert not dense._attn_calls and counted() == base  # no kernel, no pages
     # a model without a window has one kind: every layer's call is ``full``
     assert EngineCore(make_cfg(attn_impl="pallas", max_batch=2))._attn_calls \
         == {"full": (None, llama.preset("tiny-byte").num_layers)}
     core = EngineCore(make_cfg(attn_impl="pallas", **cfg))
-    assert core.paged_kernel == "dma"
+    assert core.paged_kernel == "dma[interpret]"
     assert core._attn_calls == {"full": (None, 1), "window": (8, 1)}
     seen = []
     core.dispatch_hook = lambda kind, meta, arrs: kind == "decode" and (
         seen.append((meta["S"], arrs["lengths"].copy(),
                      arrs["active_mask"].copy(), core.capturing)))
-    st = core.stage
-    # (the series are the process's: what earlier tests of this worker left
-    # there is the baseline)
-    base = dict(st.profile_captured_work._values)
+    captured0 = dict(st.profile_captured_work._values)
     got, logps = serve(core)
     assert got == want
     np.testing.assert_allclose(logps, want_logps, atol=2e-5)
-    assert st.profile_captured_work._values == base
+    assert st.profile_captured_work._values == captured0
     core.capturing = True
     try:
         reqs = {"c": req(list(range(5, 30)), max_tokens=5)}
@@ -1468,18 +1524,16 @@ def test_a_decode_dispatch_counts_the_pages_its_kernel_copies(monkeypatch):
                 for key in [(name, kind)] + [(name, kind, "cap")] * captured:
                     want[key] = want.get(key, 0) + n.sum()    # one layer each
     assert any(c for *_, c in seen) and not all(c for *_, c in seen)
-    for name, counter in (("live", st.attn_pages_live),
-                          ("visited", st.attn_pages_visited),
-                          ("lanes", st.attn_lane_calls),
-                          ("skipped", st.attn_lane_calls_skipped)):
+    moved = {key: n - base[key] for key, n in counted().items()}
+    for name, counter in counters.items():
         for kind in ("full", "window"):
-            assert counter.get(kind) == want[name, kind] > 0
-            assert st.profile_captured_work.get(counter.name, kind) == \
-                want[name, kind, "cap"] > 0
+            assert moved[name, kind] == want[name, kind] > 0
+            assert (st.profile_captured_work.get(counter.name, kind)
+                    - captured0.get((counter.name, kind), 0)
+                    ) == want[name, kind, "cap"] > 0
     # a window of one page sees two pages at most; a block holds eight
-    assert st.attn_pages_live.get("window") < st.attn_pages_live.get("full")
-    assert st.attn_pages_live.get("full") < st.attn_pages_visited.get("full")
+    assert moved["live", "window"] < moved["live", "full"]
+    assert moved["live", "full"] < moved["visited", "full"]
     # half the program's lanes or more were never served
-    assert (st.attn_lane_calls.get("full") / 2
-            <= st.attn_lane_calls_skipped.get("full")
-            < st.attn_lane_calls.get("full"))
+    assert (moved["lanes", "full"] / 2 <= moved["skipped", "full"]
+            < moved["lanes", "full"])

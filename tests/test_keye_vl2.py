@@ -29,14 +29,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PAGE = 8
 TINY = {
     "model_type": "KeyeVL2", "hidden_size": 64, "num_hidden_layers": 2,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
     "intermediate_size": 128, "moe_intermediate_size": 48, "num_experts": 8,
     "num_local_experts": 8, "num_experts_per_tok": 2, "norm_topk_prob": True,
     "decoder_sparse_step": 1, "mlp_only_layers": [], "rope_theta": 10000.0,
     "rms_norm_eps": 1e-6, "vocab_size": 259, "tie_word_embeddings": False,
     "max_position_embeddings": 1024, "attention_bias": False,
     "hidden_act": "silu",
-    "rope_scaling": {"mrope_section": [2, 3, 3], "rope_type": "default",
+    "rope_scaling": {"mrope_section": [16, 24, 24], "rope_type": "default",
                      "type": "default"},
     "sa_config": {"indexer_head_dim": 16, "indexer_num_heads": 2,
                   "indexer_num_kv_heads": 1, "topk": 8, "q_chunk_size": 512,
@@ -408,8 +408,8 @@ def test_other_paths_refuse_an_indexer(model, params, tmp_path):
                          None)
     with pytest.raises(ValueError, match="index-key pool"):
         llama.forward_decode(params, model, jnp.zeros(1, jnp.int32),
-                             jnp.zeros((2, 2, 2, PAGE, 16)),
-                             jnp.zeros((2, 2, 2, PAGE, 16)),
+                             jnp.zeros((2, 2, 2, PAGE, model.head_dim)),
+                             jnp.zeros((2, 2, 2, PAGE, model.head_dim)),
                              jnp.zeros((1, 1), jnp.int32),
                              jnp.ones(1, jnp.int32))
 
@@ -423,7 +423,7 @@ def test_expert_width_of_its_own_and_dense_as_before(model):
     assert lay["wd"].shape == (2, 8, 48, 64) and lay["wr"].shape == (2, 64, 8)
     assert lay["wiq"].shape == (2, 64, 2, 16) and lay["wik"].shape == (
         2, 64, 16)
-    assert lay["ln_q"].shape == (2, 16) and lay["ln_ik_b"].shape == (2, 16)
+    assert lay["ln_q"].shape == (2, 128) and lay["ln_ik_b"].shape == (2, 16)
     specs = llama.param_specs(model)
     assert set(specs["layers"]) == set(lay)
     # Mixtral's keys: experts of the dense width, no indexer

@@ -259,15 +259,13 @@ def test_forwards_agree(preset_params, preset, agrees):
 
 # ---------------------------------------------------------------------------
 # a decode step whose paged kernel writes the new K/V rows itself against the
-# same step with ``kv_write`` in front of the kernel (the dma kernel in the
-# interpreter: off a TPU the entry point picks the one-page kernel, which
-# never writes, so the test steers it, not the program)
+# same step with ``kv_write`` in front of the kernel (in the interpreter)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("preset,over", [
     ("tiny-qwen", {"head_dim": 128}),                   # bias, rows of a tile
     ("tiny-gemma2", {"head_dim": 128}),                 # softcap, sliding
-    ("tiny-keye", {"head_dim": 128}),                   # a selection beside it
+    ("tiny-keye", {}),                                  # a selection beside it
     ("tiny-byte", {"head_dim": 64, "kv_fold": 2}),      # two tokens a row
     ("tiny-byte", {"head_dim": 64}),                    # ... stored unfolded
 ], ids=["qwen-128", "gemma2-128", "keye-128", "fold2", "unfolded-64"])
@@ -278,9 +276,6 @@ def test_decode_with_the_write_in_the_kernel_is_kv_write_then_the_kernel(
     tokens, the same logits and the same pools, bit for bit. A pool the
     kernel cannot write (64-lane rows stored unfolded) keeps ``kv_write``
     by what ``kernel_writes`` observes, and is then the same program."""
-    from dynamo_tpu.ops import attention as A
-
-    monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
     page = 16
     cfg = llama.preset(preset, **over)
     fold = cfg.kv_fold
@@ -325,24 +320,21 @@ def test_decode_with_the_write_in_the_kernel_is_kv_write_then_the_kernel(
 @pytest.mark.parametrize("preset,over", [
     ("tiny-qwen", {"head_dim": 128}),
     ("tiny-gemma2", {"head_dim": 128}),
-    ("tiny-keye", {"head_dim": 128}),
+    ("tiny-keye", {}),
     ("tiny-byte", {"head_dim": 64, "kv_fold": 2}),
     ("tiny-byte", {"head_dim": 64}),                    # kv_write scatters
 ], ids=["qwen-128", "gemma2-128", "keye-128", "fold2", "unfolded-64"])
-def test_a_lane_the_step_does_not_serve_is_skipped_by_the_paged_kernel(
+def test_a_lane_the_step_does_not_serve_is_skipped_by_the_kernel(
         monkeypatch, preset, over):
     """Two chained decode steps of four lanes, two of them the engine's
     unserved lanes (length 1, an all-zero table), through ``forward_decode``
-    on the dma kernel (interpreter), scratch page 0 NaN in both pools. With
+    on the paged kernel (interpreter), scratch page 0 NaN in both pools. With
     ``active`` the kernel is handed those lanes as length 0 and skips them:
     every logit of every lane is finite (page 0 was never read) and a kernel
     that writes leaves page 0 as it was. The served lanes' tokens, logits
     and pages are, bit for bit, those of the program as it was before the
     kernel skipped (the same step, the kernel handed length 1 for those
     lanes, over a page 0 that can be read)."""
-    from dynamo_tpu.ops import attention as A
-
-    monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
     page = 16
     cfg = llama.preset(preset, **over)
     fold = cfg.kv_fold
@@ -378,6 +370,8 @@ def test_a_lane_the_step_does_not_serve_is_skipped_by_the_paged_kernel(
         return out, [np.asarray(a, np.float32) for a in state]
 
     got, got_pools = serve(poisoned)
+    from dynamo_tpu.ops import attention as A
+
     skipping = A.paged_attention
     monkeypatch.setattr(
         A, "paged_attention", lambda q, k, v, pt, ln, *a, **kw: skipping(

@@ -32,8 +32,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 1e-4          # served log-probability against the reference's, float32
 TINY = {
     "model_type": "mimo_v2_flash", "hidden_size": 64, "num_hidden_layers": 7,
-    "num_attention_heads": 4, "num_key_value_heads": 1, "head_dim": 24,
-    "v_head_dim": 16, "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 1, "head_dim": 128,
+    "v_head_dim": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
     "n_routed_experts": 4, "n_shared_experts": None,
     "num_experts_per_tok": 2, "norm_topk_prob": True,
     "scoring_func": "sigmoid", "n_group": 1, "topk_group": 1,
@@ -49,7 +49,7 @@ TINY = {
     "moe_layer_freq": [0, 1, 1, 1, 1, 1, 1, 1, 1],
     "add_swa_attention_sink_bias": True,
     "add_full_attention_sink_bias": False, "swa_num_attention_heads": 4,
-    "swa_num_key_value_heads": 2, "swa_head_dim": 24, "swa_v_head_dim": 16,
+    "swa_num_key_value_heads": 2, "swa_head_dim": 128, "swa_v_head_dim": 64,
     # this chip: experts 2-5 of the router's 8
     "expert_shard": {"router_experts": 8, "first_expert": 2},
 }
@@ -371,17 +371,13 @@ def test_block_moving_calls_refuse_and_no_block_is_hashed(core):
     assert core.prefix_hit_tokens == hit0 == 0
 
 
-def test_both_kinds_rows_are_written_by_the_paged_kernel(monkeypatch):
-    """At the published head widths (K 192 stored 256 wide, V 128; the tiny
-    model's 24 / 16 fill no lane tile and keep ``kv_write``) the dma kernel
-    (in the interpreter) writes the decode step's rows of BOTH kinds, the
+def test_both_kinds_rows_are_written_by_the_kernel(monkeypatch):
+    """At the published head widths (K 192 stored 256 wide, V 128) the paged
+    kernel (in the interpreter) writes the decode step's rows of BOTH kinds, the
     full layers' into the global pools and the window layers' into the
     window pools through their own page tables, sink and all: the same
     tokens, logits and four pools, bit for bit, as ``kv_write`` in front of
     the same kernel."""
-    from dynamo_tpu.ops import attention as A
-
-    monkeypatch.setattr(A, "paged_kernel_variant", lambda interpret: "dma")
     cfg = llama.LlamaConfig.from_hf_config(dict(
         TINY, head_dim=192, v_head_dim=128, swa_head_dim=192,
         swa_v_head_dim=128))
@@ -452,17 +448,17 @@ def test_costs_and_block_bytes_are_by_kind(core):
     from dynamo_tpu.utils import roofline
 
     m = core.cfg.model
-    # a block of the global cache: 2 full layers x 1 head x (24 + 16) x 4 B
-    assert llama.kv_block_bytes(m, 8) == 8 * 2 * 40 * 4
-    assert core.cache_kinds[1].token_bytes(4) == 5 * 2 * 40 * 4
+    # a block of the global cache: 2 full layers x 1 head x (128 + 64) x 4 B
+    assert llama.kv_block_bytes(m, 8) == 8 * 2 * 192 * 4
+    assert core.cache_kinds[1].token_bytes(4) == 5 * 2 * 192 * 4
     costs = roofline.model_costs(m, weight_bytes=1.0)
     assert costs.window_groups == ((8, 5), (None, 2))
-    assert costs.group_kv_bytes == (2 * 40 * 4, 1 * 40 * 4)
-    assert costs.kv_write_bytes_per_token == 5 * 320 + 2 * 160
+    assert costs.group_kv_bytes == (2 * 192 * 4, 1 * 192 * 4)
+    assert costs.kv_write_bytes_per_token == 5 * 1536 + 2 * 768
     # a decode query at length 50 reads 8 keys in a window layer, 50 in a
     # full one
     fl, by, tk = roofline.decode_cost(costs, [50], 1)
-    assert by == 1.0 + 5 * 8 * 320 + 2 * 50 * 160 + 5 * 320 + 2 * 160
+    assert by == 1.0 + 5 * 8 * 1536 + 2 * 50 * 768 + 5 * 1536 + 2 * 768
     assert [k.name for k in core.cache_kinds] == ["global", "window"]
 
 
