@@ -948,9 +948,10 @@ class EngineCore:
 
     @property
     def _moe_share(self) -> float:
-        """The part of the router's experts this chip holds."""
+        """The part of the router's outputs that are experts this chip
+        holds (identity experts are outputs no chip holds)."""
         m = self.cfg.model
-        return m.num_experts / (m.router_experts or m.num_experts)
+        return m.num_experts / m.router_width
 
     @cached_property
     def _decode_moe_form(self) -> Optional[str]:
@@ -972,13 +973,15 @@ class EngineCore:
 
     def _program_extras(self):
         """-> (jit options, out_shardings tail, the columns ``packed``
-        carries behind token and log-probability: ``experts_hit``, and
-        ``held``, the assignments to held experts, under a chip's share) of
+        carries behind token and log-probability: ``experts_hit``,
+        ``held``, the assignments to held experts, under a chip's share, and
+        ``zero``, those to identity experts, where the router has them) of
         this model's bucket programs."""
         m = self.cfg.model
         cols = (("experts_hit",) if m.num_experts and (
             m.has_indexer or self.cfg.pp == 1) else ()) + (
-            ("held",) if m.router_experts else ())
+            ("held",) if m.router_experts else ()) + (
+            ("zero",) if m.zero_experts else ())
         if m.has_indexer:
             return ({"donate_argnames": ("i_pool",)}, (self.idx_sharding,),
                     cols)
@@ -1137,7 +1140,8 @@ class EngineCore:
                           held: Optional[float] = None,
                           key_blocks: Optional[Tuple[int, int]] = None,
                           steps: int = 1,
-                          sorted_calls: Optional[float] = None) -> None:
+                          sorted_calls: Optional[float] = None,
+                          zero: Optional[float] = None) -> None:
         """Host counters of what a dispatch made the experts and the
         indexer do. ``spans``: (first position, queries) per lane; a query
         at position p sees p + 1 keys. ``hit``: experts hit, read from the
@@ -1158,7 +1162,9 @@ class EngineCore:
         attention is the latent flash call. ``steps``: the steps of a decode
         dispatch (``dyn_moe_layer_calls_total``); ``sorted_calls``: those of
         its routed-layer calls that were dispatched sorted
-        (``dyn_moe_sorted_calls_total``)."""
+        (``dyn_moe_sorted_calls_total``). ``zero``: the real tokens'
+        assignments that went to identity experts
+        (``dyn_moe_zero_assignments_total``)."""
         m = self.cfg.model
         if not (m.num_experts or m.has_indexer):
             return
@@ -1170,6 +1176,8 @@ class EngineCore:
             if held is not None:
                 work[self.stage.moe_assignments] = float(held)
                 work[self.stage.moe_routed_assignments] = routed
+            if zero is not None:
+                work[self.stage.moe_zero_assignments] = float(zero)
             if hit is not None:
                 work[self.stage.moe_experts_hit] = float(hit)
                 # the calls those experts were hit in: every routed layer,
@@ -1191,9 +1199,11 @@ class EngineCore:
             work[self.stage.sparse_attn_selected] = float(sel)
         if m.has_latent:
             # what the dispatch's attention had to read and multiply at
-            # least (one layer's worth): the latent rows (a decode query
-            # reads its lane's visible rows; a chunk's queries share their
-            # lane's, read once) and the (query, visible key) pairs
+            # least (one layer's worth; where a published layer is two
+            # sublayers with a row each, ONE sublayer's): the latent rows (a
+            # decode query reads its lane's visible rows; a chunk's queries
+            # share their lane's, read once) and the (query, visible key)
+            # pairs
             pairs = [n * p0 + n * (n + 1) // 2 for p0, n in spans]
             work[self.stage.attn_latent_pairs] = float(sum(pairs))
             work[self.stage.attn_latent_keys] = float(
@@ -2766,7 +2776,11 @@ class EngineCore:
             held=(packed_np[0, 2 + cols.index("held")]
                   * sum(n for _, n in spans) / rec["rows"]
                   if "held" in cols else None),
-            key_blocks=rec["key_blocks"])
+            key_blocks=rec["key_blocks"],
+            # (a chunk routes its padding rows too, as ``held``)
+            zero=(packed_np[0, 2 + cols.index("zero")]
+                  * sum(n for _, n in spans) / rec["rows"]
+                  if "zero" in cols else None))
         if self.win is not None:
             for _, slot, start, _, _ in work:
                 self._window_fetched(slot.seq_id, start)
@@ -3296,7 +3310,9 @@ class EngineCore:
                   if "held" in cols else None), steps=N,
             sorted_calls=(col("sorted") if form == "by_hit" else
                           self.cfg.model.routed_layers * N * (
-                              form == "sorted")))
+                              form == "sorted")),
+            # (identity assignments are the busy rows' whatever the form)
+            zero=col("zero") if "zero" in cols else None)
         if self.win is not None:
             steps = self.stage.kv_resident_token_steps
             for (_, slot, _), s0 in zip(rec["active"], rec["lengths"]):

@@ -256,6 +256,29 @@ class LlamaConfig:
     # K/V rows narrower than a 128-lane tile stored ``kv_fold`` tokens to a
     # pool row ([.., page // kv_fold, kv_fold * head_dim]; "KV pool access")
     kv_fold: int = 1
+    # A layer of TWO sublayers with a routed branch across them
+    # (LongCat-Flash's shortcut-connected MoE): a published layer is two
+    # program layers (``num_layers`` counts the program's: mixers, cache
+    # rows, dense feed-forwards), each a mixer and a DENSE feed-forward; the
+    # first of a pair also computes the routed experts from the normed
+    # stream its own feed-forward reads, and that branch is added to the
+    # stream after the second's feed-forward (:func:`_ffn_block`
+    # ``branch``). ``ffn_kinds`` is all dense; :meth:`layer_branch` says
+    # which layers the branch leaves.
+    shortcut_moe: bool = False
+    # the last ``zero_experts`` outputs of the router (behind the
+    # ``router_experts`` or ``num_experts`` routed ones) are IDENTITY
+    # experts: no weights, their part of the result is gate x input
+    # (models/moe.py ``zero``)
+    zero_experts: int = 0
+    # latent attention's two constant scales (LongCat-Flash's
+    # ``mla_scale_q_lora`` / ``mla_scale_kv_lora``; None: 1): the normed
+    # low-rank q x sqrt(hidden / q_lora_rank), which reaches a head's whole
+    # q, and the normed compressed vector x sqrt(hidden / kv_lora_rank)
+    # BEFORE its expansion, which reaches k_nope and v and not the shared
+    # rotary key
+    latent_q_scale: Optional[float] = None
+    latent_kv_scale: Optional[float] = None
 
     @property
     def per_kind(self) -> bool:
@@ -321,10 +344,13 @@ class LlamaConfig:
         sigma; a float32 stream halves the flips (PERF.md section 6, PR 42,
         has the readings). Any routed model would gain so; the two older
         ones keep their programs until a PR judges the change on their
-        cells. Norms, projections and kernels see the normed activations in
+        cells; a model with a routed branch across sublayers
+        (``shortcut_moe``) came with it. Norms, projections and kernels see
+        the normed activations in
         the model's dtype as before; only the adds and the norms' inputs are
         wider."""
-        wide = self.residual_multiplier is not None or self.router_groups
+        wide = (self.residual_multiplier is not None or self.router_groups
+                or self.shortcut_moe)
         return jnp.float32 if wide else self.dtype
 
     @property
@@ -340,6 +366,17 @@ class LlamaConfig:
         if self.ffn_kinds is not None:
             return self.ffn_kinds[l] == 1
         return bool(self.num_experts)
+
+    def layer_branch(self, l: int) -> bool:
+        """Layer ``l`` computes a routed branch BESIDE its dense
+        feed-forward, which layer ``l + 1`` adds (``shortcut_moe``)."""
+        return self.shortcut_moe and l % 2 == 0
+
+    @property
+    def router_width(self) -> int:
+        """Outputs of the router: the deployment's routed experts and the
+        identity experts behind them."""
+        return (self.router_experts or self.num_experts) + self.zero_experts
 
     def kind_layers(self, window: bool) -> Tuple[int, ...]:
         """The layers of one attention kind of a per-kind model, in order."""
@@ -366,7 +403,9 @@ class LlamaConfig:
 
     @property
     def routed_layers(self) -> int:
-        return sum(self.layer_routed(l) for l in range(self.num_layers))
+        """Layers with a router: a routed feed-forward or a routed branch."""
+        return sum(self.layer_routed(l) or self.layer_branch(l)
+                   for l in range(self.num_layers))
 
     def layer_sliding(self, layer):
         """Every ``sliding_pattern``-th layer is full attention, the rest
@@ -433,6 +472,7 @@ class LlamaConfig:
             # the feed-forward every layer has is the SHARED one's width
             cfg = {**cfg, "intermediate_size": cfg["shared_intermediate_size"]}
         conv, cfg = _map_shortconv(cfg)
+        shortcut, cfg = _map_shortcut(cfg)
         latent = _map_latent(cfg)
         rs = cfg.get("rope_scaling") or {}
         if latent:
@@ -443,6 +483,12 @@ class LlamaConfig:
             raise ValueError("rope_scaling type 'yarn' is implemented for "
                              "latent attention alone (kv_lora_rank)")
         experts, kinds = _map_experts(cfg), _map_layer_kinds(cfg)
+        if shortcut and not (experts and latent):
+            raise ValueError(
+                "a layer of two sublayers with a routed branch across them "
+                "(num_layers / ffn_hidden_size / moe_topk) is implemented "
+                "with latent attention and routed experts (kv_lora_rank, "
+                "n_routed_experts)")
         if "ffn_kinds" in experts and not (
                 {"layer_kinds"} & {*kinds, *hybrid, *latent, *conv}):
             raise ValueError(
@@ -491,7 +537,7 @@ class LlamaConfig:
             qk_norm=(_is_gemma3(cfg) or _is_qwen3_family(cfg)
                      or bool(conv)),
             dtype=dtype,
-            **{**experts, **conv},
+            **{**experts, **conv, **shortcut},
             **_map_indexer(cfg),
             **kinds,
             **_map_multipliers(cfg),
@@ -581,17 +627,23 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
            cfg.get("topk_method", "greedy"))
     routers = {("softmax", "greedy"): "softmax",
                ("sigmoid", "noaux_tc"): "sigmoid_bias",
-               ("softmax", "group_limited_greedy"): "softmax_group"}
+               ("softmax", "group_limited_greedy"): "softmax_group",
+               # (no family publishes this pair: _map_shortcut's spelling
+               # of the LongCat-Flash router)
+               ("softmax", "bias"): "softmax_bias"}
     if law not in routers:
         raise ValueError(
             f"scoring_func {law[0]!r} with topk_method {law[1]!r} is not "
             f"implemented (softmax with greedy or group_limited_greedy; "
             f"sigmoid with noaux_tc)")
     grouped = routers[law] == "softmax_group"
-    if bool(cfg.get("norm_topk_prob", True)) == grouped:
+    # the two laws whose gates are the chosen scores x routed_scaling_factor
+    scaled = grouped or routers[law] == "softmax_bias"
+    if bool(cfg.get("norm_topk_prob", True)) == scaled:
         # each law as its family publishes it: the two that choose among
-        # all experts renormalise the chosen gates, the grouped one scales
-        # them by routed_scaling_factor and does not
+        # all experts renormalise the chosen gates, the grouped one and the
+        # bias-selected softmax scale them by routed_scaling_factor and do
+        # not
         raise ValueError(
             f"norm_topk_prob {cfg.get('norm_topk_prob')!r} with topk_method "
             f"{law[1]!r} is not implemented: greedy / noaux_tc renormalise "
@@ -637,7 +689,9 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
                 raise ValueError(f"{k} {cfg[k]} is not implemented with "
                                  f"topk_method {law[1]!r}: the experts are "
                                  f"chosen among all, not by group")
-        if cfg.get("routed_scaling_factor") not in (None, 1, 1.0):
+        if routers[law] == "softmax_bias":
+            out["routed_scaling"] = float(cfg.get("routed_scaling_factor", 1))
+        elif cfg.get("routed_scaling_factor") not in (None, 1, 1.0):
             raise ValueError(
                 f"routed_scaling_factor {cfg['routed_scaling_factor']} is "
                 f"not implemented with topk_method {law[1]!r}")
@@ -917,6 +971,106 @@ def _map_shortconv(cfg: Dict[str, Any]):
                 scoring_func="sigmoid", topk_method="noaux_tc",
                 moe_layer_freq=[0] * nd + [1] * (L - nd))
     rest.pop("tie_embedding", None)
+    return ours, rest
+
+
+# the keys by which the LongCat-Flash family (``model_type longcat_flash``)
+# spells what no other family has or spells otherwise, and every other key
+# a file of the family may carry: a key outside both lists is refused, so
+# that a sibling with a mechanism of its own (an indexer, n-gram embeddings,
+# multi-token prediction) is never served as this model
+_SHORTCUT_KEYS = ("num_layers", "ffn_hidden_size", "expert_ffn_hidden_size",
+                  "moe_topk", "zero_expert_num", "zero_expert_type",
+                  "mla_scale_q_lora", "mla_scale_kv_lora", "attention_method")
+_SHORTCUT_ALSO = ("architectures", "model_type", "torch_dtype",
+                  "transformers_version", "auto_map", "use_cache",
+                  "initializer_range", "attention_dropout", "bos_token_id",
+                  "eos_token_id", "pad_token_id", "attention_bias",
+                  "vocab_size", "hidden_size", "num_attention_heads",
+                  "num_key_value_heads", "kv_lora_rank", "q_lora_rank",
+                  "qk_rope_head_dim", "qk_nope_head_dim", "v_head_dim",
+                  "routed_scaling_factor", "n_routed_experts",
+                  "max_position_embeddings", "rms_norm_eps", "rope_theta",
+                  "rope_scaling", "hidden_act", "tie_word_embeddings",
+                  "router_bias", "norm_topk_prob", "expert_shard")
+
+
+def _map_shortcut(cfg: Dict[str, Any]):
+    """The LongCat-Flash family (``num_layers`` published layers, each TWO
+    latent-attention sublayers with a dense feed-forward each and a routed
+    branch that leaves after the first and lands after the second; a router
+    over ``n_routed_experts`` + ``zero_expert_num`` outputs of which the last
+    are identity experts) -> (ours, the config with the family's keys
+    re-spelt as the keys the other mappers read). Every key of the family is
+    mapped or RAISES; a config that carries none of them is none of this
+    function's business."""
+    have = [k for k in _SHORTCUT_KEYS if k in cfg]
+    if not have:
+        return {}, cfg
+    if cfg.get("model_type", "longcat_flash") != "longcat_flash":
+        raise ValueError(
+            f"config carries {have} under model_type "
+            f"{cfg['model_type']!r}: they mean what the LongCat-Flash "
+            f"modeling file says under 'longcat_flash' alone")
+    unknown = sorted(set(cfg) - set(_SHORTCUT_KEYS) - set(_SHORTCUT_ALSO))
+    if unknown:
+        raise ValueError(
+            f"a LongCat-Flash config carries keys this engine does not "
+            f"implement: {unknown}")
+    missing = [k for k in ("num_layers", "ffn_hidden_size",
+                           "expert_ffn_hidden_size", "moe_topk")
+               if not cfg.get(k)]
+    if missing or "num_hidden_layers" in cfg:
+        raise ValueError(
+            f"a LongCat-Flash config names its layers in num_layers (two "
+            f"sublayers each) and its widths in ffn_hidden_size / "
+            f"expert_ffn_hidden_size / moe_topk (missing: {missing})")
+    if cfg.get("attention_method", "MLA") != "MLA":
+        raise ValueError(f"attention_method {cfg['attention_method']!r} is "
+                         f"not implemented (MLA)")
+    if cfg.get("kv_lora_rank") is None:
+        raise ValueError("attention_method MLA without kv_lora_rank")
+    Z = int(cfg.get("zero_expert_num") or 0)
+    if Z and cfg.get("zero_expert_type") != "identity":
+        raise ValueError(
+            f"zero_expert_type {cfg.get('zero_expert_type')!r} is not "
+            f"implemented: a zero-computation expert is the identity")
+    if (Z or "zero_expert_type" in cfg) and not cfg.get("n_routed_experts"):
+        raise ValueError("zero_expert_num without n_routed_experts: identity "
+                         "experts lie behind the routed ones in the router")
+    if cfg.get("rope_scaling"):
+        raise ValueError(
+            f"rope_scaling {cfg['rope_scaling']!r} with a LongCat-Flash "
+            f"config is not implemented: plain rotary (rope_theta)")
+    for k in ("router_bias", "norm_topk_prob", "attention_bias"):
+        if cfg.get(k):
+            raise ValueError(
+                f"{k} true is not implemented for a LongCat-Flash config: "
+                f"the router is a matrix alone, its chosen scores x "
+                f"routed_scaling_factor are the gates as they are, and no "
+                f"projection carries a bias")
+    L, D = int(cfg["num_layers"]), cfg["hidden_size"]
+
+    def scale(flag: str, rank: str) -> Optional[float]:
+        # a flag that is false (or absent) is HONOURED: no scale
+        on = cfg.get(flag) and cfg.get(rank)     # (no rank: _map_latent's)
+        return math.sqrt(D / cfg[rank]) if on else None
+
+    ours = {"shortcut_moe": True, "zero_experts": Z,
+            "ffn_kinds": (0,) * (2 * L),
+            "latent_q_scale": scale("mla_scale_q_lora", "q_lora_rank"),
+            "latent_kv_scale": scale("mla_scale_kv_lora", "kv_lora_rank")}
+    rest = {k: v for k, v in cfg.items() if k not in _SHORTCUT_KEYS
+            and k not in ("router_bias", "rope_scaling")}
+    rest.update(
+        num_hidden_layers=2 * L,
+        intermediate_size=cfg["ffn_hidden_size"],
+        moe_intermediate_size=cfg["expert_ffn_hidden_size"],
+        num_experts_per_tok=cfg["moe_topk"],
+        # softmax scores, a selection bias that chooses and never weighs,
+        # gates x routed_scaling_factor, not renormalised (route_topk)
+        scoring_func="softmax", topk_method="bias", norm_topk_prob=False,
+        tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)))
     return ours, rest
 
 
@@ -1378,8 +1532,7 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     D, Hq, Dh, Dv, F, L, V = (cfg.hidden_size, cfg.num_heads, cfg.head_dim,
                               cfg.v_dim, cfg.intermediate_size,
                               cfg.num_layers, cfg.vocab_size)
-    E, Fe, R = cfg.num_experts, cfg.expert_width, (cfg.router_experts
-                                                   or cfg.num_experts)
+    E, Fe, R = cfg.num_experts, cfg.expert_width, cfg.router_width
     ks = iter(jax.random.split(key, 32))
 
     def mat(n, fan_in, *shape, scale=1.0):
@@ -1440,6 +1593,11 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         stacks["dense"] = {"ln2": jnp.ones((nd, D), jnp.float32),
                            "wg": mat(nd, D, D, F), "wu": mat(nd, D, D, F),
                            "wd": mat(nd, F, F, D, scale=res)}
+    if cfg.shortcut_moe:
+        # (behind the dense stack: ``mat`` makes a stacked tensor through
+        # float32 temporaries six times its size, 7 GB for eight layers'
+        # 6144 x 12288, which fit while the experts are not there yet)
+        stacks["routed"] = _init_branch(cfg, ks, mat, experts)
     if nr:
         st = {"ln2": jnp.ones((nr, D), jnp.float32),
               "wr": mat(nr, D, D, R),
@@ -1480,6 +1638,39 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     return params
 
 
+def _init_branch(cfg: LlamaConfig, ks, mat, experts) -> Dict[str, Any]:
+    """The routed branches of a model whose layer is two sublayers
+    (``shortcut_moe``), one a PUBLISHED layer, by the law of
+    :func:`_init_per_kind` with three terms of this family, each of a size
+    that shows in the logits when it is left out. No norm of their own: a
+    branch reads the normed stream its sublayer's dense feed-forward reads.
+
+    - ``wr`` [n, D, R + Z] N(0, 1.5^2 / D): router logits of spread 1.5,
+      where the other families have 1. The gates are softmax scores x
+      ``routed_scaling`` and are NOT renormalised: with 768 outputs of
+      spread 1 the 12 chosen scores are 0.01 each and the whole branch a
+      twentieth of the stream; at 1.5 they are 0.011-0.04, the 12 gates (x
+      6) sum to about 1.3, and a third of them (256 of 768 outputs) are
+      identity experts': 0.4 x the normed input, the size of the other
+      branches.
+    - ``rbias`` [n, R + Z] float32 N(0, 0.002^2), the selection bias, beside
+      scores whose 12th and 13th lie 0.0005 apart in the mean: it reorders
+      the top-k of most tokens and weighs nothing; identity and routed
+      outputs alike, so a third of the assignments stay identity experts'.
+    - the experts' ``wd`` N(0, 1 / fan-in), NOT damped by 1 / sqrt(2 L): an
+      expert's output is of unit rms and enters the stream x a gate of a
+      tenth. A deployment's 8 routed assignments a token then sum to about
+      0.3, one branch's size; this chip's share (16 of 512) sees a token in
+      five, to which it adds a tenth of the stream's size: small, as a
+      thirty-second of the experts is, and visible at that token."""
+    n, D, Fe = cfg.num_layers // 2, cfg.hidden_size, cfg.expert_width
+    R = cfg.router_width
+    return {"wr": mat(n, D, D, R, scale=1.5),
+            "rbias": 0.002 * jax.random.normal(next(ks), (n, R), jnp.float32),
+            "wg": experts(n, D, D, Fe), "wu": experts(n, D, D, Fe),
+            "wd": experts(n, Fe, Fe, D)}
+
+
 def _init_latent(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
     """The attention stack of a model with latent attention, by the law of
     :func:`_init_per_kind` (every activation of unit rms): ``w_dq`` [D, Rq]
@@ -1501,7 +1692,12 @@ def _init_latent(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
     the other families' inits set theirs.
     The two inner norms' weights are U(0.5, 1.5), not 1: at unit-rms inputs
     an RMSNorm of weight 1 is nearly the identity and a dropped one would
-    not show in the logits."""
+    not show in the logits. Where the model scales the two normed vectors
+    (``latent_q_scale`` 2, ``latent_kv_scale`` 3.46 at the published ranks:
+    they stand in for what a trained low-rank pair would have grown to) the
+    matrices BEHIND each scale are seeded that much smaller, so that q,
+    k_nope and v are of unit rms as above and the scores keep their spread;
+    a scale that is dropped, or reaches the rotary key too, then shows."""
     n, D, Hq = cfg.num_layers, cfg.hidden_size, cfg.num_heads
     Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
     Dn, Dr, Dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_dim
@@ -1510,12 +1706,15 @@ def _init_latent(cfg: LlamaConfig, ks, mat, res: float) -> Dict[str, Any]:
         return jax.random.uniform(next(ks), (n, width), jnp.float32,
                                   0.5, 1.5)
 
+    sq = 1.0 / (cfg.latent_q_scale or 1.0)
+    skv = 1.0 / (cfg.latent_kv_scale or 1.0)
     return {"ln1": jnp.ones((n, D), jnp.float32),
             "w_dq": mat(n, D, D, Rq), "ln_dq": weight(Rq),
-            "w_uq": mat(n, Rq, Hq * Dn, Rq), "w_uqr": mat(n, Rq, Hq * Dr, Rq),
+            "w_uq": mat(n, Rq, Hq * Dn, Rq, scale=sq),
+            "w_uqr": mat(n, Rq, Hq * Dr, Rq, scale=sq),
             "w_dkv": mat(n, D, D, Rkv + Dr), "ln_kv": weight(Rkv),
-            "w_uk": mat(n, Rkv, Hq, Dn, Rkv),
-            "w_uv": mat(n, Rkv, Hq, Rkv, Dv),
+            "w_uk": mat(n, Rkv, Hq, Dn, Rkv, scale=skv),
+            "w_uv": mat(n, Rkv, Hq, Rkv, Dv, scale=skv),
             "wo": mat(n, Hq * Dv, Hq, Dv, D, scale=res)}
 
 
@@ -2321,16 +2520,20 @@ def _latent_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     """:func:`layer_in` of a model with latent attention. The new rows: the
     rotated shared key [B*T, 1, rope -> a lane tile] into the K pool, the
     normed compressed vector [B*T, 1, Rkv] into the V pool. -> (q, pools,
-    None) with q = (q_pe [B,T,Hq,K-pool row], q^ [B,T,Hq,Rkv])."""
+    None) with q = (q_pe [B,T,Hq,K-pool row], q^ [B,T,Hq,Rkv]). The model's
+    two constant scales (``latent_q_scale``, ``latent_kv_scale``) ride the
+    two inner norms' weights, in the norm's float32: the scaled compressed
+    vector is what the cache keeps, the shared rotary key is not scaled."""
     Rkv = cfg.kv_lora_rank
     h = _normed(x, lp["ln1"][l], cfg)
     cq = rms_norm(jnp.einsum("btd,dr->btr", h, lp["w_dq"][l]),
-                  lp["ln_dq"][l], cfg.rms_eps)
+                  _scaled(lp["ln_dq"][l], cfg.latent_q_scale), cfg.rms_eps)
     q_nope, q_pe = (
         jnp.einsum("btr,kr->btk", cq, lp[w][l]).reshape(
             *cq.shape[:2], cfg.num_heads, -1) for w in ("w_uq", "w_uqr"))
     ckv = jnp.einsum("btd,dr->btr", h, lp["w_dkv"][l])
-    c = rms_norm(ckv[..., :Rkv], lp["ln_kv"][l], cfg.rms_eps)
+    c = rms_norm(ckv[..., :Rkv], _scaled(lp["ln_kv"][l], cfg.latent_kv_scale),
+                 cfg.rms_eps)
     q_pe = apply_rope(q_pe, *rope)
     k_pe = apply_rope(ckv[..., None, Rkv:], *rope)              # [B,T,1,rope]
     k_pool, v_pool = pools
@@ -2345,6 +2548,11 @@ def _latent_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         hold.extend(rows)
     q_lat = jnp.einsum("bthn,hnr->bthr", q_nope, lp["w_uk"][l])
     return (jnp.pad(q_pe, pad), q_lat), (k_pool, v_pool), None
+
+
+def _scaled(w: jax.Array, scale: Optional[float]) -> jax.Array:
+    """A norm's weight x a model's constant scale (None: as it is)."""
+    return w if scale is None else w * scale
 
 
 def latent_attend(cfg: LlamaConfig, q, k_ctx: jax.Array, c_ctx: jax.Array,
@@ -2368,10 +2576,12 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
               stats: Optional[Dict[str, Any]] = None,
               inside: Optional[Dict[str, int]] = None,
               ffn: Optional[Tuple[Dict[str, Any], Any]] = None,
-              active: Optional[jax.Array] = None) -> jax.Array:
+              active: Optional[jax.Array] = None,
+              branch: Optional[Tuple[Any, List[jax.Array]]] = None
+              ) -> jax.Array:
     """The layer after attention: out-projection of ``attn`` [B,T,Hq,Dh] and
     residual (Gemma2 norms the branch output first), then the feed-forward
-    (``active``: :func:`_ffn_block`'s).
+    (``active``, ``branch``: :func:`_ffn_block`'s).
 
     ``inside``: a caller that is ALREADY inside manual SPMD (``forward_pp``'s
     stage body; shard_maps do not nest) names the mesh axes it is inside of
@@ -2390,7 +2600,8 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
     if cfg.sandwich_norms:
         o = rms_norm(o, lp["ln1_post"][l], cfg.rms_eps, cfg.norm_offset)
     return _ffn_block(_residual(x, o, cfg), *(ffn or (lp, l)), cfg,
-                      mesh=mesh, stats=stats, inside=inside, active=active)
+                      mesh=mesh, stats=stats, inside=inside, active=active,
+                      branch=branch)
 
 
 def _residual(x: jax.Array, branch: jax.Array, cfg: LlamaConfig) -> jax.Array:
@@ -2406,15 +2617,28 @@ def _residual(x: jax.Array, branch: jax.Array, cfg: LlamaConfig) -> jax.Array:
 def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
                mesh=None, stats: Optional[Dict[str, Any]] = None,
                inside: Optional[Dict[str, int]] = None,
-               active: Optional[jax.Array] = None) -> jax.Array:
+               active: Optional[jax.Array] = None,
+               branch: Optional[Tuple[Any, List[jax.Array]]] = None
+               ) -> jax.Array:
     """Pre-norm FFN (dense or MoE) + residual; Gemma2 adds a post-norm on
     the branch output (sandwich norms). A routed layer adds its experts hit
     to ``stats["experts_hit"]`` (see :func:`forward`). ``inside`` as
     :func:`layer_out`'s. ``active`` [B] bool (a decode step: the rows the
     dispatch serves): a routed layer dispatches the busy rows' assignments
     alone and counts theirs alone (``moe.moe_ffn``), and adds 1 to
-    ``stats["sorted"]`` if it was dispatched sorted."""
+    ``stats["sorted"]`` if it was dispatched sorted.
+
+    ``branch`` (:func:`_branch_of`) = ((routed stack, index) or None, the
+    forward's list) for a model whose routed experts are a branch ACROSS two
+    sublayers
+    (``cfg.shortcut_moe``): the first of a pair computes them from the
+    normed stream its dense feed-forward reads and leaves the result in the
+    list; the second adds it to the stream after its own feed-forward's
+    residual."""
     h2 = _normed(x, lp["ln2"][l], cfg)
+    if branch is not None and branch[0] is not None:
+        branch[1].append(_routed_ffn(h2, *branch[0], cfg, mesh, stats,
+                                     active))
     routed = cfg.num_experts and "wr" in lp   # a per-kind model's dense layers
     if routed and inside is not None:
         # router replicated, experts sharded over ep and their width over tp
@@ -2429,30 +2653,7 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
                                ep=inside.get(AXIS_EP, 1),
                                psum_axes=tuple(axes))
     elif routed:
-        from .moe import moe_ffn
-        law = {}
-        if cfg.router != "softmax" or cfg.router_experts:
-            law = {"router": cfg.router,
-                   "first": cfg.expert_first if cfg.router_experts else None,
-                   "bias": lp["rbias"][l] if "rbias" in lp else None}
-        if cfg.router_groups:
-            law.update(groups=cfg.router_groups, scaling=cfg.routed_scaling)
-        if cfg.router_norm_eps:
-            law.update(norm_eps=cfg.router_norm_eps,
-                       scaling=cfg.routed_scaling)
-        if cfg.shared_experts:
-            law["shared"] = tuple(lp[k][l] for k in ("ws_g", "ws_u", "ws_d"))
-        out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
-                           cfg.experts_per_token, mesh=mesh, layer=l,
-                           active=active, stats=stats, **law)
-        if stats is not None:
-            if cfg.router_experts:
-                # (experts hit, assignments to held experts) of this call
-                hit, held = hit
-                stats["held"] = stats.get("held", 0) + held
-            stats["experts_hit"] = stats.get("experts_hit", 0) + hit
-            if "chosen" in stats:
-                stats["chosen"].append(chosen)
+        out = _routed_ffn(h2, lp, l, cfg, mesh, stats, active)
     else:
         g = jnp.einsum("btd,df->btf", h2, lp["wg"][l])
         u = jnp.einsum("btd,df->btf", h2, lp["wu"][l])
@@ -2461,7 +2662,46 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
             out = jax.lax.psum(out, AXIS_TP)
     if cfg.sandwich_norms:
         out = rms_norm(out, lp["ln2_post"][l], cfg.rms_eps, cfg.norm_offset)
-    return _residual(x, out, cfg)
+    x = _residual(x, out, cfg)
+    if branch is not None and branch[0] is None:
+        x = _residual(x, branch[1].pop(), cfg)
+    return x
+
+
+def _routed_ffn(h2: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
+                mesh, stats: Optional[Dict[str, Any]],
+                active: Optional[jax.Array]) -> jax.Array:
+    """The routed experts of ``lp`` at ``l`` on the normed stream ``h2``, by
+    the model's router law (``moe.moe_ffn``); counts into ``stats`` as
+    :func:`_ffn_block` says."""
+    from .moe import moe_ffn
+    share = bool(cfg.router_experts or cfg.zero_experts)
+    law = {}
+    if cfg.router != "softmax" or cfg.router_experts:
+        law = {"router": cfg.router,
+               "first": cfg.expert_first if share else None,
+               "bias": lp["rbias"][l] if "rbias" in lp else None}
+    if cfg.router_groups:
+        law.update(groups=cfg.router_groups, scaling=cfg.routed_scaling)
+    if cfg.router_norm_eps:
+        law.update(norm_eps=cfg.router_norm_eps,
+                   scaling=cfg.routed_scaling)
+    if cfg.router == "softmax_bias":
+        law.update(scaling=cfg.routed_scaling, zero=cfg.zero_experts)
+    if cfg.shared_experts:
+        law["shared"] = tuple(lp[k][l] for k in ("ws_g", "ws_u", "ws_d"))
+    out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
+                       cfg.experts_per_token, mesh=mesh, layer=l,
+                       active=active, stats=stats, **law)
+    if stats is not None:
+        if share:
+            # (experts hit, assignments to held experts) of this call
+            hit, held = hit
+            stats["held"] = stats.get("held", 0) + held
+        stats["experts_hit"] = stats.get("experts_hit", 0) + hit
+        if "chosen" in stats:
+            stats["chosen"].append(chosen)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -2734,6 +2974,18 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
     return x, tuple(pools)
 
 
+def _branch_of(params: Dict[str, Any], cfg: LlamaConfig, l: int,
+               carried: List[jax.Array]):
+    """:func:`layer_out`'s ``branch`` of layer ``l``: ((routed stack, index
+    in it) where the layer computes a routed branch beside its dense
+    feed-forward, None where it adds the one before it; the forward's
+    list), or None for a model without a branch across sublayers."""
+    if not cfg.shortcut_moe:
+        return None
+    at = (params[STACKS]["routed"], l // 2) if cfg.layer_branch(l) else None
+    return at, carried
+
+
 def _segments(cfg: LlamaConfig):
     """-> [(first layer, layers)]: a run of layers that keep a state a lane,
     of one mixer kind and one feed-forward kind, is one segment (one scan),
@@ -2963,6 +3215,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                        ) if read_pos.shape[1] > cfg.index_topk else None
         pools, index = (k_pool, v_pool, i_pool), (rope_i, read_pages, visible)
 
+    carried: List[jax.Array] = []       # a routed branch between sublayers
     for l, n in _segments(cfg):
         if cfg.layer_state(l):
             x, s_pools = _state_run(x, params, cfg, l, n, s_pools, s_lanes,
@@ -3013,7 +3266,8 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                 attn = attend_ctx(cfg, q, k_ctx, v_ctx,
                                   pick(sl, sliding_mask, mask), **extra)
         x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
-                      ffn=ffn if cfg.per_kind else None)
+                      ffn=ffn if cfg.per_kind else None,
+                      branch=_branch_of(params, cfg, l, carried))
 
     if logits_idx is not None:
         with scope("head"):
@@ -3473,6 +3727,7 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
                 sliding_mask = mask & (
                     t[None] > pos[:, None] - cfg.sliding_window)[:, None, :]
 
+    carried: List[jax.Array] = []       # a routed branch between sublayers
     for l, n in _segments(cfg):
         if cfg.layer_state(l):
             x, s_pools = _state_run(x, params, cfg, l, n, s_pools, None,
@@ -3522,6 +3777,7 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         else:
             pools = kv
         x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
-                      ffn=ffn if cfg.per_kind else None, active=active)
+                      ffn=ffn if cfg.per_kind else None, active=active,
+                      branch=_branch_of(params, cfg, l, carried))
 
     return (_lm_head(x, params, cfg), *pools, *w_pools, *s_pools)

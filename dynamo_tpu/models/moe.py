@@ -13,8 +13,8 @@ tokens): SORTED dispatch (stable-sort assignments by expert +
 ``lax.ragged_dot`` segment matmuls — only the experts hit are read) and
 DENSE dispatch (every local expert sees every token — one einsum a matrix,
 combines across shards with one psum). An unsharded mesh chooses by
-:func:`sorted_wins` (measured on the chip for one geometry, the old rule
-outside it); ep/tp-sharded meshes are dense.
+:func:`sorted_wins` (measured on the chip for five geometries, the old rule
+outside them); ep/tp-sharded meshes are dense.
 
 Reference capability: the reference inherits MoE/EP from its engines
 (SURVEY §2.5 — vllm patch touches deepseek_v2.py); on TPU the in-tree
@@ -83,6 +83,25 @@ def sorted_wins(rows: int, top_k: int, n_experts: int,
     itself, where it says dense and sorted is a quarter faster: a decode
     program of 32 rows is dense, chunks of 32 to 512 rows dense, a chunk of
     1,024 sorted.
+
+    And for 16 held of a router 768 wide (512 routed + 256 identity
+    outputs), 6144 x 2048 (75.5 MB an expert, sixteen times the first
+    geometry's), 12 a token, so a row gives the held experts 0.25
+    assignments and the rule says sorted under 64 rows (my chip run, PR 48,
+    ``benchmarks/tests/program_memory_scmoe.py``; ms a layer dense / sorted,
+    held experts hit a layer): a chunk of 32 rows (7.0 hit) 2.08 / 1.33; 64
+    rows (7.5) 2.01 / 1.63; 128 rows (14.3) 1.99 / 4.09; 256 rows (15.8) 2.15
+    / 4.97; 512 rows (15.8) 3.79 / 6.41; the decode program's 32 rows of
+    which b are busy and the rest absent: b = 4 (1.5 hit) 2.06 / 0.60; 12
+    (4.0) 2.05 / 0.94; 32 (7.0) 2.05 / 1.34. Dense streams the 1.21 GB of a
+    layer's held experts in 2.0 ms whatever the rows (600 GB/s) and turns
+    compute-bound between 256 and 512 rows (every row through all 16: 0.62
+    TFLOP a layer at 512); sorted pays 0.4 ms + 0.135 ms an expert hit (an
+    expert at 560 GB/s) while the groups are a few rows, and three times
+    that once they are 8 rows and more. The rule is on the measured side at
+    every row count this configuration's programs have but the 64-row chunk
+    bucket, where it says dense and sorted is a fifth faster; it is left as
+    it is.
 
     A DECODE step knows which of its rows are busy (``moe_ffn(active=)``):
     an idle row's assignments are absent ones, and where this rule says
@@ -215,7 +234,7 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
                scaling: float = 1.0, norm_eps: float = 0.0):
     """Router: top-k gate values + expert ids ([B,T,K] each).
     Shared by every dispatch formulation (incl. forward_pp's in-stage MoE)
-    so the gating policy has exactly one implementation. Three laws:
+    so the gating policy has exactly one implementation. Four laws:
     ``softmax`` (top-k of the softmax over all experts, renormalised),
     ``sigmoid_bias`` (sigmoid scores; the k largest of score + ``bias`` [E],
     the learned selection bias, are chosen; the gates are the chosen SCORES
@@ -224,7 +243,11 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
     (softmax scores; the experts lie in ``groups[0]`` equal groups, a group
     scores as its best expert, the ``groups[1]`` best groups stay and the
     top-k is taken among their experts; the gates are the chosen scores x
-    ``scaling`` and are NOT renormalised)."""
+    ``scaling`` and are NOT renormalised) and ``softmax_bias`` (softmax
+    scores over every output of the router, identity experts among them; the
+    k largest of score + ``bias`` are chosen; the gates are the chosen
+    SCORES x ``scaling``, NOT renormalised: the bias chooses and never
+    weighs)."""
     # float32 logits, not only a float32 softmax: bfloat16 resolves a
     # router logit of 32-64 to 0.25, i.e. a gate ratio to 25 %
     logits = jnp.einsum("btd,de->bte", x, wr.astype(x.dtype),
@@ -244,6 +267,11 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
         # (a softmax score is positive: 0 never beats an expert that stays)
         vals, idx = jax.lax.top_k(jnp.where(stay, probs, 0.0), top_k)
         return vals * scaling, idx
+    elif router == "softmax_bias":
+        probs = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(probs if bias is None else probs + bias,
+                               top_k)
+        return jnp.take_along_axis(probs, idx, axis=-1) * scaling, idx
     elif router == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
         vals, idx = jax.lax.top_k(probs, top_k)           # [B,T,K]
@@ -290,7 +318,8 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
             shared: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
             norm_eps: float = 0.0,
             active: Optional[jax.Array] = None,
-            stats: Optional[Dict[str, Any]] = None):
+            stats: Optional[Dict[str, Any]] = None,
+            zero: int = 0):
     """Routed MoE feed-forward (expert width F is the weights' own: a model
     whose experts are not ``intermediate_size`` wide needs nothing here).
     With ``layer``, ``wg`` / ``wu`` / ``wd`` are the stacked [L, E, ...]
@@ -324,11 +353,25 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
     A chip's share that the rule sends dense takes no notice of ``active``
     (its crossing is another one; ROADMAP ``held-experts-hit``). With
     ``stats`` such a call adds 1 to ``stats["sorted"]`` if it was dispatched
-    sorted."""
+    sorted.
+
+    ``zero``: the LAST ``zero`` outputs of the router are IDENTITY experts
+    (ids >= ``wr.shape[1] - zero``): experts without weights, whose part of
+    the result is gate x input. Such an assignment is never dispatched: its
+    gate goes into one scalar a token and scalar x ``x`` is added to the
+    routed sum, WHOLE under a chip's share (no chip holds them, every chip
+    of the deployment computes them for its own tokens, and they count once
+    when the shares are added up) and masked by ``active`` like every other
+    assignment of an idle row, whatever the dispatch form. ``first`` then
+    counts among the routed experts alone (0 where none is given) and the
+    second result is the share's pair. With ``stats`` the call adds its
+    identity assignments (busy rows') to ``stats["zero"]``."""
     with jax.named_scope("dynamo.moe_ffn"):
         vals, idx = route_topk(x, wr, top_k, router, bias, groups, scaling,
                                norm_eps)
         E = wg.shape[-3]
+        if zero and first is None:
+            first = 0
         share = 1.0 if first is None else E / wr.shape[1]
         form = dispatch_form(x.shape[0] * x.shape[1], top_k, E, share, mesh,
                              wg.shape[-1], masked=active is not None)
@@ -361,6 +404,16 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
             a = jax.nn.silu(jnp.einsum("btd,df->btf", x, sg)) * jnp.einsum(
                 "btd,df->btf", x, su)
             out = out + jnp.einsum("btf,fd->btd", a, sd)
+        if zero:
+            ident = idx >= wr.shape[1] - zero
+            if active is not None:
+                ident = ident & active[:, None, None]
+            gate = jnp.sum(jnp.where(ident, vals, 0.0), axis=-1, keepdims=True)
+            out = (out.astype(jnp.float32)
+                   + gate * x.astype(jnp.float32)).astype(x.dtype)
+            if stats is not None:
+                stats["zero"] = stats.get("zero", 0) + jnp.sum(
+                    ident.astype(jnp.int32))
         return out, hit, idx
 
 

@@ -550,6 +550,13 @@ class StageMetrics:
             "Token x expert pairs the router chose, all layers, held here "
             "or not (only a model served as a chip's share of its experts "
             "counts here)", ("kind",))
+        self.moe_zero_assignments = r.counter(
+            "dyn_moe_zero_assignments_total",
+            "Of the token x expert pairs the router chose, those that went "
+            "to IDENTITY experts (router outputs without weights, whose "
+            "part is gate x input), real tokens of busy rows alone, all "
+            "layers (only a model whose router has such outputs counts "
+            "here)", ("kind",))
         # the two page pools of a model whose window layers keep a cache of
         # their own (engine/cache.py)
         self.kv_pages_in_use = r.gauge(

@@ -299,7 +299,10 @@ def _latent_costs(m: Any, weight_bytes: Optional[float],
     form, 2 x Hq x (Rkv + rope + Rkv); the ONE row a token a layer the cache
     holds; of the feed-forward the dense layers, and for a routed layer the
     router, the shared expert (whole) and the chip's share of a token's
-    assignments."""
+    assignments; a routed branch BESIDE a dense feed-forward
+    (``layer_branch``) likewise, on top of the dense one, its identity
+    experts' share of the assignments at D multiply-adds each and no
+    weights."""
     from ..engine.cache import cache_kinds
 
     D, V, Hq = m.hidden_size, m.vocab_size, m.num_heads
@@ -307,17 +310,19 @@ def _latent_costs(m: Any, weight_bytes: Optional[float],
                            m.qk_rope_dim, m.v_dim)
     kind, = cache_kinds(m)
     Fe = m.expert_width
-    R = m.router_experts or m.num_experts
-    share = m.num_experts / R if R else 0.0
+    R = m.router_width
+    share, zero = (m.num_experts / R, m.zero_experts / R) if R else (0.0, 0.0)
     proj = (D * Rq + Rq * Hq * (Dn + Dr) + D * (Rkv + Dr)
             + Rkv * Hq * (Dn + Dv) + Hq * Dv * D)
     mat = n_params = float(m.num_layers * proj)
     for l in range(m.num_layers):
-        if m.layer_routed(l):
+        beside = m.layer_branch(l)
+        if m.layer_routed(l) or beside:
             every = D * R + 3 * D * m.shared_experts * Fe
-            mat += m.experts_per_token * share * 3 * D * Fe + every
+            mat += (m.experts_per_token * (share * 3 * D * Fe + zero * D)
+                    + every)
             n_params += m.num_experts * 3 * D * Fe + every
-        else:
+        if beside or not m.layer_routed(l):
             mat += 3 * D * m.intermediate_size
             n_params += 3 * D * m.intermediate_size
     if weight_bytes is None:

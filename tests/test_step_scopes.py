@@ -18,6 +18,7 @@ from dynamo_tpu.llm.protocols.common import BackendInput, StopConditions
 from dynamo_tpu.models import llama
 from dynamo_tpu.utils import jaxenv, roofline
 from tests.test_granite_hybrid import TINY as GRANITE
+from tests.test_longcat_flash import TINY as LONGCAT
 from tests.test_mimo_v2_flash import TINY as MIMO
 
 EVERY = {"embed", "attn_in", "kv_write", "attn_out", "ffn", "head", "sample"}
@@ -31,6 +32,11 @@ MODELS = {
                  EVERY | {"attn", "attn_full", "attn_window", "moe_ffn"}),
     "state": (lambda: llama.LlamaConfig.from_hf_config(GRANITE),
               EVERY | {"attn", "ssm_in", "ssm_out"}),
+    # two sublayers a layer and a routed branch across them: the branch and
+    # its identity part under ``moe_ffn``, the dense feed-forward beside it
+    # and the landing add under ``ffn``
+    "two-sublayers": (lambda: llama.LlamaConfig.from_hf_config(LONGCAT),
+                      EVERY | {"attn", "moe_ffn"}),
 }
 KIND_SCOPE = {"decode": "ssm_step", "prefill": "ssm_scan"}
 # instructions that hold others or move nothing: a trace attributes LEAF
