@@ -519,6 +519,13 @@ class EngineCore:
         self.decode_kv_write = (
             next(iter(how.values())) if len(set(how.values())) == 1
             else ",".join(f"{n}:{h}" for n, h in how.items()))
+        # ... and a prefill chunk's: a window a page run (llama.
+        # kv_write_pages) where the chunks this engine cuts start on a
+        # page's first slot, else a window a row. What a dispatch ran is
+        # counted (_chunk_form, dyn_engine_prefill_kv_writes_total)
+        self.prefill_kv_write = (
+            "page" if cfg.pp == 1 and cfg.prefill_chunk % cfg.page_size == 0
+            else "row")
 
         zero_fns: Dict[Tuple[int, ...], Any] = {}
 
@@ -736,7 +743,8 @@ class EngineCore:
         self.b_buckets = _buckets(1, max(1, min(lanes, cfg.max_batch)))
         self.moe_dispatch = self._moe_dispatch_forms()
         self._decode_fns: Dict[int, Any] = {}
-        self._prefill_batch_fns: Dict[Tuple[int, int, int], Any] = {}
+        self._prefill_batch_fns: Dict[Tuple[int, int, int, bool, str],
+                                      Any] = {}
         # verify programs, keyed (S, K): compiled lazily, and ONLY when spec
         # decoding is enabled — spec off costs zero extra programs
         self._verify_fns: Dict[Tuple[int, int], Any] = {}
@@ -1309,6 +1317,8 @@ class EngineCore:
         for Bp in self.b_buckets:
             for C in self.c_buckets:
                 for S in self.s_buckets:
+                    # the form a text chunk takes (_chunk_form); the other
+                    # compiles on first use
                     fn = self._prefill_fn(Bp, C, S)
                     zt = np.zeros((Bp, C), np.int32)
                     keys = s.key[jnp.asarray(np.zeros(Bp, np.int32))]
@@ -1459,14 +1469,32 @@ class EngineCore:
                 "decode", step, self._record_compile)
         return self._decode_fns[S]
 
-    def _prefill_fn(self, Bp: int, C: int, S: int, mm: bool = False):
+    def _chunk_form(self, C: int, aligned: bool = True,
+                    mm: bool = False) -> str:
+        """How a chunk program of bucket ``C`` writes its new K/V rows:
+        ``page`` (a window a page run: every lane's chunk starts on a page's
+        first slot, ``aligned``, which the host checks as it builds the
+        chunk, and the bucket is whole page runs or shorter than a page)
+        or ``row`` (a window a token: every other chunk, an image wave)."""
+        pg = self.page_size
+        m = self.cfg.model
+        fold = max(m.kv_fold,
+                   llama.index_fold(m) if m.has_indexer else 1)
+        return ("page" if self.prefill_kv_write == "page" and aligned
+                and not mm and (C % pg == 0 or C < pg)
+                and min(C, pg) % fold == 0 else "row")
+
+    def _prefill_fn(self, Bp: int, C: int, S: int, mm: bool = False,
+                    form: Optional[str] = None):
         """Batched prefill: Bp sequence chunks advance in ONE dispatch (the
         whole admission wave prefills together instead of one dispatch — and
         one host round-trip — per sequence). Every lane computes the LM head
         only at its own last chunk position (``logits_idx``) and samples; the
         host keeps results only for lanes whose prompt completed. Padded
-        lanes write to scratch page 0 with nothing valid to read."""
-        if (Bp, C, S, mm) not in self._prefill_batch_fns:
+        lanes write to scratch page 0 with nothing valid to read. ``form``:
+        :meth:`_chunk_form` of the chunk (a text chunk's by default)."""
+        form = form or self._chunk_form(C, mm=mm)
+        if (Bp, C, S, mm, form) not in self._prefill_batch_fns:
             cfg = self.cfg
             impl = {"pallas": "flash", "ring": "ring"}.get(
                 self.attn_impl, "xla")
@@ -1505,6 +1533,12 @@ class EngineCore:
                     # context is gathered by page
                     with llama.scope("attn"):
                         read_pages = read_idx[:, ::page] // page
+                    # ... and a chunk that starts on a page's first slot
+                    # writes its rows by page, a run every `page` tokens
+                    write_pages = None
+                    if form == "page":
+                        with llama.scope("kv_write"):
+                            write_pages = write_idx[:, ::page] // page
                     # image waves run the xla attention path: the span
                     # or-mask has no Pallas kernel input (text waves keep
                     # the fast path — mm programs compile separately)
@@ -1522,7 +1556,7 @@ class EngineCore:
                             s_lanes, s_reset, s_valid)}),
                         embed_override=((ov_vals, ov_mask) if mm else None),
                         attn_spans=((q_span, read_span) if mm else None),
-                        read_pages=read_pages)
+                        read_pages=read_pages, write_pages=write_pages)
                 with llama.scope("sample"):
                     tok, logp, new_keys = sample(
                         logits[:, 0], temp, top_p, top_k, keys)
@@ -1534,9 +1568,9 @@ class EngineCore:
                 return (packed, tok, new_keys, k_pool, v_pool, *ip)
 
             from ..utils.roofline import instrument_compile
-            self._prefill_batch_fns[(Bp, C, S, mm)] = instrument_compile(
-                "prefill", fn, self._record_compile)
-        return self._prefill_batch_fns[(Bp, C, S, mm)]
+            self._prefill_batch_fns[(Bp, C, S, mm, form)] = (
+                instrument_compile("prefill", fn, self._record_compile))
+        return self._prefill_batch_fns[(Bp, C, S, mm, form)]
 
     def _verify_fn(self, S: int, K: int):
         """Speculative-decoding verify program: ONE forward over K+1
@@ -2541,13 +2575,13 @@ class EngineCore:
                              read_idx, read_pos, read_valid, last_i, temp,
                              top_p, top_k, idxs, last_lanes,
                              mm_arrays=None, win_arrays=None,
-                             ssm_arrays=None):
+                             ssm_arrays=None, form=None):
         """Execute the batched prefill program + key bookkeeping. The SAME
         code path runs on the leader (from _prefill_enqueue) and on
         followers (from mirror_dispatch) so device state stays in lockstep."""
         s = self.sampling
         keys = s.key[jnp.asarray(idxs)]
-        fn = self._prefill_fn(Bp, C, S, mm=mm_arrays is not None)
+        fn = self._prefill_fn(Bp, C, S, mm=mm_arrays is not None, form=form)
         self.phase.to("prefill", f"dynamo.prefill[B{Bp},C{C},S{S}]")
         if mm_arrays is not None:
             packed, tok, new_keys, self.k_pool, self.v_pool = fn(
@@ -2694,6 +2728,8 @@ class EngineCore:
                          "q_span": q_span, "read_span": read_span}
         seeds = self._apply_pending_seeds()
         last_lanes = [lane for lane, w in enumerate(work) if w[4]]
+        form = self._chunk_form(
+            C, all(w[2] % self.page_size == 0 for w in work), mm)
         if self.dispatch_hook is not None:
             arrays = {"tokens": tokens, "positions": positions,
                       "write_idx": write_idx, "read_idx": read_idx,
@@ -2705,6 +2741,7 @@ class EngineCore:
             self.dispatch_hook("prefill", {
                 "Bp": Bp, "C": C, "S": S, "seeds": seeds,
                 "last_lanes": last_lanes, "mm": bool(mm_arrays),
+                "form": form,
             }, arrays)
         for _, slot, start, count, _ in work:
             slot.chunks += 1
@@ -2720,7 +2757,7 @@ class EngineCore:
         packed = self._run_prefill_program(
             Bp, C, S, tokens, positions, write_idx, read_idx, read_pos,
             read_valid, last_i, temp, top_p, top_k, idxs, last_lanes,
-            mm_arrays=mm_arrays,
+            mm_arrays=mm_arrays, form=form,
             **({} if win_arrays is None else {"win_arrays": win_arrays}),
             **({"ssm_arrays": ssm_arrays} if ssm_arrays else {}))
         if ssm_arrays:
@@ -2731,7 +2768,8 @@ class EngineCore:
             "prefill", amount=float(sum(w[3] for w in work)))
         self._inflight.append({"kind": "prefill",
                                "seq": self._count_dispatch(
-                                   "prefill", greedy=not any_sampling(temp)),
+                                   "prefill", greedy=not any_sampling(temp),
+                                   kv_write=form),
                                "packed": packed, "work": work,
                                "last_lanes": last_lanes,
                                "compiled": self._take_compiled_flag(),
@@ -2741,13 +2779,18 @@ class EngineCore:
                                "dispatched_at": t_disp})
         return len(last_lanes)
 
-    def _count_dispatch(self, kind: str, greedy: bool) -> int:
+    def _count_dispatch(self, kind: str, greedy: bool,
+                        kv_write: Optional[str] = None) -> int:
         """Count a dispatch just enqueued (whether it went behind records
         still unfetched; whether no lane it served sampled, so that its
         program skipped the sampler's window: ``greedy`` is the host's
-        reading of the predicate the program reads, ``any_sampling``);
-        returns its number in enqueue order."""
+        reading of the predicate the program reads, ``any_sampling``;
+        ``kv_write``: the form in which a program built on ``llama.forward``
+        wrote its new K/V rows, ``page`` or ``row``); returns its number in
+        enqueue order."""
         self.stage.engine_dispatches.inc(kind)
+        if kv_write is not None:
+            self.stage.engine_prefill_kv_writes.inc(kv_write)
         if greedy:
             self.stage.engine_greedy_dispatches.inc(kind)
         if self._inflight:
@@ -3179,6 +3222,8 @@ class EngineCore:
             S, K, tokens, page_tables, lengths, fresh, active_mask,
             upd_tok, upd_mask)
         self.stage.engine_dispatches.inc("verify")
+        # its slots start mid-page: llama.forward's row scatter
+        self.stage.engine_prefill_kv_writes.inc("row")
         self.stage.engine_dispatch_tokens.inc(
             "verify", amount=float(sum(len(d) + 1 for d in drafts.values())))
         self.phase.to("decode_fetch")
@@ -3245,7 +3290,7 @@ class EngineCore:
                 arrs["read_pos"], arrs["read_valid"], arrs["last_i"],
                 arrs["temp"], arrs["top_p"], arrs["top_k"], arrs["idxs"],
                 [int(x) for x in meta.get("last_lanes", [])],
-                mm_arrays=mm_arrays)
+                mm_arrays=mm_arrays, form=meta.get("form"))
         elif kind == "decode":
             s = self.sampling
             s.temperature = arrs["temp"]
@@ -3573,7 +3618,8 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
             core.paged_kernel or "none", dev0.platform, dev0.device_kind,
             str(core.mesh.devices.size), core.goodput.peaks.source,
             "+".join(k.label() for k in core.cache_kinds),
-            core.decode_kv_write, core.moe_dispatch, value=1)
+            core.decode_kv_write, core.moe_dispatch, core.prefill_kv_write,
+            value=1)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._queues: Dict[str, asyncio.Queue] = {}
         self._inbox: thread_queue.Queue = thread_queue.Queue()

@@ -2215,7 +2215,7 @@ def _require_xla_attn(cfg: LlamaConfig, attn_impl: str) -> None:
 # layer for whatever wants the stored order (the paged kernel). With the
 # head index spelt out the window is one [Dh] row (or one [page, Dh] page)
 # and the donated pool is updated in place. Measured on the chip: PERF.md §6,
-# PR 26. Every program that touches a pool goes through these three.
+# PR 26. Every program that touches a pool goes through these four.
 # ---------------------------------------------------------------------------
 
 #
@@ -2225,7 +2225,7 @@ def _require_xla_attn(cfg: LlamaConfig, attn_impl: str) -> None:
 # % f) * Dh ..``, which is the order the paged dma kernel's copies take. XLA
 # keeps a pool of 64-lane rows pages-minor and re-lays it whole at a decode
 # program's entry and exit; whole-tile rows it leaves as stored (PERF.md §7
-# b, and the index keys' fold below). The three accessors take either.
+# b, and the index keys' fold below). The four accessors take either.
 
 def kv_write(pool: jax.Array, layer, w_page: jax.Array, w_off: jax.Array,
              rows: jax.Array, mode: Optional[str] = None) -> jax.Array:
@@ -2255,6 +2255,30 @@ def _fold_fills(w_page, r, slot, f: int) -> jax.Array:
     shares = (w_page[:, None] == w_page[None]) & (r[:, None] == r[None])
     return shares[:, :, None] & (slot[None, :, None]
                                  == jnp.arange(f)[None, None, :])
+
+
+def kv_write_pages(pool: jax.Array, layer, pages: jax.Array,
+                   rows: jax.Array) -> jax.Array:
+    """Write ``rows`` [n, Hkv, Dh] a page at a time: the rows are ``pages``
+    [B, P]'s runs in order, run (b, j) the ``n // (B * P)`` tokens that
+    start on page ``pages[b, j]``'s first slot (a whole page, or a chunk
+    shorter than one). One [run, Dh] window a (head, run) where
+    :func:`kv_write` issues one a (head, token); a folded pool takes the
+    run's folded rows as they lie, with nothing read back. Runs of padding
+    name scratch page 0, and a run writes its slots past the lane's last
+    real token too (its own unsealed page: nothing reads them before a
+    later write)."""
+    Hkv, f = pool.shape[1], pool.shape[-1] // rows.shape[-1]
+    run = rows.shape[0] // pages.size
+    if run % f or run * pages.size != rows.shape[0]:
+        raise ValueError(f"{rows.shape[0]} rows do not fill {pages.size} "
+                         f"page runs of rows of {f} token(s)")
+    upd = rows.astype(pool.dtype).reshape(pages.size, run, Hkv, -1)
+    upd = upd.transpose(2, 0, 1, 3).reshape(Hkv, pages.size, run // f, -1)
+    at = (jnp.arange(Hkv)[:, None], pages.reshape(1, -1))
+    if run // f < pool.shape[3]:
+        at += (slice(run // f),)
+    return pool.at[(layer, *at)].set(upd)
 
 
 def kv_rows(pool: jax.Array, layer, r_page: jax.Array,
@@ -2362,17 +2386,22 @@ def _index_project(h: jax.Array, lp: Dict[str, Any], l: int,
 def _index_step(h: jax.Array, lp: Dict[str, Any], l: int, cfg: LlamaConfig,
                 rope_i, i_pool: jax.Array, w_page: jax.Array,
                 w_off: jax.Array, pages: jax.Array,
-                visible: Optional[jax.Array]):
+                visible: Optional[jax.Array],
+                w_pages: Optional[jax.Array] = None):
     """One layer's indexer, prefill chunk and decode step alike: write the
-    new tokens' index keys, then (``visible`` [B,T,S] given: the context
-    bucket is longer than ``index_topk``) score the lane's cached index keys
-    and keep each query's exact top-k. -> (i_pool, keep [B,T,S] or None)."""
+    new tokens' index keys (by page where ``w_pages`` names the chunk's
+    page runs: :func:`kv_write_pages`, the pool's one head), then
+    (``visible`` [B,T,S] given: the context bucket is longer than
+    ``index_topk``) score the lane's cached index keys and keep each
+    query's exact top-k. -> (i_pool, keep [B,T,S] or None)."""
     from ..ops.attention import index_scores, topk_keep
 
     qi, ki, wi = _index_project(h, lp, l, cfg, *rope_i)
     with scope("kv_write"):
-        i_pool = index_write(i_pool, l, w_page, w_off,
-                             ki.reshape(-1, ki.shape[-1]))
+        ki = ki.reshape(-1, ki.shape[-1])
+        i_pool = (index_write(i_pool, l, w_page, w_off, ki)
+                  if w_pages is None
+                  else kv_write_pages(i_pool, l, w_pages, ki[:, None]))
     if visible is None:
         return i_pool, None
     ctx = index_pages(i_pool, l, pages, cfg.index_head_dim)
@@ -2434,7 +2463,8 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
              w_page: jax.Array, w_off: jax.Array, mode: Optional[str] = None,
              index: Optional[Tuple[Any, ...]] = None,
              stats: Optional[Dict[str, Any]] = None,
-             hold: Optional[List[jax.Array]] = None):
+             hold: Optional[List[jax.Array]] = None,
+             w_pages: Optional[jax.Array] = None):
     """The layer before attention: input norm, the three projections (bias,
     q/k norm), rotary, and the new rows into the cache.
 
@@ -2442,7 +2472,9 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     slice) and ``l`` indexes them and ``pools`` = (k_pool, v_pool[, i_pool]);
     ``rope`` is the (cos, sin) the caller chose for this layer (:func:`pick`).
     The rows of ``x`` [B,T,D] go to token slots (``w_page``, ``w_off``), both
-    [B*T]; ``mode`` as :func:`kv_write`'s. For a model with an indexer
+    [B*T]; ``mode`` as :func:`kv_write`'s; or, ``w_pages`` [B, P] given, to
+    those pages a run each (:func:`kv_write_pages`: a prefill chunk that
+    starts on a page's first slot). For a model with an indexer
     ``index`` = (rope_i, pages, visible) is what :func:`_index_step` takes
     beside the slots, and ``stats["keep"]``, where the caller put a list,
     receives the layer's keep mask. ``hold``: a caller whose attention
@@ -2453,7 +2485,7 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     attention: :func:`_latent_in`, whose q is a pair."""
     if cfg.has_latent:
         return _latent_in(x, lp, l, cfg, rope, pools, w_page, w_off, mode,
-                          hold)
+                          hold, w_pages)
     h = _normed(x, lp["ln1"][l], cfg)
     q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
     k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
@@ -2481,9 +2513,8 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     # write, then attend: the new rows are part of their own context
     rows = [a.reshape(-1, *a.shape[2:]) for a in (k, v)]
     if hold is None:
-        with scope("kv_write"):
-            k_pool, v_pool = (kv_write(p, l, w_page, w_off, r, mode)
-                              for p, r in zip((k_pool, v_pool), rows))
+        k_pool, v_pool = _write_rows((k_pool, v_pool), l, w_page, w_off,
+                                     rows, mode, w_pages)
     else:
         hold.extend(rows)
     keep = None
@@ -2492,10 +2523,19 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
             raise ValueError(NO_INDEX_KEYS)
         rope_i, pages, visible = index
         i_pool[0], keep = _index_step(h, lp, l, cfg, rope_i, i_pool[0],
-                                      w_page, w_off, pages, visible)
+                                      w_page, w_off, pages, visible, w_pages)
     if stats is not None and "keep" in stats:
         stats["keep"].append(keep)
     return q, (k_pool, v_pool, *i_pool), keep
+
+
+@scope("kv_write")
+def _write_rows(pools, l, w_page, w_off, rows, mode, w_pages):
+    """A layer's new ``rows`` (one array a pool) into ``pools``: a window a
+    token, or a window a page run where the caller named them."""
+    return tuple(kv_write(p, l, w_page, w_off, r, mode) if w_pages is None
+                 else kv_write_pages(p, l, w_pages, r)
+                 for p, r in zip(pools, rows))
 
 
 # ---------------------------------------------------------------------------
@@ -2516,7 +2556,7 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
 # ---------------------------------------------------------------------------
 
 def _latent_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
-               rope, pools, w_page, w_off, mode, hold):
+               rope, pools, w_page, w_off, mode, hold, w_pages=None):
     """:func:`layer_in` of a model with latent attention. The new rows: the
     rotated shared key [B*T, 1, rope -> a lane tile] into the K pool, the
     normed compressed vector [B*T, 1, Rkv] into the V pool. -> (q, pools,
@@ -2541,9 +2581,8 @@ def _latent_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     rows = [jnp.pad(k_pe, pad).reshape(-1, 1, k_pool.shape[-1]),
             c.reshape(-1, 1, Rkv)]
     if hold is None:
-        with scope("kv_write"):
-            k_pool, v_pool = (kv_write(p, l, w_page, w_off, r, mode)
-                              for p, r in zip((k_pool, v_pool), rows))
+        k_pool, v_pool = _write_rows((k_pool, v_pool), l, w_page, w_off,
+                                     rows, mode, w_pages)
     else:
         hold.extend(rows)
     q_lat = jnp.einsum("bthn,hnr->bthr", q_nope, lp["w_uk"][l])
@@ -3026,6 +3065,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             stats: Optional[Dict[str, Any]] = None,
             win: Optional[Tuple[jax.Array, ...]] = None,  # window cache
             ssm: Optional[Tuple[jax.Array, ...]] = None,  # state pools
+            write_pages: Optional[jax.Array] = None,  # [B, ceil(T / page)]
             ) -> Tuple[jax.Array, ...]:
     """One forward pass over a token chunk against the paged KV pool.
 
@@ -3043,6 +3083,15 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
     gathered a page at a time instead of a row at a time (``read_idx`` is
     then not read and may be None; slots that are not valid may hold
     anything finite).
+
+    ``write_pages``: a caller whose every lane's chunk starts on a page's
+    first slot — ``write_idx[b, t] == write_pages[b, t // page] * page + t %
+    page`` for the lane's real tokens, 0 (scratch page 0) past them — passes
+    the page ids, and the new rows are written a page run at a time instead
+    of a row at a time (:func:`kv_write_pages`; every pool of the model, a
+    window cache's from its ``w_write_idx`` likewise). A run holds padded
+    tokens' rows too: they land in scratch page 0 or past the lane's last
+    token in its own unsealed page, slots that are not valid to any read.
 
     Returns (logits [B, T, vocab] fp32, k_pool, v_pool). With ``logits_idx``
     ([B] int32), the LM head runs only on each lane's hidden state at that
@@ -3107,9 +3156,11 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             x = jnp.where(ov_mask[..., None], ov_vals.astype(x.dtype), x)
     with scope("attn_in"):
         rope, rope_sl = rope_pair(cfg, positions)
-    with scope("kv_write"):
-        flat_w = write_idx.reshape(-1)
-        wp, wo = flat_w // page, flat_w % page
+    wp = wo = wwp = wwo = ww_pages = None
+    if write_pages is None:
+        with scope("kv_write"):
+            flat_w = write_idx.reshape(-1)
+            wp, wo = flat_w // page, flat_w % page
     if read_pages is None:
         with scope("attn"):
             rp, ro = read_idx // page, read_idx % page
@@ -3191,8 +3242,11 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
         wk_pool, wv_pool, w_write, w_pages, w_pos, w_valid = win
         w_pools = (wk_pool, wv_pool)
         with scope("kv_write"):
-            flat_ww = w_write.reshape(-1)
-            wwp, wwo = flat_ww // page, flat_ww % page
+            if write_pages is None:
+                flat_ww = w_write.reshape(-1)
+                wwp, wwo = flat_ww // page, flat_ww % page
+            else:
+                ww_pages = w_write[:, ::page] // page
         if attn_impl == "xla":
             with scope("attn"):
                 w_mask = (w_valid[:, None, :]
@@ -3230,10 +3284,11 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                 x, lp, la, ffn, cfg, pick(sl, rope_sl, rope), w_pools,
                 wwp, wwo, w_pages, positions, w_pos, w_valid,
                 flash_for(l) if attn_impl == "flash" else w_mask,
-                mesh, stats)
+                mesh, stats, ww_pages)
             continue
         q, pools, keep = layer_in(x, lp, la, cfg, pick(sl, rope_sl, rope),
-                                  pools, wp, wo, index=index, stats=stats)
+                                  pools, wp, wo, index=index, stats=stats,
+                                  w_pages=write_pages)
         # gather this sequence's context: [B, S, Hkv, Dh]
         with scope("attn"):
             if read_pages is not None:
@@ -3278,11 +3333,13 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
 
 
 def _window_layer(x, lp, la, ffn, cfg: LlamaConfig, rope, w_pools, wwp, wwo,
-                  w_pages, q_pos, w_pos, w_valid, attn, mesh, stats):
+                  w_pages, q_pos, w_pos, w_valid, attn, mesh, stats,
+                  ww_pages=None):
     """A window layer of a per-kind model over a prefill chunk: the same
     layer body around attention over the window cache's short context.
     ``attn``: the flash kernel of this layer, or the xla path's mask."""
-    q, w_pools, _ = layer_in(x, lp, la, cfg, rope, w_pools, wwp, wwo)
+    q, w_pools, _ = layer_in(x, lp, la, cfg, rope, w_pools, wwp, wwo,
+                             w_pages=ww_pages)
     with scope("attn"):
         k_ctx = kv_pages(w_pools[0], la, w_pages)
         v_ctx = kv_pages(w_pools[1], la, w_pages)

@@ -479,10 +479,13 @@ class StageMetrics:
             "scatter, or kind:how comma-joined where the kinds differ), the "
             "form the routed experts' dispatch takes in the decode and the "
             "chunk programs (dense: every expert's weights are read; "
-            "sorted: those of the experts hit; none for a dense model)",
+            "sorted: those of the experts hit; none for a dense model), the "
+            "form a text prefill chunk's K/V write takes (page: a window a "
+            "page run; row: a window a token)",
             ("worker", "attn_impl", "decode_attn_impl", "paged_kernel",
              "platform", "device_kind", "devices", "peak_source",
-             "cache_kinds", "decode_kv_write", "moe_dispatch"))
+             "cache_kinds", "decode_kv_write", "moe_dispatch",
+             "prefill_kv_write"))
         self.device_peak_bytes = r.gauge(
             "dyn_device_peak_bytes_in_use",
             "Peak device memory in use per engine device "
@@ -524,6 +527,12 @@ class StageMetrics:
             "dyn_engine_greedy_dispatches_total",
             "Those whose active lanes were all at temperature 0: the "
             "program skipped the sampler's top-k window", ("kind",))
+        self.engine_prefill_kv_writes = r.counter(
+            "dyn_engine_prefill_kv_writes_total",
+            "Dispatches of a program that writes a chunk's new K/V rows "
+            "itself (a prefill chunk, a speculative verify round), by the "
+            "form the write took: page (a window a page run) or row (a "
+            "window a token)", ("form",))
         self.engine_dispatch_tokens = r.counter(
             "dyn_engine_dispatch_tokens_total",
             "Token positions computed by those dispatches (prompt tokens "

@@ -279,12 +279,15 @@ def test_paged_whole_pool_by_layer_compiles_for_v5e(v5e, geom):
 
 
 @pytest.mark.parametrize("program", ["decode_scan", "prefill_chunk",
-                                     "prefill_chunk_rows"])
+                                     "prefill_chunk_rows",
+                                     "prefill_chunk_by_page"])
 def test_bucket_program_bodies_keep_the_pool_as_stored(v5e, program):
     """The decode scan and the prefill chunk at Dh = 128 widths (qwen2-1.5b,
-    depth cut to two layers): the donated pools are updated in place — no
-    whole-pool copy at entry or exit, no per-layer slice re-laid for the
-    kernel — and come back aliased to their inputs."""
+    depth cut to two layers; the chunk's context read by row and by page,
+    its new rows written by row and, ``by_page``, a page run at a time): the
+    donated pools are updated in place — no whole-pool copy at entry or
+    exit, no per-layer slice re-laid for the kernel — and come back aliased
+    to their inputs."""
     from dynamo_tpu.parallel.mesh import serving_mesh
 
     cfg = llama.preset("qwen2-1.5b", num_layers=2)
@@ -309,9 +312,10 @@ def test_bucket_program_bodies_keep_the_pool_as_stored(v5e, program):
 
     def prefill_chunk(p, t, pos, k, v, wi, ri, rp, rv, li):
         pages = None if program.endswith("rows") else ri[:, ::PAGE] // PAGE
+        runs = wi[:, ::PAGE] // PAGE if program.endswith("by_page") else None
         return llama.forward(p, cfg, t, pos, k, v, wi, ri, rp, rv,
                              attn_impl="flash", mesh=mesh, logits_idx=li,
-                             read_pages=pages)
+                             read_pages=pages, write_pages=runs)
 
     i32 = jnp.int32
     if program == "decode_scan":
