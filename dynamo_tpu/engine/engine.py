@@ -381,6 +381,19 @@ class EngineCore:
                 # the put is a resharding — checkpoint loads meter in the
                 # loader
                 lambda a, s: global_put(a, s), params, shardings)
+        # whatever the source, the bucket programs are handed the q / k / v
+        # projections as their matmuls read them (llama.stored_params: a
+        # matrix [H x Dh, D] a layer, so that no program re-lays a weight
+        # or cuts a layer out of a stack), made once here, a leaf at a
+        # time. What indexes the stack by a traced layer keeps the
+        # published tree: a pipeline stage, the pager's programs
+        # (dyn_engine_info{attn_proj})
+        from ..llm.kvpage.runner import PagedConfig
+        self.attn_proj = ("published" if cfg.pp > 1
+                          or PagedConfig.resolve(cfg) is not None
+                          else "out_in")
+        if self.attn_proj == "out_in":
+            self.params = llama.stored_params(self.params, donate=True)
 
         # --- vision tower (Gemma3 VLM) --------------------------------
         # replicated params (the tower is tiny next to the LM; sharding it
@@ -815,7 +828,6 @@ class EngineCore:
         # sealed blocks to the host tier, decode streams them back per
         # layer through staged uploads (docs/long_context.md)
         self.kvpager = None
-        from ..llm.kvpage.runner import PagedConfig
         pcfg = PagedConfig.resolve(cfg)
         if pcfg is not None:
             from ..llm.kvpage.programs import PagedPrograms
@@ -3619,7 +3631,7 @@ class JaxEngine(AsyncEngine[BackendInput, EngineOutput]):
             str(core.mesh.devices.size), core.goodput.peaks.source,
             "+".join(k.label() for k in core.cache_kinds),
             core.decode_kv_write, core.moe_dispatch, core.prefill_kv_write,
-            value=1)
+            core.attn_proj, value=1)
         self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._queues: Dict[str, asyncio.Queue] = {}
         self._inbox: thread_queue.Queue = thread_queue.Queue()

@@ -159,15 +159,18 @@ def load_llama_params(path: str, cfg: LlamaConfig,
 
 
 def save_llama_params(path: str, params: Dict[str, Any], cfg: LlamaConfig) -> None:
-    """Write params back out in HF layout (used by tests to round-trip)."""
+    """Write params back out in HF layout (used by tests to round-trip):
+    the published tree or the one an engine stores (``llama.stored_params``:
+    a layer's ``wq`` / ``wk`` / ``wv`` [H x Dh, D] is the file's own
+    ``[out, in]``)."""
     from safetensors.numpy import save_file
 
     os.makedirs(path, exist_ok=True)
     # safetensors writes the raw buffer: every transposed view MUST be made
     # contiguous first or the transpose is silently lost
     C = np.ascontiguousarray
-    L, D, Hq, Hkv, Dh = (cfg.num_layers, cfg.hidden_size, cfg.num_heads,
-                         cfg.num_kv_heads, cfg.head_dim)
+    L, D, Hq, Dh = (cfg.num_layers, cfg.hidden_size, cfg.num_heads,
+                    cfg.head_dim)
     lp = params["layers"]
     out: Dict[str, np.ndarray] = {
         "model.embed_tokens.weight": np.asarray(params["embed"], np.float32),
@@ -189,12 +192,10 @@ def save_llama_params(path: str, params: Dict[str, Any], cfg: LlamaConfig) -> No
         else:
             out[p + "post_attention_layernorm.weight"] = np.asarray(
                 lp["ln2"][i], np.float32)
-        out[p + "self_attn.q_proj.weight"] = C(np.asarray(
-            lp["wq"][i], np.float32).reshape(D, Hq * Dh).T)
-        out[p + "self_attn.k_proj.weight"] = C(np.asarray(
-            lp["wk"][i], np.float32).reshape(D, Hkv * Dh).T)
-        out[p + "self_attn.v_proj.weight"] = C(np.asarray(
-            lp["wv"][i], np.float32).reshape(D, Hkv * Dh).T)
+        for w, name in (("wq", "q_proj"), ("wk", "k_proj"), ("wv", "v_proj")):
+            m = np.asarray(lp[w][i], np.float32)
+            out[p + f"self_attn.{name}.weight"] = C(
+                m if m.ndim == 2 else m.reshape(D, -1).T)
         out[p + "self_attn.o_proj.weight"] = C(np.asarray(
             lp["wo"][i], np.float32).reshape(Hq * Dh, D).T)
         out[p + "mlp.gate_proj.weight"] = C(np.asarray(lp["wg"][i], np.float32).T)

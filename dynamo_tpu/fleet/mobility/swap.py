@@ -111,6 +111,12 @@ def hot_swap(core, host_params, new_cfg, group_layers: Optional[int] = None
         # weights; swapping only the LM half would serve mismatched
         # encoders — take the full reload path
         raise SwapError("unsupported", "VLM engines reload cold")
+    if core.attn_proj == "out_in":
+        # the host tree is the published one (the loader's); this engine's
+        # has its q / k / v projections a matrix a layer (views here)
+        from ...models import llama
+
+        host_params = llama.stored_params(host_params)
     new_flat = _flat(host_params)
     old_flat = _flat(core.params)
     if set(new_flat) != set(old_flat):
@@ -146,7 +152,7 @@ def hot_swap(core, host_params, new_cfg, group_layers: Optional[int] = None
         if src.dtype != old_leaf.dtype:
             src = src.astype(old_leaf.dtype)
         slab_bytes += src.nbytes
-        if layered and path and path[0] == "layers" \
+        if layered and len(path) == 2 and path[0] == "layers" \
                 and old_leaf.shape[0] == L and L > group_layers:
             buf = old_leaf
             for g0 in range(0, L, group_layers):

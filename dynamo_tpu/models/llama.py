@@ -33,7 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import AXIS_EP, AXIS_TP
 
@@ -1788,19 +1788,23 @@ def layer_stacks(params: Dict[str, Any], cfg: LlamaConfig, l: int):
 
 
 def param_specs(cfg: LlamaConfig, tp_size: int = 1,
-                pp: int = 1) -> Dict[str, Any]:
+                pp: int = 1, stored: bool = False) -> Dict[str, Any]:
     """PartitionSpecs: tp shards attention heads, the ffn dimension, and —
     when the model is untied and the vocab divides tp — the LM head's vocab
     dim. KV projections replicate when GQA kv_heads aren't divisible by tp;
     the embedding stays replicated (token gathers need the full table).
     With ``pp > 1`` the stacked layer dim of every per-layer param shards
-    over the pipeline axis (each stage materializes only its layers)."""
+    over the pipeline axis (each stage materializes only its layers).
+    ``stored``: of the tree :func:`stored_params` makes (``pp`` 1), whose
+    q / k / v matrices carry the heads' sharding on their merged axis."""
     from ..parallel.mesh import AXIS_EP, AXIS_PP
 
     if cfg.per_kind:
         # one chip (validate_tp): every tensor replicated, whatever its rank
-        shapes = jax.eval_shape(partial(_init_per_kind, cfg),
-                                jax.random.PRNGKey(0))
+        init = partial(_init_per_kind, cfg)
+        shapes = jax.eval_shape(
+            (lambda k: stored_params(init(k))) if stored else init,
+            jax.random.PRNGKey(0))
         return jax.tree.map(lambda a: P(*(None,) * a.ndim), shapes)
     st = AXIS_PP if pp > 1 else None     # the [L, ...] stack dim
     tp = AXIS_TP
@@ -1857,7 +1861,99 @@ def param_specs(cfg: LlamaConfig, tp_size: int = 1,
         # only where sampling consumes it. Weight memory drops V*D/tp too.
         head_tp = tp if cfg.vocab_size % max(tp_size, 1) == 0 else None
         specs["lm_head"] = P(None, head_tp)
+    if stored:
+        for w, heads, width in (("wq", cfg.num_heads, cfg.head_dim),
+                                ("wk", cfg.num_kv_heads, cfg.head_dim),
+                                ("wv", cfg.num_kv_heads, cfg.v_dim)):
+            _, d_ax, h_ax, _ = specs["layers"][w]
+            if _cut_out((cfg.num_layers, cfg.hidden_size, heads, width),
+                        cfg.dtype):
+                specs["layers"][w] = (P(h_ax, d_ax),) * cfg.num_layers
     return specs
+
+
+# the three projections into attention, which an engine stores as its
+# programs' matmuls read them (stored_params)
+ATTN_IN = ("wq", "wk", "wv")
+
+# ... up to this many bytes a layer. A matmul operand of 8-32 MB the compiler
+# wants standing alone (it prefetches it whole into fast memory), and cuts
+# it out of a stack to have it so; one of 100 MB and more (mimo's ``wq``,
+# 12,288 x 4,096; every feed-forward matrix) it streams from where it lies
+# in the stack, and handed it as a buffer of its own the chunk program was
+# 6 % slower (mimo-v2-flash-7l.mixedqueue, PERF.md section 6, PR 50)
+CUT_OUT_BYTES = 64 << 20
+
+
+def stored_params(params: Dict[str, Any], donate: bool = False
+                  ) -> Dict[str, Any]:
+    """The tree an engine hands its bucket programs, from the published one
+    (what :func:`init_params` returns and a loader builds): the same values,
+    ``wq`` / ``wk`` / ``wv`` (:data:`ATTN_IN`) of every attention stack a
+    TUPLE of its layers' matrices [H x width, D] (heads merged, head-major,
+    so a tensor-parallel shard of the merged axis is whole heads; the
+    contraction dimension minor: a checkpoint's own ``[out, in]``) where a
+    layer's matrix is no larger than :data:`CUT_OUT_BYTES`, every other
+    leaf the very object it was. :func:`layer_in` reads either form,
+    by the weight's rank.
+
+    Why not the published stack [n, D, H, width]: at 7B widths the compiler
+    re-laid it for the matmuls inside every program (the whole of ``wq``
+    once a decode dispatch, a layer's three at every prefill chunk) and cut
+    each layer out of it into a buffer of its own besides, a tenth of the
+    device time of ``mistral-7b-16l.longprompt``. Why not a stack [n, H x
+    width, D]: nothing is re-laid, but a layer is still cut out of the stack
+    before it is multiplied by, 0.5 GB a decode STEP, and the dispatch is
+    slower than the published form's (PERF.md section 6, PR 50, has the
+    three forms' readings). A matrix that is a buffer of its own is read in
+    place, through the compiler's asynchronous prefetch. What indexes the
+    stack by a traced layer (a pipeline stage's ``shard_map``, the pager's
+    one program a layer class) keeps the published tree.
+
+    The leaves may be device arrays (cut on the device a leaf at a time, as
+    sharded as the source: ``donate`` deletes each source once its program
+    is under way, so the peak is the tree and ONE leaf), host arrays (views)
+    or tracers; a tree that is stored already comes back as it is."""
+    def relaid(_, w):
+        if isinstance(w, tuple) or not _cut_out(w.shape, w.dtype):
+            return w
+        n, d = w.shape[:2]
+
+        def cut(a):
+            return tuple(a[i].reshape(d, -1).T for i in range(n))
+        if not isinstance(w, jax.Array) or isinstance(w, jax.core.Tracer):
+            return cut(w)
+        sh = w.sharding
+        if isinstance(sh, NamedSharding):
+            _, d_ax, h_ax, _ = (*sh.spec, None, None, None, None)[:4]
+            sh = NamedSharding(sh.mesh, P(h_ax, d_ax))
+        out = jax.jit(cut, out_shardings=(sh,) * n)(w)
+        if donate:
+            w.delete()
+        return out
+
+    return map_attn_in(relaid, params)
+
+
+def _cut_out(stack_shape, dtype) -> bool:
+    """Whether a projection stack [n, D, H, width] is stored a matrix a
+    layer (:data:`CUT_OUT_BYTES`)."""
+    return (math.prod(stack_shape[1:]) * jnp.dtype(dtype).itemsize
+            <= CUT_OUT_BYTES)
+
+
+def map_attn_in(fn, params: Dict[str, Any]) -> Dict[str, Any]:
+    """``params`` with ``fn(name, leaf)`` in place of ``wq`` / ``wk`` / ``wv``
+    of every attention stack (the ``layers`` tree, or a per-kind model's
+    ``full`` and ``window`` stacks; a latent stack has none), every other
+    leaf the very object it was."""
+    def stack(st):
+        return {k: fn(k, w) if k in ATTN_IN else w for k, w in st.items()}
+
+    if STACKS in params:
+        return {**params, STACKS: {kind: stack(st) for kind, st
+                                   in params[STACKS].items()}}
+    return {**params, "layers": stack(params["layers"])}
 
 
 def validate_tp(cfg: LlamaConfig, tp: int, ep: int = 1) -> None:
@@ -2487,9 +2583,9 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         return _latent_in(x, lp, l, cfg, rope, pools, w_page, w_off, mode,
                           hold, w_pages)
     h = _normed(x, lp["ln1"][l], cfg)
-    q = jnp.einsum("btd,dhk->bthk", h, lp["wq"][l])
-    k = jnp.einsum("btd,dhk->bthk", h, lp["wk"][l])
-    v = jnp.einsum("btd,dhk->bthk", h, lp["wv"][l])
+    q = _project(h, lp["wq"][l], cfg.head_dim)
+    k = _project(h, lp["wk"][l], cfg.head_dim)
+    v = _project(h, lp["wv"][l], cfg.v_dim)
     if cfg.attention_bias:
         q = q + lp["bq"][l]
         k = k + lp["bk"][l]
@@ -2527,6 +2623,18 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     if stats is not None and "keep" in stats:
         stats["keep"].append(keep)
     return q, (k_pool, v_pool, *i_pool), keep
+
+
+def _project(h: jax.Array, w: jax.Array, width: int) -> jax.Array:
+    """``h`` [B,T,D] through one layer's q, k or v projection -> [B,T,H,
+    width], in whichever form the weight comes, which its rank says: [H x
+    width, D] as an engine stores it (:func:`stored_params`: a plain matmul
+    with the contraction dimension minor, then a reshape of the small
+    result), or the published [D, H, width]."""
+    if w.ndim == 3:
+        return jnp.einsum("btd,dhk->bthk", h, w)
+    y = jnp.einsum("btd,fd->btf", h, w)
+    return y.reshape(*y.shape[:2], -1, width)
 
 
 @scope("kv_write")

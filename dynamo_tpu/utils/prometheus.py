@@ -481,11 +481,14 @@ class StageMetrics:
             "chunk programs (dense: every expert's weights are read; "
             "sorted: those of the experts hit; none for a dense model), the "
             "form a text prefill chunk's K/V write takes (page: a window a "
-            "page run; row: a window a token)",
+            "page run; row: a window a token), the form the programs are "
+            "handed the q / k / v projection weights in (out_in: a matrix "
+            "[heads x head width, hidden] a layer, as their matmuls read "
+            "it; published: the stack [layers, hidden, heads, head width])",
             ("worker", "attn_impl", "decode_attn_impl", "paged_kernel",
              "platform", "device_kind", "devices", "peak_source",
              "cache_kinds", "decode_kv_write", "moe_dispatch",
-             "prefill_kv_write"))
+             "prefill_kv_write", "attn_proj"))
         self.device_peak_bytes = r.gauge(
             "dyn_device_peak_bytes_in_use",
             "Peak device memory in use per engine device "

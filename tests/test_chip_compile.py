@@ -339,6 +339,108 @@ def test_bucket_program_bodies_keep_the_pool_as_stored(v5e, program):
     assert ma.temp_size_in_bytes < pool_bytes // 8
 
 
+def _weight_copies(txt: str, published) -> list:
+    """Lines of a compiled program whose RESULT has the dimensions of a q /
+    k / v projection (``published``: the stacks [L, D, H, Dh]), whole or
+    one layer of it, as published or with the heads merged ([H x Dh, D]),
+    and which is a ``copy``, a ``transpose`` or a fusion: no step of
+    inference computes a tensor of a weight's shape, so such an operation
+    can only re-lay the weight or cut a layer out of its stack (a tenth of
+    the device time of mistral-7b-16l.longprompt before PR 50). The
+    compiler's asynchronous prefetch into fast memory (``copy-start`` /
+    ``slice-start`` and their ``-done``) is not one, nor is an instruction
+    INSIDE a fusion (a matmul's fusion names its operand through a bitcast
+    fusion of the weight's shape: the operand is read where it lies)."""
+    import re
+
+    dims = set()
+    for L, D, H, Dh in published:
+        for layer in ((D, H, Dh), (H * Dh, D)):
+            layer = ",".join(map(str, layer))
+            dims |= {layer, "1," + layer, f"{L},{layer}"}
+    fused = set(re.findall(r"kind=k\w+, calls=(%[\w.\-]+)", txt))
+    found, inside = [], False
+    for ln in txt.splitlines():
+        if ln[:1] in "%E":                      # a computation's header
+            inside = ln.split(" ", 1)[0] in fused
+        elif (not inside
+              and (m := re.search(r"= (.*?) (copy|transpose|fusion)\(", ln))
+              and set(re.findall(r"\w+\[([\d,]+)\]", m.group(1))) & dims):
+            found.append(ln)
+    return found
+
+
+# (lanes, chunk, context, pages): the two dense cells' programs
+WEIGHT_CASES = {"mistral-7b": (16, 256, 2176, 529),
+                "qwen2-1.5b": (32, 128, 1152, 1089)}
+
+
+@pytest.mark.parametrize("preset,program,tree", [
+    ("mistral-7b", "decode_scan", "stored"),
+    ("mistral-7b", "prefill_chunk", "stored"),
+    ("qwen2-1.5b", "decode_scan", "stored"),
+    ("qwen2-1.5b", "prefill_chunk", "stored"),
+    ("mistral-7b", "decode_scan", "published")])
+def test_no_bucket_program_copies_a_weight(v5e, preset, program, tree):
+    """The decode scan and the page-run prefill chunk at the two dense
+    cells' widths (depth four) on the tree an engine stores
+    (``llama.stored_params``: ``wq`` / ``wk`` / ``wv`` a matrix [H x Dh, D]
+    a layer) hold no operation that produces a weight-shaped array. The
+    fifth case is the finding the other way round, so that the test is known
+    to see what it guards: on the published tree the decode scan at
+    mistral-7b's widths copies the WHOLE ``wq`` stack, ``copy
+    bf16[L,4096,32,128]``, once a dispatch (1.6 ms of a dispatch on the
+    chip, PERF.md section 6, PR 50)."""
+    from dynamo_tpu.parallel.mesh import serving_mesh
+
+    cfg = llama.preset(preset, num_layers=4)
+    mesh = serving_mesh(1, devices=[v5e])
+    B, C, S, n_pages = WEIGHT_CASES[preset]
+    P = S // PAGE
+    published = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    shapes = (published if tree == "published"
+              else jax.eval_shape(llama.stored_params, published))
+    params = jax.tree.map(lambda a: _sds(v5e, a.shape, a.dtype), shapes)
+    pool = _sds(v5e, (cfg.num_layers, cfg.num_kv_heads, n_pages, PAGE,
+                      cfg.head_dim), cfg.dtype)
+    i32 = jnp.int32
+
+    def decode_scan(p, t, k, v, pt, ln):
+        def one(carry, _):
+            t, ln, k, v = carry
+            lg, k, v = llama.forward_decode(p, cfg, t, k, v, pt, ln,
+                                            attn_impl="pallas", mesh=mesh)
+            return (jnp.argmax(lg[:, 0], -1).astype(i32), ln + 1, k, v), None
+        (t, ln, k, v), _ = jax.lax.scan(one, (t, ln, k, v), None, length=4)
+        return t, k, v
+
+    def prefill_chunk(p, t, pos, k, v, wi, ri, rp, rv, li):
+        return llama.forward(p, cfg, t, pos, k, v, wi, ri, rp, rv,
+                             attn_impl="flash", mesh=mesh, logits_idx=li,
+                             read_pages=ri[:, ::PAGE] // PAGE,
+                             write_pages=wi[:, ::PAGE] // PAGE)
+
+    if program == "decode_scan":
+        fn, donate = decode_scan, (2, 3)
+        args = (params, _sds(v5e, (B,), i32), pool, pool,
+                _sds(v5e, (B, P), i32), _sds(v5e, (B,), i32))
+    else:
+        fn, donate = prefill_chunk, (3, 4)
+        args = (params, _sds(v5e, (1, C), i32), _sds(v5e, (1, C), i32),
+                pool, pool, _sds(v5e, (1, C), i32), _sds(v5e, (1, S), i32),
+                _sds(v5e, (1, S), i32), _sds(v5e, (1, S), jnp.bool_),
+                _sds(v5e, (1,), i32))
+    txt = jax.jit(fn, donate_argnums=donate).lower(*args).compile().as_text()
+    found = _weight_copies(
+        txt, [published["layers"][w].shape for w in llama.ATTN_IN])
+    if tree == "stored":
+        assert not found, found
+    else:
+        assert any(" copy(" in ln and "bf16[4,4096,32,128]" in ln
+                   for ln in found), found
+
+
 def test_the_decode_steps_rows_are_written_by_the_kernel(v5e, monkeypatch):
     """qwen2-1.5b's decode step at 32 lanes, all 28 layers: with the write
     in the paged kernel the program holds no scatter into the pools
