@@ -15,6 +15,7 @@ import argparse
 
 from ..utils.dynconfig import EnvDefaultsParser
 import asyncio
+import gc
 import json
 import statistics
 import sys
@@ -149,6 +150,17 @@ async def run_http(args, card, chat_engine, completion_engine) -> None:
     manager.add(ServedModel(card, chat_engine, completion_engine))
     svc = HttpService(manager, host=args.http_host, port=args.http_port)
     port = await svc.start()
+    # What the process holds now lives as long as it serves: the bucket
+    # programs, their jaxprs and tracing caches, the routes, hundreds of
+    # thousands of objects. A full collection walks all of them with every
+    # thread stopped (0.1 s with nine programs on a desktop CPU, PR 51),
+    # and the first few come early in a server's life, when the survivors
+    # of the first requests are a quarter of what is there: an open-loop
+    # cell of 32 requests read its tpot_p90_ms 3 % apart depending on
+    # whether one fell into the one request that decides it. Out of the
+    # collector's sight with them; what requests leave is collected as ever
+    gc.collect()
+    gc.freeze()
     print(f"dynamo_tpu http frontend listening on :{port} "
           f"(model={card.name}, out={args.output})", flush=True)
     try:

@@ -393,7 +393,8 @@ class EngineCore:
                           or PagedConfig.resolve(cfg) is not None
                           else "out_in")
         if self.attn_proj == "out_in":
-            self.params = llama.stored_params(self.params, donate=True)
+            self.params = llama.stored_params(self.params, donate=True,
+                                              cfg=m)
 
         # --- vision tower (Gemma3 VLM) --------------------------------
         # replicated params (the tower is tiny next to the LM; sharding it
@@ -1134,13 +1135,17 @@ class EngineCore:
         if self.win is not None:
             self.win.ensure(seq_id, total_tokens)
 
-    def _window_fetched(self, seq_id: str, position: int) -> None:
-        """A dispatch of the sequence whose first query stood at
-        ``position`` has been fetched: the window pages wholly behind its
-        window go back (cache.WindowPages.release_behind)."""
+    def _window_fetched(self, seq_id: str, position: int,
+                        phase: str) -> None:
+        """A dispatch (``phase``: prefill / decode) of the sequence whose
+        first query stood at ``position`` has been fetched: the window pages
+        wholly behind its window go back (cache.WindowPages.release_behind).
+        A prompt longer than the window gives pages back while it is still
+        being prefilled: a lane holds a window and the chunks in flight
+        (three at most: ``_can_admit``), whatever the prompt's length."""
         n = self.win.release_behind(seq_id, position)
         if n:
-            self.stage.kv_window_pages_released.inc(amount=float(n))
+            self.stage.kv_window_pages_released.inc(phase, amount=float(n))
 
     @staticmethod
     def _latent_key_blocks(q_pos: np.ndarray, k_pos: np.ndarray,
@@ -1198,6 +1203,9 @@ class EngineCore:
                 work[self.stage.moe_routed_assignments] = routed
             if zero is not None:
                 work[self.stage.moe_zero_assignments] = float(zero)
+            if m.shared_experts:
+                work[self.stage.moe_shared_rows] = float(
+                    tokens * m.routed_layers)
             if hit is not None:
                 work[self.stage.moe_experts_hit] = float(hit)
                 # the calls those experts were hit in: every routed layer,
@@ -2838,7 +2846,7 @@ class EngineCore:
                   if "zero" in cols else None))
         if self.win is not None:
             for _, slot, start, _, _ in work:
-                self._window_fetched(slot.seq_id, start)
+                self._window_fetched(slot.seq_id, start, "prefill")
         now = time.monotonic()
         if not rec["compiled"]:
             from ..utils.roofline import prefill_cost
@@ -3373,7 +3381,7 @@ class EngineCore:
         if self.win is not None:
             steps = self.stage.kv_resident_token_steps
             for (_, slot, _), s0 in zip(rec["active"], rec["lengths"]):
-                self._window_fetched(slot.seq_id, s0 - 1)
+                self._window_fetched(slot.seq_id, s0 - 1, "decode")
                 steps.inc("global", amount=s0 + N - 1)
                 steps.inc("window", amount=self.win.tokens_held(
                     slot.seq_id, s0 + N - 1))

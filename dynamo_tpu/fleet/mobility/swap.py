@@ -116,7 +116,8 @@ def hot_swap(core, host_params, new_cfg, group_layers: Optional[int] = None
         # has its q / k / v projections a matrix a layer (views here)
         from ...models import llama
 
-        host_params = llama.stored_params(host_params)
+        host_params = llama.stored_params(host_params,
+                                           cfg=core.cfg.model)
     new_flat = _flat(host_params)
     old_flat = _flat(core.params)
     if set(new_flat) != set(old_flat):

@@ -23,6 +23,7 @@ behind the reference's engine adapters (SURVEY §2.1, §7 step 3).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import os
@@ -279,6 +280,27 @@ class LlamaConfig:
     # rotary key
     latent_q_scale: Optional[float] = None
     latent_kv_scale: Optional[float] = None
+    # A PARALLEL block (the Cohere2 family): a layer has ONE norm; attention
+    # and the feed-forward both read the stream normed by it and land on the
+    # stream together, x + attention + feed-forward. No ``ln2`` exists
+    # (:func:`layer_in` hands the normed stream on, :func:`_ffn_block`
+    # ``par``).
+    parallel_block: bool = False
+    # every norm of the stream is a bias-free LayerNorm: (x - mean) x
+    # rsqrt(var + eps) x w, in float32 like :func:`rms_norm` (:func:`_normed`)
+    layer_norm: bool = False
+    # rotary BY KIND: a per-kind model's full layers take q and k as
+    # projected, its window layers rotate (``rope_theta``)
+    nope_full: bool = False
+    # rotary pairs dims (2i, 2i + 1) of a head (GPT-J's pairing,
+    # ``position_embedding_type rope_gptj``), not (i, i + Dh / 2). An engine
+    # stores ``wq`` / ``wk`` with a head's columns de-interleaved
+    # (:func:`stored_params`), so that rotate-half gives the same scores and
+    # a step pays nothing for the pairing
+    rope_interleaved: bool = False
+    # the ``shared_experts`` are AVERAGED: the mean of their outputs is added
+    # to the routed sum (``shared_expert_combination_strategy average``)
+    shared_average: bool = False
 
     @property
     def per_kind(self) -> bool:
@@ -345,12 +367,13 @@ class LlamaConfig:
         has the readings). Any routed model would gain so; the two older
         ones keep their programs until a PR judges the change on their
         cells; a model with a routed branch across sublayers
-        (``shortcut_moe``) came with it. Norms, projections and kernels see
+        (``shortcut_moe``) and one with a parallel block (``parallel_block``)
+        came with it. Norms, projections and kernels see
         the normed activations in
         the model's dtype as before; only the adds and the norms' inputs are
         wider."""
         wide = (self.residual_multiplier is not None or self.router_groups
-                or self.shortcut_moe)
+                or self.shortcut_moe or self.parallel_block)
         return jnp.float32 if wide else self.dtype
 
     @property
@@ -473,6 +496,7 @@ class LlamaConfig:
             cfg = {**cfg, "intermediate_size": cfg["shared_intermediate_size"]}
         conv, cfg = _map_shortconv(cfg)
         shortcut, cfg = _map_shortcut(cfg)
+        parblock, cfg = _map_parblock(cfg)
         latent = _map_latent(cfg)
         rs = cfg.get("rope_scaling") or {}
         if latent:
@@ -526,18 +550,19 @@ class LlamaConfig:
                                  if _is_gemma2(cfg) else None),
             sliding_window=(cfg.get("sliding_window")
                             if _is_gemma2(cfg) or _is_gemma3(cfg)
-                            or "hybrid_layer_pattern" in cfg else None),
+                            or "layer_kinds" in kinds else None),
             query_pre_attn_scalar=(cfg.get("query_pre_attn_scalar")
                                    if _is_gemma2(cfg) or _is_gemma3(cfg)
                                    else None),
-            sliding_pattern=2 if hybrid else _sliding_pattern(cfg),
+            sliding_pattern=(2 if hybrid or "layer_kinds" in kinds
+                             else _sliding_pattern(cfg)),
             rope_local_theta=(cfg.get("rope_local_base_freq", 10000.0)
                               if _is_gemma3(cfg)
                               else cfg.get("swa_rope_theta")),
             qk_norm=(_is_gemma3(cfg) or _is_qwen3_family(cfg)
                      or bool(conv)),
             dtype=dtype,
-            **{**experts, **conv, **shortcut},
+            **{**experts, **conv, **shortcut, **parblock},
             **_map_indexer(cfg),
             **kinds,
             **_map_multipliers(cfg),
@@ -627,6 +652,10 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
            cfg.get("topk_method", "greedy"))
     routers = {("softmax", "greedy"): "softmax",
                ("sigmoid", "noaux_tc"): "sigmoid_bias",
+               # (no family publishes this pair: _map_parblock's spelling of
+               # the Cohere2 family's router, sigmoid scores, the k best
+               # among all, no selection bias)
+               ("sigmoid", "among_all"): "sigmoid",
                ("softmax", "group_limited_greedy"): "softmax_group",
                # (no family publishes this pair: _map_shortcut's spelling
                # of the LongCat-Flash router)
@@ -658,10 +687,13 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
                          f"{cfg['decoder_sparse_step']} is not implemented: "
                          f"every layer is a routed-expert layer")
     shared = cfg.get("n_shared_experts") or 0
-    if shared and not (grouped and cfg.get("moe_intermediate_size")):
+    if shared and not (cfg.get("moe_intermediate_size") and (
+            grouped or routers[law] == "sigmoid")):
         raise ValueError(f"n_shared_experts {shared} is implemented beside "
-                         f"group-limited routed experts of a width of their "
-                         f"own (moe_intermediate_size) alone")
+                         f"routed experts of a width of their own "
+                         f"(moe_intermediate_size) that are group-limited "
+                         f"(softmax) or chosen among all by sigmoid scores "
+                         f"without a bias")
     shard = cfg.get("expert_shard")
     R = int(shard.get("router_experts", E)) if shard else int(E)
     out = {"num_experts": int(E),
@@ -689,6 +721,8 @@ def _map_experts(cfg: Dict[str, Any]) -> Dict[str, Any]:
                 raise ValueError(f"{k} {cfg[k]} is not implemented with "
                                  f"topk_method {law[1]!r}: the experts are "
                                  f"chosen among all, not by group")
+        if shared:
+            out["shared_experts"] = int(shared)
         if routers[law] == "softmax_bias":
             out["routed_scaling"] = float(cfg.get("routed_scaling_factor", 1))
         elif cfg.get("routed_scaling_factor") not in (None, 1, 1.0):
@@ -772,6 +806,17 @@ def _map_layer_kinds(cfg: Dict[str, Any]) -> Dict[str, Any]:
     if cfg.get("attention_value_scale") not in (None, 1, 1.0):
         out["attn_value_scale"] = float(cfg["attention_value_scale"])
     pattern = cfg.get("hybrid_layer_pattern")
+    lt = cfg.get("layer_types") or ()
+    if (pattern is None and not _is_gemma(cfg)
+            and {"sliding_attention", "full_attention"} <= set(lt)):
+        # the same thing in the newer spelling: a model (not Gemma, whose
+        # window layers keep the whole context under a mask) that names
+        # window and full layers one by one keeps two caches
+        bad = sorted(set(lt) - {"sliding_attention", "full_attention"})
+        if bad:
+            raise ValueError(f"layer_types names {bad} beside "
+                             f"sliding_attention / full_attention")
+        pattern = [int(t == "sliding_attention") for t in lt]
     if pattern is None:
         stray = [k for k in _LAYER_KIND_KEYS if cfg.get(k)]
         if stray and not (_is_gemma(cfg) or cfg.get("layer_types")):
@@ -783,8 +828,9 @@ def _map_layer_kinds(cfg: Dict[str, Any]) -> Dict[str, Any]:
     if (not isinstance(pattern, (list, tuple)) or len(pattern) < L
             or any(p not in (0, 1) for p in pattern)):
         raise ValueError(f"hybrid_layer_pattern must list 0 (full) or 1 "
-                         f"(window) for each of the {L} layers, got "
-                         f"{pattern!r}")
+                         f"(window) (layer_types: full_attention or "
+                         f"sliding_attention) for each of the {L} layers, "
+                         f"got {pattern!r}")
     kinds = tuple(int(p) for p in pattern[:L])
     if not 0 < sum(kinds) < L:
         raise ValueError("hybrid_layer_pattern needs layers of both kinds "
@@ -901,6 +947,25 @@ _SHORTCONV_KEYS = ("conv_L_cache", "conv_bias", "num_dense_layers",
                    "use_expert_bias", "norm_eps", "rope_parameters")
 
 
+def _plain_rope_theta(cfg: Dict[str, Any]):
+    """The ONE ``rope_theta`` of a config that may give it flat, under
+    ``rope_parameters`` or both; anything but plain rotary RAISES."""
+    rp = cfg.get("rope_parameters")
+    if rp is not None:
+        unknown = sorted(set(rp) - {"rope_theta", "rope_type"})
+        if unknown or rp.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_parameters {rp!r}: plain rotary "
+                             f"(rope_type default, rope_theta) is what is "
+                             f"implemented")
+    thetas = {(rp or {}).get("rope_theta"), cfg.get("rope_theta")} - {None}
+    if len(thetas) > 1:
+        raise ValueError("rope_theta is given twice and differs")
+    if not thetas:
+        raise ValueError("no rope_theta (flat or under rope_parameters)")
+    theta, = thetas
+    return theta
+
+
 def _map_shortconv(cfg: Dict[str, Any]):
     """``model_type lfm2_moe`` (LFM2: gated short-convolution layers beside
     GQA layers with per-head q / k norms, under bias-selected sigmoid-routed
@@ -936,19 +1001,7 @@ def _map_shortconv(cfg: Dict[str, Any]):
     if not cfg.get("norm_topk_prob", False):
         raise ValueError("norm_topk_prob false is not implemented: the "
                          "chosen scores are divided by their sum + 1e-6")
-    rp = cfg.get("rope_parameters")
-    if rp is not None:
-        unknown = sorted(set(rp) - {"rope_theta", "rope_type"})
-        if unknown or rp.get("rope_type", "default") != "default":
-            raise ValueError(f"rope_parameters {rp!r}: plain rotary "
-                             f"(rope_type default, rope_theta) is what is "
-                             f"implemented")
-    thetas = {(rp or {}).get("rope_theta"), cfg.get("rope_theta")} - {None}
-    if len(thetas) > 1:
-        raise ValueError("rope_theta is given twice and differs")
-    if not thetas:
-        raise ValueError("no rope_theta (flat or under rope_parameters)")
-    theta, = thetas
+    theta = _plain_rope_theta(cfg)
     nd = int(cfg.get("num_dense_layers", 0))
     if not 0 <= nd < L:
         raise ValueError(f"num_dense_layers {nd}: no routed layer among {L}")
@@ -1071,6 +1124,117 @@ def _map_shortcut(cfg: Dict[str, Any]):
         # gates x routed_scaling_factor, not renormalised (route_topk)
         scoring_func="softmax", topk_method="bias", norm_topk_prob=False,
         tie_word_embeddings=bool(cfg.get("tie_word_embeddings", False)))
+    return ours, rest
+
+
+# the keys by which the Cohere2-MoE family (``model_type cohere2_moe``)
+# spells what no other family has or spells otherwise; each is mapped or
+# refused by name in :func:`_map_parblock`
+_PARBLOCK_KEYS = ("use_parallel_block", "shared_expert_combination_strategy",
+                  "expert_selection_fn", "num_shared_experts", "layer_switch",
+                  "order_of_interleaved_layers",
+                  "prefix_dense_intermediate_size",
+                  "prefix_dense_sliding_window_pattern", "rotary_pct",
+                  "logit_scale", "use_gated_activation",
+                  "use_embedding_sharing", "use_parallel_embedding",
+                  "use_qk_norm", "layer_norm_eps", "tf_legacy_loss",
+                  "position_embedding_type", "rope_parameters",
+                  "first_k_dense_replace")
+
+
+def _map_parblock(cfg: Dict[str, Any]):
+    """The Cohere2-MoE family (Command A+: a PARALLEL block on one bias-free
+    LayerNorm, interleaved rotary in the window layers and none in the full
+    ones, sigmoid-routed experts chosen among all beside shared experts
+    that are averaged) -> (ours, the config with the family's keys re-spelt
+    as the keys the other mappers read). Every key of the family is mapped
+    or RAISES; under another model type the family's own keys raise (they
+    mean what its modelling code says, nowhere else)."""
+    own = ("use_parallel_block", "shared_expert_combination_strategy",
+           "expert_selection_fn", "layer_switch",
+           "order_of_interleaved_layers")
+    if cfg.get("model_type") != "cohere2_moe":
+        stray = [k for k in own if k in cfg]
+        if stray:
+            raise ValueError(
+                f"config carries {stray} without model_type 'cohere2_moe': "
+                f"refusing to guess what they mean")
+        return {}, cfg
+
+    def refuse(key, why):
+        raise ValueError(f"{key} {cfg.get(key)!r} is not implemented for a "
+                         f"cohere2_moe config: {why}")
+
+    if not cfg.get("use_parallel_block", False):
+        refuse("use_parallel_block", "the family's layer has ONE norm, which "
+               "attention and feed-forward both read; a sequential block "
+               "would need a second norm the family does not have")
+    if cfg.get("shared_expert_combination_strategy", "average") != "average":
+        refuse("shared_expert_combination_strategy",
+               "the shared experts' outputs are averaged")
+    if cfg.get("expert_selection_fn", "sigmoid") != "sigmoid":
+        refuse("expert_selection_fn", "the router scores by sigmoid")
+    if cfg.get("first_k_dense_replace"):
+        refuse("first_k_dense_replace", "leading dense layers "
+               "(prefix_dense_*) are not implemented: every layer is routed")
+    if cfg.get("use_qk_norm", False):
+        refuse("use_qk_norm", "q and k go to rotary as projected")
+    if cfg.get("position_embedding_type", "rope_gptj") != "rope_gptj":
+        refuse("position_embedding_type", "interleaved rotary (rope_gptj) in "
+               "the window layers, none in the full ones")
+    if cfg.get("rotary_pct", 1) != 1:
+        refuse("rotary_pct", "all of a head's dims rotate")
+    if cfg.get("rope_scaling"):
+        refuse("rope_scaling", "plain rotary (rope_theta)")
+    if not cfg.get("use_gated_activation", True):
+        refuse("use_gated_activation", "every expert is a gated (SwiGLU) "
+               "feed-forward")
+    if cfg.get("use_parallel_embedding", False):
+        refuse("use_parallel_embedding", "one embedding table, read by token")
+    tied = {bool(cfg[k]) for k in ("use_embedding_sharing",
+                                   "tie_word_embeddings") if k in cfg}
+    if tied != {True}:
+        refuse("use_embedding_sharing", "the head is the embedding "
+               "(use_embedding_sharing / tie_word_embeddings true, and "
+               "agreeing)")
+    if cfg.get("rms_norm_eps") is not None or "layer_norm_eps" not in cfg:
+        refuse("layer_norm_eps", "the family's norm is a LayerNorm "
+               "(layer_norm_eps given, rms_norm_eps null)")
+    theta = _plain_rope_theta(cfg)
+    L = cfg["num_hidden_layers"]
+    lt = list(cfg.get("layer_types") or ())[:L]
+    n = cfg.get("layer_switch")
+    if n is not None:
+        first = cfg.get("order_of_interleaved_layers", "local_attn_first")
+        if first != "local_attn_first":
+            refuse("order_of_interleaved_layers", "window layers come first "
+                   "in a period")
+        want = ["full_attention" if (l + 1) % int(n) == 0
+                else "sliding_attention" for l in range(L)]
+        if lt and lt != want:
+            refuse("layer_switch", f"layer_types says otherwise ({lt})")
+        lt = want
+    if len(lt) < L or not cfg.get("sliding_window"):
+        refuse("layer_types", f"a kind for each of the {L} layers (or "
+               f"layer_switch) and a sliding_window")
+    if not cfg.get("num_shared_experts"):
+        refuse("num_shared_experts", "shared experts beside the routed ones")
+    scale = cfg.get("logit_scale", 1)
+    ours = {"parallel_block": True, "layer_norm": True, "nope_full": True,
+            "rope_interleaved": True, "shared_average": True}
+    rest = {k: v for k, v in cfg.items() if k not in _PARBLOCK_KEYS
+            and k not in ("rms_norm_eps", "use_embedding_sharing")}
+    rest.update(
+        layer_types=lt, rope_theta=theta,
+        rms_norm_eps=cfg["layer_norm_eps"], tie_word_embeddings=True,
+        # the experts are ``intermediate_size`` wide, the shared ones too
+        moe_intermediate_size=cfg["intermediate_size"],
+        n_shared_experts=cfg["num_shared_experts"],
+        # sigmoid scores, the k best among all, gates renormalised
+        scoring_func="sigmoid", topk_method="among_all")
+    if scale != 1:
+        # logits x logit_scale (ours divides)
+        rest["logits_scaling"] = 1.0 / float(scale)
     return ours, rest
 
 
@@ -1553,6 +1717,44 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     # own damping of both residual adds where it has one
     res = (1.0 / math.sqrt(2 * L) if cfg.residual_multiplier is None
            else 1.0)
+    # A parallel block under a TIED head (PR 51, read on the chip): greedy
+    # decoding of seeded weights is a map from a token to the next (attention
+    # over thousands of keys of score spread 1 is a plain average and adds
+    # next to nothing), the token's own embedding in the stream gives its own
+    # logit a head start of sqrt(D) |row|^2 / |stream| sigma (2.8 with rows of
+    # norm 1 and damped branches), so every sample fell into a token that
+    # predicts itself within a step or two and its 64 scored positions were
+    # ONE token in one state; a near-tie of that token's routing then flips
+    # the same way at all 64 (one run in five read 0.12 sigma rms where the
+    # others read 0.02 and int8 weights 0.05). So: branches undamped (the
+    # stream is theirs, of unit rms after four layers) and embedding rows of
+    # norm 1/2, which leaves the head start at half a sigma; and the routed
+    # experts' down-projection a quarter of the shared ones', so that ONE
+    # assignment of a held expert, which is all a token gives this chip, is
+    # a fortieth of the stream and not an eighth
+    # What the first check of the cell taught (PR 51, tpot_p90_ms spread
+    # 3.7 % over seeds of equal arrivals and sizes): an attention branch
+    # hands on the part of the stream that ALL positions share with its
+    # full gain (its weights sum to 1 whatever the scores are), while what
+    # is a token's own comes out of the feed-forward branch at a third of
+    # the stream's size. At an output projection of gain 1 under scores of
+    # spread 1 that shared part grew tenfold a layer (0.8 % / 5 % / 21 % /
+    # 46 % of the stream's energy behind the four layers; a CPU reading at
+    # a hidden size of 512, window 1,024): the head then favours a few
+    # tokens whatever the last one was, greedy decoding locks into one of
+    # them within a few steps, the deeper layers' routers lean one way for
+    # a whole seed (0.66 held assignments a token in the fourth layer where
+    # 1 is even), and how many held experts a decode step reads, so the
+    # step's time, is a draw of the seed. So: the attention's output
+    # projection at a THIRD of the feed-forward's (the shared part then
+    # stays under 1 % of the stream) and scores of spread 3 (a query reads
+    # a handful of keys, not their average: the branch is a sixth of what a
+    # layer adds and shows in the logits). 200 decoded tokens are then
+    # 197-200 different ones and every layer's router is even (0.92-1.09)
+    routed_res, attn_res, qk_par = res, res, 1.0
+    if cfg.parallel_block:
+        res, routed_res, attn_res = 1.0, 0.25, 1.0 / 3.0
+        qk_par = math.sqrt(3.0)     # on wq AND wk: scores of spread 3
     stacks: Dict[str, Any] = {}
     if cfg.has_latent:
         stacks["full"] = _init_latent(cfg, ks, mat, res)
@@ -1560,7 +1762,7 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         n, Hkv = len(cfg.kind_layers(window)), cfg.kv_heads_of(window)
         if not n or cfg.has_latent:
             continue
-        qk = 1.0
+        qk = qk_par
         if cfg.attn_multiplier is not None:
             # scores of spread 1.5 under the model's OWN scale, as
             # init_params' second law sets it and for its reasons: at N(0,
@@ -1570,11 +1772,15 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             # logits (rel_rms 0.0146 / 0.0138 beside a sound 0.0123; my
             # chip run, PR 36, call B)
             qk = math.sqrt(1.5 / (math.sqrt(Dh) * cfg.attn_scale))
-        st = {"ln1": jnp.ones((n, D), jnp.float32),
+        # (the ONE norm of a parallel block: weights U(0.6, 1.4), so that
+        # a feed-forward that norms its input a second time shows)
+        st = {"ln1": (jax.random.uniform(next(ks), (n, D), jnp.float32,
+                                         0.6, 1.4) if cfg.parallel_block
+                      else jnp.ones((n, D), jnp.float32)),
               "wq": mat(n, D, D, Hq, Dh, scale=qk),
               "wk": mat(n, D, D, Hkv, Dh, scale=qk),
               "wv": mat(n, D, D, Hkv, Dv),
-              "wo": mat(n, Hq * Dv, Hq, Dv, D, scale=res)}
+              "wo": mat(n, Hq * Dv, Hq, Dv, D, scale=attn_res)}
         if cfg.sink_window if window else cfg.sink_full:
             st["sink"] = 4.0 + jax.random.normal(next(ks), (n, Hq),
                                                  jnp.float32)
@@ -1599,10 +1805,12 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
         # 6144 x 12288, which fit while the experts are not there yet)
         stacks["routed"] = _init_branch(cfg, ks, mat, experts)
     if nr:
-        st = {"ln2": jnp.ones((nr, D), jnp.float32),
-              "wr": mat(nr, D, D, R),
-              "wg": experts(nr, D, D, Fe), "wu": experts(nr, D, D, Fe),
-              "wd": experts(nr, Fe, Fe, D, scale=res)}
+        # (a parallel block has ONE norm, the attention stack's ``ln1``)
+        st = {} if cfg.parallel_block else {
+            "ln2": jnp.ones((nr, D), jnp.float32)}
+        st.update(wr=mat(nr, D, D, R),
+                  wg=experts(nr, D, D, Fe), wu=experts(nr, D, D, Fe),
+                  wd=experts(nr, Fe, Fe, D, scale=routed_res))
         if cfg.router == "sigmoid_bias":
             st["rbias"] = 0.02 * jax.random.normal(next(ks), (nr, R),
                                                    jnp.float32)
@@ -1611,9 +1819,13 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
             # routed ones: its output is of the size of ONE routed expert's
             # (and of the attention's), beside a routed sum of 6 gates of a
             # few tenths x 16 each, of which this chip computes its share
+            # Shared experts that are AVERAGED: each by the routed ones' law
+            # (fan-in Fe), so that their mean is half one expert's size and
+            # their sum, the reading this model does not have, four times it
             Fs = cfg.shared_experts * Fe
             st.update(ws_g=mat(nr, D, D, Fs), ws_u=mat(nr, D, D, Fs),
-                      ws_d=mat(nr, Fs, Fs, D, scale=res))
+                      ws_d=mat(nr, Fe if cfg.shared_average else Fs, Fs, D,
+                               scale=res))
         stacks["routed"] = st
     if cfg.has_conv:
         stacks["conv"] = _init_conv(cfg, ks, mat, res)
@@ -1628,8 +1840,14 @@ def _init_per_kind(cfg: LlamaConfig, key: jax.Array) -> Dict[str, Any]:
     # and is the layers' outputs from the first layer on
     small = math.sqrt(D) if (cfg.tie_embeddings and cfg.embed_multiplier
                              is None and cfg.logits_scaling is None) else 1.0
-    params = {"embed": (jax.random.normal(next(ks), (V, D), jnp.float32)
-                        / ((cfg.embed_multiplier or 1.0) * small)
+    if cfg.parallel_block:
+        small *= 2.0            # rows of norm 1/2 (the law's text above)
+    # a model of mean-centred norms: every row carries a MEAN of half its
+    # spread, which a LayerNorm removes and an RMSNorm would hand to every
+    # matrix (the tied head sees none of it: its input is centred)
+    mean = 0.5 if cfg.layer_norm else 0.0
+    params = {"embed": ((jax.random.normal(next(ks), (V, D), jnp.float32)
+                         + mean) / ((cfg.embed_multiplier or 1.0) * small)
                         ).astype(cfg.dtype),
               STACKS: stacks,
               "final_norm": jnp.ones((D,), jnp.float32)}
@@ -1803,7 +2021,7 @@ def param_specs(cfg: LlamaConfig, tp_size: int = 1,
         # one chip (validate_tp): every tensor replicated, whatever its rank
         init = partial(_init_per_kind, cfg)
         shapes = jax.eval_shape(
-            (lambda k: stored_params(init(k))) if stored else init,
+            (lambda k: stored_params(init(k), cfg=cfg)) if stored else init,
             jax.random.PRNGKey(0))
         return jax.tree.map(lambda a: P(*(None,) * a.ndim), shapes)
     st = AXIS_PP if pp > 1 else None     # the [L, ...] stack dim
@@ -1885,8 +2103,14 @@ ATTN_IN = ("wq", "wk", "wv")
 CUT_OUT_BYTES = 64 << 20
 
 
-def stored_params(params: Dict[str, Any], donate: bool = False
-                  ) -> Dict[str, Any]:
+# a stored window stack's key that says its ``wq`` / ``wk`` columns are
+# de-interleaved (a leaf of no elements: a tree's STRUCTURE says it, so a
+# program that is handed the published tree still rotates as published)
+ROPE_HALVES = "rope_halves"
+
+
+def stored_params(params: Dict[str, Any], donate: bool = False,
+                  cfg: Optional[LlamaConfig] = None) -> Dict[str, Any]:
     """The tree an engine hands its bucket programs, from the published one
     (what :func:`init_params` returns and a loader builds): the same values,
     ``wq`` / ``wk`` / ``wv`` (:data:`ATTN_IN`) of every attention stack a
@@ -1910,10 +2134,21 @@ def stored_params(params: Dict[str, Any], donate: bool = False
     stack by a traced layer (a pipeline stage's ``shard_map``, the pager's
     one program a layer class) keeps the published tree.
 
+    ``cfg`` of a model whose rotary pairs dims (2i, 2i + 1)
+    (``rope_interleaved``): ``wq`` / ``wk`` of the stacks that rotate come
+    with each head's columns de-interleaved (:func:`deinterleaved`; the
+    stack then carries the key :data:`ROPE_HALVES`), so that the programs
+    rotate halves like every other model's and the pairing costs a step
+    nothing. The cache then holds K rows in that column order: the same
+    scores, and nothing else reads a K row.
+
     The leaves may be device arrays (cut on the device a leaf at a time, as
     sharded as the source: ``donate`` deletes each source once its program
     is under way, so the peak is the tree and ONE leaf), host arrays (views)
     or tracers; a tree that is stored already comes back as it is."""
+    if cfg is not None and cfg.rope_interleaved and cfg.use_rope:
+        params = _rope_halves(params, cfg, donate)
+
     def relaid(_, w):
         if isinstance(w, tuple) or not _cut_out(w.shape, w.dtype):
             return w
@@ -1933,6 +2168,32 @@ def stored_params(params: Dict[str, Any], donate: bool = False
         return out
 
     return map_attn_in(relaid, params)
+
+
+def _rope_halves(params: Dict[str, Any], cfg: LlamaConfig,
+                 donate: bool) -> Dict[str, Any]:
+    """``params`` with ``wq`` / ``wk`` of every stack that rotates
+    de-interleaved and marked (:func:`stored_params`); a stack that is
+    marked already, or does not rotate (``nope_full``), as it is."""
+    def turned(w):
+        if not isinstance(w, jax.Array) or isinstance(w, jax.core.Tracer):
+            return deinterleaved(w)
+        out = jax.jit(deinterleaved, out_shardings=w.sharding)(w)
+        if donate:
+            w.delete()
+        return out
+
+    def stack(kind, st):
+        if ROPE_HALVES in st or "wq" not in st or (
+                cfg.nope_full and kind != "window"):
+            return st
+        return {**st, "wq": turned(st["wq"]), "wk": turned(st["wk"]),
+                ROPE_HALVES: jnp.zeros((0,), jnp.float32)}
+
+    if STACKS in params:
+        return {**params, STACKS: {kind: stack(kind, st) for kind, st
+                                   in params[STACKS].items()}}
+    return {**params, "layers": stack("layers", params["layers"])}
 
 
 def _cut_out(stack_shape, dtype) -> bool:
@@ -2080,6 +2341,16 @@ def scope(name: str):
     return jax.named_scope(SCOPES[SCOPES.index("dynamo." + name)])
 
 
+def _inner(name: str, on: bool = True):
+    """A name INSIDE one of :data:`SCOPES` (no ``dynamo.`` in front, so the
+    benchmark's reader still files the operation under the scope around
+    it): what a trace's viewer tells apart within one scope, the one norm
+    of a parallel block (``block_norm``) and its shared experts
+    (``moe_shared``). Off: nothing, and an older model's programs carry the
+    names they carried."""
+    return jax.named_scope(name) if on else contextlib.nullcontext()
+
+
 def _attn_scope(cfg: LlamaConfig, window: bool):
     """The scope of a layer's attention itself: by kind for a per-kind model
     (``attn_window`` / ``attn_full``: the per-layer roofline metrics read the
@@ -2101,11 +2372,22 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float,
     return (xf * scale * wf).astype(x.dtype)
 
 
+def layer_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """Bias-free LayerNorm: (x - mean) x rsqrt(var + eps) x w, in float32."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    scale = jax.lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
+    return (xc * scale * w.astype(jnp.float32)).astype(x.dtype)
+
+
 def _normed(x: jax.Array, w: jax.Array, cfg: "LlamaConfig") -> jax.Array:
-    """RMSNorm of the stream ``x`` as the layer's matrices take it: as it
-    comes for every model whose stream is in the model's dtype, in the
-    model's dtype where the stream is wider (``LlamaConfig.stream_dtype``)."""
-    h = rms_norm(x, w, cfg.rms_eps, cfg.norm_offset)
+    """The model's norm of the stream ``x`` (RMSNorm, or the mean-centred
+    LayerNorm of a model that says so: ``cfg.layer_norm``) as the layer's
+    matrices take it: as it comes for every model whose stream is in the
+    model's dtype, in the model's dtype where the stream is wider
+    (``LlamaConfig.stream_dtype``)."""
+    h = (layer_norm(x, w, cfg.rms_eps) if cfg.layer_norm
+         else rms_norm(x, w, cfg.rms_eps, cfg.norm_offset))
     return h if cfg.stream_dtype == cfg.dtype else h.astype(cfg.dtype)
 
 
@@ -2202,17 +2484,40 @@ def rope_pair(cfg: LlamaConfig, positions: jax.Array):
         if cfg.rope_local_theta is not None else None)
 
 
-def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array,
+               interleaved: bool = False) -> jax.Array:
     """x: [..., H, Dh]; cos/sin: [..., Dr/2] (broadcast over H). Tables
-    narrower than the head rotate its first Dr dims and pass the rest."""
+    narrower than the head rotate its first Dr dims and pass the rest.
+    ``interleaved``: dims (2i, 2i + 1) are a pair (GPT-J's pairing), not (i,
+    i + Dr / 2): what a model of that pairing costs on weights as published
+    (:func:`deinterleaved` columns make it the plain form)."""
     Dr = 2 * cos.shape[-1]
     if Dr < x.shape[-1]:
         return jnp.concatenate(
-            [apply_rope(x[..., :Dr], cos, sin), x[..., Dr:]], axis=-1)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+            [apply_rope(x[..., :Dr], cos, sin, interleaved), x[..., Dr:]],
+            axis=-1)
+    # (the order of these lines is the order of the traced operations: an
+    # older model's program text is held to what it was, lowered_same.py)
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], Dr // 2, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     c = cos[..., None, :]
     s = sin[..., None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+    turned = [x1 * c - x2 * s, x2 * c + x1 * s]
+    if interleaved:
+        return jnp.stack(turned, axis=-1).reshape(x.shape).astype(x.dtype)
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
+
+
+def deinterleaved(w: jax.Array) -> jax.Array:
+    """A q or k projection [..., Dh] with each head's columns reordered (0,
+    2, 4, ..., 1, 3, 5, ...): rotate-half on what it projects pairs the
+    dims the interleaved rotary pairs on the published columns, and q . k is
+    the same sum in another order."""
+    Dh = w.shape[-1]
+    return jnp.concatenate([w[..., 0:Dh:2], w[..., 1:Dh:2]], axis=-1)
 
 
 NEG_INF = -1e30
@@ -2553,6 +2858,16 @@ def pick(sliding, if_sliding, if_full):
     return jax.tree.map(partial(jnp.where, sliding), if_sliding, if_full)
 
 
+def _rope_of(cfg: LlamaConfig, l, rope_sl, rope):
+    """The (cos, sin) layer ``l`` rotates by (:func:`pick` of the sliding
+    layers' tables and the model's), or None for a layer that takes q and k
+    as projected: a full layer of a model whose window layers alone rotate
+    (``nope_full``)."""
+    if cfg.nope_full and not cfg.layer_window(l):
+        return None
+    return pick(cfg.layer_sliding(l), rope_sl, rope)
+
+
 @scope("attn_in")
 def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
              rope: Tuple[jax.Array, jax.Array], pools: Tuple[jax.Array, ...],
@@ -2560,13 +2875,17 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
              index: Optional[Tuple[Any, ...]] = None,
              stats: Optional[Dict[str, Any]] = None,
              hold: Optional[List[jax.Array]] = None,
-             w_pages: Optional[jax.Array] = None):
+             w_pages: Optional[jax.Array] = None,
+             normed: Optional[List[jax.Array]] = None):
     """The layer before attention: input norm, the three projections (bias,
     q/k norm), rotary, and the new rows into the cache.
 
     ``lp`` holds the stacked layer parameters (the whole stack or a stage's
     slice) and ``l`` indexes them and ``pools`` = (k_pool, v_pool[, i_pool]);
-    ``rope`` is the (cos, sin) the caller chose for this layer (:func:`pick`).
+    ``rope`` is the (cos, sin) the caller chose for this layer
+    (:func:`_rope_of`; None: a layer without rotary). ``normed``: the caller
+    of a PARALLEL block passes a list, which receives the normed stream the
+    projections read (the feed-forward reads it too: :func:`layer_out`).
     The rows of ``x`` [B,T,D] go to token slots (``w_page``, ``w_off``), both
     [B*T]; ``mode`` as :func:`kv_write`'s; or, ``w_pages`` [B, P] given, to
     those pages a run each (:func:`kv_write_pages`: a prefill chunk that
@@ -2582,7 +2901,10 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     if cfg.has_latent:
         return _latent_in(x, lp, l, cfg, rope, pools, w_page, w_off, mode,
                           hold, w_pages)
-    h = _normed(x, lp["ln1"][l], cfg)
+    with _inner("block_norm", cfg.parallel_block):
+        h = _normed(x, lp["ln1"][l], cfg)
+    if normed is not None:
+        normed.append(h)
     q = _project(h, lp["wq"][l], cfg.head_dim)
     k = _project(h, lp["wk"][l], cfg.head_dim)
     v = _project(h, lp["wv"][l], cfg.v_dim)
@@ -2594,9 +2916,11 @@ def layer_in(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
         # gemma3: per-head RMSNorm on q/k AFTER projection, BEFORE rope
         q = rms_norm(q, lp["ln_q"][l], cfg.rms_eps, cfg.norm_offset)
         k = rms_norm(k, lp["ln_k"][l], cfg.rms_eps, cfg.norm_offset)
-    if cfg.use_rope:
-        q = apply_rope(q, *rope)
-        k = apply_rope(k, *rope)
+    if cfg.use_rope and rope is not None:
+        # (a stored tree's columns are de-interleaved already)
+        gptj = cfg.rope_interleaved and ROPE_HALVES not in lp
+        q = apply_rope(q, *rope, gptj)
+        k = apply_rope(k, *rope, gptj)
     if cfg.attn_value_scale:
         v = (v.astype(jnp.float32) * cfg.attn_value_scale).astype(v.dtype)
     k_pool, v_pool, *i_pool = pools
@@ -2724,11 +3048,15 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
               inside: Optional[Dict[str, int]] = None,
               ffn: Optional[Tuple[Dict[str, Any], Any]] = None,
               active: Optional[jax.Array] = None,
-              branch: Optional[Tuple[Any, List[jax.Array]]] = None
+              branch: Optional[Tuple[Any, List[jax.Array]]] = None,
+              normed: Optional[List[jax.Array]] = None
               ) -> jax.Array:
     """The layer after attention: out-projection of ``attn`` [B,T,Hq,Dh] and
     residual (Gemma2 norms the branch output first), then the feed-forward
-    (``active``, ``branch``: :func:`_ffn_block`'s).
+    (``active``, ``branch``: :func:`_ffn_block`'s). A PARALLEL block
+    (``normed``: the list :func:`layer_in` left the normed stream in) adds
+    nothing here: the feed-forward reads that same normed stream, and the
+    two branches land on ``x`` in ONE add (:func:`_ffn_block` ``par``).
 
     ``inside``: a caller that is ALREADY inside manual SPMD (``forward_pp``'s
     stage body; shard_maps do not nest) names the mesh axes it is inside of
@@ -2746,6 +3074,14 @@ def layer_out(x: jax.Array, attn: jax.Array, lp: Dict[str, Any], l,
         o = jax.lax.psum(o, AXIS_TP)
     if cfg.sandwich_norms:
         o = rms_norm(o, lp["ln1_post"][l], cfg.rms_eps, cfg.norm_offset)
+    if cfg.parallel_block:
+        if not normed:
+            raise ValueError("a parallel block's feed-forward reads the "
+                             "normed stream its attention read (layer_in "
+                             "normed=)")
+        return _ffn_block(x, *(ffn or (lp, l)), cfg, mesh=mesh, stats=stats,
+                          inside=inside, active=active,
+                          par=(normed.pop(), o))
     return _ffn_block(_residual(x, o, cfg), *(ffn or (lp, l)), cfg,
                       mesh=mesh, stats=stats, inside=inside, active=active,
                       branch=branch)
@@ -2765,7 +3101,8 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
                mesh=None, stats: Optional[Dict[str, Any]] = None,
                inside: Optional[Dict[str, int]] = None,
                active: Optional[jax.Array] = None,
-               branch: Optional[Tuple[Any, List[jax.Array]]] = None
+               branch: Optional[Tuple[Any, List[jax.Array]]] = None,
+               par: Optional[Tuple[jax.Array, jax.Array]] = None
                ) -> jax.Array:
     """Pre-norm FFN (dense or MoE) + residual; Gemma2 adds a post-norm on
     the branch output (sandwich norms). A routed layer adds its experts hit
@@ -2781,8 +3118,13 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     (``cfg.shortcut_moe``): the first of a pair computes them from the
     normed stream its dense feed-forward reads and leaves the result in the
     list; the second adds it to the stream after its own feed-forward's
-    residual."""
-    h2 = _normed(x, lp["ln2"][l], cfg)
+    residual.
+
+    ``par`` = (the layer's normed stream, its attention's output) of a
+    PARALLEL block: no norm here (the layer has one, and ``lp`` no ``ln2``),
+    the feed-forward reads what the projections read, and ``x`` takes both
+    branches in one add."""
+    h2 = _normed(x, lp["ln2"][l], cfg) if par is None else par[0]
     if branch is not None and branch[0] is not None:
         branch[1].append(_routed_ffn(h2, *branch[0], cfg, mesh, stats,
                                      active))
@@ -2809,7 +3151,7 @@ def _ffn_block(x: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
             out = jax.lax.psum(out, AXIS_TP)
     if cfg.sandwich_norms:
         out = rms_norm(out, lp["ln2_post"][l], cfg.rms_eps, cfg.norm_offset)
-    x = _residual(x, out, cfg)
+    x = _residual(x, out if par is None else par[1] + out, cfg)
     if branch is not None and branch[0] is None:
         x = _residual(x, branch[1].pop(), cfg)
     return x
@@ -2821,7 +3163,7 @@ def _routed_ffn(h2: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
     """The routed experts of ``lp`` at ``l`` on the normed stream ``h2``, by
     the model's router law (``moe.moe_ffn``); counts into ``stats`` as
     :func:`_ffn_block` says."""
-    from .moe import moe_ffn
+    from .moe import moe_ffn, shared_ffn
     share = bool(cfg.router_experts or cfg.zero_experts)
     law = {}
     if cfg.router != "softmax" or cfg.router_experts:
@@ -2835,11 +3177,20 @@ def _routed_ffn(h2: jax.Array, lp: Dict[str, Any], l, cfg: LlamaConfig,
                    scaling=cfg.routed_scaling)
     if cfg.router == "softmax_bias":
         law.update(scaling=cfg.routed_scaling, zero=cfg.zero_experts)
-    if cfg.shared_experts:
-        law["shared"] = tuple(lp[k][l] for k in ("ws_g", "ws_u", "ws_d"))
+    shared = (tuple(lp[k][l] for k in ("ws_g", "ws_u", "ws_d"))
+              if cfg.shared_experts else None)
+    if shared and not cfg.shared_average:
+        law["shared"] = shared
     out, hit, chosen = moe_ffn(h2, lp["wr"][l], lp["wg"], lp["wu"], lp["wd"],
                        cfg.experts_per_token, mesh=mesh, layer=l,
                        active=active, stats=stats, **law)
+    if shared and cfg.shared_average:
+        # outside the routed experts' scope, so that a trace tells router +
+        # routed experts (``dynamo.moe_ffn``) from the shared experts
+        # (``dynamo.ffn``, ``moe_shared`` inside it)
+        with _inner("moe_shared"):
+            out = out + shared_ffn(h2, *shared,
+                                   scale=1.0 / cfg.shared_experts)
     if stats is not None:
         if share:
             # (experts hit, assignments to held experts) of this call
@@ -3389,14 +3740,16 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
             # a window layer of a per-kind model: its own pools, slots and
             # the short context that holds the window
             x, w_pools = _window_layer(
-                x, lp, la, ffn, cfg, pick(sl, rope_sl, rope), w_pools,
+                x, lp, la, ffn, cfg, _rope_of(cfg, l, rope_sl, rope), w_pools,
                 wwp, wwo, w_pages, positions, w_pos, w_valid,
                 flash_for(l) if attn_impl == "flash" else w_mask,
                 mesh, stats, ww_pages)
             continue
-        q, pools, keep = layer_in(x, lp, la, cfg, pick(sl, rope_sl, rope),
+        h = [] if cfg.parallel_block else None
+        q, pools, keep = layer_in(x, lp, la, cfg,
+                                  _rope_of(cfg, l, rope_sl, rope),
                                   pools, wp, wo, index=index, stats=stats,
-                                  w_pages=write_pages)
+                                  w_pages=write_pages, normed=h)
         # gather this sequence's context: [B, S, Hkv, Dh]
         with scope("attn"):
             if read_pages is not None:
@@ -3430,7 +3783,7 @@ def forward(params: Dict[str, Any], cfg: LlamaConfig,
                                   pick(sl, sliding_mask, mask), **extra)
         x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
                       ffn=ffn if cfg.per_kind else None,
-                      branch=_branch_of(params, cfg, l, carried))
+                      branch=_branch_of(params, cfg, l, carried), normed=h)
 
     if logits_idx is not None:
         with scope("head"):
@@ -3446,8 +3799,9 @@ def _window_layer(x, lp, la, ffn, cfg: LlamaConfig, rope, w_pools, wwp, wwo,
     """A window layer of a per-kind model over a prefill chunk: the same
     layer body around attention over the window cache's short context.
     ``attn``: the flash kernel of this layer, or the xla path's mask."""
+    h = [] if cfg.parallel_block else None
     q, w_pools, _ = layer_in(x, lp, la, cfg, rope, w_pools, wwp, wwo,
-                             w_pages=ww_pages)
+                             w_pages=ww_pages, normed=h)
     with scope("attn"):
         k_ctx = kv_pages(w_pools[0], la, w_pages)
         v_ctx = kv_pages(w_pools[1], la, w_pages)
@@ -3457,8 +3811,8 @@ def _window_layer(x, lp, la, ffn, cfg: LlamaConfig, rope, w_pools, wwp, wwo,
             a = attn(q, k_ctx, v_ctx, q_pos, w_pos, w_valid, **sink)
         else:
             a = attend_ctx(cfg, q, k_ctx, v_ctx, attn, **sink)
-    return (layer_out(x, a, lp, la, cfg, mesh=mesh, stats=stats, ffn=ffn),
-            w_pools)
+    return (layer_out(x, a, lp, la, cfg, mesh=mesh, stats=stats, ffn=ffn,
+                      normed=h), w_pools)
 
 
 def forward_pp(params: Dict[str, Any], cfg: LlamaConfig,
@@ -3908,9 +4262,11 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         # writes the rows itself where it can; elsewhere they are scattered
         new = [] if kernel_writes(mesh, attn_impl, kv[0].shape[-1] // fold,
                                   fold) else None
-        q, kv, keep = layer_in(x, lp, la, cfg, pick(sl, rope_sl, rope),
+        h = [] if cfg.parallel_block else None
+        q, kv, keep = layer_in(x, lp, la, cfg, _rope_of(cfg, l, rope_sl, rope),
                                kv, wpg, w_off, index=None if in_win else index,
-                               stats=None if in_win else stats, hold=new)
+                               stats=None if in_win else stats, hold=new,
+                               normed=h)
         with _attn_scope(cfg, in_win):
             extra = {"sink": lp["sink"][la]} if "sink" in lp else {}
             if attn_impl == "pallas":
@@ -3943,6 +4299,6 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
             pools = kv
         x = layer_out(x, attn, lp, la, cfg, mesh=mesh, stats=stats,
                       ffn=ffn if cfg.per_kind else None, active=active,
-                      branch=_branch_of(params, cfg, l, carried))
+                      branch=_branch_of(params, cfg, l, carried), normed=h)
 
     return (_lm_head(x, params, cfg), *pools, *w_pools, *s_pools)

@@ -13,7 +13,7 @@ tokens): SORTED dispatch (stable-sort assignments by expert +
 ``lax.ragged_dot`` segment matmuls — only the experts hit are read) and
 DENSE dispatch (every local expert sees every token — one einsum a matrix,
 combines across shards with one psum). An unsharded mesh chooses by
-:func:`sorted_wins` (measured on the chip for five geometries, the old rule
+:func:`sorted_wins` (measured on the chip for six geometries, the old rule
 outside them); ep/tp-sharded meshes are dense.
 
 Reference capability: the reference inherits MoE/EP from its engines
@@ -102,6 +102,25 @@ def sorted_wins(rows: int, top_k: int, n_experts: int,
     every row count this configuration's programs have but the 64-row chunk
     bucket, where it says dense and sorted is a fifth faster; it is left as
     it is.
+
+    And for 16 held of 128, 4096 x 4096 (100.7 MB an expert, the largest
+    measured), 8 a token, so a row gives the held experts ONE assignment,
+    beside four shared experts that are computed outside this call (my chip
+    run, PR 51, ``benchmarks/tests/program_memory_parblock.py``; ms a layer
+    dense / sorted, held experts hit a layer): a chunk of 32 rows (14.0 hit)
+    2.56 / 3.21; 64 rows (15.3) 2.57 / 5.34; 128 rows (16) 2.48 / 5.58; 256
+    rows 2.66 / 5.82; 512 rows 4.77 / 6.43; the decode program's 12 rows of
+    which b are busy: b = 3 (2.75 hit) 2.45 / 0.67; 6 (5.5) 2.44 / 1.10; 12
+    (8.25) 2.40 / 1.49. Dense streams the 1.61 GB of a layer's held experts
+    in 2.45 ms whatever the rows (657 GB/s) and turns compute-bound between
+    256 and 512 rows (every row through all 16: 0.82 TFLOP a layer at 512,
+    173 TFLOP/s); sorted pays 0.26 ms + 0.15 ms an expert hit at decode rows
+    and 0.35 ms an expert once its group is four rows and more. The rule is
+    on the measured side at EVERY row count this configuration's programs
+    have (the 12-row decode program sorted: 12 expected assignments for 16
+    held; every chunk bucket dense) and is left as it is. What it costs: a
+    512-row chunk's dense call spends 4.8 ms a layer, 19 of a 43 ms chunk,
+    on routed work of 0.05 TFLOP (PERF.md section 7, After PR 51).
 
     A DECODE step knows which of its rows are busy (``moe_ffn(active=)``):
     an idle row's assignments are absent ones, and where this rule says
@@ -239,7 +258,8 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
     ``sigmoid_bias`` (sigmoid scores; the k largest of score + ``bias`` [E],
     the learned selection bias, are chosen; the gates are the chosen SCORES
     over their sum + ``norm_eps`` (LFM2's 1e-6), x ``scaling``: the bias
-    chooses and never weighs) and ``softmax_group``
+    chooses and never weighs; ``sigmoid`` is the same law of a model that
+    has no bias: the k largest scores among all) and ``softmax_group``
     (softmax scores; the experts lie in ``groups[0]`` equal groups, a group
     scores as its best expert, the ``groups[1]`` best groups stay and the
     top-k is taken among their experts; the gates are the chosen scores x
@@ -252,7 +272,7 @@ def route_topk(x: jax.Array, wr: jax.Array, top_k: int,
     # router logit of 32-64 to 0.25, i.e. a gate ratio to 25 %
     logits = jnp.einsum("btd,de->bte", x, wr.astype(x.dtype),
                         preferred_element_type=jnp.float32)
-    if router == "sigmoid_bias":
+    if router in ("sigmoid", "sigmoid_bias"):
         scores = jax.nn.sigmoid(logits)
         _, idx = jax.lax.top_k(scores if bias is None else scores + bias,
                                top_k)
@@ -400,10 +420,7 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
         if active is not None and stats is not None:
             stats["sorted"] = stats.get("sorted", 0) + took_sorted
         if shared is not None:
-            sg, su, sd = shared
-            a = jax.nn.silu(jnp.einsum("btd,df->btf", x, sg)) * jnp.einsum(
-                "btd,df->btf", x, su)
-            out = out + jnp.einsum("btf,fd->btd", a, sd)
+            out = out + shared_ffn(x, *shared)
         if zero:
             ident = idx >= wr.shape[1] - zero
             if active is not None:
@@ -415,6 +432,18 @@ def moe_ffn(x: jax.Array,           # [B, T, D]
                 stats["zero"] = stats.get("zero", 0) + jnp.sum(
                     ident.astype(jnp.int32))
         return out, hit, idx
+
+
+def shared_ffn(x: jax.Array, sg: jax.Array, su: jax.Array, sd: jax.Array,
+               scale: Optional[float] = None) -> jax.Array:
+    """The experts EVERY token passes through, as one SwiGLU of their joint
+    width: ``sg`` / ``su`` [D, Fs], ``sd`` [Fs, D]. ``scale`` multiplies the
+    result: 1 / n for a model that AVERAGES its n shared experts (the sum of
+    the n experts' outputs is what the joint down-projection gives)."""
+    a = jax.nn.silu(jnp.einsum("btd,df->btf", x, sg)) * jnp.einsum(
+        "btd,df->btf", x, su)
+    y = jnp.einsum("btf,fd->btd", a, sd)
+    return y if scale is None else y * jnp.asarray(scale, y.dtype)
 
 
 def moe_ffn_in_stage(x: jax.Array, wr: jax.Array, wg: jax.Array,

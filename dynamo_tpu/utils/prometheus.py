@@ -584,7 +584,14 @@ class StageMetrics:
         self.kv_window_pages_released = r.counter(
             "dyn_kv_window_pages_released_total",
             "Window-cache pages given back while their sequence lived on "
-            "(they lay wholly behind the window of a fetched dispatch)", ())
+            "(they lay wholly behind the window of a fetched dispatch), by "
+            "the phase of that dispatch: prefill (a prompt longer than the "
+            "window gives pages back chunk by chunk) or decode", ("phase",))
+        self.moe_shared_rows = r.counter(
+            "dyn_moe_shared_rows_total",
+            "Rows through the shared experts (experts every token passes "
+            "through), real tokens of busy rows alone, summed over the "
+            "layers that have them", ("kind",))
         self.moe_experts_hit = r.counter(
             "dyn_moe_experts_hit_total",
             "Experts with at least one row, per layer and step, summed "

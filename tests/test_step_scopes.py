@@ -17,6 +17,7 @@ from dynamo_tpu.engine.engine import EngineCore, JaxEngineConfig
 from dynamo_tpu.llm.protocols.common import BackendInput, StopConditions
 from dynamo_tpu.models import llama
 from dynamo_tpu.utils import jaxenv, roofline
+from tests.test_command_a_plus import TINY as COMMAND_A
 from tests.test_granite_hybrid import TINY as GRANITE
 from tests.test_longcat_flash import TINY as LONGCAT
 from tests.test_mimo_v2_flash import TINY as MIMO
@@ -37,6 +38,12 @@ MODELS = {
     # and the landing add under ``ffn``
     "two-sublayers": (lambda: llama.LlamaConfig.from_hf_config(LONGCAT),
                       EVERY | {"attn", "moe_ffn"}),
+    # a parallel block: the one norm under ``attn_in``, router and routed
+    # experts under ``moe_ffn``, the shared experts and the block's one
+    # residual add under ``ffn``; no scope of its own
+    "parallel-block": (lambda: llama.LlamaConfig.from_hf_config(COMMAND_A),
+                       EVERY | {"attn", "attn_full", "attn_window",
+                                "moe_ffn"}),
 }
 KIND_SCOPE = {"decode": "ssm_step", "prefill": "ssm_scan"}
 # instructions that hold others or move nothing: a trace attributes LEAF
