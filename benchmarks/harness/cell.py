@@ -14,7 +14,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import correct, launch, runner
+from . import correct, launch, records, runner
 from .catalog import ROOT, BenchError, Catalog
 from .modeldir import tokens_of, write_model_dir
 from .peaks import peaks_for
@@ -259,6 +259,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             sample_every=0.5 if trace else None))
         after = launch.scrape(handle.base)
         cache_after = _cache_entries()
+        records.keep(os.path.join(scratch, "window_records.json"), window,
+                     before, after)
         served = asyncio.run(runner.serve_samples(handle.base, samples_rq))
         last = launch.scrape(handle.base)
     finally:

@@ -42,8 +42,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from .routed import (ASSIGNMENTS, EXPERTS_HIT, ITEMSIZE, KINDS, device_peaks,
-                     op_seconds, roofline_share, scope_ops, traced)
+from .routed import ASSIGNMENTS, EXPERTS_HIT, ITEMSIZE, KINDS, traced
 
 ROUTED = "dyn_moe_routed_assignments_total"
 RESIDENT = "dyn_kv_resident_token_steps_total"
@@ -94,18 +93,3 @@ def moe_share_least(scrapes, trace, config) -> Optional[tuple]:
     work = {k: traced(scrapes, trace, ASSIGNMENTS, k) for k in KINDS}
     one = 3.0 * d["D"] * d["F"]
     return one * ITEMSIZE * hit, 2.0 * one * sum(work.values()), work
-
-
-def scope_share(metric_file: str, least: Optional[tuple], scrapes,
-                trace) -> Optional[float]:
-    """Roofline share of one scope: ``least`` (bytes, operations, work by
-    kind) over the device time of the operations that ``<metric>.ops.json``
-    lists, in percent of the device's peaks; None where the program has no
-    such work to read or the run is off a TPU."""
-    peaks = device_peaks(scrapes)
-    if not least or not peaks:
-        return None
-    bytes_, flops, work = least
-    return roofline_share(bytes_, flops,
-                          op_seconds(trace, scope_ops(metric_file), work),
-                          peaks)

@@ -1,10 +1,9 @@
 """What the per-layer metrics of routed experts and of learned top-k
 attention share: the program's four counters between the two scrapes, the
-part of them that the traced iterations did, the device time of the two
-groups of operations in the trace, and the functions that count the LEAST
-bytes and operations any implementation must move for that work. A program
-without the counters (a parent commit from before they existed, a dense
-model) reads as no value, never as an error.
+part of them that the traced iterations did, and the functions that count
+the LEAST bytes and operations any implementation must move for that work.
+A program without the counters (a parent commit from before they existed, a
+dense model) reads as no value, never as an error.
 
 The counters (``docs/observability.md``), all by ``kind`` (prefill / decode):
 
@@ -36,15 +35,11 @@ up. A program without that counter (a parent commit from before it existed)
 reads as no value.
 
 The device time is that of the operations under the program's
-``jax.named_scope``. A trace names an operation by its HLO line, not by its
-scope, so the operations are listed beside each metric
-(``<metric>.ops.json``, made from the compiled programs by
-``benchmarks/tests/scope_ops.py``). Two guards against a list gone stale
-(a change in fusion renames operations without an error): ``required``
-names, per kind, keys of which a trace that ran that kind's programs with
-such work must hold at least one, or the metric RAISES; and a key that
-operations outside the scope share (``shared``) counts by the stated part,
-not whole.
+``jax.named_scope``, read by INSTANCE (``harness/scopes.py``
+``twin_share``): every operation of a bucket program carries its scope in
+the capture (``tf_op``), so no list of operation names stands between a
+metric and the trace, and a change of fusion or of kernel under a scope is
+read as it runs.
 
 Least work, derived:
 
@@ -64,10 +59,8 @@ Least work, derived:
 
 from __future__ import annotations
 
-import json
 from typing import Any, Dict, Optional
 
-from .catalog import BenchError
 from .launch import Series, delta
 from .peaks import peaks_for
 
@@ -109,40 +102,6 @@ def device_peaks(scrapes: Dict[str, Series]) -> Optional[Dict[str, float]]:
              if n == "dyn_engine_info" and v == 1
              and l.get("platform") == "tpu"]
     return peaks_for(kinds[0]) if kinds else None
-
-
-def scope_ops(metric_file: str) -> Dict[str, Any]:
-    """The list beside a metric's file, ``<metric>.ops.json``: ``ops``, the
-    keys (``<name> <result type>``, ``harness/xplane.py`` ``op_key``) of the
-    operations of the cell's bucket programs that lie under one
-    ``jax.named_scope``; ``prefixes`` for operations the compiler names
-    itself, whatever their shapes; ``shared``, {key: part}, the keys that
-    operations outside the scope carry too, with the part of the key's
-    time that is the scope's (and ``shared_why``: how the part was found);
-    ``required``, {kind: [keys or prefixes]}."""
-    with open(metric_file[: -len(".py")] + ".ops.json") as f:
-        return json.load(f)
-
-
-def op_seconds(trace: Optional[Dict[str, Any]], listed: Dict[str, Any],
-               work: Dict[str, float]) -> float:
-    """Device seconds of the trace's operations that ``listed`` names.
-    ``work``: {kind: the traced dispatches' work of that kind}; a kind that
-    did such work and left none of its ``required`` operations in the trace
-    means the list no longer describes the programs: an error, not a
-    value."""
-    ops = (trace or {}).get("ops", {})
-    keys, prefixes = set(listed["ops"]), tuple(listed.get("prefixes", ()))
-    shared = listed.get("shared", {})
-    for kind, amount in work.items():
-        need = tuple(listed.get("required", {}).get(kind, ()))
-        if amount > 0 and need and not any(k.startswith(need) for k in ops):
-            raise BenchError(
-                f"the trace ran {kind} programs under scope "
-                f"{listed.get('scope')} and holds none of {list(need)}: the "
-                f"operation list is stale (benchmarks/tests/scope_ops.py)")
-    return sum(v["total_s"] * shared.get(k, 1.0) for k, v in ops.items()
-               if k in keys or (prefixes and k.startswith(prefixes)))
 
 
 def roofline_share(bytes_: float, flops: float, seconds: float,

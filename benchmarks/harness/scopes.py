@@ -351,9 +351,9 @@ def scope_seconds(trace, scope: str, work: Dict[str, float]
 
 def twin_share(least: Optional[tuple], scope: str, scrapes, trace
                ) -> Optional[float]:
-    """Roofline share of one scope by instance: ``least`` (bytes,
-    operations, work by kind: the list-based metric's own function) over the
-    seconds the trace files under ``scope``, in percent of the device's
+    """Roofline share of one scope: ``least`` (bytes, operations, work by
+    kind: a least-work function of ``routed`` / ``kinds`` / ``state``) over
+    the seconds the trace files under ``scope``, in percent of the device's
     peaks; None where there is nothing to read."""
     from .routed import device_peaks, roofline_share
 

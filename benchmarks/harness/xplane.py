@@ -23,10 +23,11 @@ The summary:
               custom call with its target): events, seconds, counted on leaf
               operations only (an operation that contains others, such as a
               ``while``, is their sum and would count twice)
-  kernel_s    seconds in ``tpu_custom_call`` operations: the Pallas kernels.
-              The trace does not name the kernel (``kernel_metadata={}``);
-              which program ran it does: ``ops_by_module`` attributes every
-              leaf operation to the program run that contains it
+  ops_by_module  per program name, its ten leaf operations with most
+              device time, each attributed to the program run that contains
+              it (a Pallas kernel is a ``tpu_custom_call``; the trace does
+              not name the kernel, ``kernel_metadata={}``: which program ran
+              it, and under which scope, ``harness/scopes.py``, does)
   breakdown   device_ops: the ten operation names with most device time;
               idle_gaps: idle device time by what the host was doing, i.e.
               the annotation the gap starts in, or follows
@@ -90,9 +91,6 @@ def op_key(text: str) -> str:
     if target and target.group(1) != base:
         base = f"{base}:{target.group(1)}"
     return f"{base} {shape.group(1)}" if shape else base
-
-
-KERNEL = "tpu_custom_call"
 
 
 def label_gaps(gaps: List[Interval], spans: List[Tuple[float, float, str]]
@@ -192,8 +190,6 @@ def summarise(raw: Dict[str, Any]) -> Dict[str, Any]:
                     for k, v in modules.items()},
         "ops": {k: {"events": len(v), "total_s": sum(v) / n}
                 for k, v in ops.items()},
-        "kernel_s": sum(sum(v) for k, v in ops.items()
-                        if k.startswith(KERNEL)) / n,
         "ops_by_module": {m: dict(top({k: v / n for k, v in d.items()}))
                           for m, d in by_module.items()
                           if sum(d.values()) / n > 1e-3},

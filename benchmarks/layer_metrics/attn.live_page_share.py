@@ -2,9 +2,9 @@
 pages of the blocks it was in for them, in percent: delta
 ``dyn_attn_pages_live_total`` / delta ``dyn_attn_pages_visited_total`` over
 the window, full and window layers together (``docs/observability.md``). A
-block is ``DYNAMO_TPU_PAGED_PPB`` (8) pages; a page is live if it holds a
-token the lane's query sees. Until PR 41 the kernel copied every page of
-such a block and this read what it wasted; since, it is what is left of a
+block is 8 pages (``ops/attention.py`` ``PAGES_PER_BLOCK``, a constant); a
+page is live if it holds a token the lane's query sees. The kernel copies
+only a block's live pages (since PR 41), so this is what is left of a
 block's copies: low where most lanes of the decode program are not served (a
 lane of length 1 copies 1 page of 8), where contexts end early in their last
 block, and under a window narrower than a block. A program without the

@@ -12,11 +12,14 @@ pool access and kernels to the same program: what a PR that touches the
 shared decoder layer for a NEW model owes the models the benchmark has
 (PR 32 lost 20 % of every cell's ``setup_s`` there). It uses nothing a tree
 older than itself lacks, so it runs in a parent checkout unchanged (copy
-this file there). ``fixtures/lowered_pr31.json`` holds the lines of commit
-bf41507 (PR 31) at ``--layers 2``; ``test_lowered_same.py`` holds every
-later tree to them for the three configurations that commit has. A change
-that is MEANT to alter those programs renews the fixture from its own parent
-and says so.
+this file there): run it in a ``git archive`` of the parent and in the
+change, and compare the lines. No fixture pins a commit's lines: a PR that
+is MEANT to alter these programs may not edit a file here to renew one, and
+the engine's own programs are held in tier-1
+(``tests/test_kv_write_pages.py``, ``tests/test_stored_params.py``,
+``tests/test_chip_compile.py``). It lowers ``llama.init_params`` shapes and
+a plain pool, not the stored parameters and the page-run write the engine
+runs. Not part of any check.
 """
 
 from __future__ import annotations

@@ -8,8 +8,7 @@ engine built (``bytes_in_use``).
 
 This process imports jax and holds the chip: run it alone. The numbers go
 into the configuration file's ``memory`` group by hand. Not part of any
-check. (The same two programs compiled for a DESCRIBED chip, no engine:
-``scope_ops_state.py --memory``.)
+check.
 """
 
 from __future__ import annotations

@@ -33,7 +33,7 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(os.path.dirname(HERE)))
 
-from benchmarks.harness import launch, measures, runner  # noqa: E402
+from benchmarks.harness import launch, measures, records, runner  # noqa: E402
 from benchmarks.harness.catalog import Catalog  # noqa: E402
 from benchmarks.harness.cell import (MODEL_NAME, _warm_set, bring_up,  # noqa: E402
                                      prepare)
@@ -41,24 +41,12 @@ from benchmarks.harness.modeldir import tokens_of  # noqa: E402
 from benchmarks.harness.stats import percentile  # noqa: E402
 from benchmarks.harness.traffic import RequestSource  # noqa: E402
 
-KEPT = ("dyn_moe_", "dyn_engine_phase_seconds", "dyn_engine_dispatches")
-
-
-def counters(series: launch.Series) -> dict:
-    return {n + json.dumps(l, sort_keys=True): v for n, l, v in series
-            if n.startswith(KEPT)}
-
-
 def record(r, t0: float) -> dict:
     try:
         toks = tokens_of(" ".join(r.text))
     except ValueError:
         toks = []
-    return {"idx": r.idx, "prompt": r.want_prompt, "tokens": r.tokens,
-            "ok": r.ok(), "due": r.due - t0, "sent": r.sent - t0,
-            "first": None if r.first is None else r.first - t0,
-            "last": None if r.last is None else r.last - t0,
-            "distinct_tokens": len(set(toks))}
+    return {**records.request(r, t0), "distinct_tokens": len(set(toks))}
 
 
 def main() -> int:
@@ -86,12 +74,12 @@ def main() -> int:
                 source.prepare(1)
                 if n == 0:
                     _warm_set(handle.base, source, seed, su.engine)
-                before = counters(launch.scrape(handle.base))
+                before = records.counters(launch.scrape(handle.base))
                 w = asyncio.run(runner.drive_window(
                     su.gen, handle.base, source, params, a.seconds, seed,
                     int(su.mix.get("lengths_seed", 0)),
                     float(su.mix.get("drain_s", 30))))
-                after = counters(launch.scrape(handle.base))
+                after = records.counters(launch.scrape(handle.base))
                 res = w["results"]
                 tpot = measures.tpot_ms(res)
                 row = {"weights": weights, "tokens": seed, "window": n,
@@ -100,10 +88,8 @@ def main() -> int:
                        "tpot_p50_ms": percentile(tpot, 50),
                        "tpot_p90_ms": percentile(tpot, 90)}
                 print(json.dumps(row), flush=True)
-                rows.append({**row, "gained": {
-                    k: v - before.get(k, 0.0) for k, v in after.items()
-                    if v != before.get(k, 0.0)},
-                    "requests": [record(r, w["t0"]) for r in res]})
+                rows.append({**row, "gained": records.gained(before, after),
+                             "requests": [record(r, w["t0"]) for r in res]})
         finally:
             handle.stop()
     if a.out:

@@ -5,12 +5,14 @@
         --seconds 20 [--seed 7] [--out chiprun_out/sweep_<cell>.json]
 
 One server for the whole sweep; for each rate, in rising order, one window of
-the cell's own mix at that rate, followed to its end. Prints one JSON line a
-rate: offered and completed tokens per second, requests in flight when the
-window closed, failures, time to first token and per-request token gap. The
-knee is the highest rate at which completed tokens keep within 3 % of the
-offered ones and the number in flight does not grow over the window; the
-cell's traffic file then gets 0.8 x that, as a number. Not part of any check.
+the cell's own mix at that rate with token ids of its own (``--seed``, + 1 a
+rate: the same sizes, no prompt the server has seen), followed to its end.
+Prints one JSON line a rate: offered and completed tokens per second,
+requests in flight when the window closed, failures, time to first token and
+per-request token gap. The knee is the highest rate at which completed
+tokens keep within 3 % of the offered ones and the number in flight does not
+grow over the window; the cell's traffic file then gets 0.8 x that, as a
+number. Not part of any check.
 """
 
 from __future__ import annotations
@@ -46,18 +48,20 @@ def main() -> int:
     rows = []
     handle, info = bring_up(su, a.rehearse)
     try:
-        first = True
-        for rate in sorted(float(r) for r in a.rates.split(",")):
+        rates = sorted(float(r) for r in a.rates.split(","))
+        for seed, rate in enumerate(rates, a.seed):
+            # a seed a rate: one server serves them all, and with prefix
+            # reuse on it would serve a rate's prompts from the cache of the
+            # rate before (a knee read twice too high, PR 48)
             params = {**su.mix["arrivals"], "rate_per_s": rate}
             block = su.gen.plan(params, a.seconds)["block"]
             source = RequestSource(su.mix, su.config["vocab_size"],
-                                   MODEL_NAME, a.seed, block)
+                                   MODEL_NAME, seed, block)
             source.prepare(1)
-            if first:
-                _warm_set(handle.base, source, a.seed, su.engine)
-                first = False
+            if seed == a.seed:
+                _warm_set(handle.base, source, seed, su.engine)
             w = asyncio.run(runner.drive_window(
-                su.gen, handle.base, source, params, a.seconds, a.seed,
+                su.gen, handle.base, source, params, a.seconds, seed,
                 int(su.mix.get("lengths_seed", 0)),
                 float(su.mix.get("drain_s", 30))))
             res = w["results"]
@@ -67,7 +71,7 @@ def main() -> int:
             in_mid = sum(1 for r in res if r.sent <= mid
                          and (r.last is None or r.last > mid))
             row = {
-                "rate_per_s": rate, "requests": len(res),
+                "rate_per_s": rate, "seed": seed, "requests": len(res),
                 "failed": sum(not r.ok() for r in res),
                 "offered_tok_s": source.sizes()["output_tokens_sum"]
                 / a.seconds,

@@ -1,15 +1,15 @@
 """The readers of a per-kind model's per-layer metrics on counters and a
-trace summary written by hand: what each divides by what, that the program's
-scope lists name the cell's own kernels, and that a program without the
-counters (the parent commit, a model of one law) reads as no value."""
-
-import json
-import os
+trace summary written by hand: what the two counters' shares divide by
+what, the least work under the three roofline shares, and that a program
+without the counters (the parent commit, a model of one law) reads as no
+value. (What the shares divide the least work by, the seconds under
+``dynamo.attn_full`` / ``dynamo.attn_window`` / ``dynamo.moe_ffn``:
+``test_scopes.py``.)"""
 
 import pytest
 
 from benchmarks.harness import kinds
-from benchmarks.harness.catalog import BENCH, Catalog
+from benchmarks.harness.catalog import Catalog
 
 CAP = "dyn_profile_captured_work_total"
 CELL = "mimo-v2-flash-7l.mixedqueue"
@@ -59,14 +59,13 @@ def test_the_two_counter_shares(cat, config):
     # a program without the counters: no value, no error
     none = {"before": before, "after": series()}
     for name in ("cache.window_resident_share", "moe.held_assignment_share",
-                 "kernel.attn_window_roofline_share",
-                 "kernel.attn_full_roofline_share",
-                 "kernel.moe_share_ffn_roofline_share"):
-        assert reduce(cat, name, none, {"ops": {}, "modules": {}},
-                      config) is None
+                 "scope.attn_window_roofline_share",
+                 "scope.attn_full_roofline_share",
+                 "scope.moe_share_ffn_roofline_share"):
+        assert reduce(cat, name, none, {"modules": {}}, config) is None
 
 
-def test_attention_roofline_shares_by_hand(cat, config):
+def test_attention_least_by_hand(config):
     """One traced decode dispatch of 32 lanes x 4 steps at length 1000: a
     window layer must read 128 keys a query, a full one 1000-1003."""
     n_q = 32 * 4
@@ -76,29 +75,18 @@ def test_attention_roofline_shares_by_hand(cat, config):
                        attn_window_keys=n_q * 128,
                        attn_window_pairs=n_q * 128)}
     s = {"before": series(), "after": series(work)}
-    trace = {"modules": {"jit_step": {"runs": 1}}, "ops": {
-        "tpu_custom_call bf16[32,4,16,128]":
-            {"events": 8, "total_s": 8 * 100e-6},
-        "tpu_custom_call bf16[32,8,8,128]":
-            {"events": 20, "total_s": 20 * 50e-6},
-        "pad_bitcast_fusion bf16[32,8,8,256]":
-            {"events": 20, "total_s": 20 * 2e-6}}}
-    # full: 2 layers x 4 heads x (192 + 128) x 2 B a key
-    least = full_keys * 2 * 4 * 320 * 2 / 819e9
-    got = reduce(cat, "kernel.attn_full_roofline_share", s, trace, config)
-    assert got == pytest.approx(100 * least / 800e-6)
-    # window: 5 layers x 8 heads x 320 x 2 B, over kernel + the pad of q
-    least = n_q * 128 * 5 * 8 * 320 * 2 / 819e9
-    got = reduce(cat, "kernel.attn_window_roofline_share", s, trace, config)
-    assert got == pytest.approx(100 * least / (1000e-6 + 40e-6))
-    assert 0 < got < 100
+    trace = {"modules": {"jit_step": {"runs": 1}}}
+    # full: 2 layers x 4 K/V heads x (192 + 128) x 2 B a key; 64 query heads
+    assert kinds.attn_least(s, trace, config, window=False) == (
+        full_keys * 2 * 4 * 320 * 2, 2.0 * full_keys * 64 * 320 * 2,
+        {"prefill": 0.0, "decode": full_keys})
+    # window: 5 layers x 8 K/V heads; decode alone, as the step's bound asks
+    assert kinds.attn_least(s, trace, config, True, ("decode",)) == (
+        n_q * 128 * 5 * 8 * 320 * 2, 2.0 * n_q * 128 * 64 * 320 * 5,
+        {"decode": n_q * 128})
 
 
-def test_held_experts_roofline_share_by_hand(cat, config):
-    # every program of the cell dispatches DENSE (32 rows and more are at
-    # least as many expected assignments as experts held): a layer's
-    # down-projection shares fusion f32[rows] with attention-out (part
-    # 0.7273), in a decode step as in a chunk
+def test_held_experts_least_by_hand(config):
     work = {**captured("decode", dispatches=1, tokens=128,
                        dyn_moe_assignments_total=100,
                        dyn_moe_experts_hit_total=140),
@@ -106,46 +94,24 @@ def test_held_experts_roofline_share_by_hand(cat, config):
                        dyn_moe_assignments_total=300,
                        dyn_moe_experts_hit_total=100)}
     s = {"before": series(), "after": series(work)}
-    trace = {"modules": {"jit_step": {"runs": 1}, "jit_fn": {"runs": 1}},
-             "ops": {
-        "fusion bf16[32,16,2048]": {"events": 48, "total_s": 48 * 0.5e-3},
-        "fusion f32[32]": {"events": 60, "total_s": 60 * 0.4e-3},
-        "fusion bf16[256,16,2048]": {"events": 12, "total_s": 12 * 1e-3},
-        "fusion f32[256]": {"events": 15, "total_s": 15 * 0.4e-3}}}
-    least = 240 * 3 * 4096 * 2048 * 2 / 819e9       # memory-bound
-    got = reduce(cat, "kernel.moe_share_ffn_roofline_share", s, trace, config)
-    assert got == pytest.approx(
-        100 * least / (24e-3 + 0.7273 * 24e-3 + 12e-3 + 0.7273 * 6e-3))
-    # a trace that ran decode programs with such work and holds none of
-    # the scope's gate / up fusions of that kind: the list is stale
-    from benchmarks.harness.catalog import BenchError
-    with pytest.raises(BenchError, match="stale"):
-        reduce(cat, "kernel.moe_share_ffn_roofline_share", s,
-               {"modules": {"jit_step": {"runs": 1}, "jit_fn": {"runs": 1}},
-                "ops": {"fusion bf16[256,16,2048]": {"events": 1,
-                                                      "total_s": 1e-3}}},
-               config)
+    trace = {"modules": {"jit_step": {"runs": 1}, "jit_fn": {"runs": 1}}}
+    one = 3.0 * 4096 * 2048
+    bytes_, flops, assigned = kinds.moe_share_least(s, trace, config)
+    assert (bytes_, flops) == (240 * one * 2, 2.0 * one * 400)
+    assert assigned == {"prefill": 300.0, "decode": 100.0}
+    assert bytes_ / 819e9 > flops / 197e12          # memory-bound
+    # a configuration that holds all its experts is not this function's
+    assert kinds.moe_share_least(s, trace, {
+        **config, "n_routed_experts": 0}) is None
 
 
-def test_the_scope_lists_name_this_cells_kernels(cat, config):
-    eng = config["benchmark"]["engine"]
-    for kind, heads in (("full", 4), ("window", 8)):
-        with open(os.path.join(BENCH, "layer_metrics",
-                               f"kernel.attn_{kind}_roofline_share.ops.json"
-                               )) as f:
-            listed = json.load(f)
-        assert listed["scope"] == f"dynamo.attn_{kind}"
-        assert listed["config"] == "mimo-v2-flash-7l"
-        assert listed["lanes"] == eng["max_batch"]
-        # [lanes, Hkv, G, Dv] a decode step; [Hkv, G, chunk, Dv] a chunk
-        assert listed["required"]["decode"] == [
-            f"tpu_custom_call bf16[32,{heads},{64 // heads},128]"]
-        assert len(listed["required"]["prefill"]) == 4
+def test_the_manifest_lists_the_five_for_this_cell(cat, config):
     assert {"cache.window_resident_share", "moe.held_assignment_share",
-            "kernel.attn_window_roofline_share",
-            "kernel.attn_full_roofline_share",
-            "kernel.moe_share_ffn_roofline_share"} <= {
+            "scope.attn_window_roofline_share",
+            "scope.attn_full_roofline_share",
+            "scope.moe_share_ffn_roofline_share"} <= {
         m["name"] for m in cat.metrics("per_layer", CELL)}
+    assert config["benchmark"]["engine"]["max_batch"] == 32
 
 
 def test_dims_of_another_configuration_read_as_nothing(cat):

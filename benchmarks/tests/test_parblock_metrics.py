@@ -153,7 +153,7 @@ WORK = {**captured("decode", dispatches=2, tokens=24,
 ROW = 8 * 256 * 2
 
 
-def test_the_three_scope_shares_by_hand(cat, config, monkeypatch):
+def test_the_three_shares_of_a_scope_by_hand(cat, config, monkeypatch):
     """Two traced decode dispatches (4 steps, 3 lanes at 10,000 tokens) and
     one 512-row chunk behind 9,728 tokens."""
     s = {"before": series(), "after": series(WORK)}

@@ -56,7 +56,7 @@ def test_the_new_cells_files_rehearse_on_the_cpu(tmp_path):
     mine = [m["name"] for m in Catalog().metrics("per_layer", CELL)]
     assert set(NEW) <= set(mine)
     assert {"step.ffn_ms", "step.mixer_ms", "attn.live_page_share",
-            "kernel.attn_busy_share", "sampler.greedy_dispatch_share",
+            "scope.attn_busy_share", "sampler.greedy_dispatch_share",
             "moe.held_assignment_share"} <= set(mine)
     keep = lambda group, names: [
         {**{k: v for k, v in x.items() if k != "workloads"},

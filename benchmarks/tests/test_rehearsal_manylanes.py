@@ -13,7 +13,7 @@ import time
 from benchmarks.harness.catalog import BENCH, Catalog
 from benchmarks.harness.cell import run_cell
 
-NEW = ["kernel.ssm_step_roofline_share", "kernel.ssm_scan_roofline_share",
+NEW = ["scope.ssm_step_roofline_share", "scope.ssm_scan_roofline_share",
        "ssm.active_state_share"]
 TINY = {
     "model_type": "granitemoehybrid", "attention_bias": False,
@@ -94,6 +94,6 @@ def test_the_new_cells_files_rehearse_on_the_cpu(tmp_path):
         # nothing and raise nothing; the counters' metric reads, and agrees
         # with the lanes in use (4 clients on 4 lanes, now and then one
         # between two requests)
-        assert not {n for n in NEW if n.startswith("kernel.")} & set(got)
+        assert not {n for n in NEW if n.startswith("scope.")} & set(got)
         share = got["ssm.active_state_share"]["value"]
         assert 40.0 < share <= 100.0

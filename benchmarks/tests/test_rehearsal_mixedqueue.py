@@ -13,11 +13,11 @@ from benchmarks.harness.catalog import BENCH, Catalog
 from benchmarks.harness.cell import run_cell
 
 NEW = ["cache.window_resident_share", "moe.held_assignment_share",
-       "kernel.attn_window_roofline_share", "kernel.attn_full_roofline_share",
-       "kernel.moe_share_ffn_roofline_share"]
-DEVICE = {"kernel.attn_window_roofline_share",
-          "kernel.attn_full_roofline_share",
-          "kernel.moe_share_ffn_roofline_share"}
+       "scope.attn_window_roofline_share", "scope.attn_full_roofline_share",
+       "scope.moe_share_ffn_roofline_share"]
+DEVICE = {"scope.attn_window_roofline_share",
+          "scope.attn_full_roofline_share",
+          "scope.moe_share_ffn_roofline_share"}
 TINY = {
     "model_type": "mimo_v2_flash", "hidden_size": 64, "num_hidden_layers": 7,
     "num_attention_heads": 4, "num_key_value_heads": 1, "head_dim": 24,

@@ -59,7 +59,7 @@ def test_the_new_cells_files_rehearse_on_the_cpu(tmp_path):
     assert set(NEW) <= set(mine)
     assert {"step.ffn_ms", "step.mixer_ms", "step.head_ms",
             "step.unscoped_ms", "attn.live_page_share",
-            "kernel.attn_busy_share", "sampler.greedy_dispatch_share",
+            "scope.attn_busy_share", "sampler.greedy_dispatch_share",
             "moe.held_assignment_share", "moe.rows_per_expert_hit",
             "cache.window_resident_share", "program.prefill_chunk_ms",
             "client.ttft_p90_ms"} <= set(mine)

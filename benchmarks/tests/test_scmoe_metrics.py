@@ -132,7 +132,7 @@ def test_a_program_without_the_counters_reads_none(cat, config, monkeypatch):
         assert reduce(cat, name, none, None, config) is None
 
 
-def test_the_three_scope_shares_by_hand(cat, config, monkeypatch):
+def test_the_three_shares_of_a_scope_by_hand(cat, config, monkeypatch):
     """Two traced decode dispatches (4 steps, 10 lanes at 2,000 tokens) and
     one 512-row chunk at a context of 2,048."""
     pairs = 512 * 1536 + 512 * 513 // 2
