@@ -3285,18 +3285,32 @@ def ssm_chunk(c: jax.Array, d: jax.Array, lp: Dict[str, Any], l,
 
 def ssm_step(c: jax.Array, d: jax.Array, lp: Dict[str, Any], l,
              cfg: LlamaConfig, state: jax.Array, tail: jax.Array,
-             active: jax.Array):
+             active: jax.Array, kernel=None):
     """The one-token form. ``c`` [B,Cd], ``d`` [B,H]; ``state`` [B,H,P,N],
     ``tail`` [B,K-1,Cd]; a lane that is not ``active`` [B] keeps both bit
     for bit (its y is computed and discarded with the lane's token).
-    -> (y [B,H,P] float32, state, tail)."""
+    -> (y [B,H,P] float32, state, tail).
+
+    With ``kernel`` (:func:`forward_decode`'s ``state_kernel``) ``state`` is
+    the WHOLE pool [layers,B,H,P,N] and comes back as such: update and
+    read-out of layer ``l`` are the kernel's one pass over the served lanes'
+    blocks, in place (``ops.state.state_step``); a lane that is not active
+    keeps its state because nothing touches it, and its y is 0."""
     window = jnp.concatenate([tail, c.astype(tail.dtype)[:, None]], axis=1)
     X, Bm, Cm, dt, A = _ssm_split(_ssm_conv(window, lp, l, 1)[:, 0], d, lp,
                                   l, cfg)
-    new = (jnp.exp(dt * A)[..., None, None] * state
-           + (dt[..., None] * X)[..., None] * Bm[:, None, None, :])
-    y = jnp.sum(new * Cm[:, None, None, :], axis=-1) + lp["D"][l][:, None] * X
-    state = jnp.where(active[:, None, None, None], new, state)
+    if kernel is None:
+        new = (jnp.exp(dt * A)[..., None, None] * state
+               + (dt[..., None] * X)[..., None] * Bm[:, None, None, :])
+        y = (jnp.sum(new * Cm[:, None, None, :], axis=-1)
+             + lp["D"][l][:, None] * X)
+        state = jnp.where(active[:, None, None, None], new, state)
+    else:
+        y, state = kernel(state, l, jnp.exp(dt * A), dt[..., None] * X, Bm,
+                          Cm)
+        # (the kernel writes no row of y for a lane it does not serve)
+        y = jnp.where(active[:, None, None], y + lp["D"][l][:, None] * X,
+                      0.0)
     tail = jnp.where(active[:, None, None], window[:, 1:], tail)
     return y, state, tail
 
@@ -3344,7 +3358,7 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
                l0: int, n: int, pools: Tuple[jax.Array, ...],
                lanes: Optional[jax.Array], reset: Optional[jax.Array],
                gate: jax.Array, mesh=None,
-               stats: Optional[Dict[str, Any]] = None):
+               stats: Optional[Dict[str, Any]] = None, state_kernel=None):
     """Layers ``l0 .. l0 + n - 1``, all of ONE mixer kind that keeps a state
     a lane (state-space or gated short convolution) and ONE feed-forward
     kind, as one scan over x [B,T,D]: mixer and feed-forward of each, the
@@ -3357,7 +3371,8 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
     ``active`` [B], which a routed feed-forward takes too. Its experts
     hit, held assignments, sorted calls (decode) and chosen ids leave the
     scan as its outputs and are added to ``stats`` as :func:`_ffn_block`
-    adds them outside a scan.
+    adds them outside a scan. ``state_kernel``: :func:`forward_decode`'s,
+    which a state-space layer's decode step hands to :func:`ssm_step`.
     -> (x, pools)."""
     st = params[STACKS]
     conv, routed = cfg.layer_kinds[l0] == 3, cfg.layer_routed(l0)
@@ -3413,15 +3428,22 @@ def _state_run(x: jax.Array, params: Dict[str, Any], cfg: LlamaConfig,
             # (a lane's convolution tail is ONE flat pool row: [K - 1, Cd]
             # rows made XLA re-lay the whole pool twice a chunk)
             if decode:
-                state = jax.lax.dynamic_index_in_dim(s_pool, lm,
-                                                     keepdims=False)
                 tail = jax.lax.dynamic_index_in_dim(
                     c_pool, lm, keepdims=False).reshape(B, -1, Cd)
-                y, state, tail = ssm_step(c[:, 0], d[:, 0], mp, lm, cfg,
-                                          state, tail, gate)
+                if state_kernel is None:
+                    state = jax.lax.dynamic_index_in_dim(s_pool, lm,
+                                                         keepdims=False)
+                    y, state, tail = ssm_step(c[:, 0], d[:, 0], mp, lm, cfg,
+                                              state, tail, gate)
+                    s_pool = jax.lax.dynamic_update_index_in_dim(
+                        s_pool, state, lm, 0)
+                else:
+                    # the kernel reads and writes the pool in place, the
+                    # served lanes' blocks of layer ``lm`` alone
+                    y, s_pool, tail = ssm_step(c[:, 0], d[:, 0], mp, lm, cfg,
+                                               s_pool, tail, gate,
+                                               state_kernel)
                 y = y[:, None]
-                s_pool = jax.lax.dynamic_update_index_in_dim(s_pool, state,
-                                                             lm, 0)
                 c_pool = jax.lax.dynamic_update_index_in_dim(
                     c_pool, tail.reshape(B, -1), lm, 0)
             else:
@@ -4083,6 +4105,15 @@ def kernel_writes(mesh, attn_impl: str, row: int, fold: int) -> bool:
             and paged_kernel_writes(row, fold))
 
 
+def state_kernel_taken(mesh, attn_impl: str) -> bool:
+    """Whether :func:`forward_decode` runs a state-space layer's one-token
+    recurrence as the state kernel (``ops.state.state_step``: the served
+    lanes' state once in and once out, in place): wherever the paged kernel
+    is taken on one shard. The dense path and a tensor-parallel mesh (a
+    sharded state) keep :func:`ssm_step`'s ``jax.numpy`` form."""
+    return attn_impl == "pallas" and _tp_size(mesh) == 1
+
+
 def _tp_size(mesh) -> int:
     from ..parallel.mesh import AXIS_TP as _TP
     if mesh is None or _TP not in mesh.axis_names:
@@ -4145,7 +4176,10 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     ``active`` (an empty slot, a lane deferred under pool pressure) keeps
     its state and convolution tail bit for bit: unlike K/V written past a
     sequence's end, an advanced state cannot be trimmed afterwards. Both
-    pools come back last.
+    pools come back last. Where the paged kernel is taken on one shard
+    (:func:`state_kernel_taken`) the one-token recurrence is the state
+    kernel's: one call a layer moves the served lanes' state once in and
+    once out of the pool, in place, and touches no other lane.
 
     ``active`` [B] bool, the same mask for any model (a model with state
     layers may leave it to ``ssm``'s): a routed feed-forward dispatches the
@@ -4194,6 +4228,20 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
         with scope("kv_write"):
             ww_page = jnp.take_along_axis(w_tables, (pos // page)[:, None],
                                           axis=1)[:, 0]
+    state_kernel = None
+    if cfg.ssm_heads and state_kernel_taken(mesh, attn_impl):
+        from ..ops.state import served_lanes, state_step
+        # which lanes the kernel visits, once a step for every layer (the
+        # mask is the dispatch's: the same list in each of its steps)
+        with scope("ssm_step"):
+            served = served_lanes(s_active)
+        # traced once a program, as the paged kernel is (``paged_for``)
+        _state = jax.jit(partial(state_step,
+                                 interpret=_kernel_interpret(mesh)),
+                         inline=True)
+
+        def state_kernel(pool, layer, *operands):
+            return _state(pool, layer, *served, *operands)
     tp_sz = _tp_size(mesh) if attn_impl == "pallas" else 1
     if attn_impl == "pallas":
         from ..ops.attention import paged_attention as _paged
@@ -4250,7 +4298,8 @@ def forward_decode(params: Dict[str, Any], cfg: LlamaConfig,
     for l, n in _segments(cfg):
         if cfg.layer_state(l):
             x, s_pools = _state_run(x, params, cfg, l, n, s_pools, None,
-                                    None, s_active, mesh, stats)
+                                    None, s_active, mesh, stats,
+                                    state_kernel)
             continue
         lp, la, *ffn = layer_stacks(params, cfg, l)
         sl = cfg.layer_sliding(l)

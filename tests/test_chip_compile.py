@@ -157,6 +157,40 @@ def test_the_kernel_that_copies_live_pages_compiles_at_the_cells_shapes(
     assert f"(bf16[{B},{Hkv},{Hq // Hkv},{Dv}]" in txt
 
 
+@pytest.mark.parametrize("blocks_mib, heads", [(8, 64), (2, 16)])
+def test_the_state_kernel_compiles_at_the_cells_shapes(v5e, monkeypatch,
+                                                       blocks_mib, heads):
+    """``ops.state.state_step`` over granite's whole state pool (36 layers x
+    64 lanes x 64 heads x 64 x 128 float32) with a traced layer and a traced
+    served-lane list: the v5e compiler takes it with a whole lane a block
+    (what the cell runs) and with the lane cut by heads (a larger state),
+    the pool is aliased through the call, and the program holds no copy of
+    the pool or of a layer of it."""
+    from dynamo_tpu.ops import state as S
+
+    monkeypatch.setattr(S, "_STATE_BLOCKS_BYTES", blocks_mib << 20)
+    L, B, H, P, N = 36, 64, 64, 64, 128
+    assert S.head_block(H, P, N) == heads
+    f32 = jnp.float32
+
+    def step(pool, layer, active, a, dtx, Bm, Cm):
+        return S.state_step(pool, layer, *S.served_lanes(active), a, dtx, Bm,
+                            Cm)
+
+    compiled = jax.jit(step, donate_argnums=0).lower(
+        _sds(v5e, (L, B, H, P, N), f32), _sds(v5e, (), jnp.int32),
+        _sds(v5e, (B,), jnp.bool_), _sds(v5e, (B, H), f32),
+        _sds(v5e, (B, H, P), f32), _sds(v5e, (B, N), f32),
+        _sds(v5e, (B, N), f32)).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * B * H * P * N * 4
+    assert mem.temp_size_in_bytes < 8 << 20
+    assert not [ln for ln in txt.splitlines() if " copy(" in ln
+                and f"{B},{H},{P},{N}]" in ln]
+
+
 def test_a_batch_past_vmem_compiles_in_groups_of_lanes(v5e):
     """256 lanes at llama-8b's heads: the per-lane operands (queries, new
     rows, output: 128 KiB a lane as VMEM lays them out) do not fit whole, so

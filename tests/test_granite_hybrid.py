@@ -28,6 +28,7 @@ from dynamo_tpu.engine.cache import cache_kinds
 from dynamo_tpu.engine.engine import EngineCore, JaxEngineConfig
 from dynamo_tpu.llm.protocols.common import BackendInput, StopConditions
 from dynamo_tpu.models import llama
+from dynamo_tpu.ops import state as state_ops
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # served log-probability against the reference's, both float32: the chunk
@@ -229,6 +230,144 @@ def test_the_chunk_form_is_the_token_form_and_padding_is_inert(state):
     assert float(jnp.abs(s2[1] - s0[1]).max()) == 0.0
     assert float(jnp.abs(t2[1] - t0[1]).max()) == 0.0
     assert float(jnp.abs(s2[0] - s0[0]).max()) > 0.0
+
+
+# ---- (b') the state kernel against the token form -------------------------
+def kernel_of(active):
+    """``forward_decode``'s ``state_kernel`` for a dispatch that serves
+    ``active``, through the Pallas interpreter."""
+    served = state_ops.served_lanes(jnp.asarray(active))
+    return lambda pool, l, *operands: state_ops.state_step(
+        pool, l, *served, *operands, interpret=True)
+
+
+def step_inputs(hf, B, layers, seed):
+    """-> (cfg, one layer stack's recurrence parameters, c, d, state pool
+    [layers,B,H,P,N], tail) with random contents."""
+    cfg = model(hf)
+    rng = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    H, Cd, K = cfg.ssm_heads, cfg.ssm_conv_dim, cfg.ssm_conv
+    lp = {"conv_w": 0.5 * f(layers, K, Cd), "conv_b": 0.5 * f(layers, Cd),
+          "dt_bias": f(layers, H), "D": f(layers, H),
+          "A_log": jnp.log(1.0 + 15.0 * jnp.abs(f(layers, H)) / 3.0)}
+    return (cfg, lp, f(B, Cd), f(B, H),
+            f(layers, B, H, cfg.ssm_head_dim, cfg.ssm_state),
+            f(B, K - 1, Cd))
+
+
+WIDE = dict(TINY, mamba_n_heads=64, mamba_d_head=64, mamba_d_state=128,
+            mamba_expand=64)      # the benchmark cell's H / P / N
+MASKS = {"none": [False] * 5, "one": [False, False, False, True, False],
+         "some": [True, False, True, True, False], "all": [True] * 5,
+         "ends": [True, False, False, False, True]}
+
+
+@pytest.mark.parametrize("hf, B", [(TINY, 5), (WIDE, 3)],
+                         ids=["tiny", "cell-widths"])
+def test_the_state_kernel_is_the_token_form(hf, B):
+    """``ssm_step`` through the kernel against ``ssm_step`` itself, random
+    operands, every lane served, layer 1 of 2: the updated state to 1e-6 of
+    the state's scale (the same two products and one sum an element, so
+    they differ by whether the compiler contracts them: an ulp or two; a
+    lane updated twice or not at all is off by the scale itself), y to 1e-5
+    of y's scale (the kernel reads it out as a matrix product at HIGHEST: N
+    products summed in another order, on the chip from six bfloat16 passes:
+    a few sqrt(N) ulps); the other layer of the pool bit for bit."""
+    cfg, lp, c, d, pool, tail = step_inputs(hf, B, 2, 7)
+    on = jnp.asarray([True] * B)
+    y0, s0, t0 = llama.ssm_step(c, d, lp, 1, cfg, pool[1], tail, on)
+    y1, p1, t1 = jax.jit(lambda *a: llama.ssm_step(
+        *a[:2], lp, 1, cfg, *a[2:], on, kernel_of(on)))(c, d, pool, tail)
+    assert p1.shape == pool.shape and p1.dtype == jnp.float32
+    assert float(jnp.abs(p1[1] - s0).max()) < 1e-6 * float(jnp.abs(s0).max())
+    assert float(jnp.abs(y1 - y0).max()) < 1e-5 * float(jnp.abs(y0).max())
+    assert float(jnp.abs(p1[0] - pool[0]).max()) == 0.0
+    assert float(jnp.abs(t1 - t0).max()) == 0.0
+
+
+@pytest.mark.parametrize("mask", sorted(MASKS))
+def test_a_lane_the_kernel_does_not_serve_is_not_touched(mask):
+    """None, one, some (not contiguous) and all of five lanes served: a
+    lane that is not keeps state AND tail bit for bit and returns y == 0;
+    a served lane's state, y and tail are the token form's."""
+    cfg, lp, c, d, pool, tail = step_inputs(TINY, 5, 3, 11)
+    on = np.asarray(MASKS[mask])
+    y0, s0, t0 = llama.ssm_step(c, d, lp, 2, cfg, pool[2], tail,
+                                jnp.asarray(on))
+    y1, p1, t1 = llama.ssm_step(c, d, lp, 2, cfg, pool, tail,
+                                jnp.asarray(on), kernel_of(on))
+    off = ~on
+    assert np.array_equal(np.asarray(p1[2])[off], np.asarray(pool[2])[off])
+    assert np.array_equal(np.asarray(t1)[off], np.asarray(tail)[off])
+    assert not np.asarray(y1)[off].any()
+    assert np.array_equal(np.asarray(p1[:2]), np.asarray(pool[:2]))
+    if on.any():
+        assert float(jnp.abs(p1[2] - s0)[on].max()) < 1e-6 * float(
+            jnp.abs(s0).max())
+        assert float(jnp.abs(y1 - y0)[on].max()) < 1e-5 * float(
+            jnp.abs(y0).max())
+        assert np.array_equal(np.asarray(t1)[on], np.asarray(t0)[on])
+
+
+@pytest.mark.parametrize("mask", ["one", "some", "ends"])
+def test_a_padded_lane_list_updates_no_lane_twice(mask):
+    """The kernel's lane list repeats the last served lane in every slot
+    behind the served ones: those slots do nothing. Two steps through the
+    kernel are two steps through ``ssm_step`` (a lane stepped once more a
+    call would be off by a whole decay)."""
+    on = np.asarray(MASKS[mask])
+    lanes, count = state_ops.served_lanes(jnp.asarray(on))
+    last = int(np.flatnonzero(on)[-1])
+    assert int(count) == on.sum() < len(on)
+    assert np.asarray(lanes).tolist() == (
+        np.flatnonzero(on).tolist() + [last] * int((~on).sum()))
+    cfg, lp, c, d, pool, tail = step_inputs(TINY, 5, 2, 13)
+    s0, t0, t1, act = pool[0], tail, tail, jnp.asarray(on)
+    for k in range(2):
+        ck, dk = jnp.roll(c, k, 0), jnp.roll(d, k, 0)
+        _, s0, t0 = llama.ssm_step(ck, dk, lp, 0, cfg, s0, t0, act)
+        _, pool, t1 = llama.ssm_step(ck, dk, lp, 0, cfg, pool, t1, act,
+                                     kernel_of(on))
+    assert float(jnp.abs(pool[0] - s0).max()) < 2e-6 * float(
+        jnp.abs(s0).max())
+    assert float(jnp.abs(t1 - t0).max()) == 0.0
+
+
+def kernels_under(jaxpr, found=None):
+    """The name stacks of every ``pallas_call`` of ``jaxpr``, at any depth
+    (a scan's body, an inlined jit)."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(str(eqn.source_info.name_stack))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    kernels_under(inner, found)
+    return found
+
+
+def test_the_decode_program_takes_the_state_kernel_where_it_says(core):
+    """The engine's own decode program under ``pallas`` holds the state
+    kernel's call under ``dynamo.ssm_step`` (so the dispatches of (a) and
+    (c) ran its body), the dense path's holds none: a silent fall back to
+    the ``jax.numpy`` form fails here."""
+    B, S, s = core.cfg.max_batch, core.s_buckets[0], core.sampling
+    pt = np.zeros((B, S // core.page_size), np.int32)
+    flags = np.zeros(B, bool)
+    traced = core._decode_fn(S).jitted.trace(
+        core.params, np.zeros(B, np.int32), core.k_pool, core.v_pool, pt,
+        np.ones(B, np.int32), s.temperature, s.top_p, s.top_k, s.key,
+        core.gen_counts, flags, flags, s.freq_pen, s.pres_pen, **core._idx())
+    calls = kernels_under(traced.jaxpr)
+    state_calls = [c for c in calls if "dynamo.ssm_step" in c]
+    taken = llama.state_kernel_taken(core.mesh, core.decode_attn_impl)
+    assert taken == (core.attn_impl == "pallas")
+    # one call a scan body: the runs of state-space layers between the
+    # attention layers (2 + 2 + 1 of the 7 layers)
+    assert len(state_calls) == (3 if taken else 0), calls
 
 
 # ---- (c) -----------------------------------------------------------------
